@@ -1,10 +1,11 @@
 """compat-shim: the moving jax API surface is shimmed in exactly one place.
 
-``common/jax_compat.py`` owns every version-sensitive jax spelling
-(shard_map's check_vma/check_rep rename, ``lax.axis_size``'s absence on
-0.4.x, ``jax.distributed.initialize`` kwarg drift).  r6 found the last raw
-``shard_map`` call site by hand (tools/ragged_smoke.py); this pass makes
-the rule mechanical: outside the shim module, the following are findings —
+``common/jax_compat.py`` owns every jax spelling that has moved between
+versions (shard_map's home and its check_vma/check_rep rename,
+``lax.axis_size``, ``jax.distributed.initialize``'s kwargs), so a jax
+migration lands in one file.  r6 found the last raw ``shard_map`` call site
+by hand; this pass makes the rule mechanical: outside the shim module, the
+following are findings —
 
 - ``from jax.experimental.shard_map import ...`` / ``import
   jax.experimental.shard_map``

@@ -50,10 +50,10 @@ _BANNED_TOP = "jax"
 #: common/platform.py helpers that import jax INSIDE their body: a deferred
 #: import the graph walk cannot see — unless the module CALLS one at module
 #: level, which executes the import right there.  (This is exactly how
-#: master/main.py leaked jax into the control plane: a module-level
-#: ``apply_platform_env()`` call, found by the runtime twin test.)
+#: master/main.py once leaked jax into the control plane: a module-level
+#: platform-helper call, found by the runtime twin test.)
 JAX_IMPORTING_CALLS = frozenset(
-    {"apply_platform_env", "enable_compile_cache", "probe_devices"}
+    {"enable_compile_cache", "compile_cache_stats", "device_summary"}
 )
 
 

@@ -90,10 +90,17 @@ def submit(
 def _run_local(config: JobConfig) -> int:
     """Run the whole job on this host: in-process master, subprocess workers.
 
-    Single-host TPU deployment (one v5e host drives all local chips) and the
-    default when no cluster flags are given — the reference has no strict
-    equivalent (its Local strategy skips the master entirely); keeping the
-    master in the loop preserves dynamic sharding + elasticity locally.
+    Single-host TPU deployment and the default when no cluster flags are
+    given — the reference has no strict equivalent (its Local strategy skips
+    the master entirely); keeping the master in the loop preserves dynamic
+    sharding + elasticity locally.
+
+    On a TPU host the supported shape is ``--num_workers 1``: ONE worker
+    process drives all local chips.  A chip belongs to one process at a
+    time, and the process backend hands every worker the same environment
+    with no chip assignment, so two workers on one host fight for the same
+    chips.  The master in this process never imports jax, so it cannot hold
+    the chip the worker needs.
     """
     from elasticdl_tpu.master.main import Master
 

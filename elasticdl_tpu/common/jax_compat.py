@@ -1,40 +1,21 @@
-"""Version-compat shims over the moving jax API surface.
-
-The framework targets current jax spellings (``jax.shard_map`` with
-``check_vma``, ``lax.axis_size``); deployment images lag — this container
-ships 0.4.37, where shard_map lives under ``jax.experimental`` with the
-``check_rep`` kwarg and ``lax.axis_size`` does not exist yet.  One module
-owns the translation so call sites write the modern API exactly once and a
-jax upgrade deletes shims instead of re-touching every kernel.
-
-Import-time feature detection (not version parsing): the probe is the
-behavior we need, and vendor backports would defeat a version check.
+"""The one module through which the framework reaches jax's moving API
+surface: ``shard_map``, ``axis_size``, ``distributed_initialize`` and the
+jit wrappers.  graftlint's ``compat-shim`` / ``jit-shim`` rules route every
+call site here, so a jax API migration lands in one file.  The installed
+jax (0.9.0) spells all of these natively; there is no branch for any other
+version.
 """
 
 from __future__ import annotations
-
-import inspect
 
 import jax
 from jax import lax
 
 from elasticdl_tpu.common import jitsan
 
-try:  # jax >= 0.6 exports shard_map at top level
-    _shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-
-if "check_vma" in inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    def shard_map(*args, **kwargs):
-        # Older jax spells the replication-check kwarg ``check_rep``
-        # (renamed ~0.6).  Positional args pass through untouched.
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
+shard_map = jax.shard_map
+axis_size = lax.axis_size
+distributed_initialize = jax.distributed.initialize
 
 
 def jit_compiled(fun, name=None, expected_variants=1, **jit_kwargs):
@@ -81,57 +62,3 @@ def jit_donating(fun, donate_argnums=(0,), name=None, expected_variants=1):
         jax.jit, fun, name=name, expected_variants=expected_variants,
         jit_kwargs={"donate_argnums": donate_argnums},
     )
-
-
-def enable_cpu_multiprocess_collectives() -> None:
-    """Give multi-process XLA:CPU a cross-process collectives backend.
-
-    Newer jax defaults ``jax_cpu_collectives_implementation`` to gloo;
-    0.4.x defaults to "none", and a multi-process CPU world then fails its
-    first cross-process psum with "Multiprocess computations aren't
-    implemented on the CPU backend".  Must run before the CPU client forms
-    (callers run it next to ``jax.distributed.initialize``, which has the
-    same constraint).  No-op wherever the flag is gone or already right.
-    """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # pragma: no cover - newer jax
-        pass
-
-
-_DIST_INIT_PARAMS = frozenset(
-    inspect.signature(jax.distributed.initialize).parameters
-)
-
-
-def distributed_initialize(**kwargs) -> None:
-    """``jax.distributed.initialize`` minus the kwargs this jax lacks.
-
-    ``heartbeat_timeout_seconds`` (peer-death detection tuning) landed
-    after 0.4.x; on an older runtime the coordination service keeps its
-    built-in default rather than failing initialization — losing a faster
-    elastic re-rendezvous is strictly better than losing the whole
-    distributed world to a TypeError.
-    """
-    dropped = [k for k in kwargs if k not in _DIST_INIT_PARAMS]
-    for k in dropped:
-        kwargs.pop(k)
-    if dropped:  # pragma: no cover - depends on installed jax
-        import logging
-
-        logging.getLogger("jax_compat").warning(
-            "jax.distributed.initialize does not accept %s on jax %s; "
-            "proceeding with runtime defaults for those knobs",
-            dropped, jax.__version__,
-        )
-    jax.distributed.initialize(**kwargs)
-
-
-if hasattr(lax, "axis_size"):
-    axis_size = lax.axis_size
-else:
-    def axis_size(axis_name) -> int:
-        """Static size of a mapped axis from inside shard_map.  psum of the
-        unit python constant is special-cased to a concrete int on every
-        jax we support, so shapes derived from it stay static."""
-        return lax.psum(1, axis_name)

@@ -84,9 +84,9 @@ def finalize_metrics(metrics: Dict) -> Dict[str, float]:
 class PhaseTimers:
     """Cumulative wall-clock per named worker task-loop phase.
 
-    The job-vs-bench throughput gap (TRAINJOB_r05 53k ex/s/chip vs BENCH_r05
-    289k) was guessed at until these timers: the worker decomposes its task
-    wall into named phases so the gap is attributable instead of folklore.
+    The gap between a full job's throughput and the ingest bench's was
+    guessed at until these timers: the worker decomposes its task wall into
+    named phases so the gap is attributable instead of folklore.
     Phase names used by the worker loop:
 
     - ``prep_wait``   blocked on host ingest (bulk read + decode + stack, or

@@ -209,7 +209,14 @@ class ProcessPodBackend(PodBackend):
     peer-death recovery relaunches TWO processes (the dead pod plus the
     survivor's RESTART), so fleets that want both warm park 2.  A failure
     burst beyond the pool falls back to cold spawns — spares are a latency
-    optimization, never a correctness dependency."""
+    optimization, never a correctness dependency.
+
+    On a TPU host: every pod gets the launcher's environment and NO chip
+    assignment, and a chip belongs to one process at a time, so the
+    supported shape is one worker pod driving all local chips.  A parked
+    spare has imported jax but opened no backend, which is what lets it wait
+    beside the worker that holds the chip; it opens one only after
+    adoption, when the pod it replaces has exited."""
 
     def __init__(
         self,

@@ -45,7 +45,12 @@ from jax import lax
 from elasticdl_tpu.common.jax_compat import axis_size
 from elasticdl_tpu.data.codecs import lm_feed
 from elasticdl_tpu.models.spec import ModelSpec
-from elasticdl_tpu.ops.ring_attention import attention_reference, ring_attention
+from elasticdl_tpu.ops.ring_attention import (
+    PATH_XLA_REFERENCE,
+    announce_path,
+    attention_reference,
+    ring_attention,
+)
 from elasticdl_tpu.ops.embedding import ParallelContext
 
 
@@ -183,6 +188,7 @@ def _tp_block(x, blk, tp_axis, n_heads, compute_dtype):
     # contiguous sharding.)
     qkv = qkv.reshape(b, l, local_heads, 3, head_dim)
     q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+    announce_path(PATH_XLA_REFERENCE, q, True, "tensor-parallel block")
     att = attention_reference(q, k, v, causal=True)
     out = att.reshape(b, l, dim // tp) @ blk["wo"].astype(compute_dtype)
     if tp_axis is not None:

@@ -24,9 +24,14 @@ Training runs through a custom_vjp (standard flash backward: save out +
 logsumexp, recompute probabilities per tile; dq recomputes its own softmax
 stats since it re-derives full score rows anyway).
 
-VMEM bound: whole-K/V residency asserts L <= 8192 (per-program footprint
-~4 MB f32 scores at that limit); longer sequences are what sequence
-parallelism is for.
+VMEM bound: whole-K/V residency asserts L <= 8192.  No ``compiler_params``
+are set, so Mosaic's default 16 MiB scoped-VMEM limit applies; the L=8192
+forward (double-buffered whole K and V, a [128, 8192] f32 score tile, its
+exp and the mask iotas) compiles under it for v5e
+(``tests/test_chip_lowering.py`` compiles the bench shape and this bound
+ahead of time against libtpu, no chip needed), while the same kernel at
+L=16384 asks for 23.94 MiB and is refused.  Longer sequences are what
+sequence parallelism is for.
 """
 
 from __future__ import annotations
@@ -37,6 +42,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from elasticdl_tpu.ops.ring_attention import (
+    PATH_PALLAS_COMPILED,
+    PATH_PALLAS_INTERPRET,
+    announce_path,
+)
 
 # q rows per grid program: one MXU face; f32 (8,128) and bf16 (16,128) min
 # tiles both divide it.
@@ -222,6 +233,14 @@ def _fwd_impl(q, k, v, causal):
     qk, kk, vk = (_to_kernel_layout(x) for x in (q, k, v))
     bh, n_q = b * h, lq // _TQ
     tile, whole, vec_tile, _ = _specs(lq, n_q)
+    interpret = _use_interpret()
+    if interpret:
+        announce_path(
+            PATH_PALLAS_INTERPRET, q, causal,
+            f"backend={jax.default_backend()}",
+        )
+    else:
+        announce_path(PATH_PALLAS_COMPILED, q, causal)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale),
         grid=(bh, n_q),
@@ -231,7 +250,7 @@ def _fwd_impl(q, k, v, causal):
             jax.ShapeDtypeStruct((bh, lq, _LANE), q.dtype),
             jax.ShapeDtypeStruct((bh, n_q, _SUB, _TQ), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=interpret,
     )(qk, kk, vk)
     # Residuals are saved UNPADDED: the lane padding is pure zeros and the
     # backward re-pads in O(L*D) — at d=64 the padded copies would hold 2x

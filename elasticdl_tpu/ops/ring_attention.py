@@ -21,6 +21,7 @@ All collectives are XLA ``ppermute`` on the mesh axis (ICI), differentiable
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -28,6 +29,34 @@ import jax.numpy as jnp
 from jax import lax
 
 from elasticdl_tpu.common.jax_compat import axis_size
+from elasticdl_tpu.common.log_utils import get_logger
+
+logger = get_logger("ops.attention")
+
+#: The attention implementations a trace can land on, as logged by
+#: :func:`announce_path` (``chip_smoke.py`` refuses a transformer job whose
+#: worker log names the wrong one).
+PATH_PALLAS_COMPILED = "pallas-compiled"
+PATH_PALLAS_INTERPRET = "pallas-interpret"
+PATH_XLA_REFERENCE = "xla-reference"
+PATH_XLA_RING = "xla-ring"
+
+
+def announce_path(path: str, q, causal: bool, why: str = "") -> None:
+    """Log which attention implementation a trace chose — once per distinct
+    line per process, since a 12-layer model traces the same choice dozens
+    of times per lowering.  The choices themselves are silent by design
+    (tests need the off-TPU fallbacks); this line is what makes a run that
+    took one visible."""
+    _log_once(
+        f"attention path: {path} (q={tuple(q.shape)} {q.dtype} "
+        f"causal={causal}{'; ' + why if why else ''})"
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _log_once(line: str) -> None:
+    logger.info(line)
 
 
 def _rotate(x: jax.Array, axis_name: str) -> jax.Array:
@@ -58,8 +87,14 @@ def _local_attention(q, k, v, causal: bool) -> jax.Array:
     tests/test_flash_attention.py)."""
     from elasticdl_tpu.ops.flash_attention import flash_attention, supports
 
-    if jax.default_backend() == "tpu" and supports(q, k, v):
+    backend = jax.default_backend()
+    if backend == "tpu" and supports(q, k, v):
         return flash_attention(q, k, v, causal)
+    announce_path(
+        PATH_XLA_REFERENCE, q, causal,
+        f"backend={backend}" if backend != "tpu"
+        else "shape outside the flash kernel's contract",
+    )
     return attention_reference(q, k, v, causal=causal)
 
 
@@ -85,6 +120,7 @@ def ring_attention(
         # Degenerate ring (1-device mesh under shard_map): exact local
         # attention, flash-kernelled on TPU.
         return _local_attention(q, k, v, causal)
+    announce_path(PATH_XLA_RING, q, causal, f"n={n}")
     my = lax.axis_index(axis_name)
     b, lq, h, d = q.shape
     lk = k.shape[1]

@@ -35,10 +35,7 @@ from typing import Optional
 
 import jax
 
-from elasticdl_tpu.common.jax_compat import (
-    distributed_initialize,
-    enable_cpu_multiprocess_collectives,
-)
+from elasticdl_tpu.common.jax_compat import distributed_initialize
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.platform import free_port  # noqa: F401 — re-export
 # (free_port lives in the jax-free common.platform: bench/test master
@@ -104,12 +101,6 @@ def initialize(spec: DistributedSpec) -> None:
         "jax.distributed.initialize(%s, num_processes=%d, process_id=%d)",
         spec.coordinator_address, spec.num_processes, spec.process_id,
     )
-    # Both via the compat shims: older jax (this image's 0.4.37) predates
-    # the heartbeat_timeout_seconds kwarg (kept at the runtime default
-    # instead of failing initialization) and defaults the CPU harness's
-    # cross-process collectives to "none" (every cross-process psum would
-    # fail) where newer jax defaults to gloo.
-    enable_cpu_multiprocess_collectives()
     distributed_initialize(
         coordinator_address=spec.coordinator_address,
         num_processes=spec.num_processes,

@@ -611,17 +611,26 @@ class Trainer:
         # path is a plain local gather (VERDICT r2 Weak #1 — ragged at n=1
         # paid the full routing machinery with zero peers).
         platform = self.mesh.devices.flat[0].platform
+        # Tables shard over the LAST axis only; that is the size the
+        # collective lookup sees (a hierarchical mesh's dp axis never
+        # carries embedding traffic).
+        axis_size = self.mesh.shape[self.axis_name]
+        impl = resolve_impl(
+            self.config.embedding_lookup_impl, platform, axis_size=axis_size
+        )
+        if self.sharded_embeddings:
+            # "auto" silently means dense anywhere but multi-chip TPU; the
+            # log names the route this mesh will actually trace.
+            logger.info(
+                "embedding lookup route: %s (requested %s; platform=%s, "
+                "axis %s size %d)",
+                impl, self.config.embedding_lookup_impl, platform,
+                self.axis_name, axis_size,
+            )
         return ParallelContext(
             axis_name=self.axis_name,
             sharded_embeddings=self.sharded_embeddings,
-            embedding_impl=resolve_impl(
-                self.config.embedding_lookup_impl,
-                platform,
-                # Tables shard over the LAST axis only; that is the size the
-                # collective lookup sees (a hierarchical mesh's dp axis never
-                # carries embedding traffic).
-                axis_size=self.mesh.shape[self.axis_name],
-            ),
+            embedding_impl=impl,
             tp_axis=self.tp_axis,
         )
 
@@ -1102,8 +1111,7 @@ class Trainer:
 
         ``pre_sharded=True``: the batches are ALREADY device-placed (the
         worker's prefetch thread ran ``shard_batch``, overlapping the H2D
-        transfer with the in-flight device step — on a remote/tunneled chip
-        a synchronous device_put costs a full RTT per batch).  Only legal
+        transfer with the in-flight device step).  Only legal
         without host-tier tables: host injection needs the host batch.
 
         ``use_async=False``: the synchronous loop — each batch's pull sees

@@ -66,12 +66,14 @@ def _load() -> ctypes.CDLL:
                 lib = ctypes.CDLL(_LIB_PATH)
         except (subprocess.CalledProcessError, OSError) as e:
             _lib_error = f"native lib unavailable: {e}"
-            # Said ONCE, loudly: every ingest hot path (criteo/census
-            # decode, bulk recordio reads, host stores) silently degrades to
-            # Python fallbacks that are ~80x slower (docs/perf.md) — a
-            # profile-invisible collapse unless it is logged.  Subsequent
-            # calls fail fast on the cached error without re-logging.
-            logger.warning(
+            # Said ONCE, as an error: every ingest hot path (criteo/census
+            # decode, bulk recordio reads, host stores) degrades to Python
+            # fallbacks that are ~80x slower (docs/perf.md) and the job
+            # still exits 0 — a profile-invisible collapse unless it is
+            # logged.  Subsequent calls fail fast on the cached error
+            # without re-logging; chip_smoke.py refuses a worker whose boot
+            # line says the library is missing.
+            logger.error(
                 "%s — ingest/PS hot paths fall back to Python "
                 "implementations (~80x slower decode; see docs/perf.md)",
                 _lib_error,

@@ -26,15 +26,11 @@ from typing import List, Optional
 
 # PS pods must not grab the TPU chips the workers need — force CPU
 # UNCONDITIONALLY (not setdefault: the pod env inherits the worker-oriented
-# JAX_PLATFORMS) and re-assert through jax.config, which beats the image
-# sitecustomize's force-registered TPU plugin (common/platform.py).
+# JAX_PLATFORMS), before anything imports jax.
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.common.log_utils import get_logger, set_level
-from elasticdl_tpu.common.platform import apply_platform_env
-
-apply_platform_env()
 
 logger = get_logger("ps.main")
 

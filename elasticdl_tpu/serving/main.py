@@ -93,14 +93,16 @@ def main() -> int:
     port = int(cfg.get("base_port", 8700)) + slot
     gauge_port = int(cfg.get("metrics_base_port", 8800)) + slot
 
-    # Trainer before the model zoo: zoo modules import ops.embedding,
-    # which mid-module imports parallel (-> trainer -> ops.embedding) —
-    # resolvable only when trainer loads first.  Standby parking already
-    # orders it this way; the cold-start path must too.
-    import elasticdl_tpu.parallel.trainer  # noqa: F401
+    from elasticdl_tpu.common.platform import (
+        device_summary,
+        enable_compile_cache,
+    )
     from elasticdl_tpu.models.spec import load_model_spec
     from elasticdl_tpu.serving.server import ServingServer
 
+    # Every replica warms the same batch buckets: relaunches and scale-ups
+    # load them from the shared cache instead of recompiling.
+    enable_compile_cache()
     spec = load_model_spec(
         cfg.get("model_zoo", "elasticdl_tpu.models"),
         cfg["model_def"],
@@ -131,6 +133,7 @@ def main() -> int:
         ),
     )
     warm_s = server.warmup()
+    logger.info("replica %s device: %s", replica_id, json.dumps(device_summary()))
     logger.info(
         "replica %s (slot %d): warmed %d bucket(s) in %.2fs; serving on "
         "port %d, /metrics on %d",

@@ -11,6 +11,7 @@ Run as ``python -m elasticdl_tpu.worker.main``.
 
 from __future__ import annotations
 
+import json
 import os
 import sys
 import threading
@@ -19,10 +20,7 @@ from typing import List, Optional
 
 from elasticdl_tpu.common.config import JobConfig, parse_args
 from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.common.platform import apply_platform_env
 from elasticdl_tpu.common.rpc import PROTOCOL_VERSION
-
-apply_platform_env()
 from elasticdl_tpu.data.reader import (
     AbstractDataReader,
     CompositeDataReader,
@@ -75,7 +73,9 @@ def _park_as_standby(go_file: str) -> str:
     (docs/perf.md) — then park until the pod manager writes the go file
     naming the worker id this process should become.  Nothing here may
     touch a jax *backend* (devices/compile): in multihost mode the backend
-    must first bind to the jax.distributed world formed AFTER registration.
+    must first bind to the jax.distributed world formed AFTER registration,
+    and on a TPU host the live worker holds the chip — a spare that opened
+    a backend while parked would fail or hang, or take the chip from it.
     Returns the assigned worker id."""
     import importlib
 
@@ -105,8 +105,6 @@ def _park_as_standby(go_file: str) -> str:
             logger.info("standby orphaned (parent gone); exiting")
             raise SystemExit(0)
         time.sleep(0.05)
-    import json
-
     # JSON payload: the worker id plus per-pod identity env the backend
     # withheld at spawn time so one spare serves any slot (ProcessPodBackend
     # _IDENTITY_KEYS) — e.g. ELASTICDL_WORKER_SLOT, which
@@ -389,6 +387,18 @@ def main(argv: Optional[List[str]] = None) -> int:
         gauges=gauge.default(), incarnation=incarnation,
     )
     worker_holder["worker"] = worker
+    # Boot line: what this process — the one that owns the device and runs
+    # every step — actually landed on.  With JAX_PLATFORMS unset JAX falls
+    # back to the CPU when the accelerator fails to initialise, and the job
+    # would otherwise finish "green" on the host without saying so.
+    from elasticdl_tpu.common.platform import (
+        compile_cache_stats,
+        device_summary,
+    )
+    from elasticdl_tpu.ps.host_store import native_lib_available
+
+    boot = dict(device_summary(), native_lib=native_lib_available())
+    logger.info("worker %s device: %s", worker_id, json.dumps(boot))
     metrics_server = maybe_start(
         config.gauge_port,
         worker.gauges.render_prometheus,
@@ -415,7 +425,11 @@ def main(argv: Optional[List[str]] = None) -> int:
         hb_stop.set()
         if metrics_server is not None:
             metrics_server.stop()
-    logger.info("worker %s finished: %s", worker_id, result)
+    result["device"] = boot
+    result["compile_cache"] = compile_cache_stats()
+    logger.info(
+        "worker %s finished: %s", worker_id, json.dumps(result, default=str)
+    )
     return 0
 
 

@@ -43,8 +43,8 @@ def _batches(n_batches, seed0=0, b=16):
 
 
 def _run(devices, use_async, n_batches, async_staleness=1):
-    """Depth pinned to 1 (not the config default, which is data-chosen and
-    may move — artifacts/async_depth_r05.json): these tests characterize
+    """Depth pinned to 1 (not the config default, which a measurement may
+    move): these tests characterize
     the CLASSIC async window and its sync equivalence."""
     import jax
 

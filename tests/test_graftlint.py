@@ -848,14 +848,14 @@ def test_import_hygiene_counts_package_init():
 
 
 def test_import_hygiene_flags_module_level_platform_call():
-    # The real leak this pass closed: apply_platform_env() imports jax
-    # inside its body, so a module-level CALL executes the import even
+    # The real leak this pass closed: a common/platform.py helper imports
+    # jax inside its body, so a module-level CALL executes the import even
     # though no 'import jax' statement is visible at module scope.
     srcs = _sources({
         "pkg/__init__.py": "",
         "pkg/control.py": (
-            "from elasticdl_tpu.common.platform import apply_platform_env\n"
-            "apply_platform_env()\n"
+            "from elasticdl_tpu.common.platform import device_summary\n"
+            "device_summary()\n"
         ),
     })
     findings = run_passes(srcs, [ImportHygienePass(roots=("pkg.control",))])

@@ -676,8 +676,6 @@ import os, sys
 sys.path.insert(0, {os.path.dirname(os.path.dirname(os.path.abspath(__file__)))!r})
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-import jax
-jax.config.update("jax_platforms", "cpu")
 from elasticdl_tpu.worker.main import main
 sys.exit(main())
 """

@@ -23,9 +23,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from elasticdl_tpu.common.platform import apply_platform_env, enable_compile_cache
-
-apply_platform_env()
+from elasticdl_tpu.common.platform import enable_compile_cache
 
 
 def bench_depth(depth: int, steps: int, n_shards: int, batch: int) -> dict:
@@ -98,10 +96,6 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8192)
     ap.add_argument("--depths", default="0,1,2,4")
     args = ap.parse_args()
-    from elasticdl_tpu.common.platform import probe_devices
-
-    # Hang-proof init: see bench.py (VERDICT r4 Next #1).
-    probe_devices(attempts=3, timeout_s=90)
     enable_compile_cache()
     # The sweep's verdict flips with the wire's mood (a stall-window sweep
     # ranks sync > any async depth because the pull RTT dominates), so the

@@ -10,8 +10,9 @@ workload — CIFAR's 32x32 convs are too small to tile the systolic array.
 MFU method: FLOPs per step come from XLA's own compiled cost analysis
 (``compiled.cost_analysis()['flops']``) — the count of what the compiled
 program actually executes, not a hand-derived estimate — divided by
-measured steady-state step time and the chip's bf16 peak (v5e: 197 TFLOP/s
-per chip).  ResNet-50 is the proof the trainer sustains MXU utilization
+measured steady-state step time and the chip's bf16 peak (looked up by the
+``device_kind`` that answered, tools/artifact.PEAK_BF16_FLOPS; an unknown
+chip raises).  ResNet-50 is the proof the trainer sustains MXU utilization
 when FLOPs dominate; the tabular models are embedding/HBM-bound by design
 and their MFU is reported for completeness, not as a target.
 
@@ -29,11 +30,8 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from elasticdl_tpu.common.platform import apply_platform_env, enable_compile_cache
-
-apply_platform_env()
-
-V5E_BF16_PEAK = 197e12  # FLOP/s per chip
+from elasticdl_tpu.common.platform import device_summary, enable_compile_cache
+from tools.artifact import peak_bf16_flops
 
 WARMUP = 5
 MEASURE = 30
@@ -156,6 +154,8 @@ def bench_config(name: str, batch_override: int = 0, measure: int = MEASURE) -> 
 
     cfg = CONFIGS[name]
     devices = jax.devices()
+    device = device_summary()
+    peak = peak_bf16_flops(device)  # no TPU, or an unknown one: raise now
     n_chips = len(devices)
     batch = batch_override or cfg["batch"]
     batch = max(batch // n_chips * n_chips, n_chips)
@@ -178,21 +178,16 @@ def bench_config(name: str, batch_override: int = 0, measure: int = MEASURE) -> 
     # batch placement: the executing call may have donated the first one.
     flops = None
     try:
-        from elasticdl_tpu.common.platform import suspend_compile_cache
-
         sharded2 = trainer.shard_batch(host_batch)
-        # Cache bypassed: an XLA:CPU AOT entry re-read by the process that
-        # just wrote it hard-aborts in this jax build (platform.py).
-        with suspend_compile_cache():
-            cost = (
-                # Third arg since r15: the graftreduce subgroup mask is a
-                # traced input of every train step.
-                trainer._train_step.lower(
-                    state, sharded2, trainer._active_device()
-                )
-                .compile()
-                .cost_analysis()
+        cost = (
+            # Third arg since r15: the graftreduce subgroup mask is a
+            # traced input of every train step.
+            trainer._train_step.lower(
+                state, sharded2, trainer._active_device()
             )
+            .compile()
+            .cost_analysis()
+        )
         c = cost[0] if isinstance(cost, (list, tuple)) else cost
         flops = float(c.get("flops", 0.0)) or None
         sharded = sharded2
@@ -215,6 +210,8 @@ def bench_config(name: str, batch_override: int = 0, measure: int = MEASURE) -> 
         "examples_per_sec_per_chip": round(batch / step_s / n_chips),
         "step_ms": round(step_s * 1e3, 2),
         "chips": n_chips,
+        # What answered, observed in this process — never the env var.
+        "device": device,
     }
     if flops:
         # cost_analysis() reports the PER-DEVICE executable's flops (the
@@ -224,11 +221,11 @@ def bench_config(name: str, batch_override: int = 0, measure: int = MEASURE) -> 
         # mesh).  Verified: at global batch 8 on 8 devices the reported
         # count matches ~1 example's training flops, not 8.
         out["flops_per_step_per_device"] = flops
-        out["mfu_pct"] = round(flops / step_s / V5E_BF16_PEAK * 100, 2)
+        out["mfu_pct"] = round(flops / step_s / peak * 100, 2)
     analytic = cfg.get("analytic_flops_per_example")
     if analytic:
         out["mfu_analytic_pct"] = round(
-            analytic * (batch / n_chips) / step_s / V5E_BF16_PEAK * 100, 2
+            analytic * (batch / n_chips) / step_s / peak * 100, 2
         )
     return out
 
@@ -238,7 +235,7 @@ def run_gauge_smoke() -> int:
     answer mid-run with the instrumented families, watch_job renders a
     live scrape, instrumentation overhead holds the <2% budget, and the
     cross-rev trajectory gate passes non-empty.  Host-only (CPU-harness
-    subprocess fleet, no chip probe): the smoke measures the metrics
+    subprocess fleet): the smoke measures the metrics
     plane, not the accelerator."""
     import tempfile
 
@@ -435,7 +432,7 @@ def main() -> None:
     if args.gauge_smoke:
         raise SystemExit(run_gauge_smoke())
     if args.masterfail_smoke:
-        # CPU-harness subprocess fleet, no chip probe (the chaos-smoke
+        # CPU-harness subprocess fleet (the chaos-smoke
         # stance): the smoke measures master crash survivability — the
         # journal replay + ride-through machinery — not the accelerator.
         from tools.chaos_bench import run_masterfail_smoke
@@ -458,7 +455,7 @@ def main() -> None:
         )
         return
     if args.chaos_smoke:
-        # CPU-harness subprocess fleet, no chip probe: the smoke measures
+        # CPU-harness subprocess fleet: the smoke measures
         # the recovery machinery, not the accelerator.
         from tools.chaos_bench import run_smoke
 
@@ -519,7 +516,7 @@ def main() -> None:
         )
         return
     if args.trace_smoke:
-        # Host-only (no chip probe): the smoke measures the recorder, not
+        # Host-only: the smoke measures the recorder, not
         # the accelerator, and must run on any box.
         from tools.ingest_bench import trace_overhead_ab
 
@@ -538,12 +535,9 @@ def main() -> None:
             "< 2% budget", file=sys.stderr,
         )
         return
-    from elasticdl_tpu.common.platform import probe_devices
-
-    # Killable-subprocess probe before the first in-process backend touch:
-    # a hung chip costs bounded probe attempts, not the whole stage timeout
-    # (bench.py's hang-proofing, applied battery-wide — VERDICT r4 Next #1).
-    probe_devices(attempts=3, timeout_s=90)
+    # This process opens the backend itself (bench_config) and owns the
+    # chip for the whole battery; the fleets further down are CPU-pinned
+    # subprocess harnesses and never contend for it.
     enable_compile_cache()
     results = []
     try:

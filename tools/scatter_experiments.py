@@ -28,10 +28,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from elasticdl_tpu.common.platform import (  # noqa: E402
-    apply_platform_env,
-    enable_compile_cache,
-)
+from elasticdl_tpu.common.platform import enable_compile_cache  # noqa: E402
 from tools.gather_experiments import trace_total_device_us  # noqa: E402
 
 # jax globals populated by _init_jax() (same lazy pattern as
@@ -46,7 +43,6 @@ def _init_jax() -> None:
     global jax, jnp, lax
     if jax is not None:
         return
-    apply_platform_env()
     import jax as _jax
     import jax.numpy as _jnp
     from jax import lax as _lax

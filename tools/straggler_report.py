@@ -151,8 +151,7 @@ def run_gang(n_workers: int, n_tasks: int, log) -> dict:
     tmp = tempfile.mkdtemp(prefix="straggler_")
     raw_path = os.path.join(tmp, "dump_raw.json")
     fleet = _run_ingest_fleet(
-        n_workers, n_tasks, tmp, log, platform="cpu",
-        trace_dump_raw=raw_path,
+        n_workers, n_tasks, tmp, log, trace_dump_raw=raw_path,
     )
     if not os.path.exists(raw_path):
         # The bench swallows dump-write failures by design (a failed dump
