@@ -274,7 +274,8 @@ def main() -> None:
     # v5e — the model is embedding-bound BY DESIGN.  The honest utilization
     # lens is the embedding traffic: per step the fused table moves ~109 MB
     # of random 128-lane rows each way (gather + scatter-add); per-op trace
-    # times (tools/profile_step.py) put those at ~1.9/2.9 ms = ~50 GB/s
+    # times (a device trace; today --profile_dir + benchmark/xplane.py)
+    # put those at ~1.9/2.9 ms = ~50 GB/s
     # effective random-row bandwidth, i.e. the step sits at the HBM
     # random-access floor, not a compute ceiling.
     step_ms = elapsed / MEASURE_STEPS * 1e3

@@ -53,7 +53,10 @@ _BANNED_TOP = "jax"
 #: master/main.py once leaked jax into the control plane: a module-level
 #: platform-helper call, found by the runtime twin test.)
 JAX_IMPORTING_CALLS = frozenset(
-    {"enable_compile_cache", "compile_cache_stats", "device_summary"}
+    {
+        "enable_compile_cache", "compile_cache_stats", "device_summary",
+        "count_compiles", "device_peak_bytes", "device_bytes_in_use",
+    }
 )
 
 

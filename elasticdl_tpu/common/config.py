@@ -273,9 +273,10 @@ class JobConfig:
     # PhaseTimers phase, RPC boundary, gang wait and elastic transition,
     # ship bounded slices to the master on the heartbeat/report channel,
     # and tools/trace_dump.py merges a live job's buffers into one
-    # Perfetto-loadable file (docs/observability.md).  Off by default;
-    # measured overhead on the ingest bench is <2% (artifacts/
-    # TRACE_r12.json), so flipping it on a production job is safe.
+    # Perfetto-loadable file (docs/observability.md).  Off by default.
+    # What the ring costs a job on the chip has not been measured; the
+    # same spans written into a --profile_dir window cost nothing the
+    # report clock can see (PERF.md section 6, PR 24).
     trace: bool = False
     # Ring capacity (events) of the per-process trace buffer; oldest events
     # are overwritten, so the buffer always holds the most recent window.
@@ -294,7 +295,12 @@ class JobConfig:
     # A/B harness — docs/observability.md), so flipping the endpoint on
     # is purely additive.
     gauge_port: int = -1
-    profile_dir: str = ""  # worker: jax.profiler trace of one training task
+    # worker: a jax.profiler trace (device planes AND the host's spans of
+    # common/trace.py, on one clock) of worker.PROFILE_TASKS consecutive
+    # training tasks, from the second dispatch on (the first compiles).
+    # The traced tasks are prepped, dispatched, settled and reported like
+    # any other; the file is written off the task loop.
+    profile_dir: str = ""
     metrics_dir: str = ""  # master: JSONL + TensorBoard scalar stream
     # Process backend: capture each worker pod's stdout+stderr to
     # {pod_log_dir}/{pod-name}.log (the local analog of kubectl logs; pod

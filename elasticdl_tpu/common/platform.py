@@ -64,6 +64,7 @@ class _CompileStats:
         self.installed = False
         self.hits = 0
         self.misses = 0
+        self.compiles = 0
         self.compile_s = 0.0
         self.last = ""
         self.functions: dict = {}
@@ -83,6 +84,7 @@ class _CompileStats:
         if event != _BACKEND_COMPILE:
             return
         with self.lock:
+            self.compiles += 1
             self.compile_s += duration
             # The hit/miss event fires INSIDE this compile's span, so the
             # newest one is this function's; "" means the cache was not
@@ -118,12 +120,29 @@ def enable_compile_cache() -> None:
         )
     # Cache even fast compiles: elastic resizes re-trace many small steps.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    count_compiles()
+
+
+def count_compiles() -> None:
+    """Start counting this process's backend compiles and cache traffic
+    (``jax.monitoring`` listeners; idempotent).  Touches no jax config:
+    every worker calls it, cache or no cache, so the compile counters that
+    ride its task reports are true in any process."""
+    import jax
+
     with _stats.lock:
         if _stats.installed:
             return
         _stats.installed = True
     jax.monitoring.register_event_listener(_stats.on_event)
     jax.monitoring.register_event_duration_secs_listener(_stats.on_duration)
+
+
+def compile_counts() -> tuple:
+    """(backend compiles, their seconds) of this process so far; zeros
+    until :func:`count_compiles` has run.  jax-free to call."""
+    with _stats.lock:
+        return _stats.compiles, _stats.compile_s
 
 
 def compile_cache_stats() -> dict:
@@ -152,6 +171,26 @@ def device_bytes_in_use() -> list:
         stats = d.memory_stats()
         out.append(None if stats is None else int(stats["bytes_in_use"]))
     return out
+
+
+def device_peak_bytes() -> int:
+    """Peak device memory on the fullest local chip, in bytes:
+    ``peak_bytes_in_use + peak_bytes_reserved`` of ``memory_stats()``.  The
+    TPU runtime keeps a program's temporaries in a region it reserves at
+    the bottom of memory, outside ``bytes_in_use``, so the peak is the two
+    peaks together.  0 where the backend reports no stats (XLA:CPU)."""
+    import jax
+
+    peak = 0
+    for d in jax.local_devices():
+        stats = d.memory_stats()
+        if stats:
+            peak = max(
+                peak,
+                int(stats.get("peak_bytes_in_use", 0))
+                + int(stats.get("peak_bytes_reserved", 0)),
+            )
+    return peak
 
 
 def device_summary() -> dict:

@@ -132,6 +132,13 @@ MASTER_SCHEMAS: Dict[str, MessageSchema] = {
             # report so the master's JobStatus and the train-job artifact
             # can attribute throughput to named phases without a new RPC.
             "phase_times": _DICT,
+            # counters (PR 24): the worker's own cumulative counters since
+            # it started (worker.COUNTER_GAUGES: compiles, compile_s,
+            # hbm_peak_bytes, dispatches, dispatches_device_idle), beside
+            # phase_times.  The master writes one "counter" record per
+            # successful training report into metrics.jsonl and JobStatus
+            # serves the newest per worker.  Additive and optional.
+            "counters": _DICT,
             # seq (r18): per-worker monotonically increasing report
             # sequence number.  The master journals the highest seq seen
             # per worker (master/journal.py) and DEDUPES a replayed seq
@@ -142,7 +149,7 @@ MASTER_SCHEMAS: Dict[str, MessageSchema] = {
             # semantics, so no PROTOCOL_VERSION bump (the r9 stance).
             "seq": _INT,
         },
-        since={"requeue": 9, "seq": 18},
+        since={"requeue": 9, "seq": 18, "counters": 24},
     ),
     "ReportVersion": MessageSchema(
         required={"model_version": _INT}, optional={"worker_id": _STR}
@@ -359,8 +366,12 @@ MASTER_RESPONSE_SCHEMAS: Dict[str, MessageSchema] = {
         optional={
             "journal": _DICT, "standby_pool": _INT,
             "eval_metrics": _DICT, "eval_rounds": _INT,
+            "counters": _DICT,
         },
-        since={"journal": 18, "standby_pool": 13, "eval_rounds": 9},
+        since={
+            "journal": 18, "standby_pool": 13, "eval_rounds": 9,
+            "counters": 24,
+        },
     ),
     "DumpTrace": MessageSchema(
         required={

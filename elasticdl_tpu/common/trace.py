@@ -14,7 +14,8 @@ Design constraints, in order:
   ``append`` is GIL-atomic (no lock), overwriting the OLDEST event when
   full — a tracing stall or an unbounded buffer must never be the thing
   that makes the traced job slow.  Disabled (the default), ``span()``
-  returns a shared no-op context manager: one attribute read per call.
+  returns a shared no-op context manager: two attribute reads per call
+  (the ring's switch and the bridge below).
 - **Stdlib only.**  The master control plane and the lint/bench tools are
   jax-free by contract (graftlint import-hygiene); the recorder rides in
   all of them.
@@ -32,6 +33,14 @@ API split the ``trace-discipline`` lint rule enforces:
 - export API (forbidden in ``# hot-path`` functions): ``drain_slice`` /
   ``export`` / ``chrome_events`` — draining belongs on control-plane
   boundaries (heartbeats, checkpoint reports, dump tools).
+
+The bridge: a process that holds a profiler (the worker, while its
+``--profile_dir`` window is open) installs a factory ``(name, attrs) ->
+context manager`` with :func:`set_bridge`; every span is then ALSO entered
+through it, so the same spans land in the profiler's own trace on the
+profiler's clock, beside the device planes.  This module stays stdlib-only:
+it never learns what the factory builds.  The bridge works with the ring
+off, and costs nothing when it is not installed.
 
 Per-thread nesting: spans stack per thread; each records its parent's id
 and its SELF time (wall minus directly nested spans' wall) in
@@ -77,11 +86,30 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _BridgeSpan:
+    """A span that exists only in the bridge's trace (ring off): it has no
+    id, so RPC clients propagate no parent for it."""
+
+    __slots__ = ("_cm",)
+    span_id = 0
+
+    def __init__(self, cm):
+        self._cm = cm
+
+    def __enter__(self) -> "_BridgeSpan":
+        self._cm.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._cm.__exit__(*exc)
+        return False
+
+
 class _Span:
     """One live span: a context manager pushed on the per-thread stack."""
 
     __slots__ = ("_rec", "name", "cat", "attrs", "span_id", "parent_id",
-                 "_t0", "_child")
+                 "_t0", "_child", "_bridged")
 
     def __init__(self, rec: "TraceRecorder", name: str, cat: str,
                  attrs: Dict[str, Any]):
@@ -92,9 +120,16 @@ class _Span:
         self.span_id = 0
         self.parent_id = 0
         self._child = 0.0
+        self._bridged = None
 
     def __enter__(self) -> "_Span":
         rec = self._rec
+        bridge = rec.bridge
+        if bridge is not None:
+            # Outside the ring's own timing on both ends, so the ring's
+            # self-time arithmetic does not see the bridge.
+            self._bridged = bridge(self.name, self.attrs)
+            self._bridged.__enter__()
         stack = rec._stack()
         self.span_id = next(rec._ids)
         self.parent_id = stack[-1].span_id if stack else 0
@@ -122,6 +157,8 @@ class _Span:
             self.name, self.cat,
             rec._to_us(self._t0), elapsed * 1e6, args,
         )
+        if self._bridged is not None:
+            self._bridged.__exit__(*exc)
         return False
 
 
@@ -140,6 +177,8 @@ class TraceRecorder:
     def __init__(self, enabled: bool = False,
                  capacity: int = DEFAULT_CAPACITY):
         self.enabled = bool(enabled)
+        # (name, attrs) -> context manager, or None: see set_bridge().
+        self.bridge = None
         self.capacity = int(capacity)
         self._buf: collections.deque = collections.deque(maxlen=self.capacity)
         self._local = threading.local()
@@ -175,7 +214,10 @@ class TraceRecorder:
     def span(self, name: str, cat: str = "span", **attrs):
         """Context manager recording one complete ("X") event on exit."""
         if not self.enabled:
-            return _NULL_SPAN
+            bridge = self.bridge
+            if bridge is None:
+                return _NULL_SPAN
+            return _BridgeSpan(bridge(name, attrs))
         return _Span(self, name, cat, attrs)
 
     def instant(self, name: str, cat: str = "event", **attrs) -> None:
@@ -259,6 +301,29 @@ def configure(enabled: Optional[bool] = None,
     if enabled is not None:
         _REC.enabled = bool(enabled)
     return _REC
+
+
+def set_bridge(factory) -> None:
+    """Install (or, with None, clear) the process recorder's bridge: a
+    factory ``(name, attrs) -> context manager`` entered around every span
+    while it is set, ring on or off.  One plain attribute store: spans
+    already open keep the bridge state they were entered with."""
+    _REC.bridge = factory
+
+
+def name_os_thread() -> None:
+    """Give the calling thread's Python name to the OS (Linux; 15 bytes).
+    Python 3.12 names threads for itself only, and a profiler names a
+    thread's line after the OS name — without this every pool thread's
+    line reads like the main thread's.  Pool initializers call it; never
+    the main thread (its OS name is the process's)."""
+    try:
+        import ctypes
+
+        name = threading.current_thread().name.encode()[:15]
+        ctypes.CDLL(None).prctl(15, name, 0, 0, 0)  # PR_SET_NAME
+    except Exception:  # not Linux, no libc: the lines stay unnamed
+        pass
 
 
 def enabled() -> bool:

@@ -30,6 +30,8 @@ import os
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, List, Sequence, Tuple, TypeVar
 
+from elasticdl_tpu.common import trace
+
 _T = TypeVar("_T")
 _R = TypeVar("_R")
 
@@ -90,7 +92,10 @@ class IngestPool:
         self.threads = resolve_threads(threads)
         self._pool = (
             ThreadPoolExecutor(
-                max_workers=self.threads, thread_name_prefix="edl-ingest"
+                max_workers=self.threads, thread_name_prefix="edl-ingest",
+                # the OS thread name is what names the thread's line in a
+                # profiler trace
+                initializer=trace.name_os_thread,
             )
             if self.threads > 1
             else None

@@ -113,6 +113,10 @@ class MasterServicer:
         # sums alone cannot answer "how long is one lease RPC on average" —
         # counts make per-phase means computable from the same artifact.
         self._phase_counts: Dict[str, dict] = {}  # guarded-by: _lock
+        # Newest per-worker counters (worker.COUNTER_GAUGES: compiles,
+        # device memory peak, dispatches that found the device idle);
+        # cumulative like the phase snapshot, so latest wins.
+        self._counters: Dict[str, dict] = {}  # guarded-by: _lock
         # Per-worker trace buffers (bounded ring each, like the worker's
         # own): Heartbeat/Report-borne slices land here; DumpTrace reads
         # them.  clock_offset_us is the worker's RTT-midpoint estimate of
@@ -612,6 +616,7 @@ class MasterServicer:
         success = bool(req.get("success", True))
         task_type = req.get("task_type", "")
         self._record_phase_times(req)
+        self._record_counters(req)
         self._record_trace(req)
         # stream=True: one JSONL "gauge" record per successful training
         # report, beside the "phase" record — the same crash-safe channel
@@ -744,21 +749,47 @@ class MasterServicer:
             self._phase_times[worker_id] = dict(phases)
             if counts:
                 self._phase_counts[worker_id] = dict(counts)
-            fallback_version = self._model_version
+        if stream:
+            self._stream_report_record("phase", req, phases)
+
+    def _stream_report_record(
+        self, kind: str, req: dict, values: dict, tensorboard: bool = True
+    ) -> None:
+        """One JSONL record of a report-borne cumulative snapshot: written
+        for successful non-eval reports only, ``ts`` by this master,
+        ``step`` = the report's model version."""
         if (
-            stream
-            and self.metrics_writer is not None
-            and req.get("success", True)
-            and req.get("task_type", "") not in (TASK_EVALUATION,)
+            self.metrics_writer is None
+            or not req.get("success", True)
+            or req.get("task_type", "") == TASK_EVALUATION
         ):
-            try:
-                self.metrics_writer.write(
-                    "phase",
-                    int(req.get("model_version", fallback_version)),
-                    {k: float(v) for k, v in phases.items()},
-                )
-            except Exception:  # malformed values must not fail the report
-                logger.exception("phase_times metrics write failed")
+            return
+        with self._lock:
+            fallback_version = self._model_version
+        try:
+            self.metrics_writer.write(
+                kind,
+                int(req.get("model_version", fallback_version)),
+                {k: float(v) for k, v in values.items()},
+                tensorboard=tensorboard,
+            )
+        except Exception:  # malformed values must not fail the report
+            logger.exception("%s metrics write failed", kind)
+
+    # hot-path: rides every report
+    def _record_counters(self, req: dict) -> None:
+        """Keep the newest counters per worker and mirror them to the
+        metrics stream as one "counter" record, gated like "phase"."""
+        counters = req.get("counters")
+        worker_id = req.get("worker_id", "")
+        if not counters or not worker_id:
+            return
+        with self._lock:
+            self._counters[worker_id] = dict(counters)
+        # JSONL only: the live view of the counters is the worker's gauges,
+        # and the TensorBoard mirror would cost every report handler a
+        # third of a millisecond more.
+        self._stream_report_record("counter", req, counters, tensorboard=False)
 
     #: Bound on each worker's master-side trace ring (events).  A straggler
     #: hunt wants the RECENT window, so overwrite-oldest per worker — the
@@ -1239,6 +1270,9 @@ class MasterServicer:
             }
             status["phase_counts"] = {
                 w: dict(c) for w, c in self._phase_counts.items()
+            }
+            status["counters"] = {
+                w: dict(c) for w, c in self._counters.items()
             }
             # r13 tail tolerance: per-rank deadline-skip counts, beside
             # the dispatcher's per-task accounting already in ``status``.

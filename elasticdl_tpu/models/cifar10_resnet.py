@@ -47,8 +47,9 @@ def _group_norm(x, scale, bias, groups=8, eps=1e-5):
 
     The naive form (upcast x to f32, mean/var, normalize, affine, downcast)
     spent ~40% of the ResNet-50 step in convert_element_type + f32
-    elementwise + multi-pass reduces (per-op trace, tools/profile_step.py
-    --config resnet50_imagenet).  TPU-native form:
+    elementwise + multi-pass reduces (per-op device trace of
+    resnet50_imagenet; today --profile_dir + benchmark/xplane.py).
+    TPU-native form:
 
     - moments in ONE pass: sum and sum-of-squares reduced directly from the
       bf16 input with f32 accumulation (XLA fuses the upcast/square into the

@@ -62,7 +62,8 @@ def _init_params(
     # linear weight (last dim, zero init) per id: the per-id scatter/gather
     # cost is per PHYSICAL ROW (128 lanes) regardless of dim, so a separate
     # dim-1 linear table would double the dominant scatter-add for 1/128th
-    # of a row's payload (profiled: tools/profile_step.py).  Stored
+    # of a row's payload (from a per-op device trace; today --profile_dir
+    # + benchmark/xplane.py).  Stored
     # lane-packed — see ops/embedding.py: whole-physical-row gathers/
     # scatters are the TPU fast path (flat-slice layout hit a serial
     # per-row loop).

@@ -33,7 +33,12 @@ from elasticdl_tpu.common.checkpoint import CheckpointManager
 from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.metrics import PhaseTimers, finalize_metrics
-from elasticdl_tpu.common.platform import device_bytes_in_use
+from elasticdl_tpu.common.platform import (
+    compile_counts,
+    count_compiles,
+    device_bytes_in_use,
+    device_peak_bytes,
+)
 from elasticdl_tpu.common.rpc import (
     PROTOCOL_VERSION,
     BackoffPolicy,
@@ -54,6 +59,35 @@ from elasticdl_tpu.parallel.mesh import create_mesh, mesh_shape, resolve_2d_shap
 from elasticdl_tpu.parallel.trainer import Trainer, TrainLoopError
 
 logger = get_logger("worker")
+
+#: Consecutive training tasks one ``--profile_dir`` window traces, from the
+#: second training dispatch of the worker on (the first pays compilation).
+PROFILE_TASKS = 3
+
+#: The worker's own counters (cumulative since it started; they ride every
+#: task report as ``counters``) and the gauge each is published under.
+COUNTER_GAUGES = {
+    "compiles": (
+        "edl_xla_compiles_total", "XLA backend compiles of this process"),
+    "compile_s": (
+        "edl_xla_compile_seconds_total", "seconds in XLA backend compiles"),
+    "hbm_peak_bytes": (
+        "edl_hbm_peak_bytes",
+        "peak_bytes_in_use + peak_bytes_reserved on the fullest local chip"),
+    "dispatches": ("edl_dispatches_total", "training dispatches"),
+    "dispatches_device_idle": (
+        "edl_dispatches_device_idle_total",
+        "training dispatches that found the previous one's output ready: "
+        "the device queue had run dry and the chip waited for the host"),
+}
+
+
+def _profile_annotation(name: str, attrs: dict):
+    """common/trace.py's bridge while a profile window is open: the span
+    as a ``jax.profiler.TraceAnnotation``, on the profiler's clock."""
+    return jax.profiler.TraceAnnotation(
+        name, **{k: v for k, v in attrs.items() if v is not None}
+    )
 
 
 class DirectMasterProxy:
@@ -355,7 +389,28 @@ class Worker:
         self._ckpt_lock = locksan.lock("Worker._ckpt_lock", leaf=True)  # lock-order: leaf
         self._last_ckpt_step = 0  # guarded-by: _ckpt_lock
         self.reforms = 0  # elastic mesh re-formations (observability/tests)
-        self._training_tasks_done = 0  # gates the one-task profiler trace
+        self._training_tasks_done = 0  # training tasks dispatched
+        # --profile_dir window (_profile_open_if_due): "" until it opens,
+        # then "open", then "closed" for the rest of the worker's life.
+        # The preemption thread reaches the close through _flush_pending,
+        # which the _parked handshake serializes against the loop (see
+        # preemption_snapshot): one writer at a time.
+        self._profile_state = ""  # single-writer: main
+        self._profile_traced = 0  # single-writer: main (dispatches inside the window)
+        # Task whose report closes the window (the last traced one).
+        self._profile_last_task: Optional[int] = None  # single-writer: main
+        self._profile_closer: Optional[threading.Thread] = None  # single-writer: main
+        # Training dispatches, and those among them that began with the
+        # previous dispatch's output already ready (the device had nothing
+        # queued): see _dispatch_training_task.  _last_output is that
+        # output's last leaf, kept for the one non-blocking is_ready().
+        self._dispatches = 0  # single-writer: main
+        self._dispatches_idle = 0  # single-writer: main
+        self._last_output = None
+        # Newest counter snapshot (_counter_snapshot): replaced wholesale
+        # at every report, republished as gauges at scrape time.
+        self._counters: Dict[str, float] = {}  # gil-atomic
+        count_compiles()
         # Task-level pipeline: the previous training task's (report, device
         # metrics), fetched + reported only after the NEXT task's steps are
         # dispatched (see _dispatch_training_task for why).
@@ -889,6 +944,10 @@ class Worker:
                     "current mesh extent per axis (dp=data, tp=model)",
                     labels={"axis": ax},
                 ).set(float(val))
+        # The newest report's counters (no device read at scrape time).
+        for key, value in self._counters.items():
+            family, doc = COUNTER_GAUGES[key]
+            g.gauge(family, doc).set(float(value))
         for name, secs in self.phases.snapshot().items():
             g.gauge(
                 "edl_phase_seconds_total",
@@ -1402,21 +1461,110 @@ class Worker:
 
     # ---- profiling ----
 
-    def _maybe_start_profile(self):
-        """Trace the SECOND training task (the first pays compilation) into
-        ``config.profile_dir`` with ``jax.profiler`` — the reference's
-        TF-profiler-hook role (SURVEY.md §5 "Tracing/profiling").  Counts
-        training tasks only, so interleaved eval/predict tasks neither skip
-        the trace nor shift it onto a compiling step."""
-        if not self.config.profile_dir or self._training_tasks_done != 1:
-            return False
+    def _profile_open_if_due(self) -> None:
+        """Open the ``--profile_dir`` window as the SECOND training task is
+        taken up for dispatch (the first pays compilation; with prep-ahead
+        a task is leased and prepped earlier than that, and opening at the
+        lease would put the first task's compile inside the window).  The
+        window spans ``PROFILE_TASKS`` consecutive tasks and changes
+        nothing about how they are prepped, dispatched, settled and
+        reported.  Counts training tasks only, so interleaved eval/predict
+        tasks neither skip the trace nor shift it onto a compiling step.
+
+        While it is open every span of ``common/trace.py`` is also a
+        ``jax.profiler.TraceAnnotation`` (the bridge), so the host's phases
+        land in the same xplane as the device planes, on one clock.  The
+        Python tracer is off (it hooks every call of the loop under
+        study); host tracer level 1 is the lowest that still records the
+        annotations.  Failing to start is a logged error, never a dead
+        job."""
+        if (
+            not self.config.profile_dir
+            or self._profile_state
+            or self._training_tasks_done != 1
+        ):
+            return
         try:
-            jax.profiler.start_trace(self.config.profile_dir)
-            logger.info("profiling this task into %s", self.config.profile_dir)
-            return True
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1
+            jax.profiler.start_trace(
+                self.config.profile_dir, profiler_options=options
+            )
         except Exception:
             logger.exception("profiler start failed")
-            return False
+            self._profile_state = "closed"
+            return
+        self._profile_state = "open"
+        trace.set_bridge(_profile_annotation)
+        logger.info(
+            "profile window open: %d training tasks from dispatch seq %d "
+            "into %s (pipelining %s, prep-ahead %s, %d task(s) in prep)",
+            PROFILE_TASKS, self._dispatches, self.config.profile_dir,
+            self._pipelining_enabled(), self._prep_ahead_eligible(),
+            len(self._prep_queue),
+        )
+
+    def _profile_close_if_due(self, task_id: int) -> None:
+        """Called once a task's report has gone out: the last traced
+        task's report closes the window."""
+        if self._profile_last_task == task_id:
+            self._profile_close()
+
+    def _profile_close(self) -> None:
+        """Stop the annotations now and hand the session to a thread of
+        its own: collecting the device trace and writing the files takes
+        seconds on a TPU (1.6 s and 11.7 s in the benchmark's two cells,
+        PERF.md) and must not sit on the task loop at the edge of the
+        window it has just measured."""
+        if self._profile_state != "open":
+            return
+        # graftlint: allow[shared-state] the _parked spin-wait handshake serializes the preemption thread's _flush_pending against the loop (see preemption_snapshot)
+        self._profile_state = "closed"
+        # graftlint: allow[shared-state] the _parked spin-wait handshake serializes the preemption thread's _flush_pending against the loop (see preemption_snapshot)
+        self._profile_last_task = None
+        trace.set_bridge(None)
+        traced = self._profile_traced
+
+        def _stop():
+            t0 = time.perf_counter()
+            try:
+                jax.profiler.stop_trace()
+            except Exception:
+                logger.exception("profiler stop failed")
+                return
+            logger.info(
+                "profile window closed: %d task(s) traced into %s; "
+                "collecting and writing took %.3f s off the task loop",
+                traced, self.config.profile_dir, time.perf_counter() - t0,
+            )
+
+        closer = threading.Thread(target=_stop, name="edl-profile", daemon=True)
+        closer.start()
+        # graftlint: allow[shared-state] the _parked spin-wait handshake serializes the preemption thread's _flush_pending against the loop (see preemption_snapshot)
+        self._profile_closer = closer
+
+    def _profile_settle(self) -> None:
+        """Job end (any exit of ``run``): close a window the job was too
+        short to fill, and wait for the file."""
+        self._profile_close()
+        closer, self._profile_closer = self._profile_closer, None
+        if closer is not None:
+            closer.join(timeout=120.0)
+
+    def _counter_snapshot(self) -> Dict[str, float]:
+        """The worker's cumulative counters, read once per report on the
+        settle path (one ``memory_stats()`` per local device; nothing per
+        step).  Keys: ``COUNTER_GAUGES``."""
+        compiles, compile_s = compile_counts()
+        self._counters = {
+            "compiles": compiles,
+            "compile_s": round(compile_s, 6),
+            "hbm_peak_bytes": device_peak_bytes(),
+            "dispatches": self._dispatches,
+            "dispatches_device_idle": self._dispatches_idle,
+        }
+        return self._counters
 
     # ---- task execution ----
 
@@ -1457,64 +1605,72 @@ class Worker:
         semantics are bit-identical to the serial path (the feed decodes
         each record independently, so a chunked feed concatenates to
         exactly the serial feed's bytes)."""
-        # graftchaos: stall(point=prep) — the host-side straggler the
-        # deadline-bounded gang boundary exists to cut short.
-        chaos.hook(
-            "worker:prep", rank=self._rank, step=self._steps_dispatched
-        )
-        mb = self.config.minibatch_size
-        shard = task.shard
-        pool = self._ingest
-        chunks = (
-            plan_chunks(shard.start, shard.end, mb, pool.threads)
-            if pool.parallel
-            and getattr(self.reader, "thread_safe_ranges", False)
-            else None
-        )
-        if not chunks or len(chunks) < 2:
-            records = self._read_records(shard)
-            total = len(records)
-            n_full = total // mb
-            stacked = (
-                self._stack_full_minibatches(records, mb, n_full)
-                if n_full >= 1
+        # One span per task on its prep thread; the task id ties it to the
+        # loop's spans of the same task.
+        with trace.span("prep", cat="ingest", task=task.task_id):
+            # graftchaos: stall(point=prep) — the host-side straggler the
+            # deadline-bounded gang boundary exists to cut short.
+            chaos.hook(
+                "worker:prep", rank=self._rank, step=self._steps_dispatched
+            )
+            mb = self.config.minibatch_size
+            shard = task.shard
+            pool = self._ingest
+            chunks = (
+                plan_chunks(shard.start, shard.end, mb, pool.threads)
+                if pool.parallel
+                and getattr(self.reader, "thread_safe_ranges", False)
                 else None
             )
-            return HostPrep(total, n_full, stacked, list(records[n_full * mb:]))
-
-        def _decode_chunk(span):
-            # Runs on an ingest-pool thread; its cumulative time lands in
-            # the off-critical-path ``decode_parallel`` phase (the phase
-            # stack is per-thread, so this never subtracts from the
-            # foreground phases).
-            with self.phases.phase("decode_parallel"):
-                recs = self._read_records(Shard(shard.name, span[0], span[1]))
-                t = len(recs) // mb
+            if not chunks or len(chunks) < 2:
+                records = self._read_records(shard)
+                total = len(records)
+                n_full = total // mb
                 stacked = (
-                    self._stack_full_minibatches(recs, mb, t)
-                    if t >= 1
+                    self._stack_full_minibatches(records, mb, n_full)
+                    if n_full >= 1
                     else None
                 )
-                return len(recs), t, stacked, list(recs[t * mb:])
+                return HostPrep(
+                    total, n_full, stacked, list(records[n_full * mb:])
+                )
 
-        parts = pool.map_ordered(_decode_chunk, chunks)
-        total = sum(p[0] for p in parts)
-        n_full = sum(p[1] for p in parts)
-        stacks = [p[2] for p in parts if p[2] is not None]
-        if not stacks:
-            stacked = None
-        elif len(stacks) == 1:
-            stacked = stacks[0]
-        else:
-            # Ordered concat along the step axis: chunk i's [t_i, mb, ...]
-            # rows precede chunk i+1's, exactly the serial reshape's layout.
-            stacked = {
-                k: np.concatenate([s[k] for s in stacks], axis=0)
-                for k in stacks[0]
-            }
-        # plan_chunks puts the ragged tail on the LAST chunk, so only it
-        # can have leftover records.
-        return HostPrep(total, n_full, stacked, parts[-1][3])
+            def _decode_chunk(span):
+                # Runs on an ingest-pool thread; its cumulative time lands
+                # in the off-critical-path ``decode_parallel`` phase (the
+                # phase stack is per-thread, so this never subtracts from
+                # the foreground phases).
+                with self.phases.phase("decode_parallel", task=task.task_id):
+                    recs = self._read_records(
+                        Shard(shard.name, span[0], span[1])
+                    )
+                    t = len(recs) // mb
+                    stacked = (
+                        self._stack_full_minibatches(recs, mb, t)
+                        if t >= 1
+                        else None
+                    )
+                    return len(recs), t, stacked, list(recs[t * mb:])
+
+            parts = pool.map_ordered(_decode_chunk, chunks)
+            total = sum(p[0] for p in parts)
+            n_full = sum(p[1] for p in parts)
+            stacks = [p[2] for p in parts if p[2] is not None]
+            if not stacks:
+                stacked = None
+            elif len(stacks) == 1:
+                stacked = stacks[0]
+            else:
+                # Ordered concat along the step axis: chunk i's [t_i, mb,
+                # ...] rows precede chunk i+1's, exactly the serial
+                # reshape's layout.
+                stacked = {
+                    k: np.concatenate([s[k] for s in stacks], axis=0)
+                    for k in stacks[0]
+                }
+            # plan_chunks puts the ragged tail on the LAST chunk, so only it
+            # can have leftover records.
+            return HostPrep(total, n_full, stacked, parts[-1][3])
 
     def _gather_contribution(self, shard: int) -> None:
         """One dp shard's contribution crossing the collective gate.  On
@@ -1711,12 +1867,27 @@ class Worker:
         # past --collective_deadline_ms is excluded-and-renormalized
         # instead of holding the collective.
         self._collective_gate(task)
+        # One training dispatch begins.  Its ordinal rides the dispatch
+        # span, so the n-th execution of the step program in a device
+        # trace can be tied to the task that launched it.  If the previous
+        # dispatch's output is ready already, the device has nothing
+        # queued and waits for this host: the starvation signal that needs
+        # no profiler (one non-blocking call per task).
+        seq = self._dispatches
+        self._dispatches = seq + 1
+        if self._last_output is not None and self._last_output.is_ready():
+            self._dispatches_idle += 1
+        if self._profile_state == "open" and self._profile_last_task is None:
+            self._profile_traced += 1
+            if self._profile_traced >= PROFILE_TASKS:
+                self._profile_last_task = task.task_id
+        tid = task.task_id
         mb = self.config.minibatch_size
         if prep is not None:
             records = None
             total, n_full, stacked_host, tail = prep
         else:
-            with self.phases.phase("prep_wait"):
+            with self.phases.phase("prep_wait", task=tid):
                 records = self._read_records(task.shard)
             total = len(records)
             n_full = total // mb
@@ -1752,7 +1923,7 @@ class Worker:
                 if stacked_host is not None:
                     stacked = stacked_host
                 else:
-                    with self.phases.phase("prep_wait"):
+                    with self.phases.phase("prep_wait", task=tid):
                         stacked = self._stack_full_minibatches(
                             records, mb, n_full
                         )
@@ -1761,7 +1932,10 @@ class Worker:
                 # dispatch window a loud failure (explicit device_put /
                 # device_get spellings stay legal) — the runtime half of
                 # graftlint's transfer-discipline rule.
-                with self.phases.phase("dispatch"), jitsan.transfer_guard():
+                with self.phases.phase(
+                    "dispatch", task=tid, seq=seq,
+                    step0=self._steps_dispatched,
+                ), jitsan.transfer_guard():
                     self.state, scan_metrics = self.trainer.train_scan(
                         self.state, self.trainer.shard_stacked_batch(stacked)
                     )
@@ -1802,9 +1976,10 @@ class Worker:
                 # design — the documented sync point — so the guard arms
                 # only for the dense paths where any implicit transfer is
                 # a genuine leak.
-                with self.phases.phase("dispatch"), jitsan.transfer_guard(
-                    when=not self.spec.host_io
-                ):
+                with self.phases.phase(
+                    "dispatch", task=tid, seq=seq,
+                    step0=self._steps_dispatched,
+                ), jitsan.transfer_guard(when=not self.spec.host_io):
                     self.state, metrics_list = self.trainer.run_train_steps(
                         self.state,
                         prefetch(
@@ -1851,9 +2026,13 @@ class Worker:
         # completes, so the deferred fetch in _finalize_training_metrics
         # finds them resident instead of paying a blocking transfer RTT
         # while the device queue sits idle.
-        for leaf in jax.tree.leaves(metrics_list):
+        leaves = jax.tree.leaves(metrics_list)
+        for leaf in leaves:
             if hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
+        self._last_output = (
+            leaves[-1] if leaves and hasattr(leaves[-1], "is_ready") else None
+        )
         return metrics_list, n_steps
 
     def _recover_state(self) -> None:
@@ -1883,7 +2062,9 @@ class Worker:
 
     # hot-path: the one deliberate drain per task — both blocking halves
     # sit inside their named phase boundaries
-    def _finalize_training_metrics(self, metrics_list) -> Dict[str, float]:
+    def _finalize_training_metrics(
+        self, metrics_list, task_id: int
+    ) -> Dict[str, float]:
         """ONE device_get of the whole task's per-batch metrics, then host
         aggregation — per-batch device adds or per-scalar fetches would cost
         a dispatch/RTT each.  Entries are per-step scalar dicts OR
@@ -1892,9 +2073,9 @@ class Worker:
         # The fetch is where the in-flight device steps drain: its wall is
         # the task's device-execution tail plus the transfer ("step_wait"),
         # distinct from the microseconds of host math after it ("metrics").
-        with self.phases.phase("step_wait"):
+        with self.phases.phase("step_wait", task=task_id):
             host = jax.device_get(metrics_list)
-        with self.phases.phase("metrics"):
+        with self.phases.phase("metrics", task=task_id):
             sums: Dict[str, Any] = {}
             n = 0
             for metrics in host:
@@ -1912,9 +2093,9 @@ class Worker:
             )
 
     def _run_training_task(self, task: Task) -> Dict[str, float]:
-        """Synchronous task execution (profiled tasks, group/lockstep mode)."""
+        """Synchronous task execution (``--task_pipelining`` off)."""
         metrics_list, _ = self._dispatch_training_task(task)
-        return self._finalize_training_metrics(metrics_list)
+        return self._finalize_training_metrics(metrics_list, task.task_id)
 
     #: Collective-formation failures worth retrying in place: a gang member
     #: still COMPILING while its peer already executes trips the runtime's
@@ -2022,6 +2203,9 @@ class Worker:
         computable downstream, not just cumulative sums."""
         report["phase_times"] = self.phases.snapshot()
         report["phase_counts"] = self.phases.counts()
+        # Before the gauge envelope: its collector republishes this
+        # snapshot.
+        report["counters"] = self._counter_snapshot()
         report["seq"] = self._next_report_seq()
         # Gauge envelope on every task report (forced past the ship
         # throttle: reports are bounded frequency by construction) — the
@@ -2029,7 +2213,9 @@ class Worker:
         gp = self.gauge_payload(force=True)
         if gp is not None:
             report["gauge"] = gp
-        with self.phases.phase("metrics"):
+        # The report RPC's own span (rpc:ReportTaskResult) nests inside
+        # this one on this thread, so the task id covers it.
+        with self.phases.phase("metrics", task=report["task_id"]):
             self.master.call("ReportTaskResult", report)
 
     # hot-path: settles the PREVIOUS task while this one's steps run
@@ -2048,7 +2234,9 @@ class Worker:
             return
         report, metrics_list = pending
         try:
-            report["metrics"] = self._finalize_training_metrics(metrics_list)
+            report["metrics"] = self._finalize_training_metrics(
+                metrics_list, report["task_id"]
+            )
         except Exception:
             logger.exception(
                 "task %d failed at metrics fetch", report["task_id"]
@@ -2075,6 +2263,7 @@ class Worker:
                     )
             else:
                 self._report_result(report)
+        self._profile_close_if_due(report["task_id"])
         if report["success"]:
             # graftlint: allow[shared-state] the _parked spin-wait handshake serializes the preemption thread's _flush_pending against the loop (see preemption_snapshot)
             self._tasks_done += 1
@@ -2083,7 +2272,7 @@ class Worker:
 
     # ---- prep-ahead pipeline (fused + pipelined mode) ----
 
-    def _pipelining_enabled(self, profiling: bool = False) -> bool:
+    def _pipelining_enabled(self) -> bool:
         """Task-level pipelining: defer the previous task's metrics fetch +
         report behind this task's dispatched steps.
 
@@ -2093,9 +2282,8 @@ class Worker:
         programs still execute in identical task order on every rank.
         Reports stay rank-0-gated inside ``_flush``, and a pipelined-task
         failure resyncs the gang (``_group_resync``) exactly as a
-        synchronous one does.  A profiled task is still traced in
-        isolation."""
-        return not profiling and self.config.task_pipelining
+        synchronous one does."""
+        return self.config.task_pipelining
 
     def _prep_ahead_eligible(self) -> bool:
         """Prep-ahead runs the NEXT task's host work (read+decode+stack) on
@@ -2108,13 +2296,11 @@ class Worker:
         only at its own lockstep boundary — prep is submitted at task
         acquisition (GetGroupTask), so the gang's collective order is
         untouched.  Only the fused pre-shard path (host-tier tables need
-        the host batch on the main thread), and never in a profiling
-        session (a profiled task must be traced in isolation)."""
+        the host batch on the main thread)."""
         return (
             self.config.task_pipelining
             and self.config.fused_task_scan
             and not self.spec.host_io
-            and not self.config.profile_dir
         )
 
     # hot-path: submission only — the prep itself runs on the pool thread
@@ -2134,7 +2320,8 @@ class Worker:
                 else 1
             )
             self._prep_pool = ThreadPoolExecutor(
-                max_workers=width, thread_name_prefix="edl-prep"
+                max_workers=width, thread_name_prefix="edl-prep",
+                initializer=trace.name_os_thread,
             )
         return self._prep_pool.submit(self._prep_fused_host, task)
 
@@ -2158,8 +2345,9 @@ class Worker:
         ``_group_resync`` — the restart requeues everything this rank held,
         including the freshly prepped task, through the membership bump."""
         task, report, fut = prepped
+        self._profile_open_if_due()
         try:
-            with self.phases.phase("prep_wait"):
+            with self.phases.phase("prep_wait", task=task.task_id):
                 prep = fut.result()
             metrics_list, n_steps = self._retry_transient_collective(
                 lambda: self._dispatch_training_task(task, prep=prep),
@@ -2447,9 +2635,19 @@ class Worker:
 
     # ---- main loop ----
 
+    def run(self, membership: Optional[dict] = None) -> Dict[str, Any]:
+        """The task loop (``_run``) until the job ends, the world changes
+        or something fails."""
+        try:
+            return self._run(membership)
+        finally:
+            # Whatever ends the loop (job end, a restart for a re-form, a
+            # failure): an open profile window is closed and written.
+            self._profile_settle()
+
     # hot-path: the task loop itself — every deliberate blocking point is
     # either phase-accounted or individually waived with its reason
-    def run(self, membership: Optional[dict] = None) -> Dict[str, Any]:
+    def _run(self, membership: Optional[dict]) -> Dict[str, Any]:
         """Main loop.  ``membership`` is the view returned by an EARLIER
         RegisterWorker call (worker.main registers once, derives the
         jax.distributed spec from that view, and passes it here) — a second
@@ -2607,7 +2805,6 @@ class Worker:
             }
             try:
                 if task.type == TASK_TRAINING:
-                    profiling = self._maybe_start_profile()
                     # Task-level pipelining: dispatch this task's steps,
                     # then settle the PREVIOUS task's metrics fetch +
                     # report while these steps run — the fetch is the one
@@ -2615,75 +2812,68 @@ class Worker:
                     # the device queue full across task boundaries.  Group
                     # mode pipelines too since r6 (_pipelining_enabled):
                     # dispatch order is the lockstep seq order on every
-                    # rank, so no collective is reordered; only a profiled
-                    # task keeps the synchronous shape (traced in
-                    # isolation).
-                    pipelined = self._pipelining_enabled(profiling)
-                    try:
-                        if pipelined and self._prep_ahead_eligible():
-                            # Prep-ahead: submit THIS task's host work to
-                            # the prep pool, then dispatch + settle the
-                            # OLDEST prepped task once the queue exceeds
-                            # its depth.  At depth k the wire transfer of
-                            # task N streams while tasks N+1..N+k decode
-                            # and task N-1's metrics settle — k+2 tasks in
-                            # flight, link busy end to end.  In group mode
-                            # the submission rides the gang
-                            # task-acquisition path (this task was just
-                            # pulled at its seq), so every prepped
-                            # dispatch below stays inside the lockstep
-                            # boundary of the task it belongs to.
-                            fut = self._submit_prep(task)
-                            self._prep_queue.append((task, report, fut))
-                            while (
-                                len(self._prep_queue)
-                                > max(1, self.config.prep_depth)
-                            ):
-                                self._dispatch_prepped(
-                                    self._prep_queue.popleft()
-                                )
-                            continue
-                        if pipelined:
-                            metrics_list, n_steps = (
-                                self._retry_transient_collective(
-                                    lambda: self._dispatch_training_task(
-                                        task
-                                    ),
-                                    task.task_id,
-                                )
+                    # rank, so no collective is reordered.
+                    pipelined = self._pipelining_enabled()
+                    if pipelined and self._prep_ahead_eligible():
+                        # Prep-ahead: submit THIS task's host work to
+                        # the prep pool, then dispatch + settle the
+                        # OLDEST prepped task once the queue exceeds
+                        # its depth.  At depth k the wire transfer of
+                        # task N streams while tasks N+1..N+k decode
+                        # and task N-1's metrics settle — k+2 tasks in
+                        # flight, link busy end to end.  In group mode
+                        # the submission rides the gang
+                        # task-acquisition path (this task was just
+                        # pulled at its seq), so every prepped
+                        # dispatch below stays inside the lockstep
+                        # boundary of the task it belongs to.
+                        fut = self._submit_prep(task)
+                        self._prep_queue.append((task, report, fut))
+                        while (
+                            len(self._prep_queue)
+                            > max(1, self.config.prep_depth)
+                        ):
+                            self._dispatch_prepped(
+                                self._prep_queue.popleft()
                             )
-                            self._steps_dispatched += n_steps
-                            report["model_version"] = self._steps_dispatched
-                            self._training_tasks_done += 1
-                            prev, self._pending = (
-                                self._pending, (report, metrics_list),
+                        continue
+                    self._profile_open_if_due()
+                    if pipelined:
+                        metrics_list, n_steps = (
+                            self._retry_transient_collective(
+                                lambda: self._dispatch_training_task(
+                                    task
+                                ),
+                                task.task_id,
                             )
-                            try:
-                                self._flush(prev)
-                            except WorkerRestartRequired:
-                                raise  # group resync: process restarts
-                            except Exception:
-                                # Same containment as _dispatch_prepped: a
-                                # report-RPC failure here must not fail THIS
-                                # task's report (its steps are already in
-                                # self.state; a master requeue would train
-                                # its records twice).  The lost report is
-                                # the master task timeout's to requeue.
-                                logger.exception(
-                                    "report of previous pipelined task "
-                                    "lost (master task timeout requeues)",
-                                )
-                            continue
-                        metrics = (
-                            self._run_group_training_task(task)
-                            if self._group_mode
-                            else self._run_training_task(task)
                         )
-                    finally:
-                        if profiling:
-                            # graftlint: allow[hot-path-sync] a profiled task is traced in isolation; the trace must capture the drain
-                            jax.block_until_ready(self.state)
-                            jax.profiler.stop_trace()
+                        self._steps_dispatched += n_steps
+                        report["model_version"] = self._steps_dispatched
+                        self._training_tasks_done += 1
+                        prev, self._pending = (
+                            self._pending, (report, metrics_list),
+                        )
+                        try:
+                            self._flush(prev)
+                        except WorkerRestartRequired:
+                            raise  # group resync: process restarts
+                        except Exception:
+                            # Same containment as _dispatch_prepped: a
+                            # report-RPC failure here must not fail THIS
+                            # task's report (its steps are already in
+                            # self.state; a master requeue would train
+                            # its records twice).  The lost report is
+                            # the master task timeout's to requeue.
+                            logger.exception(
+                                "report of previous pipelined task "
+                                "lost (master task timeout requeues)",
+                            )
+                        continue
+                    metrics = (
+                        self._run_group_training_task(task)
+                        if self._group_mode
+                        else self._run_training_task(task)
+                    )
                     self._training_tasks_done += 1
                     report["metrics"] = metrics
                     # graftlint: allow[hot-path-sync] synchronous (non-pipelined) mode settles every task by design
@@ -2720,6 +2910,7 @@ class Worker:
                 # In lockstep mode every process ran the task's collectives,
                 # but exactly one report must hit the master's queues.
                 self._report_result(report)
+            self._profile_close_if_due(task.task_id)
             if report["success"]:
                 self._tasks_done += 1
                 self._g_tasks.inc()

@@ -1334,7 +1334,7 @@ def test_cli_callgraph_dump():
     assert out.returncode == 0, out.stderr
     doc = json.loads(out.stdout)
     assert doc["functions"] > 100
-    assert any("Worker.run" in q for q in doc["hot_path_functions"])
+    assert any("Worker._run" in q for q in doc["hot_path_functions"])
     assert "elasticdl_tpu.worker.worker:Worker._ckpt_lock" in doc["locks"]
     assert doc["locks"]["elasticdl_tpu.worker.worker:Worker._ckpt_lock"]["leaf"]
 

@@ -39,6 +39,25 @@ def test_metrics_writer_tensorboard(tmp_path):
     assert events, "expected a tensorboard event file"
 
 
+def test_a_group_can_stay_out_of_the_tensorboard_mirror(tmp_path):
+    class _Tb:
+        seen = []
+
+        def add_scalar(self, tag, value, step):
+            self.seen.append(tag)
+
+        def close(self):
+            pass
+
+    writer = MetricsWriter(str(tmp_path), tensorboard=False)
+    writer._tb = _Tb()
+    writer.write("phase", 1, {"dispatch": 2.0})
+    writer.write("counter", 1, {"compiles": 3.0}, tensorboard=False)
+    writer.close()
+    assert _Tb.seen == ["phase/dispatch"]
+    assert [r["kind"] for r in read_metrics(str(tmp_path))] == ["phase", "counter"]
+
+
 def test_read_metrics_missing_dir(tmp_path):
     assert read_metrics(str(tmp_path / "nope")) == []
 
@@ -80,10 +99,10 @@ def test_read_metrics_tolerates_torn_final_line(tmp_path):
         read_metrics(str(tmp_path))
 
 
-def _job(tmp_path, **cfg):
+def _job(tmp_path, records=64, **cfg):
     train = str(tmp_path / "train.rio")
     val = str(tmp_path / "val.rio")
-    generate("mnist", train, 64)
+    generate("mnist", train, records)
     generate("mnist", val, 32)
     config = JobConfig(
         model_def="mnist.model_spec",
@@ -163,13 +182,120 @@ def test_phase_counts_ride_reports_into_job_status(tmp_path, devices):
         assert seconds >= 0
 
 
-def test_worker_profiler_trace(tmp_path, devices):
-    prof = str(tmp_path / "prof")
-    config, dispatcher, evaluation, reader, spec = _job(
-        tmp_path, profile_dir=prof
-    )
-    servicer = MasterServicer(dispatcher)
-    worker = Worker(config, DirectMasterProxy(servicer), reader, spec=spec)
+def _train_job(tmp_path, name, devices=None, **cfg):
+    """Six training tasks of two steps, metrics to ``<tmp>/<name>``."""
+    work = tmp_path / name
+    work.mkdir()
+    config, dispatcher, _, reader, spec = _job(work, records=192, **cfg)
+    writer = MetricsWriter(str(work / "metrics"), tensorboard=False)
+    servicer = MasterServicer(dispatcher, metrics_writer=writer)
+    worker = Worker(config, DirectMasterProxy(servicer), reader, spec=spec, devices=devices)
     worker.run()
+    writer.close()
+    return worker, servicer, read_metrics(str(work / "metrics"))
+
+
+def _host_events(xplane_path, name):
+    """(line name, event stats) of the ``/host:CPU`` events called ``name``."""
+    from jax.profiler import ProfileData
+
+    out = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out += [(line.name, dict(e.stats)) for e in line.events if e.name == name]
+    return out
+
+
+def test_worker_profiler_trace(tmp_path, devices):
+    """``--profile_dir`` traces PROFILE_TASKS tasks from the second one on,
+    with the host's spans in the same file, and leaves the loop alone:
+    prep-ahead stays on and the job trains to the same losses."""
+    from elasticdl_tpu.common import trace
+    from elasticdl_tpu.worker.worker import PROFILE_TASKS
+
+    prof = str(tmp_path / "prof")
+    worker, _, records = _train_job(tmp_path, "traced", profile_dir=prof)
     traces = glob.glob(os.path.join(prof, "**", "*.xplane.pb"), recursive=True)
-    assert traces, "expected an xplane trace from the profiled task"
+    assert len(traces) == 1, "expected one xplane trace from the profile window"
+    assert worker._prep_ahead_eligible() and worker._pipelining_enabled()
+    assert worker._profile_state == "closed" and trace.default().bridge is None
+
+    dispatches = _host_events(traces[0], "dispatch")
+    # one task-loop line; the first task (seq 0, the compile) is outside
+    assert len({line for line, _ in dispatches}) == 1
+    seqs = [stats["seq"] for _, stats in dispatches]
+    assert seqs[:PROFILE_TASKS] == list(range(1, PROFILE_TASKS + 1))
+    assert len({stats["task"] for _, stats in dispatches}) == len(dispatches)
+    # two steps a task: the model version before the n-th dispatch
+    assert [stats["step0"] for _, stats in dispatches] == [2 * n for n in seqs]
+    traced_tasks = {stats["task"] for _, stats in dispatches[:PROFILE_TASKS]}
+    for name in ("prep_wait", "step_wait", "metrics"):
+        tasks = {stats.get("task") for _, stats in _host_events(traces[0], name)}
+        assert traced_tasks <= tasks, name
+    # prep runs on a pool thread, named for the profiler
+    prep_lines = {line for line, _ in _host_events(traces[0], "prep")}
+    assert prep_lines and all(line.startswith("edl-prep") for line in prep_lines)
+
+    _, _, plain = _train_job(tmp_path, "plain")
+    losses = lambda recs: [(r["step"], r["loss"]) for r in recs if r["kind"] == "train"]  # noqa: E731
+    assert len(losses(records)) == 6
+    assert losses(records) == pytest.approx(losses(plain), rel=1e-6)
+
+
+def test_a_job_shorter_than_the_profile_window_still_writes_its_trace(tmp_path, devices):
+    prof = str(tmp_path / "prof")
+    config, dispatcher, _, reader, spec = _job(tmp_path, profile_dir=prof)
+    worker = Worker(config, DirectMasterProxy(MasterServicer(dispatcher)), reader, spec=spec)
+    worker.run()
+    assert glob.glob(os.path.join(prof, "**", "*.xplane.pb"), recursive=True)
+    assert worker._profile_state == "closed" and worker._profile_closer is None
+
+
+def test_profiler_that_cannot_start_is_logged_not_fatal(tmp_path, devices, monkeypatch):
+    import jax
+
+    def boom(*a, **k):
+        raise RuntimeError("no profiler here")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", boom)
+    worker, _, records = _train_job(tmp_path, "job", profile_dir=str(tmp_path / "prof"))
+    assert sum(r["kind"] == "train" for r in records) == 6
+    assert worker._profile_state == "closed"
+
+
+def test_counters_ride_every_training_report(tmp_path, devices):
+    """One ``counter`` record per successful training report, cumulative
+    (so non-decreasing), beside the ``phase`` record; JobStatus serves the
+    newest per worker; the same five are gauges of the worker's registry."""
+    from elasticdl_tpu.worker.worker import COUNTER_GAUGES
+
+    worker, servicer, records = _train_job(tmp_path, "job")
+    counters = [r for r in records if r["kind"] == "counter"]
+    train = [r for r in records if r["kind"] == "train"]
+    assert len(counters) == len(train) == 6
+    assert [r["step"] for r in counters] == [r["step"] for r in train]
+    for key in COUNTER_GAUGES:
+        values = [r[key] for r in counters]
+        assert values == sorted(values), key
+    # a report goes out after the NEXT task's dispatch (pipelining)
+    assert [r["dispatches"] for r in counters] == [2, 3, 4, 5, 6, 6]
+    assert counters[-1]["compiles"] >= 1 and counters[-1]["compile_s"] > 0
+    assert 0 <= counters[-1]["dispatches_device_idle"] <= 5
+    assert counters[-1]["hbm_peak_bytes"] == 0  # XLA:CPU reports no memory stats
+    newest = servicer.JobStatus({})["counters"][worker.worker_id]
+    assert newest == {k: counters[-1][k] for k in COUNTER_GAUGES}
+    families = worker.gauges.snapshot()
+    for key, (family, _) in COUNTER_GAUGES.items():
+        assert family in families, family
+
+
+def test_starved_dispatches_are_the_ones_that_found_the_device_idle(tmp_path, devices):
+    """Synchronous mode settles every task before the next dispatch: every
+    dispatch but the first finds the previous output ready.  On ONE device:
+    the settle fetches one replica, and on a loaded box the other seven
+    virtual devices may really still be running."""
+    _, _, records = _train_job(tmp_path, "sync", devices=devices[:1], task_pipelining=False)
+    last = [r for r in records if r["kind"] == "counter"][-1]
+    assert last["dispatches"] == 6 and last["dispatches_device_idle"] == 5

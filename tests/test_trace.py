@@ -159,6 +159,106 @@ def test_nested_span_self_time_agrees_with_phase_timers(recorder):
     assert self_us["control"] / 1e6 < 0.045
 
 
+# ------------------------------------------------------------- the bridge
+
+
+class _FakeAnnotation:
+    """What a bridge factory builds: a context manager that remembers."""
+
+    log = []
+
+    def __init__(self, name, attrs):
+        self.name, self.attrs = name, dict(attrs)
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name, self.attrs))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture()
+def bridge():
+    _FakeAnnotation.log = []
+    trace.set_bridge(_FakeAnnotation)
+    yield _FakeAnnotation.log
+    trace.set_bridge(None)
+
+
+def test_span_without_ring_or_bridge_is_the_shared_noop():
+    assert not trace.enabled()
+    a, b = trace.span("x", task=1), trace.span("y")
+    assert a is b and a.span_id == 0
+    with a:
+        pass
+
+
+def test_bridge_gets_name_and_attrs_with_the_ring_off(bridge):
+    assert not trace.enabled()
+    timers = PhaseTimers()
+    with timers.phase("dispatch", task=7, seq=3, step0=16):
+        with trace.span("rpc:Foo", cat="rpc.client", method="Foo") as sp:
+            # no ring, no id: an RPC client propagates no parent for it
+            assert sp.span_id == 0
+    assert bridge == [
+        ("enter", "dispatch", {"task": 7, "seq": 3, "step0": 16}),
+        ("enter", "rpc:Foo", {"method": "Foo"}),
+        ("exit", "rpc:Foo"),
+        ("exit", "dispatch"),
+    ]
+    assert trace.default().export() == []
+    assert timers.counts() == {"dispatch": 1}
+    # cleared: the shared no-op again, and nothing more reaches the bridge
+    trace.set_bridge(None)
+    assert trace.span("after") is trace.span("again")
+    assert len(bridge) == 4
+
+
+@pytest.mark.parametrize("bridged", [False, True])
+def test_ring_self_time_arithmetic_is_the_same_with_a_bridge(recorder, bridged):
+    """The ring's per-span self time (checked against PhaseTimers' own
+    stack) does not change when every span is also entered through a
+    bridge, and the bridge sees every span the ring records."""
+    log = []
+    if bridged:
+        _FakeAnnotation.log = log
+        trace.set_bridge(_FakeAnnotation)
+    try:
+        timers = PhaseTimers()
+        with timers.phase("control"):
+            time.sleep(0.02)
+            with timers.phase("lease_wait", task=5):
+                time.sleep(0.03)
+    finally:
+        trace.set_bridge(None)
+    snap = timers.snapshot()
+    spans = {e["name"]: e for e in recorder.export() if e["ph"] == "X"}
+    assert set(spans) == {"control", "lease_wait"}
+    for name, ev in spans.items():
+        assert ev["args"]["self_us"] / 1e6 == pytest.approx(snap[name], abs=5e-3)
+    assert spans["lease_wait"]["args"]["task"] == 5
+    assert spans["lease_wait"]["args"]["parent"] == spans["control"]["args"]["span_id"]
+    assert [x[:2] for x in log] == (
+        [("enter", "control"), ("enter", "lease_wait"),
+         ("exit", "lease_wait"), ("exit", "control")] if bridged else []
+    )
+
+
+def test_pool_threads_give_their_python_name_to_the_os():
+    from concurrent.futures import ThreadPoolExecutor
+
+    def comm():
+        with open(f"/proc/self/task/{threading.get_native_id()}/comm") as f:
+            return f.read().strip()
+
+    with ThreadPoolExecutor(
+        1, thread_name_prefix="edl-prep", initializer=trace.name_os_thread
+    ) as pool:
+        assert pool.submit(comm).result() == "edl-prep_0"
+
+
 # ------------------------------------------------- gRPC round-trip context
 
 

@@ -16,8 +16,8 @@ one physical chip.
 
 Steps settle via the loss fetch, so ``tokens_per_s`` is host wall-clock
 around whole steps.  Each length row ALSO records trace-derived device
-self-time (``device_step_ms`` / ``device_tokens_per_s``, same xplane
-instrument as tools/profile_step.py).  Not measured on current code.
+self-time (``device_step_ms`` / ``device_tokens_per_s``, from the xplane
+trace, as benchmark/xplane.py reduces one).  Not measured on current code.
 
 Usage: python tools/longcontext_bench.py [--lengths 2048,4096,8192]
 One JSON line per length; artifact: artifacts/longcontext_r05.json.
