@@ -506,11 +506,19 @@ def _parse_kv_string(spec: str) -> Dict[str, Any]:
     for item in filter(None, (s.strip() for s in spec.split(";"))):
         if "=" not in item:
             raise ValueError(f"malformed param {item!r}, expected key=value")
-        key, value = item.split("=", 1)
+        key, value = (part.strip() for part in item.split("=", 1))
         try:
-            out[key.strip()] = json.loads(value)
+            out[key] = json.loads(value)
         except json.JSONDecodeError:
-            out[key.strip()] = value.strip()
+            if value in ("True", "False", "None"):
+                # A Python literal is no JSON: kept as a string it would be
+                # truthy whatever it spells ("host_tier=False" turned the
+                # host tier ON).
+                spelled = "null" if value == "None" else value.lower()
+                raise ValueError(
+                    f"param {key}={value}: values are JSON, spell it {spelled}"
+                ) from None
+            out[key] = value
     return out
 
 

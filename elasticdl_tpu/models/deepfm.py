@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from elasticdl_tpu.common.jax_compat import jit_compiled
 from elasticdl_tpu.data.codecs import criteo_feed, criteo_feed_pre
 from elasticdl_tpu.models.spec import EmbeddingTableSpec, HostTableIO, ModelSpec
 from elasticdl_tpu.models.tabular import (
@@ -37,7 +38,7 @@ from elasticdl_tpu.models.tabular import (
 from elasticdl_tpu.ops.embedding import (
     ParallelContext,
     embedding_lookup,
-    pack_table,
+    normal_packed_table,
 )
 
 NUM_DENSE = 13
@@ -78,14 +79,9 @@ def _init_params(
     if not host_tier:
         # Host-tier mode keeps NO device table: rows live in the native C++
         # store (lazy, per-id) and arrive through the batch.
-        fm_logical = jnp.concatenate(
-            [
-                jax.random.normal(ks[0], (vocab, embedding_dim)) * 0.01,
-                jnp.zeros((vocab, 1), jnp.float32),
-            ],
-            axis=-1,
+        params["fm_table"] = normal_packed_table(
+            ks[0], vocab, embedding_dim + 1, live_dim=embedding_dim
         )
-        params["fm_table"] = pack_table(fm_logical, embedding_dim + 1)
     in_dim = NUM_CAT * embedding_dim + NUM_DENSE
     for i, width in enumerate(hidden):
         params["mlp"][f"layer{i}"] = {
@@ -236,12 +232,19 @@ def model_spec(
         )
     return ModelSpec(
         name="deepfm",
-        init=functools.partial(
-            _init_params,
-            buckets_per_feature=buckets_per_feature,
-            embedding_dim=dim,
-            hidden=hidden,
-            host_tier=host_tier,
+        # Jitted, so the table is one fused pass from counters to packed
+        # rows wherever init is called (alone, or inlined in the trainer's
+        # sharded init), with the same bits either way.
+        init=jit_compiled(
+            functools.partial(
+                _init_params,
+                buckets_per_feature=buckets_per_feature,
+                embedding_dim=dim,
+                hidden=hidden,
+                host_tier=host_tier,
+            ),
+            name="deepfm.init",
+            expected_variants=2,  # typed and raw uint32 keys
         ),
         apply=functools.partial(
             _apply,

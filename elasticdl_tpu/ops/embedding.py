@@ -86,7 +86,9 @@ reference's per-PS-pod Go optimizer state).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import threading
 from functools import partial
 from typing import Optional, Tuple
 
@@ -94,6 +96,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.extend.random import threefry2x32_p
 
 from elasticdl_tpu.common.jax_compat import axis_size
 
@@ -218,6 +221,68 @@ def init_table(rng: jax.Array, vocab_size: int, dim: int, scale: float = 0.01):
     return jax.random.normal(rng, table_shape(vocab_size, dim)) * scale
 
 
+def _normal_at(rng: jax.Array, hi: jax.Array, lo: jax.Array) -> jax.Array:
+    """The float32 values ``jax.random.normal(rng, shape)`` holds at the
+    row-major positions ``hi * 2**32 + lo`` of ``shape``.  Under
+    ``jax_threefry_partitionable`` every element's bits are threefry of
+    its own 64-bit position, so any subset, in any layout, on any number
+    of devices, can be drawn without the array that holds them all; the
+    bits-to-normal recipe is jax.random's own (mantissa bits to [1, 2),
+    shifted to (-1, 1), ``sqrt(2) * erf_inv``)."""
+    k1, k2 = jax.random.key_data(rng)
+    b1, b2 = threefry2x32_p.bind(k1, k2, hi, lo)
+    one = np.float32(1).view(np.uint32)
+    floats = lax.bitcast_convert_type(
+        ((b1 ^ b2) >> np.uint32(9)) | one, jnp.float32
+    ) - np.float32(1)
+    low = np.nextafter(np.float32(-1), np.float32(0))
+    u = jnp.maximum(low, floats * (np.float32(1) - low) + low)
+    return np.float32(np.sqrt(2)) * lax.erf_inv(u)
+
+
+def _flat_position(row: jax.Array, col: jax.Array, width: int):
+    """``row * width + col`` (uint32 arrays, ``width`` < 2**16) as the two
+    uint32 halves ``(hi, lo)`` of the 64-bit product: with ``row = a16 *
+    2**16 + b16`` it is ``(a16 * width) * 2**16 + (b16 * width + col)``,
+    each factor below 2**32."""
+    a = (row >> np.uint32(16)) * np.uint32(width)
+    b = (row & np.uint32(0xFFFF)) * np.uint32(width) + col
+    lo = (a << np.uint32(16)) + b
+    hi = (a >> np.uint32(16)) + (lo < b).astype(jnp.uint32)
+    return hi, lo
+
+
+def normal_packed_table(
+    rng: jax.Array, vocab_size: int, dim: int,
+    live_dim: Optional[int] = None, scale: float = 0.01,
+) -> jax.Array:
+    """A lane-packed [P, pack*stride] table born packed: logical row ``r``
+    holds ``jax.random.normal(rng, (vocab_size, live_dim))[r] * scale`` in
+    its first ``live_dim`` lanes (default ``dim``); the other lanes of the
+    stride and the padding rows are zero.  Every element is drawn from its
+    own counter (:func:`_normal_at`), so there is no [vocab, stride] array
+    with a padded minor dimension on the way (on a TPU that one is 8 x the
+    table), and under ``jit(..., out_shardings=rows over the mesh)`` each
+    device computes its own rows only; the values do not depend on the
+    number of devices.  (Run op by op the bits are exactly those of the
+    expression above; inside a jit XLA fuses the two constant factors and
+    some values land one ulp away.)"""
+    live_dim = live_dim or dim
+    if not 0 < live_dim <= dim < 1 << 16:
+        raise ValueError(f"need 0 < live_dim <= dim < 65536, got {live_dim}, {dim}")
+    if pad_vocab(vocab_size, dim) >= 1 << 32:
+        raise ValueError(f"vocab {vocab_size} does not fit a 32-bit row counter")
+    rows, width = table_shape(vocab_size, dim)
+    stride = row_stride(dim)
+    p = lax.broadcasted_iota(jnp.uint32, (rows, width), 0)
+    lane = lax.broadcasted_iota(jnp.uint32, (rows, width), 1)
+    row = p * np.uint32(width // stride) + lane // np.uint32(stride)
+    col = lane % np.uint32(stride)
+    hi, lo = _flat_position(row, col, live_dim)
+    live = (col < live_dim) & (row < vocab_size)
+    return jnp.where(live, _normal_at(rng, hi, lo) * np.float32(scale), 0.0)
+
+
 def pack_table(table: jax.Array, dim: int) -> jax.Array:
     """Convert a plain [V, dim] (or flat [V*dim]) table into the padded
     lane-packed [P, pack*stride] layout.  Rows past V and lanes past dim
@@ -333,9 +398,34 @@ def embedding_lookup(
         axis_size(ctx.axis_name) == 1 and impl == IMPL_RAGGED_EMULATED
     ):
         return _dense_lookup(table, ids, ctx.axis_name, dim)
-    return _ragged_lookup(
+    out, rows_received = _ragged_lookup(
         table, ids, ctx.axis_name, dim, impl == IMPL_RAGGED_EMULATED
     )
+    if _ROUTE_TAPS.rows is not None:
+        _ROUTE_TAPS.rows.append(rows_received)
+    return out
+
+
+class _RouteTaps(threading.local):
+    rows: Optional[list] = None
+
+
+_ROUTE_TAPS = _RouteTaps()
+
+
+@contextlib.contextmanager
+def route_taps():
+    """Trace-time tap on the ragged route: while open (on this thread),
+    every ragged lookup traced appends the number of rows THIS shard
+    received (an int32 scalar of the enclosing trace) to the yielded list —
+    how the train step gets the route's load balance into its metrics
+    without the model's apply returning it."""
+    rows: list = []
+    prev, _ROUTE_TAPS.rows = _ROUTE_TAPS.rows, rows
+    try:
+        yield rows
+    finally:
+        _ROUTE_TAPS.rows = prev
 
 
 def resolve_impl(
@@ -483,10 +573,15 @@ def _exclusive_cumsum(x: jax.Array) -> jax.Array:
 
 @partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def _ragged_lookup(local_table, ids, axis_name: str, dim: int, emulate: bool):
+    """(vectors ``ids.shape + (dim,)``, rows this shard received: int32)."""
     out, _ = _ragged_lookup_fwd(local_table, ids, axis_name, dim, emulate)
     return out
 
 
+# The route's parts carry ``jax.named_scope``s (forward ``route_plan``,
+# ``route_ids``, ``route_gather``, ``route_vectors``, ``route_unsort``;
+# backward ``route_bwd_sort``, ``route_bwd_vectors``, ``route_bwd_scatter``)
+# so a device trace can put each op's time down to its part.
 def _ragged_lookup_fwd(local_table, ids, axis_name: str, dim: int, emulate: bool):
     n = axis_size(axis_name)
     rows_local = logical_rows(local_table, dim)
@@ -494,40 +589,46 @@ def _ragged_lookup_fwd(local_table, ids, axis_name: str, dim: int, emulate: bool
     flat_ids = ids.reshape(-1)
     L = flat_ids.shape[0]
 
-    (perm, sorted_ids, send, in_off, out_off, recv, S) = _routing_plan(
-        flat_ids, axis_name, rows_local
-    )
+    with jax.named_scope("route_plan"):
+        (perm, sorted_ids, send, in_off, out_off, recv, S) = _routing_plan(
+            flat_ids, axis_name, rows_local
+        )
     # ids -> owners.  Buffer statically sized n*L (worst-case skew: every
     # shard's batch hits my rows); -1 padding = OOB = NaN row if ever read.
-    id_buf = jnp.full((n * L,), -1, dtype=flat_ids.dtype)
-    recv_ids = _ragged_collective(
-        sorted_ids, id_buf, in_off, send, out_off, recv, axis_name, emulate
-    )
-    local_rows = recv_ids - lax.axis_index(axis_name) * rows_local
-    vecs = gather_rows(local_table, local_rows, dim)   # [n*L, dim], NaN on OOB
+    with jax.named_scope("route_ids"):
+        id_buf = jnp.full((n * L,), -1, dtype=flat_ids.dtype)
+        recv_ids = _ragged_collective(
+            sorted_ids, id_buf, in_off, send, out_off, recv, axis_name, emulate
+        )
+    with jax.named_scope("route_gather"):
+        local_rows = recv_ids - lax.axis_index(axis_name) * rows_local
+        vecs = gather_rows(local_table, local_rows, dim)   # [n*L, dim], NaN on OOB
 
     # vectors -> requesters: exactly the reverse plan.  My block offsets are
     # recv's exclusive cumsum (received chunks are sender-ordered); my chunk
     # lands back where requester j's sorted block for me starts — j's in_off
     # for me, which is S[j, :me].sum() row-wise.
-    me = lax.axis_index(axis_name)
-    back_in_off = _exclusive_cumsum(recv)
-    before = (jnp.arange(n) < me)[None, :]
-    back_out_off = jnp.sum(jnp.where(before, S, 0), axis=1).astype(jnp.int32)
-    vec_buf = jnp.zeros((L, dim), vecs.dtype)
-    sorted_out = _ragged_collective(
-        vecs, vec_buf, back_in_off, recv, back_out_off, send, axis_name, emulate
-    )
-    inv = jnp.zeros_like(perm).at[perm].set(jnp.arange(L))
-    out = sorted_out[inv].reshape(ids_shape + (dim,))
+    with jax.named_scope("route_vectors"):
+        me = lax.axis_index(axis_name)
+        back_in_off = _exclusive_cumsum(recv)
+        before = (jnp.arange(n) < me)[None, :]
+        back_out_off = jnp.sum(jnp.where(before, S, 0), axis=1).astype(jnp.int32)
+        vec_buf = jnp.zeros((L, dim), vecs.dtype)
+        sorted_out = _ragged_collective(
+            vecs, vec_buf, back_in_off, recv, back_out_off, send, axis_name, emulate
+        )
+    with jax.named_scope("route_unsort"):
+        inv = jnp.zeros_like(perm).at[perm].set(jnp.arange(L))
+        out = sorted_out[inv].reshape(ids_shape + (dim,))
     residuals = (perm, send, in_off, out_off, recv, back_in_off, back_out_off,
                  local_rows, local_table.shape, ids_shape)
-    return out, residuals
+    return (out, jnp.sum(recv)), residuals
 
 
 def _ragged_lookup_bwd(axis_name: str, dim: int, emulate: bool, residuals, g):
     (perm, send, in_off, out_off, recv, back_in_off, back_out_off,
      local_rows, table_shape_, ids_shape) = residuals
+    g, _ = g  # the row count is an integer: no cotangent
     n = axis_size(axis_name)
     L = perm.shape[0]
     # Cotangents retrace the forward id route (requester -> owner): sort by
@@ -535,14 +636,17 @@ def _ragged_lookup_bwd(axis_name: str, dim: int, emulate: bool, residuals, g):
     # scatter-add into the local shard.  Stale buffer slots hold
     # local_rows=-1 (OOB), so the fill-mode transpose drops them — as it
     # drops junk-id cotangents.
-    g_sorted = g.reshape(L, dim)[perm]
-    g_buf = jnp.zeros((n * L, dim), g_sorted.dtype)
-    g_at_owner = _ragged_collective(
-        g_sorted, g_buf, in_off, send, out_off, recv, axis_name, emulate
-    )
-    zeros = jnp.zeros(table_shape_, g_at_owner.dtype)
-    _, pull = jax.vjp(lambda t: gather_rows(t, local_rows, dim), zeros)
-    (table_bar,) = pull(g_at_owner)
+    with jax.named_scope("route_bwd_sort"):
+        g_sorted = g.reshape(L, dim)[perm]
+    with jax.named_scope("route_bwd_vectors"):
+        g_buf = jnp.zeros((n * L, dim), g_sorted.dtype)
+        g_at_owner = _ragged_collective(
+            g_sorted, g_buf, in_off, send, out_off, recv, axis_name, emulate
+        )
+    with jax.named_scope("route_bwd_scatter"):
+        zeros = jnp.zeros(table_shape_, g_at_owner.dtype)
+        _, pull = jax.vjp(lambda t: gather_rows(t, local_rows, dim), zeros)
+        (table_bar,) = pull(g_at_owner)
     ids_bar = np.zeros(ids_shape, jax.dtypes.float0)
     return table_bar, ids_bar
 

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import inspect
 import os
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -38,6 +39,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from elasticdl_tpu.common import trace
 from elasticdl_tpu.common.config import DistributionStrategy, JobConfig
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.metrics import HIST_PREFIX
@@ -46,8 +48,10 @@ from elasticdl_tpu.parallel import collectives as coll
 logger = get_logger("trainer")
 from elasticdl_tpu.ops.embedding import (
     ParallelContext,
+    logical_rows,
     pack_table,
     resolve_impl,
+    route_taps,
     table_shape,
 )
 
@@ -221,7 +225,7 @@ def opt_shard_plan(
         if _path_keys(path) in table_paths or d is not None:
             entries.append(_OPT_KEEP)
             continue
-        shape = tuple(leaf.shape)
+        shape = tuple(np.shape(leaf))
         size = int(np.prod(shape)) if shape else 1
         padded = -(-size // n_shards) * n_shards
         entries.append(_OptShard(shape, size, padded))
@@ -261,6 +265,16 @@ def opt_state_partition_specs(
         shard_plan,
         transform_non_params=lambda _: P(),
     )
+
+
+def _pad_flat(x, entry: _OptShard):
+    """Canonical (param-shaped) -> flat zero-padded [padded], on device."""
+    v = jnp.reshape(x, (-1,))
+    if entry.padded != entry.size:
+        v = jnp.concatenate(
+            [v, jnp.zeros((entry.padded - entry.size,), v.dtype)]
+        )
+    return v
 
 
 def _tree_psum_except(tree: Any, skip_paths, axes, skip_axes, topo=None):
@@ -332,6 +346,9 @@ class Trainer:
         )
         self.ctx = self._make_ctx()
         self._state_specs = None
+        #: Wall seconds of the last ``init_state`` (the worker's
+        #: ``init_state_s`` counter).
+        self.init_state_s = 0.0
         # ZeRO-style optimizer-state shard plan (opt_shard_plan) — set by
         # shard_state once the mode resolves against this mesh; None =
         # replicated layout.
@@ -658,11 +675,72 @@ class Trainer:
     # ---- state management ----
 
     def init_state(self, rng: jax.Array) -> TrainState:
-        params = self.spec.init(rng)
-        params = pad_embedding_tables(params, self.spec.embedding_tables)
-        opt_state = self.spec.optimizer.init(params)
-        state = TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=opt_state)
-        return self.shard_state(state)
+        """Fresh params and optimizer state, born in this mesh's layout:
+        ONE jitted program whose outputs carry the shardings
+        ``shard_state`` would give them, so a device only ever computes
+        and holds its own shard (a row-sharded table larger than one
+        chip's memory initialises; nothing is built whole on device 0).
+        jax's counter-based threefry makes the values independent of the
+        number of devices."""
+        t0 = time.monotonic()
+        with trace.span("init_state"):
+            state = jax.block_until_ready(self._init_program(rng)(rng))
+        self.init_state_s = time.monotonic() - t0
+        self._log_table_layout(state)
+        return state
+
+    def _init_program(self, rng: Any) -> Callable:
+        """The jitted ``rng -> TrainState`` of :meth:`init_state`; adopts
+        this mesh's layout for the model's shapes on the way (``rng`` may
+        be a ShapeDtypeStruct: tests compile this for a described chip)."""
+
+        def init_params(rng):
+            return pad_embedding_tables(
+                self.spec.init(rng), self.spec.embedding_tables
+            )
+
+        shardings = self._adopt_layout(jax.eval_shape(init_params, rng))
+        plan = self._opt_plan
+
+        def init(rng):
+            params = init_params(rng)
+            opt_state = self.spec.optimizer.init(params)
+            if plan is not None:
+                opt_state = self._opt_map(
+                    lambda leaf, entry: _pad_flat(leaf, entry)
+                    if isinstance(entry, _OptShard) else leaf,
+                    opt_state, plan,
+                )
+            return TrainState(
+                step=jnp.zeros((), jnp.int32),
+                params=params, opt_state=opt_state,
+            )
+
+        return jit_compiled(
+            init, name="trainer.init_state", out_shardings=shardings
+        )
+
+    def _log_table_layout(self, state: TrainState) -> None:
+        """One line per mesh-sharded table: what a chip holds of it."""
+        if not self.sharded_embeddings:
+            return
+        shards = int(self.mesh.shape[self.axis_name])
+        for t in self.spec.embedding_tables:
+            leaf = state.params
+            for key in t.path:
+                leaf = leaf[key]
+            moments = sum(
+                1 for m in jax.tree.leaves(state.opt_state)
+                if m.shape == leaf.shape
+            )
+            gib = leaf.nbytes / shards / 2**30
+            logger.info(
+                "embedding table %s: %d logical rows of %d floats (padded "
+                "to %d), row-sharded %d ways: %.3f GiB of rows a chip, "
+                "%.3f GiB of optimizer moments a chip",
+                "/".join(t.path), t.vocab_size, t.dim,
+                logical_rows(leaf, t.dim), shards, gib, moments * gib,
+            )
 
     def state_specs(self) -> TrainState:
         if self._state_specs is None:
@@ -778,29 +856,23 @@ class Trainer:
                 per[key] = per.get(key, 0) + int(shard.data.nbytes)
         return per
 
-    def shard_state(self, state: TrainState) -> TrainState:
-        """Place (or re-place, after a mesh re-formation) state on the mesh.
-
-        Accepts optimizer state in EITHER layout (canonical param-shaped,
-        or the flat dp-sharded layout of any PREVIOUS mesh): leaves are
-        first canonicalized, then laid out for THIS mesh per the resolved
-        optimizer_sharding mode — so an elastic 4->8->4 resize
-        REDISTRIBUTES existing Adam/Adagrad moments instead of rebuilding
-        them."""
+    def _adopt_layout(self, params: Any) -> TrainState:
+        """Resolve this mesh's layout for ``params`` (arrays or shapes):
+        sets the optimizer shard plan and ``state_specs`` and returns the
+        matching tree of shardings."""
         tp_dims = (
-            self.spec.tensor_sharding(state.params)
+            self.spec.tensor_sharding(params)
             if self.tp_axis is not None and self.spec.tensor_sharding
             else None
         )
         p_specs = params_partition_specs(
-            state.params,
+            params,
             self.spec.embedding_tables,
             self.axis_name,
             self.sharded_embeddings,
             tp_dims=tp_dims,
             tp_axis=self.tp_axis,
         )
-        params = jax.tree.map(jnp.asarray, state.params)
         plan = opt_shard_plan(
             params,
             self.spec.embedding_tables,
@@ -818,14 +890,25 @@ class Trainer:
             shard_plan=self._opt_plan,
             shard_axis=self._opt_shard_axis(),
         )
+        self._state_specs = TrainState(step=P(), params=p_specs, opt_state=o_specs)
+        return jax.tree.map(
+            lambda s: NamedSharding(self.mesh, s), self._state_specs
+        )
+
+    def shard_state(self, state: TrainState) -> TrainState:
+        """Place (or re-place, after a mesh re-formation) state on the mesh.
+
+        Accepts optimizer state in EITHER layout (canonical param-shaped,
+        or the flat dp-sharded layout of any PREVIOUS mesh): leaves are
+        first canonicalized, then laid out for THIS mesh per the resolved
+        optimizer_sharding mode — so an elastic 4->8->4 resize
+        REDISTRIBUTES existing Adam/Adagrad moments instead of rebuilding
+        them."""
+        shardings = self._adopt_layout(state.params)
         opt_state = self._opt_canonical(state.opt_state, state.params)
         if self._opt_plan is not None:
             opt_state = self._opt_flat_host(opt_state, self._opt_plan)
         state = state.replace(opt_state=opt_state)
-        self._state_specs = TrainState(step=P(), params=p_specs, opt_state=o_specs)
-        shardings = jax.tree.map(
-            lambda s: NamedSharding(self.mesh, s), self._state_specs
-        )
         procs = {d.process_index for d in self.mesh.devices.flat}
         if len(procs) <= 1:
             return jax.device_put(state, shardings)
@@ -1539,14 +1622,6 @@ def build_train_step(
         n_shards = int(mesh.shape[shard_axis])
         other_axes = tuple(a for a in axes if a != shard_axis)
 
-        def _pad_flat(x, entry):
-            v = jnp.reshape(x, (-1,))
-            if entry.padded != entry.size:
-                v = jnp.concatenate(
-                    [v, jnp.zeros((entry.padded - entry.size,), v.dtype)]
-                )
-            return v
-
         def sharded_update(state: TrainState, grads):
             idx = lax.axis_index(shard_axis)
 
@@ -1629,14 +1704,16 @@ def build_train_step(
         def loss_fn(params, host_embs):
             merged = dict(batch)
             merged.update(host_embs)
-            out = spec.apply(params, merged, train=True, ctx=ctx)
+            with route_taps() as received:
+                out = spec.apply(params, merged, train=True, ctx=ctx)
+            aux = (out, sum(received) if received else None)
             if mask is not None:
                 # count/total are constants w.r.t. params; the psum above
                 # traces fine under grad.
-                return spec.loss(out, merged, mask=mask) * count / total, out
-            return spec.loss(out, merged) * w / n_active, out
+                return spec.loss(out, merged, mask=mask) * count / total, aux
+            return spec.loss(out, merged) * w / n_active, aux
 
-        (loss, out), (grads, host_grads) = jax.value_and_grad(
+        (loss, (out, rows_received)), (grads, host_grads) = jax.value_and_grad(
             loss_fn, argnums=(0, 1), has_aux=True
         )(state.params, host_in)
         loss = coll.psum(loss, axes)
@@ -1668,6 +1745,15 @@ def build_train_step(
                 if not k.startswith(HIST_PREFIX)
             }
         metrics["loss"] = loss
+        if rows_received is not None:
+            # The ragged route's load: table rows this shard served in the
+            # step, on the fullest shard and on average (the worker sums
+            # both into its ROUTE_COUNTERS instead of reporting them).
+            rows = rows_received.astype(jnp.float32)
+            metrics["route_rows_recv_max"] = lax.pmax(rows, axes)
+            metrics["route_rows_recv_mean"] = coll.psum(
+                rows, axes
+            ) / coll.contributor_count(mesh, axes)
         new_state = TrainState(step=state.step + 1, params=params, opt_state=opt_state)
         if host_keys:
             # Per-example cotangents of the global-mean loss, batch-sharded;

@@ -79,7 +79,23 @@ COUNTER_GAUGES = {
         "edl_dispatches_device_idle_total",
         "training dispatches that found the previous one's output ready: "
         "the device queue had run dry and the chip waited for the host"),
+    "init_state_s": (
+        "edl_init_state_seconds",
+        "wall seconds of the last Trainer.init_state (the sharded jitted init)"),
+    "route_rows_recv_max": (
+        "edl_route_rows_recv_max_total",
+        "table rows the fullest shard served over the ragged route, summed "
+        "over training steps"),
+    "route_rows_recv_mean": (
+        "edl_route_rows_recv_mean_total",
+        "table rows a shard served over the ragged route on average, "
+        "summed over training steps"),
 }
+
+#: Step metrics (parallel/trainer.py) that are counts, not model metrics:
+#: summed over steps into the counters of the same name, never reported
+#: as a task's metrics.
+ROUTE_COUNTERS = ("route_rows_recv_max", "route_rows_recv_mean")
 
 
 def _profile_annotation(name: str, attrs: dict):
@@ -410,6 +426,10 @@ class Worker:
         # Newest counter snapshot (_counter_snapshot): replaced wholesale
         # at every report, republished as gauges at scrape time.
         self._counters: Dict[str, float] = {}  # gil-atomic
+        # ROUTE_COUNTERS' running sums: added to wherever a task's metrics
+        # settle (the task loop; the preemption thread's last flush).
+        self._route_lock = threading.Lock()
+        self._route_rows = dict.fromkeys(ROUTE_COUNTERS, 0.0)  # guarded-by: _route_lock
         count_compiles()
         # Task-level pipeline: the previous training task's (report, device
         # metrics), fetched + reported only after the NEXT task's steps are
@@ -1557,12 +1577,18 @@ class Worker:
         settle path (one ``memory_stats()`` per local device; nothing per
         step).  Keys: ``COUNTER_GAUGES``."""
         compiles, compile_s = compile_counts()
+        with self._route_lock:
+            route_rows = dict(self._route_rows)
         self._counters = {
             "compiles": compiles,
             "compile_s": round(compile_s, 6),
             "hbm_peak_bytes": device_peak_bytes(),
             "dispatches": self._dispatches,
             "dispatches_device_idle": self._dispatches_idle,
+            "init_state_s": round(
+                self.trainer.init_state_s if self.trainer else 0.0, 6
+            ),
+            **route_rows,
         }
         return self._counters
 
@@ -2087,6 +2113,10 @@ class Worker:
                         a = a.sum(axis=0)
                     sums[k] = sums.get(k, 0.0) + a
                 n += steps
+            with self._route_lock:
+                for key in ROUTE_COUNTERS:
+                    if key in sums:
+                        self._route_rows[key] += float(sums.pop(key))
             # finalize: scalars -> float, histogram pairs -> scalar (AUC).
             return finalize_metrics(
                 {k: s / max(n, 1) for k, s in sums.items()}
@@ -2673,10 +2703,7 @@ class Worker:
         self._apply_membership(membership, initial=True)
         if self.state is None:
             self.state = self.trainer.init_state(jax.random.key(0))
-            # init_state builds the whole unsharded params + optimizer
-            # state on the default device before shard_state places it;
-            # the log shows whether device 0 ends up holding more than
-            # its shard.
+            # Every device should hold its shard and nothing else.
             logger.info(
                 "device bytes in use after init: %s",
                 json.dumps(device_bytes_in_use()),

@@ -58,9 +58,10 @@ def test_flash_fwd_bwd_lowers_to_three_mosaic_calls(
 
 
 @pytest.fixture(scope="module")
-def v5e_device():
-    """One device of a described (not attached) v5e host: libtpu compiles
-    for it ahead of time.  Skips where the installed libtpu cannot."""
+def v5e_host():
+    """The four devices of a described (not attached) v5e 2x2 host: libtpu
+    compiles for them ahead of time.  Skips where the installed libtpu
+    cannot."""
     from jax.experimental import topologies
 
     try:
@@ -69,7 +70,12 @@ def v5e_device():
         )
     except Exception as e:  # noqa: BLE001 — any plugin failure means "cannot"
         pytest.skip(f"libtpu cannot describe a v5e topology here: {e}")
-    return topo.devices[0]
+    return list(topo.devices)
+
+
+@pytest.fixture(scope="module")
+def v5e_device(v5e_host):
+    return v5e_host[0]
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES)
@@ -131,3 +137,89 @@ def test_deepfm_ragged_step_lowers_with_ragged_all_to_all(devices):
     )
     # ids out, vectors back, cotangents out.
     assert text.count("ragged_all_to_all") == 3
+
+
+# deepfm_criteo_tb_x4 (benchmark/configs): 163.6 M rows over four chips.
+X4_BUCKETS = 6291456
+X4_ROUTE_SCOPES = (
+    "route_plan", "route_ids", "route_gather", "route_vectors", "route_unsort",
+    "route_bwd_sort", "route_bwd_vectors", "route_bwd_scatter",
+)
+
+
+def test_x4_init_and_step_compile_for_a_v5e_host(v5e_host):
+    """The configuration that only four chips can hold, at its real size,
+    through the chip's own compiler: the jitted init bears every output
+    sharded with a per-device temporary far under a shard (the eager init
+    needed 8 x the table on device 0), the step holds the real
+    ragged-all-to-all three times and fits a chip, and what the
+    ``*_ms_step.ex4`` metrics match in a device trace is there: the route's
+    named scopes, and the dense Adam sweep as ONE multiply_add_fusion over
+    the table shard and both its moments."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    spec = load_model_spec(
+        "elasticdl_tpu.models", "deepfm.model_spec",
+        buckets_per_feature=X4_BUCKETS, embedding_dim=10,
+        hidden=(400, 400, 400), host_tier=False,
+    )
+    mesh = create_mesh(v5e_host, num_devices=4)
+    trainer = Trainer(
+        spec,
+        JobConfig(distribution_strategy=DistributionStrategy.PARAMETER_SERVER),
+        mesh,
+    )
+    assert trainer.ctx.embedding_impl == "ragged"  # what ``auto`` means on 4 TPU chips
+    replicated = NamedSharding(mesh, P())
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=replicated)
+    init = trainer._init_program(key)
+    compiled = init.trace(key).lower(lowering_platforms=("tpu",)).compile()
+    memory = compiled.memory_analysis()
+    rows, width = 26 * X4_BUCKETS // 8, 128
+    shard = rows * width * 4 // 4
+    assert shard == 2496 * 2**20  # 2.44 GiB of rows a chip
+    assert 3 * shard <= memory.output_size_in_bytes < 3 * shard + 2**24
+    assert memory.temp_size_in_bytes < 1.5 * shard
+    assert memory.output_size_in_bytes + memory.temp_size_in_bytes < 12 * 2**30
+
+    state = jax.tree.map(
+        lambda leaf, spec_: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec_)
+        ),
+        jax.eval_shape(init, key), trainer.state_specs(),
+    )
+    one = spec.example_batch(8192)
+    stacked = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            (8,) + x.shape, x.dtype,
+            sharding=NamedSharding(mesh, P(None, *trainer._batch_spec_for(x))),
+        ),
+        one,
+    )
+    step = trainer._scanned(
+        trainer._train_steps, build_train_step, stacked, host_keys=(),
+        variant_budget=1, **trainer._train_build_kwargs(),
+    )
+    active = jax.ShapeDtypeStruct(
+        (trainer.num_contributors(),), jnp.float32, sharding=replicated
+    )
+    compiled = (
+        step.trace(state, stacked, active)
+        .lower(lowering_platforms=("tpu",)).compile()
+    )
+    memory = compiled.memory_analysis()
+    # state (aliased in and out) + the step's temporaries, of which the
+    # dense gradient buffer is one more shard: a 16 GiB chip holds it.
+    assert memory.alias_size_in_bytes >= 3 * shard
+    assert shard <= memory.temp_size_in_bytes < 1.5 * shard
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * 2**30
+    text = compiled.as_text()
+    assert len(re.findall(r" ragged-all-to-all\(", text)) == 3
+    for scope in X4_ROUTE_SCOPES:
+        assert re.search(rf"op_name=\"[^\"]*\b{scope}\b", text), scope
+    sweep = re.findall(
+        rf"%multiply_add_fusion[.\d]* = \((f32\[{rows // 4},128\]\S*, ){{2}}f32\[{rows // 4},128\]",
+        text,
+    )
+    assert len(sweep) == 1

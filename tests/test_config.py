@@ -1,3 +1,5 @@
+import pytest
+
 from elasticdl_tpu.common.config import (
     DistributionStrategy,
     JobConfig,
@@ -61,6 +63,15 @@ def test_learning_rate_flag_reaches_model():
     grads = {"w": __import__("jax.numpy", fromlist=["x"]).ones((2,))}
     updates, _ = spec.optimizer.update(grads, state, params)
     assert abs(float(updates["w"][0])) == 0.5
+
+
+@pytest.mark.parametrize("literal, json_spelling", [("False", "false"), ("True", "true"), ("None", "null")])
+def test_model_params_reject_python_literals(literal, json_spelling):
+    """Values are JSON: ``host_tier=False`` used to arrive as the truthy
+    string "False" and turn the host tier on."""
+    with pytest.raises(ValueError, match=f"host_tier={literal}.*{json_spelling}"):
+        JobConfig(model_params=f"host_tier={literal}").parsed_model_params()
+    assert JobConfig(model_params="host_tier=false").parsed_model_params() == {"host_tier": False}
 
 
 def test_model_params_override_learning_rate_flag():

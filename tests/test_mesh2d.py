@@ -186,8 +186,12 @@ def test_tensor_parallel_matches_1d(devices):
         jax.tree.leaves(jax.device_get(s2.params)),
         jax.tree.leaves(jax.device_get(s1.params)),
     ):
+        # Four Adam steps amplify reduction-order noise where a gradient
+        # is near zero (the update is lr * m / sqrt(v), sign-like early
+        # on): with the jitted init's values (PR 26; one ulp from the
+        # eager ones) one element of 4096 lands 1.9e-6 apart.
         np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6
+            np.asarray(a), np.asarray(b), rtol=1e-6, atol=5e-6
         )
 
 
