@@ -14,24 +14,29 @@ Design (static shapes, XLA/ICI-friendly — see SURVEY.md §7 item 5):
   power-of-two stride matters: a dim-9 table packed at its natural width 126
   measured a 3x slower gather than the same data at width 128 on v5e.  This
   formulation is
-  what XLA:TPU vectorizes: per-op device times from a ``jax.profiler`` trace
-  of the real DeepFM step (8192×26 ids into a 1.7M-row dim-8 table, v5e)
-  measure the packed row gather at 0.53 ms and its transpose scatter-add at
-  2.75 ms — versus **370 ms / 728 ms** for the same shapes stored flat 1-D
-  and gathered as ``dim``-element slices, which XLA lowers to a *serial
-  per-row while loop* (212,992 iterations/step at ~2-3 µs each; this was
-  round 2's entire ~200x throughput gap).  An unpacked 2-D ``[V, 8]`` table
-  vectorizes too but wastes 15/16 of each vreg on the scatter (18.2 ms); a
-  one-hot-matmul lookup costs 20 ms of MXU time.  bf16 rows do NOT help:
-  the scatter-add is op-rate-bound (~13 ns/row whether the physical row is
-  256 B or 512 B — measured 2.97 ms bf16 vs 2.75 ms f32), so tables stay
-  f32 (see docs/perf.md).  Trace-derived per-op device times from the r3
-  session, not re-measured on current code; reproduce with
-  ``tools/gather_experiments.py``.
+  what XLA:TPU vectorizes.  Per-op device times of the r3 session (a
+  backend that is gone; 8192×26 ids into a 1.7M-row dim-8 table = 106,496
+  physical rows, 54.5 MB, v5e): the packed row gather 0.53 ms and its
+  transpose scatter-add 2.75 ms — versus **370 ms / 728 ms** for the same
+  shapes stored flat 1-D and gathered as ``dim``-element slices, which XLA
+  lowers to a *serial per-row while loop* (212,992 iterations/step at ~2-3
+  µs each; this was round 2's entire ~200x throughput gap).  An unpacked
+  2-D ``[V, 8]`` table vectorizes too but wastes 15/16 of each vreg on the
+  scatter (18.2 ms); a one-hot-matmul lookup costs 20 ms of MXU time.  bf16
+  rows do NOT help: at that table size the scatter-add is op-rate-bound
+  (~13 ns/row whether the physical row is 256 B or 512 B — 2.97 ms bf16 vs
+  2.75 ms f32), so tables stay f32 (docs/perf.md).  The scatter-add's cost
+  a row is NOT a constant, though: re-measured on current code (PERF.md,
+  PR 27, step 0) the same 212,992 rows cost 2.8 ms into 54 MB, 3.9 ms into
+  654 MB and 18.8 ms into the benchmark's 1.31 GB table (74 ns a row in the
+  step), which is why a big table's cotangent is built by the sorted merge
+  sweep instead (``SWEEP_MIN_ROWS``, ``ops/table_grad.py``).
 - Lookup of logical row ``i`` reads physical row ``i // pack`` (one 128-lane
   gather) and selects lane group ``i % pack`` with a tiny one-hot einsum;
   the AD transpose expands cotangents back to 128-lane rows (einsum
-  transpose) and scatter-adds whole physical rows.
+  transpose) and scatter-adds whole physical rows — or, for a big f32 table
+  on a TPU, sorts them and merges them into the buffer in one sequential
+  sweep (``_sweeps``: read from the table's shape, no flag).
 - The table is **physical-row-sharded** over the mesh axis: ``V'`` is padded
   so the physical row count divides every power-of-two mesh size up to 256,
   and shard ``i`` owns logical rows ``[i*V'/n, (i+1)*V'/n)`` — GSPMD's
@@ -66,7 +71,8 @@ TPU takes ``ragged``; CPU takes ``dense``.
 
 Backward (both impls): the cotangents retrace the forward route back to the
 owner shard and scatter-add into its local rows (whole-physical-row
-scatter-add — the transpose of the packed gather), with duplicate ids
+scatter-add — the transpose of the packed gather — or the merge sweep that
+builds the same buffer), with duplicate ids
 correctly accumulated — the moral equivalent of the reference's server-side
 IndexedSlices apply.  The ragged impl does this through a ``custom_vjp`` (the
 ragged collective has no AD rule): the saved routing metadata is replayed,
@@ -99,6 +105,7 @@ from jax import lax
 from jax.extend.random import threefry2x32_p
 
 from elasticdl_tpu.common.jax_compat import axis_size
+from elasticdl_tpu.ops.table_grad import sweep_table_grad
 
 # TPU vreg lane count: physical rows are packed to (at most) this many lanes.
 LANES = 128
@@ -108,6 +115,14 @@ LANES = 128
 # shapes then stay identical across elastic resizes (4->8->4 never reshapes
 # params or optimizer state).
 PHYSICAL_ROW_MULTIPLE = 256
+
+# Physical rows from which, on a TPU, the table cotangent of a row gather is
+# built by the sorted merge sweep (ops/table_grad.py) instead of the AD
+# transpose's zeros + scatter-add.  N = 212,992 update rows on a v5e (PERF.md,
+# PR 27, step 0): the two tie at 1.28 M rows (3.8 against 3.9 ms), XLA's
+# scatter-add then falls off a cliff between 1.54 M and 1.79 M rows (4.2 ->
+# 18.2 ms) while the sweep grows with the bytes it writes (5.0 ms at 2.56 M).
+SWEEP_MIN_ROWS = 3 << 19
 
 # HBM guard for auto host-tier promotion: a table whose padded storage plus
 # Adam moments (3x) would crowd a v5e's 16 GiB HBM (shared with activations
@@ -331,7 +346,8 @@ def gather_rows(table: jax.Array, ids: jax.Array, dim: Optional[int] = None):
     ``table`` is ``[P, pack*dim]`` (``dim`` defaults to the full width, i.e. a
     plain ``[V, dim]`` table is the ``pack == 1`` case).  Whole-physical-row
     gather + one-hot lane select; its AD transpose is a whole-physical-row
-    scatter-add.  Out-of-range ids (either sign) fill with NaN (floats) so
+    scatter-add, built for a big table on a TPU by the sorted merge sweep
+    (:func:`_sweeps`).  Out-of-range ids (either sign) fill with NaN (floats) so
     id-generation bugs surface immediately instead of silently training on a
     clamped row; the fill-mode transpose likewise drops OOB cotangents.
     """
@@ -350,16 +366,56 @@ def gather_rows(table: jax.Array, ids: jax.Array, dim: Optional[int] = None):
     oob = (flat_ids < 0) | (flat_ids >= P * pack)
     if pack == 1:
         idx = jnp.where(oob, P, flat_ids)
-        out = jnp.take(table, idx, axis=0, mode="fill", fill_value=fill)
+        out = _take_rows(table, idx, fill)
         out = out[:, :dim]
     else:
         hi = jnp.where(oob, P, flat_ids // pack)
         lo = jnp.where(oob, 0, flat_ids - (flat_ids // pack) * pack)
-        rows = jnp.take(table, hi, axis=0, mode="fill", fill_value=fill)
+        rows = _take_rows(table, hi, fill)
         rows = rows.reshape(flat_ids.shape[0], pack, stride)
         sel = jax.nn.one_hot(lo, pack, dtype=table.dtype)
         out = jnp.einsum("nps,np->ns", rows, sel)[:, :dim]
     return out.reshape(ids.shape + (dim,))
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _sweeps(table: jax.Array) -> bool:
+    """Whether the cotangent of a row gather from ``table`` is built by the
+    merge sweep: read from the table's shape and the platform, at trace
+    time.  Everything else keeps the AD transpose of ``jnp.take``."""
+    rows, width = table.shape
+    return (
+        rows >= SWEEP_MIN_ROWS and width == LANES
+        and table.dtype == jnp.float32 and _on_tpu()
+    )
+
+
+def _take_rows(table: jax.Array, idx: jax.Array, fill) -> jax.Array:
+    """Physical rows ``idx`` (in ``[0, P]``; ``P`` fills) of ``table``."""
+    if _sweeps(table):
+        return _take_rows_swept(table, idx.astype(jnp.int32), table.shape[0])
+    return jnp.take(table, idx, axis=0, mode="fill", fill_value=fill)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take_rows_swept(table, idx, num_rows: int):
+    return jnp.take(table, idx, axis=0, mode="fill", fill_value=jnp.nan)
+
+
+def _take_rows_swept_fwd(table, idx, num_rows: int):
+    return _take_rows_swept(table, idx, num_rows), idx
+
+
+def _take_rows_swept_bwd(num_rows: int, idx, g):
+    with jax.named_scope("table_grad"):
+        table_bar = sweep_table_grad(idx, g, num_rows)
+    return table_bar, np.zeros(idx.shape, jax.dtypes.float0)
+
+
+_take_rows_swept.defvjp(_take_rows_swept_fwd, _take_rows_swept_bwd)
 
 
 def embedding_lookup(
@@ -389,6 +445,7 @@ def embedding_lookup(
     _pack_geometry(table.shape[1], dim)  # raises on inconsistent width/dim
 
     if not (ctx.sharded_embeddings and ctx.axis_name):
+        _tap_lookup(table, ids, dim)
         return gather_rows(table, ids, dim)
     impl = resolve_impl(ctx.embedding_impl)
     # n=1 degenerates to a local gather (dense short-circuits it); an
@@ -398,34 +455,66 @@ def embedding_lookup(
         axis_size(ctx.axis_name) == 1 and impl == IMPL_RAGGED_EMULATED
     ):
         return _dense_lookup(table, ids, ctx.axis_name, dim)
-    out, rows_received = _ragged_lookup(
+    out, rows_received, rows_in_range = _ragged_lookup(
         table, ids, ctx.axis_name, dim, impl == IMPL_RAGGED_EMULATED
     )
-    if _ROUTE_TAPS.rows is not None:
-        _ROUTE_TAPS.rows.append(rows_received)
+    if _TAPS.open is not None:
+        _TAPS.open.rows_received.append(rows_received)
+    _tap_table_grad(table, rows_in_range)
     return out
 
 
-class _RouteTaps(threading.local):
-    rows: Optional[list] = None
+@dataclasses.dataclass
+class LookupTaps:
+    """What :func:`route_taps` collects, one entry a lookup traced: int32
+    scalars of the enclosing trace."""
+
+    #: ragged route: rows THIS shard received.
+    rows_received: list = dataclasses.field(default_factory=list)
+    #: every route: (update rows the table's cotangent is offered — the
+    #: looked-up ids inside the table —, those of them whose cotangent is
+    #: built by the merge sweep: all or none, :func:`_sweeps`).
+    table_grad: list = dataclasses.field(default_factory=list)
 
 
-_ROUTE_TAPS = _RouteTaps()
+class _Taps(threading.local):
+    open: Optional[LookupTaps] = None
+
+
+_TAPS = _Taps()
 
 
 @contextlib.contextmanager
 def route_taps():
-    """Trace-time tap on the ragged route: while open (on this thread),
-    every ragged lookup traced appends the number of rows THIS shard
-    received (an int32 scalar of the enclosing trace) to the yielded list —
-    how the train step gets the route's load balance into its metrics
-    without the model's apply returning it."""
-    rows: list = []
-    prev, _ROUTE_TAPS.rows = _ROUTE_TAPS.rows, rows
+    """Trace-time tap on the lookups: while open (on this thread), every
+    ``embedding_lookup`` traced appends to the yielded :class:`LookupTaps` —
+    how the train step gets the route's load balance and the table
+    gradient's path into its metrics without the model's apply returning
+    them."""
+    taps = LookupTaps()
+    prev, _TAPS.open = _TAPS.open, taps
     try:
-        yield rows
+        yield taps
     finally:
-        _ROUTE_TAPS.rows = prev
+        _TAPS.open = prev
+
+
+def _rows_in_range(table: jax.Array, ids: jax.Array, dim: int) -> jax.Array:
+    inside = (ids >= 0) & (ids < logical_rows(table, dim))
+    return jnp.sum(inside, dtype=jnp.int32)
+
+
+def _tap_table_grad(table: jax.Array, rows: jax.Array) -> None:
+    if _TAPS.open is not None:
+        swept = rows if _sweeps(table) else jnp.zeros_like(rows)
+        _TAPS.open.table_grad.append((rows, swept))
+
+
+def _tap_lookup(table: jax.Array, ids: jax.Array, dim: int) -> None:
+    """Tap a plain ``gather_rows(table, ids, dim)``; counts only while a
+    tap is open."""
+    if _TAPS.open is not None:
+        _tap_table_grad(table, _rows_in_range(table, ids, dim))
 
 
 def resolve_impl(
@@ -468,6 +557,7 @@ def _dense_lookup(local_table: jax.Array, ids: jax.Array, axis_name: str, dim: i
     flat_ids = ids.reshape(-1)
     bad = (flat_ids < 0) | (flat_ids >= n * rows_local)
     if n == 1:
+        _tap_lookup(local_table, flat_ids, dim)
         out = gather_rows(local_table, flat_ids, dim)  # NaN-fills OOB itself
         return out.reshape(ids_shape + (dim,))
 
@@ -478,6 +568,8 @@ def _dense_lookup(local_table: jax.Array, ids: jax.Array, axis_name: str, dim: i
     local_row = all_ids - owner * rows_local
     mine = owner == my_shard
     safe_row = jnp.where(mine, local_row, 0)
+    # Every gathered id is offered as an update row: the others' as zeros.
+    _tap_table_grad(local_table, jnp.int32(all_ids.shape[0]))
     vectors = jnp.where(mine[:, None], gather_rows(local_table, safe_row, dim), 0)
 
     # Route each device its own block, summing over shards (one nonzero each).
@@ -573,7 +665,8 @@ def _exclusive_cumsum(x: jax.Array) -> jax.Array:
 
 @partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def _ragged_lookup(local_table, ids, axis_name: str, dim: int, emulate: bool):
-    """(vectors ``ids.shape + (dim,)``, rows this shard received: int32)."""
+    """(vectors ``ids.shape + (dim,)``, rows this shard received, those of
+    them inside its row range: int32)."""
     out, _ = _ragged_lookup_fwd(local_table, ids, axis_name, dim, emulate)
     return out
 
@@ -603,6 +696,7 @@ def _ragged_lookup_fwd(local_table, ids, axis_name: str, dim: int, emulate: bool
     with jax.named_scope("route_gather"):
         local_rows = recv_ids - lax.axis_index(axis_name) * rows_local
         vecs = gather_rows(local_table, local_rows, dim)   # [n*L, dim], NaN on OOB
+        rows_in_range = _rows_in_range(local_table, local_rows, dim)
 
     # vectors -> requesters: exactly the reverse plan.  My block offsets are
     # recv's exclusive cumsum (received chunks are sender-ordered); my chunk
@@ -622,13 +716,13 @@ def _ragged_lookup_fwd(local_table, ids, axis_name: str, dim: int, emulate: bool
         out = sorted_out[inv].reshape(ids_shape + (dim,))
     residuals = (perm, send, in_off, out_off, recv, back_in_off, back_out_off,
                  local_rows, local_table.shape, ids_shape)
-    return (out, jnp.sum(recv)), residuals
+    return (out, jnp.sum(recv), rows_in_range), residuals
 
 
 def _ragged_lookup_bwd(axis_name: str, dim: int, emulate: bool, residuals, g):
     (perm, send, in_off, out_off, recv, back_in_off, back_out_off,
      local_rows, table_shape_, ids_shape) = residuals
-    g, _ = g  # the row count is an integer: no cotangent
+    g, _, _ = g  # the row counts are integers: no cotangent
     n = axis_size(axis_name)
     L = perm.shape[0]
     # Cotangents retrace the forward id route (requester -> owner): sort by

@@ -1704,16 +1704,20 @@ def build_train_step(
         def loss_fn(params, host_embs):
             merged = dict(batch)
             merged.update(host_embs)
-            with route_taps() as received:
+            with route_taps() as taps:
                 out = spec.apply(params, merged, train=True, ctx=ctx)
-            aux = (out, sum(received) if received else None)
+            aux = (
+                out,
+                sum(taps.rows_received) if taps.rows_received else None,
+                tuple(map(sum, zip(*taps.table_grad))),
+            )
             if mask is not None:
                 # count/total are constants w.r.t. params; the psum above
                 # traces fine under grad.
                 return spec.loss(out, merged, mask=mask) * count / total, aux
             return spec.loss(out, merged) * w / n_active, aux
 
-        (loss, (out, rows_received)), (grads, host_grads) = jax.value_and_grad(
+        (loss, (out, rows_received, table_grad)), (grads, host_grads) = jax.value_and_grad(
             loss_fn, argnums=(0, 1), has_aux=True
         )(state.params, host_in)
         loss = coll.psum(loss, axes)
@@ -1748,12 +1752,19 @@ def build_train_step(
         if rows_received is not None:
             # The ragged route's load: table rows this shard served in the
             # step, on the fullest shard and on average (the worker sums
-            # both into its ROUTE_COUNTERS instead of reporting them).
+            # both into its STEP_COUNTERS instead of reporting them).
             rows = rows_received.astype(jnp.float32)
             metrics["route_rows_recv_max"] = lax.pmax(rows, axes)
             metrics["route_rows_recv_mean"] = coll.psum(
                 rows, axes
             ) / coll.contributor_count(mesh, axes)
+        if table_grad:
+            # Update rows the tables' cotangents were offered in the step,
+            # and those built by the merge sweep (ops/table_grad.py): all
+            # of them or none, a table at a time.
+            rows, swept = (coll.psum(x.astype(jnp.float32), axes) for x in table_grad)
+            metrics["table_grad_rows"] = rows
+            metrics["table_grad_rows_swept"] = swept
         new_state = TrainState(step=state.step + 1, params=params, opt_state=opt_state)
         if host_keys:
             # Per-example cotangents of the global-mean loss, batch-sharded;

@@ -90,12 +90,23 @@ COUNTER_GAUGES = {
         "edl_route_rows_recv_mean_total",
         "table rows a shard served over the ragged route on average, "
         "summed over training steps"),
+    "table_grad_rows": (
+        "edl_table_grad_rows_total",
+        "update rows offered to the embedding tables' gradients, summed over "
+        "training steps and devices"),
+    "table_grad_rows_swept": (
+        "edl_table_grad_rows_swept_total",
+        "those of them whose gradient buffer the sorted merge sweep built "
+        "(ops/table_grad.py) and not XLA's scatter-add"),
 }
 
 #: Step metrics (parallel/trainer.py) that are counts, not model metrics:
 #: summed over steps into the counters of the same name, never reported
 #: as a task's metrics.
-ROUTE_COUNTERS = ("route_rows_recv_max", "route_rows_recv_mean")
+STEP_COUNTERS = (
+    "route_rows_recv_max", "route_rows_recv_mean",
+    "table_grad_rows", "table_grad_rows_swept",
+)
 
 
 def _profile_annotation(name: str, attrs: dict):
@@ -426,10 +437,10 @@ class Worker:
         # Newest counter snapshot (_counter_snapshot): replaced wholesale
         # at every report, republished as gauges at scrape time.
         self._counters: Dict[str, float] = {}  # gil-atomic
-        # ROUTE_COUNTERS' running sums: added to wherever a task's metrics
+        # STEP_COUNTERS' running sums: added to wherever a task's metrics
         # settle (the task loop; the preemption thread's last flush).
-        self._route_lock = threading.Lock()
-        self._route_rows = dict.fromkeys(ROUTE_COUNTERS, 0.0)  # guarded-by: _route_lock
+        self._step_counts_lock = threading.Lock()
+        self._step_counts = dict.fromkeys(STEP_COUNTERS, 0.0)  # guarded-by: _step_counts_lock
         count_compiles()
         # Task-level pipeline: the previous training task's (report, device
         # metrics), fetched + reported only after the NEXT task's steps are
@@ -1577,8 +1588,8 @@ class Worker:
         settle path (one ``memory_stats()`` per local device; nothing per
         step).  Keys: ``COUNTER_GAUGES``."""
         compiles, compile_s = compile_counts()
-        with self._route_lock:
-            route_rows = dict(self._route_rows)
+        with self._step_counts_lock:
+            step_counts = dict(self._step_counts)
         self._counters = {
             "compiles": compiles,
             "compile_s": round(compile_s, 6),
@@ -1588,7 +1599,7 @@ class Worker:
             "init_state_s": round(
                 self.trainer.init_state_s if self.trainer else 0.0, 6
             ),
-            **route_rows,
+            **step_counts,
         }
         return self._counters
 
@@ -2113,10 +2124,10 @@ class Worker:
                         a = a.sum(axis=0)
                     sums[k] = sums.get(k, 0.0) + a
                 n += steps
-            with self._route_lock:
-                for key in ROUTE_COUNTERS:
+            with self._step_counts_lock:
+                for key in STEP_COUNTERS:
                     if key in sums:
-                        self._route_rows[key] += float(sums.pop(key))
+                        self._step_counts[key] += float(sums.pop(key))
             # finalize: scalars -> float, histogram pairs -> scalar (AUC).
             return finalize_metrics(
                 {k: s / max(n, 1) for k, s in sums.items()}

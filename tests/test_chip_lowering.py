@@ -14,6 +14,7 @@ import pytest
 
 from elasticdl_tpu.common.config import DistributionStrategy, JobConfig
 from elasticdl_tpu.models.spec import load_model_spec
+from elasticdl_tpu.ops import embedding, table_grad
 from elasticdl_tpu.ops import flash_attention as fa
 from elasticdl_tpu.parallel.mesh import create_mesh
 from elasticdl_tpu.parallel.trainer import Trainer, build_train_step
@@ -139,6 +140,90 @@ def test_deepfm_ragged_step_lowers_with_ragged_all_to_all(devices):
     assert text.count("ragged_all_to_all") == 3
 
 
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """What ``ops/embedding.py`` reads from the backend, set as the chip
+    sets it (the devices here are described, the backend is the CPU): the
+    platform is a TPU, and the sweep is compiled, not interpreted."""
+    monkeypatch.setattr(embedding, "_on_tpu", lambda: True)
+    monkeypatch.setattr(table_grad, "_use_interpret", lambda: False)
+
+
+def _abstract_scan_step(trainer, mesh, minibatch=8192, steps=8):
+    """(the scanned step, its abstract (state, batches, active)) on ``mesh``."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    replicated = NamedSharding(mesh, P())
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=replicated)
+    state = jax.tree.map(
+        lambda leaf, spec_: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec_)
+        ),
+        jax.eval_shape(trainer._init_program(key), key), trainer.state_specs(),
+    )
+    stacked = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            (steps,) + x.shape, x.dtype,
+            sharding=NamedSharding(mesh, P(None, *trainer._batch_spec_for(x))),
+        ),
+        trainer.spec.example_batch(minibatch),
+    )
+    step = trainer._scanned(
+        trainer._train_steps, build_train_step, stacked, host_keys=(),
+        variant_budget=1, **trainer._train_build_kwargs(),
+    )
+    active = jax.ShapeDtypeStruct(
+        (trainer.num_contributors(),), jnp.float32, sharding=replicated
+    )
+    return step, (state, stacked, active)
+
+
+def _assert_table_grad_is_the_sweep(text: str, rows: int, scopes=("table_grad",)):
+    """In a compiled step: no scatter into a table-shaped buffer is left,
+    ONE Mosaic call under ``scopes`` writes it, and the dense Adam update
+    is still ONE multiply_add_fusion over the table and both moments."""
+    assert not re.findall(rf"f32\[{rows},128\]\S* scatter\(", text)
+    calls = re.findall(
+        rf"= f32\[{rows},128\]\S* custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\".*op_name=\"([^\"]*)\"", text,
+    )
+    assert len(calls) == 1, calls
+    for scope in scopes:
+        assert re.search(rf"\b{scope}\b", calls[0]), calls[0]
+    sweep = re.findall(
+        rf"%multiply_add_fusion[.\d]* = \((f32\[{rows},128\]\S*, ){{2}}f32\[{rows},128\]",
+        text,
+    )
+    assert len(sweep) == 1
+
+
+def test_deepfm_job_step_builds_its_table_gradient_by_the_sweep(
+    v5e_device, as_on_the_chip
+):
+    """``deepfm_criteo`` at its real size on one described v5e chip: what
+    ``table_grad_ms_step.ex`` matches is there, the scatter-add is not, and
+    the step's temporaries are the gradient buffer and little more."""
+    spec = load_model_spec(
+        "elasticdl_tpu.models", "deepfm.model_spec",
+        buckets_per_feature=786432, embedding_dim=10,
+        hidden=(400, 400, 400), host_tier=False,
+    )
+    mesh = create_mesh([v5e_device], num_devices=1)
+    trainer = Trainer(
+        spec,
+        JobConfig(distribution_strategy=DistributionStrategy.PARAMETER_SERVER),
+        mesh,
+    )
+    step, args = _abstract_scan_step(trainer, mesh)
+    compiled = step.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    rows = 26 * 786432 // 8
+    assert rows == 2555904 >= embedding.SWEEP_MIN_ROWS
+    buffer = rows * 128 * 4
+    assert buffer <= compiled.memory_analysis().temp_size_in_bytes < 1.2 * buffer
+    _assert_table_grad_is_the_sweep(compiled.as_text(), rows)
+
+
 # deepfm_criteo_tb_x4 (benchmark/configs): 163.6 M rows over four chips.
 X4_BUCKETS = 6291456
 X4_ROUTE_SCOPES = (
@@ -147,15 +232,16 @@ X4_ROUTE_SCOPES = (
 )
 
 
-def test_x4_init_and_step_compile_for_a_v5e_host(v5e_host):
+def test_x4_init_and_step_compile_for_a_v5e_host(v5e_host, as_on_the_chip):
     """The configuration that only four chips can hold, at its real size,
     through the chip's own compiler: the jitted init bears every output
     sharded with a per-device temporary far under a shard (the eager init
     needed 8 x the table on device 0), the step holds the real
     ragged-all-to-all three times and fits a chip, and what the
     ``*_ms_step.ex4`` metrics match in a device trace is there: the route's
-    named scopes, and the dense Adam sweep as ONE multiply_add_fusion over
-    the table shard and both its moments."""
+    named scopes, the shard's gradient buffer written by the merge sweep
+    under ``route_bwd_scatter``, and the dense Adam sweep as ONE
+    multiply_add_fusion over the table shard and both its moments."""
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
@@ -183,31 +269,8 @@ def test_x4_init_and_step_compile_for_a_v5e_host(v5e_host):
     assert memory.temp_size_in_bytes < 1.5 * shard
     assert memory.output_size_in_bytes + memory.temp_size_in_bytes < 12 * 2**30
 
-    state = jax.tree.map(
-        lambda leaf, spec_: jax.ShapeDtypeStruct(
-            leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec_)
-        ),
-        jax.eval_shape(init, key), trainer.state_specs(),
-    )
-    one = spec.example_batch(8192)
-    stacked = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(
-            (8,) + x.shape, x.dtype,
-            sharding=NamedSharding(mesh, P(None, *trainer._batch_spec_for(x))),
-        ),
-        one,
-    )
-    step = trainer._scanned(
-        trainer._train_steps, build_train_step, stacked, host_keys=(),
-        variant_budget=1, **trainer._train_build_kwargs(),
-    )
-    active = jax.ShapeDtypeStruct(
-        (trainer.num_contributors(),), jnp.float32, sharding=replicated
-    )
-    compiled = (
-        step.trace(state, stacked, active)
-        .lower(lowering_platforms=("tpu",)).compile()
-    )
+    step, args = _abstract_scan_step(trainer, mesh)
+    compiled = step.trace(*args).lower(lowering_platforms=("tpu",)).compile()
     memory = compiled.memory_analysis()
     # state (aliased in and out) + the step's temporaries, of which the
     # dense gradient buffer is one more shard: a 16 GiB chip holds it.
@@ -218,8 +281,6 @@ def test_x4_init_and_step_compile_for_a_v5e_host(v5e_host):
     assert len(re.findall(r" ragged-all-to-all\(", text)) == 3
     for scope in X4_ROUTE_SCOPES:
         assert re.search(rf"op_name=\"[^\"]*\b{scope}\b", text), scope
-    sweep = re.findall(
-        rf"%multiply_add_fusion[.\d]* = \((f32\[{rows // 4},128\]\S*, ){{2}}f32\[{rows // 4},128\]",
-        text,
+    _assert_table_grad_is_the_sweep(
+        text, rows // 4, scopes=("table_grad", "route_bwd_scatter")
     )
-    assert len(sweep) == 1

@@ -404,3 +404,177 @@ def test_lookup_impls_match_config():
         JobConfig(embedding_lookup_impl=impl).validate()
     with pytest.raises(ValueError, match="embedding_lookup_impl"):
         JobConfig(embedding_lookup_impl="bogus").validate()
+
+
+# ---------------------------------------------------------------------------
+# The table cotangent by the sorted merge sweep (ops/table_grad.py), run in
+# Pallas interpret mode on the CPU as ops/flash_attention.py's kernels are.
+# ---------------------------------------------------------------------------
+
+SWEEP_ROWS = 256  # buffer rows; tiles of 64, chunks of 128 update rows
+
+
+def _zipf_ids(rng, n, rows):
+    p = np.arange(1, rows + 1, dtype=np.float64) ** -1.05
+    return rng.permutation(rows)[rng.choice(rows, n, p=p / p.sum())]
+
+
+# name -> (ids as a function of (rng), bit-equal to the scatter-add?)
+SWEEP_CASES = {
+    "uniform": (lambda r: r.integers(0, SWEEP_ROWS, 300), False),
+    "distinct": (lambda r: r.permutation(SWEEP_ROWS)[:200], True),
+    "one_id": (lambda r: np.full(300, 37), False),
+    "zipf": (lambda r: _zipf_ids(r, 300, SWEEP_ROWS), False),
+    "three_quarters_filler": (
+        lambda r: r.permutation(
+            np.concatenate([r.permutation(SWEEP_ROWS)[:80], np.full(240, SWEEP_ROWS)])
+        ),
+        True,
+    ),
+    "tile_edges": (lambda r: np.array([0, 63, 64, 127, 128, 191, 192, 255]), True),
+    "a_tile_with_no_update": (lambda r: r.permutation(128)[:100] + 128, True),
+    # 300 rows for one tile of 64: chunks 0, 1 and the loop's 2.
+    "a_tile_with_three_chunks": (lambda r: r.integers(64, 128, 300), False),
+    "n_not_a_multiple_of_the_chunk": (lambda r: r.permutation(SWEEP_ROWS)[:131], True),
+    "rows_not_a_multiple_of_the_tile": (lambda r: r.permutation(200)[:150], True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SWEEP_CASES))
+def test_merge_sweep_builds_the_scatter_adds_buffer(case):
+    from elasticdl_tpu.ops.table_grad import sweep_table_grad
+
+    make_ids, exact = SWEEP_CASES[case]
+    rng = np.random.default_rng(7)
+    ids = jnp.asarray(make_ids(rng), jnp.int32)
+    num_rows = 200 if case == "rows_not_a_multiple_of_the_tile" else SWEEP_ROWS
+    rows = jnp.asarray(rng.standard_normal((ids.shape[0], 128)), jnp.float32)
+    expected = jnp.zeros((num_rows, 128), jnp.float32).at[ids].add(rows, mode="drop")
+    got = jax.jit(
+        lambda i, r: sweep_table_grad(i, r, num_rows, tile=64, chunk=128)
+    )(ids, rows)
+    if exact:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(expected))
+    else:
+        # Same addends, another order: f32 summation error of <= 300 terms.
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(expected), rtol=0, atol=300 * 2**-23 * 8
+        )
+
+
+@pytest.fixture
+def swept(monkeypatch):
+    """The choice the program makes on a TPU for a big table, made here for
+    a small one: the platform forced, the threshold lowered.  The kernel
+    then runs in the interpreter (the backend is still the CPU)."""
+    from elasticdl_tpu.ops import embedding
+
+    monkeypatch.setattr(embedding, "_on_tpu", lambda: True)
+    monkeypatch.setattr(embedding, "SWEEP_MIN_ROWS", 8)
+
+
+def _mosaic_calls(fn, *args) -> int:
+    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
+
+
+@pytest.mark.parametrize("dim", [11, 128])  # pack 8 in a stride of 16; pack 1
+def test_swept_gather_rows_gradient_is_the_transposes(monkeypatch, swept, dim):
+    from elasticdl_tpu.ops import embedding
+
+    rng = np.random.default_rng(3)
+    vocab = 8 * 256
+    table = pack_table(jnp.asarray(rng.standard_normal((vocab, dim)), jnp.float32), dim)
+    ids = jnp.asarray(
+        np.concatenate([rng.integers(0, vocab, 500), [-1, vocab * 9, 5, 5, 5]]), jnp.int32
+    )
+    cot = jnp.asarray(rng.standard_normal((ids.shape[0], dim)), jnp.float32)
+
+    def loss(t):
+        vec = gather_rows(t, ids, dim)
+        return jnp.sum(jnp.where(jnp.isnan(vec), 0.0, vec * cot))
+
+    got = jax.jit(jax.grad(loss))(table)
+    assert _mosaic_calls(jax.grad(loss), table) == 1
+    monkeypatch.setattr(embedding, "_on_tpu", lambda: False)
+    expected = jax.jit(jax.grad(loss))(table)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(expected), rtol=0, atol=2e-6)
+    assert float(jnp.abs(expected).max()) > 0.5
+
+
+@pytest.mark.parametrize("route", ["local", "dense", "ragged_emulated"])
+def test_swept_lookup_gradient_equals_the_transpose_on_every_route(
+    devices, monkeypatch, swept, route
+):
+    """``jax.grad`` through ``embedding_lookup`` with the sweep in the
+    backward against the same with the AD transpose, duplicates and an
+    out-of-vocabulary id included; four devices on the sharded routes."""
+    from elasticdl_tpu.ops import embedding
+
+    n = 1 if route == "local" else 4
+    mesh = create_mesh(devices, num_devices=n)
+    axis = mesh.axis_names[0]
+    dim, vocab = 11, 8 * 64 * n
+    rng = np.random.default_rng(5)
+    table = pack_table(jnp.asarray(rng.standard_normal((vocab, dim)), jnp.float32), dim)
+    ids = jnp.asarray(
+        np.concatenate([rng.integers(0, vocab, 120), [3] * 7, [vocab * 3]]), jnp.int32
+    )
+    cot = jnp.asarray(rng.standard_normal((ids.shape[0], dim)), jnp.float32)
+    ctx = ParallelContext(
+        axis_name=axis if n > 1 else None, sharded_embeddings=n > 1, embedding_impl=route
+        if n > 1 else "auto",
+    )
+
+    def local_loss(t, i, c):
+        vec = embedding_lookup(t, i, ctx, dim=dim)
+        return jnp.sum(jnp.where(jnp.isnan(vec), 0.0, vec * c))
+
+    def grad_fn():
+        if n == 1:
+            return jax.jit(jax.grad(local_loss))
+        return jax.jit(shard_map(
+            jax.grad(local_loss), mesh=mesh, in_specs=(P(axis),) * 3,
+            out_specs=P(axis), check_vma=False,
+        ))
+
+    got = grad_fn()(table, ids, cot)
+    assert "pallas_call" in str(jax.make_jaxpr(grad_fn())(table, ids, cot))
+    monkeypatch.setattr(embedding, "_on_tpu", lambda: False)
+    expected = grad_fn()(table, ids, cot)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(expected), rtol=0, atol=2e-6)
+    assert float(jnp.abs(expected).max()) > 0.5
+
+
+# (platform is a TPU, physical rows, width, dtype) -> Mosaic calls in the grad
+SELECTION = {
+    "big_table_on_a_tpu": (True, 3 << 19, 128, jnp.float32, 1),
+    "big_table_on_the_cpu": (False, 3 << 19, 128, jnp.float32, 0),
+    "small_table_on_a_tpu": (True, (3 << 19) - 256, 128, jnp.float32, 0),
+    "wide_rows_on_a_tpu": (True, 3 << 19, 256, jnp.float32, 0),
+    "bfloat16_table_on_a_tpu": (True, 3 << 19, 128, jnp.bfloat16, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECTION))
+def test_the_sweep_is_chosen_from_platform_and_table_shape(monkeypatch, case):
+    """One constant decides, read against the table's shape at trace time;
+    nothing is run (the tables are shapes)."""
+    from elasticdl_tpu.ops import embedding
+
+    on_tpu, rows, width, dtype, calls = SELECTION[case]
+    monkeypatch.setattr(embedding, "_on_tpu", lambda: on_tpu)
+    assert embedding.SWEEP_MIN_ROWS == 3 << 19
+    table = jax.ShapeDtypeStruct((rows, width), dtype)
+    ids = jax.ShapeDtypeStruct((64,), jnp.int32)
+
+    def grad(t, i):
+        return jax.grad(lambda t: jnp.sum(gather_rows(t, i, 16).astype(jnp.float32)))(t)
+
+    assert _mosaic_calls(grad, table, ids) == calls
+
+
+def test_integer_tables_keep_the_plain_gather(monkeypatch, swept):
+    table = jnp.arange(64 * 128, dtype=jnp.int32).reshape(64, 128)
+    out = gather_rows(table, jnp.array([3, 64, -1]), 128)
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(table[3]))
+    assert int(jnp.abs(out[1:]).max()) == 0

@@ -164,3 +164,46 @@ def test_route_rows_ride_the_step_metrics_on_the_ragged_route(devices):
         dense.init_state(jax.random.key(0)), dense.shard_stacked_batch(batch)
     )
     assert not [k for k in metrics if k.startswith("route_")]
+
+
+@pytest.mark.parametrize(
+    "n, impl, rows_a_step",
+    [
+        (1, "auto", 64 * 26),             # local gather: the ids inside the table
+        (4, "ragged_emulated", 64 * 26),  # every id reaches exactly one shard
+        (4, "dense", 4 * 64 * 26),        # every shard gathers every id
+    ],
+)
+def test_table_grad_rows_ride_the_step_metrics(devices, monkeypatch, n, impl, rows_a_step):
+    """Update rows offered to the table's gradient, and those of them the
+    merge sweep built: none on the CPU, all of them where the program
+    would choose the sweep (platform and threshold steered here; the
+    kernel itself runs in the interpreter)."""
+    from elasticdl_tpu.ops import embedding
+
+    rng = np.random.default_rng(0)
+    batch = {
+        "dense": rng.random((2, 64, 13)).astype(np.float32),
+        "cat": rng.integers(0, 2**31 - 1, (2, 64, 26)).astype(np.int32),
+        "labels": rng.integers(0, 2, (2, 64)).astype(np.int32),
+    }
+
+    def counts():
+        trainer = _trainer(devices, n, embedding_lookup_impl=impl)
+        _, metrics = trainer.train_scan(
+            trainer.init_state(jax.random.key(0)), trainer.shard_stacked_batch(batch)
+        )
+        return (
+            np.asarray(metrics["table_grad_rows"]).tolist(),
+            np.asarray(metrics["table_grad_rows_swept"]).tolist(),
+            np.asarray(metrics["loss"]),
+        )
+
+    rows, swept, loss = counts()
+    assert rows == [rows_a_step] * 2 and swept == [0, 0]
+    monkeypatch.setattr(embedding, "_on_tpu", lambda: True)
+    monkeypatch.setattr(embedding, "SWEEP_MIN_ROWS", 8)
+    rows, swept, swept_loss = counts()
+    assert rows == swept == [rows_a_step] * 2
+    # the second step's loss has been through one swept table gradient
+    np.testing.assert_allclose(swept_loss, loss, rtol=1e-6)

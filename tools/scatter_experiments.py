@@ -1,7 +1,12 @@
 """Backward-path (table-gradient) formulation experiments on the live chip.
 
 The round-3 profile (docs/perf.md) puts the embedding scatter-add at 2.85 ms
-— 42% of the DeepFM step — at ~13 ns per touched row, op-rate-bound.  This
+— 42% of the DeepFM step — at ~13 ns per touched row, op-rate-bound: measured
+at THIS file's table, P = 106,496 physical rows (54.5 MB).  The cost a row
+is not a constant of the op: into the benchmark's 1.31 GB table the same
+scatter-add is 74 ns a row, and from ``ops/embedding.SWEEP_MIN_ROWS`` rows
+on the program builds the buffer by a sorted merge sweep instead
+(``ops/table_grad.py``; PERF.md, PR 27, has both at every size).  This
 tool measures candidate reformulations of JUST the backward table-grad
 computation, trace-derived like tools/gather_experiments.py:
 
