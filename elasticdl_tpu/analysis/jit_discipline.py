@@ -175,8 +175,8 @@ def declared_sites(sources: Sequence[SourceFile]) -> Dict[str, dict]:
     serving bucket count), and falls back to ``None`` only when truly
     unresolvable.  Stamped into the LINT artifact next to the jitsan
     runtime stats so the declared contract and the measured compile
-    counts live in one place (tools/bench_regress.py gates the two
-    against each other)."""
+    counts live in one place (tests/test_jitsan.py holds the two
+    against each other on the live tree)."""
     out: Dict[str, dict] = {}
 
     def visit_calls(body_owner, defaults: Dict[str, int]) -> None:

@@ -6,8 +6,8 @@ warm-standby splice-in, recovery time) was only testable by hoping real
 hardware misbehaved on cue.  This module is the supply side — a
 stdlib-only fault injector cheap enough to ride in every process, whose
 scheduled faults (kill a rank at a step, stall a prep, drop an RPC, delay
-a PS pull) turn "the gang survives churn" into a benchable, CI-checkable
-property (tools/chaos_bench.py; docs/robustness.md).
+a PS pull) turn "the gang survives churn" into a CI-checkable property
+(tests/test_chaos.py, tests/test_gang_deadline.py; docs/robustness.md).
 
 Design constraints, in order (grafttrace's, deliberately):
 
@@ -402,7 +402,7 @@ class ChaosInjector:
         # line is the audit of last resort: a kill's ring dies with its
         # process and a blacked-out (drop_rpc) process can never ship
         # its ring over a heartbeat — the pod LOG is the one channel a
-        # severed process still writes, and chaos_bench counts these
+        # severed process still writes, so a fleet drive can count these
         # lines as its injection audit.
         trace.instant(
             f"chaos:{fault.kind}", cat="chaos", point=point,

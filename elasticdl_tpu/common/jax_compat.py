@@ -48,8 +48,8 @@ def jit_donating(fun, donate_argnums=(0,), name=None, expected_variants=1):
     the ``donate_argnames`` world) lands here once instead of per call
     site.  Donation lets XLA alias the input state's buffers into the
     output state — without it every step holds two full copies of
-    params + optimizer state resident (measurable on CPU as peak-RSS
-    delta; tools/optshard_bench.py records the A/B).
+    params + optimizer state resident
+    (tests/test_trainer_allreduce.py pins the knob's off side).
 
     ``name=``/``expected_variants=`` declare the jitsan compile budget,
     exactly as in :func:`jit_compiled` — donation makes stable jit

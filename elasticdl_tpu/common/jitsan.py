@@ -38,8 +38,8 @@ that half, the locksan/racesan pattern:
 
 ``GRAFT_JITSAN_DUMP=<path>`` writes the per-name stats as JSON at
 process exit — ``tools/graftlint.py --artifact`` merges that file into
-the LINT artifact so ``bench_regress.py`` can gate compile counts
-against declared budgets across revisions.
+the LINT artifact beside the declared budgets; ``tests/test_jitsan.py``
+is what fails when a compile count passes its budget.
 
 Pure stdlib at import time (jax is imported only inside
 :func:`transfer_guard` when armed): importable by gauge/watch tooling

@@ -1,9 +1,8 @@
 """Bounded thread pool for intra-task parallel shard ingest.
 
-The r5 stage table (artifacts/ingest_stages_r05.json) pins the e2e bound on
-single-threaded host read+decode: ~574k examples/sec against a 910k
-device-step ceiling — the chip idles ~2/3 of each task waiting on one
-host core.  The codec stack is embarrassingly parallel WITHIN a task: the
+On the retired backend (round 5) single-threaded host read+decode bound
+the job: the chip idled about two thirds of each task waiting on one host
+core.  The codec stack is embarrassingly parallel WITHIN a task: the
 recordio bulk read, the C++ CRC check, and the C++ criteo decode all
 release the GIL, and every record decodes independently of its neighbors.
 This module owns the sub-task parallelism:

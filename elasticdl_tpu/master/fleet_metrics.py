@@ -10,16 +10,11 @@ the paper's elastic design is judged on while the job still runs:
 - **fleet examples/sec** — summed per-worker rate over a sliding window
   of each worker's cumulative ``edl_examples_trained_total`` (restart-
   tolerant: a counter that went backwards re-anchors its worker);
-- **goodput-under-churn** — the live twin of ``chaos_bench``'s stamped
-  ratio.  The bench divides a faulted run's examples/sec by a
-  shape-matched fault-free baseline; a live job has no parallel
-  baseline, so the stand-in denominator is the PEAK windowed rate this
-  very job has sustained (``edl_fleet_examples_per_sec_peak``) — during
-  a kill/stall the ratio dips exactly as the bench's does, and a healthy
-  steady state reads ~1.0.  When a committed device-ceiling record is
-  readable (``bench.py``'s artifact), ``edl_goodput_vs_ceiling`` is
-  stamped beside it — the "live examples/sec vs the device-ceiling
-  record" view;
+- **goodput-under-churn** — a faulted run's examples/sec over what the
+  same job sustains fault-free.  A live job has no parallel baseline,
+  so the denominator is the PEAK windowed rate this very job has
+  sustained (``edl_fleet_examples_per_sec_peak``): during a kill/stall
+  the ratio dips, and a healthy steady state reads ~1.0;
 - **per-rank gang-arrival lag** — seconds each rank trails the gang
   head's lockstep arrival (the r13 deadline's own signal, read live
   instead of post-hoc from a skip event);
@@ -37,9 +32,6 @@ jax-free (the master control plane contract).
 
 from __future__ import annotations
 
-import json
-import os
-import re
 from typing import Dict, Optional
 
 from elasticdl_tpu.common import gauge, locksan
@@ -47,48 +39,6 @@ from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.metrics import critical_path_seconds
 
 logger = get_logger("master.fleet_metrics")
-
-#: Where the committed bench records live (best-effort; absent on a
-#: deployed master, present in the repo checkout the benches run from).
-#: ``device_step_examples_per_sec_per_chip`` is bench.py's measured
-#: device ceiling — the denominator of the e2e-vs-ceiling story in
-#: docs/perf.md.
-ARTIFACTS_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "artifacts",
-)
-
-_BENCH_REV = re.compile(r"^bench_r(\d+)(?:_latest)?\.json$")
-
-
-def read_device_ceiling(artifacts_dir: str = ARTIFACTS_DIR) -> Optional[float]:
-    """The NEWEST committed device-step ceiling (examples/sec/chip), or
-    None.  Scans ``bench_r<NN>[_latest].json`` and takes the highest
-    revision carrying the key — pinning a filename would silently keep
-    dividing by an old record after the next bench round moves the
-    ceiling.  Best-effort by design: a live job without the repo's
-    artifacts still serves every other family."""
-    try:
-        names = os.listdir(artifacts_dir)
-    except OSError:
-        return None
-    best: Optional[float] = None
-    best_rev = -1
-    for name in names:
-        m = _BENCH_REV.match(name)
-        if not m or int(m.group(1)) < best_rev:
-            continue
-        try:
-            with open(os.path.join(artifacts_dir, name)) as f:
-                record = json.load(f)
-        except (OSError, ValueError):
-            continue
-        v = record.get("device_step_examples_per_sec_per_chip")
-        if isinstance(v, (int, float)) and not isinstance(v, bool):
-            rev = int(m.group(1))
-            if rev > best_rev or (rev == best_rev and float(v) > best):
-                best, best_rev = float(v), rev
-    return best
 
 
 class FleetMetrics:
@@ -106,7 +56,6 @@ class FleetMetrics:
         servicer,
         registry: Optional[gauge.Registry] = None,
         window_s: float = 30.0,
-        ceiling: Optional[float] = None,
     ):
         self._servicer = servicer
         self.registry = registry or gauge.Registry()
@@ -119,9 +68,6 @@ class FleetMetrics:
         self._envelopes: Dict[str, dict] = {}  # guarded-by: _lock
         self._rates = gauge.RateWindow(window_s=window_s)
         self._peak_rate = 0.0  # guarded-by: _lock
-        self._ceiling = (
-            ceiling if ceiling is not None else read_device_ceiling()
-        )
 
     # -- hot-path side (rides every Heartbeat/Report: bank, never walk) --
 
@@ -254,19 +200,8 @@ class FleetMetrics:
         ).set(peak)
         reg.gauge(
             "edl_goodput_under_churn",
-            "live fleet rate / peak fleet rate — the live twin of "
-            "chaos_bench's faulted-over-baseline ratio (1.0 = healthy)",
+            "live fleet rate / peak fleet rate (1.0 = healthy)",
         ).set(fleet_rate / peak if peak > 0 else 0.0)
-        if self._ceiling:
-            reg.gauge(
-                "edl_device_ceiling_examples_per_sec",
-                "committed device-step record (bench.py artifact)",
-            ).set(self._ceiling)
-            reg.gauge(
-                "edl_goodput_vs_ceiling",
-                "live fleet examples/sec over the committed device-step "
-                "ceiling",
-            ).set(fleet_rate / self._ceiling)
 
     #: Most-recently-updated DEPARTED workers whose envelopes stay
     #: servable (the r12 TRACE_DEPARTED_KEEP stance): a job-end or

@@ -201,15 +201,15 @@ class ProcessPodBackend(PodBackend):
 
     ``warm_standby=True`` keeps a small POOL of pre-booted spares parked:
     processes that have already paid python + jax + framework imports
-    (~13 s of the r4 25.7 s re-rendezvous, docs/perf.md) and wait on a
-    go-file for their worker id (worker.main standby mode).  ``start_pod``
-    adopts a spare when its environment matches and immediately refills
-    the pool, so a relaunch boots in restore+compile time instead of
-    import time.  ``standby_pool`` sizes it: 1 covers a lone failure; a
-    peer-death recovery relaunches TWO processes (the dead pod plus the
-    survivor's RESTART), so fleets that want both warm park 2.  A failure
-    burst beyond the pool falls back to cold spawns — spares are a latency
-    optimization, never a correctness dependency.
+    (about half of a re-rendezvous on the CPU harness, docs/perf.md) and
+    wait on a go-file for their worker id (worker.main standby mode).
+    ``start_pod`` adopts a spare when its environment matches and
+    immediately refills the pool, so a relaunch boots in restore+compile
+    time instead of import time.  ``standby_pool`` sizes it: 1 covers a
+    lone failure; a peer-death recovery relaunches TWO processes (the dead
+    pod plus the survivor's RESTART), so fleets that want both warm park 2.
+    A failure burst beyond the pool falls back to cold spawns — spares are
+    a latency optimization, never a correctness dependency.
 
     On a TPU host: every pod gets the launcher's environment and NO chip
     assignment, and a chip belongs to one process at a time, so the
@@ -343,7 +343,7 @@ class ProcessPodBackend(PodBackend):
                 logger.warning("could not link %s -> %s", link, spare_log)
         logger.info("adopted warm standby (pid %d) as %s", proc.pid, name)
         # Two instants, one moment: the standby lifecycle event and the
-        # splice-timeline stage chaos_bench decomposes recovery over
+        # splice-timeline stage recovery is decomposed over
         # (detect -> adopt -> reformed, docs/robustness.md).
         trace.instant("standby:adopt", cat="standby", pod=name, pid=proc.pid)
         trace.instant(
@@ -1195,8 +1195,8 @@ class PodManager:
                     self._slots[info.slot] = None
         if phase == PodPhase.FAILED:
             # The splice timeline's t0: the master KNOWS the pod is gone.
-            # chaos_bench decomposes recovery as detect -> adopt ->
-            # reformed -> trained-again from these master-clock instants
+            # Recovery decomposes as detect -> adopt -> reformed ->
+            # trained-again from these master-clock instants
             # (the dying worker's own chaos:kill instant never ships —
             # its buffer dies with it).
             trace.instant(

@@ -235,8 +235,8 @@ def model_spec(
     """depth=50 -> bottleneck stages (3,4,6,3); depth=14 (tests) -> (1,1,1,1).
 
     ``image_size=224, num_classes=1000, imagenet_stem=True`` is the
-    standard ImageNet ResNet-50 — the configuration MFU benchmarks use
-    (tools/bench_all.py 'resnet50_imagenet'); the CIFAR default matches
+    standard ImageNet ResNet-50 — the configuration MFU benchmarks use;
+    the CIFAR default matches
     BASELINE config #2.
     """
     stage_map = {50: (3, 4, 6, 3), 26: (2, 2, 2, 2), 14: (1, 1, 1, 1)}

@@ -34,8 +34,8 @@ runs in three phases over ``axis_index_groups``:
        bytes cut by the local fan-in);
     3. intra-host all-gather to reassemble the full reduced tensor.
 
-The result equals the flat psum up to float reduction order (the parity
-probe in tools/collective_bench.py stamps the max divergence).  Leaves
+The result equals the flat psum up to float reduction order
+(tests/test_collectives.py bounds the divergence).  Leaves
 below ``min_elems`` (loss scalars, metric means, masked counts) stay
 single flat collectives — three launches for an 8-byte scalar would be
 pure overhead.
@@ -375,8 +375,7 @@ def interhost_bytes_per_step(
     ``2 * (size/n_local) * (n_host-1)/n_host`` per replica.  Leaves
     below ``min_elems`` take the flat route either way.  This is the
     number the ``edl_collective_interhost_bytes_total`` gauge advances
-    by (the CPU harness has no real DCN to meter, so the artifact stamps
-    the model, labeled as such)."""
+    by (it is the model, not a meter: no DCN has been measured)."""
     if n_replicas <= 1:
         return 0
     total = 0.0

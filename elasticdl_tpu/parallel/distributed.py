@@ -54,14 +54,13 @@ class DistributedSpec:
     process_id: int
     # Coordination-service peer-death detection.  JAX's default (100 s)
     # dominates elastic recovery: a survivor blocked inside a collective on
-    # a dead peer sits there until THIS timeout aborts it (measured 83 s of
-    # a 99 s total re-rendezvous — tools/rendezvous_bench.py).  30 s is a
-    # 3.3x faster default that still tolerates heartbeat-thread starvation
+    # a dead peer sits there until THIS timeout aborts it (most of a
+    # re-rendezvous on the CPU harness, round 4).  30 s is a 3.3x shorter
+    # default that still tolerates heartbeat-thread starvation
     # on oversubscribed hosts (a 10 s bound produced FALSE peer-death under
     # 1-core CPU contention during XLA compiles: the coordinator declared a
     # live, compiling peer dead).  Dedicated TPU hosts can set
-    # --distributed_heartbeat_timeout_s=10 for the measured 25.7 s total
-    # re-rendezvous (docs/perf.md).
+    # --distributed_heartbeat_timeout_s=10 (docs/perf.md).
     heartbeat_timeout_s: float = 30.0
 
     @property

@@ -68,11 +68,11 @@ def _load() -> ctypes.CDLL:
             _lib_error = f"native lib unavailable: {e}"
             # Said ONCE, as an error: every ingest hot path (criteo/census
             # decode, bulk recordio reads, host stores) degrades to Python
-            # fallbacks that are ~80x slower (docs/perf.md) and the job
-            # still exits 0 — a profile-invisible collapse unless it is
-            # logged.  Subsequent calls fail fast on the cached error
-            # without re-logging; chip_smoke.py refuses a worker whose boot
-            # line says the library is missing.
+            # fallbacks that are ~80x slower (CPU harness; docs/perf.md)
+            # and the job still exits 0 — a profile-invisible collapse
+            # unless it is logged.  Subsequent calls fail fast on the
+            # cached error without re-logging; chip_smoke.py refuses a
+            # worker whose boot line says the library is missing.
             logger.error(
                 "%s — ingest/PS hot paths fall back to Python "
                 "implementations (~80x slower decode; see docs/perf.md)",
@@ -328,8 +328,9 @@ def criteo_decode_pre_native(
     (models/tabular.py hash_buckets + log_normalize) applied DURING the
     parse, emitting compact wire types — labels uint8, dense float16
     (log1p), cat uint16 in [0, buckets).  79 B/example vs the raw decode's
-    160 B: the host->device link is the e2e bottleneck on remote-attached
-    chips (docs/perf.md).  Requires buckets <= 65536."""
+    160 B: the host->device link was the e2e bottleneck on the retired
+    backend's remote-attached chip (docs/perf.md).  Requires buckets <=
+    65536."""
     lib = _load()
     buf = np.ascontiguousarray(buf, np.uint8)
     offsets = np.ascontiguousarray(offsets, np.int64)

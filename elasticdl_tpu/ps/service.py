@@ -287,7 +287,7 @@ class PSServer:
         # graftgauge (r14): pull/push rates + latency tails, live.  The
         # shard's own registry defaults to the process-default one so the
         # PS pod's /metrics endpoint (ps/main.py) serves everything the
-        # process records; in-process fleets (tests, serving_bench) pass
+        # process records; in-process fleets (tests) pass
         # their own instance to keep shards' families apart.  Updates are
         # O(1) counter/histogram ops — legal in the # hot-path handlers
         # (gauge-discipline); table row counts are a scrape-time collector.
@@ -313,8 +313,8 @@ class PSServer:
         # Message-size limits must cover production batches: a full 8192x26
         # dim-8 push is ~8.5 MB of frame, over gRPC's 4 MB default — the
         # server AND the client (PSClient) both raise the cap, or a
-        # realistic batch dies with RESOURCE_EXHAUSTED (found by
-        # tools/ps_bench.py at exactly the flagship batch shape).
+        # realistic batch dies with RESOURCE_EXHAUSTED (found at exactly
+        # the flagship batch shape).
         self._server = grpc.server(
             futures.ThreadPoolExecutor(max_workers),
             options=[

@@ -2,10 +2,10 @@
 
 Online traffic is zipfian: a small set of hot ids dominates the pull volume
 (the reference serves the same skew — "Elastic Model Aggregation with
-Parameter Service", PAPERS.md).  The PS host store sustains millions of
-rows/s but each pull pays an RPC round trip (tools/ps_bench.py quantifies
-it); caching the hot rows worker-side turns the steady-state embedding read
-into a dict hit and reserves the RPC for the cold tail.
+Parameter Service", PAPERS.md).  The PS host store is fast but each pull
+pays an RPC round trip; caching the hot rows worker-side turns the
+steady-state embedding read into a dict hit and reserves the RPC for the
+cold tail.
 
 Consistency contract:
 

@@ -7,7 +7,7 @@ same machinery, because r13–r18 already built it:
 
 - **Spawn/retire**: ``master/pod_manager.PodManager`` over a pluggable
   backend.  Subprocess replicas run ``python -m elasticdl_tpu.serving.main``
-  (ProcessPodBackend; warm-standby spares pre-pay the ~13 s jax import and
+  (ProcessPodBackend; warm-standby spares pre-pay the jax import and
   park on a go-file exactly like worker standbys), in-process replicas
   (:class:`InProcessServingBackend`) serve the tier-1 fleet smoke without
   subprocess boot costs.  A replica that crashes relaunches on the
@@ -169,8 +169,8 @@ class InProcessServingBackend(PodBackend):
     """Serving replicas as ServingServer instances IN THIS PROCESS.
 
     The tier-1 fleet smoke's backend: subprocess replicas each pay the
-    full python + jax boot (~13 s on this box) before their first answer,
-    which is bench territory, not CI.  ``server_factory(slot)`` builds and
+    full python + jax boot before their first answer, too slow for
+    tier-1.  ``server_factory(slot)`` builds and
     RETURNS A STARTED, WARMED server (jax stays an implementation detail
     of the factory — this module is import-time jax-free); the backend
     maps pod lifecycle onto it and reports real bound addresses, so the

@@ -27,8 +27,7 @@ ROADMAP item 4: everything before r10 was training-side; this server is the
   watcher thread CONCURRENT with serving; the cutover is one reference
   swap under a leaf lock plus a cache invalidation.  In-flight flushes
   hold the snapshot they started with — no request is ever dropped or
-  drained for a reload (tools/serving_bench.py measures the swap at
-  microseconds and stamps it).
+  drained for a reload (tests/test_serving.py reloads under traffic).
 
 Wire contract: JSON-over-gRPC like the master (``common/rpc.py``
 SERVING_SCHEMAS — Predict / ModelInfo).  Online requests are a handful of

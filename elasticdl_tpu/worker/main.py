@@ -69,13 +69,14 @@ def build_job_reader(config: JobConfig) -> AbstractDataReader:
 
 def _park_as_standby(go_file: str) -> str:
     """Warm-standby mode (ELASTICDL_STANDBY_GO_FILE): pre-pay the boot tail
-    — python + jax + framework imports, ~13 s of the r4 re-rendezvous
-    (docs/perf.md) — then park until the pod manager writes the go file
-    naming the worker id this process should become.  Nothing here may
-    touch a jax *backend* (devices/compile): in multihost mode the backend
-    must first bind to the jax.distributed world formed AFTER registration,
-    and on a TPU host the live worker holds the chip — a spare that opened
-    a backend while parked would fail or hang, or take the chip from it.
+    — python + jax + framework imports, about half of a re-rendezvous on
+    the CPU harness (docs/perf.md) — then park until the pod manager
+    writes the go file naming the worker id this process should become.
+    Nothing here may touch a jax *backend* (devices/compile): in multihost
+    mode the backend must first bind to the jax.distributed world formed
+    AFTER registration, and on a TPU host the live worker holds the chip —
+    a spare that opened a backend while parked would fail or hang, or take
+    the chip from it.
     Returns the assigned worker id."""
     import importlib
 
@@ -138,8 +139,8 @@ def settle_membership(
     gate, staggered relaunches form worlds one member at a time; without
     the confirmation gate, a fresh relaunch forms a world with a STALE
     incarnation that is about to restart — each late restart then
-    restarts everyone who already formed (measured 54 s of churn on a
-    2-pod peer-death recovery before these gates; docs/perf.md).  Fall
+    restarts everyone who already formed (tripling a 2-pod peer-death
+    recovery on the CPU harness before these gates; docs/perf.md).  Fall
     back to the version-stability heuristic when the master doesn't
     publish a target (hand-spawned workers), and proceed with whoever is
     present at the deadline either way: a crash-looping peer must degrade

@@ -5,8 +5,9 @@ jax.distributed coordination heartbeat (default 30 s) before restarting.
 ``Worker.death_watch_tick`` — run from the liveness-heartbeat thread —
 polls the master's membership and forces the RESTART exit within the grace
 window of the master's eviction.  These tests drive the decision function
-directly with a fake master; the real-process path is measured by
-tools/rendezvous_bench.py.
+directly with a fake master; the real-process path is driven by
+tests/test_multihost.py (slow) and, on the chip, by
+benchmark/sizing/kill_drive.py.
 """
 
 from __future__ import annotations

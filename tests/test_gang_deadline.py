@@ -1,7 +1,6 @@
 """Deadline-bounded gang boundary (r13): dispatcher skip accounting and
 the servicer's straggler-skip protocol, driven with a fake clock so the
-deadline mechanics are deterministic.  The subprocess-gang twin lives in
-tools/chaos_bench.py's stall fleet."""
+deadline mechanics are deterministic."""
 
 import pytest
 

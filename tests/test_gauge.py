@@ -1,6 +1,5 @@
 """graftgauge (r14): registry semantics, Prometheus exposition, fleet
-aggregation, heartbeat envelope compat, live endpoints, and the
-bench_regress trajectory gate.
+aggregation, heartbeat envelope compat and live endpoints.
 
 The concurrency tests assert EXACT totals — the registry's counters back
 the goodput computer, and an approximate examples-trained count would
@@ -355,42 +354,6 @@ def test_master_aggregation_two_worker_fleet():
     assert health["workers_reporting"] == ["w0", "w1"]
 
 
-def test_read_device_ceiling_takes_newest_rev(tmp_path):
-    from elasticdl_tpu.master.fleet_metrics import read_device_ceiling
-
-    d = str(tmp_path)
-    for name, v in (
-        ("bench_r05.json", 100.0),
-        ("bench_r05_latest.json", 90.0),
-        ("bench_r07_latest.json", 250.0),  # newest rev wins, even if lower
-        ("other_r09.json", 999.0),         # wrong family: ignored
-    ):
-        with open(os.path.join(d, name), "w") as f:
-            json.dump({"device_step_examples_per_sec_per_chip": v}, f)
-    assert read_device_ceiling(d) == 250.0
-    assert read_device_ceiling(os.path.join(d, "absent")) is None
-
-
-def test_goodput_vs_ceiling_uses_committed_record():
-    servicer = _servicer()
-    # Pin the ceiling instead of reading the repo artifact: the unit is
-    # the ratio arithmetic, not the file layout.
-    servicer.fleet._ceiling = 1000.0
-    reg = gauge.Registry()
-    c = reg.counter(gauge.EXAMPLES_TRAINED)
-    c.inc(0)
-    servicer.Heartbeat({"worker_id": "w0", "gauge": {"families": reg.snapshot()}})
-    time.sleep(0.2)
-    c.inc(100)
-    servicer.Heartbeat({"worker_id": "w0", "gauge": {"families": reg.snapshot()}})
-    snap = servicer.fleet.registry.snapshot()
-    ceiling = snap["edl_device_ceiling_examples_per_sec"]["samples"][0]["value"]
-    ratio = snap["edl_goodput_vs_ceiling"]["samples"][0]["value"]
-    rate = snap["edl_fleet_examples_per_sec"]["samples"][0]["value"]
-    assert ceiling == 1000.0
-    assert ratio == pytest.approx(rate / 1000.0)
-
-
 def test_remove_collector_unhooks_and_tolerates_absent():
     reg = gauge.Registry()
     calls = []
@@ -722,154 +685,6 @@ def test_worker_families_match_the_naming_table_after_a_job(tmp_path, devices):
 
 
 # ---------------------------------------------------------------------------
-# bench_regress: the trajectory gate
-# ---------------------------------------------------------------------------
-
-def _write(repo, name, payload):
-    os.makedirs(os.path.join(repo, "artifacts"), exist_ok=True)
-    with open(os.path.join(repo, "artifacts", name), "w") as f:
-        json.dump(payload, f)
-
-
-class TestBenchRegress:
-    def _bench(self, value, pipeline=None, platform="cpu"):
-        d = {
-            "metric": "deepfm_criteo_e2e_examples_per_sec_per_chip",
-            "value": value,
-            "jax_platforms": platform,
-        }
-        if pipeline is not None:
-            d["pipeline"] = pipeline
-        return d
-
-    def test_pass_improvement(self, tmp_path):
-        from tools.bench_regress import build_trajectory, index_artifacts
-
-        repo = str(tmp_path)
-        _write(repo, "bench_r05.json", self._bench(100.0))
-        _write(repo, "bench_r06.json", self._bench(150.0))
-        t = build_trajectory(index_artifacts(repo), 10.0)
-        (series,) = [
-            s for s in t["series"] if s["name"] == "e2e_examples_per_sec_per_chip"
-        ]
-        assert series["status"] == "ok"
-        assert series["latest_delta_pct"] == pytest.approx(50.0)
-        assert t["regressions"] == []
-
-    def test_fail_regression_and_exit_code(self, tmp_path):
-        from tools.bench_regress import main as regress_main
-
-        repo = str(tmp_path)
-        _write(repo, "bench_r05.json", self._bench(100.0))
-        _write(repo, "bench_r06.json", self._bench(80.0))  # -20%
-        rc = regress_main(["--repo", repo, "--threshold", "10"])
-        assert rc == 1
-        with open(os.path.join(repo, "artifacts", "TRAJECTORY.json")) as f:
-            trajectory = json.load(f)
-        assert trajectory["regressions"]
-        r = trajectory["regressions"][0]
-        assert r["delta_pct"] == pytest.approx(-20.0)
-
-    def test_threshold_tolerates_weather(self, tmp_path):
-        from tools.bench_regress import build_trajectory, index_artifacts
-
-        repo = str(tmp_path)
-        _write(repo, "bench_r05.json", self._bench(100.0))
-        _write(repo, "bench_r06.json", self._bench(95.0))  # -5%
-        t = build_trajectory(index_artifacts(repo), 10.0)
-        assert t["regressions"] == []
-        t = build_trajectory(index_artifacts(repo), 3.0)
-        assert len(t["regressions"]) == 1
-
-    def test_lower_is_better_direction(self, tmp_path):
-        from tools.bench_regress import build_trajectory, index_artifacts
-
-        repo = str(tmp_path)
-        point = {"offered_qps": 50.0, "p99_ms": 20.0}
-        _write(repo, "SERVE_r10.json", {
-            "metric": "serving_latency_vs_qps", "points": [point],
-        })
-        _write(repo, "SERVE_r11.json", {
-            "metric": "serving_latency_vs_qps",
-            "points": [{"offered_qps": 50.0, "p99_ms": 40.0}],  # 2x worse
-        })
-        t = build_trajectory(index_artifacts(repo), 10.0)
-        assert len(t["regressions"]) == 1
-        assert t["regressions"][0]["name"] == "p99_ms[qps50.0]"
-
-    def test_config_change_skips_comparison(self, tmp_path):
-        from tools.bench_regress import build_trajectory, index_artifacts
-
-        repo = str(tmp_path)
-        _write(repo, "bench_r05.json",
-               self._bench(100.0, pipeline={"lease_batch": 4}))
-        _write(repo, "bench_r06.json",
-               self._bench(50.0, pipeline={"lease_batch": 1}))
-        t = build_trajectory(index_artifacts(repo), 10.0)
-        (series,) = [
-            s for s in t["series"] if s["name"] == "e2e_examples_per_sec_per_chip"
-        ]
-        assert series["status"] == "config_changed"
-        assert t["regressions"] == []
-
-    def test_missing_config_key_is_unconstrained(self, tmp_path):
-        from tools.bench_regress import build_trajectory, index_artifacts
-
-        repo = str(tmp_path)
-        _write(repo, "bench_r05.json", self._bench(100.0))  # pre-pipeline rev
-        _write(repo, "bench_r06.json",
-               self._bench(150.0, pipeline={"lease_batch": 4}))
-        t = build_trajectory(index_artifacts(repo), 10.0)
-        (series,) = [
-            s for s in t["series"] if s["name"] == "e2e_examples_per_sec_per_chip"
-        ]
-        assert series["status"] == "ok"
-
-    def test_same_rev_keeps_direction_best(self, tmp_path):
-        from tools.bench_regress import build_trajectory, index_artifacts
-
-        repo = str(tmp_path)
-        _write(repo, "bench_r05.json", self._bench(100.0))
-        _write(repo, "bench_r05_latest.json", self._bench(120.0))
-        _write(repo, "bench_r06.json", self._bench(115.0))
-        t = build_trajectory(index_artifacts(repo), 10.0)
-        (series,) = [
-            s for s in t["series"] if s["name"] == "e2e_examples_per_sec_per_chip"
-        ]
-        # 115 vs the r5 RECORD (120), within threshold: ok, slight dip.
-        assert series["status"] == "ok"
-        assert series["points"][0]["value"] == 120.0
-
-    def test_committed_repo_trajectory_is_nonempty_and_clean(self):
-        from tools.bench_regress import build_trajectory, index_artifacts
-
-        t = build_trajectory(index_artifacts(), 10.0)
-        assert t["series"], "the committed artifacts must index"
-        assert t["compared"] >= 2, "gang_ingest r06->r09 must compare"
-        assert t["regressions"] == []
-
-    def test_unreadable_and_own_output_skipped(self, tmp_path):
-        from tools.bench_regress import index_artifacts
-
-        repo = str(tmp_path)
-        _write(repo, "bench_r05.json", self._bench(100.0))
-        _write(repo, "TRAJECTORY.json", {"metric": "cross_rev_perf_trajectory"})
-        with open(os.path.join(repo, "artifacts", "broken_r01.json"), "w") as f:
-            f.write("{not json")
-        entries = index_artifacts(repo)
-        assert [e["file"] for e in entries] == ["artifacts/bench_r05.json"]
-
-    def test_parse_name_variants(self):
-        from tools.bench_regress import parse_name
-
-        assert parse_name("gang_ingest_r09.json") == ("gang_ingest", 9)
-        assert parse_name("LINT_r14.json") == ("LINT", 14)
-        assert parse_name("bench_r05_latest.json") == ("bench", 5)
-        assert parse_name("ps_bench_r10.json") == ("ps_bench", 10)
-        assert parse_name("TRAJECTORY.json") == ("TRAJECTORY", 0)
-
-
-# ---------------------------------------------------------------------------
 # locksan contention bridge (r16): edl_lock_acquire_total / edl_lock_wait_ms
 # ---------------------------------------------------------------------------
 
@@ -940,25 +755,3 @@ class TestLockContentionGauges:
         with pytest.raises(ValueError):
             h.load_snapshot({"edges": [1.0], "counts": [0, 0], "sum": 0.0,
                              "count": 0})
-
-
-class TestLintTrajectorySeries:
-    def test_lint_findings_series_and_zero_baseline_gate(self, tmp_path):
-        from tools.bench_regress import build_trajectory, index_artifacts
-
-        repo = str(tmp_path)
-        # Old LINT artifacts predate the "metric" key: the family fallback
-        # must index them so the lint-debt series spans revisions.
-        _write(repo, "LINT_r15.json", {"findings": 0})
-        _write(repo, "LINT_r16.json", {"metric": "lint_findings", "findings": 0})
-        t = build_trajectory(index_artifacts(repo), 10.0)
-        (series,) = [s for s in t["series"] if s["family"] == "LINT"]
-        assert series["direction"] == "lower"
-        assert [p["value"] for p in series["points"]] == [0.0, 0.0]
-        assert t["regressions"] == []
-        # Any climb off the zero baseline is a regression outright.
-        _write(repo, "LINT_r17.json", {"metric": "lint_findings", "findings": 2})
-        t = build_trajectory(index_artifacts(repo), 10.0)
-        (series,) = [s for s in t["series"] if s["family"] == "LINT"]
-        assert series["status"] == "REGRESSED"
-        assert t["regressions"]

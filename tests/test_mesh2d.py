@@ -220,6 +220,28 @@ def test_tp_weights_are_sharded_and_bytes_drop(devices):
     assert b2["resolved"] < b1["resolved"]
 
 
+@pytest.fixture(scope="module")
+def resolved_bytes_by_tp(devices):
+    """Resolved grad-reduce bytes of the (dp, tp) factorizations of 8."""
+    cfg = JobConfig(distribution_strategy="AllReduce")
+    out = {}
+    for tp in (1, 2, 4, 8):
+        t = Trainer(_tp_spec(n_heads=8, dim=64), cfg,
+                    create_mesh(devices, num_devices=8, tensor_parallelism=tp))
+        out[tp] = t.collective_bytes_per_step(t.init_state(jax.random.key(0)))["resolved"]
+    return out
+
+
+@pytest.mark.parametrize("tp_lo,tp_hi", [(1, 2), (2, 4), (4, 8)])
+def test_resolved_bytes_fall_as_tp_rises(resolved_bytes_by_tp, tp_lo, tp_hi):
+    """The grad reduce runs over dp only and each rank reduces 1/tp of
+    every tp-sharded leaf, so the resolved inter-host bytes fall strictly
+    as tp rises, down to none at dp = 1 — the traffic the 2D layout exists
+    to not move."""
+    assert resolved_bytes_by_tp[tp_hi] < resolved_bytes_by_tp[tp_lo]
+    assert resolved_bytes_by_tp[8] == 0
+
+
 # ---- the elastic 2D re-partitioner ----
 
 

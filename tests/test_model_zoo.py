@@ -158,7 +158,7 @@ def test_resnet50_builds():
 
 def test_resnet_imagenet_stem_variant(devices):
     """The ImageNet-shaped configuration (224x224 input, 1000-class head,
-    7x7/s2 stem + maxpool — tools/bench_all.py 'resnet50_imagenet') trains
+    7x7/s2 stem + maxpool) trains
     a step at a reduced size: the stride-2 stem halves the spatial dims
     twice before the stages, and the head width follows num_classes."""
     spec = load_model_spec(

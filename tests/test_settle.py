@@ -1,7 +1,7 @@
 """Gang-formation settle protocol (worker.main.settle_membership).
 
-The pod-event recovery bench (tools/rendezvous_bench.py pod) measured 54 s
-of restart churn when staggered relaunches formed worlds one member at a
+On the CPU harness a 2-pod recovery churned through restart after restart
+when staggered relaunches formed worlds one member at a
 time or with stale incarnations; the settle gates (desired size + per-
 member version confirmation) fixed it.  These tests drive the extracted
 loop against the REAL RendezvousServer with scripted peer actions and a

@@ -209,6 +209,19 @@ def test_sharded_optimizer_matches_replicated(spec, devices):
         np.testing.assert_allclose(a, b, rtol=2e-6, atol=1e-7)
 
 
+@pytest.mark.parametrize("dp", [2, 8])
+def test_sharded_optimizer_bytes_per_replica(spec, devices, dp):
+    """The memory claim at the other widths (4-way is asserted above):
+    each replica holds at most 1/dp of the param-shaped optimizer slots
+    plus padding, where the replicated layout holds a full copy."""
+    mesh = create_mesh(devices, num_devices=dp)
+    tr = Trainer(spec, JobConfig(), mesh)
+    ts = Trainer(spec, JobConfig(optimizer_sharding="sharded"), mesh)
+    rep = max(tr.opt_state_bytes_per_device(tr.init_state(jax.random.key(0))).values())
+    sh = max(ts.opt_state_bytes_per_device(ts.init_state(jax.random.key(0))).values())
+    assert sh <= rep / dp * 1.05 + 1024
+
+
 def test_sharded_train_scan_matches_step_loop(spec, devices):
     """The fused lax.scan task must carry the FLAT sharded optimizer state
     through its scan body identically to per-step dispatch."""

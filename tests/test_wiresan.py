@@ -270,6 +270,9 @@ def test_version_skew_roundtrip_real_grpc():
     assert verdict["ok"], verdict["errors"]
     assert verdict["tasks_done"] == 4
     assert verdict["wire_violations"] == 0
+    # A masked (older) peer drops fields, it never invents them: nothing
+    # the current master receives is outside its schema.
+    assert verdict["wiresan"]["unknown_fields"] == {}
     assert verdict["job_status"]["duplicate_done"] == 0
     assert verdict["job_status"]["stale_reports"] == 0
     assert verdict["job_status"]["finished"] is True

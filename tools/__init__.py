@@ -1,5 +1,6 @@
-"""Operational tools: benches, profilers, experiment harnesses.
+"""Operational tools: the lint driver, the sanitizer matrices, trace and
+job viewers.
 
-Importable as a package so bench.py can reuse tools/bench_e2e.py; each tool
-also runs standalone (``python tools/<name>.py``).
+Importable as a package (tests import them); each tool also runs
+standalone (``python tools/<name>.py``).
 """

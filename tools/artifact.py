@@ -1,9 +1,10 @@
-"""Shared number-of-record artifact writer for the bench tools.
+"""Shared artifact writer for the tools that stamp a file under
+``artifacts/`` (graftlint, crashsan_matrix, wire_skew).
 
-Every perf tool commits its measurement as a JSON file under
-``artifacts/`` stamped with the command line and UTC time (docs/perf.md
-quotes the files; VERDICT r4 Next #5).  One definition so the write idiom
-— env override, directory creation, stamping — cannot drift per tool.
+The file carries counts and findings, stamped with the command line and
+UTC time; speed lives in ``PERF.md`` and ``PERF_LEDGER.jsonl``.  One
+definition so the write idiom — env override, directory creation,
+stamping — cannot drift per tool.
 """
 
 from __future__ import annotations
@@ -15,38 +16,11 @@ from typing import Callable, Optional, Sequence
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-#: bf16 peak FLOP/s per chip, keyed by ``device_kind`` as JAX reports it.
-#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16).
-PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
-
-
-def peak_bf16_flops(device: dict) -> float:
-    """The bf16 peak of the chip a bench ran on, from the
-    ``common/platform.device_summary()`` of the process that ran it.  A
-    measurement path that finds no TPU fails instead of reporting a host
-    number under a device metric's name, and a chip that is not in the
-    table is an error, not a default: a utilization computed against
-    another chip's peak is a wrong number under a right name."""
-    if device["platform"] != "tpu":
-        raise RuntimeError(
-            f"this bench measures the chip; the backend that answered is "
-            f"{device['platform']!r} ({device['device_kind']})"
-        )
-    if device["device_kind"] not in PEAK_BF16_FLOPS:
-        raise KeyError(
-            f"no bf16 peak recorded for device_kind "
-            f"{device['device_kind']!r}; add it to "
-            "tools/artifact.PEAK_BF16_FLOPS with its source"
-        )
-    return PEAK_BF16_FLOPS[device["device_kind"]]
-
-
 def code_rev(repo: Optional[str] = None) -> str:
     """Commit hash of the code producing an artifact (best-effort).
 
-    Stamped into bench/lint artifacts so trend consumers (and bench.py's
-    best-run-wins record guard) can tell "another run of the same code"
-    from "the first run of NEW code".  A dirty tree gets a "-dirty" suffix
+    Stamped into artifacts so a reader can tell "another run of the same
+    code" from "the first run of NEW code".  A dirty tree gets a "-dirty" suffix
     — uncommitted changes are NEW code under the same HEAD, and two dirty
     runs may differ from each other too, so dirty never matches anything.
     Untracked files count as dirt: a new not-yet-added module is importable
@@ -78,9 +52,8 @@ def code_rev(repo: Optional[str] = None) -> str:
 class ArtifactRun:
     """Capture ``code_rev`` at TOOL ENTRY and stamp it at write time.
 
-    The pattern c5125b1 fixed by hand in straggler_report.py, made
-    un-regressable: a tool whose RUN rewrites committed outputs (merged
-    traces, prior artifacts) dirties its own tree, so a stamp-time
+    A tool whose RUN rewrites committed outputs (prior artifacts)
+    dirties its own tree, so a stamp-time
     ``code_rev()`` would mark every artifact "-dirty" from the tool's OWN
     output files.  The code that produced the measurement is the tree as
     it stood on entry — construct one of these FIRST, write through it
@@ -108,7 +81,7 @@ class ArtifactRun:
 
 #: Shared log-spaced histogram bucket edges (MILLISECONDS) for
 #: ``latency_stats(..., buckets=True)``.  One FIXED grid across every
-#: artifact (serving_bench, ps_bench, straggler_report) so tail shapes are
+#: report (straggler_report) so tail shapes are
 #: comparable file to file and round to round — per-run adaptive edges
 #: would make two artifacts' histograms incomparable.  Canonical home is
 #: ``common/gauge.py`` since r14: the LIVE registry histograms bucket on
@@ -122,9 +95,8 @@ def latency_stats(
     samples_ms: Sequence[float], prefix: str = "", buckets=None
 ) -> dict:
     """p50/p99/mean/max over per-request latencies in MILLISECONDS — the
-    one definition every latency consumer (ps_bench, serving_bench,
-    straggler_report) stamps, so percentile conventions cannot drift per
-    tool.  Empty input returns {} (a point with zero completed requests has
+    one definition every latency consumer (straggler_report) uses, so
+    percentile conventions cannot drift per tool.  Empty input returns {} (a point with zero completed requests has
     no latency distribution; callers report their error tallies instead).
 
     ``buckets``: True for the shared ``DEFAULT_BUCKET_EDGES_MS`` grid, or
@@ -180,9 +152,9 @@ def write_artifact(
         or os.path.join(_REPO_ROOT, "artifacts", default_name)
     )
     # Atomic since r21 (durable.atomic_publish): a tool killed mid-stamp
-    # used to leave a truncated JSON file that bench_regress parses as a
-    # corrupt artifact — a number of record must commit whole or not at
-    # all, same as any durable state.
+    # used to leave a truncated JSON file where graftlint expects a whole
+    # one — an artifact must commit whole or not at all, same as any
+    # durable state.
     from elasticdl_tpu.common import durable
 
     durable.atomic_publish_json(

@@ -28,9 +28,9 @@ class (docs/robustness.md "Durability contracts"):
 
 Anything else — records that are not a prefix, an unexpected exception,
 silent acceptance of mid-file garbage — is an UNRECOVERED crash point and
-fails the row.  ``tools/bench_regress.py`` gates the summary's
-``unrecovered`` count at zero via the LINT artifact merge
-(tools/graftlint.py --artifact picks up artifacts/crashsan_matrix.json).
+fails the row (exit 1 here; ``tests/test_crashsan.py`` asserts the same
+matrix in-process).  ``tools/graftlint.py --artifact`` copies the
+summary from artifacts/crashsan_matrix.json into the LINT stamp.
 
 Usage:
     python tools/crashsan_matrix.py            # print summary, exit 1 on

@@ -316,8 +316,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         # v6 jitsan section: the statically declared name/budget table,
         # plus the runtime lowering counts when a jitsan-armed run left a
         # dump (env JITSAN_STATS overrides the default path).  The
-        # bench_regress trajectory gate reads the runtime half: any
-        # compile count past its declared budget gates outright.
+        # budget itself is held live by tests/test_jitsan.py: a compile
+        # count past its declared budget fails there.
         stats_path = os.environ.get(
             "JITSAN_STATS", os.path.join(_REPO_ROOT, JITSAN_STATS_DEFAULT)
         )
@@ -361,7 +361,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         # v7 crashsan section: the matrix driver's summary (crash points
         # injected / recovered / contract class per scenario) when a run
         # left one (env CRASHSAN_MATRIX overrides the default path).
-        # bench_regress gates crashsan_unrecovered at zero.
+        # tests/test_crashsan.py holds unrecovered at zero, in-process.
         matrix_path = os.environ.get(
             "CRASHSAN_MATRIX",
             os.path.join(_REPO_ROOT, CRASHSAN_MATRIX_DEFAULT),
@@ -378,8 +378,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         # v8 wire section: the static inventory (methods, schemas,
         # resolved sender/receiver sites) plus the version-skew roundtrip
         # verdict when a tools/wire_skew.py run left one (env WIRE_SKEW
-        # overrides the default path).  bench_regress gates
-        # wire_unknown_fields at zero alongside the finding counts.
+        # overrides the default path).  tests/test_wiresan.py's
+        # run_skew holds unknown fields at zero, and the repo-clean run
+        # in tests/test_graftlint.py the finding counts.
         from elasticdl_tpu.analysis.wire_discipline import wire_inventory
 
         skew_path = os.environ.get(
@@ -403,8 +404,8 @@ def main(argv: Optional[List[str]] = None) -> int:
 
         write_artifact(
             {
-                # The trajectory gate (tools/bench_regress.py) indexes
-                # this family by findings count, direction=down.
+                # A stamp for a reader of the tree; what holds the count
+                # at zero is tests/test_graftlint.py's repo-clean run.
                 "metric": "lint_findings",
                 "findings": len(findings),
                 "by_rule": dict(sorted(by_rule.items())),

@@ -1,5 +1,5 @@
 """straggler_report — per-rank gang-boundary wait skew and per-phase
-p50/p99 from a merged grafttrace, stamped as a perf artifact.
+p50/p99 from a merged grafttrace.
 
 ROADMAP item 3 (tail tolerance) needs stragglers as a RECORDED number
 before anything can sacrifice or route around them: OptiReduce-style
@@ -17,14 +17,8 @@ timing visibility.  This tool turns the merged cross-process trace
   already ships.
 
 Modes:
-    python tools/straggler_report.py --trace merged.json [--artifact [PATH]]
-    python tools/straggler_report.py --raw dump.json     [--artifact [PATH]]
-    python tools/straggler_report.py --run-gang 2        [--tasks 8]
-        drive a REAL 2-worker lockstep gang (tools/multiworker_bench.py's
-        ingest fleet) with --trace on, dump + merge it (the merged file is
-        itself committed: artifacts/trace_gang_r12.json), run the ingest
-        trace-overhead A/B, and stamp artifacts/TRACE_r12.json with skew +
-        per-phase stats + measured overhead.
+    python tools/straggler_report.py --trace merged.json
+    python tools/straggler_report.py --raw dump.json
 """
 
 from __future__ import annotations
@@ -38,9 +32,6 @@ from typing import Dict, List, Optional
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
-
-ARTIFACT_NAME = "TRACE_r12.json"
-MERGED_TRACE_NAME = "trace_gang_r12.json"
 
 
 def analyze(merged: dict) -> dict:
@@ -138,50 +129,6 @@ def _merged_from_args(args) -> dict:
         return merge(json.load(f))
 
 
-def run_gang(n_workers: int, n_tasks: int, log) -> dict:
-    """Drive a real lockstep gang with tracing on; return the analysis plus
-    bench figures, and leave the merged trace in artifacts/."""
-    import tempfile
-
-    # multiworker_bench pins this (jax-free) process and the worker env to
-    # cpu at import; the gang runs exactly like the r9 ingest bench.
-    from tools.multiworker_bench import _run_ingest_fleet
-    from tools.trace_dump import merge
-
-    tmp = tempfile.mkdtemp(prefix="straggler_")
-    raw_path = os.path.join(tmp, "dump_raw.json")
-    fleet = _run_ingest_fleet(
-        n_workers, n_tasks, tmp, log, trace_dump_raw=raw_path,
-    )
-    if not os.path.exists(raw_path):
-        # The bench swallows dump-write failures by design (a failed dump
-        # must not fail the BENCH) — but for THIS caller the dump IS the
-        # product: fail with the real cause, not a bare FileNotFoundError
-        # after a multi-minute run.
-        raise RuntimeError(
-            f"gang run finished but wrote no trace dump at {raw_path} — "
-            "see the bench log above for the swallowed dump error"
-        )
-    with open(raw_path) as f:
-        dump = json.load(f)
-    merged = merge(dump)
-    merged_path = os.path.join(_REPO_ROOT, "artifacts", MERGED_TRACE_NAME)
-    os.makedirs(os.path.dirname(merged_path), exist_ok=True)
-    with open(merged_path, "w") as f:
-        json.dump(merged, f)
-    log(f"merged Perfetto trace -> {merged_path} "
-        f"({len(merged['traceEvents'])} events)")
-    report = analyze(merged)
-    report["gang"] = {
-        "workers": fleet["workers"],
-        "examples_per_sec": fleet["examples_per_sec"],
-        "tasks_total": fleet["tasks_total"],
-        "merged_trace": os.path.relpath(merged_path, _REPO_ROOT),
-        "merged_events": len(merged["traceEvents"]),
-    }
-    return report
-
-
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="straggler_report", description=__doc__,
@@ -189,59 +136,11 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     ap.add_argument("--trace", default="", help="merged Chrome-trace JSON")
     ap.add_argument("--raw", default="", help="raw DumpTrace response JSON")
-    ap.add_argument(
-        "--run-gang", type=int, default=0, metavar="N",
-        help="drive an N-worker lockstep gang with tracing on (cpu "
-        "harness), merge its trace, and analyze it",
-    )
-    ap.add_argument("--tasks", type=int, default=8, help="gang tasks")
-    ap.add_argument(
-        "--artifact", nargs="?", const="", default=None, metavar="PATH",
-        help=f"stamp the report (+ the ingest trace-overhead A/B) as "
-        f"artifacts/{ARTIFACT_NAME} (env override TRACE_OUT)",
-    )
     args = ap.parse_args(argv)
-    log = lambda m: print(f"[straggler] {m}", file=sys.stderr, flush=True)
-    run = None
-    if args.artifact is not None:
-        # ArtifactRun captures code_rev at ENTRY, before run_gang rewrites
-        # the committed trace artifacts (tools/artifact.py documents why a
-        # stamp-time read would mark every --run-gang artifact "-dirty"
-        # from its own outputs).
-        from tools.artifact import ArtifactRun
-
-        run = ArtifactRun()
-
-    if bool(args.run_gang) + bool(args.trace) + bool(args.raw) != 1:
-        print(
-            "straggler_report: exactly one of --run-gang/--trace/--raw",
-            file=sys.stderr,
-        )
+    if bool(args.trace) == bool(args.raw):
+        print("straggler_report: exactly one of --trace/--raw", file=sys.stderr)
         return 2
-
-    if args.run_gang:
-        report = run_gang(args.run_gang, args.tasks, log)
-    else:
-        report = analyze(_merged_from_args(args))
-
-    if args.artifact is not None:
-        # The overhead A/B belongs in the SAME artifact as the skew
-        # numbers: "stragglers are measurable AND measuring them is ~free"
-        # is one claim, checkable from one file.
-        from tools.ingest_bench import trace_overhead_ab
-
-        overhead = trace_overhead_ab(log)
-        run.write(
-            {
-                "metric": "gang_trace_straggler_report",
-                **report,
-                "trace_overhead_ingest_ab": overhead,
-            },
-            ARTIFACT_NAME,
-            env_var="TRACE_OUT",
-            path=args.artifact or None,
-            log=log,
-        )
+    report = analyze(_merged_from_args(args))
     print(json.dumps(report), flush=True)
     return 0
 
