@@ -23,11 +23,10 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-import optax
 
 from elasticdl_tpu.common.jax_compat import jit_compiled
 from elasticdl_tpu.data.codecs import criteo_feed, criteo_feed_pre
-from elasticdl_tpu.models.spec import EmbeddingTableSpec, HostTableIO, ModelSpec
+from elasticdl_tpu.models.spec import Adam, EmbeddingTableSpec, HostTableIO, ModelSpec
 from elasticdl_tpu.models.tabular import (
     bce_loss,
     binary_metrics,
@@ -260,7 +259,7 @@ def model_spec(
         ),
         loss=_loss,
         metrics=_metrics,
-        optimizer=optax.adam(learning_rate),
+        optimizer=Adam(learning_rate),
         embedding_tables=(
             []
             if host_tier

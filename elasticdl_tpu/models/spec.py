@@ -76,6 +76,27 @@ class HostTableIO:
     per_token: bool = False
 
 
+@dataclasses.dataclass(frozen=True)
+class Adam:
+    """Plain Adam, declared: ``optax.adam(learning_rate, b1, b2, eps)`` and
+    nothing else (no weight decay, no clipping, no schedule), as numbers the
+    trainer can read.  A ``ModelSpec`` given one as its ``optimizer`` holds
+    the optax transformation built from it there and the record itself as
+    ``adam`` — what lets the trainer apply a swept table's update inside
+    the merge sweep (ops/table_grad.sweep_adam) instead of calling optax on
+    that leaf."""
+
+    learning_rate: float = 1e-3
+    b1: float = 0.9
+    b2: float = 0.999
+    eps: float = 1e-8
+
+    def transformation(self):
+        import optax
+
+        return optax.adam(self.learning_rate, b1=self.b1, b2=self.b2, eps=self.eps)
+
+
 @dataclasses.dataclass
 class ModelSpec:
     name: str
@@ -83,7 +104,7 @@ class ModelSpec:
     apply: Callable[..., Any]  # (params, batch, train=bool) -> outputs
     loss: Callable[[Any, Batch], Any]  # (outputs, batch) -> scalar
     metrics: Callable[[Any, Batch], Dict[str, Any]]
-    optimizer: Any  # optax.GradientTransformation
+    optimizer: Any  # optax.GradientTransformation, or an Adam record (above)
     feed: Optional[Callable[[Sequence[bytes]], Batch]] = None
     embedding_tables: List[EmbeddingTableSpec] = dataclasses.field(
         default_factory=list
@@ -116,6 +137,13 @@ class ModelSpec:
     # None = serve ``apply(params, batch, train=False)`` outputs as-is.
     # Jitted inside build_predict_step, so the transform is free on device.
     predict: Optional[Callable[..., Any]] = None
+    # The Adam record ``optimizer`` was declared as, if it was.
+    adam: Optional[Adam] = dataclasses.field(default=None, init=False)
+
+    def __post_init__(self):
+        if isinstance(self.optimizer, Adam):
+            self.adam = self.optimizer
+            self.optimizer = self.adam.transformation()
 
 
 def load_model_spec(model_zoo: str, model_def: str, **params: Any) -> ModelSpec:

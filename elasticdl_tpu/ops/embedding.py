@@ -36,7 +36,7 @@ Design (static shapes, XLA/ICI-friendly — see SURVEY.md §7 item 5):
   the AD transpose expands cotangents back to 128-lane rows (einsum
   transpose) and scatter-adds whole physical rows — or, for a big f32 table
   on a TPU, sorts them and merges them into the buffer in one sequential
-  sweep (``_sweeps``: read from the table's shape, no flag).
+  sweep (``sweeps``: read from the table's shape, no flag).
 - The table is **physical-row-sharded** over the mesh axis: ``V'`` is padded
   so the physical row count divides every power-of-two mesh size up to 256,
   and shard ``i`` owns logical rows ``[i*V'/n, (i+1)*V'/n)`` — GSPMD's
@@ -78,6 +78,28 @@ IndexedSlices apply.  The ragged impl does this through a ``custom_vjp`` (the
 ragged collective has no AD rule): the saved routing metadata is replayed,
 vectors flow requester→owner, and the owner applies the same masked
 scatter-add.
+
+Handing the update rows over (PR 29).  A trainer that applies a table's
+update itself (``ops/table_grad.sweep_adam``: dense Adam inside the merge
+sweep) needs the table's update as ``(physical row [N], cotangent row
+[N, 128])`` and not as a table-shaped cotangent.  It names such tables when
+it opens :func:`route_taps` (``hand_over``: the very arrays its params hold,
+recognised by identity, so models keep calling ``embedding_lookup(table,
+ids, ctx, dim)`` unaware) and gives each a zero "carrier" of the gathered
+rows' shape.  The lookup's gather then runs under a ``custom_vjp``
+(``_take_rows_handed``; on the ragged route ``_ragged_lookup`` itself)
+whose backward returns the rows' cotangent as the CARRIER's and none for
+the table, and appends the physical row of each to ``taps.handed``; on the
+ragged route both are what the OWNER holds after ``route_bwd_vectors`` and
+the lane select's transpose, so nothing new crosses the interconnect.  Why
+a carrier: a cotangent has to have the shape of some primal input, and the
+update rows have the shape of none; a zero input that the forward never
+reads costs nothing (XLA drops it) and stays inside JAX's rules, where
+smuggling the rows out of the backward through a side channel would not.
+Its shape is not known before the apply is traced, so the trainer traces
+the apply once more under ``jax.eval_shape`` with no carriers given (the
+counting trace: ``taps.handed`` then holds each lookup's carrier shape, and
+tells a table looked up twice, which is not fused, from one looked up once).
 
 Fail-loud OOV contract (both impls): an id outside the padded global vocab
 comes back as a NaN row — never a silently wrong or zero row.  In the ragged
@@ -340,41 +362,51 @@ def logical_rows(table: jax.Array, dim: int) -> int:
     return table.shape[0] * pack
 
 
-def gather_rows(table: jax.Array, ids: jax.Array, dim: Optional[int] = None):
+def _physical_rows(num_physical: int, flat_ids: jax.Array, pack: int):
+    """(physical row, lane group) of each logical id; an id outside the
+    table (either sign) goes to physical row ``num_physical``, which
+    :func:`_take_rows` NaN-fills and every table gradient drops."""
+    # jnp.take wraps NEGATIVE indices NumPy-style before the bounds check,
+    # so a bare -1 would silently read the last row: mark OOB explicitly.
+    oob = (flat_ids < 0) | (flat_ids >= num_physical * pack)
+    if pack == 1:
+        return jnp.where(oob, num_physical, flat_ids), None
+    hi = jnp.where(oob, num_physical, flat_ids // pack)
+    lo = jnp.where(oob, 0, flat_ids - (flat_ids // pack) * pack)
+    return hi, lo
+
+
+def _select_lanes(rows: jax.Array, lo, pack: int, stride: int, dim: int):
+    """[N, dim]: lane group ``lo`` of each physical row (linear in ``rows``;
+    a NaN row stays NaN: NaN * 0 == NaN)."""
+    if pack == 1:
+        return rows[:, :dim]
+    rows = rows.reshape(rows.shape[0], pack, stride)
+    sel = jax.nn.one_hot(lo, pack, dtype=rows.dtype)
+    return jnp.einsum("nps,np->ns", rows, sel)[:, :dim]
+
+
+def gather_rows(table: jax.Array, ids: jax.Array, dim: Optional[int] = None,
+                hand: Optional["_Hand"] = None):
     """Logical rows ``ids`` of a lane-packed table as ``ids.shape + (dim,)``.
 
     ``table`` is ``[P, pack*dim]`` (``dim`` defaults to the full width, i.e. a
     plain ``[V, dim]`` table is the ``pack == 1`` case).  Whole-physical-row
     gather + one-hot lane select; its AD transpose is a whole-physical-row
     scatter-add, built for a big table on a TPU by the sorted merge sweep
-    (:func:`_sweeps`).  Out-of-range ids (either sign) fill with NaN (floats) so
+    (:func:`sweeps`).  Out-of-range ids (either sign) fill with NaN (floats) so
     id-generation bugs surface immediately instead of silently training on a
     clamped row; the fill-mode transpose likewise drops OOB cotangents.
+    ``hand``: the table's hand-over slot of an open :func:`route_taps`, or
+    None (module docstring, "Handing the update rows over").
     """
     P, W = table.shape
     if dim is None:
         dim = W
     pack, stride = _pack_geometry(W, dim)
     fill = jnp.nan if jnp.issubdtype(table.dtype, jnp.floating) else 0
-    flat_ids = ids.reshape(-1)
-    # Mark OOB (either sign) explicitly and redirect to physical row P, which
-    # take's fill mode NaN-fills — jnp.take wraps NEGATIVE indices NumPy-style
-    # before the bounds check, so a bare -1 would silently read the last row.
-    # The redirected rows' cotangents are dropped by the fill-mode transpose,
-    # and in the packed path the NaN survives the lane-select einsum below
-    # (NaN * 0 == NaN).
-    oob = (flat_ids < 0) | (flat_ids >= P * pack)
-    if pack == 1:
-        idx = jnp.where(oob, P, flat_ids)
-        out = _take_rows(table, idx, fill)
-        out = out[:, :dim]
-    else:
-        hi = jnp.where(oob, P, flat_ids // pack)
-        lo = jnp.where(oob, 0, flat_ids - (flat_ids // pack) * pack)
-        rows = _take_rows(table, hi, fill)
-        rows = rows.reshape(flat_ids.shape[0], pack, stride)
-        sel = jax.nn.one_hot(lo, pack, dtype=table.dtype)
-        out = jnp.einsum("nps,np->ns", rows, sel)[:, :dim]
+    hi, lo = _physical_rows(P, ids.reshape(-1), pack)
+    out = _select_lanes(_take_rows(table, hi, fill, hand), lo, pack, stride, dim)
     return out.reshape(ids.shape + (dim,))
 
 
@@ -382,7 +414,7 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _sweeps(table: jax.Array) -> bool:
+def sweeps(table: jax.Array) -> bool:
     """Whether the cotangent of a row gather from ``table`` is built by the
     merge sweep: read from the table's shape and the platform, at trace
     time.  Everything else keeps the AD transpose of ``jnp.take``."""
@@ -393,9 +425,15 @@ def _sweeps(table: jax.Array) -> bool:
     )
 
 
-def _take_rows(table: jax.Array, idx: jax.Array, fill) -> jax.Array:
+def _take_rows(table: jax.Array, idx: jax.Array, fill, hand: Optional["_Hand"] = None):
     """Physical rows ``idx`` (in ``[0, P]``; ``P`` fills) of ``table``."""
-    if _sweeps(table):
+    if hand is not None:
+        carrier = hand.carrier(idx.shape[0], table)
+        if carrier is not None:
+            idx = idx.astype(jnp.int32)
+            hand.deliver(idx)
+            return _take_rows_handed(table, idx, carrier)
+    if sweeps(table):
         return _take_rows_swept(table, idx.astype(jnp.int32), table.shape[0])
     return jnp.take(table, idx, axis=0, mode="fill", fill_value=fill)
 
@@ -416,6 +454,24 @@ def _take_rows_swept_bwd(num_rows: int, idx, g):
 
 
 _take_rows_swept.defvjp(_take_rows_swept_fwd, _take_rows_swept_bwd)
+
+
+@jax.custom_vjp
+def _take_rows_handed(table, idx, carrier):
+    """The gather of a table whose update rows are handed over: the rows'
+    cotangent comes back as ``carrier``'s, the table gets none."""
+    return jnp.take(table, idx, axis=0, mode="fill", fill_value=jnp.nan)
+
+
+def _take_rows_handed_fwd(table, idx, carrier):
+    return _take_rows_handed(table, idx, carrier), idx
+
+
+def _take_rows_handed_bwd(idx, g):
+    return None, np.zeros(idx.shape, jax.dtypes.float0), g
+
+
+_take_rows_handed.defvjp(_take_rows_handed_fwd, _take_rows_handed_bwd)
 
 
 def embedding_lookup(
@@ -444,9 +500,10 @@ def embedding_lookup(
         dim = table.shape[1]
     _pack_geometry(table.shape[1], dim)  # raises on inconsistent width/dim
 
+    hand = _hand_of(table)
     if not (ctx.sharded_embeddings and ctx.axis_name):
-        _tap_lookup(table, ids, dim)
-        return gather_rows(table, ids, dim)
+        _tap_lookup(table, ids, dim, hand)
+        return gather_rows(table, ids, dim, hand)
     impl = resolve_impl(ctx.embedding_impl)
     # n=1 degenerates to a local gather (dense short-circuits it); an
     # EXPLICIT ragged request is still honored so the real op can be
@@ -454,13 +511,20 @@ def embedding_lookup(
     if impl == IMPL_DENSE or (
         axis_size(ctx.axis_name) == 1 and impl == IMPL_RAGGED_EMULATED
     ):
-        return _dense_lookup(table, ids, ctx.axis_name, dim)
-    out, rows_received, rows_in_range = _ragged_lookup(
-        table, ids, ctx.axis_name, dim, impl == IMPL_RAGGED_EMULATED
+        return _dense_lookup(table, ids, ctx.axis_name, dim, hand)
+    # The owner's side of the route gathers n * L rows (worst-case skew).
+    carrier = (
+        None if hand is None
+        else hand.carrier(axis_size(ctx.axis_name) * ids.size, table)
     )
+    out, rows_received, rows_in_range, physical = _ragged_lookup(
+        table, ids, carrier, ctx.axis_name, dim, impl == IMPL_RAGGED_EMULATED
+    )
+    if carrier is not None:
+        hand.deliver(physical)
     if _TAPS.open is not None:
         _TAPS.open.rows_received.append(rows_received)
-    _tap_table_grad(table, rows_in_range)
+    _tap_table_grad(table, rows_in_range, hand)
     return out
 
 
@@ -472,9 +536,20 @@ class LookupTaps:
     #: ragged route: rows THIS shard received.
     rows_received: list = dataclasses.field(default_factory=list)
     #: every route: (update rows the table's cotangent is offered — the
-    #: looked-up ids inside the table —, those of them whose cotangent is
-    #: built by the merge sweep: all or none, :func:`_sweeps`).
+    #: looked-up ids inside the table —, those of them that reach the table
+    #: sorted, by a merge sweep: all or none, :func:`sweeps`, and those of
+    #: them that are handed over instead of becoming a cotangent).
     table_grad: list = dataclasses.field(default_factory=list)
+    #: The tables (the very arrays the opener's params hold) whose lookups
+    #: hand their update rows over, and one zero carrier each, by position
+    #: (None: the counting trace, which only records ``handed``).
+    hand_over: tuple = ()
+    carriers: Optional[tuple] = None
+    #: One ``(position in hand_over, x)`` a lookup of such a table: ``x`` is
+    #: the carrier's ShapeDtypeStruct in the counting trace, and the int32
+    #: physical row [N] of each update row (``P`` = none) once carriers are
+    #: given; the rows themselves are the carrier's cotangent.
+    handed: list = dataclasses.field(default_factory=list)
 
 
 class _Taps(threading.local):
@@ -485,13 +560,13 @@ _TAPS = _Taps()
 
 
 @contextlib.contextmanager
-def route_taps():
+def route_taps(hand_over=(), carriers=None):
     """Trace-time tap on the lookups: while open (on this thread), every
     ``embedding_lookup`` traced appends to the yielded :class:`LookupTaps` —
-    how the train step gets the route's load balance and the table
-    gradient's path into its metrics without the model's apply returning
-    them."""
-    taps = LookupTaps()
+    how the train step gets the route's load balance, the table gradient's
+    path and (``hand_over``, ``carriers``) a table's update rows without
+    the model's apply returning them."""
+    taps = LookupTaps(hand_over=tuple(hand_over), carriers=carriers)
     prev, _TAPS.open = _TAPS.open, taps
     try:
         yield taps
@@ -499,22 +574,59 @@ def route_taps():
         _TAPS.open = prev
 
 
+@dataclasses.dataclass(frozen=True)
+class _Hand:
+    """A handed-over table's slot in the open taps."""
+
+    taps: LookupTaps
+    slot: int
+
+    @property
+    def fusing(self) -> bool:
+        return self.taps.carriers is not None
+
+    def carrier(self, rows: int, table: jax.Array):
+        """The zero [rows, W] array whose cotangent the gathered rows'
+        becomes; None in the counting trace, which notes its shape."""
+        if self.fusing:
+            return self.taps.carriers[self.slot]
+        shape = jax.ShapeDtypeStruct((rows, table.shape[1]), table.dtype)
+        self.taps.handed.append((self.slot, shape))
+        return None
+
+    def deliver(self, physical_ids: jax.Array) -> None:
+        self.taps.handed.append((self.slot, physical_ids))
+
+
+def _hand_of(table: jax.Array) -> Optional[_Hand]:
+    taps = _TAPS.open
+    if taps is not None:
+        for slot, handed in enumerate(taps.hand_over):
+            if handed is table:
+                return _Hand(taps, slot)
+    return None
+
+
 def _rows_in_range(table: jax.Array, ids: jax.Array, dim: int) -> jax.Array:
     inside = (ids >= 0) & (ids < logical_rows(table, dim))
     return jnp.sum(inside, dtype=jnp.int32)
 
 
-def _tap_table_grad(table: jax.Array, rows: jax.Array) -> None:
+def _tap_table_grad(table: jax.Array, rows: jax.Array, hand: Optional[_Hand] = None) -> None:
     if _TAPS.open is not None:
-        swept = rows if _sweeps(table) else jnp.zeros_like(rows)
-        _TAPS.open.table_grad.append((rows, swept))
+        none = jnp.zeros_like(rows)
+        _TAPS.open.table_grad.append((
+            rows,
+            rows if sweeps(table) else none,
+            rows if hand is not None and hand.fusing else none,
+        ))
 
 
-def _tap_lookup(table: jax.Array, ids: jax.Array, dim: int) -> None:
+def _tap_lookup(table: jax.Array, ids: jax.Array, dim: int, hand: Optional[_Hand] = None) -> None:
     """Tap a plain ``gather_rows(table, ids, dim)``; counts only while a
     tap is open."""
     if _TAPS.open is not None:
-        _tap_table_grad(table, _rows_in_range(table, ids, dim))
+        _tap_table_grad(table, _rows_in_range(table, ids, dim), hand)
 
 
 def resolve_impl(
@@ -543,7 +655,8 @@ def resolve_impl(
 # ---------------------------------------------------------------------------
 
 
-def _dense_lookup(local_table: jax.Array, ids: jax.Array, axis_name: str, dim: int):
+def _dense_lookup(local_table: jax.Array, ids: jax.Array, axis_name: str, dim: int,
+                  hand: Optional[_Hand] = None):
     # Trace-time import: a module-level one closes the ops -> parallel ->
     # ops cycle (parallel/__init__ pulls the trainer, which needs this
     # module mid-initialization) whenever ops is imported first.
@@ -557,8 +670,8 @@ def _dense_lookup(local_table: jax.Array, ids: jax.Array, axis_name: str, dim: i
     flat_ids = ids.reshape(-1)
     bad = (flat_ids < 0) | (flat_ids >= n * rows_local)
     if n == 1:
-        _tap_lookup(local_table, flat_ids, dim)
-        out = gather_rows(local_table, flat_ids, dim)  # NaN-fills OOB itself
+        _tap_lookup(local_table, flat_ids, dim, hand)
+        out = gather_rows(local_table, flat_ids, dim, hand)  # NaN-fills OOB itself
         return out.reshape(ids_shape + (dim,))
 
     # [n * local_ids] — every device's flat id list.
@@ -569,8 +682,8 @@ def _dense_lookup(local_table: jax.Array, ids: jax.Array, axis_name: str, dim: i
     mine = owner == my_shard
     safe_row = jnp.where(mine, local_row, 0)
     # Every gathered id is offered as an update row: the others' as zeros.
-    _tap_table_grad(local_table, jnp.int32(all_ids.shape[0]))
-    vectors = jnp.where(mine[:, None], gather_rows(local_table, safe_row, dim), 0)
+    _tap_table_grad(local_table, jnp.int32(all_ids.shape[0]), hand)
+    vectors = jnp.where(mine[:, None], gather_rows(local_table, safe_row, dim, hand), 0)
 
     # Route each device its own block, summing over shards (one nonzero each).
     vectors = vectors.reshape(n, -1, dim)
@@ -663,11 +776,13 @@ def _exclusive_cumsum(x: jax.Array) -> jax.Array:
     )
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _ragged_lookup(local_table, ids, axis_name: str, dim: int, emulate: bool):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _ragged_lookup(local_table, ids, carrier, axis_name: str, dim: int, emulate: bool):
     """(vectors ``ids.shape + (dim,)``, rows this shard received, those of
-    them inside its row range: int32)."""
-    out, _ = _ragged_lookup_fwd(local_table, ids, axis_name, dim, emulate)
+    them inside its row range: int32, the physical row [n * L] of each row
+    it gathered).  ``carrier``: None, or the zero [n * L, W] array that
+    takes the gathered rows' cotangent in the table's place."""
+    out, _ = _ragged_lookup_fwd(local_table, ids, carrier, axis_name, dim, emulate)
     return out
 
 
@@ -675,7 +790,7 @@ def _ragged_lookup(local_table, ids, axis_name: str, dim: int, emulate: bool):
 # ``route_ids``, ``route_gather``, ``route_vectors``, ``route_unsort``;
 # backward ``route_bwd_sort``, ``route_bwd_vectors``, ``route_bwd_scatter``)
 # so a device trace can put each op's time down to its part.
-def _ragged_lookup_fwd(local_table, ids, axis_name: str, dim: int, emulate: bool):
+def _ragged_lookup_fwd(local_table, ids, carrier, axis_name: str, dim: int, emulate: bool):
     n = axis_size(axis_name)
     rows_local = logical_rows(local_table, dim)
     ids_shape = ids.shape
@@ -697,6 +812,10 @@ def _ragged_lookup_fwd(local_table, ids, axis_name: str, dim: int, emulate: bool
         local_rows = recv_ids - lax.axis_index(axis_name) * rows_local
         vecs = gather_rows(local_table, local_rows, dim)   # [n*L, dim], NaN on OOB
         rows_in_range = _rows_in_range(local_table, local_rows, dim)
+        physical, lane = _physical_rows(
+            local_table.shape[0], local_rows,
+            _pack_geometry(local_table.shape[1], dim)[0],
+        )
 
     # vectors -> requesters: exactly the reverse plan.  My block offsets are
     # recv's exclusive cumsum (received chunks are sender-ordered); my chunk
@@ -715,14 +834,15 @@ def _ragged_lookup_fwd(local_table, ids, axis_name: str, dim: int, emulate: bool
         inv = jnp.zeros_like(perm).at[perm].set(jnp.arange(L))
         out = sorted_out[inv].reshape(ids_shape + (dim,))
     residuals = (perm, send, in_off, out_off, recv, back_in_off, back_out_off,
-                 local_rows, local_table.shape, ids_shape)
-    return (out, jnp.sum(recv), rows_in_range), residuals
+                 local_rows, local_table.shape, ids_shape,
+                 None if carrier is None else (lane,))
+    return (out, jnp.sum(recv), rows_in_range, physical.astype(jnp.int32)), residuals
 
 
 def _ragged_lookup_bwd(axis_name: str, dim: int, emulate: bool, residuals, g):
     (perm, send, in_off, out_off, recv, back_in_off, back_out_off,
-     local_rows, table_shape_, ids_shape) = residuals
-    g, _, _ = g  # the row counts are integers: no cotangent
+     local_rows, table_shape_, ids_shape, handed) = residuals
+    g = g[0]  # the row counts and the row numbers are integers: no cotangent
     n = axis_size(axis_name)
     L = perm.shape[0]
     # Cotangents retrace the forward id route (requester -> owner): sort by
@@ -737,12 +857,23 @@ def _ragged_lookup_bwd(axis_name: str, dim: int, emulate: bool, residuals, g):
         g_at_owner = _ragged_collective(
             g_sorted, g_buf, in_off, send, out_off, recv, axis_name, emulate
         )
+    ids_bar = np.zeros(ids_shape, jax.dtypes.float0)
     with jax.named_scope("route_bwd_scatter"):
+        if handed is not None:
+            # Handed over: the lane select's transpose only; whole physical
+            # rows leave as the carrier's cotangent.
+            (lane,) = handed
+            pack, stride = _pack_geometry(table_shape_[1], dim)
+            _, pull = jax.vjp(
+                lambda rows: _select_lanes(rows, lane, pack, stride, dim),
+                jnp.zeros((n * L, table_shape_[1]), g_at_owner.dtype),
+            )
+            (rows_bar,) = pull(g_at_owner)
+            return None, ids_bar, rows_bar
         zeros = jnp.zeros(table_shape_, g_at_owner.dtype)
         _, pull = jax.vjp(lambda t: gather_rows(t, local_rows, dim), zeros)
         (table_bar,) = pull(g_at_owner)
-    ids_bar = np.zeros(ids_shape, jax.dtypes.float0)
-    return table_bar, ids_bar
+    return table_bar, ids_bar, None
 
 
 _ragged_lookup.defvjp(_ragged_lookup_fwd, _ragged_lookup_bwd)

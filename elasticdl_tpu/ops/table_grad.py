@@ -1,5 +1,7 @@
-"""The dense table-gradient buffer of a row gather, built by one sorted
-merge sweep instead of XLA's scatter-add.
+"""The dense table gradient of a row gather, built by one sorted merge
+sweep instead of XLA's scatter-add — into a buffer (:func:`merge_sweep`), or,
+where the table's rule is plain dense Adam, straight into the table and its
+moments (:func:`merge_sweep_adam`), the buffer never written.
 
 ``zeros([P, W]).at[ids].add(rows)`` is, on a TPU, a serial read-modify-write
 of HBM per update row, and each one pays a whole random access into the
@@ -33,6 +35,26 @@ of its own.
 One difference from the scatter-add: a non-finite update row reaches every
 row of its tile (``0 * nan`` in the matmul), not only its own.  A step
 that produces one is lost either way.
+
+**The update in the same pass** (PR 29).  The dense Adam update that follows
+reads that buffer back and sweeps p, m and v: with the buffer's write that
+is eight table-sized passes over HBM a step, at the roofline of its bytes.
+The kernel already holds every gradient tile in VMEM, in row order, exactly
+once — so :func:`merge_sweep_adam` also takes the tile's p, m and v as
+pipelined blocks, applies ``optax.adam``'s arithmetic to EVERY row of it on
+the VPU, in f32 and in optax's order of operations (a row with g = 0 and
+live moments still moves; a tile with no update row is swept like any
+other: skipping it would be a different optimizer), and stores the three
+back through ``input_output_aliases``, in place.  Six passes, one kernel,
+no ``[P, W]`` temporary: 12.3 ms against 17.0 for the pair at 2.56 M rows
+on a v5e, 24.1 against 32.8 at 5.11 M (PERF.md, PR 29, step 0).  The merge
+is the same code (:func:`_merge_tile`), so m and v come out equal to the
+pair's to the bit; p to the last bit or two (Mosaic's divide and square
+root against XLA's fusion).  Who may use it is the trainer's decision
+(``parallel/trainer.py``: the declared plain Adam, one lookup of the table
+a step, no other replica adding to its gradient); :func:`merge_sweep`
+serves every other rule, and a table under ``SWEEP_MIN_ROWS`` keeps the
+AD transpose.
 """
 
 from __future__ import annotations
@@ -68,8 +90,12 @@ def _exact_bf16_pieces(rows):
     return hi, mid, lo
 
 
-def _sweep_kernel(offs, ids_a, rows_a, ids_b, rows_b, ids_any, rows_any,
-                  out, ids_buf, rows_buf, sems, *, tile: int, chunk: int):
+def _merge_tile(offs, ids_a, rows_a, ids_b, rows_b, ids_any, rows_any,
+                acc, ids_buf, rows_buf, sems, *, tile: int, chunk: int):
+    """``acc[...]`` (a [tile, W] f32 block in VMEM) = the update rows of
+    grid step ``program_id(0)``'s tile, added up at its row numbers.  The
+    whole kernel of :func:`merge_sweep` (``acc`` its output block) and the
+    first half of :func:`merge_sweep_adam`'s."""
     t = pl.program_id(0)
     first, end = offs[t], offs[t + 1]
     k0 = first // chunk
@@ -84,11 +110,11 @@ def _sweep_kernel(offs, ids_a, rows_a, ids_b, rows_b, ids_any, rows_any,
         )
         return (hi + mid) + lo
 
-    out[...] = merged(ids_a[...], rows_a[...])
+    acc[...] = merged(ids_a[...], rows_a[...])
 
     @pl.when(end > (k0 + 1) * chunk)
     def _():
-        out[...] += merged(ids_b[...], rows_b[...])
+        acc[...] += merged(ids_b[...], rows_b[...])
 
     extra = jnp.maximum(0, (end - (k0 + 1) * chunk - 1) // chunk)
 
@@ -116,10 +142,27 @@ def _sweep_kernel(offs, ids_a, rows_a, ids_b, rows_b, ids_any, rows_any,
 
             for copy in copies(slot, k0 + 2 + i):
                 copy.wait()
-            out[...] += merged(ids_buf[slot], rows_buf[slot])
+            acc[...] += merged(ids_buf[slot], rows_buf[slot])
             return carry
 
         lax.fori_loop(0, extra, body, 0)
+
+
+def _apply_kernel(offs, bias, ids_a, rows_a, ids_b, rows_b, ids_any, rows_any,
+                  p_in, m_in, v_in, p_out, m_out, v_out,
+                  grad, ids_buf, rows_buf, sems, *, tile: int, chunk: int,
+                  step_size: float, b1: float, b2: float, eps: float):
+    _merge_tile(offs, ids_a, rows_a, ids_b, rows_b, ids_any, rows_any,
+                grad, ids_buf, rows_buf, sems, tile=tile, chunk=chunk)
+    # optax.adam's update of EVERY row of the tile, in optax's order of
+    # operations (scale_by_adam, then scale(-learning_rate), then
+    # apply_updates): a row with g = 0 and live moments still moves.
+    g = grad[...]
+    m = (1 - b1) * g + b1 * m_in[...]
+    v = (1 - b2) * (g * g) + b2 * v_in[...]
+    m_out[...] = m
+    v_out[...] = v
+    p_out[...] = p_in[...] + step_size * ((m / bias[0]) / (jnp.sqrt(v / bias[1]) + eps))
 
 
 def sort_updates(ids: jax.Array, rows: jax.Array, num_rows: int, *,
@@ -140,13 +183,10 @@ def sort_updates(ids: jax.Array, rows: jax.Array, num_rows: int, *,
     return offsets, sorted_ids, rows[order]
 
 
-def merge_sweep(offsets: jax.Array, sorted_ids: jax.Array, sorted_rows: jax.Array,
-                num_rows: int, *, tile: int = TILE, chunk: int = CHUNK,
-                interpret: Optional[bool] = None) -> jax.Array:
-    """The kernel half: the [num_rows, W] buffer from :func:`sort_updates`'
-    three outputs (same ``tile`` and ``chunk``)."""
-    if interpret is None:
-        interpret = _use_interpret()
+def _chunk_operands(sorted_ids, sorted_rows, chunk: int):
+    """What both kernels read the sorted update rows through: (operands,
+    their specs, the scratch of the hot tiles' loop).  The first two chunks
+    of a tile are pipelined blocks, the rest stay in HBM."""
     n_pad, width = sorted_rows.shape
     chunks = n_pad // chunk
     ids3 = sorted_ids.reshape(chunks, 1, chunk)
@@ -156,32 +196,82 @@ def merge_sweep(offsets: jax.Array, sorted_ids: jax.Array, sorted_rows: jax.Arra
         that holds the tile's first update row."""
         def at(t, offs):
             return jnp.minimum(offs[t] // chunk + step, chunks - 1)
-        return (
-            pl.BlockSpec((None, 1, chunk), lambda t, offs: (at(t, offs), 0, 0)),
-            pl.BlockSpec((chunk, width), lambda t, offs: (at(t, offs), 0)),
+        return (  # ``*_``: merge_sweep_adam prefetches a second scalar array
+            pl.BlockSpec((None, 1, chunk), lambda t, offs, *_: (at(t, offs), 0, 0)),
+            pl.BlockSpec((chunk, width), lambda t, offs, *_: (at(t, offs), 0)),
         )
 
+    specs = [
+        *block(0), *block(1),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    scratch = [
+        pltpu.VMEM((2, 1, chunk), jnp.int32),
+        pltpu.VMEM((2, chunk, width), sorted_rows.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+    ]
+    return (ids3, sorted_rows) * 3, specs, scratch
+
+
+def merge_sweep(offsets: jax.Array, sorted_ids: jax.Array, sorted_rows: jax.Array,
+                num_rows: int, *, tile: int = TILE, chunk: int = CHUNK,
+                interpret: Optional[bool] = None) -> jax.Array:
+    """The kernel half: the [num_rows, W] buffer from :func:`sort_updates`'
+    three outputs (same ``tile`` and ``chunk``)."""
+    if interpret is None:
+        interpret = _use_interpret()
+    width = sorted_rows.shape[1]
+    operands, specs, scratch = _chunk_operands(sorted_ids, sorted_rows, chunk)
     return pl.pallas_call(
-        partial(_sweep_kernel, tile=tile, chunk=chunk),
+        partial(_merge_tile, tile=tile, chunk=chunk),  # acc = the output block
         out_shape=jax.ShapeDtypeStruct((num_rows, width), sorted_rows.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(-(-num_rows // tile),),
-            in_specs=[
-                *block(0), *block(1),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
+            in_specs=specs,
             out_specs=pl.BlockSpec((tile, width), lambda t, offs: (t, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, 1, chunk), jnp.int32),
-                pltpu.VMEM((2, chunk, width), sorted_rows.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ],
+            scratch_shapes=scratch,
         ),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(offsets, ids3, sorted_rows, ids3, sorted_rows, ids3, sorted_rows)
+    )(offsets, *operands)
+
+
+def merge_sweep_adam(
+    offsets: jax.Array, sorted_ids: jax.Array, sorted_rows: jax.Array,
+    table: jax.Array, mu: jax.Array, nu: jax.Array, bias: jax.Array, *,
+    learning_rate: float, b1: float, b2: float, eps: float,
+    tile: int = TILE, chunk: int = CHUNK, interpret: Optional[bool] = None,
+):
+    """:func:`merge_sweep` that keeps each gradient tile in VMEM and applies
+    ``optax.adam``'s dense update to the same tile of ``table``, ``mu`` and
+    ``nu`` (f32 [num_rows, W]) in place of writing it out: returns the three
+    updated, aliased onto the three given.  ``bias`` is f32 [2], the step's
+    two bias corrections ``1 - b**count`` (they change with the step, so
+    they travel through SMEM; the rule's own numbers are compiled in)."""
+    if interpret is None:
+        interpret = _use_interpret()
+    num_rows, width = table.shape
+    operands, specs, scratch = _chunk_operands(sorted_ids, sorted_rows, chunk)
+    tile_spec = pl.BlockSpec((tile, width), lambda t, *_: (t, 0))
+    state = jax.ShapeDtypeStruct(table.shape, table.dtype)
+    first = 2 + len(operands)  # p, m, v follow the two prefetched scalars
+    return pl.pallas_call(
+        partial(_apply_kernel, tile=tile, chunk=chunk,
+                step_size=-learning_rate, b1=b1, b2=b2, eps=eps),
+        out_shape=(state,) * 3,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(-(-num_rows // tile),),
+            in_specs=[*specs, tile_spec, tile_spec, tile_spec],
+            out_specs=(tile_spec,) * 3,
+            scratch_shapes=[pltpu.VMEM((tile, width), jnp.float32), *scratch],
+        ),
+        input_output_aliases={first: 0, first + 1: 1, first + 2: 2},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(offsets, bias, *operands, table, mu, nu)
 
 
 def sweep_table_grad(
@@ -196,3 +286,27 @@ def sweep_table_grad(
         *sort_updates(ids, rows, num_rows, tile=tile, chunk=chunk),
         num_rows, tile=tile, chunk=chunk, interpret=interpret,
     )
+
+
+def sweep_adam(
+    table: jax.Array, mu: jax.Array, nu: jax.Array, count: jax.Array,
+    ids: jax.Array, rows: jax.Array, *,
+    learning_rate: float, b1: float, b2: float, eps: float,
+    tile: int = TILE, chunk: int = CHUNK, interpret: Optional[bool] = None,
+):
+    """One ``optax.adam(learning_rate, b1, b2, eps)`` step on ``table`` (f32
+    [P, W]) whose dense gradient is ``zeros([P, W]).at[ids].add(rows,
+    mode="drop")``, without that gradient ever being an array: ``(table,
+    mu, nu)`` after the step.  ``count`` is the step's own count (optax's
+    ``count`` AFTER its increment).  The sort runs under the scope
+    ``table_grad``, the kernel under ``table_apply``."""
+    with jax.named_scope("table_grad"):
+        sorted_updates = sort_updates(ids, rows, table.shape[0], tile=tile, chunk=chunk)
+    with jax.named_scope("table_apply"):
+        # optax.tree.bias_correction's own expression, for the same bits.
+        bias = jnp.stack([1 - b1**count, 1 - b2**count]).astype(table.dtype)
+        return merge_sweep_adam(
+            *sorted_updates, table, mu, nu, bias,
+            learning_rate=learning_rate, b1=b1, b2=b2, eps=eps,
+            tile=tile, chunk=chunk, interpret=interpret,
+        )

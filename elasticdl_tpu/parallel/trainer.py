@@ -25,6 +25,7 @@ are left alone.  The two paths are consistent without rescaling.
 
 from __future__ import annotations
 
+import dataclasses
 import inspect
 import os
 import time
@@ -52,8 +53,10 @@ from elasticdl_tpu.ops.embedding import (
     pack_table,
     resolve_impl,
     route_taps,
+    sweeps,
     table_shape,
 )
+from elasticdl_tpu.ops.table_grad import sweep_adam
 
 from elasticdl_tpu.common.jax_compat import jit_compiled, jit_donating, shard_map
 
@@ -295,6 +298,71 @@ def _tree_psum_except(tree: Any, skip_paths, axes, skip_axes, topo=None):
         return coll.psum(leaf, axes, topo)
 
     return jax.tree_util.tree_map_with_path(maybe_psum, tree)
+
+
+def _with_leaves(tree: Any, at: Sequence[int], values: Sequence[Any]) -> Any:
+    """``tree`` with its ``at``-th leaves (flattening order) replaced."""
+    leaves, treedef = jax.tree.flatten(tree)
+    for i, value in zip(at, values):
+        leaves[i] = value
+    return treedef.unflatten(leaves)
+
+
+def _tables_the_sweep_updates(spec: ModelSpec, ctx: ParallelContext, params: Any,
+                              batch: Any, could: Sequence[int]):
+    """Of the ``could``-th leaves of ``params``, those one apply looks up
+    exactly once: (their positions, a zero carrier each: ops/embedding.py,
+    "Handing the update rows over").  From a counting trace of the apply
+    that computes nothing."""
+    if not could:
+        return [], ()
+    leaves = jax.tree.leaves(params)
+    with route_taps(hand_over=[leaves[i] for i in could]) as taps:
+        jax.eval_shape(lambda: spec.apply(params, batch, train=True, ctx=ctx))
+    slots = [slot for slot, _ in taps.handed]
+    once = sorted((s, shape) for s, shape in taps.handed if slots.count(s) == 1)
+    return (
+        [could[slot] for slot, _ in once],
+        tuple(jnp.zeros(shape.shape, shape.dtype) for _, shape in once),
+    )
+
+
+def _adam_update_with_fused_tables(
+    spec: ModelSpec, params: Any, opt_state: Any, grads: Any,
+    at: Sequence[int], updates: Sequence[Tuple[Any, Any]],
+):
+    """``spec.optimizer.update`` + ``apply_updates`` where the ``at``-th
+    leaves are tables updated by the merge sweep itself: optax sees a
+    one-row placeholder in their place (``grads`` already holds it) and so
+    counts the step once and updates every other leaf; ``updates`` is a
+    table's ``(physical ids [N], update rows [N, W])``.  The state keeps
+    its pytree, shapes and dtypes."""
+    adam, *rest = opt_state
+    assert isinstance(adam, optax.ScaleByAdamState), type(adam)
+    grad_leaves = jax.tree.leaves(grads)
+
+    def small(tree):
+        return _with_leaves(tree, at, [grad_leaves[i] for i in at])
+
+    steps, (counted, *rest) = spec.optimizer.update(
+        grads, (adam._replace(mu=small(adam.mu), nu=small(adam.nu)), *rest),
+        small(params),
+    )
+    tables, mus, nus = (
+        [jax.tree.leaves(tree)[i] for i in at] for tree in (params, adam.mu, adam.nu)
+    )
+    rule = dataclasses.asdict(spec.adam)
+    tables, mus, nus = zip(*(
+        sweep_adam(table, mu, nu, counted.count, ids, rows, **rule)
+        for table, mu, nu, (ids, rows) in zip(tables, mus, nus, updates)
+    ))
+    new_params = _with_leaves(
+        optax.apply_updates(small(params), steps), at, tables
+    )
+    counted = counted._replace(
+        mu=_with_leaves(counted.mu, at, mus), nu=_with_leaves(counted.nu, at, nus)
+    )
+    return new_params, (counted, *rest)
 
 
 def pad_embedding_tables(params: Any, tables: List[EmbeddingTableSpec]) -> Any:
@@ -1677,6 +1745,18 @@ def build_train_step(
     # row and must never be excluded alone.
     contrib_axes = tuple(axes) if spec.batch_shard_dim == 0 else tuple(axes[:-1])
 
+    def sweep_applies(path, leaf) -> bool:
+        """Whether this leaf's update COULD be applied by the merge sweep
+        itself (ops/table_grad.sweep_adam) instead of through a gradient
+        buffer and optax: read at trace time from what is declared and
+        what the leaf is — the declared plain Adam, unsharded optimizer
+        state, a table the sweep serves, a gradient no other replica adds
+        to.  (local_step adds: looked up exactly once in the step.)"""
+        if spec.adam is None or opt_shard is not None or leaf.ndim != 2:
+            return False
+        summed_over = dcn_axes if _path_keys(path) in grad_skip else axes
+        return sweeps(leaf) and all(mesh.shape[a] == 1 for a in summed_over)
+
     def local_step(state: TrainState, batch, active):
         # This shard's 0/1 subgroup weight (graftreduce r15): scales the
         # loss BEFORE autodiff, so every gradient — dense psum'd, table
@@ -1701,15 +1781,29 @@ def build_train_step(
             count = jnp.sum(mask.astype(jnp.float32)) * w
             total = jnp.maximum(coll.psum(count, axes), 1e-12)
 
-        def loss_fn(params, host_embs):
+        # Tables whose update rows the lookup hands over (ops/embedding.py)
+        # for the merge sweep to apply: ``fused_at`` their positions among
+        # the params' leaves.  Differentiation sees a one-row placeholder
+        # in their place and a zero carrier each, whose cotangent is the
+        # update rows.
+        leaves = jax.tree_util.tree_leaves_with_path(state.params)
+        could = [i for i, (path, leaf) in enumerate(leaves) if sweep_applies(path, leaf)]
+        fused_at, carriers = _tables_the_sweep_updates(
+            spec, ctx, state.params, {**batch, **host_in}, could
+        )
+        fused_tables = [leaves[i][1] for i in fused_at]
+
+        def loss_fn(params, host_embs, carriers):
             merged = dict(batch)
             merged.update(host_embs)
-            with route_taps() as taps:
+            params = _with_leaves(params, fused_at, fused_tables)
+            with route_taps(fused_tables, carriers) as taps:
                 out = spec.apply(params, merged, train=True, ctx=ctx)
             aux = (
                 out,
                 sum(taps.rows_received) if taps.rows_received else None,
                 tuple(map(sum, zip(*taps.table_grad))),
+                [ids for _, ids in sorted(taps.handed, key=lambda h: h[0])],
             )
             if mask is not None:
                 # count/total are constants w.r.t. params; the psum above
@@ -1717,9 +1811,15 @@ def build_train_step(
                 return spec.loss(out, merged, mask=mask) * count / total, aux
             return spec.loss(out, merged) * w / n_active, aux
 
-        (loss, (out, rows_received, table_grad)), (grads, host_grads) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1), has_aux=True
-        )(state.params, host_in)
+        (loss, (out, rows_received, table_grad, handed_ids)), (
+            grads, host_grads, handed_rows
+        ) = jax.value_and_grad(loss_fn, argnums=(0, 1, 2), has_aux=True)(
+            _with_leaves(
+                state.params, fused_at,
+                [jnp.zeros((1,) + t.shape[1:], t.dtype) for t in fused_tables],
+            ),
+            host_in, carriers,
+        )
         loss = coll.psum(loss, axes)
         if opt_shard is not None:
             params, opt_state = sharded_update(state, grads)
@@ -1727,10 +1827,16 @@ def build_train_step(
             grads = _tree_psum_except(
                 grads, grad_skip, axes, dcn_axes, collective
             )
-            updates, opt_state = spec.optimizer.update(
-                grads, state.opt_state, state.params
-            )
-            params = optax.apply_updates(state.params, updates)
+            if fused_at:
+                params, opt_state = _adam_update_with_fused_tables(
+                    spec, state.params, state.opt_state, grads, fused_at,
+                    list(zip(handed_ids, handed_rows)),
+                )
+            else:
+                updates, opt_state = spec.optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+                params = optax.apply_updates(state.params, updates)
         # Histogram metrics (streaming AUC, common/metrics.HIST_PREFIX) are
         # EVAL machinery — per-minibatch training AUC is noise, and the
         # reference computes AUC only in evaluation — so the train step
@@ -1759,12 +1865,16 @@ def build_train_step(
                 rows, axes
             ) / coll.contributor_count(mesh, axes)
         if table_grad:
-            # Update rows the tables' cotangents were offered in the step,
-            # and those built by the merge sweep (ops/table_grad.py): all
-            # of them or none, a table at a time.
-            rows, swept = (coll.psum(x.astype(jnp.float32), axes) for x in table_grad)
+            # Update rows the tables' gradients were offered in the step,
+            # those delivered by a sorted merge sweep (ops/table_grad.py),
+            # and those of them whose table the sweep updated itself: all
+            # or none, a table at a time.
+            rows, swept, fused = (
+                coll.psum(x.astype(jnp.float32), axes) for x in table_grad
+            )
             metrics["table_grad_rows"] = rows
             metrics["table_grad_rows_swept"] = swept
+            metrics["table_grad_rows_fused"] = fused
         new_state = TrainState(step=state.step + 1, params=params, opt_state=opt_state)
         if host_keys:
             # Per-example cotangents of the global-mean loss, batch-sharded;

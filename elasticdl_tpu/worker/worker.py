@@ -96,8 +96,12 @@ COUNTER_GAUGES = {
         "training steps and devices"),
     "table_grad_rows_swept": (
         "edl_table_grad_rows_swept_total",
-        "those of them whose gradient buffer the sorted merge sweep built "
+        "those of them delivered by the sorted merge sweep "
         "(ops/table_grad.py) and not XLA's scatter-add"),
+    "table_grad_rows_fused": (
+        "edl_table_grad_rows_fused_total",
+        "those of them whose table the merge sweep updated itself (dense "
+        "Adam in the kernel, no gradient buffer)"),
 }
 
 #: Step metrics (parallel/trainer.py) that are counts, not model metrics:
@@ -105,7 +109,7 @@ COUNTER_GAUGES = {
 #: as a task's metrics.
 STEP_COUNTERS = (
     "route_rows_recv_max", "route_rows_recv_mean",
-    "table_grad_rows", "table_grad_rows_swept",
+    "table_grad_rows", "table_grad_rows_swept", "table_grad_rows_fused",
 )
 
 

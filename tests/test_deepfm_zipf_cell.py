@@ -52,7 +52,10 @@ def test_the_cell_resolves_and_its_traffic_differs_in_the_ids_alone():
         assert ours.pop(key) != theirs.pop(key)
     assert ours == theirs
     reported = [m["name"] for m in bench.metrics_of(CELL, "per_layer")]
-    assert sorted(reported) == sorted([*TWINS, "table_grad_ms_step.ex", "table_grad_sweep_pct.ex"])
+    assert sorted(reported) == sorted([
+        *TWINS, "table_grad_ms_step.ex", "table_grad_sweep_pct.ex",
+        "table_apply_ms_step.ex", "table_apply_fused_pct.ex",  # PR 29
+    ])
 
 
 @pytest.mark.parametrize("name", sorted(TWINS))

@@ -175,10 +175,11 @@ def test_route_rows_ride_the_step_metrics_on_the_ragged_route(devices):
     ],
 )
 def test_table_grad_rows_ride_the_step_metrics(devices, monkeypatch, n, impl, rows_a_step):
-    """Update rows offered to the table's gradient, and those of them the
-    merge sweep built: none on the CPU, all of them where the program
-    would choose the sweep (platform and threshold steered here; the
-    kernel itself runs in the interpreter)."""
+    """Update rows offered to the table's gradient, those of them the
+    merge sweep delivered, and those whose table it updated itself: none
+    on the CPU, all of them where the program would choose the sweep
+    (platform and threshold steered here; the kernel itself runs in the
+    interpreter) — DeepFM declares plain Adam, so the sweep applies it."""
     from elasticdl_tpu.ops import embedding
 
     rng = np.random.default_rng(0)
@@ -196,14 +197,15 @@ def test_table_grad_rows_ride_the_step_metrics(devices, monkeypatch, n, impl, ro
         return (
             np.asarray(metrics["table_grad_rows"]).tolist(),
             np.asarray(metrics["table_grad_rows_swept"]).tolist(),
+            np.asarray(metrics["table_grad_rows_fused"]).tolist(),
             np.asarray(metrics["loss"]),
         )
 
-    rows, swept, loss = counts()
-    assert rows == [rows_a_step] * 2 and swept == [0, 0]
+    rows, swept, fused, loss = counts()
+    assert rows == [rows_a_step] * 2 and swept == fused == [0, 0]
     monkeypatch.setattr(embedding, "_on_tpu", lambda: True)
     monkeypatch.setattr(embedding, "SWEEP_MIN_ROWS", 8)
-    rows, swept, swept_loss = counts()
-    assert rows == swept == [rows_a_step] * 2
+    rows, swept, fused, swept_loss = counts()
+    assert rows == swept == fused == [rows_a_step] * 2
     # the second step's loss has been through one swept table gradient
     np.testing.assert_allclose(swept_loss, loss, rtol=1e-6)
