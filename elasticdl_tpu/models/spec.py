@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 Params = Any  # pytree
 Batch = Any  # pytree of arrays
@@ -137,6 +137,14 @@ class ModelSpec:
     # None = serve ``apply(params, batch, train=False)`` outputs as-is.
     # Jitted inside build_predict_step, so the transform is free on device.
     predict: Optional[Callable[..., Any]] = None
+    # Keys of ``metrics`` that are COUNTS of what a step did on a device (a
+    # routed model's slots, say), not model metrics, each with the help
+    # text of its gauge ``edl_<key>_total``.  This is their one
+    # declaration: the train step sums them over the devices instead of
+    # averaging, the worker sums them over steps into counters of the same
+    # name and never reports them as a task's metrics; evaluation drops
+    # them.
+    step_counters: Mapping[str, str] = dataclasses.field(default_factory=dict)
     # The Adam record ``optimizer`` was declared as, if it was.
     adam: Optional[Adam] = dataclasses.field(default=None, init=False)
 

@@ -1846,14 +1846,18 @@ def build_train_step(
             metrics = {
                 k: coll.psum(v * count, axes) / total
                 for k, v in raw.items()
-                if not k.startswith(HIST_PREFIX)
+                if not k.startswith(HIST_PREFIX) and k not in spec.step_counters
             }
         else:
+            raw = spec.metrics(out, batch)
             metrics = {
                 k: coll.psum(v * w, axes) / n_active
-                for k, v in spec.metrics(out, batch).items()
-                if not k.startswith(HIST_PREFIX)
+                for k, v in raw.items()
+                if not k.startswith(HIST_PREFIX) and k not in spec.step_counters
             }
+        for k in spec.step_counters:
+            # Counts of what each device did, not means: summed.
+            metrics[k] = coll.psum(raw[k] * w, axes)
         metrics["loss"] = loss
         if rows_received is not None:
             # The ragged route's load: table rows this shard served in the
@@ -2032,6 +2036,7 @@ def build_eval_step(
         return {
             k: coll.pmean(v, axes)
             for k, v in spec.metrics(out, batch).items()
+            if k not in spec.step_counters
         }
 
     if scan_steps:

@@ -106,7 +106,9 @@ COUNTER_GAUGES = {
 
 #: Step metrics (parallel/trainer.py) that are counts, not model metrics:
 #: summed over steps into the counters of the same name, never reported
-#: as a task's metrics.
+#: as a task's metrics.  These are the trainer's own; a model's are named
+#: by its ``ModelSpec.step_counters`` and join them (gauge
+#: ``edl_<name>_total``).
 STEP_COUNTERS = (
     "route_rows_recv_max", "route_rows_recv_mean",
     "table_grad_rows", "table_grad_rows_swept", "table_grad_rows_fused",
@@ -441,10 +443,13 @@ class Worker:
         # Newest counter snapshot (_counter_snapshot): replaced wholesale
         # at every report, republished as gauges at scrape time.
         self._counters: Dict[str, float] = {}  # gil-atomic
-        # STEP_COUNTERS' running sums: added to wherever a task's metrics
-        # settle (the task loop; the preemption thread's last flush).
+        # Running sums of STEP_COUNTERS and of the model's own step
+        # counters: added to wherever a task's metrics settle (the task
+        # loop; the preemption thread's last flush).
         self._step_counts_lock = threading.Lock()
-        self._step_counts = dict.fromkeys(STEP_COUNTERS, 0.0)  # guarded-by: _step_counts_lock
+        self._step_counts = dict.fromkeys(  # guarded-by: _step_counts_lock
+            (*STEP_COUNTERS, *self.spec.step_counters), 0.0
+        )
         count_compiles()
         # Task-level pipeline: the previous training task's (report, device
         # metrics), fetched + reported only after the NEXT task's steps are
@@ -981,7 +986,9 @@ class Worker:
                 ).set(float(val))
         # The newest report's counters (no device read at scrape time).
         for key, value in self._counters.items():
-            family, doc = COUNTER_GAUGES[key]
+            family, doc = COUNTER_GAUGES.get(key) or (
+                f"edl_{key}_total", self.spec.step_counters[key]
+            )
             g.gauge(family, doc).set(float(value))
         for name, secs in self.phases.snapshot().items():
             g.gauge(
@@ -1590,7 +1597,7 @@ class Worker:
     def _counter_snapshot(self) -> Dict[str, float]:
         """The worker's cumulative counters, read once per report on the
         settle path (one ``memory_stats()`` per local device; nothing per
-        step).  Keys: ``COUNTER_GAUGES``."""
+        step).  Keys: ``COUNTER_GAUGES`` and the model's step counters."""
         compiles, compile_s = compile_counts()
         with self._step_counts_lock:
             step_counts = dict(self._step_counts)
@@ -2129,7 +2136,7 @@ class Worker:
                     sums[k] = sums.get(k, 0.0) + a
                 n += steps
             with self._step_counts_lock:
-                for key in STEP_COUNTERS:
+                for key in self._step_counts:
                     if key in sums:
                         self._step_counts[key] += float(sums.pop(key))
             # finalize: scalars -> float, histogram pairs -> scalar (AUC).

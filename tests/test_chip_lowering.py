@@ -324,3 +324,136 @@ def test_gpt2_medium_step_has_no_table_update_in_it(as_on_the_chip):
         host_tier=False,
     )
     assert control.count("tpu_custom_call") == 1
+
+
+# --------------------------------------------------------------- olmoe_job
+
+
+@pytest.fixture
+def olmoe_as_on_the_chip(monkeypatch):
+    """The backend reads ``tpu`` (ops/ring_attention.py picks the flash
+    kernels by it, ops/moe.py and ops/flash_attention.py compile their
+    kernels instead of interpreting them); the devices are described."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_olmoe_step_compiles_for_v5e_with_its_scopes_and_no_row_scatter(
+    v5e_device, olmoe_as_on_the_chip
+):
+    """``olmoe_job``'s real step (OLMoE's widths, one layer, 4 sequences of
+    4096, two steps a dispatch) compiled for a described v5e: it fits the
+    chip; the five device scopes the ``.moe`` metrics read are there; the
+    attention is the three flash kernels at L = 4096, D = 128 and the
+    experts are nine grouped matmuls; under ``moe_dispatch`` and
+    ``moe_combine`` nothing scatters rows (the row movement is gathers,
+    both ways, forward and backward)."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "olmoe_1b_7b_l1.json")) as f:
+        params = json.load(f)["model_params"]
+    with open(os.path.join(root, "benchmark", "traffic", "job_seq4k.json")) as f:
+        traffic = json.load(f)
+    spec = load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", **params)
+    mesh = create_mesh([v5e_device], num_devices=1)
+    trainer = Trainer(
+        spec, JobConfig(distribution_strategy=DistributionStrategy.ALLREDUCE), mesh
+    )
+    step, args = _abstract_scan_step(
+        trainer, mesh, minibatch=traffic["minibatch_size"],
+        steps=traffic["minibatches_per_task"],
+    )
+    compiled = step.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    ma = compiled.memory_analysis()
+    total = (
+        ma.argument_size_in_bytes + ma.output_size_in_bytes
+        - ma.alias_size_in_bytes + ma.temp_size_in_bytes
+    )
+    # mostly full, and inside the chip's 15.75 GiB
+    assert 11 * 2**30 < total < 15.5 * 2**30, total / 2**30
+    text = compiled.as_text()
+    for scope in ("moe_router", "moe_dispatch", "moe_experts", "moe_combine", "lm_head"):
+        assert re.search(rf'op_name="[^"]*\b{scope}\b', text), scope
+    calls = [
+        line for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    flash = [c for c in calls if "moe_experts" not in c]
+    grouped = [c for c in calls if re.search(r'op_name="[^"]*\bmoe_experts\b', c)]
+    # forward, dQ, dK+dV: every one over [B * H, 4096, 128]
+    assert len(flash) == 3 and all("bf16[64,4096,128]" in c for c in flash), flash
+    # three projections x (forward, dx: gmm; dw: tgmm)
+    assert len(grouped) == 9
+    assert sum("jit(tgmm)" in c for c in grouped) == 3
+    assert all("bf16[65536," in c or "bf16[64," in c for c in grouped)
+    scatters = [line for line in text.splitlines() if re.search(r" scatter\(", line)]
+    for line in scatters:
+        shape = re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)
+        under = re.search(r'op_name="([^"]*)"', line)
+        rows = "," in shape  # two dimensions or more: rows
+        if under and re.search(r"\bmoe_(dispatch|combine|router)\b", under.group(1)):
+            assert not rows and int(shape) < 1024, line[:300]
+    # XLA fuses the head's dW matmul into the head's AdamW update: that ONE
+    # ``multiply_add_fusion`` carries ``lm_head`` (``lm_head_ms_step.moe``
+    # counts it), and ``optimizer_ms_step.moe`` excludes exactly it.
+    with open(os.path.join(root, "benchmark", "metrics", "optimizer_ms_step.moe.json")) as f:
+        sweeps = json.load(f)["params"]
+    named = [
+        line.strip() for line in text.splitlines()
+        if re.search(sweeps["pattern"], re.sub(r"^ROOT ", "", line.strip()))
+        and " fusion(" in line
+    ]
+    under_head = [line for line in named if re.search(r'op_name="[^"]*\blm_head\b', line)]
+    left_out = [line for line in named if re.search(sweeps["exclude"], line)]
+    assert len(named) > 10 and len(left_out) == 1 and left_out == under_head
+    # the one row scatter of the step is the token embedding's gradient
+    row_scatters = [
+        line for line in scatters
+        if "," in re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)
+    ]
+    assert len(row_scatters) == 1 and "f32[50304,2048]" in row_scatters[0]
+
+
+#: sha256 of ``gpt2_medium``'s step lowered for the chip (StableHLO text,
+#: 16 sequences of 1024, two steps a dispatch) at the parent of PR 30
+#: (f841ab1): a PR that may not move ``gpt2m_job`` pins that its program
+#: is the same to the byte.  A PR that changes ``transformer_lm`` or the
+#: trainer's step on purpose re-pins it and says so.
+GPT2_MEDIUM_STEP_SHA256 = "58a2c564d02b08ee2e9b7fecf741a8173f4b3ce9bfda70654d78a77dac6ca370"
+
+
+def test_gpt2_medium_lowered_step_is_the_pinned_program():
+    import hashlib
+    import subprocess
+    import sys
+
+    script = (
+        "import hashlib, sys, jax\n"
+        "sys.path.insert(0, 'tests')\n"
+        "from elasticdl_tpu.common.config import DistributionStrategy, JobConfig\n"
+        "from elasticdl_tpu.models.spec import load_model_spec\n"
+        "from elasticdl_tpu.parallel.mesh import create_mesh\n"
+        "from elasticdl_tpu.parallel.trainer import Trainer\n"
+        "import test_chip_lowering as T\n"
+        "spec = load_model_spec('elasticdl_tpu.models', 'transformer_lm.model_spec', vocab=50257, dim=1024,"
+        " n_heads=16, n_layers=24, seq_len=1024, max_seq=1024, remat=True, parallelism='sequence')\n"
+        "mesh = create_mesh(jax.devices()[:1], num_devices=1)\n"
+        "trainer = Trainer(spec, JobConfig(distribution_strategy=DistributionStrategy.ALLREDUCE), mesh)\n"
+        "step, args = T._abstract_scan_step(trainer, mesh, minibatch=16, steps=2)\n"
+        "text = step.trace(*args).lower(lowering_platforms=('tpu',)).as_text()\n"
+        "print('SHA', hashlib.sha256(text.encode()).hexdigest())\n"
+    )
+    # A fresh process: inner jits cached by earlier tests of this one would
+    # carry other names into the text.
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", script], cwd=root, capture_output=True, text=True,
+        timeout=600, env=dict(os.environ, PYTHONPATH=root),
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    (sha,) = re.findall(r"^SHA (\w+)$", done.stdout, re.M)
+    assert len(hashlib.sha256(b"").hexdigest()) == len(sha)
+    assert sha == GPT2_MEDIUM_STEP_SHA256

@@ -291,6 +291,33 @@ def test_counters_ride_every_training_report(tmp_path, devices):
         assert family in families, family
 
 
+def test_a_models_step_counters_are_summed_not_reported(tmp_path, devices):
+    """``ModelSpec.step_counters`` is the one declaration of a model's
+    counts: the trainer sums the key over devices, the worker over steps
+    into a counter of the same name (gauge ``edl_<key>_total``, the
+    declared help text), and no task reports it as a metric."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    config, dispatcher, _, reader, spec = _job(tmp_path, records=192)
+    spec = dataclasses.replace(
+        spec,
+        metrics=lambda out, batch, m=spec.metrics: {**m(out, batch), "widgets": jnp.float32(3.0)},
+        step_counters={"widgets": "widgets a step made"},
+    )
+    writer = MetricsWriter(str(tmp_path / "metrics"), tensorboard=False)
+    servicer = MasterServicer(dispatcher, metrics_writer=writer)
+    worker = Worker(config, DirectMasterProxy(servicer), reader, spec=spec, devices=devices[:2])
+    worker.run()
+    writer.close()
+    records = read_metrics(str(tmp_path / "metrics"))
+    # 3 a device a step, two devices, two steps a task, six tasks
+    assert [r["widgets"] for r in records if r["kind"] == "counter"] == [12.0 * n for n in range(1, 7)]
+    assert all("widgets" not in r for r in records if r["kind"] == "train")
+    assert "edl_widgets_total" in worker.gauges.snapshot()
+
+
 def test_starved_dispatches_are_the_ones_that_found_the_device_idle(tmp_path, devices):
     """Synchronous mode settles every task before the next dispatch: every
     dispatch but the first finds the previous output ready.  On ONE device:
