@@ -5,8 +5,10 @@ float32 scores are 1 GiB a sequence), and the expert layer DENSE: every
 expert on every token, times a ``[T, E]`` matrix that holds the router's
 weight at the chosen experts and 0 elsewhere.  No sort, no groups, no
 kernels, and no code of ``elasticdl_tpu/ops/moe.py`` or
-``elasticdl_tpu/models/moe_lm.py`` (the model is imported for ONE thing:
-``model_spec.init(key(0))``, whose weights are data here).
+``elasticdl_tpu/models/moe_lm.py`` (the reference takes ONE thing of the
+model: ``model_spec.init(key(0))``, whose weights are data here; the
+``checks`` at the end of this file run the model itself, as the thing
+measured).
 
 OLMoE-1B-7B's block (arXiv:2409.02060; ``transformers``' ``OlmoeModel``),
 with ``rmsnorm(x, g) = x * rsqrt(mean(x^2) + eps) * g``, eps 1e-5:
@@ -47,6 +49,17 @@ rematerialised (``jax.checkpoint`` changes memory, not values), and the
 query blocks and expert chunks are walked by ``lax.map`` (one compiled body
 each: at matmul precision ``highest`` the unrolled form took 110 s to
 compile of the child's 300).
+
+After the loss, in the same process, a reading for each of the
+configuration's ``checks``: the router's precision, which the first task's
+mean loss cannot see.  The model's own entry — ``spec.apply`` of
+``moe_lm.model_spec`` at the job's dtypes, the function the trainer's step
+differentiates — runs on the run's first minibatch from the initial weights,
+and what it hands ``elasticdl_tpu.ops.moe.route`` and gets back is read
+(:func:`routers_of_the_model`) against the float64 product, on the host, of
+those rows and the float32 router parameter (:func:`router_readings`).  The
+child reports the bare readings as ``"checks": {name: value}``; ``run.py``
+holds each against the limit in the configuration's file.
 """
 
 from __future__ import annotations
@@ -161,6 +174,77 @@ def build(p: dict):
     return forward, loss_terms
 
 
+def router_readings(u, wg, logits, choices, top_k: int) -> dict:
+    """A router's float32 ``logits`` [T, E] and ``choices`` [T, k] on the
+    rows ``u`` [T, D] and the weight ``wg`` [D, E], against the float64
+    product on the host: the largest error of a logit relative to the
+    largest logit, and the number of (token, rank) choices that differ from
+    float64's (stable, best first)."""
+    want_r = np.asarray(u, np.float64) @ np.asarray(wg, np.float64)
+    want_c = np.argsort(-want_r, axis=-1, kind="stable")[:, :top_k]
+    return {
+        "router_logits": float(np.abs(np.asarray(logits, np.float64) - want_r).max() / np.abs(want_r).max()),
+        "router_choices_differing": int(np.sum(np.asarray(choices) != want_c)),
+    }
+
+
+def routers_of_the_model(spec, before_the_call=None):
+    """A compiled ``(params, tokens, labels) -> [{"u", "logits", "choices"}]``,
+    one entry an expert layer in layer order: the rows the MODEL's own entry
+    ``spec.apply`` (``train=True``, at the job's dtypes) hands
+    ``elasticdl_tpu.ops.moe.route`` on a minibatch, and the float32 logits
+    and the choices it gets back.  The op is tapped where the model looks it
+    up (the module's attribute) while ``apply`` is traced, and at no other
+    time; a model that routes by another function is seen handing it
+    nothing, and the list is empty.  What of ``apply`` the routers' inputs do
+    not need, the compiler prunes.  ``before_the_call(u, wg) -> (u, wg)``
+    stands for a step that changes the operands on their way to the op (the
+    control)."""
+    import jax
+
+    from elasticdl_tpu.ops import moe
+
+    def run(params, tokens, labels):
+        real, seen = moe.route, []
+
+        def tapped(u, wg, k):
+            if before_the_call is not None:
+                u, wg = before_the_call(u, wg)
+            routing = real(u, wg, k)
+            seen.append({"u": u, "logits": routing.logits, "choices": routing.choices})
+            return routing
+
+        moe.route = tapped
+        try:
+            # at jax's own default matmul precision, as the job runs: this
+            # process's ``highest`` would lend the model a precision it does not ask for
+            with jax.default_matmul_precision(None):
+                spec.apply(params, {"tokens": tokens, "labels": labels}, train=True)
+        finally:
+            moe.route = real
+        return seen
+
+    return jax.jit(run)
+
+
+def router_checks(routed: list, params, top_k: int) -> dict:
+    """The configuration's two ``checks``, bare readings: every expert
+    layer's router as the model ran it (``routed``, of
+    :func:`routers_of_the_model`) against the float64 product of the rows it
+    was handed and the layer's float32 router PARAMETER (not the operand the
+    op was handed: a weight rounded on the way shows); the worst layer.
+    ``{}`` where the model did not hand the op every expert layer's rows:
+    ``run.py`` then finds no reading."""
+    names = [name for name in sorted(params["blocks"]) if "router" in params["blocks"][name]]
+    if not names or len(routed) != len(names):
+        return {}
+    readings = [
+        router_readings(r["u"], params["blocks"][name]["router"], r["logits"], r["choices"], top_k)
+        for name, r in zip(names, routed)
+    ]
+    return {key: max(reading[key] for reading in readings) for key in readings[0]}
+
+
 def main() -> None:
     t_start = time.time()
     config, traffic, data, out = parse_args()
@@ -226,8 +310,18 @@ def main() -> None:
         losses.append(loss_sum / n)
         print(f"step {i}: loss {losses[-1]:.6f} at {time.time() - t_start:.1f} s", flush=True)
         terms.append({k: v / n for k, v in aux_sum.items()})
+    result = {"loss": float(np.mean(losses)), "step_losses": losses, "step_terms": terms, "device": device_report()}
+    if config.get("checks"):
+        del params, opt_state, total
+        t_checks = time.time()
+        first = toks[:mb]  # the run's first minibatch, at the step's own size
+        params = spec.init(jax.random.key(0))
+        routed = routers_of_the_model(spec)(params, first[:, :-1], first[:, 1:])
+        result["checks"] = router_checks(routed, params, int(p["num_experts_per_tok"]))
+        result["checks_seconds"] = time.time() - t_checks
+        print(f"checks: {result['checks']} in {result['checks_seconds']:.1f} s", flush=True)
     with open(out, "w") as f_out:
-        json.dump({"loss": float(np.mean(losses)), "step_losses": losses, "step_terms": terms, "device": device_report()}, f_out)
+        json.dump(result, f_out)
 
 
 if __name__ == "__main__":

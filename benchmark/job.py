@@ -21,6 +21,13 @@ import time
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
 
+#: One numbered entry per chip's IOMMU group.  The kernel gives a group back
+#: seconds AFTER the process that held it has left /proc (8-10 s for the
+#: four of a v5e host, 3-6 s for one, PERF.md section 6): until then the
+#: next process's TPU open dies with ``Device or resource busy``.
+VFIO_DIR = "/dev/vfio"
+CHIPS_FREE_DEADLINE_S = 60.0
+
 
 class JobFailed(Exception):
     """The job cannot give a measurement (no chip, a dead worker, ...)."""
@@ -47,8 +54,10 @@ def job_argv(config: dict, traffic: dict, data_dir: str, work: str, extra: dict)
         "pod_log_dir": os.path.join(work, "pods"),
         "metrics_dir": os.path.join(work, "metrics"),
     }
-    flags.update(config.get("job_flags", {}))
-    flags.update(traffic.get("job_flags", {}))
+    # A configuration's or a traffic mix's own flags; the literal ``{work}``
+    # in a value is this run's directory (a checkpoint directory, say).
+    for own in (config.get("job_flags", {}), traffic.get("job_flags", {})):
+        flags.update({k: v.replace("{work}", work) if isinstance(v, str) else v for k, v in own.items()})
     flags.update(extra)
     argv = [sys.executable, "-m", "elasticdl_tpu.client.main", "train", "--local"]
     for key, value in flags.items():
@@ -73,8 +82,59 @@ def _pids_in_group(pgid: int) -> list:
     return pids
 
 
+def _opens(path: str) -> bool:
+    try:
+        os.close(os.open(path, os.O_RDWR))
+    except OSError:
+        return False
+    return True
+
+
+def _numbered_groups(vfio_dir: str) -> list:
+    try:
+        return [os.path.join(vfio_dir, g) for g in sorted(os.listdir(vfio_dir)) if g.isdigit()]
+    except OSError:
+        return []
+
+
+def _groups_held(pids: list, vfio_dir: str) -> list:
+    """The numbered groups under ``vfio_dir`` that one of ``pids`` has open
+    (``/proc/<pid>/fd``)."""
+    held = set()
+    for pid in pids:
+        try:
+            fds = os.listdir(f"/proc/{pid}/fd")
+        except OSError:
+            continue
+        for fd in fds:
+            try:
+                target = os.readlink(f"/proc/{pid}/fd/{fd}")
+            except OSError:
+                continue
+            if os.path.dirname(target) == vfio_dir.rstrip("/") and os.path.basename(target).isdigit():
+                held.add(target)
+    return sorted(held)
+
+
+def wait_for_chips(groups: list, deadline_s: float) -> float:
+    """Seconds waited until every one of ``groups`` opened (and was closed
+    again at once), or until the deadline, which is said on stderr; no
+    group, no wait."""
+    t0 = time.time()
+    while groups:
+        groups = [g for g in groups if not _opens(g)]
+        waited = time.time() - t0
+        if groups and waited >= deadline_s:
+            print(f"[bench] {groups} still busy {deadline_s:.0f} s after the job's end: going on", file=sys.stderr, flush=True)
+        if not groups or waited >= deadline_s:
+            return waited
+        time.sleep(0.25)
+    return 0.0
+
+
 class Job:
-    def __init__(self, argv: list, work: str, platform: str, cache_dir: str):
+    def __init__(self, argv: list, work: str, platform: str, cache_dir: str,
+                 vfio_dir: str = VFIO_DIR, chips_deadline_s: float = CHIPS_FREE_DEADLINE_S):
         self.work = work
         self.metrics_path = os.path.join(work, "metrics", "metrics.jsonl")
         self.master_log = os.path.join(work, "master.log")
@@ -96,6 +156,12 @@ class Job:
             stderr=subprocess.STDOUT, start_new_session=True,
         )
         self._requests = 0
+        self.platform, self.vfio_dir, self.chips_deadline_s = platform, vfio_dir, chips_deadline_s
+        #: the chips' groups the job's processes held when :meth:`stop` killed them
+        self.groups_held: list = []
+        #: what :meth:`wait_for_chips` polled, and the seconds it waited
+        self.chips_waited_for: list = []
+        self.chips_wait_s = 0.0
 
     # -- reading what the job writes --
 
@@ -174,8 +240,12 @@ class Job:
 
     def stop(self) -> None:
         """Kill the job's whole session and wait until every process of it
-        has ended (the worker holds the chip until it has)."""
+        has ended.  The kernel gives the chips back some seconds later: a
+        process that opens the TPU next waits for them first
+        (:meth:`wait_for_chips`)."""
         pgid = self.proc.pid
+        if self.platform == "tpu":
+            self.groups_held = _groups_held(_pids_in_group(pgid), self.vfio_dir)
         deadline = time.time() + 30.0
         while True:
             try:
@@ -187,3 +257,15 @@ class Job:
                 break
             time.sleep(0.05)
         self._log.close()
+
+    def wait_for_chips(self) -> float:
+        """After :meth:`stop`, on a TPU: poll the groups the job held (every
+        numbered group of the machine where none was seen held) until each
+        opens again or the deadline passes; the seconds waited.  Only a run
+        that starts another process on the chips (the traced run's reference
+        child) calls this: an untraced run has nothing to wait for.  A
+        machine without the directory (the CPU rehearsal) waits for nothing."""
+        if self.platform == "tpu":
+            self.chips_waited_for = self.groups_held or _numbered_groups(self.vfio_dir)
+            self.chips_wait_s = wait_for_chips(self.chips_waited_for, self.chips_deadline_s)
+        return self.chips_wait_s

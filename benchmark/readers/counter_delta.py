@@ -3,7 +3,8 @@ first and the last ``counter`` record (``runfiles.counter_records``; the
 worker's cumulative counters ride every task report into the master's
 metrics.jsonl), optionally over the growth of another counter
 (``params["over"]``), times ``params["scale"]``.  Absent with fewer than
-two records, or when the denominator did not grow."""
+two records, or when the denominator did not grow.
+(``params["how"]``, where a file has it, says ``growth`` and is not read.)"""
 
 import runfiles
 

@@ -1,5 +1,6 @@
 """One of the worker's counters as the window's last ``counter`` record
-has it (``runfiles.counter_records``), times ``params["scale"]``."""
+has it (``runfiles.counter_records``), times ``params["scale"]``.
+(``params["how"]``, where a file has it, says ``last`` and is not read.)"""
 
 import runfiles
 

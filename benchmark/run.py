@@ -54,10 +54,24 @@ def boot_line(log: str) -> dict | None:
     return json.loads(found[-1]) if found else None
 
 
+def last_words(path: str) -> str:
+    """The last line of a log that names an error (jax ends a traceback
+    with a note about its frames, not with the exception), else its last
+    line."""
+    try:
+        with open(path, errors="replace") as f:
+            lines = [line.strip() for line in f.read().strip().splitlines()]
+    except OSError:
+        return ""
+    errors = [line for line in lines if re.match(r"[\w.]*(Error|Exception)\b", line)]
+    return (errors or lines or [""])[-1][-300:]
+
+
 def run_reference(bench: Bench, config: dict, traffic: dict, first_file: str, work: str, platform: str) -> dict:
     """The plain float32 reference, in a child that runs once the job has
-    released the chip: the mean loss of the first task from the same
-    initial weights on the same records."""
+    released the chip (``Job.wait_for_chips``): the mean loss of the first
+    task from the same initial weights on the same records and, where the
+    configuration names ``checks``, a reading for each."""
     out = os.path.join(work, "reference.json")
     env = dict(os.environ, JAX_PLATFORMS=platform, JAX_COMPILATION_CACHE_DIR=CACHE_DIR)
     env["PYTHONPATH"] = ROOT + (os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
@@ -70,11 +84,44 @@ def run_reference(bench: Bench, config: dict, traffic: dict, first_file: str, wo
     with open(os.path.join(work, "reference.log"), "w") as log:
         rc = subprocess.run(argv, cwd=ROOT, env=env, stdout=log, stderr=subprocess.STDOUT, timeout=300).returncode
     if rc != 0:
-        return {"error": f"reference child exited {rc}", "seconds": time.time() - t0}
+        said = last_words(os.path.join(work, "reference.log"))
+        return {"error": f"reference child exited {rc}: {said}", "seconds": time.time() - t0}
     with open(out) as f:
         result = json.load(f)
     result["seconds"] = time.time() - t0
     return result
+
+
+def reference_problems(reference: dict, first_loss, tolerance: float, checks: dict) -> list:
+    """What the reference child's report holds against the run: the first
+    task's mean loss beside the reference's (``relative_difference`` is
+    written into ``reference``), then every check the CONFIGURATION names
+    (``checks``: ``{name: {"limit": l, ...}}``) beside the child's bare
+    reading of it (``reference["checks"]``: ``{name: value}``).  The judging
+    is here and nowhere else: a reading that is missing, not a finite number
+    or over its limit is a problem, whatever the child thinks of it;
+    ``reference["checks"]`` becomes ``{name: {"value", "limit", "ok"}}``.  A
+    configuration without ``checks`` is judged on the loss alone."""
+    if "loss" not in reference:
+        return [f"reference: {reference.get('error')}"]
+    problems = []
+    rel = abs(first_loss - reference["loss"]) / abs(reference["loss"])
+    reference["relative_difference"] = rel
+    if rel > tolerance:
+        problems.append(
+            f"first task's loss {first_loss} differs from the float32 reference "
+            f"{reference['loss']} by {rel:.2e} (tolerance {tolerance})"
+        )
+    readings, judged = reference.get("checks") or {}, {}
+    for name, check in checks.items():
+        value, limit = readings.get(name), check["limit"]
+        ok = isinstance(value, (int, float)) and not isinstance(value, bool) and math.isfinite(value) and value <= limit
+        judged[name] = {"value": value, "limit": limit, "ok": ok}
+        if not ok:
+            problems.append(f"check {name}: no reading (limit {limit})" if value is None else f"check {name}: {value} over {limit}")
+    if checks:
+        reference["checks"] = judged
+    return problems
 
 
 def main() -> int:
@@ -217,17 +264,9 @@ def main() -> int:
         trace = xplane.summarize(path) if path else {"devices": 0}
         if not args.rehearsal and not trace.get("devices"):
             problems.append("the profile holds no TPU device plane")
+        job.wait_for_chips()  # the reference child opens the TPU next
         reference = run_reference(bench, config, traffic, shape["first_file"], work, platform)
-        if "loss" not in reference:
-            problems.append(f"reference: {reference.get('error')}")
-        else:
-            rel = abs(first_loss - reference["loss"]) / abs(reference["loss"])
-            reference["relative_difference"] = rel
-            if rel > config["reference_tolerance"]:
-                problems.append(
-                    f"first task's loss {first_loss} differs from the float32 reference "
-                    f"{reference['loss']} by {rel:.2e} (tolerance {config['reference_tolerance']})"
-                )
+        problems += reference_problems(reference, first_loss, config["reference_tolerance"], config.get("checks", {}))
 
     # -- metrics --
     metrics = {}
@@ -287,6 +326,8 @@ def main() -> int:
         "data": shape,
         "costs": costs,
         "reference": reference,
+        "chips_wait_s": job.chips_wait_s,
+        "chips_waited_for": job.chips_waited_for,
         "trace_in_task": None if not (trace and trace.get("devices")) else {
             "busy_s": trace["busy_s"], "span_s": trace["span_s"], "events": trace["events"],
             "idle_pct_inside_the_traced_task": 100.0 * (1 - trace["busy_s"] / trace["span_s"]) if trace["span_s"] else None,
@@ -294,6 +335,14 @@ def main() -> int:
         "wall_s": time.time() - T_START,
     }
     print("[bench-info] " + json.dumps(info), flush=True)
+    # each number compared beside its limit, last on standard error
+    say(f"compared: first task's loss {first_loss} in {band}; compiles in the window {compiles} (limit 0); "
+        f"abandoned + duplicate_done {abandoned + duplicate} (limit 0); non-finite losses {nonfinite} (limit 0)")
+    if reference and "loss" in reference:
+        say(f"compared: loss against the reference {reference['relative_difference']:.3e} (limit {config['reference_tolerance']})")
+        for name, check in (reference["checks"] if config.get("checks") else {}).items():
+            say(f"compared: check {name} {check['value']} (limit {check['limit']})")
+    say(f"correct {not problems}: {problems}")
     if args.rehearsal:
         print("[bench-rehearsal] " + json.dumps(result), file=sys.stderr, flush=True)
         return 4

@@ -8,8 +8,12 @@ in the precision below the configuration's (the same weights rounded to
 bfloat16, so bfloat16 activations, router logits and head), forward only:
 which of the loss terms and slot counts tell that precision from float32
 (``reference_in_bfloat16``).  And the router ALONE, where its precision can
-be seen: ``ops/moe.route``'s logits on a seeded bfloat16 input against
-float64, beside the same product taken in bfloat16 (``router_logits``).
+be seen: what the model's ``apply`` hands ``ops/moe.route`` and gets back,
+against float64, beside the same with the operands rounded to bfloat16 on
+their way to the op (``router_logits``, by the reference file's
+``router_checks``: what ``correct``'s two checks read in every traced run;
+``--router_seeds 12 --router_only`` reads only that, on a dozen seeds of
+tokens, which is where the checks' limits come from).
 
     chiprun -- python3 benchmark/sizing/olmoe_against_reference.py [--seed N] [--sequences 4]
 
@@ -49,6 +53,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=2147483777)
     ap.add_argument("--sequences", type=int, default=4)
+    ap.add_argument("--router_seeds", type=int, default=1, help="read the router alone on this many seeds")
+    ap.add_argument("--router_only", action="store_true", help="stop after the router's readings")
     ap.add_argument("--rehearsal", default="", help="a rehearsal file whose model_params replace the widths (CPU dry run)")
     args = ap.parse_args()
     import jax
@@ -68,6 +74,24 @@ def main() -> None:
     params = spec.init(jax.random.key(0))
     pairs = toks[:, :-1].size * len(params["blocks"])  # (layer, token) pairs: the routers' shares are over these
 
+    # -- the routers as the MODEL runs them, against float64, and the same with the operands rounded to bfloat16 on
+    # their way to the op (the precision below the configuration's) -- the reference file's ``router_checks``: what
+    # ``correct``'s two checks read in every traced run
+    reference = load_module(bench.reference_path("olmoe_1b_7b_l1"))
+    top_k = int(p["num_experts_per_tok"])
+    router_logits = {"slots": int(toks[:, :-1].size * top_k), "seeds": {}}
+    sound = reference.routers_of_the_model(spec)
+    lower = reference.routers_of_the_model(spec, lambda u, wg: (u.astype(jnp.bfloat16), wg.astype(jnp.bfloat16)))
+    for seed in [args.seed + 1000003 * i for i in range(args.router_seeds)]:
+        batch = np.random.default_rng(seed).integers(0, vocab, (args.sequences, seq + 1)).astype(np.int32)
+        router_logits["seeds"][str(seed)] = {
+            "system": reference.router_checks(sound(params, batch[:, :-1], batch[:, 1:]), params, top_k),
+            "bfloat16": reference.router_checks(lower(params, batch[:, :-1], batch[:, 1:]), params, top_k),
+        }
+    if args.router_only:
+        print(json.dumps({"device": {"platform": jax.devices()[0].platform, "kind": jax.devices()[0].device_kind}, "router_logits": router_logits}))
+        return
+
     # -- the system, on the whole minibatch, as the train step computes it --
     def system(params, tokens, labels):
         batch = {"tokens": tokens, "labels": labels}
@@ -81,27 +105,7 @@ def main() -> None:
     sys_grads = jax.tree.map(np.asarray, grads)
     del logits, grads, metrics
 
-    # -- the router alone: float32 logits on bfloat16 rows against float64, and the product in bfloat16 --
-    from elasticdl_tpu.ops import moe
-
-    wg = params["blocks"][sorted(params["blocks"])[0]]["router"]
-    u = jax.random.normal(jax.random.key(args.seed % 2**31), (toks[:, :-1].size, wg.shape[0]), jnp.bfloat16)
-    top_k = int(p["num_experts_per_tok"])
-    routed = jax.jit(lambda u, wg: moe.route(u, wg, top_k))(u, wg)
-    low_r = jax.jit(lambda u, wg: (u @ wg.astype(jnp.bfloat16)).astype(jnp.float32))(u, wg)
-    want_r = np.asarray(u, np.float64) @ np.asarray(wg, np.float64)
-    want_c = np.argsort(-want_r, axis=-1, kind="stable")[:, :top_k]
-    off = lambda r: float(np.abs(np.asarray(r, np.float64) - want_r).max() / np.abs(want_r).max())  # noqa: E731
-    router_logits = {
-        "system_relative_to_largest": off(routed.logits), "bfloat16_relative_to_largest": off(low_r),
-        "system_choices_differing": int(np.sum(np.asarray(routed.choices) != want_c)),
-        "bfloat16_choices_differing": int(np.sum(np.argsort(-np.asarray(low_r), axis=-1, kind="stable")[:, :top_k] != want_c)),
-        "slots": int(want_c.size),
-    }
-    del routed, low_r, u
-
     # -- the reference, a sequence at a time (its own micro-batching) --
-    reference = load_module(bench.reference_path("olmoe_1b_7b_l1"))
     jax.config.update("jax_default_matmul_precision", "highest")
     _, loss_terms = reference.build(p)
     shares = jax.jit(lambda params, t, l: loss_terms(params, t, l)["f_sum"])

@@ -9,6 +9,7 @@ from __future__ import annotations
 import json
 import os
 import shutil
+import subprocess
 import sys
 
 import numpy as np
@@ -195,11 +196,8 @@ def test_new_config_traffic_metric_and_cell_are_added_as_files_only(copy):
     """A later PR adds a configuration, a traffic mix, a per-layer metric
     (with a reader of its own) and a cell without editing a file that is
     there: new files, new BENCHMARK.json entries."""
-    before = {
-        os.path.relpath(os.path.join(base, f), copy): open(os.path.join(base, f), "rb").read()
-        for base, _, files in os.walk(copy / "benchmark") for f in files
-    }
     bdir = copy / "benchmark"
+    before = _contents(bdir)
     config = json.load(open(bdir / "configs" / "deepfm_criteo.json"))
     config["model_params"]["buckets_per_feature"] = 2097152
     json.dump(config, open(bdir / "configs" / "deepfm_criteo_x4.json", "w"))
@@ -235,8 +233,139 @@ def test_new_config_traffic_metric_and_cell_are_added_as_files_only(copy):
     assert value == 42.0
     assert [m["name"] for m in bench.metrics_of("deepfm_x4_zipf", "end_to_end")] == ["examples_per_s_chip", "setup_s"]
     # nothing that was there has changed
-    for rel, content in before.items():
-        assert open(os.path.join(copy, rel), "rb").read() == content, rel
+    after = _contents(bdir)
+    assert [rel for rel in before if after[rel] != before[rel]] == []
+
+
+# ------------------------------------------- the benchmark grows by files
+
+#: Set in the environment of the test runs the rehearsal below starts, so
+#: that the copy's own rehearsal does not start them again.
+GROWING = "EDL_BENCH_GROWTH_REHEARSAL"
+
+#: What later ``model_config`` PRs do to whatever tree this is: (how many
+#: cells an earlier PR has already added to it, the cells added then).  An
+#: added cell is (its name, the cell it is made like, chips, whether it
+#: brings a configuration of its own); a four-chip cell that the quota of
+#: the grown benchmark has no room for is added on one chip, like
+#: ``deepfm_job_zipf``, instead.  Nothing here counts today's cells.
+ONE_MORE = [("added_lm_job", "gpt2m_job", 1, True)]
+GROWTH = {
+    "one_more": (0, ONE_MORE),
+    "three_more_of_which_a_four_chip_cell": (0, ONE_MORE + [("added_ctr_job", "deepfm_job", 1, False), ("added_x4_job", "deepfm_x4_job", 4, True)]),
+    "one_more_on_a_tree_that_has_grown": (1, ONE_MORE),
+}
+EARLIER = [("earlier_moe_job", "olmoe_job", 1, True)]
+
+
+def quota(n_cells: int) -> int:
+    return max(1, n_cells // 4)
+
+
+def add_cell_like(root, name: str, like: str, chips: int, own_config: bool, tag: str) -> dict:
+    """Add the cell ``name`` to the checkout at ``root`` by NEW files and
+    ``BENCHMARK.json`` entries alone: a traffic file (whose ``job_flags``
+    use the run's ``{work}`` directory), where ``own_config`` a
+    configuration with its reference, and a twin ``<metric>.<tag>`` of every
+    per-layer metric ``like`` reports; the cell's name is appended to the
+    ``workloads`` of its rate metric.  Returns the cell's entry."""
+    root = str(root)
+    spec_path = os.path.join(root, "BENCHMARK.json")
+    spec = json.load(open(spec_path))
+    bdir = os.path.join(root, spec["paths"][0])
+    (old,) = [w for w in spec["workloads"] if w["name"] == like]
+    traffic = json.load(open(os.path.join(bdir, "traffic", old["traffic"] + ".json")))
+    traffic["job_flags"] = {"checkpoint_dir": "{work}/ckpt", "checkpoint_steps": 64}
+    json.dump(traffic, open(os.path.join(bdir, "traffic", f"job_{tag}.json"), "x"))
+    config = old["config"]
+    if own_config:
+        (entry,) = [c for c in spec["configs"] if c["name"] == old["config"]]
+        config, stem = f"config_{tag}", entry["file"][: -len(".json")]
+        new_stem = os.path.join(os.path.dirname(entry["file"]), config)
+        shutil.copy(os.path.join(root, entry["file"]), os.path.join(root, new_stem + ".json"))
+        shutil.copy(os.path.join(root, stem + "_reference.py"), os.path.join(root, new_stem + "_reference.py"))
+        spec["configs"].append(dict(entry, name=config, file=new_stem + ".json", source="test: " + entry["source"][:150]))
+    cell = {"name": name, "config": config, "traffic": f"job_{tag}", "chips": chips, "why": "test: a cell a later PR adds"}
+    spec["workloads"].append(cell)
+    for metric in [m for m in spec["per_layer"] if like in m.get("workloads", [like])]:
+        twin = f"{metric['name'].rsplit('.', 1)[0]}.{tag}"
+        described = json.load(open(os.path.join(bdir, "metrics", metric["name"] + ".json")))
+        json.dump(dict(described, name=twin, cells=[name]), open(os.path.join(bdir, "metrics", twin + ".json"), "x"))
+        spec["per_layer"].append(dict(metric, name=twin, workloads=[name]))
+    next(m for m in spec["end_to_end"] if m["name"] == traffic["rate_metric"])["workloads"].append(name)
+    json.dump(spec, open(spec_path, "w"), indent=1)
+    return cell
+
+
+def _contents(root) -> dict:
+    out = {}
+    for base, dirs, files in os.walk(root):
+        dirs[:] = [d for d in dirs if d not in (".state", "__pycache__")]
+        for f in files:
+            path = os.path.join(base, f)
+            out[os.path.relpath(path, root)] = open(path, "rb").read()
+    return out
+
+
+@pytest.mark.skipif(GROWING in os.environ, reason="this IS the grown copy's run")
+@pytest.mark.parametrize("stage", sorted(GROWTH, reverse=True))
+def test_every_benchmark_test_stays_green_when_later_prs_add_cells(tmp_path, stage):
+    """The benchmark's own tests, ALL modules of ``tests/benchmark/``, run
+    against a copy of the checkout to which later PRs have added cells as
+    new files + ``BENCHMARK.json`` entries: one more one-chip cell with a
+    configuration of its own; three more, of which one takes four chips
+    where the grown benchmark's quota admits it; and one more on a tree an
+    earlier PR has already grown.  Every count is taken from the tree the
+    copy was made of, so this test holds on the trees those PRs leave.  A
+    test that pins the number of cells, the list of four-chip cells, the
+    metrics that name a reader or the metrics of a cell it does not own goes
+    red here, before it stops a PR that may not edit it."""
+    copy = tmp_path / "checkout"
+    (copy / "tests").mkdir(parents=True)
+    for name in ("BENCHMARK.json", "pyproject.toml"):
+        shutil.copy(os.path.join(ROOT, name), copy / name)
+    ignore = shutil.ignore_patterns(".state", "__pycache__")
+    shutil.copytree(BENCH_DIR, copy / "benchmark", ignore=ignore)
+    shutil.copytree(HERE, copy / "tests" / "benchmark", ignore=ignore)
+    shutil.copy(os.path.join(ROOT, "tests", "conftest.py"), copy / "tests" / "conftest.py")
+    os.symlink(os.path.join(ROOT, "elasticdl_tpu"), copy / "elasticdl_tpu")  # the system under test: not copied, not changed
+    earlier, added = GROWTH[stage]
+    for i, (name, like, chips, own_config) in enumerate(EARLIER[:earlier]):
+        add_cell_like(copy, name, like, chips, own_config, tag=f"was{i}")
+    tree = json.load(open(copy / "BENCHMARK.json"))["workloads"]
+    n0, four0 = len(tree), sum(w["chips"] == 4 for w in tree)
+    before = _contents(copy)
+    for i, (name, like, chips, own_config) in enumerate(added):
+        if chips == 4 and four0 + 1 > quota(n0 + len(added)):
+            like, chips = "deepfm_job_zipf", 1
+        add_cell_like(copy, name, like, chips, own_config, tag=f"add{i}")
+    after = _contents(copy)
+    changed = sorted(rel for rel in before if after[rel] != before[rel])
+    assert changed == ["BENCHMARK.json"] and len(after) > len(before)
+
+    # the grown benchmark resolves, keeps the quota, and a run's flags hold the run's own directory
+    import job
+
+    bench = resolve.Bench(str(copy))
+    cells = bench.spec["workloads"]
+    assert len(cells) == n0 + len(added) and [w["name"] for w in cells[:n0]] == [w["name"] for w in tree]
+    assert four0 <= sum(w["chips"] == 4 for w in cells) <= quota(len(cells))
+    cell = bench.cell("added_lm_job")
+    config, traffic = bench.config(cell["config"]), bench.traffic(cell["traffic"])
+    argv = job.job_argv(config, traffic, "/data", "/runs/added_lm_job", {})
+    assert argv[argv.index("--checkpoint_dir") + 1] == "/runs/added_lm_job/ckpt" and "{work}" not in " ".join(argv)
+    assert argv[argv.index("--checkpoint_steps") + 1] == "64"
+    assert len(bench.metrics_of("added_lm_job", "per_layer")) == len(bench.metrics_of("gpt2m_job", "per_layer"))
+
+    # ... and every module under tests/benchmark/ is green on it.  The whole-job rehearsals (45 s
+    # each) run in the first stage only: they read nothing of how many cells there are.
+    argv = [sys.executable, "-m", "pytest", "tests/benchmark", "-q", "-m", "not slow", "-p", "no:cacheprovider", "-p", "no:randomly"]
+    if stage != "one_more":
+        argv += ["-k", "not test_rehearsal_"]
+    env = dict(os.environ, **{GROWING: stage})
+    env.pop("PYTEST_XDIST_WORKER", None)
+    done = subprocess.run(argv, cwd=copy, env=env, capture_output=True, text=True, timeout=900)
+    assert done.returncode == 0, done.stdout[-4000:] + done.stderr[-2000:]
 
 
 @pytest.mark.parametrize("what,name", [("cell", "nope"), ("traffic", "nope"), ("metric_file", "nope"), ("peaks", "TPU v9")])

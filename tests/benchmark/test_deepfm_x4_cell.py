@@ -50,9 +50,9 @@ def test_the_cell_its_configuration_and_its_traffic_resolve_by_name():
     assert config["expect"]["embedding_route"] in ("ragged", "dense")
     assert os.path.isfile(bench.reference_path(cell["config"]))
     assert [m["name"] for m in bench.metrics_of(CELL, "end_to_end")] == ["examples_per_s_chip", "setup_s"]
-    # one four-chip cell of three: inside the quota
+    # a four-chip cell, inside the quota (a quarter of the cells, one always)
     four = [w["name"] for w in bench.spec["workloads"] if w["chips"] == 4]
-    assert four == [CELL] and len(four) <= max(1, len(bench.spec["workloads"]) // 4)
+    assert CELL in four and len(four) <= max(1, len(bench.spec["workloads"]) // 4)
 
 
 @pytest.mark.parametrize("name", EX4)

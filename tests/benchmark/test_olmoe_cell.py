@@ -40,6 +40,17 @@ CATALOG = {
 CATALOG_FILE = "/opt/skills/guides/model-configs/architectures.jsonl"
 
 
+def _catalog_rows(name: str, path: str = "") -> list:
+    """The catalog's rows of that name: none without the file (it is outside
+    the checkout), none when the catalog has moved on from the model."""
+    try:
+        with open(path or CATALOG_FILE) as f:
+            rows = [json.loads(line) for line in f if line.strip()]
+    except (OSError, ValueError):
+        return []
+    return [r for r in rows if r.get("name") == name]
+
+
 def test_the_cell_its_configuration_traffic_rehearsal_and_reference_resolve_by_name():
     bench = resolve.Bench(ROOT)
     cell = bench.cell(CELL)
@@ -56,9 +67,8 @@ def test_the_cell_its_configuration_traffic_rehearsal_and_reference_resolve_by_n
     assert traffic["units_per_record"] == 4096 and traffic["minibatches_per_task"] == 2
     assert traffic["minibatch_size"] in (2, 4) and traffic["rate_metric"] == "tokens_per_s_chip"
     assert traffic["job_flags"] == {} and traffic["warmup_tasks"] == 4
-    # the benchmark: five cells, one of them on four chips
-    cells = bench.spec["workloads"]
-    assert len(cells) == 5 and [w["name"] for w in cells if w["chips"] == 4] == ["deepfm_x4_job"]
+    # how many cells the benchmark has, and which take four chips, is not this cell's
+    # business: test_benchmark_yardstick.py holds the quota once, for all
 
 
 def test_the_configuration_keeps_every_published_width_and_cuts_the_depth_only():
@@ -67,10 +77,9 @@ def test_the_configuration_keeps_every_published_width_and_cuts_the_depth_only()
     config = bench.config("olmoe_1b_7b_l1")
     assert entry["reduced"] == config["reduced"] == ["num_hidden_layers"]
     assert entry["source"] == config["source"] == "https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct/blob/main/config.json"
-    assert config["published"] == CATALOG
-    if os.path.isfile(CATALOG_FILE):
-        rows = [json.loads(line) for line in open(CATALOG_FILE)]
-        (row,) = [r for r in rows if r["name"] == "OLMoE-1B-7B-0125-Instruct"]
+    assert config["published"] == CATALOG  # the pin: the copy above
+    # the catalog itself, where this machine has the file AND the file still has the row
+    for row in _catalog_rows("OLMoE-1B-7B-0125-Instruct"):
         assert row["config"] == config["published"] and row["source_url"] == config["source"]
     # the file holds every key of the published config under the same name, as it is run
     for key, value in CATALOG.items():
@@ -85,6 +94,18 @@ def test_the_configuration_keeps_every_published_width_and_cuts_the_depth_only()
     assert config["first_task_loss_band"][0] >= 10.8 and config["reference_tolerance"] <= 1e-3
 
 
+@pytest.mark.parametrize("catalog", ["absent", "without_the_row", "with_the_row"])
+def test_the_catalog_is_compared_only_where_the_file_and_the_row_exist(tmp_path, catalog):
+    path = tmp_path / "architectures.jsonl"
+    rows = [{"name": "some-other-model", "config": {}, "source_url": "x"}]
+    if catalog == "with_the_row":
+        rows.append({"name": "OLMoE-1B-7B-0125-Instruct", "config": CATALOG, "source_url": "y"})
+    if catalog != "absent":
+        path.write_text("".join(json.dumps(r) + "\n" for r in rows))
+    found = _catalog_rows("OLMoE-1B-7B-0125-Instruct", str(path))
+    assert [r["config"] for r in found] == ([CATALOG] if catalog == "with_the_row" else [])
+
+
 @pytest.mark.parametrize("name", MOE)
 def test_every_moe_metric_resolves_to_a_file_and_a_reader(name):
     bench = resolve.Bench(ROOT)
@@ -94,14 +115,14 @@ def test_every_moe_metric_resolves_to_a_file_and_a_reader(name):
     assert spec["cells"] == [CELL] and callable(bench.reader(spec["reader"]).read)
     for key in ("unit", "layer", "moves", "better", "source"):
         assert spec[key] == entry[key], key
-    assert spec["reader"] not in ("counter_delta", "counter_last")  # test_host_spans_and_counters counts those
     assert name in [m["name"] for m in bench.metrics_of(CELL, "per_layer")]
 
 
-def test_the_moe_metrics_are_exactly_these():
+def test_the_moe_metrics_are_all_there():
+    """The eighteen PR 30 named, every one reported in ``olmoe_job``; a later
+    PR may give the cell more (the list is a floor, not a fence)."""
     bench = resolve.Bench(ROOT)
-    assert sorted(m["name"] for m in bench.spec["per_layer"] if m["name"].endswith(".moe")) == sorted(MOE)
-    assert sorted(m["name"] for m in bench.metrics_of(CELL, "per_layer")) == sorted(MOE)
+    assert set(MOE) <= {m["name"] for m in bench.metrics_of(CELL, "per_layer")}
     # the flash kernels keep the operand signatures gpt2m_job's metric reads
     assert bench.metric_file("flash_roofline_pct.moe")["params"] == bench.metric_file("flash_roofline_pct.tok")["params"]
 
@@ -176,6 +197,11 @@ def test_rehearsal_trains_the_model_through_the_normal_path(tmp_path):
     assert info["reference"]["relative_difference"] < 1e-3
     terms = info["reference"]["step_terms"][0]
     assert 1.9 < terms["lb_loss"] < 2.3 and terms["z_loss"] > 0
+    # the configuration's checks ran in the same child, after the loss (PR 32)
+    checks = info["reference"]["checks"]
+    assert sorted(checks) == ["router_choices_differing", "router_logits"] and all(c["ok"] for c in checks.values()), checks
+    assert info["chips_wait_s"] == 0.0  # no chip to wait for on the CPU
+    assert "compared: check router_logits" in done.stderr
     metrics = result["metrics"]
     assert metrics["moe_slots_computed_pct.moe"]["value"] == 100.0
     assert 100.0 <= metrics["expert_load_max_pct_mean.moe"]["value"] <= 250.0
