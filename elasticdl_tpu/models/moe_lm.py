@@ -1,8 +1,12 @@
-"""Decoder-only LM with a modern block: rotary positions, QK-norm, a gated
-(SwiGLU) feed-forward that is dense or a dropless mixture of experts layer
-by layer, and a tied or untied head.  ``model_spec``'s defaults and
-parameter names are OLMoE's (``OLMoE-1B-7B-0125``: every layer ``moe``, 64
-experts, 8 a token, untied head); the published keys are the arguments.
+"""Decoder-only LM with a modern block: rotary positions, multi-head
+attention with QK-norm or latent attention, a gated (SwiGLU) feed-forward
+that is dense or a dropless mixture of experts layer by layer — with or
+without shared experts and a router correction bias — and a tied or untied
+head.  ONE decoder that adapts to the published keys, which are
+``model_spec``'s arguments: its defaults and parameter names are OLMoE's
+(``OLMoE-1B-7B-0125``: every layer ``moe``, 64 experts, 8 a token, softmax
+router, untied head); ``kv_lora_rank`` > 0 and the ``deepseek_v3`` keys make
+it DeepSeek-V3's block (``kanana-2-30b-a3b``: below).
 
 The block, with ``rmsnorm(x, g) = x * rsqrt(mean(x^2) + eps) * g``:
 
@@ -22,6 +26,32 @@ The block, with ``rmsnorm(x, g) = x * rsqrt(mean(x^2) + eps) * g``:
                                            P[e] = mean over (layer, token) pairs of p[e]
     Z   = mean over (layer, token) pairs of logsumexp(r)^2
 
+With ``kv_lora_rank`` > 0 (``transformers``' ``DeepseekV3`` modules,
+``q_lora_rank`` null) the attention and the expert layer read instead, H
+heads, ``nope`` / ``rot`` / ``v`` = ``qk_nope_head_dim`` /
+``qk_rope_head_dim`` / ``v_head_dim``:
+
+    q      = a Wq                 -> [T, H, nope + rot] = (q_nope, q_rot)
+    (c, k_rot) = a Wkv_a          -> c [T, kv_lora_rank], k_rot [T, rot]: ONE rotary key for all heads
+    (k_nope, v) = rmsnorm(c, kv_norm) Wkv_b   -> [T, H, nope], [T, H, v]
+    q_rot, k_rot = rope(q_rot), rope(k_rot)   (the rot columns only; ``rope_interleave``: pairs (2i, 2i + 1))
+    s_h    = (q_nope_h . k_nope_h + q_rot_h . k_rot) * (nope + rot)^-0.5 ; causal softmax ; o_h = p_h v_h
+    x     += o Wo
+    layers < first_k_dense_replace: dense, ``intermediate_size`` wide
+    others: r = u Wg (float32) ; s = sigmoid(r)                  (``scoring_func``)
+            e_1..e_k = top-k of (s + b)        b = the layer's correction bias (``topk_method`` noaux_tc):
+                                               it chooses, it never weighs
+            w_i = s[e_i] / (sum_j s[e_j] + 1e-20) * routed_scaling_factor      (``norm_topk_prob``)
+            x += sum_i w_i * expert_{e_i}(u) + shared(u)         experts ``moe_intermediate_size`` wide;
+                                               shared = ONE gated MLP ``n_shared_experts`` times as wide
+    after every step, by the model's own rule (``ModelSpec.after_update``; no gradient, no weight decay):
+            b_e += bias_update_speed * sign(mean_e'(c_e') - c_e),   c_e = slots the step sent to expert e
+
+Which experts are HELD (``experts_held`` of the router's ``num_experts``,
+from ``first_expert_held`` on: one chip's share under expert parallelism):
+the router keeps its width, the layer computes its own experts' part
+(``ops/moe.expert_ffn``), and ``c_e`` counts over all of the router's.
+
 ``LB`` is ``transformers``' ``load_balancing_loss_func`` (the layers'
 router outputs concatenated, one ``f`` and one ``P`` for the model); ``Z``
 is the OLMoE paper's router z-loss.  ``apply`` returns what ``loss`` and
@@ -34,9 +64,9 @@ the mesh axis shards the SEQUENCE, attention runs over the ring
 index times its chunk), parameters are replicated with psum'd gradients
 (the AllReduce strategy).  Every device holds ALL experts and routes its
 own tokens; ``f`` and ``P`` are averaged over the axis before they are
-multiplied, so the load-balancing loss is the global one.  Expert
-parallelism (experts sharded over the mesh, tokens exchanged) is out of
-scope here: ROADMAP R5.
+multiplied, so the load-balancing loss is the global one, and ``c_e`` is
+summed over it.  The exchange of expert parallelism (experts sharded over
+the mesh, tokens exchanged) is out of scope here: ROADMAP R7.
 
 bfloat16 compute, float32 parameters; router, norms' statistics, rotary
 arithmetic, logits and losses in float32.
@@ -64,13 +94,16 @@ from elasticdl_tpu.ops.ring_attention import ring_attention
 MOE_COUNTERS = {
     "moe_slots": "(token, expert) slots the routers filled, summed over "
     "expert layers, training steps and devices",
+    "moe_slots_held": "slots the routers sent to experts held on the device "
+    "(all of moe_slots where every expert is), summed likewise",
     "moe_slots_computed": "rows the experts' grouped matmuls ran (the sum of "
-    "their group sizes): equals moe_slots, or a slot was dropped",
-    "moe_expert_load_max": "slots on a device's fullest expert, summed over "
-    "expert layers, training steps and devices",
-    "moe_expert_load_mean": "slots on a device's average expert, summed likewise",
+    "the held groups' sizes): equals moe_slots_held, or a slot was dropped",
+    "moe_expert_load_max": "slots on a device's fullest held expert, summed "
+    "over expert layers, training steps and devices",
+    "moe_expert_load_mean": "slots on a device's average held expert, summed likewise",
 }
 LAYER_TYPES = ("moe", "dense")
+TOPK_METHODS = ("greedy", "noaux_tc")
 
 
 def _rms_norm(x, scale, eps):
@@ -97,12 +130,35 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
 
 
+def _rotary_columns(w: jax.Array, interleave: bool) -> jax.Array:
+    """The rotary output columns of a projection ``w`` [..., rot] in the
+    order :func:`rope` pairs them, (i, i + rot/2).  A model that pairs
+    (2i, 2i + 1) (``rope_interleave``) has its even columns moved to the
+    first half here, on the WEIGHT: the same permutation of q_rot and k_rot
+    leaves every score as it is, and no activation is shuffled."""
+    if not interleave:
+        return w
+    # a transpose, whose gradient is a transpose (two strided slices'
+    # gradient is a scatter of rows, one at a time on the TPU)
+    pairs = w.reshape(w.shape[:-1] + (w.shape[-1] // 2, 2))
+    return jnp.swapaxes(pairs, -1, -2).reshape(w.shape)
+
+
 def _init_params(
     rng, vocab_size: int, hidden_size: int, intermediate_size: int, num_experts: int,
     layer_types: Sequence[str], tie_word_embeddings: bool, init_std: float = 0.02,
+    *, n_heads: int = 0, latent: Optional[Dict[str, int]] = None, moe_intermediate_size: int = 0,
+    experts_held: int = 0, n_shared_experts: int = 0, correction_bias: bool = False,
 ) -> Dict[str, Any]:
+    """``latent`` (``kv_lora_rank``, ``nope``, ``rot``, ``v``) makes every
+    layer's attention latent; the experts are ``moe_intermediate_size`` wide
+    (0: ``intermediate_size``, as the dense layers) and ``experts_held`` of
+    the router's ``num_experts`` are here (0: all)."""
     d, f, e = hidden_size, intermediate_size, num_experts
-    ks = iter(jax.random.split(rng, 2 + 8 * len(layer_types)))
+    f_moe, held = moe_intermediate_size or f, experts_held or e
+    # OLMoE's block draws 8 keys a layer; the draws below keep their order.
+    per_layer = 12 if latent or n_shared_experts else 8
+    ks = iter(jax.random.split(rng, 2 + per_layer * len(layer_types)))
 
     def normal(shape):
         return jax.random.normal(next(ks), shape, jnp.float32) * init_std
@@ -115,18 +171,34 @@ def _init_params(
     if not tie_word_embeddings:
         params["head"] = normal((d, vocab_size))
     for i, kind in enumerate(layer_types):
-        blk = {
-            "attn_norm": jnp.ones((d,), jnp.float32),
-            "wq": normal((d, d)), "wk": normal((d, d)), "wv": normal((d, d)),
-            "wo": normal((d, d)),
-            "q_norm": jnp.ones((d,), jnp.float32),
-            "k_norm": jnp.ones((d,), jnp.float32),
-            "ffn_norm": jnp.ones((d,), jnp.float32),
-        }
+        blk = {"attn_norm": jnp.ones((d,), jnp.float32)}
+        if latent:
+            rank, nope, rot, v = (latent[key] for key in ("kv_lora_rank", "nope", "rot", "v"))
+            # The published shapes: a head's columns of wq are (nope | rot),
+            # of wkv_b (nope | v); wkv_a's are (the latent | the rotary key).
+            blk["wq"] = normal((d, n_heads * (nope + rot)))
+            blk["wkv_a"] = normal((d, rank + rot))
+            blk["kv_norm"] = jnp.ones((rank,), jnp.float32)
+            blk["wkv_b"] = normal((rank, n_heads * (nope + v)))
+            blk["wo"] = normal((n_heads * v, d))
+        else:
+            blk.update({
+                "wq": normal((d, d)), "wk": normal((d, d)), "wv": normal((d, d)),
+                "wo": normal((d, d)),
+                "q_norm": jnp.ones((d,), jnp.float32),
+                "k_norm": jnp.ones((d,), jnp.float32),
+            })
+        blk["ffn_norm"] = jnp.ones((d,), jnp.float32)
         if kind == "moe":
             blk["router"] = normal((d, e))
-            blk["w_gate"], blk["w_up"] = normal((e, d, f)), normal((e, d, f))
-            blk["w_down"] = normal((e, f, d))
+            if correction_bias:
+                blk["router_bias"] = jnp.zeros((e,), jnp.float32)
+            blk["w_gate"], blk["w_up"] = normal((held, d, f_moe)), normal((held, d, f_moe))
+            blk["w_down"] = normal((held, f_moe, d))
+            if n_shared_experts:
+                f_shared = n_shared_experts * f_moe
+                blk["ws_gate"], blk["ws_up"] = normal((d, f_shared)), normal((d, f_shared))
+                blk["ws_down"] = normal((f_shared, d))
         else:
             blk["w_gate"], blk["w_up"] = normal((d, f)), normal((d, f))
             blk["w_down"] = normal((f, d))
@@ -135,35 +207,96 @@ def _init_params(
     return params
 
 
-def _block(x, blk, positions, *, axis, n_heads, top_k, theta, eps, compute_dtype):
-    """One block: attention and a feed-forward whose kind is read off its
-    parameters (a ``router`` makes it ``moe``).  Returns (x, the layer's
-    router sums and slot counts — None for a dense layer)."""
-    b, l, dim = x.shape
-    cast = lambda w: w.astype(compute_dtype)  # noqa: E731
-    a = _rms_norm(x, blk["attn_norm"], eps)
+def _attention(a, blk, positions, *, axis, n_heads, theta, eps, cast):
+    """OLMoE's: three projections, whole-width QK-norm, rotate-half rope
+    over the whole head."""
+    b, l, dim = a.shape
     q = _qk_norm(a @ cast(blk["wq"]), blk["q_norm"], eps)
     k = _qk_norm(a @ cast(blk["wk"]), blk["k_norm"], eps)
     v = a @ cast(blk["wv"])
     heads = lambda t: t.reshape(b, l, n_heads, dim // n_heads)  # noqa: E731
     q, k = rope(heads(q), positions, theta), rope(heads(k), positions, theta)
     att = ring_attention(q, k, heads(v), axis_name=axis, causal=True)
-    x = x + att.reshape(b, l, dim) @ cast(blk["wo"])
+    return att.reshape(b, l, dim) @ cast(blk["wo"])
+
+
+def _latent_attention(a, blk, positions, *, axis, n_heads, theta, eps, cast, rot, interleave):
+    """DeepSeek-V3's (module docstring): keys and values through a latent,
+    ``rot`` rotary columns a head of q against ONE shared rotary key.  The
+    widths are read off the parameters.  Each projection is multiplied by
+    its own column block of the published matrix (a slice of the WEIGHT):
+    every product is then born in the layout the attention kernels read,
+    [B, L, H * width], with no slice of an activation in between."""
+    b, l, dim = a.shape
+    rank = blk["kv_norm"].shape[0]
+    with jax.named_scope("mla_proj"):
+        wq = blk["wq"].reshape(dim, n_heads, -1)
+        nope = wq.shape[-1] - rot
+        wkv_b = blk["wkv_b"].reshape(rank, n_heads, -1)
+        columns = lambda w: cast(w.reshape(w.shape[0], -1))  # noqa: E731
+        heads = lambda t: t.reshape(b, l, n_heads, -1)  # noqa: E731
+        q = heads(a @ columns(wq[..., :nope]))
+        q_rot = heads(a @ columns(_rotary_columns(wq[..., nope:], interleave)))
+        c = _rms_norm(a @ cast(blk["wkv_a"][:, :rank]), blk["kv_norm"], eps)
+        k_rot = a @ cast(_rotary_columns(blk["wkv_a"][:, rank:], interleave))
+        k, v = heads(c @ columns(wkv_b[..., :nope])), heads(c @ columns(wkv_b[..., nope:]))
+        q_rot = rope(q_rot, positions, theta)
+        k_rot = rope(k_rot[:, :, None, :], positions, theta)[:, :, 0]
+    att = ring_attention(q, k, v, axis_name=axis, causal=True, q_rot=q_rot, k_rot=k_rot)
+    with jax.named_scope("mla_proj"):
+        return att.reshape(b, l, -1) @ cast(blk["wo"])
+
+
+def _gated_mlp(u, w_gate, w_up, w_down):
+    return (jax.nn.silu(u @ w_gate) * (u @ w_up)) @ w_down
+
+
+def _block(
+    x, blk, positions, *, axis, n_heads, top_k, theta, eps, compute_dtype,
+    rot=0, interleave=False, router=None, first_expert_held=0,
+):
+    """One block: an attention and a feed-forward whose kinds are read off
+    its parameters (``wkv_a`` makes the attention latent, a ``router`` makes
+    the feed-forward ``moe``, ``ws_gate`` adds the shared experts).
+    ``router`` are ``ops/moe.route``'s published keys.  Returns (x, the
+    layer's router sums and slot counts — None for a dense layer)."""
+    b, l, dim = x.shape
+    cast = lambda w: w.astype(compute_dtype)  # noqa: E731
+    a = _rms_norm(x, blk["attn_norm"], eps)
+    common = dict(axis=axis, n_heads=n_heads, theta=theta, eps=eps, cast=cast)
+    if "wkv_a" in blk:
+        x = x + _latent_attention(a, blk, positions, rot=rot, interleave=interleave, **common)
+    else:
+        x = x + _attention(a, blk, positions, **common)
     u = _rms_norm(x, blk["ffn_norm"], eps)
     if "router" not in blk:
-        h = jax.nn.silu(u @ cast(blk["w_gate"])) * (u @ cast(blk["w_up"]))
-        return x + h @ cast(blk["w_down"]), None
+        return x + _gated_mlp(u, cast(blk["w_gate"]), cast(blk["w_up"]), cast(blk["w_down"])), None
     tokens = u.reshape(b * l, dim)
-    routing = moe.route(tokens, blk["router"], top_k)
-    y, sizes = moe.expert_ffn(
+    n_experts, held = blk["router"].shape[1], blk["w_gate"].shape[0]
+    # Only the keys that depart from ``route``'s defaults (OLMoE's) are
+    # passed: OLMoE's call stays ``route(u, wg, k)``, which is also what the
+    # benchmark's tap of it (``olmoe_1b_7b_l1_reference.py``) wraps.
+    keys = dict(router or {})
+    if "router_bias" in blk:
+        keys["bias"] = blk["router_bias"]
+    routing = moe.route(tokens, blk["router"], top_k, **keys)
+    y, slots = moe.expert_ffn(
         tokens, routing.choices, routing.weights,
         cast(blk["w_gate"]), cast(blk["w_up"]), cast(blk["w_down"]),
+        n_experts=n_experts, lo=first_expert_held,
     )
+    if "ws_gate" in blk:
+        with jax.named_scope("moe_shared"):
+            y = y + _gated_mlp(tokens, cast(blk["ws_gate"]), cast(blk["ws_up"]), cast(blk["ws_down"]))
     f, p, z = moe.router_stats(routing)
-    sizes = sizes.astype(jnp.float32)
+    slots = slots.astype(jnp.float32)
+    sizes = slots[first_expert_held:first_expert_held + held]
+    here = (routing.choices >= first_expert_held) & (routing.choices < first_expert_held + held)
     stats = {
         "f": f, "p": p, "z": z, "pairs": jnp.float32(b * l),
+        "slots": slots,
         "moe_slots": jnp.float32(b * l * top_k),
+        "moe_slots_held": jnp.sum(here.astype(jnp.float32)),
         "moe_slots_computed": jnp.sum(sizes),
         "moe_expert_load_max": jnp.max(sizes),
         "moe_expert_load_mean": jnp.mean(sizes),
@@ -173,8 +306,7 @@ def _block(x, blk, positions, *, axis, n_heads, top_k, theta, eps, compute_dtype
 
 def _apply(
     params, batch, train: bool = False, ctx: ParallelContext = ParallelContext(),
-    *, n_heads: int, top_k: int, theta: float, eps: float, compute_dtype, remat: bool,
-    **_,
+    *, compute_dtype, remat: bool, **block_args,
 ):
     tokens = batch["tokens"]  # [B, L_local]: sequence-sharded over the axis
     l = tokens.shape[1]
@@ -182,10 +314,7 @@ def _apply(
     offset = lax.axis_index(axis) * l if axis is not None else 0
     positions = offset + jnp.arange(l)
     x = params["tok_emb"][tokens].astype(compute_dtype)
-    block_fn = functools.partial(
-        _block, axis=axis, n_heads=n_heads, top_k=top_k, theta=theta, eps=eps,
-        compute_dtype=compute_dtype,
-    )
+    block_fn = functools.partial(_block, axis=axis, compute_dtype=compute_dtype, **block_args)
     if remat and train:
         block_fn = jax.checkpoint(block_fn)
     routed = []
@@ -194,26 +323,44 @@ def _apply(
         if stats is not None:
             routed.append(stats)
     with jax.named_scope("lm_head"):
-        x = _rms_norm(x, params["norm_f"], eps)
+        x = _rms_norm(x, params["norm_f"], block_args["eps"])
         head = params["head"] if "head" in params else params["tok_emb"].T
         logits = jnp.dot(x, head.astype(compute_dtype), preferred_element_type=jnp.float32)
     out = {"logits": logits}
     if routed:
+        slots = jnp.stack([stats.pop("slots") for stats in routed])  # [expert layers, E]
         total = jax.tree.map(lambda *leaves: sum(leaves), *routed)
         with jax.named_scope("moe_router"):
             # Shares over every (layer, token) pair of the GLOBAL batch: the
             # load-balancing loss multiplies two means, so they are taken
-            # over the axis before the product, not after.
+            # over the axis before the product, not after.  The slots an
+            # expert was sent (the correction bias's rule reads them) are
+            # summed over the devices that share the layer.
             f, p, z, pairs = (total[key] for key in ("f", "p", "z", "pairs"))
             if axis is not None:
                 # Trace-time import, as transformer_lm's: a module-level one
                 # closes the ops -> parallel -> ops import cycle.
                 from elasticdl_tpu.parallel.collectives import psum
 
-                f, p, z, pairs = (psum(t, axis) for t in (f, p, z, pairs))
+                f, p, z, pairs, slots = (psum(t, axis) for t in (f, p, z, pairs, slots))
             out["router"] = {"f": f / pairs, "p": p / pairs, "z": z / pairs}
+            out["router_slots"] = slots
         out["moe_counters"] = {key: total[key] for key in MOE_COUNTERS}
     return out
+
+
+def _update_correction_bias(params, out, *, speed: float):
+    """The model's own rule for the routers' correction biases
+    (``ModelSpec.after_update``; DeepSeek-V3, arXiv:2412.19437, section
+    2.1.2): after a step, an expert that was sent more slots than the mean
+    of its layer has its bias lowered by ``speed``, one that was sent fewer
+    has it raised.  Each expert layer has its own bias and its own counts."""
+    blocks = dict(params["blocks"])
+    routed = [name for name in sorted(blocks) if "router_bias" in blocks[name]]
+    for name, slots in zip(routed, out["router_slots"]):  # both in layer order
+        step = speed * jnp.sign(jnp.mean(slots) - slots)
+        blocks[name] = {**blocks[name], "router_bias": blocks[name]["router_bias"] + step}
+    return {**params, "blocks": blocks}
 
 
 def _cross_entropy(out, batch):
@@ -237,9 +384,12 @@ def _router_losses(out):
 
 
 def _terms(out, batch, lb_coef: float, z_coef: float):
-    """(the total the optimizer descends, CE, LB, Z)."""
+    """(the total the optimizer descends, CE, LB, Z).  With both
+    coefficients 0 (a model trained on CE alone) the total IS the CE."""
     ce = _cross_entropy(out, batch)
     lb, z = _router_losses(out)
+    if not lb_coef and not z_coef:
+        return ce, ce, lb, z
     return ce + lb_coef * lb + z_coef * z, ce, lb, z
 
 
@@ -268,6 +418,13 @@ def _example_batch(batch_size: int, seq_len: int):
     }
 
 
+def _is_decayed(params):
+    """AdamW's weight-decay mask: every leaf but the correction biases."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) != "router_bias", params
+    )
+
+
 def model_spec(
     learning_rate: float = 4e-4,
     compute_dtype: str = "bfloat16",
@@ -286,28 +443,92 @@ def model_spec(
     router_aux_loss_coef: float = 0.01,
     router_z_loss_coef: float = 0.001,
     weight_decay: float = 0.1,
+    lr_warmup_steps: int = 0,
     remat: bool = True,
+    # deepseek_v3's keys (defaults: OLMoE's block)
+    kv_lora_rank: int = 0,
+    q_lora_rank: Optional[int] = None,
+    qk_nope_head_dim: int = 0,
+    qk_rope_head_dim: int = 0,
+    v_head_dim: int = 0,
+    rope_interleave: bool = False,
+    moe_intermediate_size: int = 0,
+    n_shared_experts: int = 0,
+    first_k_dense_replace: int = 0,
+    scoring_func: str = "softmax",
+    norm_topk_prob: bool = False,
+    routed_scaling_factor: float = 1.0,
+    topk_method: str = "greedy",
+    n_group: int = 1,
+    topk_group: int = 1,
+    bias_update_speed: float = 0.001,
+    experts_held: int = 0,
+    first_expert_held: int = 0,
 ) -> ModelSpec:
     """``layer_types`` names each layer's feed-forward, ``"moe"`` or
-    ``"dense"`` (both gated, ``intermediate_size`` wide); None = every one
-    of the ``num_hidden_layers`` is ``moe``, as in OLMoE."""
-    layer_types = tuple(layer_types or ("moe",) * num_hidden_layers)
+    ``"dense"`` (both gated; dense layers ``intermediate_size`` wide, experts
+    ``moe_intermediate_size``, 0 = the same); None = the first
+    ``first_k_dense_replace`` are dense and the rest ``moe`` (all ``moe`` in
+    OLMoE).  ``kv_lora_rank`` > 0 = latent attention at ``qk_nope_head_dim``
+    + ``qk_rope_head_dim`` / ``v_head_dim``.  ``num_experts`` is the
+    ROUTER's width; ``experts_held`` of them (0 = all), from
+    ``first_expert_held`` on, are computed here.  ``topk_method``
+    ``noaux_tc`` gives every expert layer a correction bias, moved after
+    each step by ``bias_update_speed`` (module docstring).
+    ``lr_warmup_steps`` > 0 raises the learning rate linearly from 0 over
+    that many steps (0: flat from the first step)."""
+    if layer_types is None:
+        dense = min(first_k_dense_replace, num_hidden_layers)
+        layer_types = ("dense",) * dense + ("moe",) * (num_hidden_layers - dense)
+    layer_types = tuple(layer_types)
     if len(layer_types) != num_hidden_layers or set(layer_types) - set(LAYER_TYPES):
         raise ValueError(
             f"layer_types must name {num_hidden_layers} layers from {LAYER_TYPES}, "
             f"got {layer_types!r}"
         )
-    if hidden_size % num_attention_heads or (hidden_size // num_attention_heads) % 2:
+    latent = None
+    if kv_lora_rank:
+        if q_lora_rank is not None:
+            raise ValueError("a low-rank query projection (q_lora_rank) is not supported: no cell runs one")
+        if min(qk_nope_head_dim, v_head_dim) <= 0 or qk_rope_head_dim <= 0 or qk_rope_head_dim % 2:
+            raise ValueError(
+                f"latent attention needs qk_nope_head_dim, v_head_dim and an even qk_rope_head_dim, got "
+                f"{qk_nope_head_dim} / {v_head_dim} / {qk_rope_head_dim}"
+            )
+        latent = {"kv_lora_rank": kv_lora_rank, "nope": qk_nope_head_dim, "rot": qk_rope_head_dim, "v": v_head_dim}
+    elif hidden_size % num_attention_heads or (hidden_size // num_attention_heads) % 2:
         raise ValueError(
             f"hidden_size {hidden_size} must split into {num_attention_heads} heads "
             f"of even width (rotary pairs)"
         )
     if num_experts_per_tok > num_experts:
         raise ValueError(f"top-{num_experts_per_tok} of {num_experts} experts")
+    if (n_group, topk_group) != (1, 1):
+        raise ValueError(f"group-limited routing (n_group {n_group}, topk_group {topk_group}) is not supported: no cell runs it")
+    if topk_method not in TOPK_METHODS or scoring_func not in moe.SCORING_FUNCS:
+        raise ValueError(
+            f"topk_method {topk_method!r} / scoring_func {scoring_func!r}: known are "
+            f"{TOPK_METHODS} / {moe.SCORING_FUNCS}"
+        )
+    held = experts_held or num_experts
+    if not 0 <= first_expert_held <= num_experts - held:
+        raise ValueError(f"experts [{first_expert_held}, {first_expert_held + held}) are not among the router's {num_experts}")
+    correction_bias = topk_method == "noaux_tc" and "moe" in layer_types
     apply = functools.partial(
         _apply, n_heads=num_attention_heads, top_k=num_experts_per_tok,
         theta=float(rope_theta), eps=float(rms_norm_eps),
         compute_dtype=jnp.dtype(compute_dtype), remat=remat,
+        rot=qk_rope_head_dim if latent else 0, interleave=bool(rope_interleave),
+        router={
+            key: value
+            for key, value, default in (
+                ("scoring_func", scoring_func, "softmax"),
+                ("norm_topk_prob", bool(norm_topk_prob), False),
+                ("routed_scaling_factor", float(routed_scaling_factor), 1.0),
+            )
+            if value != default
+        },
+        first_expert_held=first_expert_held,
     )
     coefs = dict(lb_coef=router_aux_loss_coef, z_coef=router_z_loss_coef)
     return ModelSpec(
@@ -316,16 +537,27 @@ def model_spec(
             _init_params, vocab_size=vocab_size, hidden_size=hidden_size,
             intermediate_size=intermediate_size, num_experts=num_experts,
             layer_types=layer_types, tie_word_embeddings=tie_word_embeddings,
+            n_heads=num_attention_heads, latent=latent,
+            moe_intermediate_size=moe_intermediate_size, experts_held=experts_held,
+            n_shared_experts=n_shared_experts, correction_bias=correction_bias,
         ),
         apply=apply,
         loss=functools.partial(_loss, **coefs),
         metrics=functools.partial(_metrics, **coefs),
         optimizer=optax.adamw(
-            learning_rate, b1=0.9, b2=0.95, eps=1e-8, weight_decay=weight_decay
+            optax.linear_schedule(0.0, learning_rate, lr_warmup_steps) if lr_warmup_steps else learning_rate,
+            b1=0.9, b2=0.95, eps=1e-8, weight_decay=weight_decay,
+            # a correction bias gets no gradient (it only chooses) and no decay:
+            # Adam's update of a leaf whose gradient is always 0 is exactly 0
+            mask=_is_decayed if correction_bias else None,
         ),
         feed=lm_feed,
         example_batch=functools.partial(_example_batch, seq_len=seq_len),
         batch_shard_dim=1,
         predict=functools.partial(_predict, apply=apply),
         step_counters=MOE_COUNTERS if "moe" in layer_types else {},
+        after_update=(
+            functools.partial(_update_correction_bias, speed=float(bias_update_speed))
+            if correction_bias else None
+        ),
     )

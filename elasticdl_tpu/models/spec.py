@@ -145,6 +145,15 @@ class ModelSpec:
     # name and never reports them as a task's metrics; evaluation drops
     # them.
     step_counters: Mapping[str, str] = dataclasses.field(default_factory=dict)
+    # The model's own rule for parameters that no gradient moves (a
+    # router's correction bias, say): ``(params, out) -> params``, given the
+    # parameters as the optimizer left them and the step's ``apply`` output,
+    # inside the jitted train step, after the optimizer's update.  The
+    # model builds its ``optimizer`` so that it leaves those leaves alone
+    # (no gradient reaches them; it masks them out of any weight decay),
+    # and sums over the mesh inside ``apply`` whatever the rule reads, so
+    # that every replica makes the same move.
+    after_update: Optional[Callable[[Params, Any], Params]] = None
     # The Adam record ``optimizer`` was declared as, if it was.
     adam: Optional[Adam] = dataclasses.field(default=None, init=False)
 

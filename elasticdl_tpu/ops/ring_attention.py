@@ -64,12 +64,24 @@ def _rotate(x: jax.Array, axis_name: str) -> jax.Array:
     return lax.ppermute(x, axis_name, [(j, (j + 1) % n) for j in range(n)])
 
 
+def _scores(q, k, q_rot, k_rot):
+    """[B, H, Lq, Lk] scaled scores: ``q . k`` and, where a rotary part is
+    given (``q_rot`` [B, Lq, H, R], ``k_rot`` [B, Lk, R]: ONE key for every
+    head — latent attention), ``+ q_rot . k_rot``, over the root of the
+    whole width."""
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    if q_rot is None:
+        return scores * q.shape[-1] ** -0.5
+    scores += jnp.einsum("bqhr,bkr->bhqk", q_rot, k_rot)
+    return scores * (q.shape[-1] + q_rot.shape[-1]) ** -0.5
+
+
 def attention_reference(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
+    q_rot: Optional[jax.Array] = None, k_rot: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Plain full attention ([B, L, H, D] layout) — the numerics oracle."""
-    scale = q.shape[-1] ** -0.5
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    scores = _scores(q, k, q_rot, k_rot)
     if causal:
         lq, lk = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((lq, lk), bool), lk - lq)
@@ -78,7 +90,7 @@ def attention_reference(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _local_attention(q, k, v, causal: bool) -> jax.Array:
+def _local_attention(q, k, v, causal: bool, q_rot=None, k_rot=None) -> jax.Array:
     """Exact single-shard attention: the Pallas flash kernel on TPU (no
     O(L^2) HBM tensors — at the MFU-bench shape the XLA path's saved
     probability tensors alone are ~19 GB at b=32, the difference between
@@ -94,12 +106,14 @@ def _local_attention(q, k, v, causal: bool) -> jax.Array:
     if backend != "tpu":
         why_not = f"backend={backend}"
     else:
-        why_not = outside_contract(q, k, v)
+        why_not = outside_contract(q, k, v, q_rot, k_rot)
         if not why_not:
-            return flash_attention(q, k, v, causal)
+            return flash_attention(q, k, v, causal, q_rot, k_rot)
         why_not = f"outside the flash kernels' contract: {why_not}"
+    if q_rot is not None:
+        why_not += f" rotary={q_rot.shape[-1]}"
     announce_path(PATH_XLA_REFERENCE, q, causal, why_not)
-    return attention_reference(q, k, v, causal=causal)
+    return attention_reference(q, k, v, causal, q_rot, k_rot)
 
 
 def ring_attention(
@@ -108,32 +122,36 @@ def ring_attention(
     v: jax.Array,
     axis_name: Optional[str] = None,
     causal: bool = False,
+    q_rot: Optional[jax.Array] = None,
+    k_rot: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Blockwise attention with K/V ring rotation over ``axis_name``.
 
     Inputs are the LOCAL sequence shards ``[B, L_local, H, D]`` (inside
     shard_map over the ``sp`` axis); the output is the local shard of the
     full-attention result.  With ``axis_name=None`` (or outside shard_map)
-    it degrades to exact single-device attention.
+    it degrades to exact single-device attention.  ``q_rot`` [B, L_local,
+    H, R] and ``k_rot`` [B, L_local, R] add a second product to the score
+    (:func:`_scores`); the shared key rides the ring with K and V.
     """
     if axis_name is None:
-        return _local_attention(q, k, v, causal)
+        return _local_attention(q, k, v, causal, q_rot, k_rot)
 
     n = axis_size(axis_name)
     if n == 1:
         # Degenerate ring (1-device mesh under shard_map): exact local
         # attention, flash-kernelled on TPU.
-        return _local_attention(q, k, v, causal)
+        return _local_attention(q, k, v, causal, q_rot, k_rot)
     announce_path(PATH_XLA_RING, q, causal, f"n={n}")
     my = lax.axis_index(axis_name)
     b, lq, h, d = q.shape
     lk = k.shape[1]
-    scale = d**-0.5
     q_pos = my * lq + jnp.arange(lq)  # global positions of local queries
+    f32 = lambda x: None if x is None else x.astype(jnp.float32)  # noqa: E731
 
-    def accumulate(acc, src, k_blk, v_blk):
+    def accumulate(acc, src, k_blk, v_blk, kr_blk):
         o, m, l = acc
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k_blk) * scale
+        scores = _scores(q, k_blk, q_rot, kr_blk)
         if causal:
             kv_pos = src * lk + jnp.arange(lk)
             mask = q_pos[:, None] >= kv_pos[None, :]  # [lq, lk]
@@ -153,23 +171,17 @@ def ring_attention(
     o0 = jnp.zeros((b, h, lq, d), jnp.float32)
     m0 = jnp.full((b, h, lq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, h, lq), jnp.float32)
-    acc = accumulate(
-        (o0, m0, l0), my, k.astype(jnp.float32), v.astype(jnp.float32)
-    )
+    blocks = (f32(k), f32(v), f32(k_rot))
+    acc = accumulate((o0, m0, l0), my, *blocks)
 
     def step(carry, i):
-        acc, k_blk, v_blk = carry
-        k_blk = _rotate(k_blk, axis_name)
-        v_blk = _rotate(v_blk, axis_name)
-        acc = accumulate(acc, (my - i) % n, k_blk, v_blk)
-        return (acc, k_blk, v_blk), None
+        acc, blocks = carry
+        blocks = jax.tree.map(lambda x: _rotate(x, axis_name), blocks)
+        acc = accumulate(acc, (my - i) % n, *blocks)
+        return (acc, blocks), None
 
     if n > 1:
-        (acc, _, _), _ = lax.scan(
-            step,
-            (acc, k.astype(jnp.float32), v.astype(jnp.float32)),
-            jnp.arange(1, n),
-        )
+        (acc, _), _ = lax.scan(step, (acc, blocks), jnp.arange(1, n))
     o, m, l = acc
     out = o / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 2, 1, 3).astype(q.dtype)  # [B, Lq, H, D]

@@ -1837,6 +1837,9 @@ def build_train_step(
                     grads, state.opt_state, state.params
                 )
                 params = optax.apply_updates(state.params, updates)
+        if spec.after_update is not None:
+            # Leaves the optimizer leaves alone, moved by the model's rule.
+            params = spec.after_update(params, out)
         # Histogram metrics (streaming AUC, common/metrics.HIST_PREFIX) are
         # EVAL machinery — per-minibatch training AUC is noise, and the
         # reference computes AUC only in evaluation — so the train step

@@ -474,6 +474,131 @@ def test_olmoe_step_compiles_for_v5e_with_its_scopes_and_no_row_scatter(
     assert len(row_scatters) == 1 and "f32[50304,2048]" in row_scatters[0]
 
 
+# ------------------------------------------------------------- kanana2_job
+
+
+def _signatures(text: str):
+    """(bf16 operands, f32 operands) of every Mosaic call in a compiled
+    program, sorted: what the ``flash_roofline_pct.*`` patterns tell the
+    three flash kernels apart by."""
+    calls = re.findall(
+        r"custom_call_target=\"tpu_custom_call\", "
+        r"operand_layout_constraints=\{(.*?)\}, frontend_attributes",
+        text,
+    )
+    return sorted((ops.count("bf16["), ops.count("f32[")) for ops in calls)
+
+
+def test_flash_with_a_rotary_part_compiles_for_v5e(compiled_kernel, v5e_device, path_lines):
+    """Latent attention's call at ``kanana2_job``'s shape, [2, 8192, 32,
+    128 + 64 / 128] with ONE shared rotary key: Mosaic accepts the three
+    kernels under the default scoped-VMEM limit, at two more bf16 operands
+    each than the plain calls (``flash_roofline_pct.mla`` reads 5 / 6 + 1 /
+    6 + 2), over the model's own [B, L, H * width] arrays and the key tiled
+    to one block of lanes."""
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.bfloat16, sharding=jax.sharding.SingleDeviceSharding(v5e_device)
+        )
+
+    def loss(q, k, v, q_rot, k_rot):
+        return jnp.sum(fa.flash_attention(q, k, v, True, q_rot, k_rot).astype(jnp.float32) ** 2)
+
+    wide = arg(2, 8192, 32, 128)
+    text = (
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))
+        .trace(wide, wide, wide, arg(2, 8192, 32, 64), arg(2, 8192, 64))
+        .lower(lowering_platforms=("tpu",))
+        .compile()
+        .as_text()
+    )
+    assert _signatures(text) == [(5, 0), (6, 1), (6, 2)]
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert all("bf16[2,8192,4096]" in c and "bf16[2,8192,2048]" in c and "bf16[2,8192,128]" in c for c in calls)
+    (line,) = set(path_lines)
+    assert "attention path: pallas-compiled" in line and line.endswith("heads_per_block=1 rotary=64)")
+
+
+def test_kanana2_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
+    v5e_device, olmoe_as_on_the_chip, path_lines
+):
+    """``kanana2_job``'s real step (kanana-2's widths, the dense layer and
+    four expert layers of 16 held experts, 2 sequences of 8192, two steps a
+    dispatch) compiled for a described v5e: it fits the chip; the eight
+    device scopes the ``.mla`` metrics read are there; the attention is the
+    three flash kernels with a rotary part at the operand lists
+    ``flash_roofline_pct.mla`` reads; the experts are grouped matmuls over
+    the router's 128 group sizes and the 16 held experts' weights; nothing
+    scatters rows under a ``moe_*`` scope or under ``mla_proj``; and no
+    array of [*, 8192, 8192] exists anywhere (the XLA attention path would
+    write 32 of them a sequence)."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "kanana2_30b_a3b_ep8_l5.json")) as f:
+        params = json.load(f)["model_params"]
+    with open(os.path.join(root, "benchmark", "traffic", "job_seq8k.json")) as f:
+        traffic = json.load(f)
+    spec = load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", **params)
+    mesh = create_mesh([v5e_device], num_devices=1)
+    trainer = Trainer(
+        spec, JobConfig(distribution_strategy=DistributionStrategy.ALLREDUCE), mesh
+    )
+    step, args = _abstract_scan_step(
+        trainer, mesh, minibatch=traffic["minibatch_size"],
+        steps=traffic["minibatches_per_task"],
+    )
+    compiled = step.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    ma = compiled.memory_analysis()
+    total = (
+        ma.argument_size_in_bytes + ma.output_size_in_bytes
+        - ma.alias_size_in_bytes + ma.temp_size_in_bytes
+    )
+    # over a quarter of the chip by a wide margin, and inside its 15.75 GiB
+    assert 8 * 2**30 < total < 15.5 * 2**30, total / 2**30
+    text = compiled.as_text()
+    for scope in ("mla_proj", "flash_attn", "moe_router", "moe_dispatch", "moe_experts", "moe_shared",
+                  "moe_combine", "lm_head"):
+        assert re.search(rf'op_name="[^"]*\b{scope}\b', text), scope
+    assert not re.search(r"\[(\d+,)*8192,8192\]", text)
+    assert any(line.endswith("heads_per_block=1 rotary=64)") for line in path_lines), path_lines
+    layers, expert_layers = params["num_hidden_layers"], params["num_hidden_layers"] - params["first_k_dense_replace"]
+    runs = 2 if params["remat"] else 1  # a rematerialised block runs its forward twice
+    flash = _flash_calls(text)
+    assert len(flash) == (runs + 2) * layers
+    lists = re.findall(
+        r"custom_call_target=\"tpu_custom_call\", operand_layout_constraints=\{(.*?)\}, frontend_attributes",
+        "\n".join(flash),
+    )
+    assert sorted({(ops.count("bf16["), ops.count("f32[")) for ops in lists}) == [(5, 0), (6, 1), (6, 2)]
+    assert all("bf16[2,8192,4096]" in c and "bf16[2,8192,128]" in c for c in flash), flash[:1]
+    grouped = [
+        line for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line and re.search(r'op_name="[^"]*\bmoe_experts\b', line)
+    ]
+    # three projections x (forward, its re-run, dx: gmm; dw: tgmm), every expert layer,
+    # over all T * k = 98,304 slot rows and the 16 held experts' weights
+    assert len(grouped) == 3 * (runs + 2) * expert_layers
+    assert sum("jit(tgmm)" in c for c in grouped) == 3 * expert_layers
+    assert all("bf16[98304," in c and ("bf16[16," in c) for c in grouped), grouped[:1]
+    scatters = [line for line in text.splitlines() if re.search(r" scatter\(", line)]
+    for line in scatters:
+        shape = re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)
+        under = re.search(r'op_name="([^"]*)"', line)
+        if under and re.search(r"\b(moe_(dispatch|combine|router|shared|experts)|mla_proj)\b", under.group(1)):
+            assert "," not in shape and int(shape) < 1024, line[:300]
+    # the one row scatter of the step is the token embedding's gradient
+    row_scatters = [
+        line for line in scatters
+        if "," in re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)
+    ]
+    assert len(row_scatters) == 1 and "f32[16032,2048]" in row_scatters[0]
+    # the correction biases are parameters of the step that no optimizer sweep writes a
+    # gradient into: their update is the model's own rule (sign of mean - count)
+    assert re.search(r'op_name="[^"]*sign', text)
+
+
 #: sha256 of ``gpt2_medium``'s step lowered for the chip (StableHLO text,
 #: 16 sequences of 1024, two steps a dispatch): a PR that may not move
 #: ``gpt2m_job`` pins that its program is the same to the byte.  A PR that

@@ -227,3 +227,121 @@ def test_attention_path_line_reports_key_tiles(monkeypatch, causal):
     assert (visited < total) == causal
     assert "key_tiles=3/4 fwd, 10/16 bwd" in line or not causal
     assert line.endswith("heads_per_block=2)")
+
+
+# -- a score of two products (PR 33) -----------------------------------------
+# Latent attention: a head's score is a 128-wide product plus a rotary product
+# whose KEY is one head shared by all, its value 128 wide.  The oracle is an
+# explicit masked softmax over the concatenated 128 + R wide queries and keys,
+# the shared key copied to every head.
+
+
+def _rotary_inputs(dtype, b=1, l=256, h=2, r=64, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    q, k, v = ((jax.random.normal(ks[i], (b, l, h, 128)) * 0.5).astype(dtype) for i in range(3))
+    q_rot = (jax.random.normal(ks[3], (b, l, h, r)) * 0.5).astype(dtype)
+    k_rot = (jax.random.normal(ks[4], (b, l, r)) * 0.5).astype(dtype)
+    return q, k, v, q_rot, k_rot
+
+
+def _explicit_masked_softmax(q, k, v, q_rot, k_rot, causal):
+    h = q.shape[2]
+    q_full = jnp.concatenate([q, q_rot], -1)
+    k_full = jnp.concatenate([k, jnp.repeat(k_rot[:, :, None, :], h, axis=2)], -1)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q_full, k_full) / np.sqrt(q_full.shape[-1])
+    if causal:
+        l = scores.shape[-1]
+        scores = jnp.where(jnp.tril(jnp.ones((l, l), bool)), scores, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
+
+
+def _rotary_grads(attn, args, cot):
+    return jax.grad(lambda *a: jnp.vdot(attn(*a).astype(jnp.float32), cot), argnums=(0, 1, 2, 3, 4))(*args)
+
+
+ROTARY = [(2, 64), (4, 64), (4, 32), (1, 128)]
+rotary = pytest.mark.parametrize("h,r", ROTARY, ids=[f"{h}x128+{r}" for h, r in ROTARY])
+
+
+@rotary
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 5e-5), (jnp.bfloat16, 3e-2)], ids=["f32", "bf16"])
+def test_rotary_part_forward_and_gradients_match_an_explicit_masked_softmax(dtype, tol, causal, h, r):
+    """Forward, dq, dk, dv, dq_rot and dk_rot — the shared key's gradient is
+    the SUM over the heads that share it."""
+    args = _rotary_inputs(dtype, h=h, r=r, seed=h + r)
+    f32 = tuple(x.astype(jnp.float32) for x in args)
+    cot = jax.random.normal(jax.random.key(9), args[0].shape, jnp.float32)
+    flash = lambda q, k, v, qr, kr: flash_attention(q, k, v, causal, qr, kr)  # noqa: E731
+    ref = lambda *a: _explicit_masked_softmax(*a, causal)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(flash(*args), np.float32), np.asarray(ref(*f32)), atol=tol, rtol=tol)
+    for got, want, name in zip(_rotary_grads(flash, args, cot), _rotary_grads(ref, f32, cot),
+                               ("dq", "dk", "dv", "dq_rot", "dk_rot")):
+        assert got.shape == want.shape, name
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=tol, rtol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("block,l", [(128, 512), (256, 384)])
+def test_rotary_part_carries_state_across_pairs(monkeypatch, block, l, causal):
+    """Many pairs a head, the head a grid axis BEFORE the key blocks: the
+    first / last visited pair, the skipped ones, the scratch carry and the
+    two heads that share a block of q_rot; L = 384 in blocks of 256 leaves
+    padding in the last block."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_BLOCK", block)
+    monkeypatch.setattr(fa, "_T_FWD", 128)
+    monkeypatch.setattr(fa, "_T_BWD", 128)
+    args = _rotary_inputs(jnp.float32, b=2, l=l, h=4, r=64, seed=block)
+    cot = jax.random.normal(jax.random.key(2), args[0].shape, jnp.float32)
+    flash = lambda q, k, v, qr, kr: fa.flash_attention(q, k, v, causal, qr, kr)  # noqa: E731
+    ref = lambda *a: _explicit_masked_softmax(*a, causal)  # noqa: E731
+    np.testing.assert_allclose(flash(*args), ref(*args), atol=2e-5, rtol=2e-5)
+    for got, want, name in zip(_rotary_grads(flash, args, cot), _rotary_grads(ref, args, cot),
+                               ("dq", "dk", "dv", "dq_rot", "dk_rot")):
+        np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5, err_msg=name)
+
+
+def test_rotary_part_is_the_dispatchers_reference_too():
+    """``attention_reference`` with the rotary part (the CPU's, the
+    rehearsal's and the ring's path) is the explicit softmax, scale
+    (128 + R)^-0.5."""
+    args = _rotary_inputs(jnp.float32, h=2, r=64, seed=5)
+    for causal in (False, True):
+        np.testing.assert_allclose(
+            attention_reference(*args[:3], causal, *args[3:]), _explicit_masked_softmax(*args, causal),
+            atol=2e-6, rtol=2e-6,
+        )
+
+
+@pytest.mark.parametrize(
+    "d,h,r,why",
+    [(64, 2, 64, "a rotary part needs head_dim = 128"), (128, 3, 64, "H whole groups of 128 // R heads"),
+     (128, 2, 48, "R a divisor of 128")],
+)
+def test_rotary_shapes_outside_the_contract_take_the_reference_path(monkeypatch, d, h, r, why):
+    from elasticdl_tpu.ops import ring_attention
+
+    lines = []
+    monkeypatch.setattr(ring_attention, "_log_once", lines.append)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ks = jax.random.split(jax.random.key(0), 5)
+    q, k, v = (jax.random.normal(ks[i], (1, 128, h, d)) for i in range(3))
+    q_rot, k_rot = jax.random.normal(ks[3], (1, 128, h, r)), jax.random.normal(ks[4], (1, 128, r))
+    out = ring_attention._local_attention(q, k, v, True, q_rot, k_rot)
+    (line,) = lines
+    assert "attention path: xla-reference" in line and why in line and line.endswith(f"rotary={r})")
+    np.testing.assert_array_equal(out, attention_reference(q, k, v, True, q_rot, k_rot))
+    with pytest.raises(ValueError, match="a rotary part needs"):
+        flash_attention(q, k, v, True, q_rot, k_rot)
+
+
+def test_attention_path_line_names_the_rotary_width(monkeypatch):
+    from elasticdl_tpu.ops import ring_attention
+
+    lines = []
+    monkeypatch.setattr(ring_attention, "_log_once", lines.append)
+    flash_attention(*_rotary_inputs(jnp.bfloat16, l=128)[:3], True, *_rotary_inputs(jnp.bfloat16, l=128)[3:])
+    (line,) = lines
+    assert "attention path: pallas-interpret" in line and line.endswith("heads_per_block=1 rotary=64)")

@@ -1,0 +1,376 @@
+"""``models/moe_lm.py`` under DeepSeek-V3's keys — latent attention, a
+sigmoid router with a correction bias, shared experts, a leading dense
+layer, a SHARE of the experts held — against the plain reference
+(``benchmark/configs/kanana2_30b_a3b_ep8_l5_reference.py``: textbook 192-wide
+attention, dense experts, no code shared with the model or ``ops/``) on seeded
+random weights at small sizes, and the pieces one by one: the router, the
+bias's rule, the held range of ``ops/moe.expert_ffn``, the shares that add
+up to the whole layer.  CPU only."""
+
+from __future__ import annotations
+
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elasticdl_tpu.common.config import JobConfig
+from elasticdl_tpu.models import moe_lm
+from elasticdl_tpu.models.spec import load_model_spec
+from elasticdl_tpu.ops import moe
+from elasticdl_tpu.parallel.mesh import create_mesh
+from elasticdl_tpu.parallel.trainer import Trainer
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = os.path.join(ROOT, "benchmark")
+if BENCH_DIR not in sys.path:
+    sys.path.insert(0, BENCH_DIR)
+
+import resolve  # noqa: E402
+
+#: kanana-2's keys at a small size: 16 experts of which 4 are held, top-3.
+KEYS = dict(
+    vocab_size=256, hidden_size=64, num_attention_heads=4, num_experts=16, num_experts_per_tok=3,
+    intermediate_size=96, moe_intermediate_size=32, n_shared_experts=2, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True, rope_theta=1e6,
+    rms_norm_eps=1e-6, scoring_func="sigmoid", norm_topk_prob=True, routed_scaling_factor=2.448,
+    topk_method="noaux_tc", bias_update_speed=0.001, seq_len=128, learning_rate=2.2e-4, weight_decay=0.1,
+    router_aux_loss_coef=0.0, router_z_loss_coef=0.0,
+)
+#: (layers, leading dense layers, experts held, the first of them)
+SHAPES = {
+    "dense_layer": dict(num_hidden_layers=1, first_k_dense_replace=1, experts_held=4, first_expert_held=0),
+    "expert_layer": dict(num_hidden_layers=1, first_k_dense_replace=0, experts_held=4, first_expert_held=8),
+    "dense_then_experts": dict(num_hidden_layers=3, first_k_dense_replace=1, experts_held=4, first_expert_held=4),
+    "every_expert_held": dict(num_hidden_layers=2, first_k_dense_replace=1, experts_held=16, first_expert_held=0),
+}
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return resolve.load_module(os.path.join(BENCH_DIR, "configs", "kanana2_30b_a3b_ep8_l5_reference.py"))
+
+
+def _keys(shape: str, **kw):
+    return {**KEYS, **SHAPES[shape], **kw}
+
+
+def _spec(shape: str, dtype: str = "float32", **kw):
+    return load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype=dtype, **_keys(shape, **kw))
+
+
+def _weights(spec, seed: int = 0):
+    """Seeded weights, away from the init's symmetries: gains that are not
+    1, matrices five times the init's scale (a router whose scores are not
+    all 1/2), and a correction bias that is NOT zero and as large as the
+    scores' spread, so that it changes choices."""
+    params = spec.init(jax.random.key(seed))
+    keys = iter(jax.random.split(jax.random.key(seed + 1), len(jax.tree.leaves(params))))
+    return jax.tree.map(
+        lambda a: a * 5.0 if a.ndim > 1 else a + 0.2 * jax.random.normal(next(keys), a.shape), params)
+
+
+def _batch(b: int = 2, seed: int = 0):
+    toks = np.random.default_rng(seed).integers(0, KEYS["vocab_size"], (b, KEYS["seq_len"] + 1)).astype(np.int32)
+    return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+
+
+def _rel(got, want) -> float:
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
+
+
+def _system(spec, params, batch):
+    def total(params):
+        out = spec.apply(params, batch, train=True)
+        return spec.loss(out, batch), out
+
+    return jax.jit(jax.value_and_grad(total, has_aux=True))(params)
+
+
+def _reference(reference, keys, params, batch):
+    import optax
+
+    forward = reference.build(keys)
+
+    def total(params):
+        logits, slots = forward(params, batch["tokens"])
+        return optax.softmax_cross_entropy_with_integer_labels(logits, batch["labels"]).mean(), (logits, slots)
+
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(total, has_aux=True))(params)
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_float32_system_equals_the_plain_reference(reference, shape):
+    """Logits, the loss, the gradient of EVERY leaf that has one (by group:
+    the leaf's path is in the message) and the slots each of the router's
+    experts was sent, to 1e-5 of the largest value.  The bias is seeded
+    non-zero: it gets NO gradient on either side (it chooses, it never
+    weighs), and the choices it makes are the reference's."""
+    spec = _spec(shape)
+    params, batch = _weights(spec), _batch()
+    (loss, out), grads = _system(spec, params, batch)
+    (ref_loss, (ref_logits, ref_slots)), ref_grads = _reference(reference, _keys(shape), params, batch)
+    assert _rel(out["logits"], ref_logits) <= 1e-5, "logits"
+    assert abs(float(loss) - float(ref_loss)) <= 1e-5 * abs(float(ref_loss))
+    flat, ref_flat = jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(ref_grads)
+    assert len(flat) == len(ref_flat)
+    for (path, g), r in zip(flat, ref_flat):
+        name = jax.tree_util.keystr(path)
+        if "router_bias" in name:
+            assert not np.any(np.asarray(g)) and not np.any(np.asarray(r)), name
+            continue
+        assert float(jnp.max(jnp.abs(r))) > 0, name  # every other leaf is trained
+        assert _rel(g, r) <= 1e-5, name
+    if SHAPES[shape]["first_k_dense_replace"] < SHAPES[shape]["num_hidden_layers"]:
+        np.testing.assert_array_equal(np.asarray(out["router_slots"]), np.asarray(ref_slots))
+        held = SHAPES[shape]["experts_held"]
+        lo = SHAPES[shape]["first_expert_held"]
+        counters = out["moe_counters"]
+        assert float(counters["moe_slots_held"]) == float(np.asarray(ref_slots)[:, lo:lo + held].sum())
+        assert float(counters["moe_slots_computed"]) == float(counters["moe_slots_held"])
+        assert float(counters["moe_slots"]) == float(np.asarray(ref_slots).sum())
+
+
+def test_the_bias_chooses_and_never_weighs():
+    """A bias large enough to force the choice: the chosen experts are the
+    biased ones, and their weights are the UNBIASED scores, normalised and
+    scaled — against float64."""
+    rng = np.random.default_rng(3)
+    u = rng.standard_normal((64, 32)).astype(np.float32)
+    wg = rng.standard_normal((32, 16)).astype(np.float32) * 0.3
+    bias = np.zeros(16, np.float32)
+    bias[[2, 5, 11]] = 4.0  # sigmoid < 1: these three win every token
+    routing = moe.route(jnp.asarray(u), jnp.asarray(wg), 3, scoring_func="sigmoid", bias=jnp.asarray(bias),
+                        norm_topk_prob=True, routed_scaling_factor=2.448)
+    assert set(np.asarray(routing.choices).ravel()) == {2, 5, 11}
+    s = 1.0 / (1.0 + np.exp(-(u.astype(np.float64) @ wg.astype(np.float64))))
+    want = np.take_along_axis(s, np.asarray(routing.choices), axis=1)
+    want = want / (want.sum(-1, keepdims=True) + 1e-20) * 2.448
+    np.testing.assert_allclose(np.asarray(routing.weights), want, rtol=2e-6)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_sigmoid_router_is_float32_against_float64(seed):
+    """bfloat16 rows in, as the model hands them: the logits are the
+    float64 product's to 1e-6 of the largest, and the top-k of sigmoid + bias
+    is float64's but for scores float32 cannot tell apart."""
+    rng = np.random.default_rng(seed)
+    u = jnp.asarray(rng.standard_normal((512, 64)), jnp.bfloat16)
+    wg = jnp.asarray(rng.standard_normal((64, 32)) * 0.1, jnp.float32)
+    bias = jnp.asarray(rng.standard_normal(32) * 0.05, jnp.float32)
+    routing = moe.route(u, wg, 4, scoring_func="sigmoid", bias=bias, norm_topk_prob=True)
+    want_r = np.asarray(u, np.float64) @ np.asarray(wg, np.float64)
+    assert np.abs(np.asarray(routing.logits, np.float64) - want_r).max() <= 1e-6 * np.abs(want_r).max()
+    want_s = 1.0 / (1.0 + np.exp(-want_r)) + np.asarray(bias, np.float64)
+    want_c = np.argsort(-want_s, axis=-1, kind="stable")[:, :4]
+    assert int(np.sum(np.asarray(routing.choices) != want_c)) <= 2
+    np.testing.assert_allclose(np.asarray(routing.weights).sum(-1), 1.0, rtol=1e-6)
+    # rounding the operands to bfloat16 on the way is seen: the control
+    low = moe.route(u, wg.astype(jnp.bfloat16), 4, scoring_func="sigmoid", bias=bias, norm_topk_prob=True)
+    assert np.abs(np.asarray(low.logits, np.float64) - want_r).max() > 1e-4 * np.abs(want_r).max()
+
+
+def test_softmax_route_is_unchanged_by_the_new_keys():
+    """OLMoE's call (no key given) and the same with every key at its
+    default: the parent's arithmetic, to the bit."""
+    rng = np.random.default_rng(0)
+    u = jnp.asarray(rng.standard_normal((128, 32)), jnp.bfloat16)
+    wg = jnp.asarray(rng.standard_normal((32, 8)) * 0.1, jnp.float32)
+    plain = moe.route(u, wg, 2)
+    keyed = moe.route(u, wg, 2, scoring_func="softmax", bias=None, norm_topk_prob=False, routed_scaling_factor=1.0)
+    for a, b in zip(plain, keyed):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    p = jax.nn.softmax(jnp.dot(u.astype(jnp.float32), wg, precision=jax.lax.Precision.HIGHEST), -1)
+    values, choices = jax.lax.top_k(p, 2)
+    np.testing.assert_array_equal(np.asarray(plain.choices), np.asarray(choices))
+    np.testing.assert_array_equal(np.asarray(plain.weights), np.asarray(values))
+
+
+def _parents_expert_ffn(u, choices, weights, w_gate, w_up, w_down):
+    """``ops/moe.expert_ffn`` as the parent commit (49def3e) had it: every
+    expert held, the grouped matmuls given no offset."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    def gmm(x, w, sizes):
+        tm, tk, tn = moe.GMM_TILING
+        tiling = (math.gcd(x.shape[0], tm), min(tk, w.shape[1]), min(tn, w.shape[2]))
+        return megablox.gmm(x, w, sizes, preferred_element_type=x.dtype, tiling=tiling, interpret=True)
+
+    n_tokens, k = choices.shape
+    order, inverse, sizes = moe.sort_slots(choices, w_gate.shape[0])
+    x = moe._rows_out(u, order, inverse, k)
+    y = gmm(jax.nn.silu(gmm(x, w_gate, sizes)) * gmm(x, w_up, sizes), w_down, sizes)
+    y = moe._rows_back(y, order, inverse).reshape(n_tokens, k, -1).astype(jnp.float32)
+    return jnp.sum(y * weights[..., None], axis=1).astype(u.dtype), sizes
+
+
+def _layer_inputs(dtype, tokens=256, d=64, f=32, experts=16, k=3, seed=0):
+    rng = np.random.default_rng(seed)
+    u = jnp.asarray(rng.standard_normal((tokens, d)), dtype)
+    logits = rng.standard_normal((tokens, experts))
+    choices = jnp.asarray(np.argsort(-logits, -1)[:, :k], jnp.int32)
+    weights = jnp.asarray(rng.random((tokens, k)), jnp.float32)
+    w = lambda *shape: jnp.asarray(rng.standard_normal(shape) * 0.1, dtype)  # noqa: E731
+    return u, choices, weights, w(experts, d, f), w(experts, d, f), w(experts, f, d)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_every_expert_held_is_the_parents_layer_to_the_bit(dtype):
+    """``n = E`` at ``lo = 0`` is the same code as a share, and its output,
+    its group sizes and its gradients are the parent's bits."""
+    u, choices, weights, wg, wu, wd = _layer_inputs(dtype)
+    got, slots = moe.expert_ffn(u, choices, weights, wg, wu, wd)
+    want, sizes = _parents_expert_ffn(u, choices, weights, wg, wu, wd)
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(slots), np.asarray(sizes))
+    loss = lambda f: lambda u, wg, wu, wd: jnp.sum(f(u, choices, weights, wg, wu, wd)[0].astype(jnp.float32) ** 2)  # noqa: E731
+    g_got = jax.grad(loss(moe.expert_ffn), argnums=(0, 1, 2, 3))(u, wg, wu, wd)
+    g_want = jax.grad(loss(_parents_expert_ffn), argnums=(0, 1, 2, 3))(u, wg, wu, wd)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("lo,held", [(0, 4), (4, 4), (12, 4), (2, 8), (0, 16)])
+def test_a_held_range_computes_its_own_experts_part(lo, held):
+    """Against a dense masked sum over the held experts only: a slot on an
+    absent expert adds nothing, forward and in every gradient; the slots
+    returned count ALL of the router's experts."""
+    u, choices, weights, wg, wu, wd = _layer_inputs(jnp.float32, seed=lo + held)
+    part = lambda w: w[lo:lo + held]  # noqa: E731
+
+    def dense(u, wg, wu, wd):
+        h = jax.nn.silu(jnp.einsum("td,edf->tef", u, wg)) * jnp.einsum("td,edf->tef", u, wu)
+        y = jnp.einsum("tef,efd->ted", h, wd)  # [T, held, D]
+        onehot = jax.nn.one_hot(choices - lo, held, dtype=jnp.float32)  # out of range: all zero
+        return jnp.einsum("tk,tke,ted->td", weights, onehot, y)
+
+    def system(u, wg, wu, wd):
+        return moe.expert_ffn(u, choices, weights, wg, wu, wd, n_experts=16, lo=lo)[0]
+
+    args = (u, part(wg), part(wu), part(wd))
+    with jax.default_matmul_precision("highest"):
+        want = dense(*args)
+        g_want = jax.grad(lambda *a: jnp.sum(dense(*a) ** 2), argnums=(0, 1, 2, 3))(*args)
+    np.testing.assert_allclose(system(*args), want, atol=2e-5, rtol=2e-5)
+    g_got = jax.grad(lambda *a: jnp.sum(system(*a) ** 2), argnums=(0, 1, 2, 3))(*args)
+    for a, b in zip(g_got, g_want):
+        assert _rel(a, b) <= 2e-5
+    slots = moe.expert_ffn(*args[:1], choices, weights, *args[1:], n_experts=16, lo=lo)[1]
+    np.testing.assert_array_equal(np.asarray(slots), np.bincount(np.asarray(choices).ravel(), minlength=16))
+    with pytest.raises(ValueError, match="are not among the router's"):
+        moe.expert_ffn(u, choices, weights, part(wg), part(wu), part(wd), n_experts=16, lo=16 - held + 1)
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer(reference):
+    """The share tied to the model.  ONE expert layer, 16 experts, at 8
+    chips of 2: every chip runs the model's block with its own range held,
+    on the same weights and tokens.  What differs between the chips' block
+    outputs is the routed part alone (attention and the shared expert are
+    computed alike by all): so chip 0's whole output plus the other chips'
+    routed parts — their output less what every chip computes alike, i.e.
+    less a run with NO slot held — is the uncut layer, which the plain
+    reference gives with all 16 held."""
+    shares, per = 8, 2
+    whole = _keys("expert_layer", experts_held=16, first_expert_held=0)
+    full = load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype="float32", **whole)
+    params, batch = _weights(full), _batch()
+    blk = params["blocks"]["b00"]
+    forward = reference.build(whole)
+    with jax.default_matmul_precision("highest"):
+        want_logits, want_slots = forward(params, batch["tokens"])
+    # the model's block as ``_apply`` calls it, a share's weights at a time
+    x = params["tok_emb"][batch["tokens"]]
+    positions = jnp.arange(x.shape[1])
+    common = dict(
+        axis=None, n_heads=KEYS["num_attention_heads"], top_k=KEYS["num_experts_per_tok"], theta=KEYS["rope_theta"],
+        eps=KEYS["rms_norm_eps"], compute_dtype=jnp.float32, rot=KEYS["qk_rope_head_dim"], interleave=True,
+        router={key: KEYS[key] for key in ("scoring_func", "norm_topk_prob", "routed_scaling_factor")},
+    )
+    parts, alike = [], None
+    for lo in range(0, shares * per, per):
+        cut = {**blk, **{name: blk[name][lo:lo + per] for name in ("w_gate", "w_up", "w_down")}}
+        out, stats = moe_lm._block(x, cut, positions, first_expert_held=lo, **common)
+        # what every chip computes alike: the same block with its held experts' weights zeroed
+        zeroed = {**cut, **{name: jnp.zeros_like(cut[name]) for name in ("w_gate", "w_up", "w_down")}}
+        same, _ = moe_lm._block(x, zeroed, positions, first_expert_held=lo, **common)
+        alike = same if alike is None else alike
+        np.testing.assert_allclose(same, alike, atol=1e-6)  # attention + shared: every chip's alike
+        parts.append(out - same)
+        np.testing.assert_array_equal(np.asarray(stats["slots"]), np.asarray(want_slots[0]))
+    assert sum(float(jnp.abs(p).max()) > 0 for p in parts) == shares  # every share routes something
+    layer = alike + sum(parts)  # the shared expert and attention counted ONCE
+    # ... through the head (final norm, untied head), against the reference's whole model
+    with jax.default_matmul_precision("highest"):
+        normed = layer * jax.lax.rsqrt(jnp.mean(layer * layer, -1, keepdims=True) + KEYS["rms_norm_eps"])
+        got_logits = (normed * params["norm_f"]) @ params["head"]
+    assert _rel(got_logits, want_logits) <= 1e-5
+
+
+def test_bias_rule_over_two_steps_is_the_references_to_the_bit(reference):
+    """Two training steps through the Trainer (AdamW that leaves the bias
+    alone, then the model's own rule) against the reference's rule on the
+    reference's own counts: every expert layer's bias, bit for bit, and
+    neither is all zero."""
+    import optax
+
+    shape = "dense_then_experts"
+    spec = _spec(shape)
+    mesh = create_mesh(jax.devices()[:1], num_devices=1)
+    trainer = Trainer(spec, JobConfig(distribution_strategy="AllReduce"), mesh)
+    state = trainer.init_state(jax.random.key(0))
+    params = jax.tree.map(np.asarray, state.params)
+    keys = _keys(shape)
+    forward = reference.build(keys)
+    optimizer = optax.adamw(keys["learning_rate"], b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1, mask=reference.decayed)
+    ref_params = jax.tree.map(jnp.asarray, params)
+    opt_state = optimizer.init(ref_params)
+
+    def loss(params, batch):
+        logits, slots = forward(params, batch["tokens"])
+        return optax.softmax_cross_entropy_with_integer_labels(logits, batch["labels"]).mean(), slots
+
+    for step in range(2):
+        batch = _batch(seed=step)
+        state, _ = trainer.train_step(state, trainer.shard_batch(batch))
+        with jax.default_matmul_precision("highest"):
+            (_, slots), grads = jax.value_and_grad(loss, has_aux=True)(ref_params, batch)
+        updates, opt_state = optimizer.update(grads, opt_state, ref_params)
+        ref_params = reference.update_bias(optax.apply_updates(ref_params, updates), slots, keys["bias_update_speed"])
+    for name in ("b01", "b02"):
+        got = np.asarray(state.params["blocks"][name]["router_bias"])
+        want = np.asarray(ref_params["blocks"][name]["router_bias"])
+        np.testing.assert_array_equal(got, want)
+        assert set(np.unique(np.abs(got))) <= {np.float32(0.0), np.float32(0.001), np.float32(0.002)} and got.any()
+    assert "router_bias" not in state.params["blocks"]["b00"]
+
+
+def test_other_routing_keys_raise():
+    for bad in (dict(n_group=2), dict(topk_group=2), dict(q_lora_rank=1536), dict(scoring_func="tanh"),
+                dict(topk_method="group_limited_greedy"), dict(experts_held=4, first_expert_held=13)):
+        with pytest.raises(ValueError):
+            _spec("expert_layer", **bad)
+
+
+def test_two_devices_equal_one_with_latent_attention():
+    """Sequence-sharded over two devices (the ring carries the shared rotary
+    key with K and V, the slot counts are summed over the axis): the loss,
+    the bias after a step and a weight's update are one device's."""
+    spec = _spec("dense_then_experts")
+    batch = _batch(b=2)
+    results = []
+    for n in (1, 2):
+        mesh = create_mesh(jax.devices()[:n], num_devices=n)
+        trainer = Trainer(spec, JobConfig(distribution_strategy="AllReduce"), mesh)
+        state = trainer.init_state(jax.random.key(0))
+        state, metrics = trainer.train_step(state, trainer.shard_batch(batch))
+        results.append((float(metrics["loss"]), jax.tree.map(np.asarray, state.params)))
+    (loss1, p1), (loss2, p2) = results
+    assert abs(loss1 - loss2) <= 1e-5 * abs(loss1)
+    np.testing.assert_array_equal(p1["blocks"]["b01"]["router_bias"], p2["blocks"]["b01"]["router_bias"])
+    np.testing.assert_allclose(p1["blocks"]["b01"]["wq"], p2["blocks"]["b01"]["wq"], atol=2e-6)
