@@ -98,6 +98,8 @@ MOE_COUNTERS = {
     "(all of moe_slots where every expert is), summed likewise",
     "moe_slots_computed": "rows the experts' grouped matmuls ran (the sum of "
     "the held groups' sizes): equals moe_slots_held, or a slot was dropped",
+    "moe_slots_overflow": "held slots that fell past the always-run row buffers "
+    "and were computed by the second tier (ops/moe.SLACK), summed likewise",
     "moe_expert_load_max": "slots on a device's fullest held expert, summed "
     "over expert layers, training steps and devices",
     "moe_expert_load_mean": "slots on a device's average held expert, summed likewise",
@@ -280,7 +282,7 @@ def _block(
     if "router_bias" in blk:
         keys["bias"] = blk["router_bias"]
     routing = moe.route(tokens, blk["router"], top_k, **keys)
-    y, slots = moe.expert_ffn(
+    y, slots, given = moe.expert_ffn(
         tokens, routing.choices, routing.weights,
         cast(blk["w_gate"]), cast(blk["w_up"]), cast(blk["w_down"]),
         n_experts=n_experts, lo=first_expert_held,
@@ -297,7 +299,8 @@ def _block(
         "slots": slots,
         "moe_slots": jnp.float32(b * l * top_k),
         "moe_slots_held": jnp.sum(here.astype(jnp.float32)),
-        "moe_slots_computed": jnp.sum(sizes),
+        "moe_slots_computed": (given.first + given.second).astype(jnp.float32),
+        "moe_slots_overflow": given.second.astype(jnp.float32),
         "moe_expert_load_max": jnp.max(sizes),
         "moe_expert_load_mean": jnp.mean(sizes),
     }

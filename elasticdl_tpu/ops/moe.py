@@ -19,25 +19,48 @@ the same code.
 
 How the rows move.  The ``T * k`` slots are sorted by expert
 (``lax.sort_key_val``, as ``ops/table_grad.sort_updates`` sorts update rows
-by table row), the token rows are gathered into that order, three GROUPED
-matmuls (``_grouped_matmul``: ``E`` groups of uneven, data-dependent size,
-one compiled program whatever the sizes) run the held experts' run of the
-sorted rows and no row more (the rows of absent experts come back zero), and
-the results are gathered back by the inverse order and summed over ``k``.
-The row buffers hold all ``T * k`` slots — the worst case the shapes allow:
-every slot may fall on a held expert — so a share of the experts still
-gathers every row (PERF.md section 7 has what that costs).  Both
-directions are PERMUTATIONS, forward and backward: the transpose of
-"gather by ``order``" is "gather by its inverse" (``_rows_out`` /
-``_rows_back`` spell that as ``custom_vjp``; autodiff alone would emit a
-scatter-add of ``[T * k, D]`` rows, which XLA:TPU executes a row at a time
-— 74 ns a row measured in PR 27).  No scatter-add of rows anywhere.
+by table row); after the sort the held experts' slots are ONE contiguous run
+of the sorted order, and only that run moves.  The row buffers hold ``C``
+rows, a number read off the shapes (``held_rows_bound``: ``SLACK`` times the
+held experts' even share ``T * k * n / E``, at most ``T * k``):
+
+* The always-run tier takes the first ``C`` sorted positions of the run
+  (``_held_window``): their token rows are gathered, three GROUPED matmuls
+  (``_grouped_matmul``: uneven, data-dependent groups, one compiled program
+  whatever the sizes) run the held experts' rows of the window — the rows
+  past the run are a last group of no expert, which comes back zero — and
+  each result row is weighted and summed into its token, at most ``k`` rows
+  a token, in float32 (``_token_sum``: the rows sorted by token and one
+  sweep of ``ops/table_grad.merge_sweep`` over the tokens' tiles).  The
+  transpose of "gather a window's token rows" is that same sum, and the
+  transpose of the sum is the gather (``_rows_of_tokens`` /
+  ``_sum_to_tokens`` spell it as ``custom_vjp``); the weights' gradient
+  returns to ``[T, k]`` by a gather of scalars through the inverse order.
+  No array of ``T * k`` rows exists in this path.
+* Every one of a token's ``k`` slots may fall on a held expert, so the run
+  can be longer than ``C``.  The rows past ``C`` are a second tier
+  (``_overflow``): the same function on the same window of ``C`` rows,
+  moved along the run by a loop that makes as many trips as the run has
+  further windows — none while it fits the first tier — so the result is
+  exact whatever the routing; it keeps no residuals (its backward runs a
+  window's forward again).  ``Given`` counts the rows each tier's grouped
+  matmuls were given: together the held slots.
+* Where the buffers hold every slot (``C == T * k``: every expert held, or
+  nearly) one window is the whole sorted order and the way back to the
+  tokens is the inverse PERMUTATION: "gather by ``order``" out, "gather by
+  its inverse" back and a sum over ``k`` (``_rows_out`` / ``_rows_back``,
+  each the other's transpose).
+
+Autodiff alone would emit a scatter-add of rows for every one of these
+gathers, which XLA:TPU executes a row at a time (74 ns a row measured in PR
+27; PR 34's step 0 has it 15 % behind the sweep at 18,432 rows of 2048).  No
+scatter-add of rows anywhere.
 
 Device scopes (``jax.named_scope``; ``benchmark/readers/op_ms_step.py``
 reads them): ``moe_router`` here in ``route`` and in ``router_stats``,
-``moe_dispatch`` (sort, sizes, both permutations, forward and backward),
-``moe_experts`` (the grouped matmuls and the gate), ``moe_combine`` (the
-weights and the sum over ``k``).
+``moe_dispatch`` (sort, sizes, the windows, the row gathers out and their
+transposes), ``moe_experts`` (the grouped matmuls and the gate),
+``moe_combine`` (the weights, the sum into the tokens and its transpose).
 
 The EXCHANGE of expert parallelism is not here: on a mesh every device
 holds the same experts and routes its own tokens (the AllReduce strategy);
@@ -54,6 +77,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+from elasticdl_tpu.ops import table_grad
 
 
 class Routing(NamedTuple):
@@ -205,8 +230,9 @@ def _use_interpret() -> bool:
 
 def _grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array, lo: int) -> jax.Array:
     """``x[rows of group e] @ w[e - lo]`` for every HELD group ``e`` in
-    ``[lo, lo + n)``: [N, A] x [n, A, B] -> [N, B], ``sizes`` [E] the
-    (data-dependent) rows of each of the router's groups, held or not:
+    ``[lo, lo + n)``: [N, A] x [n, A, B] -> [N, B], ``sizes`` the
+    (data-dependent) rows of each group of ``x``, held or not (all the
+    router's ``E``, or a window's: the held ``n`` and its filler):
     megablox's grouped matmul (a Pallas kernel that walks the held groups'
     row tiles group by group and zeroes the rows of the others; its custom
     VJP runs the same kernel for dx and its transposed twin for dw), one
@@ -219,6 +245,219 @@ def _grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array, lo: int) -> ja
     )
 
 
+def _experts(x, w_gate, w_up, w_down, sizes, lo: int):
+    """The held experts' gated feed-forward over rows grouped by expert."""
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(_grouped_matmul(x, w_gate, sizes, lo)) * _grouped_matmul(x, w_up, sizes, lo)
+        return _grouped_matmul(h, w_down, sizes, lo)
+
+
+#: Room the always-run row buffers leave over the held experts' even share
+#: ``T * k * n / E`` of the slots, from step 0 on a v5e (PERF.md, PR 34: one
+#: layer forward + backward at [16384, 2048], k = 6, 16 of 128 experts held,
+#: 12.6 % of the slots theirs; the parent's ``T * k``-row buffers 34.3 ms):
+#: 1.25 -> 11.2 ms, 1.5 -> 12.0, 2 -> 13.4 with the second tier present and
+#: making no trip: 0.8 ms a layer for a quarter of slack, in every step.
+#: Each further window the held run takes costs some 13 ms (the whole run
+#: held, five of them: 76.9 against 49.9).  So a quarter more slack pays
+#: from one step in sixteen that would otherwise overflow: 1.5 covers a
+#: chip whose experts draw half again their even share, which a router
+#: still finding its balance does and a balanced one (the benchmark's
+#: reads 12.05-12.24 % of 12.5) never does; 2 would buy the range
+#: 18.75-25 % for 1.3 ms a layer of every step.
+SLACK = 1.5
+
+#: Token rows a grid step of the token sum builds (``ops/table_grad``'s
+#: ``tile``): at 2048-wide float32 rows 128 fits the 16 MiB of VMEM a
+#: kernel may scope on a v5e, 256 asks for 18.5 (compiled for a described
+#: v5e, PR 34).
+TOKEN_TILE = 128
+
+
+def held_rows_bound(n_slots: int, n_held: int, n_experts: int) -> int:
+    """``C``: the rows of the always-run buffers, read off the shapes —
+    ``SLACK`` times the held experts' even share of the ``n_slots = T * k``
+    slots, rounded up to the grouped matmul's row tile (the one
+    ``_grouped_matmul`` would take for ``n_slots`` rows), at most all of
+    them."""
+    tile = math.gcd(n_slots, GMM_TILING[0])
+    want = math.ceil(SLACK * n_slots * n_held / n_experts)
+    return min(n_slots, -(-want // tile) * tile)
+
+
+# graftlint: allow[jit-shim] an inner jit, a trace cache inside the step's one compile (as megablox's gmm is), never a compile of its own
+@functools.partial(jax.jit, static_argnames=("n_tokens",))
+def _token_sum(rows: jax.Array, tok: jax.Array, n_tokens: int) -> jax.Array:
+    """``zeros([n_tokens, D]).at[tok].add(rows)`` (float32; ``tok ==
+    n_tokens`` is dropped) without the scatter-add: the rows sorted by token
+    and one sweep over the tokens' tiles (``ops/table_grad``).  Jitted: a
+    model's layers and their backward passes share ONE trace and one
+    lowered kernel a shape (a Pallas kernel is otherwise traced and lowered
+    anew at every call: ``setup_s``)."""
+    return table_grad.sweep_table_grad(tok, rows, n_tokens, tile=min(TOKEN_TILE, n_tokens))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_of_tokens(u, tok, n_tokens):
+    """Token rows [T, D] -> the rows of a window of the sorted slots [W, D]
+    (``tok`` [W]: the token of each, ``n_tokens`` past the held run)."""
+    with jax.named_scope("moe_dispatch"):
+        return _take(u, jnp.minimum(tok, n_tokens - 1))
+
+
+def _rows_of_tokens_fwd(u, tok, n_tokens):
+    return _rows_of_tokens(u, tok, n_tokens), tok
+
+
+def _rows_of_tokens_bwd(n_tokens, tok, g):
+    with jax.named_scope("moe_dispatch"):
+        return _token_sum(g.astype(jnp.float32), tok, n_tokens).astype(g.dtype), None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _sum_to_tokens(rows, tok, n_tokens):
+    """The transpose of :func:`_rows_of_tokens`: a window's rows [W, D]
+    (float32) summed into their tokens [T, D], at most ``k`` a token."""
+    with jax.named_scope("moe_combine"):
+        return _token_sum(rows, tok, n_tokens)
+
+
+def _sum_to_tokens_fwd(rows, tok, n_tokens):
+    return _sum_to_tokens(rows, tok, n_tokens), tok
+
+
+def _sum_to_tokens_bwd(n_tokens, tok, g):
+    with jax.named_scope("moe_combine"):
+        rows = _take(g, jnp.minimum(tok, n_tokens - 1))
+        return jnp.where((tok < n_tokens)[:, None], rows, 0), None
+
+
+_sum_to_tokens.defvjp(_sum_to_tokens_fwd, _sum_to_tokens_bwd)
+
+
+@jax.custom_vjp
+def _weights_of_rows(weights, slots, inverse, first, count):
+    """The router's weights [T, k] -> the weights of a window's rows [W]
+    (``slots`` [W] their slots; the window starts at sorted position
+    ``first`` and holds ``count`` rows of the held run, the rest read 0)."""
+    valid = lax.iota(jnp.int32, slots.shape[0]) < count
+    return jnp.where(valid, _take(weights.reshape(-1), slots), 0.0)
+
+
+def _weights_of_rows_fwd(weights, slots, inverse, first, count):
+    out = _weights_of_rows(weights, slots, inverse, first, count)
+    # a [0, k] array carries the weights' shape and dtype, and no bytes
+    return out, (inverse, first, count, weights[:0])
+
+
+def _weights_of_rows_bwd(res, g):
+    inverse, first, count, like = res
+    # Back to [T, k] by a GATHER of scalars through the inverse order (a
+    # scatter of scalars runs one element at a time on the TPU).
+    at = inverse - first
+    mine = (at >= 0) & (at < count)
+    dw = jnp.where(mine, _take(g, jnp.clip(at, 0, g.shape[0] - 1)), 0.0)
+    return dw.reshape(-1, like.shape[1]).astype(like.dtype), None, None, None, None
+
+
+_weights_of_rows.defvjp(_weights_of_rows_fwd, _weights_of_rows_bwd)
+
+
+def _held_window(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, first, width: int):
+    """Rows ``[first, first + width)`` of the held experts' run of the
+    sorted slots (the run starts at sorted position ``start``; ``ends`` [n]
+    are where each held expert's slots end in it), through their experts
+    and summed into their tokens with the router's weights: ([T, D]
+    float32, the rows the grouped matmuls were given)."""
+    n_tokens, k = weights.shape
+    with jax.named_scope("moe_dispatch"):
+        clipped = jnp.clip(jnp.concatenate([jnp.zeros(1, jnp.int32), ends]), first, first + width)
+        given = clipped[1:] - clipped[:-1]  # [n]: each held expert's rows in the window
+        count = clipped[-1] - first
+        # a last group of no expert takes the window's filler: the grouped
+        # matmul zeroes its rows
+        sizes = jnp.concatenate([given, (width - count)[None]])
+        slots = lax.dynamic_slice(jnp.pad(order, (0, width)), (start + first,), (width,))
+        tok = jnp.where(lax.iota(jnp.int32, width) < count, slots // k, n_tokens)
+    y = _experts(_rows_of_tokens(u, tok, n_tokens), w_gate, w_up, w_down, sizes, 0)
+    with jax.named_scope("moe_combine"):
+        w_rows = _weights_of_rows(weights, slots, inverse, start + first, count)
+        rows = y.astype(jnp.float32) * w_rows[:, None]
+    return _sum_to_tokens(rows, tok, n_tokens), jnp.sum(given)
+
+
+def _windows(ends, bound: int):
+    """Windows of ``bound`` rows the held run takes: 1 (or 0) while it fits
+    the first tier's buffers."""
+    return (ends[-1] + bound - 1) // bound
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(10,))
+def _overflow(acc, u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound):
+    """``acc`` plus the held run's rows PAST ``bound``: the second tier, the
+    same window of ``bound`` rows moved along the run by a loop that makes
+    no trip while the run fits the first tier's buffers.  Returns (the sum,
+    the rows its grouped matmuls were given: 0 without a trip).  A
+    ``custom_vjp`` that keeps its inputs and runs a window again in its
+    backward: the step's live residuals are the first tier's alone.  A
+    window of the first tier's shapes runs the first tier's kernels: ONE
+    window of the other ``T * k - C`` rows needs eight more, 4.9 s of
+    tracing, lowering and loading in a 61 s warm ``setup_s`` (PERF.md, PR
+    34)."""
+    def body(i, carry):
+        acc, given = carry
+        out, rows = _held_window(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, i * bound, bound)
+        return acc + out, given + rows
+
+    return lax.fori_loop(1, _windows(ends, bound), body, (acc, jnp.int32(0)))
+
+
+def _overflow_fwd(acc, u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound):
+    args = (u, weights, w_gate, w_up, w_down, order, inverse, start, ends)
+    return _overflow(acc, *args, bound), args
+
+
+def _overflow_bwd(bound, res, g):
+    *diff, order, inverse, start, ends = res
+    g_out, _ = g
+
+    def body(i, grads):
+        def window(*diff):
+            return _held_window(*diff, order, inverse, start, ends, i * bound, bound)[0]
+
+        return tuple(a + b for a, b in zip(grads, jax.vjp(window, *diff)[1](g_out)))
+
+    grads = lax.fori_loop(1, _windows(ends, bound), body, tuple(jnp.zeros_like(a) for a in diff))
+    return (g_out, *grads, None, None, None, None)
+
+
+_overflow.defvjp(_overflow_fwd, _overflow_bwd)
+
+
+class Given(NamedTuple):
+    """The rows the grouped matmuls were given, by tier (int32 scalars):
+    their sum is the slots computed, ``second`` the held slots that fell
+    past the first tier's buffers."""
+    first: jax.Array
+    second: jax.Array
+
+
+# graftlint: allow[jit-shim] an inner jit, a trace cache inside the step's one compile (as megablox's gmm is), never a compile of its own
+@functools.partial(jax.jit, static_argnames=("bound",))
+def _held_tiers(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound: int):
+    """Both tiers of the windowed path: ([T, D] float32, :class:`Given`).
+    Jitted so that a model's expert layers, alike in shape, are traced,
+    differentiated and lowered ONCE (a window, its transpose and the second
+    tier's loops are some 1 s of tracing a layer otherwise: ``setup_s``)."""
+    args = (u, weights, w_gate, w_up, w_down, order, inverse, start, ends)
+    acc, first = _held_window(*args, 0, bound)
+    acc, second = _overflow(acc, *args, bound)
+    return acc, Given(first, second)
+
+
 def expert_ffn(
     u: jax.Array,
     choices: jax.Array,
@@ -228,27 +467,34 @@ def expert_ffn(
     w_down: jax.Array,
     n_experts: Optional[int] = None,
     lo: int = 0,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, Given]:
     """``sum_i weights[t, i] * (silu(u Wgate[e]) * (u Wup[e])) Wdown[e]``
     with ``e = choices[t, i]``, over the slots whose expert is HELD, for
     every token ``t``: ``u`` [T, D], ``choices`` / ``weights`` [T, k] over
     the router's ``n_experts`` (None: as many as are held), the held
     experts ``[lo, lo + n)``'s weights [n, D, F] / [n, F, D] already in the
     compute dtype.  Returns (the result [T, D] in ``u``'s dtype, the slots
-    [E] the router sent each of ITS experts: entries ``lo .. lo + n - 1``
-    are the group sizes the matmuls ran)."""
+    [E] the router sent each of ITS experts, the rows the grouped matmuls
+    were :class:`Given`: together the held slots, whatever the routing)."""
     n_tokens, k = choices.shape
     n_held = w_gate.shape[0]
     n_experts = n_held if n_experts is None else n_experts
     if not 0 <= lo <= n_experts - n_held:
         raise ValueError(f"held experts [{lo}, {lo + n_held}) are not among the router's {n_experts}")
     order, inverse, sizes = sort_slots(choices, n_experts)
-    x = _rows_out(u, order, inverse, k)
-    with jax.named_scope("moe_experts"):
-        h = jax.nn.silu(_grouped_matmul(x, w_gate, sizes, lo)) * _grouped_matmul(x, w_up, sizes, lo)
-        y = _grouped_matmul(h, w_down, sizes, lo)
-    y = _rows_back(y, order, inverse)
+    bound = held_rows_bound(n_tokens * k, n_held, n_experts)
+    if bound == n_tokens * k:
+        # The buffers hold every slot: one window is the whole sorted
+        # order, and the way back is its inverse permutation.
+        y = _experts(_rows_out(u, order, inverse, k), w_gate, w_up, w_down, sizes, lo)
+        y = _rows_back(y, order, inverse)
+        with jax.named_scope("moe_combine"):
+            y = y.reshape(n_tokens, k, -1).astype(jnp.float32)
+            out = jnp.sum(y * weights[..., None], axis=1).astype(u.dtype)
+        return out, sizes, Given(jnp.sum(sizes[lo:lo + n_held]), jnp.int32(0))
+    with jax.named_scope("moe_dispatch"):
+        start = jnp.sum(sizes[:lo])
+        ends = jnp.cumsum(sizes[lo:lo + n_held])
+    acc, given = _held_tiers(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound)
     with jax.named_scope("moe_combine"):
-        y = y.reshape(n_tokens, k, -1).astype(jnp.float32)
-        out = jnp.sum(y * weights[..., None], axis=1).astype(u.dtype)
-    return out, sizes
+        return acc.astype(u.dtype), sizes, given

@@ -527,11 +527,16 @@ def test_kanana2_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
     dispatch) compiled for a described v5e: it fits the chip; the eight
     device scopes the ``.mla`` metrics read are there; the attention is the
     three flash kernels with a rotary part at the operand lists
-    ``flash_roofline_pct.mla`` reads; the experts are grouped matmuls over
-    the router's 128 group sizes and the 16 held experts' weights; nothing
-    scatters rows under a ``moe_*`` scope or under ``mla_proj``; and no
-    array of [*, 8192, 8192] exists anywhere (the XLA attention path would
-    write 32 of them a sequence)."""
+    ``flash_roofline_pct.mla`` reads; the experts' grouped matmuls read
+    ``C`` = 18,432 rows (``ops/moe.held_rows_bound``: 1.5 x the 16 held
+    experts' even share of the 98,304 slots) and the 16 held experts'
+    weights, the always-run tier's and — inside its loops' bodies — the
+    second tier's alike; no array of all the slots, or of the other
+    79,872, exists anywhere in the step; the step's memory is under the
+    14.76 GiB it took with row buffers of ``T * k`` (PR 33);
+    nothing scatters rows under a ``moe_*`` scope or under ``mla_proj``;
+    and no array of [*, 8192, 8192] exists anywhere (the XLA attention path
+    would write 32 of them a sequence)."""
     import json
     import os
 
@@ -555,8 +560,9 @@ def test_kanana2_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
         ma.argument_size_in_bytes + ma.output_size_in_bytes
         - ma.alias_size_in_bytes + ma.temp_size_in_bytes
     )
-    # over a quarter of the chip by a wide margin, and inside its 15.75 GiB
-    assert 8 * 2**30 < total < 15.5 * 2**30, total / 2**30
+    # over a quarter of the chip by a wide margin, and under what the step
+    # took while every row buffer held all T * k slots (14.76 GiB, PR 33)
+    assert 8 * 2**30 < total < 14.76 * 2**30, total / 2**30
     text = compiled.as_text()
     for scope in ("mla_proj", "flash_attn", "moe_router", "moe_dispatch", "moe_experts", "moe_shared",
                   "moe_combine", "lm_head"):
@@ -577,11 +583,34 @@ def test_kanana2_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
         line for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line and re.search(r'op_name="[^"]*\bmoe_experts\b', line)
     ]
-    # three projections x (forward, its re-run, dx: gmm; dw: tgmm), every expert layer,
-    # over all T * k = 98,304 slot rows and the 16 held experts' weights
-    assert len(grouped) == 3 * (runs + 2) * expert_layers
-    assert sum("jit(tgmm)" in c for c in grouped) == 3 * expert_layers
-    assert all("bf16[98304," in c and ("bf16[16," in c) for c in grouped), grouped[:1]
+    from elasticdl_tpu.ops import moe
+
+    slots = traffic["minibatch_size"] * params["seq_len"] * params["num_experts_per_tok"]
+    bound = moe.held_rows_bound(slots, params["experts_held"], params["num_experts"])
+    assert (slots, bound) == (98304, 18432)
+    # the step is a scan: an op of the second tier's loop sits in a while body inside the scan's
+    in_loop = lambda line: re.search(r'op_name="([^"]*)"', line).group(1).count("while/body") > 1  # noqa: E731
+    always = [c for c in grouped if not in_loop(c)]
+    second = [c for c in grouped if in_loop(c)]
+    # the first tier: three projections x (forward, its re-run, dx: gmm; dw: tgmm), every
+    # expert layer, over C rows and the 16 held experts' weights
+    assert len(always) == 3 * (runs + 2) * expert_layers
+    assert sum("jit(tgmm)" in c for c in always) == 3 * expert_layers
+    # the second tier, inside its loops' bodies (no trip while the held run fits the first
+    # tier): its forward, and in the backward's loop the forward again, dx and dw — the SAME
+    # window of C rows, so the same kernels: no array of the other T * k - C rows anywhere
+    assert len(second) == 3 * 4 * expert_layers
+    assert all(f"bf16[{bound}," in c and "bf16[16," in c for c in grouped), grouped[:1]
+    for rows in (f"[{slots},2048]", f"[{slots},768]", f"[{slots - bound},", "[16384,6,2048]"):
+        assert rows not in text, rows  # the slots' ints (the sort, its inverse) are all there is of T * k
+    # two token sums a layer always run (the combine, and the dispatch's transpose), each
+    # ONE sweep kernel into [T, D] float32
+    sums = [
+        line for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+        and re.search(r'op_name="[^"]*\bmoe_(dispatch|combine)\b', line) and not in_loop(line)
+    ]
+    assert len(sums) == 2 * expert_layers and all("= f32[16384,2048]" in c for c in sums), sums[:1]
     scatters = [line for line in text.splitlines() if re.search(r" scatter\(", line)]
     for line in scatters:
         shape = re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)

@@ -9,8 +9,10 @@ up to the whole layer.  CPU only."""
 
 from __future__ import annotations
 
+import json
 import math
 import os
+import re
 import sys
 
 import jax
@@ -225,10 +227,11 @@ def test_every_expert_held_is_the_parents_layer_to_the_bit(dtype):
     """``n = E`` at ``lo = 0`` is the same code as a share, and its output,
     its group sizes and its gradients are the parent's bits."""
     u, choices, weights, wg, wu, wd = _layer_inputs(dtype)
-    got, slots = moe.expert_ffn(u, choices, weights, wg, wu, wd)
+    got, slots, given = moe.expert_ffn(u, choices, weights, wg, wu, wd)
     want, sizes = _parents_expert_ffn(u, choices, weights, wg, wu, wd)
     np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
     np.testing.assert_array_equal(np.asarray(slots), np.asarray(sizes))
+    assert (int(given.first), int(given.second)) == (choices.size, 0)  # one window holds every slot
     loss = lambda f: lambda u, wg, wu, wd: jnp.sum(f(u, choices, weights, wg, wu, wd)[0].astype(jnp.float32) ** 2)  # noqa: E731
     g_got = jax.grad(loss(moe.expert_ffn), argnums=(0, 1, 2, 3))(u, wg, wu, wd)
     g_want = jax.grad(loss(_parents_expert_ffn), argnums=(0, 1, 2, 3))(u, wg, wu, wd)
@@ -265,6 +268,113 @@ def test_a_held_range_computes_its_own_experts_part(lo, held):
     np.testing.assert_array_equal(np.asarray(slots), np.bincount(np.asarray(choices).ravel(), minlength=16))
     with pytest.raises(ValueError, match="are not among the router's"):
         moe.expert_ffn(u, choices, weights, part(wg), part(wu), part(wd), n_experts=16, lo=16 - held + 1)
+
+
+#: The held range's edges, at 40 tokens x top-3 = 120 slots over 16 experts of
+#: which 4 are held: ``C`` = 48 rows (1.5 x 120 x 4 / 16 = 45, up to the row
+#: tile 8).  (name, held slots, the first held expert, on one expert only,
+#: under ``jax.checkpoint``)
+HELD_EDGES = [
+    ("under_the_bound", 31, 4, False, False),
+    ("exactly_the_bound", 48, 4, False, False),
+    ("one_over_the_bound", 49, 4, False, False),
+    ("every_choice_held", 120, 4, False, False),
+    ("no_slot_held", 0, 4, False, False),
+    ("all_on_one_expert", 40, 4, True, False),
+    ("top_of_the_range", 57, 12, False, False),
+    ("over_the_bound_under_remat", 77, 8, False, True),
+]
+
+
+def _choices_with(held_slots: int, lo: int, one_expert: bool, tokens=40, k=3, experts=16, held=4, seed=0):
+    """[tokens, k] choices, distinct within a token, of which exactly
+    ``held_slots`` fall in ``[lo, lo + held)``: spread over the tokens and
+    the held experts, or all on expert ``lo + 1``."""
+    rng = np.random.default_rng(seed)
+    absent = np.array([e for e in range(experts) if not lo <= e < lo + held])
+    choices = np.stack([rng.permutation(absent)[:k] for _ in range(tokens)])
+    places = rng.permutation(tokens * k)[:held_slots] if not one_expert else rng.permutation(tokens)[:held_slots] * k
+    for place in places:
+        t, i = divmod(int(place), k)
+        choices[t, i] = lo + 1 if one_expert else lo + (i + t) % held  # ranks of a token get distinct experts
+    assert int(((choices >= lo) & (choices < lo + held)).sum()) == held_slots
+    assert all(len(set(row)) == k for row in choices)
+    return jnp.asarray(choices, jnp.int32)
+
+
+@pytest.mark.parametrize("name,held_slots,lo,one_expert,remat", HELD_EDGES, ids=[e[0] for e in HELD_EDGES])
+def test_the_held_range_is_exact_at_every_edge_of_its_row_buffers(name, held_slots, lo, one_expert, remat):
+    """The windowed path (``C < T * k``) against the dense masked sum over
+    the held experts, forward and all five gradients, whatever share of the
+    slots the held experts receive: under the always-run buffers' rows,
+    exactly, over (the second tier runs), none, one expert's alone, a range
+    that ends at the router's last expert, under ``jax.checkpoint``.  The
+    rows the grouped matmuls were given are the held slots, and those past
+    the bound are the second tier's."""
+    tokens, k, d, f, experts, held = 40, 3, 32, 24, 16, 4
+    bound = moe.held_rows_bound(tokens * k, held, experts)
+    assert bound == 48 < tokens * k
+    choices = _choices_with(held_slots, lo, one_expert)
+    rng = np.random.default_rng(1)
+    u = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
+    weights = jnp.asarray(rng.uniform(0.05, 0.5, (tokens, k)), jnp.float32)
+    wg, wu, wd = (jnp.asarray(rng.standard_normal(shape) * 0.3, jnp.float32)
+                  for shape in ((held, d, f), (held, d, f), (held, f, d)))
+    args = (u, weights, wg, wu, wd)
+
+    def dense(u, weights, wg, wu, wd):
+        h = jax.nn.silu(jnp.einsum("td,edf->tef", u, wg)) * jnp.einsum("td,edf->tef", u, wu)
+        y = jnp.einsum("tef,efd->ted", h, wd)
+        onehot = jax.nn.one_hot(choices - lo, held, dtype=jnp.float32)  # out of range: all zero
+        out = jnp.einsum("tk,tke,ted->td", weights, onehot, y)
+        return jnp.sum(jnp.sin(out)), out
+
+    def system(u, weights, wg, wu, wd):
+        out, _, given = moe.expert_ffn(u, choices, weights, wg, wu, wd, n_experts=experts, lo=lo)
+        return jnp.sum(jnp.sin(out)), (out, given)
+
+    ours = jax.checkpoint(system) if remat else system
+    (_, (out, given)), grads = jax.jit(jax.value_and_grad(ours, argnums=range(5), has_aux=True))(*args)
+    with jax.default_matmul_precision("highest"):
+        (_, want), want_grads = jax.jit(jax.value_and_grad(dense, argnums=range(5), has_aux=True))(*args)
+    assert int(given.first) + int(given.second) == held_slots  # moe_slots_computed == moe_slots_held
+    assert int(given.second) == max(held_slots - bound, 0)  # moe_slots_overflow
+    assert _rel(out, want) <= 1e-5
+    for g, w, leaf in zip(grads, want_grads, ("u", "weights", "w_gate", "w_up", "w_down")):
+        if held_slots == 0:
+            assert not np.any(np.asarray(g)) and not np.any(np.asarray(w)), leaf
+        else:
+            assert _rel(g, w) <= 1e-5, leaf
+
+
+@pytest.mark.parametrize("held", [16, 4], ids=["every_expert_held", "a_share_held"])
+def test_no_row_buffer_of_all_the_slots_in_the_held_path(held):
+    """The rule that survives the row buffers' resizing, read off the jaxpr
+    of the gradient.  No scatter of ROWS anywhere (the grouped matmul's and
+    the token sum's bookkeeping scatter vectors of a few numbers).  With
+    every expert held the rows move by gathers of all ``T * k`` slots, both
+    ways; with a share held NO gather reads or writes ``T * k`` rows: the
+    always-run tier moves ``C`` of them, the second tier the rest."""
+    tokens, k, d, f, experts = 40, 3, 32, 24, 16
+    choices = _choices_with(31, 4, False)
+    u = jnp.ones((tokens, d))
+    w = jnp.ones((held, d, f)), jnp.ones((held, d, f)), jnp.ones((held, f, d))
+
+    def loss(u, weights, w):
+        return jnp.sum(moe.expert_ffn(u, choices, weights, *w, n_experts=experts, lo=0)[0])
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(u, jnp.ones(choices.shape), w))
+    scattered = re.findall(r":\w+\[([\d,]*)\] = scatter", text)
+    assert all(shape.isdigit() and int(shape) <= 64 for shape in scattered), scattered
+    gathered = [shape.split(",") for shape in re.findall(r":\w+\[([\d,]*)\] = gather", text)]
+    rows = sorted({int(shape[0]) for shape in gathered if len(shape) == 2 and int(shape[1]) == d})
+    if held == experts:
+        assert rows == [tokens * k]
+    else:
+        bound = moe.held_rows_bound(tokens * k, held, experts)
+        # the window's rows, and the token sum's copy of them in whole chunks of 128
+        assert rows and set(rows) <= {bound, tokens * k - bound, 128}, rows
+        assert "cond" in text and tokens * k not in rows
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer(reference):
@@ -348,6 +458,74 @@ def test_bias_rule_over_two_steps_is_the_references_to_the_bit(reference):
         np.testing.assert_array_equal(got, want)
         assert set(np.unique(np.abs(got))) <= {np.float32(0.0), np.float32(0.001), np.float32(0.002)} and got.any()
     assert "router_bias" not in state.params["blocks"]["b00"]
+
+
+@pytest.mark.parametrize("tilt", [0.0, 4.0], ids=["the_inits_router", "a_router_tilted_onto_the_held"])
+def test_step_counters_count_the_overflow(reference, tilt):
+    """One train step's counters at 4 held experts of 16 (768 slots, always-
+    run buffers of 512 rows) against the reference's own count of the slots
+    each expert was sent: every held slot computed whatever the routing;
+    with the init's router the held run fits the first tier
+    (``moe_slots_overflow`` 0), with a correction bias that tilts every
+    choice onto the held experts the rows past the bound are the second
+    tier's — and the step is the same compiled program."""
+    from elasticdl_tpu.common import jitsan
+
+    shape = "expert_layer"
+    lo, held = SHAPES[shape]["first_expert_held"], SHAPES[shape]["experts_held"]
+    spec = _spec(shape)
+    trainer = Trainer(spec, JobConfig(distribution_strategy="AllReduce"), create_mesh(jax.devices()[:1], num_devices=1))
+    state = trainer.init_state(jax.random.key(0))
+    blk = state.params["blocks"]["b00"]
+    bias = blk["router_bias"].at[lo:lo + held].add(tilt)
+    state = state.replace(params={**state.params, "blocks": {"b00": {**blk, "router_bias": bias}}})
+    batch = _batch()
+    with jax.default_matmul_precision("highest"):
+        _, ref_slots = reference.build(_keys(shape))(jax.device_get(state.params), batch["tokens"])
+    sent = float(np.asarray(ref_slots)[0, lo:lo + held].sum())
+    slots = batch["tokens"].size * KEYS["num_experts_per_tok"]
+    bound = moe.held_rows_bound(slots, held, KEYS["num_experts"])
+    assert (slots, bound) == (768, 512)
+    before = jitsan.compiles("trainer.train_step")
+    state, metrics = trainer.train_step(state, trainer.shard_batch(batch))
+    assert jitsan.compiles("trainer.train_step") <= before + 1
+    assert float(metrics["moe_slots"]) == slots and float(metrics["moe_slots_held"]) == sent
+    assert float(metrics["moe_slots_computed"]) == sent
+    assert float(metrics["moe_slots_overflow"]) == max(sent - bound, 0.0)
+    assert (sent > bound) == bool(tilt), sent
+    assert "moe_slots_overflow" in spec.step_counters and spec.step_counters == moe_lm.MOE_COUNTERS
+
+
+def test_the_overflow_metric_reads_the_counter_and_nothing_on_the_parent(tmp_path, monkeypatch):
+    """``moe_slots_overflow_pct.mla``: a data file and an entry appended to
+    BENCHMARK.json, read by ``counter_delta`` as the growth of
+    ``moe_slots_overflow`` over that of ``moe_slots_held`` inside the
+    window; a program without the counter (the parent) gives no metric and
+    no error."""
+    import runfiles
+
+    name, cell = "moe_slots_overflow_pct.mla", "kanana2_job"
+    bench = resolve.Bench(ROOT)
+    (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
+    assert entry == bench.spec["per_layer"][-1] and entry["workloads"] == [cell]
+    spec = bench.metric_file(name)
+    assert spec["cells"] == [cell] and spec["reader"] == "counter_delta" and spec["better"] == "lower"
+    for key in ("unit", "layer", "moves", "better", "source"):
+        assert spec[key] == entry[key], key
+    assert name in [m["name"] for m in bench.metrics_of(cell, "per_layer")]
+    run = tmp_path / "run"
+    (run / "metrics").mkdir(parents=True)
+    monkeypatch.setattr(runfiles, "run_dir", lambda ctx: str(run))
+    ctx = {"window": {"ts": [10.0, 13.0]}}
+
+    def reading(overflow):
+        records = [{"kind": "counter", "ts": 10.0 + i, "moe_slots_held": 1000.0 * (i + 1), **overflow(i)} for i in range(4)]
+        (run / "metrics" / "metrics.jsonl").write_text("".join(json.dumps(r) + "\n" for r in records))
+        return bench.reader(spec["reader"]).read(ctx, spec["params"])
+
+    assert reading(lambda i: {"moe_slots_overflow": 0.0}) == 0.0
+    assert reading(lambda i: {"moe_slots_overflow": 30.0 * i}) == pytest.approx(3.0)
+    assert reading(lambda i: {}) is None
 
 
 def test_other_routing_keys_raise():

@@ -213,18 +213,19 @@ def test_expert_ffn_is_the_dense_masked_sum_forward_and_backward(routing):
     args = (u, weights, w_gate, w_up, w_down)
 
     def ours(u, weights, *w):
-        out, sizes = moe.expert_ffn(u, choices, weights, *w)
-        return jnp.sum(jnp.sin(out)), (out, sizes)
+        out, sizes, given = moe.expert_ffn(u, choices, weights, *w)
+        return jnp.sum(jnp.sin(out)), (out, sizes, given)
 
     def dense(u, weights, *w):
         out = _dense_expert_sum(u, choices, weights, *w)
         return jnp.sum(jnp.sin(out)), out
 
-    (_, (out, sizes)), grads = jax.jit(jax.value_and_grad(ours, argnums=range(5), has_aux=True))(*args)
+    (_, (out, sizes, given)), grads = jax.jit(jax.value_and_grad(ours, argnums=range(5), has_aux=True))(*args)
     with jax.default_matmul_precision("highest"):
         (_, want), want_grads = jax.jit(jax.value_and_grad(dense, argnums=range(5), has_aux=True))(*args)
     np.testing.assert_array_equal(np.asarray(sizes), np.bincount(np.asarray(choices).ravel(), minlength=n_experts))
     assert int(jnp.sum(sizes)) == choices.size  # dropless
+    assert (int(given.first), int(given.second)) == (choices.size, 0)  # all in the one tier
     assert _rel(out, want) <= 1e-5
     for g, w in zip(grads, want_grads):
         assert _rel(g, w) <= 1e-5
@@ -233,7 +234,11 @@ def test_expert_ffn_is_the_dense_masked_sum_forward_and_backward(routing):
 def test_no_row_scatter_in_the_expert_layer():
     """Both directions of the row movement are gathers, forward and
     backward: the jaxpr of the gradient holds sorts and gathers and no
-    scatter of rows."""
+    scatter of rows.  (Every expert held: the row buffers hold all ``T *
+    k`` slots.  ``tests/test_latent_moe.py::
+    test_no_row_buffer_of_all_the_slots_in_the_held_path`` holds the rule
+    for a share of the experts: no scatter of rows there either, and no
+    gather of ``T * k`` rows.)"""
     choices = jnp.asarray(_routings(96, 8)["uniform"], jnp.int32)
     u = jnp.ones((96, 32))
     w = jnp.ones((8, 32, 24)), jnp.ones((8, 32, 24)), jnp.ones((8, 24, 32))
