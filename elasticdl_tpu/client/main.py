@@ -9,6 +9,11 @@ client-validates/master-re-parses layering.
 
 from __future__ import annotations
 
+# First, before anything heavy: importing the recorder stamps the origin of
+# this process's set-up chain (common/trace.py ``setup()``; the master's
+# ``setup:launch`` starts there).
+from elasticdl_tpu.common import trace  # noqa: F401  isort: skip
+
 import argparse
 import sys
 from typing import List, Optional

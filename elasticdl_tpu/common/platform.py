@@ -33,6 +33,15 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS = "/jax/compilation_cache/cache_misses"
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+#: The other durations jax reports of a compile request, by the key
+#: :func:`compile_phase_seconds` gives each: tracing to a jaxpr, lowering
+#: it to a module, reading a cached executable (a part of the backend
+#: compile's seconds, reported on a hit only).
+_PHASE_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+}
 
 
 def free_port() -> int:
@@ -66,6 +75,7 @@ class _CompileStats:
         self.misses = 0
         self.compiles = 0
         self.compile_s = 0.0
+        self.phase_s = dict.fromkeys(_PHASE_DURATIONS.values(), 0.0)
         self.last = ""
         self.functions: dict = {}
 
@@ -81,6 +91,11 @@ class _CompileStats:
                 self.last = "miss"
 
     def on_duration(self, event: str, duration: float, **kw) -> None:
+        phase = _PHASE_DURATIONS.get(event)
+        if phase is not None:
+            with self.lock:
+                self.phase_s[phase] += duration
+            return
         if event != _BACKEND_COMPILE:
             return
         with self.lock:
@@ -143,6 +158,21 @@ def compile_counts() -> tuple:
     until :func:`count_compiles` has run.  jax-free to call."""
     with _stats.lock:
         return _stats.compiles, _stats.compile_s
+
+
+def compile_phase_seconds() -> dict:
+    """What this process's compile requests have cost so far, cumulative,
+    as ``jax.monitoring`` reported it: seconds tracing (``trace_s``),
+    lowering (``lower_s``), in backend compiles (``compile_s``: a cache
+    hit's read is inside it, and apart as ``cache_load_s``), and the
+    requests the persistent cache served (``cache_hits``) or did not
+    (``cache_misses``).  jax-free to call; zeros until
+    :func:`count_compiles` has run."""
+    with _stats.lock:
+        return dict(
+            _stats.phase_s, compile_s=_stats.compile_s,
+            cache_hits=float(_stats.hits), cache_misses=float(_stats.misses),
+        )
 
 
 def compile_cache_stats() -> dict:

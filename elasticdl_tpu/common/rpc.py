@@ -148,8 +148,15 @@ MASTER_SCHEMAS: Dict[str, MessageSchema] = {
             # optional: an absent field keeps the pre-r18 at-least-once
             # semantics, so no PROTOCOL_VERSION bump (the r9 stance).
             "seq": _INT,
+            # setup (PR 35): the worker incarnation's set-up chain
+            # (common/trace.py SetupChain.flat(): ``<span>_t0`` /
+            # ``<span>_t1`` epoch seconds and a few bare floats), on its
+            # FIRST successful training report and on no other.  The
+            # master writes it as one "setup" record of metrics.jsonl.
+            # Additive and optional.
+            "setup": _DICT,
         },
-        since={"requeue": 9, "seq": 18, "counters": 24},
+        since={"requeue": 9, "seq": 18, "counters": 24, "setup": 35},
     ),
     "ReportVersion": MessageSchema(
         required={"model_version": _INT}, optional={"worker_id": _STR}

@@ -277,6 +277,94 @@ class TraceRecorder:
         self.dropped = 0
 
 
+class SetupChain:
+    """One process's set-up as CONSECUTIVE spans on the wall epoch.
+
+    ``mark(name)`` closes the span ``name`` from the previous stamp (the
+    chain's origin for the first) to now, so the spans partition
+    ``[origin, last stamp]`` by construction: no overlap, and time nobody
+    named is inside whichever span the next mark closes, never a silence.
+    A dozen stamps in a process's life, none in a task loop.  Seconds on
+    ``now_us``'s clock: ``time.time``'s epoch at ``perf_counter``'s
+    resolution, comparable across the processes of one host and with the
+    ``ts`` the master stamps ``metrics.jsonl`` with.
+
+    ``child`` records a span INSIDE the current one (the worker's index
+    scan inside its build): kept beside the chain, outside the partition.
+    ``extras`` are bare floats that ride with the spans (pid, counts, the
+    seconds jax reported for a span's parts).  Three sinks read the same
+    stamps: :meth:`flat` (the ``setup`` record of ``metrics.jsonl``),
+    :meth:`emit` (``cat="setup"`` spans of the ring) and the worker's
+    ``edl_setup_seconds`` gauges (``durations``).
+    """
+
+    def __init__(self, origin_s: Optional[float] = None):
+        self.origin_s = now_s() if origin_s is None else float(origin_s)
+        self.spans: List[tuple] = []  # (name, t0_s, t1_s), consecutive
+        self.children: List[tuple] = []
+        self.extras: Dict[str, float] = {}
+
+    @property
+    def last_s(self) -> float:
+        return self.spans[-1][2] if self.spans else self.origin_s
+
+    def restart(self) -> None:
+        """Forget everything and start at now (a warm standby's adoption:
+        what it paid while parked belongs to no job's set-up)."""
+        self.__init__()
+
+    def mark(self, name: str, at_s: Optional[float] = None) -> float:
+        """Close ``name`` at ``at_s`` (now unless given; never before the
+        previous stamp) and return the stamp."""
+        t1 = max(now_s() if at_s is None else float(at_s), self.last_s)
+        self.spans.append((name, self.last_s, t1))
+        return t1
+
+    def has(self, name: str) -> bool:
+        return any(s[0] == name for s in self.spans)
+
+    def child(self, name: str) -> "_ChildSpan":
+        return _ChildSpan(self, name)
+
+    def durations(self) -> Dict[str, float]:
+        return {n: t1 - t0 for n, t0, t1 in self.spans + self.children}
+
+    def flat(self) -> Dict[str, float]:
+        """``<span>_t0`` / ``<span>_t1`` epoch seconds of every span and
+        child, the extras, and whose chain it is (``pid``; ``proc_start``,
+        when the kernel started the process): all floats
+        (``MetricsWriter.write``)."""
+        out = dict(self.extras, pid=float(os.getpid()))
+        started = process_start_s()
+        if started is not None:
+            out["proc_start"] = started
+        for name, t0, t1 in self.spans + self.children:
+            out[name + "_t0"], out[name + "_t1"] = t0, t1
+        return out
+
+    def emit(self) -> None:
+        """The spans as ``cat="setup"`` complete events of the process
+        ring (nothing while the ring is off): ``tools/trace_dump.py``
+        shows them beside everything else."""
+        for name, t0, t1 in self.spans + self.children:
+            _REC.add_complete(name, "setup", t0 * 1e6, (t1 - t0) * 1e6)
+
+
+class _ChildSpan:
+    __slots__ = ("_chain", "_name", "_t0")
+
+    def __init__(self, chain: SetupChain, name: str):
+        self._chain, self._name = chain, name
+
+    def __enter__(self) -> "_ChildSpan":
+        self._t0 = now_s()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._chain.children.append((self._name, self._t0, now_s()))
+        return False
+
+
 # -- the process-global recorder ------------------------------------------
 
 #: One recorder per process.  GRAFT_TRACE=1 enables at import (subprocess
@@ -340,3 +428,36 @@ def instant(name: str, cat: str = "event", **attrs) -> None:
 
 def now_us() -> float:
     return _REC.now_us()
+
+
+def now_s() -> float:
+    """``now_us`` in seconds: the set-up chains' unit, and the unit of
+    the ``ts`` in ``metrics.jsonl``."""
+    return _REC.now_us() / 1e6
+
+
+#: This process's set-up chain.  Its origin is the recorder's own anchor:
+#: the moment this module was first imported, which the entry points
+#: (``client/main.py``, ``worker/main.py``) make their first statement.
+_SETUP = SetupChain(_REC._wall0)
+
+
+def setup() -> SetupChain:
+    return _SETUP
+
+
+def process_start_s() -> Optional[float]:
+    """When the kernel started this process, on the wall epoch, from ONE
+    read of ``/proc/self/stat`` (field 22: start in clock ticks after
+    boot, so 10 ms coarse); None off Linux.  Before the chain's origin by
+    what the interpreter took to reach the entry point's first
+    statement."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = float(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf(
+            "SC_CLK_TCK"
+        )
+        return time.time() - age
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None

@@ -21,6 +21,7 @@ import sys
 import time
 from typing import Dict, List, Optional
 
+from elasticdl_tpu.common import trace
 from elasticdl_tpu.common.config import JobConfig, parse_args
 from elasticdl_tpu.common.log_utils import get_logger
 
@@ -82,6 +83,13 @@ class Master:
         heartbeat_timeout_s: float = 30.0,
         ps_backend: Optional[PodBackend] = None,
     ):
+        # This master's set-up chain (common/trace.py SetupChain), from the
+        # process's first stamp: launch | shards | serve | spawn, written
+        # as ONE ``setup`` record of metrics.jsonl once the fleet is
+        # spawned (run()).  Its own object: tests build several masters
+        # in one process.
+        self._setup = trace.SetupChain(trace.setup().origin_s)
+        self._setup.mark("setup:launch")
         config.validate()
         self.config = config
         if config.chaos:
@@ -97,9 +105,7 @@ class Master:
             # events) join the same merged trace the workers ship into —
             # and the master clock is the reference every worker offset
             # aims at (stdlib recorder: the control plane stays jax-free).
-            from elasticdl_tpu.common import trace as _trace
-
-            _trace.configure(
+            trace.configure(
                 enabled=True, capacity=config.trace_buffer_events
             )
         records_per_task = (
@@ -119,6 +125,8 @@ class Master:
             primary, config.parsed_data_reader_params()
         )
         shards = reader.create_shards(records_per_task)
+        # The reader's construction and the index scan of the data.
+        self._setup.mark("setup:shards")
         # Master-restart resume (SURVEY §5 "restore on master restart"): a
         # training job with a checkpoint_dir persists its task-progress
         # watermark (epoch + done shards); a restarted master skips finished
@@ -343,6 +351,8 @@ class Master:
         # drained pool must be visible BEFORE the next failure finds it
         # empty and pays a cold relaunch.
         self.servicer.set_standby_depth(self.pod_manager.standby_depth)
+        # A worker's set-up chain starts where its pod's launch ended.
+        self.servicer.set_launched_at(self.pod_manager.launched_at)
 
         # graftgauge (r14): the master's live /metrics endpoint serves the
         # fleet-aggregated view + goodput/SLO computer (servicer.fleet,
@@ -460,11 +470,9 @@ class Master:
             replayed.restarts + 1,
             " (torn tail tolerated)" if replayed.torn_tail else "",
         )
-        from elasticdl_tpu.common import trace as _trace
-
         # The masterfail bench's replay-stage clock (wall-anchored ts, so
         # cross-process decomposition needs no alignment).
-        _trace.instant(
+        trace.instant(
             "master:replay", cat="elastic",
             events=replayed.events_applied,
             replay_ms=self._journal_replay_ms,
@@ -625,6 +633,7 @@ class Master:
     def run(self, poll_interval_s: float = 0.2, reap_every_s: float = 5.0) -> Dict:
         """Supervise the job to completion; returns the final job status."""
         self.server.start()
+        self._setup.mark("setup:serve")
         last_reap = time.monotonic()
         try:
             if self.ps_manager is not None:
@@ -636,6 +645,7 @@ class Master:
                 self._wait_ps_ready()
             self.rendezvous.set_expected(self.config.num_workers)
             self.pod_manager.start()
+            self._write_setup_record()
             while not self.servicer.job_finished():
                 now = time.monotonic()
                 if now - last_reap >= reap_every_s:
@@ -673,6 +683,19 @@ class Master:
             return status
         finally:
             self.shutdown()
+
+    def _write_setup_record(self) -> None:
+        """Close the master's chain where the last pod's launch returned
+        (a worker's own chain names that stamp as its cause) and deliver
+        it once: one ``setup`` record, and the ring's ``cat="setup"``
+        spans when ``--trace`` is on."""
+        setup = self._setup
+        setup.mark("setup:spawn", at_s=self.pod_manager.launched_at())
+        setup.emit()
+        if self.metrics_writer is not None:
+            self.metrics_writer.write(
+                "setup", 0, setup.flat(), tensorboard=False
+            )
 
     def shutdown(self) -> None:
         if self.metrics_server is not None:

@@ -122,6 +122,10 @@ class PodInfo:
     slot: int
     phase: str = PodPhase.PENDING
     relaunches: int = 0  # relaunch generation of this slot
+    #: When the backend's ``start_pod`` returned for this pod (epoch
+    #: seconds on common/trace.py's clock; 0.0 until then): where the
+    #: pod's own set-up chain begins (``setup:interp``).
+    launched_at: float = 0.0
 
 
 # Listener signature: fn(pod_name: str, phase: str)
@@ -1077,6 +1081,8 @@ class PodManager:
                 return  # slot was scaled away or superseded while backing off
         try:
             self._backend.start_pod(info.name, self._pod_env(info))
+            with self._lock:
+                info.launched_at = trace.now_s()
             self._persist_registry()
         except Exception:
             logger.exception(
@@ -1237,6 +1243,18 @@ class PodManager:
     def pod_info(self, name: str) -> Optional[PodInfo]:
         with self._lock:
             return self._by_name.get(name)
+
+    def launched_at(self, name: Optional[str] = None) -> Optional[float]:
+        """When the launch of pod ``name`` returned (the newest launch of
+        any pod without a name); None for a pod this manager never
+        launched."""
+        with self._lock:
+            pods = (
+                [self._by_name.get(name)] if name is not None
+                else list(self._by_name.values())
+            )
+        stamps = [p.launched_at for p in pods if p and p.launched_at]
+        return max(stamps) if stamps else None
 
     def standby_depth(self) -> Optional[int]:
         """Warm-standby pool depth, or None when the backend has no pool

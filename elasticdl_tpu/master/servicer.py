@@ -182,6 +182,9 @@ class MasterServicer:
         # PodManager's depth here; Heartbeat/JobStatus republish it so a
         # DRAINED pool is visible before the next failure needs it.
         self._standby_depth_fn = None  # guarded-by: _lock
+        # Set-up chains (PR 35): worker id -> when its pod's launch
+        # returned; master main wires PodManager.launched_at here.
+        self._launched_at_fn = None  # guarded-by: _lock
         # Durable control-plane journal (r18, master/journal.py): the
         # servicer records its OWN nondeterministic inputs — lockstep
         # group-log entries, membership/model-version advances, the
@@ -711,6 +714,9 @@ class MasterServicer:
                     int(req.get("model_version", fallback_version)),
                     train_metrics,
                 )
+                setup = req.get("setup")
+                if setup:
+                    self._record_setup(req, setup)
         model_version = req.get("model_version")
         if model_version is not None:
             self._bump_version(int(model_version))
@@ -775,6 +781,29 @@ class MasterServicer:
             )
         except Exception:  # malformed values must not fail the report
             logger.exception("%s metrics write failed", kind)
+
+    def _record_setup(self, req: dict, setup: dict) -> None:
+        """A worker incarnation's set-up chain (common/trace.py
+        SetupChain), which rides its FIRST successful training report
+        only: one ``setup`` record, written right after that report's
+        ``train`` record.  The chain arrives closed at the first
+        dispatch's return; this handler adds the two spans only the
+        master can stamp: ``setup:interp`` (the pod's launch returned ->
+        the worker's first stamp) and the end of ``setup:first_step``
+        (the report accepted: now)."""
+        setup = dict(setup)
+        with self._lock:
+            launched_at_fn = self._launched_at_fn
+        launched = launched_at_fn(req["worker_id"]) if launched_at_fn else None
+        first = setup.get("setup:imports_t0")
+        if launched is not None and first is not None:
+            setup["setup:interp_t0"] = min(launched, first)
+            setup["setup:interp_t1"] = first
+        last = setup.get("setup:first_dispatch_t1")
+        if last is not None:
+            setup["setup:first_step_t0"] = last
+            setup["setup:first_step_t1"] = max(trace.now_s(), last)
+        self._stream_report_record("setup", req, setup, tensorboard=False)
 
     # hot-path: rides every report
     def _record_counters(self, req: dict) -> None:
@@ -1260,6 +1289,13 @@ class MasterServicer:
         republish it."""
         with self._lock:
             self._standby_depth_fn = fn
+
+    def set_launched_at(self, fn) -> None:
+        """Wire a callable ``worker id -> epoch seconds its pod's launch
+        returned, or None`` (master main passes PodManager.launched_at):
+        the start of that worker's ``setup:interp``."""
+        with self._lock:
+            self._launched_at_fn = fn
 
     def JobStatus(self, req: dict) -> dict:
         status = self.dispatcher.counts()

@@ -750,6 +750,9 @@ class Trainer:
         chip's memory initialises; nothing is built whole on device 0).
         jax's counter-based threefry makes the values independent of the
         number of devices."""
+        # ``init_state_s`` is kept for the counter the metric
+        # init_state_s.ex4 reads (worker._counter_snapshot); the worker's
+        # set-up chain stamps the same call as its ``init_state`` span.
         t0 = time.monotonic()
         with trace.span("init_state"):
             state = jax.block_until_ready(self._init_program(rng)(rng))

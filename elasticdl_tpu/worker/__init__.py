@@ -7,4 +7,14 @@ change re-forms its mesh from the latest checkpoint (the reference's elastic
 Horovod retry path, §3.5).
 """
 
-from elasticdl_tpu.worker.worker import DirectMasterProxy, RpcMasterProxy, Worker  # noqa: F401
+
+def __getattr__(name: str):
+    # Lazily (PEP 562): ``python -m elasticdl_tpu.worker.main`` imports
+    # this package first, and main.py's first statement is the first stamp
+    # of the worker's set-up chain; the heavy imports (jax, the trainer)
+    # belong after it, in ``setup:imports``.
+    if name in ("DirectMasterProxy", "RpcMasterProxy", "Worker"):
+        from elasticdl_tpu.worker import worker
+
+        return getattr(worker, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -11,6 +11,11 @@ Run as ``python -m elasticdl_tpu.worker.main``.
 
 from __future__ import annotations
 
+# First, before anything heavy: importing the recorder stamps the origin of
+# this incarnation's set-up chain (``setup:interp`` ends and
+# ``setup:imports`` starts there).
+from elasticdl_tpu.common import trace  # isort: skip
+
 import json
 import os
 import sys
@@ -253,9 +258,14 @@ def main(argv: Optional[List[str]] = None) -> int:
     from elasticdl_tpu.common.log_utils import set_level
 
     set_level(config.log_level)
+    # This incarnation's set-up chain (common/trace.py SetupChain): the
+    # marks below and Worker's partition the boot, and the whole rides the
+    # first training report into the master's metrics.jsonl.
+    setup = trace.setup()
     go_file = os.environ.get("ELASTICDL_STANDBY_GO_FILE", "")
     if go_file:
         worker_id = _park_as_standby(go_file)
+        setup.restart()  # the imports were paid while parked
     else:
         worker_id = os.environ.get(
             "ELASTICDL_WORKER_ID", f"worker-{os.getpid()}"
@@ -271,6 +281,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     from elasticdl_tpu.common.platform import enable_compile_cache
 
     enable_compile_cache()
+    setup.mark("setup:imports")
 
     # Call deadline + outage ride-through budget come off the config bus
     # (r18): the proxy owns both — see RpcMasterProxy.
@@ -376,6 +387,24 @@ def main(argv: Optional[List[str]] = None) -> int:
             heartbeat_timeout_s=config.distributed_heartbeat_timeout_s,
         )
         distributed.initialize(spec)
+    setup.mark("setup:register")
+    # Boot line: what this process — the one that owns the device and runs
+    # every step — actually landed on.  With JAX_PLATFORMS unset JAX falls
+    # back to the CPU when the accelerator fails to initialise, and the job
+    # would otherwise finish "green" on the host without saying so.  This
+    # is the process's first touch of the backend (the TPU client opens
+    # here, before Worker's own ``jax.devices()``), so it is a span of its
+    # own.
+    from elasticdl_tpu.common.platform import (
+        compile_cache_stats,
+        device_summary,
+    )
+    from elasticdl_tpu.ps.host_store import native_lib_available
+
+    device = device_summary()
+    setup.mark("setup:device_open")
+    boot = dict(device, native_lib=native_lib_available())
+    logger.info("worker %s device: %s", worker_id, json.dumps(boot))
     # The process-default registry (r14): the worker's own families plus
     # cross-cutting client-side ones (the PS retry counter records via
     # gauge.default()) all land in ONE registry, so the scrape endpoint
@@ -383,23 +412,13 @@ def main(argv: Optional[List[str]] = None) -> int:
     from elasticdl_tpu.common import gauge
     from elasticdl_tpu.common.metrics_http import maybe_start
 
+    with setup.child("setup:shards"):  # the reader's index scan
+        reader = build_job_reader(config)
     worker = Worker(
-        config, master, build_job_reader(config), worker_id=worker_id,
-        gauges=gauge.default(), incarnation=incarnation,
+        config, master, reader, worker_id=worker_id,
+        gauges=gauge.default(), incarnation=incarnation, setup=setup,
     )
     worker_holder["worker"] = worker
-    # Boot line: what this process — the one that owns the device and runs
-    # every step — actually landed on.  With JAX_PLATFORMS unset JAX falls
-    # back to the CPU when the accelerator fails to initialise, and the job
-    # would otherwise finish "green" on the host without saying so.
-    from elasticdl_tpu.common.platform import (
-        compile_cache_stats,
-        device_summary,
-    )
-    from elasticdl_tpu.ps.host_store import native_lib_available
-
-    boot = dict(device_summary(), native_lib=native_lib_available())
-    logger.info("worker %s device: %s", worker_id, json.dumps(boot))
     metrics_server = maybe_start(
         config.gauge_port,
         worker.gauges.render_prometheus,

@@ -35,6 +35,7 @@ from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.metrics import PhaseTimers, finalize_metrics
 from elasticdl_tpu.common.platform import (
     compile_counts,
+    compile_phase_seconds,
     count_compiles,
     device_bytes_in_use,
     device_peak_bytes,
@@ -360,7 +361,18 @@ class Worker:
         poll_interval_s: float = 0.05,
         gauges: Optional[gaugelib.Registry] = None,
         incarnation: Optional[str] = None,
+        setup: Optional[trace.SetupChain] = None,
     ):
+        # This incarnation's set-up chain (common/trace.py SetupChain):
+        # worker.main hands over the process's, already marked up to the
+        # device's opening; a standalone Worker's starts here.  _run and
+        # the first training dispatch add their marks, the first
+        # successful training report carries the whole to the master, and
+        # from then on this is None: "chain already sent" is the one check
+        # the task loop pays.
+        self._setup: Optional[trace.SetupChain] = (  # single-writer: main
+            setup if setup is not None else trace.SetupChain()
+        )
         self.config = config
         self.master = master
         self.reader = reader
@@ -1594,6 +1606,47 @@ class Worker:
         if closer is not None:
             closer.join(timeout=120.0)
 
+    def _setup_first_dispatch(
+        self, setup: trace.SetupChain, begins: bool
+    ) -> None:
+        """The set-up chain around the worker's FIRST training dispatch:
+        ``setup:first_prep`` (the first lease and the first task's host
+        half) ends where the dispatch call begins; ``setup:first_dispatch``
+        is the call (trace, lower, compile or cache load, enqueue), with
+        the seconds ``jax.monitoring`` reported of it riding as
+        ``setup:first_dispatch.<part>``: the cumulative seconds at the
+        end less those at the beginning."""
+        setup.mark("setup:first_prep" if begins else "setup:first_dispatch")
+        parts, sign = compile_phase_seconds(), -1.0 if begins else 1.0
+        for part in ("trace_s", "lower_s", "compile_s", "cache_load_s"):
+            key = f"setup:first_dispatch.{part}"
+            setup.extras[key] = setup.extras.get(key, 0.0) + sign * parts[part]
+
+    def _setup_payload(self, setup: trace.SetupChain) -> Dict[str, float]:
+        """The chain as it rides the first report (``SetupChain.flat`` and
+        this incarnation's compile requests), published on the way as the gauge family
+        ``edl_setup_seconds{phase=}`` and, with the ring on, as
+        ``cat="setup"`` spans: one set of stamps, three sinks."""
+        parts = compile_phase_seconds()
+        setup.extras.update(
+            # the nonce is "<pid>-<epoch ms>" (worker.main, __init__)
+            incarnation_ms=float(self._incarnation.rpartition("-")[2]),
+            # every compile request up to this report, and how the
+            # persistent cache served them
+            compile_requests=float(compile_counts()[0]),
+            cache_hits=parts["cache_hits"],
+            cache_misses=parts["cache_misses"],
+        )
+        for phase, seconds in setup.durations().items():
+            self.gauges.gauge(
+                "edl_setup_seconds",
+                "wall seconds of each span of this incarnation's set-up "
+                "chain, as sent with its first training report",
+                labels={"phase": phase},
+            ).set(seconds)
+        setup.emit()
+        return setup.flat()
+
     def _counter_snapshot(self) -> Dict[str, float]:
         """The worker's cumulative counters, read once per report on the
         settle path (one ``memory_stats()`` per local device; nothing per
@@ -1607,6 +1660,10 @@ class Worker:
             "hbm_peak_bytes": device_peak_bytes(),
             "dispatches": self._dispatches,
             "dispatches_device_idle": self._dispatches_idle,
+            # Rides every report only because the metric init_state_s.ex4
+            # reads it here; the set-up chain's ``init_state`` span is the
+            # same seconds, once (the benchmark issue that retires the
+            # twin takes this key out).
             "init_state_s": round(
                 self.trainer.init_state_s if self.trainer else 0.0, 6
             ),
@@ -1925,6 +1982,9 @@ class Worker:
         self._dispatches = seq + 1
         if self._last_output is not None and self._last_output.is_ready():
             self._dispatches_idle += 1
+        # The set-up chain's two stamps around the FIRST dispatch (None
+        # from the second on, and once the chain is sent).
+        setup = self._setup if seq == 0 else None
         if self._profile_state == "open" and self._profile_last_task is None:
             self._profile_traced += 1
             if self._profile_traced >= PROFILE_TASKS:
@@ -1984,6 +2044,8 @@ class Worker:
                     "dispatch", task=tid, seq=seq,
                     step0=self._steps_dispatched,
                 ), jitsan.transfer_guard():
+                    if setup is not None:
+                        self._setup_first_dispatch(setup, begins=True)
                     self.state, scan_metrics = self.trainer.train_scan(
                         self.state, self.trainer.shard_stacked_batch(stacked)
                     )
@@ -2028,6 +2090,8 @@ class Worker:
                     "dispatch", task=tid, seq=seq,
                     step0=self._steps_dispatched,
                 ), jitsan.transfer_guard(when=not self.spec.host_io):
+                    if setup is not None:
+                        self._setup_first_dispatch(setup, begins=True)
                     self.state, metrics_list = self.trainer.run_train_steps(
                         self.state,
                         prefetch(
@@ -2060,6 +2124,8 @@ class Worker:
                 self._recover_state()
             self._steps_dispatched = int(self.state.step)
             raise
+        if setup is not None:
+            self._setup_first_dispatch(setup, begins=False)
         # Live throughput counters (r14): O(1) adds under a leaf lock —
         # the only gauge API legal on the hot path (gauge-discipline).
         self._g_examples.inc(total)
@@ -2265,10 +2331,25 @@ class Worker:
         gp = self.gauge_payload(force=True)
         if gp is not None:
             report["gauge"] = gp
+        # The set-up chain rides the first successful training report and
+        # no other: sent, it is dropped.
+        setup = self._setup
+        if (
+            setup is not None
+            and report["success"]
+            and report.get("task_type") == TASK_TRAINING
+            and setup.has("setup:first_dispatch")
+        ):
+            report["setup"] = self._setup_payload(setup)
+        else:
+            setup = None
         # The report RPC's own span (rpc:ReportTaskResult) nests inside
         # this one on this thread, so the task id covers it.
         with self.phases.phase("metrics", task=report["task_id"]):
             self.master.call("ReportTaskResult", report)
+        if setup is not None:
+            # graftlint: allow[shared-state] the _parked spin-wait handshake serializes the preemption thread's _flush_pending (the only off-loop report path) against the loop (see preemption_snapshot)
+            self._setup = None
 
     # hot-path: settles the PREVIOUS task while this one's steps run
     def _flush(self, pending: Optional[tuple]) -> None:
@@ -2723,8 +2804,14 @@ class Worker:
             )
         # graftlint: allow[blocking-propagation] one-time initial membership application before the loop starts
         self._apply_membership(membership, initial=True)
+        setup = self._setup
         if self.state is None:
+            if setup is not None:
+                # Worker.__init__, the model spec, the mesh, the Trainer.
+                setup.mark("setup:build")
             self.state = self.trainer.init_state(jax.random.key(0))
+            if setup is not None:
+                setup.mark("init_state")
             # Every device should hold its shard and nothing else.
             logger.info(
                 "device bytes in use after init: %s",
@@ -2779,6 +2866,9 @@ class Worker:
                         "every retained checkpoint step %s was torn; "
                         "training from freshly initialized state", steps,
                     )
+            if setup is not None and steps:
+                # A relaunched incarnation's walk over the checkpoints.
+                setup.mark("setup:restore")
 
         self._tasks_done = 0
         # graftlint: allow[hot-path-sync] one-time mirror seed before the loop; the restore above already settled the state
