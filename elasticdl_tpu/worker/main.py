@@ -412,7 +412,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     from elasticdl_tpu.common import gauge
     from elasticdl_tpu.common.metrics_http import maybe_start
 
-    with setup.child("setup:shards"):  # the reader's index scan
+    # The reader scans nothing here: a file is indexed at its first read,
+    # inside setup:first_prep (docs/observability.md).
+    with setup.child("setup:shards"):
         reader = build_job_reader(config)
     worker = Worker(
         config, master, reader, worker_id=worker_id,

@@ -565,3 +565,201 @@ def test_prefetch_thread_name_attributes_task():
 
     assert list(prefetch(gen(), 2, name="prefetch:42")) == [1]
     assert names == ["prefetch:42"]
+
+
+# -- the record index: one vectorised scan a file a process (PR 36) --
+
+_LINE_FILES = {
+    "trailing_newline": b"1,a\n2,b\n3,c\n",
+    "no_trailing_newline": b"1,a\n2,b\n3,c",
+    "crlf": b"1,a\r\n2,b\r\n3,c\r\n",
+    "blank_lines_middle_and_end": b"1,a\n\n\n2,b\n\n",
+    "only_newlines": b"\n\n\n",
+    "empty": b"",
+    "one_line": b"1,a",
+    "one_line_newline": b"1,a\n",
+}
+
+
+def _loop_line_offsets(path):
+    """The plain scan the vectorised one replaced, kept as its reference."""
+    offsets = []
+    with open(path, "rb") as f:
+        pos = f.tell()
+        for line in f:
+            offsets.append(pos)
+            pos += len(line)
+    return offsets
+
+
+# Chunks of 1 to 5 bytes put a boundary on, before and after a newline of
+# every file above ("1,a\n": 4 bytes a line); None is the real chunk size.
+@pytest.mark.parametrize("chunk_bytes", [1, 2, 3, 4, 5, None])
+@pytest.mark.parametrize("name", sorted(_LINE_FILES))
+def test_vectorised_line_scan_equals_the_line_loop(tmp_path, name, chunk_bytes):
+    from elasticdl_tpu.data.reader import scan_line_offsets
+
+    path = str(tmp_path / "lines.txt")
+    open(path, "wb").write(_LINE_FILES[name])
+    got = (
+        scan_line_offsets(path) if chunk_bytes is None
+        else scan_line_offsets(path, chunk_bytes)
+    )
+    assert got.dtype == np.int64 and got.ndim == 1
+    assert got.tolist() == _loop_line_offsets(path)
+
+
+@pytest.mark.parametrize("name", sorted(_LINE_FILES))
+def test_skip_header_slices_the_shared_line_index(tmp_path, name):
+    path = str(tmp_path / "lines.txt")
+    open(path, "wb").write(_LINE_FILES[name])
+    lines = [l.rstrip(b"\r\n") for l in _LINE_FILES[name].split(b"\n")]
+    lines = lines[: len(_loop_line_offsets(path))]
+    whole = Shard(path, 0, 99)
+    for skip in (False, True):
+        reader = CSVDataReader(path, skip_header=skip)
+        assert reader._offsets(path).tolist() == _loop_line_offsets(path)[skip:]
+        assert list(reader.read_records(whole)) == lines[skip:]
+        assert list(reader.read_records_packed(whole)) == lines[skip:]
+        assert sum(s.size for s in reader.create_shards(2)) == len(lines[skip:])
+
+
+def _index_counts(container):
+    """(builds, waits) of the process's registry for one container."""
+    from elasticdl_tpu.common import gauge
+
+    return tuple(
+        gauge.default().counter(name, labels={"container": container}).value()
+        for name in ("edl_reader_index_builds_total", "edl_reader_index_waits_total")
+    )
+
+
+def _slow_scan(monkeypatch, module, name, calls, seconds=0.3):
+    """Make a module's scan last long enough that threads released together
+    all arrive while it runs, and count its calls."""
+    import time
+
+    real = getattr(module, name)
+
+    def scan(path):
+        calls.append(path)
+        time.sleep(seconds)
+        return real(path)
+
+    monkeypatch.setattr(module, name, scan)
+
+
+@pytest.mark.parametrize("container", ["text", "recordio"])
+def test_cold_index_is_built_once_for_ten_threads(tmp_path, monkeypatch, container):
+    """Ten threads released together on a cold reader, each reading its own
+    range of ONE file (a worker's first task): the scan runs once, the
+    others wait for it, every thread gets its own range's records."""
+    import sys
+    import threading
+    from elasticdl_tpu.data import reader as reader_mod
+    from elasticdl_tpu.data import recordio as rio
+
+    records = [b"%d,r" % i for i in range(200)]
+    calls = []
+    if container == "text":
+        path = str(tmp_path / "d.csv")
+        open(path, "wb").write(b"\n".join(records) + b"\n")
+        _slow_scan(monkeypatch, reader_mod, "scan_line_offsets", calls)
+        reader = CSVDataReader(path)
+    else:
+        path = str(tmp_path / "d.rio")
+        write_records(path, records)
+        _slow_scan(monkeypatch, rio, "_scan_record_offsets", calls)
+        reader = RecordIODataReader(path)
+    builds0, waits0 = _index_counts(container)
+    n = 10
+    barrier = threading.Barrier(n)
+    got, errors = [None] * n, []
+
+    def read(i):
+        try:
+            barrier.wait(timeout=10)
+            got[i] = list(reader.read_records_packed(Shard(path, 20 * i, 20 * i + 20)))
+        except BaseException as e:  # surfaced below: a thread must not die silently
+            errors.append(e)
+
+    threads = [threading.Thread(target=read, args=(i,), daemon=True) for i in range(n)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(t.is_alive() for t in threads)
+    assert calls == [path]  # ONE scan
+    builds, waits = _index_counts(container)
+    assert builds - builds0 == 1
+    assert 1 <= waits - waits0 <= n - 1
+    for i in range(n):
+        assert got[i] == records[20 * i : 20 * i + 20]
+
+
+def test_two_files_index_concurrently(tmp_path, monkeypatch):
+    """The cache-wide lock is not held during a scan: while one file's scan
+    is in flight another file is indexed to the end, and neither waits on
+    the other's key."""
+    import threading
+    from elasticdl_tpu.data import reader as reader_mod
+
+    slow, fast = str(tmp_path / "slow.csv"), str(tmp_path / "fast.csv")
+    open(slow, "wb").write(b"1\n2\n3\n")
+    open(fast, "wb").write(b"4\n5\n")
+    real = reader_mod.scan_line_offsets
+    started, fast_done = threading.Event(), threading.Event()
+    overlapped = []
+
+    def scan(path):
+        if path == slow:
+            started.set()
+            overlapped.append(fast_done.wait(timeout=10))
+        return real(path)
+
+    monkeypatch.setattr(reader_mod, "scan_line_offsets", scan)
+    builds0, waits0 = _index_counts("text")
+    out = {}
+    t = threading.Thread(
+        target=lambda: out.update(slow=list(CSVDataReader(slow).read_records(Shard(slow, 0, 9)))),
+        daemon=True,
+    )
+    t.start()
+    assert started.wait(timeout=10)
+    assert list(CSVDataReader(fast).read_records(Shard(fast, 0, 9))) == [b"4", b"5"]
+    fast_done.set()
+    t.join(timeout=10)
+    assert not t.is_alive() and out["slow"] == [b"1", b"2", b"3"]
+    assert overlapped == [True]  # the other file was indexed inside this scan
+    builds, waits = _index_counts("text")
+    assert (builds - builds0, waits - waits0) == (2, 0)
+
+
+@pytest.mark.parametrize("change", ["size", "mtime"])
+def test_rewritten_text_file_is_indexed_again(tmp_path, change):
+    """A second reader instance of an UNCHANGED file shares the first's
+    scan; one of a file rewritten since (new size, or the same size at a new
+    mtime) scans again and serves the new lines."""
+    import os
+
+    path = str(tmp_path / "d.csv")
+    open(path, "wb").write(b"1,a\n2,b\n3,c\n")
+    builds0, _ = _index_counts("text")
+    first = CSVDataReader(path)
+    assert list(first.read_records(Shard(path, 0, 9))) == [b"1,a", b"2,b", b"3,c"]
+    assert np.shares_memory(CSVDataReader(path)._offsets(path), first._offsets(path))
+    assert _index_counts("text")[0] - builds0 == 1
+    before = os.stat(path)
+    new = b"1,a\n2,b\n3,c\n4,d\n" if change == "size" else b"1\n2\n3\n4\n5\n6\n"
+    open(path, "wb").write(new)
+    if change == "mtime":
+        assert os.stat(path).st_size == before.st_size
+        os.utime(path, ns=(before.st_atime_ns, before.st_mtime_ns + 1_000_000_000))
+    again = CSVDataReader(path)
+    assert list(again.read_records(Shard(path, 0, 9))) == new.split(b"\n")[:-1]
+    assert _index_counts("text")[0] - builds0 == 2
