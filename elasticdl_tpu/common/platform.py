@@ -203,6 +203,26 @@ def device_bytes_in_use() -> list:
     return out
 
 
+def device_bytes_limit(devices) -> "int | None":
+    """The smallest ``memory_stats()["bytes_limit"]`` among ``devices`` that
+    this process addresses: what a program may occupy on a chip.  None where
+    the backend reports no stats (XLA:CPU, a described device)."""
+    import jax
+
+    limits = []
+    for d in devices:
+        if d.process_index != jax.process_index():
+            continue
+        try:
+            stats = d.memory_stats()
+        except Exception:  # noqa: BLE001 - a compile-only device has no runtime to ask
+            stats = None
+        if not stats or not stats.get("bytes_limit"):
+            return None
+        limits.append(int(stats["bytes_limit"]))
+    return min(limits) if limits else None
+
+
 def device_peak_bytes() -> int:
     """Peak device memory on the fullest local chip, in bytes:
     ``peak_bytes_in_use + peak_bytes_reserved`` of ``memory_stats()``.  The

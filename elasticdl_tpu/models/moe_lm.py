@@ -104,6 +104,7 @@ from elasticdl_tpu.data.codecs import lm_feed
 from elasticdl_tpu.models.spec import ModelSpec
 from elasticdl_tpu.ops import eva_attention as eva_ops
 from elasticdl_tpu.ops import moe
+from elasticdl_tpu.ops import remat as remat_lib
 from elasticdl_tpu.ops.embedding import ParallelContext
 from elasticdl_tpu.ops.ring_attention import ring_attention
 
@@ -277,9 +278,11 @@ def _eva_attention(a, blk, positions, *, axis, theta, cast, window, chunk):
     held, hd = blk["eva_phi"].shape
     heads = lambda t: t.reshape(b, l, held, hd)  # noqa: E731
     with jax.named_scope("eva_proj"):
-        q = rope(heads(a @ cast(blk["wq"])), positions, theta)
-        k = rope(heads(a @ cast(blk["wk"])), positions, theta)
-        v = heads(a @ cast(blk["wv"]))
+        # save sites (ops/remat.py): each projection as the attention reads it
+        wq, wk, wv = cast(blk["wq"]), cast(blk["wk"]), cast(blk["wv"])
+        q = remat_lib.site("q", 2 * a.size * wq.shape[1], rope(heads(a @ wq), positions, theta))
+        k = remat_lib.site("k", 2 * a.size * wk.shape[1], rope(heads(a @ wk), positions, theta))
+        v = heads(remat_lib.product("v", a, wv))
     att = eva_ops.eva_attention(q, k, v, blk["eva_phi"], blk["eva_mu"], window=window, chunk=chunk)
     with jax.named_scope("eva_proj"):
         return att.reshape(b, l, held * hd) @ cast(blk["wo"])
@@ -314,7 +317,8 @@ def _latent_attention(a, blk, positions, *, axis, n_heads, theta, eps, cast, rot
 
 def _gated_mlp(u, w_gate, w_up, w_down):
     with jax.named_scope("mlp"):
-        return (jax.nn.silu(u @ w_gate) * (u @ w_up)) @ w_down
+        # gate and up are save sites (ops/remat.py); silu and the product never
+        return (jax.nn.silu(remat_lib.product("mlp_gate", u, w_gate)) * remat_lib.product("mlp_up", u, w_up)) @ w_down
 
 
 def _block(
@@ -388,11 +392,17 @@ def _apply(
     positions = offset + jnp.arange(l)
     x = params["tok_emb"][tokens].astype(residual_dtype or compute_dtype)
     block_fn = functools.partial(_block, axis=axis, compute_dtype=compute_dtype, **block_args)
+    names = sorted(params["blocks"])
+    blocks = [block_fn] * len(names)
     if remat and train:
-        block_fn = jax.checkpoint(block_fn)
+        # Every block rematerialised, each keeping what the byte budget the
+        # trainer resolved gives it (ops/remat.py; 0 = nothing, as ever).
+        blocks = remat_lib.plan(
+            block_fn, [(x, params["blocks"][name], positions) for name in names], ctx.remat_keep_bytes
+        )
     routed = []
-    for name in sorted(params["blocks"]):
-        x, stats = block_fn(x, params["blocks"][name], positions)
+    for name, block in zip(names, blocks):
+        x, stats = block(x, params["blocks"][name], positions)
         if stats is not None:
             routed.append(stats)
     with jax.named_scope("lm_head"):
@@ -697,4 +707,5 @@ def model_spec(
             functools.partial(_update_correction_bias, speed=float(bias_update_speed))
             if correction_bias else None
         ),
+        rematerialises=bool(remat),
     )

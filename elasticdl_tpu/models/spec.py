@@ -154,6 +154,11 @@ class ModelSpec:
     # and sums over the mesh inside ``apply`` whatever the rule reads, so
     # that every replica makes the same move.
     after_update: Optional[Callable[[Params, Any], Params]] = None
+    # The model rematerialises its blocks through ``ops/remat.plan``: the
+    # trainer then resolves ``ParallelContext.remat_keep_bytes`` (what the
+    # blocks may keep of their activations) from the device's memory before
+    # it traces the model, and holds the compiled step to the device.
+    rematerialises: bool = False
     # The Adam record ``optimizer`` was declared as, if it was.
     adam: Optional[Adam] = dataclasses.field(default=None, init=False)
 

@@ -45,6 +45,7 @@ from jax import lax
 from elasticdl_tpu.common.jax_compat import axis_size
 from elasticdl_tpu.data.codecs import lm_feed
 from elasticdl_tpu.models.spec import ModelSpec
+from elasticdl_tpu.ops import remat as remat_lib
 from elasticdl_tpu.ops.ring_attention import (
     PATH_XLA_REFERENCE,
     announce_path,
@@ -103,15 +104,18 @@ def _block(x, blk, axis, n_heads, compute_dtype):
     # "Layouts"; a slice between the two reshapes made XLA keep a 4-D array
     # of 64-wide rows, sequence-minor, and pay a transpose copy per operand:
     # PERF.md, PR 31).
+    # Every product here is a save site of the rematerialised block
+    # (ops/remat.py), and so are the flash kernel's output and logsumexp
+    # (tagged where they are born); norms, gelu and the adds never.
     q, k, v = (
-        (h @ w).reshape(b, l, n_heads, head_dim)
-        for w in jnp.split(blk["wqkv"].astype(compute_dtype), 3, axis=1)
+        remat_lib.product(name, h, w).reshape(b, l, n_heads, head_dim)
+        for name, w in zip("qkv", jnp.split(blk["wqkv"].astype(compute_dtype), 3, axis=1))
     )
     # Blockwise causal attention; K/V ring over the sequence axis.
     att = ring_attention(q, k, v, axis_name=axis, causal=True)
-    x = x + att.reshape(b, l, dim) @ blk["wo"].astype(compute_dtype)
+    x = x + remat_lib.product("attn_proj", att.reshape(b, l, dim), blk["wo"].astype(compute_dtype))
     h = _rms_norm(x, blk["ln2"])
-    h = jax.nn.gelu(h @ blk["w1"].astype(compute_dtype))
+    h = jax.nn.gelu(remat_lib.product("mlp_up", h, blk["w1"].astype(compute_dtype)))
     return x + h @ blk["w2"].astype(compute_dtype)
 
 
@@ -142,23 +146,34 @@ def _apply(
 
     x = params["tok_emb"][tokens] + params["pos_emb"][pos][None]
     x = x.astype(compute_dtype)
-    # Rematerialization (jax.checkpoint) per block in TRAINING: activations
-    # inside a block are recomputed during the backward instead of living in
-    # HBM for the whole forward — peak activation memory drops from
+    # Rematerialization per block in TRAINING: activations inside a block
+    # are recomputed during the backward instead of living in HBM for the
+    # whole forward — peak activation memory drops from
     # O(n_layers * B * S/n * dim * ~10) to ~one block's worth (+ the residual
-    # stream), the standard FLOPs-for-HBM trade for long sequences.  The
-    # ring-attention ppermutes replay fine under remat (pure collective).
+    # stream), the standard FLOPs-for-HBM trade for long sequences — except
+    # the save sites the byte budget the trainer resolved lets a layer keep
+    # (ops/remat.py; 0, off the TPU, keeps nothing).  The ring-attention
+    # ppermutes replay fine under remat (pure collective).
     # Eval/predict skip it — there is no backward to save memory for.
     block_fn = functools.partial(
         _block, axis=axis, n_heads=n_heads, compute_dtype=compute_dtype
     )
-    if remat and train:
-        block_fn = jax.checkpoint(block_fn)
-    for name in sorted(params["blocks"]):
-        x = block_fn(x, params["blocks"][name])
+    x = _run_blocks(block_fn, x, params["blocks"], remat and train, ctx)
     x = _rms_norm(x, params["ln_f"])
     # Weight-tied head; logits in f32 for a stable softmax/CE.
     return (x @ params["tok_emb"].T.astype(compute_dtype)).astype(jnp.float32)
+
+
+def _run_blocks(block_fn, x, blocks, rematerialise: bool, ctx: ParallelContext):
+    """``x`` through the blocks in name order, each rematerialised with its
+    own keep-set where ``rematerialise``."""
+    names = sorted(blocks)
+    fns = [block_fn] * len(names)
+    if rematerialise:
+        fns = remat_lib.plan(block_fn, [(x, blocks[name]) for name in names], ctx.remat_keep_bytes)
+    for name, fn in zip(names, fns):
+        x = fn(x, blocks[name])
+    return x
 
 
 def _tp_block(x, blk, tp_axis, n_heads, compute_dtype):
@@ -249,10 +264,7 @@ def _tp_apply(
         _tp_block, tp_axis=ctx.tp_axis, n_heads=n_heads,
         compute_dtype=compute_dtype,
     )
-    if remat and train:
-        block_fn = jax.checkpoint(block_fn)
-    for name in sorted(params["blocks"]):
-        x = block_fn(x, params["blocks"][name])
+    x = _run_blocks(block_fn, x, params["blocks"], remat and train, ctx)
     x = _rms_norm(x, params["ln_f"])
     return (x @ params["tok_emb"].T.astype(compute_dtype)).astype(jnp.float32)
 
@@ -353,4 +365,5 @@ def model_spec(
         # parallelism keeps sequences whole and shards examples over dp.
         batch_shard_dim=0 if tensor else 1,
         tensor_sharding=_tp_dims if tensor else None,
+        rematerialises=bool(remat),
     )

@@ -190,13 +190,17 @@ class ParallelContext:
     resolves it before tracing via :func:`resolve_impl`.  ``tp_axis`` names
     the tensor-parallel mesh axis on a 2D ``(dp, tp)`` mesh (r20) — models
     with a ``tensor_sharding`` plan switch their apply to the column/row
-    -split path when it is set; None everywhere else.
+    -split path when it is set; None everywhere else.  ``remat_keep_bytes``
+    is what a model's rematerialised blocks may keep of their activations
+    (``ops/remat.py``): the trainer resolves it from the device's memory
+    before it traces the model; 0 (off the TPU, always) keeps nothing.
     """
 
     axis_name: Optional[str] = None
     sharded_embeddings: bool = False
     embedding_impl: str = IMPL_AUTO
     tp_axis: Optional[str] = None
+    remat_keep_bytes: int = 0
 
 
 def row_stride(dim: int) -> int:

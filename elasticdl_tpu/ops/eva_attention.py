@@ -58,6 +58,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.ops import flash_attention as fa
+from elasticdl_tpu.ops import remat
 from elasticdl_tpu.ops.flash_attention import (
     _LANE,
     _NN,
@@ -362,7 +363,7 @@ def _lanes(x):
     return x.reshape(x.shape[0], x.shape[1], -1)
 
 
-def _fwd_impl(q, k, v, ktil, vtil, window):
+def _fwd_impl(q, k, v, ktil, vtil, window, keep=False):
     b, l, h, d = q.shape
     # What the kernels cannot do at all; the tiles' alignment the compiler
     # needs is ``outside_contract``'s (the interpreter takes any).
@@ -380,6 +381,7 @@ def _fwd_impl(q, k, v, ktil, vtil, window):
     )
     with jax.named_scope("eva_attn"):
         operands = tuple(_lanes(x) for x in (q, k, v, ktil, vtil))
+        cost = plan.cost(4 * _LANE, operands + operands[:1])
         o, lse = plan.launch(
             _fwd_kernel,
             in_specs=[plan.rows] * 3 + [plan.far] * 2,
@@ -389,24 +391,29 @@ def _fwd_impl(q, k, v, ktil, vtil, window):
                 jax.ShapeDtypeStruct((b * h, 1, l), jnp.float32),
             ],
             scratch_shapes=plan.state(_T_FWD, 1, 1, _LANE),
-            cost_estimate=plan.cost(4 * _LANE, operands + operands[:1]),
+            cost_estimate=cost,
         )(*operands)
+        # A save site (ops/remat.py): the backward kernels' residuals are born
+        # here, so a rematerialised block that keeps them runs no second forward.
+        o, lse = remat.site("attn_out", remat.kernel_work(cost), o, lse, keep=keep)
         return o.reshape(q.shape), (*operands, o, lse)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def eva_flash(q, k, v, ktil, vtil, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def eva_flash(q, k, v, ktil, vtil, window, keep=False):
     """Step 5 by the kernels: ``q``, ``k``, ``v`` [B, L, H, 128] with L
     whole windows, ``ktil``, ``vtil`` [B, C, H, 128] the chunks' summaries
-    (:func:`chunk_summaries`), C / (L / window) a window."""
+    (:func:`chunk_summaries`), C / (L / window) a window.  ``keep``: the
+    rematerialised block being traced keeps the output and its logsumexp
+    (``remat.kept``, asked while the primal is traced)."""
     return _fwd_impl(q, k, v, ktil, vtil, window)[0]
 
 
-def _eva_fwd(q, k, v, ktil, vtil, window):
-    return _fwd_impl(q, k, v, ktil, vtil, window)
+def _eva_fwd(q, k, v, ktil, vtil, window, keep):
+    return _fwd_impl(q, k, v, ktil, vtil, window, keep)
 
 
-def _eva_bwd(window, res, g):
+def _eva_bwd(window, keep, res, g):
     qk, kk, vk, ks, vs, o, lse = res
     b, l, h, d = g.shape
     plan = _Plan(g.shape, window, ks.shape[1])
@@ -467,7 +474,8 @@ def eva_attention(q, k, v, phi, mu, *, window: int, chunk: int, with_lse: bool =
     padded = -(-l // window) * window
     if padded != l:
         q, k, v = (jnp.pad(t, ((0, 0), (0, padded - l), (0, 0), (0, 0))) for t in (q, k, v))
-    ktil, vtil = chunk_summaries(k, v, phi, mu, chunk)
+    # a save site (ops/remat.py): the two weighted sums; their softmax is recomputed
+    ktil, vtil = remat.site("summaries", 4 * k.size, *chunk_summaries(k, v, phi, mu, chunk))
     why_not = _why_not_kernels(q, k, v, window, chunk)
     if why_not:
         announce_path(PATH_XLA_REFERENCE, q, True, f"eva window={window} chunk={chunk}; {why_not}")
@@ -477,5 +485,5 @@ def eva_attention(q, k, v, phi, mu, *, window: int, chunk: int, with_lse: bool =
         out, res = _fwd_impl(q, k, v, ktil, vtil, window)
         out = out, res[-1].reshape(b, h, padded)
     else:
-        out = eva_flash(q, k, v, ktil, vtil, window)
+        out = eva_flash(q, k, v, ktil, vtil, window, remat.kept("attn_out"))
     return (out[0][:, :l], out[1][:, :, :l]) if with_lse else out[:, :l]

@@ -118,6 +118,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from elasticdl_tpu.ops import remat
 from elasticdl_tpu.ops.ring_attention import (
     PATH_PALLAS_COMPILED,
     PATH_PALLAS_INTERPRET,
@@ -632,7 +633,7 @@ class _Plan:
         return [pltpu.VMEM((slabs, self.rows, w), jnp.float32) for w in widths]
 
 
-def _fwd_impl(q, k, v, causal, rot=()):
+def _fwd_impl(q, k, v, causal, rot=(), keep=False):
     _check(q, k, v, rot)
     r = rot[0].shape[-1] if rot else 0
     plan = _Plan(q.shape, causal, r)
@@ -663,6 +664,7 @@ def _fwd_impl(q, k, v, causal, rot=()):
                 _lanes(rot[0], plan.lp, plan.groups * _LANE),
                 _lanes(jnp.tile(rot[1], (1, 1, _LANE // r)), plan.lp, _LANE),
             )
+        cost = plan.cost(3 if r else 2, _T_FWD, (qk, kk, vk, *rot, qk))
         o, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, **plan.kernel_args),
             grid=plan.grid,
@@ -673,11 +675,12 @@ def _fwd_impl(q, k, v, causal, rot=()):
                 jax.ShapeDtypeStruct((plan.bh, 1, plan.lp), jnp.float32),
             ],
             scratch_shapes=plan.state(1, 1, _LANE),
-            cost_estimate=plan.cost(
-                3 if r else 2, _T_FWD, (qk, kk, vk, *rot, qk)
-            ),
+            cost_estimate=cost,
             interpret=interpret,
         )(qk, kk, vk, *rot)
+        # A save site (ops/remat.py): a rematerialised block that keeps the
+        # output and its logsumexp runs no second forward.
+        o, lse = remat.site("attn_out", remat.kernel_work(cost), o, lse, keep=keep)
         # Residuals are the kernels' operands themselves: q, k, v as they
         # came in (viewed [B, L, H*D]), the output as it goes out.
         return _heads(o, q.shape), (qk, kk, vk, o, lse, *rot)
@@ -688,19 +691,21 @@ def flash_attention(q, k, v, causal=False, q_rot=None, k_rot=None):
     ``q_rot`` [B, L, H, R] and ``k_rot`` [B, L, R] (ONE key for every head)
     a head's score is ``(q . k + q_rot . k_rot) * (D + R)^-0.5``: latent
     attention's 128 + 64 wide queries and keys over 128-wide values."""
-    return _flash(q, k, v, () if q_rot is None else (q_rot, k_rot), causal)
+    # ``remat.kept``: asked here, while the primal is traced (jax runs the
+    # forward rule later, outside the rematerialised block's trace).
+    return _flash(q, k, v, () if q_rot is None else (q_rot, k_rot), causal, remat.kept("attn_out"))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _flash(q, k, v, rot, causal):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash(q, k, v, rot, causal, keep=False):
     return _fwd_impl(q, k, v, causal, rot)[0]
 
 
-def _fa_fwd(q, k, v, rot, causal):
-    return _fwd_impl(q, k, v, causal, rot)
+def _fa_fwd(q, k, v, rot, causal, keep):
+    return _fwd_impl(q, k, v, causal, rot, keep)
 
 
-def _fa_bwd(causal, res, g):
+def _fa_bwd(causal, keep, res, g):
     qk, kk, vk, o, lse, *rot = res
     r = rot[0].shape[2] // g.shape[2] if rot else 0
     plan = _Plan(g.shape, causal, r)

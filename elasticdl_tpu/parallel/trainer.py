@@ -26,6 +26,7 @@ are left alone.  The two paths are consistent without rescaling.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import inspect
 import os
 import time
@@ -44,6 +45,7 @@ from elasticdl_tpu.common import trace
 from elasticdl_tpu.common.config import DistributionStrategy, JobConfig
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.metrics import HIST_PREFIX
+from elasticdl_tpu.common.platform import device_bytes_limit
 from elasticdl_tpu.models.spec import EmbeddingTableSpec, ModelSpec
 from elasticdl_tpu.parallel import collectives as coll
 logger = get_logger("trainer")
@@ -56,6 +58,7 @@ from elasticdl_tpu.ops.embedding import (
     sweeps,
     table_shape,
 )
+from elasticdl_tpu.ops import remat
 from elasticdl_tpu.ops.table_grad import sweep_adam
 
 from elasticdl_tpu.common.jax_compat import jit_compiled, jit_donating, shard_map
@@ -400,6 +403,82 @@ def pad_embedding_tables(params: Any, tables: List[EmbeddingTableSpec]) -> Any:
     return jax.tree_util.tree_map_with_path(pad, params)
 
 
+#: What a compiled step leaves free of a device's ``bytes_limit``: the line
+#: it is held to is the limit less this (14.25 of a v5e's 15.75 GiB).  The
+#: runtime's own peak reads 0.13 GiB over the compiler's sum, and a program
+#: at the limit's edge fails to load beside the checkpoint copy of its state.
+REMAT_HEADROOM = 3 * 2**29
+#: What the choice aims under the line by (the estimate of a step with
+#: nothing kept is within 0.1 GiB of the compiler's account, and what is kept
+#: cost gpt2m_job's step 0.14 GiB more than its bytes), and what is taken off
+#: the aim again, beside the overshoot, when a compiled step reads over the
+#: line all the same and is compiled once more with less kept.
+REMAT_REFIT_MARGIN = 2**28
+#: The loss's share of a step's peak, in the logits' bytes: the float32
+#: logits, and their gradient in the compute type (gpt2m_job's compiled step
+#: with nothing kept: PERF.md section 6, PR 38).
+_LOGITS_HELD = 1.5
+#: A block's backward holds its recomputed sites and their cotangents.
+_BLOCK_HELD = 2.0
+
+
+@dataclasses.dataclass
+class KeepPlan:
+    """What a model's rematerialised blocks may keep on one device
+    (``ops/remat.py``): ``line`` is what the compiled step may occupy, ``aim``
+    what the choice is made for (the line, less what an earlier compile read
+    over it).  The rest is filled when the step is traced: the step's
+    estimated bytes with nothing kept, the budget that leaves
+    (``ParallelContext.remat_keep_bytes``), and the sites' bytes."""
+
+    line: int
+    aim: int
+    estimate: int = 0
+    budget: int = 0
+    tagged: int = 0
+    kept: int = 0
+
+
+def _resolve_keep_budget(spec: ModelSpec, ctx: ParallelContext, plan: KeepPlan, state, batch):
+    """``ctx`` with the bytes this device's blocks may keep, and the abstract
+    traces of the blocks that were made to find them (the step's own trace
+    reuses them).  The budget is what ``plan.aim`` leaves over an estimate of
+    the step with nothing kept, from what can be observed before the model
+    is traced: the state's bytes (parameters and optimizer moments; a
+    gradient is consumed by its leaf's update as it is born) and the model's
+    own activations off one abstract trace of it (every block's inputs, the
+    loss's working set and one block's backward).  The estimate is within
+    0.1 GiB of the compiler's account of both LM cells' steps with nothing
+    kept (PERF.md section 6, PR 38)."""
+    with remat.survey(shapes_only=True) as held:
+        out = jax.eval_shape(lambda p, b: spec.apply(p, b, train=True, ctx=ctx), state.params, batch)
+    # XLA's schedule has the loss's tensors alive while the last block's
+    # backward begins: the two working sets add up.
+    layer_bytes = [sum(s.nbytes for s in sites) for sites in held.layers]
+    loss_held = _LOGITS_HELD * remat.nbytes(out)
+    block_held = _BLOCK_HELD * max(layer_bytes, default=0)
+    plan.estimate = int(remat.nbytes(state) + remat.nbytes(batch) + held.block_input_bytes + loss_held + block_held)
+    plan.budget = max(plan.aim - plan.estimate, 0) if held.layers else 0
+    if plan.budget and block_held > loss_held:
+        # Where a block's backward, not the loss, is the step's peak, what
+        # the LAST block keeps is what its backward would hold recomputed
+        # anyway: it costs the peak nothing (evabyte_job: everything kept
+        # compiles to 0.70 GiB under its bytes), and the chooser's ties go
+        # to the last layer first.
+        plan.budget += layer_bytes[-1]
+    return dataclasses.replace(ctx, remat_keep_bytes=plan.budget), held.traces
+
+
+def compiled_bytes(compiled) -> int:
+    """What a compiled program occupies on a device, by the compiler's own
+    account: arguments + outputs - aliased + temporaries."""
+    ma = compiled.memory_analysis()
+    return int(
+        ma.argument_size_in_bytes + ma.output_size_in_bytes
+        - ma.alias_size_in_bytes + ma.temp_size_in_bytes
+    )
+
+
 class Trainer:
     """Builds and runs jitted train/eval steps for a ModelSpec over a mesh."""
 
@@ -414,6 +493,7 @@ class Trainer:
         )
         self.ctx = self._make_ctx()
         self._state_specs = None
+        self.keep_plan: Optional[KeepPlan] = None
         #: Wall seconds of the last ``init_state`` (the worker's
         #: ``init_state_s`` counter).
         self.init_state_s = 0.0
@@ -1489,11 +1569,12 @@ class Trainer:
     # blows up on the tail's pytree (found by test_partial_tail_batch).
     # jit still handles shape/dtype retraces within a structure.
 
-    def _structured(self, cache: Dict, build, batch: Any, **kwargs):
+    def _structured(self, cache: Dict, build, batch: Any, fit_args=None, **kwargs):
         key = jax.tree.structure(batch)
         fn = cache.get(key)
         if fn is None:
-            fn = build(
+            make = functools.partial(
+                build,
                 self.spec,
                 self.mesh,
                 self.ctx,
@@ -1502,7 +1583,7 @@ class Trainer:
                 batch_axes=self.batch_axes,
                 **kwargs,
             )
-            cache[key] = fn
+            fn = cache[key] = make() if fit_args is None else self._held_to_the_line(make, fit_args)
         return fn
 
     def _train_build_kwargs(self) -> Dict[str, Any]:
@@ -1516,10 +1597,52 @@ class Trainer:
             collective=self.collective,
         )
 
+    def _new_keep_plan(self, over: int = 0) -> Optional[KeepPlan]:
+        """The byte budget of a model that rematerialises its blocks, from
+        the memory of this mesh's devices; None (nothing kept, the step as
+        it always was) for every other model and wherever the backend
+        reports no memory (off the TPU).  ``over``: what an earlier compile
+        of the step read over the line."""
+        if not self.spec.rematerialises:
+            return None
+        limit = device_bytes_limit(self.mesh.devices.flat)
+        if limit is None:
+            return None
+        line = limit - REMAT_HEADROOM
+        #: the newest plan, filled when its step is traced (logs and tests read it)
+        self.keep_plan = KeepPlan(line=line, aim=line - REMAT_REFIT_MARGIN - over)
+        return self.keep_plan
+
+    def _held_to_the_line(self, make: Callable, args: Tuple) -> Callable:
+        """The train step ``make(keep_plan=...)`` builds, for its first
+        call's ``args``.  Where the model is given a :class:`KeepPlan` the
+        step is compiled here and held to the plan's line: while the
+        compiler's own account of it reads over the line and something is
+        kept, it is made again with the overshoot and a margin off the aim.
+        The last compile is the one the first call would have made: the
+        call finds it."""
+        plan, compiles, gib = self._new_keep_plan(), 0, 2.0**30
+        step = make(keep_plan=plan)
+        while plan is not None:
+            total = compiled_bytes(step.lower(*args).compile())
+            compiles += 1
+            logger.info(
+                "rematerialised blocks keep %.3f of %.3f GiB tagged (budget %.3f): the step compiled to "
+                "%.3f GiB against a line of %.3f (estimated with nothing kept: %.3f); compile %d",
+                plan.kept / gib, plan.tagged / gib, plan.budget / gib, total / gib, plan.line / gib,
+                plan.estimate / gib, compiles,
+            )
+            if total <= plan.line or not plan.kept:
+                break
+            plan = self._new_keep_plan(over=total - plan.aim)
+            step = make(keep_plan=plan)
+        return step
+
     # jit-boundary: returns device buffers fresh off the compiled step
     def train_step(self, state: TrainState, batch: Any):
         self._train_step = self._structured(
             self._train_steps, build_train_step, batch,
+            fit_args=(state, batch, self._active_device()),
             host_keys=tuple(sorted(self.spec.host_io)),
             variant_budget=self.jit_budgets[
                 "train_step_2d" if self.tp_axis is not None else "train_step"
@@ -1551,13 +1674,14 @@ class Trainer:
             lambda t: jax.tree.map(lambda v: v[0], t), stacked
         )
 
-    def _scanned(self, cache: Dict, build, stacked: Any, **kwargs):
+    def _scanned(self, cache: Dict, build, stacked: Any, fit_args=None, **kwargs):
         """Scan-variant twin of _structured: build (or fetch) the fused
         lax.scan step for this stacked batch's tree structure."""
         key = ("scan", jax.tree.structure(stacked))
         fn = cache.get(key)
         if fn is None:
-            fn = build(
+            make = functools.partial(
+                build,
                 self.spec,
                 self.mesh,
                 self.ctx,
@@ -1567,7 +1691,7 @@ class Trainer:
                 scan_steps=True,
                 **kwargs,
             )
-            cache[key] = fn
+            fn = cache[key] = make() if fit_args is None else self._held_to_the_line(make, fit_args)
         return fn
 
     # jit-boundary: returns device buffers fresh off the compiled scan
@@ -1577,7 +1701,8 @@ class Trainer:
         ``stacked``: device batch from shard_stacked_batch.  Returns
         (state, metrics dict of [T]-stacked scalars)."""
         self._train_step = self._scanned(
-            self._train_steps, build_train_step, stacked, host_keys=(),
+            self._train_steps, build_train_step, stacked,
+            fit_args=(state, stacked, self._active_device()), host_keys=(),
             variant_budget=self.jit_budgets["train_scan"],
             **self._train_build_kwargs(),
         )
@@ -1625,6 +1750,7 @@ def build_train_step(
     donate: bool = True,
     collective: Any = None,
     variant_budget: int = 1,
+    keep_plan: Optional[KeepPlan] = None,
 ) -> Callable:
     """The jitted train step ``(state, batch, active) -> ...``.  With
     ``host_keys`` (host-tier tables), the step ALSO differentiates with
@@ -1796,12 +1922,18 @@ def build_train_step(
         )
         fused_tables = [leaves[i][1] for i in fused_at]
 
+        # What the model's rematerialised blocks may keep on this device is
+        # resolved here, before the model is traced (ops/remat.py).
+        model_ctx, block_traces = ctx, None
+        if keep_plan is not None:
+            model_ctx, block_traces = _resolve_keep_budget(spec, ctx, keep_plan, state, {**batch, **host_in})
+
         def loss_fn(params, host_embs, carriers):
             merged = dict(batch)
             merged.update(host_embs)
             params = _with_leaves(params, fused_at, fused_tables)
             with route_taps(fused_tables, carriers) as taps:
-                out = spec.apply(params, merged, train=True, ctx=ctx)
+                out = spec.apply(params, merged, train=True, ctx=model_ctx)
             aux = (
                 out,
                 sum(taps.rows_received) if taps.rows_received else None,
@@ -1814,15 +1946,16 @@ def build_train_step(
                 return spec.loss(out, merged, mask=mask) * count / total, aux
             return spec.loss(out, merged) * w / n_active, aux
 
-        (loss, (out, rows_received, table_grad, handed_ids)), (
-            grads, host_grads, handed_rows
-        ) = jax.value_and_grad(loss_fn, argnums=(0, 1, 2), has_aux=True)(
-            _with_leaves(
-                state.params, fused_at,
-                [jnp.zeros((1,) + t.shape[1:], t.dtype) for t in fused_tables],
-            ),
-            host_in, carriers,
-        )
+        with remat.survey(traces=block_traces) as held:
+            (loss, (out, rows_received, table_grad, handed_ids)), (
+                grads, host_grads, handed_rows
+            ) = jax.value_and_grad(loss_fn, argnums=(0, 1, 2), has_aux=True)(
+                _with_leaves(
+                    state.params, fused_at,
+                    [jnp.zeros((1,) + t.shape[1:], t.dtype) for t in fused_tables],
+                ),
+                host_in, carriers,
+            )
         loss = coll.psum(loss, axes)
         if opt_shard is not None:
             params, opt_state = sharded_update(state, grads)
@@ -1865,6 +1998,13 @@ def build_train_step(
             # Counts of what each device did, not means: summed.
             metrics[k] = coll.psum(raw[k] * w, axes)
         metrics["loss"] = loss
+        if held.layers:
+            # What the rematerialised blocks tagged and kept on a device
+            # (the worker sums both into its STEP_COUNTERS).
+            metrics["remat_bytes_tagged"] = coll.psum(jnp.float32(held.tagged_bytes) * w, axes)
+            metrics["remat_bytes_kept"] = coll.psum(jnp.float32(held.kept_bytes) * w, axes)
+            if keep_plan is not None:
+                keep_plan.tagged, keep_plan.kept = held.tagged_bytes, held.kept_bytes
         if rows_received is not None:
             # The ragged route's load: table rows this shard served in the
             # step, on the fullest shard and on average (the worker sums

@@ -103,6 +103,16 @@ COUNTER_GAUGES = {
         "edl_table_grad_rows_fused_total",
         "those of them whose table the merge sweep updated itself (dense "
         "Adam in the kernel, no gradient buffer)"),
+    "remat_bytes_tagged": (
+        "edl_remat_bytes_tagged_total",
+        "bytes of the save sites of the model's rematerialised blocks (the "
+        "tensors whose recomputation is a matmul or a kernel: ops/remat.py), "
+        "summed over layers, training steps and devices"),
+    "remat_bytes_kept": (
+        "edl_remat_bytes_kept_total",
+        "bytes of those sites the blocks kept for their backward instead of "
+        "recomputing them (chosen by bytes against the device's memory), "
+        "summed likewise"),
 }
 
 #: Step metrics (parallel/trainer.py) that are counts, not model metrics:
@@ -113,6 +123,7 @@ COUNTER_GAUGES = {
 STEP_COUNTERS = (
     "route_rows_recv_max", "route_rows_recv_mean",
     "table_grad_rows", "table_grad_rows_swept", "table_grad_rows_fused",
+    "remat_bytes_tagged", "remat_bytes_kept",
 )
 
 

@@ -180,7 +180,7 @@ def _abstract_scan_step(trainer, mesh, minibatch=8192, steps=8):
     )
     step = trainer._scanned(
         trainer._train_steps, build_train_step, stacked, host_keys=(),
-        variant_budget=1, **trainer._train_build_kwargs(),
+        variant_budget=1, keep_plan=trainer._new_keep_plan(), **trainer._train_build_kwargs(),
     )
     active = jax.ShapeDtypeStruct(
         (trainer.num_contributors(),), jnp.float32, sharding=replicated
@@ -354,15 +354,29 @@ def _flash_calls(text: str):
     ]
 
 
+#: A v5e chip's memory as the trainer reads it (``memory_stats()["bytes_limit"]``
+#: reads 15.748 GiB on the chip: my chip run, PR 38), or None: a backend that
+#: reports none (every CPU program), where rematerialised blocks keep nothing.
+V5E_BYTES_LIMIT = [int(15.75 * 2**30), None]
+
+
+@pytest.mark.parametrize("bytes_limit", V5E_BYTES_LIMIT, ids=["v5e_budget", "budget_0"])
 def test_gpt2_medium_step_compiles_for_v5e_with_no_layout_glue_at_flash(
-    v5e_device, olmoe_as_on_the_chip, path_lines
+    v5e_device, olmoe_as_on_the_chip, path_lines, monkeypatch, bytes_limit
 ):
     """``gpt2m_job``'s real step (24 layers, 16 sequences of 1024, remat)
     compiled for a described v5e: the flash kernels' operands ARE the
     model's ``[B, L, H*D]`` arrays, two 64-wide heads to a 128-lane block.
     Nothing pads a head to 128 lanes, and no transpose, copy or relayout of
     an array the size of q stands between the projections and a kernel (the
-    parent had some thirty such passes a layer: PERF.md, PR 31)."""
+    parent had some thirty such passes a layer: PERF.md, PR 31).  With the
+    budget the trainer resolves from a v5e's memory every layer keeps its
+    flash output and logsumexp (the forward ONCE a layer) and the step stays
+    under the trainer's line; with none (budget 0) it is the program it
+    was: the forward twice a layer."""
+    from elasticdl_tpu.parallel import trainer as trainer_lib
+
+    monkeypatch.setattr(trainer_lib, "device_bytes_limit", lambda devices: bytes_limit)
     layers = 24
     spec = load_model_spec(
         "elasticdl_tpu.models", "transformer_lm.model_spec", vocab=50257,
@@ -374,14 +388,23 @@ def test_gpt2_medium_step_compiles_for_v5e_with_no_layout_glue_at_flash(
         spec, JobConfig(distribution_strategy=DistributionStrategy.ALLREDUCE), mesh
     )
     step, args = _abstract_scan_step(trainer, mesh, minibatch=16, steps=2)
-    text = step.trace(*args).lower(lowering_platforms=("tpu",)).compile().as_text()
+    compiled = step.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
     (line,) = set(path_lines)
     assert "attention path: pallas-compiled" in line
     assert line.endswith("heads_per_block=2)")
-    # forward, its re-run under remat, dQ, dK + dV, every layer; each over
-    # the model's own [B, L, H * D]
+    # forward, its re-run under remat unless the layer keeps its output, dQ,
+    # dK + dV, every layer; each over the model's own [B, L, H * D]
     calls = _flash_calls(text)
-    assert len(calls) == 4 * layers
+    plan = trainer.keep_plan
+    if bytes_limit is None:
+        assert plan is None and len(calls) == 4 * layers
+    else:
+        assert len(calls) == 3 * layers
+        assert 0.5 * plan.tagged < plan.kept <= plan.budget < plan.tagged
+        assert 12.5 * 2**30 < trainer_lib.compiled_bytes(compiled) < plan.line == bytes_limit - trainer_lib.REMAT_HEADROOM
+        # the estimate of the step with nothing kept (the compiler's own account: 9.795 GiB)
+        assert abs(plan.estimate - 9.795 * 2**30) < 0.15 * 2**30
     assert all("bf16[16,1024,1024]" in c and "bf16[256," not in c for c in calls)
     # q is 16 x 1024 x 16 x 64 elements; padded to 128 lanes, twice that
     q_sized = {16 * 1024 * 16 * 64, 16 * 1024 * 16 * 128}
@@ -661,21 +684,30 @@ def test_eva_kernels_compile_for_v5e_at_sixteen_thousand(olmoe_as_on_the_chip, v
     assert "eva window=2048 summaries_per_window=128 pairs_a_head=16785408+7340032" in line
 
 
+@pytest.mark.parametrize("bytes_limit", V5E_BYTES_LIMIT, ids=["v5e_budget", "budget_0"])
 def test_evabyte_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
-    v5e_device, olmoe_as_on_the_chip, path_lines
+    v5e_device, olmoe_as_on_the_chip, path_lines, monkeypatch, bytes_limit
 ):
     """``evabyte_job``'s real step (EvaByte's widths, four layers of 16 held
     heads, ONE sequence of 16,384 bytes, one step a dispatch, per-block
-    rematerialisation) compiled for a described v5e: it fits the chip with
-    room (over a quarter of it, under the 15.0 GiB ISSUE 37 set as the
-    line); the five device scopes the ``.eva`` metrics read are there; the
-    attention is the three EVA kernels at the operand lists
-    ``eva_roofline_pct.eva`` reads, the forward twice a layer (the
-    rematerialised repeat); and no score tensor exists anywhere: nothing of
-    [*, 16384, 16384], [*, 16384, 1024], [*, 2048, 2048] or [*, 2048, 3072]
-    (what the XLA path would write, 3.2 GB a sequence and layer)."""
+    rematerialisation) compiled for a described v5e, with the byte budget
+    the trainer resolves from a v5e's memory: the blocks keep every save
+    site (gate, up, q, k, v, the EVA output with its logsumexp, the
+    summaries) and the step stays between 12.5 GiB and the trainer's line
+    of 14.25; the five device scopes the ``.eva`` metrics read are there;
+    the attention is the three EVA kernels at the operand lists
+    ``eva_roofline_pct.eva`` reads, the forward ONCE a layer; and no score
+    tensor exists anywhere: nothing of [*, 16384, 16384], [*, 16384, 1024],
+    [*, 2048, 2048] or [*, 2048, 3072] (what the XLA path would write,
+    3.2 GB a sequence and layer).  With no memory to read (budget 0, every
+    CPU program) it is the step it was: 10.8 GiB, the forward twice a layer
+    (the rematerialised repeat)."""
     import json
     import os
+
+    from elasticdl_tpu.parallel import trainer as trainer_lib
+
+    monkeypatch.setattr(trainer_lib, "device_bytes_limit", lambda devices: bytes_limit)
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs", "evabyte_6b5_tp2_l4.json")) as f:
@@ -692,12 +724,15 @@ def test_evabyte_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
         steps=traffic["minibatches_per_task"],
     )
     compiled = step.trace(*args).lower(lowering_platforms=("tpu",)).compile()
-    ma = compiled.memory_analysis()
-    total = (
-        ma.argument_size_in_bytes + ma.output_size_in_bytes
-        - ma.alias_size_in_bytes + ma.temp_size_in_bytes
-    )
-    assert 0.25 * 15.75 * 2**30 < total < 15.0 * 2**30, total / 2**30
+    total, plan = trainer_lib.compiled_bytes(compiled), trainer.keep_plan
+    if bytes_limit is None:
+        assert plan is None and 10.5 * 2**30 < total < 11.0 * 2**30, total / 2**30
+    else:
+        assert 12.5 * 2**30 < total < plan.line == bytes_limit - trainer_lib.REMAT_HEADROOM, total / 2**30
+        assert plan.kept == plan.tagged <= plan.budget
+        # the estimate of the step with nothing kept (the compiler's own account: 10.805 GiB)
+        assert abs(plan.estimate - 10.805 * 2**30) < 0.15 * 2**30
+    forwards = 2 if bytes_limit is None else 1
     text = compiled.as_text()
     for scope in ("eva_proj", "eva_pool", "eva_attn", "mlp", "lm_head"):
         assert re.search(rf'op_name="[^"]*\b{scope}\b', text), scope
@@ -708,8 +743,8 @@ def test_evabyte_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
         line for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line and re.search(r'op_name="[^"]*\beva_attn\b', line)
     ]
-    assert len(calls) == (2 + 2) * layers and params["remat"]
-    assert _signatures("\n".join(calls)) == sorted([(5, 0)] * 2 * layers + [(6, 1)] * layers + [(6, 2)] * layers)
+    assert len(calls) == (forwards + 2) * layers and params["remat"]
+    assert _signatures("\n".join(calls)) == sorted([(5, 0)] * forwards * layers + [(6, 1)] * layers + [(6, 2)] * layers)
     assert all("bf16[1,16384,2048]" in c and "bf16[1,1024,2048]" in c for c in calls), calls[:1]
     assert any("attention path: pallas-compiled" in line and "eva window=2048" in line for line in path_lines), path_lines
 
@@ -726,7 +761,14 @@ def test_evabyte_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
 #: Mosaic payload carries the checkout's path: tried, not stable); the step
 #: compiled with them is held by
 #: ``test_gpt2_medium_step_compiles_for_v5e_with_no_layout_glue_at_flash``.
-GPT2_MEDIUM_STEP_SHA256 = "7c496aa89d15ccf13c401fdca6ba2ae2589a8b7473065cf766d4cc87565a51b6"
+#: RE-PINNED in PR 38 (was 7c496aa8...5a51b6 since PR 31): ``transformer_lm``
+#: rematerialises through ``ops/remat.plan``, and the step of a model that
+#: does reports two more counts, ``remat_bytes_tagged`` (6.44 GB here: the
+#: XLA attention has no kernel output to tag) and ``remat_bytes_kept`` (0:
+#: off the TPU the budget is 0).  The diff of the two texts is those two
+#: constants with their sums over the mesh and the step's two more outputs;
+#: every block is the plain ``jax.checkpoint`` it was and carries no name.
+GPT2_MEDIUM_STEP_SHA256 = "c635201162024437d79e8ea579e276f9e51d2fe2b21fb4c4d2987008ca98cdc2"
 
 
 def test_gpt2_medium_lowered_step_is_the_pinned_program():
