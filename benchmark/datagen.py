@@ -19,8 +19,9 @@ master starts an epoch only when every task of the last one has been
 reported, which drains the worker's pipeline (0.17 s on the chip, PR 23);
 a real Criteo epoch is ~690 tasks, so the file is long enough that no
 boundary falls inside a run, instead of one every ``distinct_tasks`` tasks.
-One file, not many: the program scans a file's record index in Python at
-first touch, from every ingest thread at once (PERF.md, Findings).
+One file, not many: every file costs the program one scan of its record
+index at first touch (once a process since PR 36: 0.67 s for the 32-task
+Criteo file, linear in its bytes; PERF.md, Findings).
 """
 from __future__ import annotations
 

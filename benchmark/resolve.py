@@ -7,7 +7,7 @@ by adding files and BENCHMARK.json entries, and this module finds them.
     configs/<config>.json            sizes, flags, source (path from BENCHMARK.json "file")
     configs/<config>_reference.py    the plain float32 reference beside it
     traffic/<traffic>.json           the parameters the general generator reads
-    metrics/<metric>.json            unit, layer, moves, cells, reader, params
+    metrics/<metric>.json            unit, layer, moves, reader, params (its cells: BENCHMARK.json "workloads")
     readers/<reader>.py              read(ctx, params) -> number or None
     costs/<model>.py                 operations and bytes from shapes
 """

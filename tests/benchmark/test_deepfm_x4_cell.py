@@ -25,9 +25,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 TRACE = os.path.join(HERE, "data", "deepfm_two_steps.xplane.pb")
 CELL = "deepfm_x4_job"
 EX4 = [
-    "step_ms.ex4", "step_roofline_pct.ex4", "device_idle_pct.ex4", "host_loop_pct.ex4",
-    "prep_wait_pct.ex4", "starved_dispatch_pct.ex4", "compiles_in_window.ex4",
-    "hbm_peak_reported_gib.ex4", "task_gap_max_ms.ex4", "init_state_s.ex4",
+    "step_ms.ex", "step_roofline_pct.ex4", "device_idle_pct.ex", "host_loop_pct.ex",
+    "prep_wait_pct.ex", "starved_dispatch_pct.ex4", "compiles_in_window.ex4",
+    "hbm_peak_reported_gib.ex4", "task_gap_max_ms.ex", "setup_init_state_s",
     "collective_ms_step.ex4", "route_ms_step.ex4", "optimizer_ms_step.ex4",
     "route_recv_max_pct_mean.ex4",
 ]
@@ -59,10 +59,12 @@ def test_the_cell_its_configuration_and_its_traffic_resolve_by_name():
 def test_every_ex4_metric_resolves_to_a_file_and_a_reader(name):
     bench = resolve.Bench(ROOT)
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
     spec = bench.metric_file(name)
-    assert spec["cells"] == [CELL] and callable(bench.reader(spec["reader"]).read)
-    assert entry["moves"] == ("setup_s" if name == "init_state_s.ex4" else "examples_per_s_chip")
+    assert callable(bench.reader(spec["reader"]).read)
+    for key in ("unit", "layer", "moves", "better", "source"):
+        assert spec[key] == entry[key], key
+    assert entry["moves"] == ("setup_s" if name == "setup_init_state_s" else "examples_per_s_chip")
     assert name in [m["name"] for m in bench.metrics_of(CELL, "per_layer")]
 
 
@@ -183,8 +185,9 @@ def test_rehearsal_runs_the_cells_control_flow_on_four_cpu_devices(tmp_path):
     assert info["reference"]["devices"] == 4 and info["reference"]["rows_touched"] <= 2 * 64 * 26
     assert info["reference"]["relative_difference"] < 2e-3
     metrics = result["metrics"]
-    assert metrics["init_state_s.ex4"]["value"] > 0
+    assert metrics["setup_init_state_s"]["value"] > 0
     assert 100.0 <= metrics["route_recv_max_pct_mean.ex4"]["value"] <= 400.0
-    for name in ("host_loop_pct.ex4", "prep_wait_pct.ex4", "starved_dispatch_pct.ex4", "compiles_in_window.ex4", "task_gap_max_ms.ex4"):
+    for name in ("host_loop_pct.ex", "prep_wait_pct.ex", "starved_dispatch_pct.ex4", "compiles_in_window.ex4", "task_gap_max_ms.ex",
+                 "lease_ms_task.ex"):  # the last joined the cell in PR 39
         assert name in metrics, name
     assert "examples_per_s_chip" not in metrics  # a traced run reports per-layer metrics only

@@ -25,10 +25,10 @@ import resolve  # noqa: E402
 
 CELL, CONFIG, TRAFFIC = "evabyte_job", "evabyte_6b5_tp2_l4", "job_seq16k"
 EVA = [
-    "step_ms.eva", "mfu_pct.eva", "device_idle_pct.eva", "host_loop_pct.eva", "prep_wait_pct.eva",
-    "starved_dispatch_pct.eva", "compiles_in_window.eva", "hbm_peak_reported_gib.eva", "task_gap_max_ms.eva",
-    "lease_ms_task.eva", "eva_attn_ms_step.eva", "eva_roofline_pct.eva", "eva_pool_ms_step.eva",
-    "eva_pool_hbm_pct.eva", "eva_proj_ms_step.eva", "mlp_ms_step.eva", "lm_head_ms_step.eva",
+    "step_ms.tok", "mfu_pct.tok", "device_idle_pct.tok", "host_loop_pct.tok", "prep_wait_pct.tok",
+    "starved_dispatch_pct.tok", "compiles_in_window.tok", "hbm_peak_reported_gib.tok", "task_gap_max_ms.tok",
+    "lease_ms_task.tok", "eva_attn_ms_step.eva", "eva_roofline_pct.eva", "eva_pool_ms_step.eva",
+    "eva_pool_hbm_pct.eva", "eva_proj_ms_step.eva", "mlp_ms_step.eva", "lm_head_ms_step.tok",
 ]
 CHECKS = sorted([
     "eva_output", "eva_lse", "head_logits", "logits", "adamw_update",
@@ -163,9 +163,9 @@ def test_the_share_is_the_arithmetic_the_file_states():
 def test_every_eva_metric_resolves_to_a_file_and_a_reader(name):
     bench = resolve.Bench(ROOT)
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == [CELL] and entry["moves"] == "tokens_per_s_chip"
+    assert CELL in entry["workloads"] and entry["moves"] == "tokens_per_s_chip"
     spec = bench.metric_file(name)
-    assert spec["cells"] == [CELL] and callable(bench.reader(spec["reader"]).read)
+    assert callable(bench.reader(spec["reader"]).read)
     for key in ("unit", "layer", "moves", "better", "source"):
         assert spec[key] == entry[key], key
     assert name in [m["name"] for m in bench.metrics_of(CELL, "per_layer")]
@@ -246,8 +246,9 @@ def test_rehearsal_trains_the_model_through_the_normal_path(tmp_path):
         next(os.path.join(base, f) for base, _, files in os.walk(scratch / "benchmark" / ".state" / "runs" / CELL / "pods")
              for f in files if f.endswith(".log"))).read()
     metrics = result["metrics"]
-    for name in ("host_loop_pct.eva", "prep_wait_pct.eva", "starved_dispatch_pct.eva", "compiles_in_window.eva",
-                 "task_gap_max_ms.eva", "lease_ms_task.eva", "mfu_pct.eva", "hbm_peak_reported_gib.eva"):
+    for name in ("host_loop_pct.tok", "prep_wait_pct.tok", "starved_dispatch_pct.tok", "compiles_in_window.tok",
+                 "task_gap_max_ms.tok", "lease_ms_task.tok", "mfu_pct.tok", "hbm_peak_reported_gib.tok",
+                 "setup_master_s", "setup_init_state_s", "setup_compile_s", "setup_unattributed_s"):  # the setup_* joined in PR 39
         assert name in metrics, name
     assert "tokens_per_s_chip" not in metrics  # a traced run reports per-layer metrics only
 

@@ -25,10 +25,10 @@ import resolve  # noqa: E402
 
 CELL, CONFIG, TRAFFIC = "kanana2_job", "kanana2_30b_a3b_ep8_l5", "job_seq8k"
 MLA = [
-    "step_ms.mla", "mfu_pct.mla", "device_idle_pct.mla", "host_loop_pct.mla", "prep_wait_pct.mla",
-    "starved_dispatch_pct.mla", "compiles_in_window.mla", "hbm_peak_reported_gib.mla", "task_gap_max_ms.mla",
-    "lease_ms_task.mla", "flash_attn_ms_step.mla", "flash_roofline_pct.mla", "mla_proj_ms_step.mla",
-    "moe_shared_ms_step.mla", "moe_experts_ms_step.mla", "moe_glue_ms_step.mla", "lm_head_ms_step.mla",
+    "step_ms.tok", "mfu_pct.tok", "device_idle_pct.tok", "host_loop_pct.tok", "prep_wait_pct.tok",
+    "starved_dispatch_pct.tok", "compiles_in_window.tok", "hbm_peak_reported_gib.tok", "task_gap_max_ms.tok",
+    "lease_ms_task.tok", "flash_attn_ms_step.tok", "flash_roofline_pct.mla", "mla_proj_ms_step.mla",
+    "moe_shared_ms_step.mla", "moe_experts_ms_step.tok", "moe_glue_ms_step.tok", "lm_head_ms_step.tok",
     "optimizer_ms_step.mla", "expert_mxu_pct.mla", "moe_slots_computed_pct.mla", "moe_slots_held_pct.mla",
 ]
 #: The catalog row's ``config`` (model-configs guide, ``architectures.jsonl``,
@@ -146,9 +146,9 @@ def test_the_share_is_the_arithmetic_the_file_states():
 def test_every_mla_metric_resolves_to_a_file_and_a_reader(name):
     bench = resolve.Bench(ROOT)
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == [CELL] and entry["moves"] == "tokens_per_s_chip"
+    assert CELL in entry["workloads"] and entry["moves"] == "tokens_per_s_chip"
     spec = bench.metric_file(name)
-    assert spec["cells"] == [CELL] and callable(bench.reader(spec["reader"]).read)
+    assert callable(bench.reader(spec["reader"]).read)
     for key in ("unit", "layer", "moves", "better", "source"):
         assert spec[key] == entry[key], key
     assert name in [m["name"] for m in bench.metrics_of(CELL, "per_layer")]
@@ -159,7 +159,7 @@ def test_the_new_flash_patterns_read_the_new_operand_lists_and_only_those():
 
     bench = resolve.Bench(ROOT)
     new = [k["pattern"] for k in bench.metric_file("flash_roofline_pct.mla")["params"]["kernels"]]
-    old = [k["pattern"] for k in bench.metric_file("flash_roofline_pct.moe")["params"]["kernels"]]
+    old = [k["pattern"] for k in bench.metric_file("flash_roofline_pct.tok")["params"]["kernels"]]
     operand = lambda dtype, i: f"{dtype}[2,8192,4096]{{2,1,0:T(8,128)(2,1)}} %fusion.{i}"  # noqa: E731
     event = lambda n_bf16, n_f32: (  # noqa: E731
         "%custom-call.7 = bf16[2,8192,4096]{2,1,0} custom-call("
@@ -268,7 +268,7 @@ def test_rehearsal_trains_the_model_through_the_normal_path(tmp_path):
     metrics = result["metrics"]
     assert metrics["moe_slots_computed_pct.mla"]["value"] == 100.0
     assert 15.0 < metrics["moe_slots_held_pct.mla"]["value"] < 35.0  # 4 of 16 experts held: 25 when balanced
-    for name in ("host_loop_pct.mla", "prep_wait_pct.mla", "starved_dispatch_pct.mla", "compiles_in_window.mla",
-                 "task_gap_max_ms.mla", "lease_ms_task.mla", "mfu_pct.mla"):
+    for name in ("host_loop_pct.tok", "prep_wait_pct.tok", "starved_dispatch_pct.tok", "compiles_in_window.tok",
+                 "task_gap_max_ms.tok", "lease_ms_task.tok", "mfu_pct.tok"):
         assert name in metrics, name
     assert "tokens_per_s_chip" not in metrics  # a traced run reports per-layer metrics only

@@ -22,10 +22,10 @@ import resolve  # noqa: E402
 
 CELL = "olmoe_job"
 MOE = [
-    "step_ms.moe", "mfu_pct.moe", "flash_roofline_pct.moe", "device_idle_pct.moe", "host_loop_pct.moe",
-    "prep_wait_pct.moe", "task_gap_max_ms.moe", "lease_ms_task.moe", "starved_dispatch_pct.moe",
-    "compiles_in_window.moe", "hbm_peak_reported_gib.moe", "moe_experts_ms_step.moe", "moe_glue_ms_step.moe",
-    "lm_head_ms_step.moe", "optimizer_ms_step.moe", "expert_load_max_pct_mean.moe", "moe_slots_computed_pct.moe",
+    "step_ms.tok", "mfu_pct.tok", "flash_roofline_pct.tok", "device_idle_pct.tok", "host_loop_pct.tok",
+    "prep_wait_pct.tok", "task_gap_max_ms.tok", "lease_ms_task.tok", "starved_dispatch_pct.tok",
+    "compiles_in_window.tok", "hbm_peak_reported_gib.tok", "moe_experts_ms_step.tok", "moe_glue_ms_step.tok",
+    "lm_head_ms_step.tok", "optimizer_ms_step.moe", "expert_load_max_pct_mean.moe", "moe_slots_computed_pct.moe",
     "expert_mxu_pct.moe",
 ]
 #: The catalog row's ``config`` (model-configs guide, ``architectures.jsonl``,
@@ -110,9 +110,9 @@ def test_the_catalog_is_compared_only_where_the_file_and_the_row_exist(tmp_path,
 def test_every_moe_metric_resolves_to_a_file_and_a_reader(name):
     bench = resolve.Bench(ROOT)
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == [CELL] and entry["moves"] == "tokens_per_s_chip"
+    assert CELL in entry["workloads"] and entry["moves"] == "tokens_per_s_chip"
     spec = bench.metric_file(name)
-    assert spec["cells"] == [CELL] and callable(bench.reader(spec["reader"]).read)
+    assert callable(bench.reader(spec["reader"]).read)
     for key in ("unit", "layer", "moves", "better", "source"):
         assert spec[key] == entry[key], key
     assert name in [m["name"] for m in bench.metrics_of(CELL, "per_layer")]
@@ -123,8 +123,9 @@ def test_the_moe_metrics_are_all_there():
     PR may give the cell more (the list is a floor, not a fence)."""
     bench = resolve.Bench(ROOT)
     assert set(MOE) <= {m["name"] for m in bench.metrics_of(CELL, "per_layer")}
-    # the flash kernels keep the operand signatures gpt2m_job's metric reads
-    assert bench.metric_file("flash_roofline_pct.moe")["params"] == bench.metric_file("flash_roofline_pct.tok")["params"]
+    # the flash kernels keep the operand signatures gpt2m_job's metric reads: one entry, one file, both cells (PR 39)
+    (flash,) = [m for m in bench.spec["per_layer"] if m["name"] == "flash_roofline_pct.tok"]
+    assert {"gpt2m_job", CELL} <= set(flash["workloads"])
 
 
 def test_olmoe_flops_counts_what_its_docstring_says():
@@ -205,7 +206,7 @@ def test_rehearsal_trains_the_model_through_the_normal_path(tmp_path):
     metrics = result["metrics"]
     assert metrics["moe_slots_computed_pct.moe"]["value"] == 100.0
     assert 100.0 <= metrics["expert_load_max_pct_mean.moe"]["value"] <= 250.0
-    for name in ("host_loop_pct.moe", "prep_wait_pct.moe", "starved_dispatch_pct.moe", "compiles_in_window.moe",
-                 "task_gap_max_ms.moe", "lease_ms_task.moe", "mfu_pct.moe"):
+    for name in ("host_loop_pct.tok", "prep_wait_pct.tok", "starved_dispatch_pct.tok", "compiles_in_window.tok",
+                 "task_gap_max_ms.tok", "lease_ms_task.tok", "mfu_pct.tok"):
         assert name in metrics, name
     assert "tokens_per_s_chip" not in metrics  # a traced run reports per-layer metrics only

@@ -26,7 +26,7 @@ import runfiles  # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 TRACE = os.path.join(HERE, "data", "deepfm_toy_job_host_spans.xplane.pb")
 
-CELLS = ["deepfm_job", "gpt2m_job", "deepfm_x4_job", "deepfm_job_zipf", "olmoe_job", "kanana2_job"]
+CELLS = ["deepfm_job", "gpt2m_job", "deepfm_x4_job", "deepfm_job_zipf", "olmoe_job", "kanana2_job", "evabyte_job"]
 #: metric -> (the reader's quantity, unit, better, layer)
 SETUP = {
     "setup_master_s": ("master_s", "s", "lower", "master"),
@@ -59,18 +59,20 @@ def test_new_metric_files_say_what_benchmark_json_says(name):
     spec = bench.metric_file(name)
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert spec[key] == entry[key], key
-    assert spec["cells"] == entry["workloads"] and entry["source"] == "program_span"
+    assert "cells" not in spec and entry["source"] == "program_span"
     assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
     if name == "prep_ms_task.ex":
         assert (spec["reader"], spec["params"]) == ("host_span_ms_task", {"span": "prep", "lines": "edl-prep_"})
         assert (entry["moves"], entry["layer"], entry["unit"], entry["better"]) == ("examples_per_s_chip", "ingest", "ms", "lower")
-        assert entry["workloads"] == ["deepfm_job", "deepfm_job_zipf"]
+        assert entry["workloads"][:2] == ["deepfm_job", "deepfm_job_zipf"]  # a later cell appends itself
         return
     what, unit, better, layer = SETUP[name]
     assert (spec["reader"], spec["params"]) == ("setup_spans", {"what": what})
     assert (entry["moves"], entry["unit"], entry["better"], entry["layer"]) == ("setup_s", unit, better, layer)
-    # init_state_s.ex4 stays that cell's reading of the initialisation
-    assert entry["workloads"] == [c for c in CELLS if not (name == "setup_init_state_s" and c == "deepfm_x4_job")]
+    # every cell the benchmark had at PR 39, in the order they joined (deepfm_x4_job joined setup_init_state_s
+    # in PR 39, when init_state_s.ex4 was retired); a later cell appends itself
+    joined = [*(c for c in CELLS[:6] if c != "deepfm_x4_job"), "deepfm_x4_job", "evabyte_job"] if name == "setup_init_state_s" else CELLS
+    assert entry["workloads"][: len(CELLS)] == joined
 
 
 def test_every_cell_reads_the_set_up_and_the_layers_are_the_benchmarks_own():
@@ -80,8 +82,7 @@ def test_every_cell_reads_the_set_up_and_the_layers_are_the_benchmarks_own():
     layers = {e["layer"] for e in bench.spec["per_layer"] if not e["name"].startswith(("setup_", "prep_ms_task"))}
     for cell in CELLS:
         names = {m["name"] for m in bench.metrics_of(cell, "per_layer")}
-        want = set(SETUP) - ({"setup_init_state_s"} if cell == "deepfm_x4_job" else set())
-        assert want <= names and ("init_state_s.ex4" in names) == (cell == "deepfm_x4_job"), cell
+        assert set(SETUP) <= names and "init_state_s.ex4" not in names, cell
     assert {SETUP[n][3] for n in SETUP} <= layers
 
 
