@@ -295,11 +295,19 @@ class JobConfig:
     # is purely additive.
     gauge_port: int = -1
     # worker: a jax.profiler trace (device planes AND the host's spans of
-    # common/trace.py, on one clock) of worker.PROFILE_TASKS consecutive
+    # common/trace.py, on one clock) of --profile_tasks consecutive
     # training tasks, from the second dispatch on (the first compiles).
     # The traced tasks are prepped, dispatched, settled and reported like
     # any other; the file is written off the task loop.
     profile_dir: str = ""
+    profile_tasks: int = 3
+    # Collect and write the trace ON the task loop when the window closes:
+    # the loop stands still meanwhile, and the files are whole before the
+    # next task reports.  For a job that may be killed sooner after the
+    # window than the writer's thread needs (20 s for a step of 12,000
+    # device ops: PERF.md section 6, PR 40); off, the loop goes on and the
+    # files appear when the thread is done.
+    profile_inline: bool = False
     metrics_dir: str = ""  # master: JSONL + TensorBoard scalar stream
     # Process backend: capture each worker pod's stdout+stderr to
     # {pod_log_dir}/{pod-name}.log (the local analog of kubectl logs; pod

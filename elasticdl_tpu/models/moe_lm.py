@@ -75,6 +75,28 @@ chip's share under head parallelism: wq / wk / wv ``[d, held * hd]``, wo
                                                predicts token i + 1 + p
     loss = mean over the heads of each head's mean CE over the positions whose target the record holds
 
+With ``hybrid_override_pattern`` (``nemotron_h``'s keys) a layer is ONE norm,
+ONE mixer or feed-forward part and one residual, its kind a letter of the
+pattern (``M`` / ``*`` / ``E``; the kind is read off the layer's parameters),
+with no position table and no rotary turn:
+
+    u  = rmsnorm(x, norm) ;  x += part(u)
+    M (Mamba-2, ``ops/ssm``; H heads of P in G groups, state N, HELD: ``mamba_heads_held`` heads and their groups):
+        (z, xBC, dt) = u W_in                  columns (H P | H P + 2 G N | H) of the held heads and groups
+        (x, B, C) = silu(conv(xBC))            causal, depthwise, ``conv_kernel`` taps and a bias a channel
+        dt = softplus(dt + dt_bias) ; a_t = exp(-exp(A_log) dt_t)
+        S_t = a_t S_{t-1} + dt_t x_t B_t^T ; y_t = S_t C_t + D x_t      S_0 = 0 at each sequence's start
+        part = rmsnorm over each GROUP's channels of (y * silu(z)), times a gain, then W_out
+    * : q = u Wq (``heads_held`` heads of ``head_dim``), k, v = u Wk, u Wv (``kv_heads_held`` heads);
+        query head h reads key/value head h // (heads / kv heads); causal softmax of q k / sqrt(head_dim); part = o Wo
+    E (LatentMoE): r = u Wg (float32), s = sigmoid(r), top-k of s + b, w_i = scaling x s[e_i] / sum_j s[e_j]
+        c = u W_down_lat                       [``moe_latent_size``]
+        part = (sum_i w_i relu(c W1[e_i])^2 W2[e_i]) W_up_lat + relu(u Ws1)^2 Ws2     (``mlp_hidden_act`` relu2:
+                                               experts and the shared expert are TWO matrices each)
+
+What the heads and experts that are not held would add is left out; the
+all-reduce of the head shares and the experts' exchange are not here.
+
 Parallelism: as ``transformer_lm``'s sequence path.  ``batch_shard_dim=1``:
 the mesh axis shards the SEQUENCE, attention runs over the ring
 (``ops/ring_attention``), rotary positions are global (the device's axis
@@ -105,6 +127,7 @@ from elasticdl_tpu.models.spec import ModelSpec
 from elasticdl_tpu.ops import eva_attention as eva_ops
 from elasticdl_tpu.ops import moe
 from elasticdl_tpu.ops import remat as remat_lib
+from elasticdl_tpu.ops import ssm as ssm_ops
 from elasticdl_tpu.ops.embedding import ParallelContext
 from elasticdl_tpu.ops.ring_attention import ring_attention
 
@@ -133,7 +156,17 @@ EVA_COUNTERS = {
     "training steps and devices",
     "eva_pairs_summary": "(query, chunk summary) pairs of earlier windows, summed likewise",
 }
+#: The state-space layers' count a step reports, likewise (gauge
+#: ``edl_ssm_positions_total``): what the traffic asks of the scan, from
+#: shapes (the operator's measure of scanned work; no per-layer metric reads
+#: a constant of the shapes).
+SSM_COUNTERS = {
+    "ssm_positions": "(head, position) pairs the state-space scans advanced a state over, from the "
+    "shapes they were called with, summed over layers, training steps and devices",
+}
 LAYER_TYPES = ("moe", "dense")
+#: ``hybrid_override_pattern``'s letters (``-``, a dense MLP layer, is not one: no cell runs it)
+PATTERN_KINDS = {"M": "a Mamba-2 mixer", "*": "attention", "E": "a latent mixture of experts"}
 ATTENTION_CLASSES = ("mha", "eva")
 TOPK_METHODS = ("greedy", "noaux_tc")
 
@@ -256,6 +289,66 @@ def _init_params(
     return params
 
 
+def _init_hybrid_params(
+    rng, *, pattern: str, vocab_size: int, hidden_size: int, init_std: float, residual_layers: int,
+    mamba_heads: int, mamba_head_dim: int, groups: int, state: int, conv_kernel: int, dt_range,
+    q_heads: int, kv_heads: int, head_dim: int,
+    num_experts: int, experts_held: int, latent: int, expert_width: int, shared_width: int,
+) -> Dict[str, Any]:
+    """``nemotron_h``'s parameters, a layer's by its letter of ``pattern``;
+    every count is what is HELD here.  Matrices normal(0, ``init_std``),
+    those that write into the residual stream (``ssm_out``, ``wo``,
+    ``w_down``, ``ws_down``) scaled by ``residual_layers``^-1/2
+    (``rescale_prenorm_residual``); ``A_log`` = log uniform(1, 16), ``dt_bias``
+    the inverse softplus of a log-uniform draw in ``dt_range`` (min, max,
+    floor), ``D`` = 1; the taps and their bias uniform(+-``conv_kernel``^-1/2)."""
+    d = hidden_size
+    ks = iter(jax.random.split(rng, 2 + 8 * len(pattern)))
+    into_stream = residual_layers ** -0.5
+
+    def normal(shape, scale=1.0):
+        return jax.random.normal(next(ks), shape, jnp.float32) * (init_std * scale)
+
+    def uniform(shape, lo, hi):
+        return jax.random.uniform(next(ks), shape, jnp.float32, lo, hi)
+
+    params: Dict[str, Any] = {
+        "tok_emb": normal((vocab_size, d)), "norm_f": jnp.ones((d,), jnp.float32),
+        "head": normal((d, vocab_size)), "blocks": {},
+    }
+    for i, kind in enumerate(pattern):
+        blk: Dict[str, Any] = {"norm": jnp.ones((d,), jnp.float32)}
+        if kind == "M":
+            inner, conv_dim = mamba_heads * mamba_head_dim, mamba_heads * mamba_head_dim + 2 * groups * state
+            lo, hi, floor = dt_range
+            dt = jnp.maximum(jnp.exp(uniform((mamba_heads,), jnp.log(lo), jnp.log(hi))), floor)
+            blk.update({
+                "ssm_in": normal((d, inner + conv_dim + mamba_heads)),
+                "conv_w": uniform((conv_kernel, conv_dim), -conv_kernel ** -0.5, conv_kernel ** -0.5),
+                "conv_b": uniform((conv_dim,), -conv_kernel ** -0.5, conv_kernel ** -0.5),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+                "A_log": jnp.log(uniform((mamba_heads,), 1.0, 16.0)),
+                "D": jnp.ones((mamba_heads,), jnp.float32),
+                "ssm_norm": jnp.ones((inner,), jnp.float32),
+                "ssm_out": normal((inner, d), into_stream),
+            })
+        elif kind == "*":
+            blk.update({
+                "wq": normal((d, q_heads * head_dim)), "wk": normal((d, kv_heads * head_dim)),
+                "wv": normal((d, kv_heads * head_dim)), "wo": normal((q_heads * head_dim, d), into_stream),
+            })
+        else:
+            blk.update({
+                "router": normal((d, num_experts)), "router_bias": jnp.zeros((num_experts,), jnp.float32),
+                "w_lat_down": normal((d, latent)), "w_lat_up": normal((latent, d), into_stream),
+                "w_up": normal((experts_held, latent, expert_width)),
+                "w_down": normal((experts_held, expert_width, latent), into_stream),
+                "ws_up": normal((d, shared_width)), "ws_down": normal((shared_width, d), into_stream),
+            })
+        params["blocks"][f"b{i:02d}"] = blk
+    return params
+
+
 def _attention(a, blk, positions, *, axis, n_heads, theta, eps, cast):
     """OLMoE's: three projections, whole-width QK-norm, rotate-half rope
     over the whole head."""
@@ -321,9 +414,116 @@ def _gated_mlp(u, w_gate, w_up, w_down):
         return (jax.nn.silu(remat_lib.product("mlp_gate", u, w_gate)) * remat_lib.product("mlp_up", u, w_up)) @ w_down
 
 
+def _relu2_mlp(u, w_up, w_down, site: str):
+    with jax.named_scope("mlp"):
+        # the first product is a save site (ops/remat.py); relu and the square never
+        return jnp.square(jax.nn.relu(remat_lib.product(site, u, w_up))) @ w_down
+
+
+def _mamba_mixer(u, blk, *, axis, eps, cast, state: int, chunk: int):
+    """Mamba-2's mixer over the HELD heads and groups (read off ``A_log``
+    and the convolution's channels)."""
+    if axis is not None and axis_size(axis) > 1:
+        raise ValueError("a state-space layer over a sharded sequence is not supported: the state at a shard's start lives on the shard before it")
+    b, l, _ = u.shape
+    heads, inner, conv_dim = blk["A_log"].shape[0], blk["ssm_norm"].shape[0], blk["conv_w"].shape[1]
+    groups = (conv_dim - inner) // (2 * state)
+    with jax.named_scope("ssm_proj"):
+        # Each part is multiplied by its own column block of the published
+        # matrix (a slice of the WEIGHT, as latent attention's); z and xBC
+        # are save sites (ops/remat.py).
+        w_in = blk["ssm_in"]
+        z = remat_lib.product("ssm_z", u, cast(w_in[:, :inner]))
+        xbc = remat_lib.product("ssm_xbc", u, cast(w_in[:, inner:inner + conv_dim]))
+        dt = (u @ cast(w_in[:, inner + conv_dim:])).astype(jnp.float32)
+    xbc = ssm_ops.causal_conv(xbc, blk["conv_w"], blk["conv_b"])
+    with jax.named_scope("ssm_conv"):
+        xbc = jax.nn.silu(xbc)
+        x = xbc[..., :inner].reshape(b, l, heads, inner // heads)
+        bm = xbc[..., inner:inner + groups * state].reshape(b, l, groups, state)
+        cm = xbc[..., inner + groups * state:].reshape(b, l, groups, state)
+        dt = jax.nn.softplus(dt + blk["dt_bias"])
+    y = ssm_ops.ssm_scan(x, dt, -jnp.exp(blk["A_log"]), bm, cm, blk["D"], chunk=chunk)
+    y = ssm_ops.gated_group_norm(y.reshape(b, l, inner), z, blk["ssm_norm"], groups, eps)
+    with jax.named_scope("ssm_proj"):
+        return y @ cast(blk["ssm_out"])
+
+
+def _grouped_query_attention(u, blk, *, axis, cast, head_dim: int):
+    """Attention whose key/value heads are fewer than its query heads
+    (query head h reads key/value head ``h // group``), over the HELD heads
+    (read off the projections); no position signal.  The key/value heads are
+    REPEATED to the queries' ahead of the attention (PERF.md section 7:
+    the flash kernels' contract wants as many; one layer in eleven)."""
+    b, l, _ = u.shape
+    with jax.named_scope("attn_proj"):
+        heads = lambda t: t.reshape(b, l, -1, head_dim)  # noqa: E731
+        q, k, v = (heads(remat_lib.product(name, u, cast(blk["w" + name]))) for name in ("q", "k", "v"))
+        group = q.shape[2] // k.shape[2]
+        if group > 1:
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    att = ring_attention(q, k, v, axis_name=axis, causal=True)
+    with jax.named_scope("attn_proj"):
+        return att.reshape(b, l, -1) @ cast(blk["wo"])
+
+
+def _routed_stats(routing, slots, given, pairs: int, top_k: int, first_expert_held: int, held: int):
+    """An expert layer's router sums and slot counts (``_apply`` adds them up)."""
+    f, p, z = moe.router_stats(routing)
+    slots = slots.astype(jnp.float32)
+    sizes = slots[first_expert_held:first_expert_held + held]
+    here = (routing.choices >= first_expert_held) & (routing.choices < first_expert_held + held)
+    return {
+        "f": f, "p": p, "z": z, "pairs": jnp.float32(pairs),
+        "slots": slots,
+        "moe_slots": jnp.float32(pairs * top_k),
+        "moe_slots_held": jnp.sum(here.astype(jnp.float32)),
+        "moe_slots_computed": (given.first + given.second).astype(jnp.float32),
+        "moe_slots_overflow": given.second.astype(jnp.float32),
+        "moe_expert_load_max": jnp.max(sizes),
+        "moe_expert_load_mean": jnp.mean(sizes),
+    }
+
+
+def _latent_moe(u, blk, *, top_k, router, first_expert_held, cast):
+    """LatentMoE: the router reads the token, the routed experts (two
+    matrices under relu squared) work in a latent between two projections,
+    their weighted sum is taken IN the latent (linear: the same result as
+    after ``w_lat_up``, a quarter of the rows' width); the shared expert
+    reads the token.  Returns (the part, the layer's stats)."""
+    b, l, dim = u.shape
+    tokens = u.reshape(b * l, dim)
+    n_experts, held = blk["router"].shape[1], blk["w_up"].shape[0]
+    routing = moe.route(tokens, blk["router"], top_k, bias=blk["router_bias"], **(router or {}))
+    with jax.named_scope("moe_latent"):
+        c = remat_lib.product("moe_latent_down", tokens, cast(blk["w_lat_down"]))
+    y, slots, given = moe.expert_ffn(
+        c, routing.choices, routing.weights, None, cast(blk["w_up"]), cast(blk["w_down"]),
+        n_experts=n_experts, lo=first_expert_held,
+    )
+    with jax.named_scope("moe_latent"):
+        y = y @ cast(blk["w_lat_up"])
+    with jax.named_scope("moe_shared"):
+        y = y + _relu2_mlp(tokens, cast(blk["ws_up"]), cast(blk["ws_down"]), "shared_up")
+    return y.reshape(b, l, dim), _routed_stats(routing, slots, given, b * l, top_k, first_expert_held, held)
+
+
+def _hybrid_layer(x, blk, *, axis, top_k, eps, compute_dtype, router, first_expert_held, hybrid):
+    """One ``nemotron_h`` layer: one norm, ONE mixer or feed-forward part
+    (read off its parameters), one residual."""
+    cast = lambda w: w.astype(compute_dtype)  # noqa: E731
+    u = _rms_norm(cast(x), blk["norm"], eps)
+    if "ssm_in" in blk:
+        return x + _mamba_mixer(u, blk, axis=axis, eps=eps, cast=cast, state=hybrid["state"], chunk=hybrid["chunk"]), None
+    if "router" in blk:
+        y, stats = _latent_moe(u, blk, top_k=top_k, router=router, first_expert_held=first_expert_held, cast=cast)
+        return x + y, stats
+    return x + _grouped_query_attention(u, blk, axis=axis, cast=cast, head_dim=hybrid["head_dim"]), None
+
+
 def _block(
     x, blk, positions, *, axis, n_heads, top_k, theta, eps, compute_dtype,
-    rot=0, interleave=False, router=None, first_expert_held=0, eva=None, unit_offset=False,
+    rot=0, interleave=False, router=None, first_expert_held=0, eva=None, unit_offset=False, hybrid=None,
 ):
     """One block: an attention and a feed-forward whose kinds are read off
     its parameters (``wkv_a`` makes the attention latent, ``eva_phi`` makes
@@ -332,7 +532,14 @@ def _block(
     ``router`` are ``ops/moe.route``'s published keys.  ``x`` may be wider
     than ``compute_dtype`` (a float32 residual stream): the block reads it
     cast and adds in ``x``'s own type.  Returns (x, the
-    layer's router sums and slot counts — None for a dense layer)."""
+    layer's router sums and slot counts — None for a dense layer).  A layer
+    with ONE norm (``norm``: ``nemotron_h``) is one part alone
+    (:func:`_hybrid_layer`)."""
+    if "norm" in blk:
+        return _hybrid_layer(
+            x, blk, axis=axis, top_k=top_k, eps=eps, compute_dtype=compute_dtype, router=router,
+            first_expert_held=first_expert_held, hybrid=hybrid,
+        )
     b, l, dim = x.shape
     cast = lambda w: w.astype(compute_dtype)  # noqa: E731
     a = _rms_norm(cast(x), _gain(blk["attn_norm"], unit_offset), eps)
@@ -364,20 +571,7 @@ def _block(
     if "ws_gate" in blk:
         with jax.named_scope("moe_shared"):
             y = y + _gated_mlp(tokens, cast(blk["ws_gate"]), cast(blk["ws_up"]), cast(blk["ws_down"]))
-    f, p, z = moe.router_stats(routing)
-    slots = slots.astype(jnp.float32)
-    sizes = slots[first_expert_held:first_expert_held + held]
-    here = (routing.choices >= first_expert_held) & (routing.choices < first_expert_held + held)
-    stats = {
-        "f": f, "p": p, "z": z, "pairs": jnp.float32(b * l),
-        "slots": slots,
-        "moe_slots": jnp.float32(b * l * top_k),
-        "moe_slots_held": jnp.sum(here.astype(jnp.float32)),
-        "moe_slots_computed": (given.first + given.second).astype(jnp.float32),
-        "moe_slots_overflow": given.second.astype(jnp.float32),
-        "moe_expert_load_max": jnp.max(sizes),
-        "moe_expert_load_mean": jnp.mean(sizes),
-    }
+    stats = _routed_stats(routing, slots, given, b * l, top_k, first_expert_held, held)
     return x + y.reshape(b, l, dim), stats
 
 
@@ -421,6 +615,9 @@ def _apply(
             "eva_pairs_exact": jnp.float32(scored * exact),
             "eva_pairs_summary": jnp.float32(scored * far),
         }
+    scanned = sum(blk["A_log"].shape[0] for blk in params["blocks"].values() if "A_log" in blk)  # heads, all layers
+    if scanned:
+        out["ssm_counters"] = {"ssm_positions": jnp.float32(tokens.shape[0] * l * scanned)}
     if routed:
         slots = jnp.stack([stats.pop("slots") for stats in routed])  # [expert layers, E]
         total = jax.tree.map(lambda *leaves: sum(leaves), *routed)
@@ -510,6 +707,7 @@ def _metrics(out, batch, lb_coef: float, z_coef: float):
     metrics = {"loss": loss, "ce": ce, "lb_loss": lb, "z_loss": z, "accuracy": acc}
     metrics.update(out.get("moe_counters", {}))
     metrics.update(out.get("eva_counters", {}))
+    metrics.update(out.get("ssm_counters", {}))
     return metrics
 
 
@@ -527,7 +725,10 @@ def _example_batch(batch_size: int, seq_len: int):
 #: Leaves AdamW never decays: the correction biases; and, for a model that
 #: decays its matrices alone (``decay_matrices_only``), the gains and EVA's vectors.
 _NEVER_DECAYED = ("router_bias",)
-_NOT_MATRICES = _NEVER_DECAYED + ("attn_norm", "ffn_norm", "norm_f", "kv_norm", "q_norm", "k_norm", "eva_phi", "eva_mu")
+_NOT_MATRICES = _NEVER_DECAYED + (
+    "attn_norm", "ffn_norm", "norm_f", "kv_norm", "q_norm", "k_norm", "eva_phi", "eva_mu",
+    "norm", "ssm_norm", "A_log", "D", "dt_bias", "conv_b",
+)
 
 
 def _is_decayed(params, skip=_NEVER_DECAYED):
@@ -586,6 +787,25 @@ def model_spec(
     num_pred_heads: int = 1,
     init_std: float = 0.02,
     decay_matrices_only: bool = False,
+    # nemotron_h's keys (defaults: OLMoE's block)
+    hybrid_override_pattern: Optional[str] = None,
+    mamba_num_heads: int = 0,
+    mamba_head_dim: int = 0,
+    n_groups: int = 1,
+    ssm_state_size: int = 0,
+    conv_kernel: int = 4,
+    time_step_min: float = 0.001,
+    time_step_max: float = 0.1,
+    time_step_floor: float = 1e-4,
+    num_key_value_heads: int = 0,
+    head_dim: int = 0,
+    moe_latent_size: int = 0,
+    moe_shared_expert_intermediate_size: int = 0,
+    mlp_hidden_act: str = "silu",
+    mamba_heads_held: int = 0,
+    kv_heads_held: int = 0,
+    rescale_prenorm_residual: bool = False,
+    residual_layers: int = 0,
 ) -> ModelSpec:
     """``layer_types`` names each layer's feed-forward, ``"moe"`` or
     ``"dense"`` (both gated; dense layers ``intermediate_size`` wide, experts
@@ -606,7 +826,64 @@ def model_spec(
     0); ``fp32_skip_add``: a float32 residual stream; ``num_pred_heads``
     heads of prediction (module docstring); ``decay_matrices_only``: AdamW
     decays no gain and neither of EVA's vectors (default: every leaf but
-    the correction biases)."""
+    the correction biases).  ``hybrid_override_pattern`` (``nemotron_h``): a
+    letter a layer, ``M`` a Mamba-2 mixer (``mamba_num_heads`` heads of
+    ``mamba_head_dim`` in ``n_groups`` groups, state ``ssm_state_size``,
+    ``conv_kernel`` taps, the scan in chunks of ``chunk_size``;
+    ``mamba_heads_held`` of the heads, whole groups, are computed here), ``*``
+    attention with ``num_key_value_heads`` key/value heads of ``head_dim``
+    (``heads_held`` / ``kv_heads_held`` here) and no rotary turn, ``E`` a
+    LatentMoE (sigmoid router with a correction bias over ``num_experts``,
+    experts of two matrices ``moe_latent_size`` -> ``moe_intermediate_size``
+    under ``mlp_hidden_act`` relu2, a shared expert
+    ``moe_shared_expert_intermediate_size`` wide); ``rescale_prenorm_residual``
+    scales the matrices that write into the stream by ``residual_layers``^-1/2
+    (0: this model's depth).  Every held count 0 = all."""
+    hybrid = None
+    if hybrid_override_pattern is not None:
+        pattern = str(hybrid_override_pattern)
+        if len(pattern) != num_hidden_layers or set(pattern) - set(PATTERN_KINDS):
+            raise ValueError(
+                f"hybrid_override_pattern must give {num_hidden_layers} layers a letter of {sorted(PATTERN_KINDS)} "
+                f"({PATTERN_KINDS}), got {pattern!r}"
+            )
+        if layer_types is not None or kv_lora_rank or attention_class != "mha":
+            raise ValueError("hybrid_override_pattern names every layer's kind: layer_types, latent attention and attention_class do not go with it")
+        if mlp_hidden_act != "relu2" or topk_method != "noaux_tc" or scoring_func != "sigmoid" or tie_word_embeddings:
+            raise ValueError(
+                "a hybrid_override_pattern model is nemotron_h's: mlp_hidden_act 'relu2', a sigmoid router with a "
+                f"correction bias (topk_method 'noaux_tc') and an untied head; got {mlp_hidden_act!r} / {scoring_func!r} / "
+                f"{topk_method!r} / tie_word_embeddings {tie_word_embeddings}"
+            )
+        m_held, q_held = mamba_heads_held or mamba_num_heads, heads_held or num_attention_heads
+        kv_all = num_key_value_heads or num_attention_heads
+        kv_held = kv_heads_held or kv_all
+        if "M" in pattern:
+            per_group = mamba_num_heads // max(n_groups, 1)
+            if min(mamba_num_heads, mamba_head_dim, ssm_state_size, chunk_size, conv_kernel) <= 0 or mamba_num_heads % n_groups:
+                raise ValueError(
+                    f"a Mamba-2 layer needs mamba_num_heads in whole n_groups, mamba_head_dim, ssm_state_size, conv_kernel "
+                    f"and chunk_size, got {mamba_num_heads} / {n_groups} / {mamba_head_dim} / {ssm_state_size} / {conv_kernel} / {chunk_size}"
+                )
+            if not 0 < m_held <= mamba_num_heads or m_held % per_group:
+                raise ValueError(f"mamba_heads_held {m_held} of {mamba_num_heads}: whole groups of {per_group} heads (the gated norm's)")
+            if seq_len % chunk_size:
+                raise ValueError(f"seq_len {seq_len} is not whole chunks of {chunk_size}: the chunked scan needs them")
+        if "*" in pattern and (
+            num_attention_heads % kv_all or not 0 < q_held <= num_attention_heads or not 0 < kv_held <= kv_all
+            or q_held % kv_held or (num_attention_heads // kv_all) % (q_held // kv_held)
+        ):
+            raise ValueError(
+                f"{q_held} of {num_attention_heads} query heads on {kv_held} of {kv_all} key/value heads: a share's "
+                f"query heads sit evenly on its key/value heads, a divisor of the published {num_attention_heads // max(kv_all, 1)} on each"
+            )
+        if "E" in pattern and min(moe_latent_size, moe_intermediate_size, moe_shared_expert_intermediate_size) <= 0:
+            raise ValueError("a LatentMoE layer needs moe_latent_size, moe_intermediate_size and moe_shared_expert_intermediate_size")
+        # what the step's counters and the correction bias's rule read
+        layer_types = tuple("moe" if kind == "E" else "dense" for kind in pattern)
+        hybrid = {"state": ssm_state_size, "chunk": chunk_size, "head_dim": head_dim or hidden_size // num_attention_heads}
+    elif mlp_hidden_act != "silu" or mamba_heads_held or kv_heads_held:
+        raise ValueError("mlp_hidden_act, mamba_heads_held and kv_heads_held go with hybrid_override_pattern: every other block's feed-forward is gated silu")
     if layer_types is None:
         dense = min(first_k_dense_replace, num_hidden_layers)
         layer_types = ("dense",) * dense + ("moe",) * (num_hidden_layers - dense)
@@ -640,8 +917,8 @@ def model_spec(
         if not 0 <= heads_held <= num_attention_heads:
             raise ValueError(f"heads_held {heads_held} of {num_attention_heads} heads")
         eva = {"window": window_size, "chunk": chunk_size}
-    elif heads_held:
-        raise ValueError("heads_held goes with attention_class 'eva': no other attention takes a share of the heads")
+    elif heads_held and hybrid is None:
+        raise ValueError("heads_held goes with attention_class 'eva' or a hybrid_override_pattern: no other attention takes a share of the heads")
     if num_pred_heads < 1 or (num_pred_heads > 1 and tie_word_embeddings):
         raise ValueError(f"num_pred_heads {num_pred_heads}: at least one, and more than one only with an untied head")
     if num_experts_per_tok > num_experts:
@@ -671,14 +948,26 @@ def model_spec(
             )
             if value != default
         },
-        first_expert_held=first_expert_held, eva=eva, unit_offset=bool(norm_add_unit_offset),
+        first_expert_held=first_expert_held, eva=eva, unit_offset=bool(norm_add_unit_offset), hybrid=hybrid,
         residual_dtype=jnp.float32 if fp32_skip_add else None, num_pred_heads=num_pred_heads,
     )
     skip = _NOT_MATRICES if decay_matrices_only else _NEVER_DECAYED
     coefs = dict(lb_coef=router_aux_loss_coef, z_coef=router_z_loss_coef)
+    init = None
+    if hybrid is not None:
+        init = functools.partial(
+            _init_hybrid_params, pattern=pattern, vocab_size=vocab_size, hidden_size=hidden_size, init_std=init_std,
+            residual_layers=(residual_layers or num_hidden_layers) if rescale_prenorm_residual else 1,
+            mamba_heads=m_held, mamba_head_dim=mamba_head_dim,
+            groups=m_held * n_groups // mamba_num_heads if mamba_num_heads else 0,
+            state=ssm_state_size, conv_kernel=conv_kernel, dt_range=(time_step_min, time_step_max, time_step_floor),
+            q_heads=q_held, kv_heads=kv_held, head_dim=hybrid["head_dim"], num_experts=num_experts,
+            experts_held=held, latent=moe_latent_size, expert_width=moe_intermediate_size,
+            shared_width=moe_shared_expert_intermediate_size,
+        )
     return ModelSpec(
         name="moe_lm",
-        init=functools.partial(
+        init=init or functools.partial(
             _init_params, vocab_size=vocab_size, hidden_size=hidden_size,
             intermediate_size=intermediate_size, num_experts=num_experts,
             layer_types=layer_types, tie_word_embeddings=tie_word_embeddings,
@@ -702,7 +991,10 @@ def model_spec(
         example_batch=functools.partial(_example_batch, seq_len=seq_len),
         batch_shard_dim=1,
         predict=functools.partial(_predict, apply=apply),
-        step_counters={**(MOE_COUNTERS if "moe" in layer_types else {}), **(EVA_COUNTERS if eva else {})},
+        step_counters={
+            **(MOE_COUNTERS if "moe" in layer_types else {}), **(EVA_COUNTERS if eva else {}),
+            **(SSM_COUNTERS if hybrid and "M" in pattern else {}),
+        },
         after_update=(
             functools.partial(_update_correction_bias, speed=float(bias_update_speed))
             if correction_bias else None

@@ -2,8 +2,9 @@
 
 ``route`` picks ``k`` of ``E`` experts a token (a softmax or a sigmoid over
 all ``E``, then top-k, float32 throughout); ``expert_ffn`` runs every
-(token, expert) slot whose expert it HOLDS through that expert's gated
-feed-forward and sums a token's ``k`` results with the router's weights.
+(token, expert) slot whose expert it HOLDS through that expert's
+feed-forward (gated, or two matrices under relu squared) and sums a token's
+``k`` results with the router's weights.
 DROPLESS: there is no capacity factor, every held slot is computed,
 whatever the routing (the sum of the held groups' sizes equals the slots
 the router sent to them; the trainer's ``moe_slots_computed`` and
@@ -26,8 +27,9 @@ held experts' even share ``T * k * n / E``, at most ``T * k``):
 
 * The always-run tier takes the first ``C`` sorted positions of the run
   (``_held_window``): their token rows are gathered, three GROUPED matmuls
-  (``_grouped_matmul``: uneven, data-dependent groups, one compiled program
-  whatever the sizes) run the held experts' rows of the window — the rows
+  (two for an expert of two matrices; ``_grouped_matmul``: uneven,
+  data-dependent groups, one compiled program whatever the sizes) run the
+  held experts' rows of the window — the rows
   past the run are a last group of no expert, which comes back zero — and
   each result row is weighted and summed into its token, at most ``k`` rows
   a token, in float32 (``_token_sum``: the rows sorted by token and one
@@ -246,9 +248,14 @@ def _grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array, lo: int) -> ja
 
 
 def _experts(x, w_gate, w_up, w_down, sizes, lo: int):
-    """The held experts' gated feed-forward over rows grouped by expert."""
+    """The held experts' feed-forward over rows grouped by expert: gated
+    (``silu(x Wgate) * (x Wup)``), or, for experts of TWO matrices
+    (``w_gate`` None), ``relu(x Wup)^2``; then ``Wdown``."""
     with jax.named_scope("moe_experts"):
-        h = jax.nn.silu(_grouped_matmul(x, w_gate, sizes, lo)) * _grouped_matmul(x, w_up, sizes, lo)
+        if w_gate is None:
+            h = jnp.square(jax.nn.relu(_grouped_matmul(x, w_up, sizes, lo)))
+        else:
+            h = jax.nn.silu(_grouped_matmul(x, w_gate, sizes, lo)) * _grouped_matmul(x, w_up, sizes, lo)
         return _grouped_matmul(h, w_down, sizes, lo)
 
 
@@ -428,9 +435,10 @@ def _overflow_bwd(bound, res, g):
         def window(*diff):
             return _held_window(*diff, order, inverse, start, ends, i * bound, bound)[0]
 
-        return tuple(a + b for a, b in zip(grads, jax.vjp(window, *diff)[1](g_out)))
+        # (trees: an expert of two matrices has None for its gate)
+        return jax.tree.map(jnp.add, grads, jax.vjp(window, *diff)[1](g_out))
 
-    grads = lax.fori_loop(1, _windows(ends, bound), body, tuple(jnp.zeros_like(a) for a in diff))
+    grads = lax.fori_loop(1, _windows(ends, bound), body, jax.tree.map(jnp.zeros_like, tuple(diff)))
     return (g_out, *grads, None, None, None, None)
 
 
@@ -473,11 +481,13 @@ def expert_ffn(
     every token ``t``: ``u`` [T, D], ``choices`` / ``weights`` [T, k] over
     the router's ``n_experts`` (None: as many as are held), the held
     experts ``[lo, lo + n)``'s weights [n, D, F] / [n, F, D] already in the
-    compute dtype.  Returns (the result [T, D] in ``u``'s dtype, the slots
+    compute dtype.  ``w_gate`` None: experts of two matrices,
+    ``relu(u Wup[e])^2 Wdown[e]``, through the same tiers and buffers.
+    Returns (the result [T, D] in ``u``'s dtype, the slots
     [E] the router sent each of ITS experts, the rows the grouped matmuls
     were :class:`Given`: together the held slots, whatever the routing)."""
     n_tokens, k = choices.shape
-    n_held = w_gate.shape[0]
+    n_held = w_up.shape[0]
     n_experts = n_held if n_experts is None else n_experts
     if not 0 <= lo <= n_experts - n_held:
         raise ValueError(f"held experts [{lo}, {lo + n_held}) are not among the router's {n_experts}")

@@ -830,9 +830,10 @@ class Trainer:
         chip's memory initialises; nothing is built whole on device 0).
         jax's counter-based threefry makes the values independent of the
         number of devices."""
-        # ``init_state_s`` is kept for the counter the metric
-        # init_state_s.ex4 reads (worker._counter_snapshot); the worker's
-        # set-up chain stamps the same call as its ``init_state`` span.
+        # ``init_state_s`` feeds the worker's counter of that name
+        # (worker._counter_snapshot; no metric reads it since PR 39: ROADMAP
+        # D16); the worker's set-up chain stamps the same call as its
+        # ``init_state`` span.
         t0 = time.monotonic()
         with trace.span("init_state"):
             state = jax.block_until_ready(self._init_program(rng)(rng))
@@ -1639,7 +1640,14 @@ class Trainer:
         return step
 
     # jit-boundary: returns device buffers fresh off the compiled step
-    def train_step(self, state: TrainState, batch: Any):
+    def build_train_step(self, state: Any, batch: Any) -> Callable:
+        """The jitted train step for ``batch``'s structure, built once.  Only
+        the SHAPES of ``state`` and ``batch`` are read (arrays or
+        ``ShapeDtypeStruct``s): where the model's rematerialised blocks keep
+        by a byte budget the step is compiled here, against the line
+        (:meth:`_held_to_the_line`), so a caller that knows the shapes early
+        can pay that compile on a thread of its own (the benchmark's
+        reference child does, while the device is busy)."""
         self._train_step = self._structured(
             self._train_steps, build_train_step, batch,
             fit_args=(state, batch, self._active_device()),
@@ -1649,7 +1657,10 @@ class Trainer:
             ],
             **self._train_build_kwargs(),
         )
-        return self._train_step(state, batch, self._active_device())
+        return self._train_step
+
+    def train_step(self, state: TrainState, batch: Any):
+        return self.build_train_step(state, batch)(state, batch, self._active_device())
 
     def shard_stacked_batch(self, stacked: Any) -> Any:
         """Place a HOST batch of stacked minibatches ([T, mb, ...] per leaf)

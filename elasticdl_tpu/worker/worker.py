@@ -61,9 +61,6 @@ from elasticdl_tpu.parallel.trainer import Trainer, TrainLoopError
 
 logger = get_logger("worker")
 
-#: Consecutive training tasks one ``--profile_dir`` window traces, from the
-#: second training dispatch of the worker on (the first pays compilation).
-PROFILE_TASKS = 3
 
 #: The worker's own counters (cumulative since it started; they ride every
 #: task report as ``counters``) and the gauge each is published under.
@@ -1531,7 +1528,7 @@ class Worker:
         taken up for dispatch (the first pays compilation; with prep-ahead
         a task is leased and prepped earlier than that, and opening at the
         lease would put the first task's compile inside the window).  The
-        window spans ``PROFILE_TASKS`` consecutive tasks and changes
+        window spans ``--profile_tasks`` consecutive tasks and changes
         nothing about how they are prepped, dispatched, settled and
         reported.  Counts training tasks only, so interleaved eval/predict
         tasks neither skip the trace nor shift it onto a compiling step.
@@ -1565,7 +1562,7 @@ class Worker:
         logger.info(
             "profile window open: %d training tasks from dispatch seq %d "
             "into %s (pipelining %s, prep-ahead %s, %d task(s) in prep)",
-            PROFILE_TASKS, self._dispatches, self.config.profile_dir,
+            self.config.profile_tasks, self._dispatches, self.config.profile_dir,
             self._pipelining_enabled(), self._prep_ahead_eligible(),
             len(self._prep_queue),
         )
@@ -1581,7 +1578,9 @@ class Worker:
         its own: collecting the device trace and writing the files takes
         seconds on a TPU (1.6 s and 11.7 s in the benchmark's two cells,
         PERF.md) and must not sit on the task loop at the edge of the
-        window it has just measured."""
+        window it has just measured.  With ``--profile_inline`` the loop
+        does it itself and stands still meanwhile: the files are whole
+        before the next task reports."""
         if self._profile_state != "open":
             return
         # graftlint: allow[shared-state] the _parked spin-wait handshake serializes the preemption thread's _flush_pending against the loop (see preemption_snapshot)
@@ -1600,10 +1599,15 @@ class Worker:
                 return
             logger.info(
                 "profile window closed: %d task(s) traced into %s; "
-                "collecting and writing took %.3f s off the task loop",
+                "collecting and writing took %.3f s %s the task loop",
                 traced, self.config.profile_dir, time.perf_counter() - t0,
+                "on" if self.config.profile_inline else "off",
             )
 
+        if self.config.profile_inline:
+            with self.phases.phase("control"):
+                _stop()
+            return
         closer = threading.Thread(target=_stop, name="edl-profile", daemon=True)
         closer.start()
         # graftlint: allow[shared-state] the _parked spin-wait handshake serializes the preemption thread's _flush_pending against the loop (see preemption_snapshot)
@@ -1671,10 +1675,9 @@ class Worker:
             "hbm_peak_bytes": device_peak_bytes(),
             "dispatches": self._dispatches,
             "dispatches_device_idle": self._dispatches_idle,
-            # Rides every report only because the metric init_state_s.ex4
-            # reads it here; the set-up chain's ``init_state`` span is the
-            # same seconds, once (the benchmark issue that retires the
-            # twin takes this key out).
+            # No metric reads it since PR 39 retired init_state_s.ex4 (the
+            # set-up chain's ``init_state`` span is the same seconds, once):
+            # ROADMAP D16 takes this key out.
             "init_state_s": round(
                 self.trainer.init_state_s if self.trainer else 0.0, 6
             ),
@@ -1998,7 +2001,7 @@ class Worker:
         setup = self._setup if seq == 0 else None
         if self._profile_state == "open" and self._profile_last_task is None:
             self._profile_traced += 1
-            if self._profile_traced >= PROFILE_TASKS:
+            if self._profile_traced >= self.config.profile_tasks:
                 self._profile_last_task = task.task_id
         tid = task.task_id
         mb = self.config.minibatch_size

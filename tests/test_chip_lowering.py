@@ -749,6 +749,61 @@ def test_evabyte_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
     assert any("attention path: pallas-compiled" in line and "eva window=2048" in line for line in path_lines), path_lines
 
 
+def test_nemotron3_step_compiles_for_v5e_with_its_scopes_and_the_flash_kernels_lists(
+    v5e_device, olmoe_as_on_the_chip, path_lines, monkeypatch
+):
+    """``nemotron3_job``'s real step (Nemotron 3 Super's widths, one period
+    of 11 layers: 5 LatentMoE, 5 Mamba-2, 1 attention; ONE sequence of 8192
+    tokens, the traffic's two steps a dispatch, per-layer rematerialisation) compiled for
+    a described v5e with the byte budget the trainer resolves from a v5e's
+    memory: 773.6 M parameters and their moments are 8.65 GiB of arguments,
+    the layers keep every save site and the step stays between 11.5 GiB and
+    the trainer's line of 14.25; the device scopes the ``.ssm`` / ``.tok`` /
+    ``.mla`` metrics read are there; the attention layer is the three flash
+    kernels at the operand lists ``flash_roofline_pct.tok`` reads (3 / 4 + 1
+    / 4 + 2: the key/value head is repeated AHEAD of them), the forward
+    once; the chunked scan is XLA (no Mosaic call under ``ssm_scan``); the
+    experts are grouped matmuls; and no [*, 8192, 8192] score matrix exists."""
+    import json
+    import os
+
+    from elasticdl_tpu.parallel import trainer as trainer_lib
+
+    monkeypatch.setattr(trainer_lib, "device_bytes_limit", lambda devices: V5E_BYTES_LIMIT[0])
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "nemotron3_super_tp4_ep64_l11.json")) as f:
+        params = json.load(f)["model_params"]
+    with open(os.path.join(root, "benchmark", "traffic", "job_seq8k_x1.json")) as f:
+        traffic = json.load(f)
+    spec = load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", **params)
+    mesh = create_mesh([v5e_device], num_devices=1)
+    trainer = Trainer(spec, JobConfig(distribution_strategy=DistributionStrategy.ALLREDUCE), mesh)
+    step, args = _abstract_scan_step(
+        trainer, mesh, minibatch=traffic["minibatch_size"], steps=traffic["minibatches_per_task"])
+    compiled = step.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    total, plan = trainer_lib.compiled_bytes(compiled), trainer.keep_plan
+    assert 11.5 * 2**30 < total < plan.line == V5E_BYTES_LIMIT[0] - trainer_lib.REMAT_HEADROOM, total / 2**30
+    assert abs(compiled.memory_analysis().argument_size_in_bytes - 12 * 773582304) < 2**20  # parameters and two moments
+    assert plan.kept == plan.tagged <= plan.budget and plan.tagged > 2**30
+    text = compiled.as_text()
+    scopes = ("ssm_proj", "ssm_conv", "ssm_scan", "ssm_norm", "attn_proj", "moe_latent", "moe_shared", "moe_router",
+              "moe_dispatch", "moe_experts", "moe_combine", "mlp", "flash_attn", "lm_head")
+    for scope in scopes:
+        assert re.search(rf'op_name="[^"]*\b{scope}\b', text), scope
+    assert not re.search(r"\[(\d+,)*8192,8192\]", text)
+    mosaic = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    under = lambda scope: [c for c in mosaic if re.search(rf'op_name="[^"]*\b{scope}\b', c)]  # noqa: E731
+    flash = _flash_calls(text)
+    assert _signatures("\n".join(flash)) == [(3, 0), (4, 1), (4, 2)]  # the forward ONCE: its output is kept
+    assert all("bf16[1,8192,1024]" in c for c in flash), flash[:1]  # 8 query heads of 128; K and V repeated to as many
+    assert not under("ssm_scan") and under("moe_experts") and len(under("moe_experts")) % 5 == 0
+    # the patterns of the roofline entry the cell joined tell exactly these three apart
+    with open(os.path.join(root, "benchmark", "metrics", "flash_roofline_pct.tok.json")) as f:
+        patterns = [k["pattern"] for k in json.load(f)["params"]["kernels"]]
+    assert any("attention path: pallas-compiled" in line and "heads_per_block=1" in line for line in path_lines), path_lines
+    assert len(patterns) == 3 and params["remat"]
+
+
 #: sha256 of ``gpt2_medium``'s step lowered for the chip (StableHLO text,
 #: 16 sequences of 1024, two steps a dispatch): a PR that may not move
 #: ``gpt2m_job`` pins that its program is the same to the byte.  A PR that

@@ -53,22 +53,23 @@ def test_the_cell_resolves_and_its_traffic_differs_in_the_ids_alone():
     assert ours == theirs
     # the cell's own; PR 35's set-up metrics and `prep` span metric list every cell they are read in
     reported = [m["name"] for m in bench.metrics_of(CELL, "per_layer") if not m["name"].startswith(("setup_", "prep_ms_task"))]
-    assert sorted(reported) == sorted([
+    assert set(reported) >= {
         *TWINS, "table_grad_ms_step.ex", "table_grad_sweep_pct.ex",
         "table_apply_ms_step.ex", "table_apply_fused_pct.ex",  # PR 29
-    ])
+    }
 
 
 @pytest.mark.parametrize("name", sorted(TWINS))
 def test_an_exz_metric_is_its_original_under_another_name(name):
     bench = resolve.Bench(ROOT)
     ours, theirs = bench.metric_file(name), bench.metric_file(TWINS[name])
-    assert (ours.pop("name"), ours.pop("cells")) == (name, [CELL])
-    del theirs["name"], theirs["cells"]
+    # (a file's own list of cells, where it still has one, is not read: BENCHMARK.json's entry is)
+    assert ours.pop("name") == name and theirs.pop("name") == TWINS[name]
+    ours.pop("cells", None), theirs.pop("cells", None)
     assert ours == theirs
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
     (original,) = [m for m in bench.spec["per_layer"] if m["name"] == TWINS[name]]
-    assert entry.pop("workloads") == [CELL]
+    assert CELL in entry.pop("workloads")
     assert {**original, "name": name, "workloads": None} == {**entry, "workloads": None}
 
 
@@ -77,7 +78,9 @@ def test_a_table_grad_metric_reads_the_sweeps_scope_or_counters(name):
     bench = resolve.Bench(ROOT)
     spec = bench.metric_file(name)
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
-    assert spec["cells"] == entry["workloads"] == TABLE_GRAD[name]
+    assert all(cell in entry["workloads"] for cell in TABLE_GRAD[name])
+    for key in ("unit", "layer", "moves", "better", "source"):
+        assert spec[key] == entry[key], key
     assert (entry["layer"], entry["moves"]) == ("ops", "examples_per_s_chip")
     assert callable(bench.reader(spec["reader"]).read)
     if name.startswith("table_grad_ms_step"):
@@ -89,7 +92,7 @@ def test_a_table_grad_metric_reads_the_sweeps_scope_or_counters(name):
     else:
         from elasticdl_tpu.worker.worker import COUNTER_GAUGES, STEP_COUNTERS
         params = spec["params"]
-        assert (params["how"], params["scale"]) == ("growth", 100)
+        assert params["scale"] == 100
         for counter in (params["counter"], params["over"]):
             assert counter in STEP_COUNTERS and counter in COUNTER_GAUGES
         # a program without the counters (the parent commit) reports nothing

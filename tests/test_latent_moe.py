@@ -507,9 +507,9 @@ def test_the_overflow_metric_reads_the_counter_and_nothing_on_the_parent(tmp_pat
     name, cell = "moe_slots_overflow_pct.mla", "kanana2_job"
     bench = resolve.Bench(ROOT)
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == [cell]  # appended in PR 34; later PRs append after it
+    assert cell in entry["workloads"]  # BENCHMARK.json's entry is where a metric's cells live (PR 39)
     spec = bench.metric_file(name)
-    assert spec["cells"] == [cell] and spec["reader"] == "counter_delta" and spec["better"] == "lower"
+    assert spec["reader"] == "counter_delta" and spec["better"] == "lower"
     for key in ("unit", "layer", "moves", "better", "source"):
         assert spec[key] == entry[key], key
     assert name in [m["name"] for m in bench.metrics_of(cell, "per_layer")]

@@ -208,19 +208,26 @@ def _host_events(xplane_path, name):
     return out
 
 
-def test_worker_profiler_trace(tmp_path, devices):
-    """``--profile_dir`` traces PROFILE_TASKS tasks from the second one on,
-    with the host's spans in the same file, and leaves the loop alone:
-    prep-ahead stays on and the job trains to the same losses."""
+@pytest.mark.parametrize("flags", [{}, {"profile_tasks": 2, "profile_inline": True}], ids=["three_tasks_written_on_a_thread", "two_tasks_written_on_the_loop"])
+def test_worker_profiler_trace(tmp_path, devices, flags):
+    """``--profile_dir`` traces ``--profile_tasks`` tasks (3) from the second
+    one on, with the host's spans in the same file, and leaves the loop
+    alone: prep-ahead stays on and the job trains to the same losses.
+    With ``--profile_inline`` the loop writes the files itself: they are
+    there, whole, when the window's last task has reported, and no thread
+    is left to wait for."""
     from elasticdl_tpu.common import trace
-    from elasticdl_tpu.worker.worker import PROFILE_TASKS
 
+    PROFILE_TASKS = flags.get("profile_tasks", 3)
     prof = str(tmp_path / "prof")
-    worker, _, records = _train_job(tmp_path, "traced", profile_dir=prof)
+    worker, _, records = _train_job(tmp_path, "traced", profile_dir=prof, **flags)
     traces = glob.glob(os.path.join(prof, "**", "*.xplane.pb"), recursive=True)
     assert len(traces) == 1, "expected one xplane trace from the profile window"
     assert worker._prep_ahead_eligible() and worker._pipelining_enabled()
     assert worker._profile_state == "closed" and trace.default().bridge is None
+    assert worker.config.profile_tasks == PROFILE_TASKS
+    if flags.get("profile_inline"):
+        assert worker._profile_closer is None  # the loop wrote the files: no thread was started
 
     dispatches = _host_events(traces[0], "dispatch")
     # one task-loop line; the first task (seq 0, the compile) is outside

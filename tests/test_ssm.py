@@ -1,0 +1,141 @@
+"""``ops/ssm.py`` against its plain references: the chunked scan (forward,
+the last state, every gradient), the causal depthwise convolution and the
+gated norm over groups."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elasticdl_tpu.ops import remat, ssm
+
+
+def _operands(seed, batch, length, heads, width, groups, state, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.key(seed), 7)
+    x = jax.random.normal(ks[0], (batch, length, heads, width), jnp.float32)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (batch, length, heads)) - 2.0)
+    a = -jnp.exp(jax.random.uniform(ks[2], (heads,), minval=0.0, maxval=jnp.log(16.0)))
+    b = jax.random.normal(ks[3], (batch, length, groups, state), jnp.float32)
+    c = jax.random.normal(ks[4], (batch, length, groups, state), jnp.float32)
+    d = jax.random.normal(ks[5], (heads,))
+    g = jax.random.normal(ks[6], x.shape, jnp.float32)
+    return (x.astype(dtype), dt, a, b.astype(dtype), c.astype(dtype), d), g
+
+
+SHAPES = {
+    "one_group": (1, 32, 2, 4, 1, 8, 8),
+    "two_groups": (2, 48, 4, 8, 2, 16, 16),
+    "one_chunk": (1, 16, 2, 4, 2, 8, 16),
+    "heads_are_groups": (1, 24, 3, 4, 3, 4, 8),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SHAPES))
+def test_scan_forward_and_last_state_match_the_recurrence(name):
+    *shape, chunk = SHAPES[name]
+    args, _ = _operands(0, *shape)
+    y, aux = ssm.ssm_scan(*args, chunk=chunk, with_aux=True)
+    want, last = ssm.ssm_scan_reference(*args)
+    np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(aux.state, last, rtol=2e-5, atol=2e-5)
+    # the log-decays restart at every chunk
+    log = np.asarray(args[1] * args[2]).reshape(shape[0], shape[1] // chunk, chunk, shape[2]).cumsum(2)
+    np.testing.assert_allclose(aux.log_decay, log.reshape(aux.log_decay.shape), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("operand", range(6), ids=["x", "dt", "a", "b", "c", "d"])
+@pytest.mark.parametrize("name", ["two_groups", "heads_are_groups"])
+def test_scan_gradient_matches_the_recurrence(name, operand):
+    *shape, chunk = SHAPES[name]
+    args, g = _operands(1, *shape)
+    loss = lambda f: (lambda *a: jnp.sum(f(*a) * g))  # noqa: E731
+    got = jax.grad(loss(lambda *a: ssm.ssm_scan(*a, chunk=chunk)), argnums=operand)(*args)
+    want = jax.grad(loss(lambda *a: ssm.ssm_scan_reference(*a)[0]), argnums=operand)(*args)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_scan_last_state_carries_a_gradient():
+    *shape, chunk = SHAPES["two_groups"]
+    args, _ = _operands(2, *shape)
+    # ssm_scan stops the aux's gradient; the inner op's second output carries one
+    got = jax.grad(lambda x: jnp.sum(ssm._scan(x, *args[1:5], chunk, False)[1] ** 2))(args[0])
+    want = jax.grad(lambda x: jnp.sum(ssm.ssm_scan_reference(x, *args[1:5])[1] ** 2))(args[0])
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_scan_in_bfloat16_stays_near_the_float32_recurrence():
+    *shape, chunk = SHAPES["two_groups"]
+    args, _ = _operands(3, *shape, dtype=jnp.bfloat16)
+    y = ssm.ssm_scan(*args, chunk=chunk)
+    want, _ = ssm.ssm_scan_reference(*args)
+    assert y.dtype == jnp.bfloat16
+    assert float(jnp.max(jnp.abs(y.astype(jnp.float32) - want)) / jnp.max(jnp.abs(want))) < 2e-2
+
+
+def test_scan_over_a_long_decay_keeps_its_float32_island():
+    # 512 positions of decays near 1: a bfloat16 cumulative sum would be off by whole units
+    args, _ = _operands(4, 1, 512, 2, 4, 1, 8)
+    args = (args[0], 0.01 * jnp.ones_like(args[1]), -jnp.ones_like(args[2]), *args[3:])
+    y = ssm.ssm_scan(*args, chunk=128)
+    np.testing.assert_allclose(y, ssm.ssm_scan_reference(*args)[0], rtol=1e-4, atol=1e-4)
+
+
+def test_scan_refuses_a_sequence_that_is_not_whole_chunks():
+    args, _ = _operands(0, 1, 40, 2, 4, 1, 8)
+    with pytest.raises(ValueError, match="whole chunks of 16"):
+        ssm.ssm_scan(*args, chunk=16)
+
+
+def test_scan_refuses_heads_that_do_not_split_into_the_groups():
+    args, _ = _operands(0, 1, 32, 3, 4, 1, 8)
+    bad = (args[0], args[1], args[2], jnp.zeros((1, 32, 2, 8)), jnp.zeros((1, 32, 2, 8)), args[5])
+    with pytest.raises(ValueError, match="3 heads in 2 groups"):
+        ssm.ssm_scan(*bad, chunk=16)
+
+
+@pytest.mark.parametrize("keep", [False, True], ids=["recomputed", "kept"])
+def test_scan_under_a_rematerialised_block_gives_the_same_gradient(keep):
+    *shape, chunk = SHAPES["two_groups"]
+    args, g = _operands(5, *shape)
+
+    def block(x):
+        return jnp.sum(ssm.ssm_scan(x, *args[1:], chunk=chunk) * g)
+
+    sites, _ = remat.trace_sites(block, args[0])
+    assert [s.name for s in sites] == ["ssm_scan_out"]
+    assert sites[0].work == ssm.scan_flops(*shape, chunk)
+    got = jax.grad(remat.rematerialised(block, {"ssm_scan_out"} if keep else ()))(args[0])
+    np.testing.assert_allclose(got, jax.grad(block)(args[0]), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("operand", range(3), ids=["x", "taps", "bias"])
+def test_conv_matches_the_positionwise_sum_forward_and_backward(operand):
+    ks = jax.random.split(jax.random.key(0), 4)
+    x, w, bias = jax.random.normal(ks[0], (2, 11, 6)), jax.random.normal(ks[1], (4, 6)), jax.random.normal(ks[2], (6,))
+    g = jax.random.normal(ks[3], x.shape)
+    np.testing.assert_allclose(ssm.causal_conv(x, w, bias), ssm.causal_conv_reference(x, w, bias), rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(ssm.causal_conv(*a) * g), argnums=operand)(x, w, bias)
+    want = jax.grad(lambda *a: jnp.sum(ssm.causal_conv_reference(*a) * g), argnums=operand)(x, w, bias)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_conv_sees_nothing_ahead_and_nothing_of_another_sequence():
+    x = jnp.zeros((2, 8, 1)).at[0, 5, 0].set(1.0)
+    y = ssm.causal_conv(x, jnp.arange(1.0, 5.0)[:, None], jnp.zeros((1,)))
+    np.testing.assert_array_equal(np.asarray(y[0, :, 0]), [0, 0, 0, 0, 0, 4, 3, 2])
+    np.testing.assert_array_equal(np.asarray(y[1]), 0)
+
+
+@pytest.mark.parametrize("operand", range(3), ids=["y", "gate", "gain"])
+def test_gated_group_norm_matches_the_groupwise_form(operand):
+    ks = jax.random.split(jax.random.key(0), 4)
+    y, z, gain = jax.random.normal(ks[0], (2, 5, 12)), jax.random.normal(ks[1], (2, 5, 12)), jax.random.normal(ks[2], (12,))
+    g = jax.random.normal(ks[3], y.shape)
+    got = ssm.gated_group_norm(y, z, gain, 3, 1e-5)
+    np.testing.assert_allclose(got, ssm.gated_group_norm_reference(y, z, gain, 3, 1e-5), rtol=1e-5, atol=1e-5)
+    # a group's norm reads its own channels alone
+    moved = ssm.gated_group_norm(y.at[..., 8:].multiply(3.0), z, gain, 3, 1e-5)
+    np.testing.assert_allclose(moved[..., :8], got[..., :8], rtol=1e-6, atol=1e-6)
+    got = jax.grad(lambda *a: jnp.sum(ssm.gated_group_norm(*a, 3, 1e-5) * g), argnums=operand)(y, z, gain)
+    want = jax.grad(lambda *a: jnp.sum(ssm.gated_group_norm_reference(*a, 3, 1e-5) * g), argnums=operand)(y, z, gain)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
