@@ -28,7 +28,6 @@ import time
 from typing import Any, Dict, Optional
 
 import jax
-import orbax.checkpoint as ocp
 
 from elasticdl_tpu.common import durable, trace
 from elasticdl_tpu.common.log_utils import get_logger
@@ -87,6 +86,16 @@ def read_manifest(directory: str) -> Optional[Dict[str, Any]]:
 
 class CheckpointManager:
     def __init__(self, directory: str, keep_max: int = 3):
+        # Orbax (and the tensorstore / cloud-logging stack under it) is
+        # imported HERE, where a job that checkpoints builds its manager,
+        # and not with this module: seconds of every worker's start that
+        # a job without ``checkpoint_dir``, and the serving watcher's
+        # ``read_manifest``, never use.  The worker builds its manager
+        # before the first task, so no save or restore is the first to
+        # import.
+        import orbax.checkpoint as ocp
+
+        self._ocp = ocp
         self.directory = os.path.abspath(directory)
         os.makedirs(self.directory, exist_ok=True)
         self._mgr = ocp.CheckpointManager(
@@ -98,7 +107,7 @@ class CheckpointManager:
 
     def save(self, step: int, state: Any, wait: bool = False) -> None:
         """Async snapshot (training continues while Orbax writes)."""
-        self._mgr.save(step, args=ocp.args.StandardSave(state))
+        self._mgr.save(step, args=self._ocp.args.StandardSave(state))
         if wait:
             self._mgr.wait_until_finished()
 
@@ -115,7 +124,9 @@ class CheckpointManager:
             else jax.ShapeDtypeStruct(x.shape, x.dtype),
             state_like,
         )
-        return self._mgr.restore(step, args=ocp.args.StandardRestore(abstract))
+        return self._mgr.restore(
+            step, args=self._ocp.args.StandardRestore(abstract)
+        )
 
     def publish(
         self,

@@ -16,8 +16,10 @@ off jax entirely, and the helpers below import it lazily.
 
 from __future__ import annotations
 
+import errno
 import os
 import threading
+import time
 
 #: Fixed in-checkout compile-cache directory used when
 #: ``JAX_COMPILATION_CACHE_DIR`` is unset.  The path is part of the cache
@@ -241,6 +243,61 @@ def device_peak_bytes() -> int:
                 + int(stats.get("peak_bytes_reserved", 0)),
             )
     return peak
+
+
+#: Where a TPU host lists its chips: one numbered VFIO group a chip (v5e),
+#: beside the container's own entry ``vfio``.
+VFIO_DIR = "/dev/vfio"
+#: How long a process about to open the TPU waits for chips that a process
+#: just ended still holds.  The kernel gives a killed process's groups back
+#: 3-4 s (one chip) to 8-10 s (four) after it has left ``/proc``
+#: (PERF.md section 6, PR 32), and since PR 43 a relaunched worker reaches
+#: its TPU open 7 s after its launch.
+CHIPS_FREE_DEADLINE_S = 60.0
+
+
+def _busy(group: str) -> bool:
+    try:
+        os.close(os.open(group, os.O_RDWR))
+    except OSError as err:
+        return err.errno == errno.EBUSY
+    return False
+
+
+def wait_for_chips(
+    vfio_dir: str = VFIO_DIR, deadline_s: float = CHIPS_FREE_DEADLINE_S
+) -> tuple:
+    """Before the process's first touch of the backend: poll the numbered
+    groups under ``vfio_dir`` until none refuses ``open`` with EBUSY, or
+    until the deadline.  ``(seconds waited, groups still busy)``.
+
+    A TPU client that finds a group busy does not wait: it fails the
+    process (``TPU initialization failed: open(/dev/vfio/1): Device or
+    resource busy``), and a worker relaunched on the host of a worker just
+    killed finds exactly that for some seconds.  A host without the
+    directory (the CPU, another TPU generation) waits for nothing, and a
+    group that stays busy past the deadline is left to the client's own
+    error.  Nor does a process told to stay off the TPU
+    (``JAX_PLATFORMS`` set, without ``tpu``) wait for chips it will not
+    open."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0.0, []
+    try:
+        groups = [
+            os.path.join(vfio_dir, g)
+            for g in sorted(os.listdir(vfio_dir))
+            if g.isdigit()
+        ]
+    except OSError:
+        return 0.0, []
+    t0 = time.time()
+    while True:
+        groups = [g for g in groups if _busy(g)]
+        waited = time.time() - t0
+        if not groups or waited >= deadline_s:
+            return waited, groups
+        time.sleep(0.25)
 
 
 def device_summary() -> dict:

@@ -398,9 +398,20 @@ def main(argv: Optional[List[str]] = None) -> int:
     from elasticdl_tpu.common.platform import (
         compile_cache_stats,
         device_summary,
+        wait_for_chips,
     )
     from elasticdl_tpu.ps.host_store import native_lib_available
 
+    # A worker relaunched where one was just killed gets here before the
+    # kernel has given the dead one's chips back; the TPU client would fail
+    # the process on a busy chip, so the wait is this span's.
+    waited_s, still_busy = wait_for_chips()
+    if still_busy or waited_s >= 0.25:
+        logger.info(
+            "worker %s waited %.2f s for the chips an ended process held%s",
+            worker_id, waited_s,
+            f"; still busy: {still_busy}" if still_busy else "",
+        )
     device = device_summary()
     setup.mark("setup:device_open")
     boot = dict(device, native_lib=native_lib_available())

@@ -2,7 +2,13 @@
 trace capture in the worker loop (SURVEY.md §5)."""
 
 import glob
+import json
+import math
 import os
+import struct
+import subprocess
+import sys
+import time
 
 import pytest
 
@@ -30,13 +36,154 @@ def test_metrics_writer_roundtrip(tmp_path):
     assert records[1]["kind"] == "eval"
 
 
-def test_metrics_writer_tensorboard(tmp_path):
-    pytest.importorskip("tensorboardX")
+def _crc32c(data: bytes) -> int:
+    """An independent CRC-32C: ``google_crc32c`` where importable, else the
+    bitwise definition (no table), pinned on the standard check vector."""
+    try:
+        import google_crc32c
+
+        return google_crc32c.value(data)
+    except ImportError:
+        crc = 0xFFFFFFFF
+        for byte in data:
+            crc ^= byte
+            for _ in range(8):
+                crc = (crc >> 1) ^ (0x82F63B78 if crc & 1 else 0)
+        return crc ^ 0xFFFFFFFF
+
+
+def _masked(data: bytes) -> int:
+    crc = _crc32c(data)
+    return (((crc >> 15) | (crc << 17)) + 0xA282EAD8) & 0xFFFFFFFF
+
+
+def _fields(buf: bytes) -> dict:
+    """One protobuf message's fields by number (the five kinds the mirror
+    writes; a nested message comes back as bytes): a parser that shares
+    no code with the writer."""
+    out, i = {}, 0
+    while i < len(buf):
+        field, kind = buf[i] >> 3, buf[i] & 7
+        i += 1
+        if kind == 0:  # varint
+            value = shift = 0
+            while True:
+                value |= (buf[i] & 0x7F) << shift
+                shift += 7
+                i += 1
+                if not buf[i - 1] & 0x80:
+                    break
+        elif kind == 2:  # length-delimited (every length here is under 128)
+            value = buf[i + 1:i + 1 + buf[i]]
+            i += 1 + len(value)
+        else:  # fixed64 (double) / fixed32 (float)
+            size, fmt = (8, "<d") if kind == 1 else (4, "<f")
+            value = struct.unpack(fmt, buf[i:i + size])[0]
+            i += size
+        out[field] = value
+    return out
+
+
+def _read_events(path: str) -> list:
+    """The events of a TFRecord file, each record's two checksums verified."""
+    events = []
+    with open(path, "rb") as f:
+        data = f.read()
+    while data:
+        (length,) = struct.unpack("<Q", data[:8])
+        assert struct.unpack("<I", data[8:12])[0] == _masked(data[:8])
+        payload = data[12:12 + length]
+        assert struct.unpack("<I", data[12 + length:16 + length])[0] == _masked(payload)
+        events.append(_fields(payload))
+        data = data[16 + length:]
+    return events
+
+
+def test_the_mirror_checksum_is_crc32c():
+    from elasticdl_tpu.common.metrics import _CRC32C, _masked_crc32c
+
+    assert _crc32c(b"123456789") == 0xE3069283  # the standard check vector
+    assert len(_CRC32C) == 256
+    for data in (b"", b"123456789", bytes(range(256)) * 3):
+        assert int.from_bytes(_masked_crc32c(data), "little") == _masked(data)
+
+
+def _mirror_of(tmp_path) -> str:
     writer = MetricsWriter(str(tmp_path))
     writer.write("train", 1, {"loss": 2.0})
+    writer.write("eval", 300, {"auc": 0.625, "loss": 1.5})
+    writer.write("counter", 300, {"compiles": 3.0}, tensorboard=False)
     writer.close()
-    events = glob.glob(str(tmp_path / "tensorboard" / "events*"))
-    assert events, "expected a tensorboard event file"
+    (events,) = glob.glob(str(tmp_path / "tensorboard" / "events*"))
+    return events
+
+
+#: what ``_mirror_of`` mirrored: (tag, step, value), values exact in float32
+MIRRORED = [("train/loss", 1, 2.0), ("eval/auc", 300, 0.625), ("eval/loss", 300, 1.5)]
+
+
+def test_metrics_writer_tensorboard(tmp_path):
+    """The mirror's file IS a TensorBoard events file: TFRecord framing
+    with both masked CRC-32C values right, a ``file_version`` event first,
+    then one ``Event{wall_time, step, summary{value{tag, simple_value}}}``
+    a mirrored scalar."""
+    path = _mirror_of(tmp_path)
+    assert "tfevents" in os.path.basename(path)
+    first, *scalars = _read_events(path)
+    assert first[3] == b"brain.Event:2" and set(first) == {1, 2, 3}
+    assert abs(first[1] - time.time()) < 600
+    got = []
+    for event in scalars:
+        assert set(event) == {1, 2, 5} and event[1] >= first[1]
+        value = _fields(_fields(event[5])[1])  # Summary{1: Value{1: tag, 2: simple_value}}
+        assert set(value) == {1, 2}
+        got.append((value[1].decode(), event[2], value[2]))
+    assert got == MIRRORED
+
+
+def test_a_value_past_float32_is_mirrored_as_infinity(tmp_path):
+    """A diverged loss must not raise in the master's report handler."""
+    writer = MetricsWriter(str(tmp_path))
+    writer.write("train", 1, {"loss": 1e39, "drift": -1e300, "nan": float("nan")})
+    writer.close()
+    (path,) = glob.glob(str(tmp_path / "tensorboard" / "events*"))
+    values = [_fields(_fields(e[5])[1])[2] for e in _read_events(path)[1:]]
+    assert values[:2] == [math.inf, -math.inf] and math.isnan(values[2])
+
+
+def test_tensorboard_reads_the_mirror(tmp_path):
+    """TensorBoard's own loader gives the same scalars.  In a child: the
+    loader imports TensorFlow where it is installed, which this process
+    (jax, several workers) is better off without."""
+    pytest.importorskip("tensorboard")
+    path = _mirror_of(tmp_path)
+    script = (
+        "import json, sys\n"
+        "from tensorboard.backend.event_processing.event_file_loader import EventFileLoader\n"
+        "from tensorboard.util import tensor_util\n"
+        "events = list(EventFileLoader(sys.argv[1]).Load())\n"
+        "out = [[v.tag, e.step, float(tensor_util.make_ndarray(v.tensor))] for e in events for v in e.summary.value]\n"
+        "print(json.dumps({'version': events[0].file_version, 'scalars': out}))\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", script, path], capture_output=True, text=True, timeout=600
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    said = json.loads(out.stdout.strip().splitlines()[-1])
+    assert said["version"] == "brain.Event:2"
+    assert [tuple(s) for s in said["scalars"]] == MIRRORED
+
+
+def test_a_second_mirror_of_one_second_appends_without_a_second_header(tmp_path, monkeypatch):
+    monkeypatch.setattr(time, "time", lambda: 1790000000.25)
+    for step in (1, 2):
+        writer = MetricsWriter(str(tmp_path))
+        writer.write("train", step, {"loss": 2.0})
+        writer.close()
+    (path,) = glob.glob(str(tmp_path / "tensorboard" / "events*"))
+    events = _read_events(path)
+    assert [3 in e for e in events] == [True, False, False]
+    assert [e[2] for e in events] == [0, 1, 2]
 
 
 def test_a_group_can_stay_out_of_the_tensorboard_mirror(tmp_path):

@@ -134,3 +134,52 @@ def test_ops_modules_import_first_in_a_fresh_interpreter(module):
     tools/ragged_smoke.py from importing at all)."""
     out = _fresh(f"import {module}")
     assert out.returncode == 0, out.stderr
+
+
+@pytest.mark.parametrize("case, platforms, refusals, deadline_s", [
+    ("free_at_once", None, 0, 30.0),
+    ("opens_after_refusals", None, 3, 30.0),
+    ("asked_for_the_tpu_by_name", "tpu,cpu", 3, 30.0),
+    ("never_opens", None, 10**9, 0.4),
+    ("no_group_directory", None, 0, 30.0),
+    ("told_to_stay_on_the_cpu", "cpu", 10**9, 30.0),
+])
+def test_a_worker_waits_for_chips_an_ended_process_still_holds(tmp_path, monkeypatch, case, platforms, refusals, deadline_s):
+    """A fake ``/dev/vfio``: two numbered groups that refuse ``open`` with
+    EBUSY a number of times (what a relaunched worker finds for some
+    seconds after the kill of the one before it: PR 43), and the
+    container's own entry ``vfio``, which is no chip's and is never tried."""
+    import errno
+
+    from elasticdl_tpu.common import platform
+
+    vfio = tmp_path / "vfio"
+    if case != "no_group_directory":
+        vfio.mkdir()
+        for name in ("0", "1", "vfio"):
+            (vfio / name).write_text("")
+    tried, real_open = {}, os.open
+
+    def fake_open(path, flags, *args, **kwargs):
+        if str(path).startswith(str(vfio) + os.sep):
+            name = os.path.basename(path)
+            tried[name] = tried.get(name, 0) + 1
+            assert flags == os.O_RDWR
+            if tried[name] <= refusals:
+                raise OSError(errno.EBUSY, "Device or resource busy", str(path))
+        return real_open(path, flags, *args, **kwargs)
+
+    monkeypatch.setattr(os, "open", fake_open)
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    waited, still_busy = platform.wait_for_chips(str(vfio), deadline_s)
+    if case in ("opens_after_refusals", "asked_for_the_tpu_by_name"):
+        assert tried == {"0": 4, "1": 4} and 0.5 <= waited < 10.0 and still_busy == []
+    elif case == "never_opens":
+        assert deadline_s <= waited < 5.0 and still_busy == [str(vfio / "0"), str(vfio / "1")]
+    elif case == "free_at_once":
+        assert tried == {"0": 1, "1": 1} and waited < 0.25 and still_busy == []
+    else:
+        assert tried == {} and (waited, still_busy) == (0.0, [])
