@@ -156,13 +156,16 @@ EVA_COUNTERS = {
     "training steps and devices",
     "eva_pairs_summary": "(query, chunk summary) pairs of earlier windows, summed likewise",
 }
-#: The state-space layers' count a step reports, likewise (gauge
-#: ``edl_ssm_positions_total``): what the traffic asks of the scan, from
-#: shapes (the operator's measure of scanned work; no per-layer metric reads
-#: a constant of the shapes).
+#: The state-space layers' counts a step reports, likewise (gauges
+#: ``edl_ssm_positions*_total``): what the traffic asks of the scan, from
+#: shapes (the operator's measure of scanned work), and the part of it the
+#: scan's kernels took, each layer's counted where its scan is called
+#: (``_mamba_mixer``; no per-layer metric reads them yet: PERF.md section 7).
 SSM_COUNTERS = {
     "ssm_positions": "(head, position) pairs the state-space scans advanced a state over, from the "
     "shapes they were called with, summed over layers, training steps and devices",
+    "ssm_positions_kernel": "those of them whose chunks the scan's Pallas kernels computed (ops/ssm_kernels.py: "
+    "on a TPU inside their contract, decided when the step is traced), summed likewise",
 }
 LAYER_TYPES = ("moe", "dense")
 #: ``hybrid_override_pattern``'s letters (``-``, a dense MLP layer, is not one: no cell runs it)
@@ -422,7 +425,8 @@ def _relu2_mlp(u, w_up, w_down, site: str):
 
 def _mamba_mixer(u, blk, *, axis, eps, cast, state: int, chunk: int):
     """Mamba-2's mixer over the HELD heads and groups (read off ``A_log``
-    and the convolution's channels)."""
+    and the convolution's channels).  Returns (the part, the layer's
+    ``SSM_COUNTERS``)."""
     if axis is not None and axis_size(axis) > 1:
         raise ValueError("a state-space layer over a sharded sequence is not supported: the state at a shard's start lives on the shard before it")
     b, l, _ = u.shape
@@ -444,9 +448,12 @@ def _mamba_mixer(u, blk, *, axis, eps, cast, state: int, chunk: int):
         cm = xbc[..., inner + groups * state:].reshape(b, l, groups, state)
         dt = jax.nn.softplus(dt + blk["dt_bias"])
     y = ssm_ops.ssm_scan(x, dt, -jnp.exp(blk["A_log"]), bm, cm, blk["D"], chunk=chunk)
+    # counted where the scan is called, from what it was called with
+    by_kernels = ssm_ops.scan_path(x, bm, chunk)[0] != ssm_ops.PATH_XLA_REFERENCE
+    counts = {"ssm_positions": jnp.float32(b * l * heads), "ssm_positions_kernel": jnp.float32(b * l * heads * by_kernels)}
     y = ssm_ops.gated_group_norm(y.reshape(b, l, inner), z, blk["ssm_norm"], groups, eps)
     with jax.named_scope("ssm_proj"):
-        return y @ cast(blk["ssm_out"])
+        return y @ cast(blk["ssm_out"]), counts
 
 
 def _grouped_query_attention(u, blk, *, axis, cast, head_dim: int):
@@ -514,7 +521,8 @@ def _hybrid_layer(x, blk, *, axis, top_k, eps, compute_dtype, router, first_expe
     cast = lambda w: w.astype(compute_dtype)  # noqa: E731
     u = _rms_norm(cast(x), blk["norm"], eps)
     if "ssm_in" in blk:
-        return x + _mamba_mixer(u, blk, axis=axis, eps=eps, cast=cast, state=hybrid["state"], chunk=hybrid["chunk"]), None
+        y, counts = _mamba_mixer(u, blk, axis=axis, eps=eps, cast=cast, state=hybrid["state"], chunk=hybrid["chunk"])
+        return x + y, counts
     if "router" in blk:
         y, stats = _latent_moe(u, blk, top_k=top_k, router=router, first_expert_held=first_expert_held, cast=cast)
         return x + y, stats
@@ -594,11 +602,11 @@ def _apply(
         blocks = remat_lib.plan(
             block_fn, [(x, params["blocks"][name], positions) for name in names], ctx.remat_keep_bytes
         )
-    routed = []
+    routed, scanned = [], []
     for name, block in zip(names, blocks):
         x, stats = block(x, params["blocks"][name], positions)
         if stats is not None:
-            routed.append(stats)
+            (scanned if "ssm_positions" in stats else routed).append(stats)
     with jax.named_scope("lm_head"):
         norm_f = _gain(params["norm_f"], block_args["unit_offset"])
         x = _rms_norm(x.astype(compute_dtype), norm_f, block_args["eps"])
@@ -615,9 +623,8 @@ def _apply(
             "eva_pairs_exact": jnp.float32(scored * exact),
             "eva_pairs_summary": jnp.float32(scored * far),
         }
-    scanned = sum(blk["A_log"].shape[0] for blk in params["blocks"].values() if "A_log" in blk)  # heads, all layers
     if scanned:
-        out["ssm_counters"] = {"ssm_positions": jnp.float32(tokens.shape[0] * l * scanned)}
+        out["ssm_counters"] = jax.tree.map(lambda *leaves: sum(leaves), *scanned)  # all layers
     if routed:
         slots = jnp.stack([stats.pop("slots") for stats in routed])  # [expert layers, E]
         total = jax.tree.map(lambda *leaves: sum(leaves), *routed)

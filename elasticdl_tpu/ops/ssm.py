@@ -29,10 +29,38 @@ job) and accumulate in float32; the log-decays, their sums, the decay
 factors and the carried state are float32 whatever the operands are: a
 sequence's state is a product of L factors near 1.
 
-Everything here is XLA (einsums and fusions): PERF.md section 6, PR 40, has
-the trace that decided it.  Scopes: ``ssm_scan``, ``ssm_conv``,
-``ssm_norm``.  Each op has a plain ``*_reference`` (the scan position by
-position) that the tests hold it to.
+What runs where.  The arithmetic INSIDE a chunk (``_chunk_ends``,
+``_chunk_outputs`` and their transposes) has two implementations of the one
+algorithm, chosen by what a call can observe (``scan_path``; no flag):
+
+- the Pallas kernels of ``ops/ssm_kernels.py`` on a TPU inside their
+  contract (``outside_contract``: ``chunk`` and N whole multiples of 128, a
+  group's ``R * P`` lanes a multiple of 128, P whole sublanes; the published
+  widths are inside): a chunk's decay mask, its ``C B^T``, the masked weights
+  and the ``[Q, Q]`` gradient of the weights never leave VMEM (as XLA einsums
+  they were 134 MB a layer and pass through HBM: PERF.md section 6, PR 40
+  and PR 44);
+- the XLA einsums everywhere else: off the TPU (every CPU test and
+  rehearsal), chunks of 16, any width that is not whole lanes.
+
+Both call the SAME two seams around the chunks, forward and backward, and
+the kernels must keep calling them: ``_log_decays(dt, a, chunk)`` (XLA,
+float32; the kernels take the sums as an operand) and ``_carry(ends, decay,
+first, reverse)`` (the 64-step recurrence over the chunks, XLA; between the
+kernel that makes the chunks' end states and the one that reads the start
+states, and in reverse between the two backward kernels).  The benchmark's
+controls swap exactly these two by module attribute
+(``benchmark/configs/nemotron3_super_tp4_ep64_l11_reference.py:286-297``,
+``faults``: ``bfloat16_decay`` / ``all_bfloat16`` round the sums,
+``no_carried_state`` zeroes the start states) and ``correct`` is only as
+good as their bite: a kernel that summed the log-decays itself, or carried
+the state in VMEM scratch across a sequential grid axis (fewer bytes:
+PERF.md section 7), would disarm them in silence.  The seam's transpose is
+``jax.vjp`` of the seam, so a patched seam is differentiated as patched.
+
+Scopes: ``ssm_scan`` (forward and backward, either path), ``ssm_conv``,
+``ssm_norm`` (XLA).  Each op has a plain ``*_reference`` (the scan position
+by position) that the tests hold it to.
 """
 
 from __future__ import annotations
@@ -44,7 +72,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from elasticdl_tpu.ops import remat
+from elasticdl_tpu.ops import remat, ssm_kernels
+from elasticdl_tpu.ops.ring_attention import PATH_PALLAS_COMPILED, PATH_PALLAS_INTERPRET, PATH_XLA_REFERENCE, announce_path
 
 
 class Aux(NamedTuple):
@@ -129,10 +158,69 @@ def _carry(ends, decay, first, reverse: bool = False):
     return jnp.moveaxis(starts, 0, 1), last
 
 
-def _forward(x, dt, a, b, c, chunk: int, keep: bool = False):
-    ends, decay = _chunk_ends(x, dt, a, b, chunk)
-    starts, last = _carry(ends, decay, jnp.zeros_like(ends[:, 0]))
-    y = _chunk_outputs(x, dt, a, b, c, starts, chunk)
+def outside_contract(x, b, chunk: int) -> str:
+    """Why a scan of ``x`` [B, L, H, P] and ``b`` [B, L, G, N] is outside
+    the kernels' contract (``""``: inside): their tiles are whole lanes
+    (ops/ssm_kernels.py)."""
+    (heads, width), (groups, state) = x.shape[2:], b.shape[2:]
+    per = heads // groups
+    if chunk % 128 or state % 128:
+        return f"chunk {chunk} and state {state} are not whole multiples of 128"
+    if per * width % 128 or width % 8:
+        return f"a group's {per} heads of {width} are not whole lanes of 128 in whole sublanes of 8"
+    return ""
+
+
+def scan_path(x, b, chunk: int, interpret: Optional[bool] = None):
+    """Which path ``ssm_scan`` takes for ``x`` and ``b`` (their shapes are
+    read), from what the code can observe: ``(one of ring_attention's
+    PATH_*, why not the kernels)``.  The kernels compiled on a TPU inside
+    their contract, the einsums everywhere else; ``interpret`` given
+    (tests): the kernels, in the Pallas interpreter or compiled."""
+    outside = outside_contract(x, b, chunk)
+    if interpret is not None:
+        if outside:
+            raise ValueError(f"the scan's kernels were asked for outside their contract: {outside}")
+        return (PATH_PALLAS_INTERPRET if interpret else PATH_PALLAS_COMPILED), ""
+    backend = jax.default_backend()
+    why_not = f"backend={backend}" if backend != "tpu" else outside
+    return (PATH_XLA_REFERENCE if why_not else PATH_PALLAS_COMPILED), why_not
+
+
+def _kernel_operands(dt, a, groups: int, chunk: int):
+    """What the kernels read of ``dt`` and of the seam's log-decays: ``dt``
+    and the sums [B, n, G, R, Q] float32, and the sums again [B, n, G, Q, R]
+    (a [Q, Q] mask's rows and columns, neither transposed in a kernel)."""
+    by_group = lambda t: jnp.moveaxis(_grouped(t, groups), 2, 3)  # noqa: E731 — [B, n, Q, H] -> [B, n, G, Q, R]
+    cum_qr = by_group(_log_decays(dt, a, chunk))
+    return jnp.swapaxes(by_group(_by_chunks(dt.astype(jnp.float32), chunk)), -1, -2), jnp.swapaxes(cum_qr, -1, -2), cum_qr
+
+
+def _lanes(t):
+    return t.reshape(t.shape[0], t.shape[1], -1)
+
+
+def _forward_kernels(x, dt, a, b, c, chunk: int, interpret: bool):
+    """``_chunk_ends``, ``_carry``, ``_chunk_outputs`` with the chunks'
+    arithmetic in ops/ssm_kernels.py; the two seams are called as the XLA
+    path calls them (module docstring)."""
+    dt_rq, cum, cum_qr = _kernel_operands(dt, a, b.shape[2], chunk)
+    to_end = jnp.exp(cum[..., -1:] - cum) * dt_rq
+    ends = ssm_kernels.chunk_states(_lanes(x), to_end, _lanes(b), chunk=chunk, interpret=interpret)
+    starts, last = _carry(ends, jnp.exp(cum[..., -1]), jnp.zeros_like(ends[:, 0]))
+    y = ssm_kernels.chunk_outputs(_lanes(x), dt_rq, cum, cum_qr, _lanes(b), _lanes(c), starts, chunk=chunk, interpret=interpret)
+    return y.reshape(x.shape), last, starts
+
+
+def _forward(x, dt, a, b, c, chunk: int, keep: bool = False, interpret: Optional[bool] = None):
+    path, why_not = scan_path(x, b, chunk, interpret)
+    announce_path(path, x, True, f"ssm_scan groups={b.shape[2]} state={b.shape[3]} chunk={chunk}" + f"; {why_not}" * bool(why_not))
+    if path == PATH_XLA_REFERENCE:
+        ends, decay = _chunk_ends(x, dt, a, b, chunk)
+        starts, last = _carry(ends, decay, jnp.zeros_like(ends[:, 0]))
+        y = _chunk_outputs(x, dt, a, b, c, starts, chunk)
+    else:
+        y, last, starts = _forward_kernels(x, dt, a, b, c, chunk, path == PATH_PALLAS_INTERPRET)
     # A save site (ops/remat.py): a rematerialised block that keeps the
     # outputs and the chunks' states runs no second forward of the scan.
     work = scan_flops(x.shape[0], x.shape[1], x.shape[2], x.shape[3], b.shape[2], b.shape[3], chunk)
@@ -140,22 +228,54 @@ def _forward(x, dt, a, b, c, chunk: int, keep: bool = False):
     return y, last, starts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _scan(x, dt, a, b, c, chunk, keep):
-    y, last, _ = _forward(x, dt, a, b, c, chunk)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _scan(x, dt, a, b, c, chunk, keep, interpret=None):
+    y, last, _ = _forward(x, dt, a, b, c, chunk, interpret=interpret)
     return y, last
 
 
-def _scan_fwd(x, dt, a, b, c, chunk, keep):
-    y, last, starts = _forward(x, dt, a, b, c, chunk, keep)
+def _scan_fwd(x, dt, a, b, c, chunk, keep, interpret):
+    y, last, starts = _forward(x, dt, a, b, c, chunk, keep, interpret)
     return (y, last), (x, dt, a, b, c, starts)
 
 
-def _scan_bwd(chunk, keep, res, grads):
+def _chunk_grads_kernels(x, dt, a, b, c, starts, g_y, g_next, operands, chunk: int, interpret: bool):
+    """The chunks' own arithmetic transposed (the einsums' ``jax.vjp(chunks,
+    ...)``) by ops/ssm_kernels.py, from ``_kernel_operands``' ``operands``.
+    The transpose of the seam ``_log_decays`` is ``jax.vjp`` of the seam
+    itself: a patched seam is differentiated as patched."""
+    dt_rq, cum, cum_qr = operands
+    dx, db, dc, ddt_rq, dcum, dcum_qr = ssm_kernels.chunk_grads(
+        _lanes(x), _lanes(g_y), dt_rq, cum, cum_qr, _lanes(b), _lanes(c), starts, g_next, chunk=chunk, interpret=interpret)
+    by_position = lambda t: jnp.moveaxis(t, 2, 3).reshape(dt.shape[0], -1, chunk, dt.shape[2])  # noqa: E731 — [B, n, G, Q, R] -> [B, n, Q, H]
+    ddt, da = jax.vjp(lambda dt, a: _log_decays(dt, a, chunk), dt, a)[1](by_position(dcum_qr + jnp.swapaxes(dcum, -1, -2)))
+    ddt = ddt + by_position(jnp.swapaxes(ddt_rq, -1, -2)).reshape(dt.shape).astype(dt.dtype)
+    return dx.reshape(x.shape), ddt, da, db.astype(b.dtype).reshape(b.shape), dc.astype(c.dtype).reshape(c.shape)
+
+
+def _backward_kernels(x, dt, a, b, c, starts, g_y, g_last, chunk: int, interpret: bool):
+    """``_scan_bwd``'s three steps with the chunks' arithmetic in
+    ops/ssm_kernels.py, around the same ``_carry``."""
+    operands = _kernel_operands(dt, a, b.shape[2], chunk)
+    cum = operands[1]
+    from_y = ssm_kernels.chunk_states(_lanes(g_y), jnp.exp(cum), _lanes(c), chunk=chunk, interpret=interpret)
+    g_next, _ = _carry(from_y, jnp.exp(cum[..., -1]), g_last.astype(jnp.float32), reverse=True)
+    return _chunk_grads_kernels(x, dt, a, b, c, starts, g_y, g_next, operands, chunk, interpret)
+
+
+def _scan_bwd(chunk, keep, interpret, res, grads):
     x, dt, a, b, c, starts = res
     g_y, g_last = grads
     groups = b.shape[2]
+    path, _ = scan_path(x, b, chunk, interpret)
     with jax.named_scope("ssm_scan"):
+        # Three steps on either path: what y asks of the chunks' start
+        # states, that carried back over the chunks (``_carry`` in reverse),
+        # the chunks' own arithmetic transposed.  The kernels' path
+        # (``_backward_kernels``) is the first and the third as Pallas
+        # calls around the same ``_carry``; the einsums' is below.
+        if path != PATH_XLA_REFERENCE:
+            return _backward_kernels(x, dt, a, b, c, starts, g_y, g_last, chunk, path == PATH_PALLAS_INTERPRET)
         # What y asks of the state at each chunk's start ...
         cum = _grouped(_log_decays(dt, a, chunk), groups)
         weighted = (_grouped(_by_chunks(g_y, chunk), groups).astype(jnp.float32) * jnp.exp(cum)[..., None]).astype(x.dtype)

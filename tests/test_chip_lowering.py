@@ -762,7 +762,12 @@ def test_nemotron3_step_compiles_for_v5e_with_its_scopes_and_the_flash_kernels_l
     ``.mla`` metrics read are there; the attention layer is the three flash
     kernels at the operand lists ``flash_roofline_pct.tok`` reads (3 / 4 + 1
     / 4 + 2: the key/value head is repeated AHEAD of them), the forward
-    once; the chunked scan is XLA (no Mosaic call under ``ssm_scan``); the
+    once; the chunked scan's chunk arithmetic is the Mosaic calls of
+    ``ops/ssm_kernels.py`` under ``ssm_scan`` (a layer: the chunks' end
+    states and their outputs, the forward ONCE because the site is kept,
+    what ``y`` asks of the start states and the chunks' gradients), within
+    the scoped VMEM they ask for, and no [.., 128, 128] float32 decay mask
+    or masked weight is left among the step's buffers under the scope; the
     experts are grouped matmuls; and no [*, 8192, 8192] score matrix exists."""
     import json
     import os
@@ -796,7 +801,15 @@ def test_nemotron3_step_compiles_for_v5e_with_its_scopes_and_the_flash_kernels_l
     flash = _flash_calls(text)
     assert _signatures("\n".join(flash)) == [(3, 0), (4, 1), (4, 2)]  # the forward ONCE: its output is kept
     assert all("bf16[1,8192,1024]" in c for c in flash), flash[:1]  # 8 query heads of 128; K and V repeated to as many
-    assert not under("ssm_scan") and under("moe_experts") and len(under("moe_experts")) % 5 == 0
+    assert under("moe_experts") and len(under("moe_experts")) % 5 == 0
+    scan, m_layers = under("ssm_scan"), params["hybrid_override_pattern"].count("M")
+    kernels = {name: sum(f"%{name}" in c.split(" = ")[0] for c in scan) for name in ("ssm_chunk_states", "ssm_chunk_outputs", "ssm_chunk_grads")}
+    # a layer: ends + what y asks of the starts; the outputs (forward ONCE: the site is kept); the gradients
+    assert kernels == {"ssm_chunk_states": 2 * m_layers, "ssm_chunk_outputs": m_layers, "ssm_chunk_grads": m_layers} and len(scan) == 4 * m_layers
+    assert all("f32[1,64,2,16,64,128]" in c for c in scan if "ssm_chunk_outputs" not in c.split(" = ")[0])  # the states through HBM
+    scoped = [line for line in text.splitlines() if re.search(r'op_name="[^"]*\bssm_scan\b', line)]
+    assert scoped and not any(re.search(r"f32\[(\d+,)*128,128\]", line.split(" = ")[1].split("(")[0]) for line in scoped if " = " in line)
+    assert any("attention path: pallas-compiled" in line and "ssm_scan groups=2 state=128 chunk=128" in line for line in path_lines), path_lines
     # the patterns of the roofline entry the cell joined tell exactly these three apart
     with open(os.path.join(root, "benchmark", "metrics", "flash_roofline_pct.tok.json")) as f:
         patterns = [k["pattern"] for k in json.load(f)["params"]["kernels"]]

@@ -190,7 +190,9 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
             blk = params["blocks"]["b01"]
             assert blk["ssm_in"].shape == (32, 64 + (64 + 64) + 8)
             part = functools.partial(moe_lm._mamba_mixer, u, axis=None, eps=1e-5, cast=cast, state=8, chunk=16)
-            whole, parts = part(blk), [part(_share_of_mamba(blk, lo, 2)) for lo in (0, 2, 4, 6)]
+            (whole, counts), shares = part(blk), [part(_share_of_mamba(blk, lo, 2)) for lo in (0, 2, 4, 6)]
+            assert float(counts["ssm_positions"]) == sum(float(held["ssm_positions"]) for _, held in shares) == 2 * KEYS["seq_len"] * 8
+            parts = [got for got, _ in shares]
         elif kind == "*":
             blk = params["blocks"]["b04"]
             assert blk["wq"].shape == (32, 64) and blk["wk"].shape == (32, 16)
@@ -223,8 +225,30 @@ def test_the_step_counters_are_what_the_shapes_give():
     batch = _batch()
     metrics = spec.metrics(spec.apply(spec.init(jax.random.key(0)), batch), batch)
     assert float(metrics["ssm_positions"]) == 2 * 48 * (4 + 4)
+    assert float(metrics["ssm_positions_kernel"]) == 0  # the CPU's XLA path (and chunks of 16 are outside the kernels' contract)
     assert float(metrics["moe_slots"]) == 2 * 2 * 48 * 5
     assert float(metrics["moe_slots_computed"]) == float(metrics["moe_slots_held"]) <= float(metrics["moe_slots"])
+
+
+def test_the_kernel_counter_is_all_of_the_positions_where_the_scans_kernels_run(monkeypatch):
+    """Two M layers inside the kernels' contract (ops/ssm.outside_contract):
+    off the TPU the einsums run and ``ssm_positions_kernel`` is 0; where the
+    backend says TPU the kernels run (here Pallas's TPU interpreter stands in
+    for the chip), it is all of ``ssm_positions``, counted where each scan is
+    called, and the model computes the same logits."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    spec = _spec(num_hidden_layers=2, hybrid_override_pattern="MM", mamba_num_heads=4, mamba_heads_held=4, mamba_head_dim=64,
+                 n_groups=2, ssm_state_size=128, chunk_size=128, seq_len=256)
+    params, batch = _weights(spec), _batch(b=1, l=256)
+    read = lambda: (lambda out: (out["logits"], spec.metrics(out, batch)))(spec.apply(params, batch))  # noqa: E731
+    logits, metrics = read()
+    assert float(metrics["ssm_positions"]) == 256 * (4 + 4) and float(metrics["ssm_positions_kernel"]) == 0
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        by_kernels, metrics = read()
+    assert float(metrics["ssm_positions_kernel"]) == float(metrics["ssm_positions"]) == 256 * (4 + 4)
+    np.testing.assert_allclose(by_kernels, logits, rtol=2e-4, atol=2e-4)
 
 
 def test_adamw_decays_the_matrices_alone_and_the_job_trains():
