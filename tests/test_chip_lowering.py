@@ -875,7 +875,7 @@ def test_gpt2_medium_lowered_step_is_the_pinned_program():
     assert sha == GPT2_MEDIUM_STEP_SHA256
 
 
-#: sha256 of ``olmoe_job``'s and ``kanana2_job``'s steps lowered for the chip
+#: sha256 of the four ``moe_lm`` cells' steps lowered for the chip
 #: from their configuration and traffic files (StableHLO text, lowered from
 #: the CPU as ``GPT2_MEDIUM_STEP_SHA256`` is: the attention is the XLA
 #: reference, the kernels are not in the text).  PINNED in PR 37 at the
@@ -886,6 +886,9 @@ def test_gpt2_medium_lowered_step_is_the_pinned_program():
 MOE_LM_STEP_SHA256 = {
     ("olmoe_1b_7b_l1", "job_seq4k"): "6610fc1c02aea4649af2155ba6b4899fa16010f6156736d9da8378fde6ca8792",
     ("kanana2_30b_a3b_ep8_l5", "job_seq8k"): "1853512d7a5e8eb7375aca1b6187ee2f8d6d66976aab2ee556e22afc4dd5438e",
+    # PINNED in PR 45 at the values its PARENT commit (9611bdf) gives, ahead of parting the file along its layers
+    ("evabyte_6b5_tp2_l4", "job_seq16k"): "87b44ef8533274d8216ec8aaeaaeb214522f60bb60cad971e1e29df4bf74797a",
+    ("nemotron3_super_tp4_ep64_l11", "job_seq8k_x1"): "8c69791bf49f57535a34227915cc3bde193bb61cdbe82ca9b0fdda3a4a6edc2e",
 }
 
 
