@@ -1,0 +1,249 @@
+"""The attentions of ``moe_lm``'s layers (``models/parts.py``: what a part
+is), with ``rmsnorm(x, g) = x * rsqrt(mean(x^2) + eps) * g`` and ``a`` the
+normed residual stream:
+
+OLMoE's (:class:`QKNormAttention`):
+
+    q, k, v = a Wq, a Wk, a Wv                            (no bias, no clip)
+    q   = rmsnorm(q, q_norm) ; k = rmsnorm(k, k_norm)     (over ALL heads' columns, before the split)
+    q, k = rope(q), rope(k)                               (per head, rotate-half pairing (i, i + hd/2), theta)
+    part = causal_attention(q, k, v) Wo                   (scores / sqrt(hd))
+
+DeepSeek-V3's (:class:`LatentAttention`: ``transformers``' ``DeepseekV3``
+modules, ``q_lora_rank`` null), H heads, ``nope`` / ``rot`` / ``v`` =
+``qk_nope_head_dim`` / ``qk_rope_head_dim`` / ``v_head_dim``:
+
+    q      = a Wq                 -> [T, H, nope + rot] = (q_nope, q_rot)
+    (c, k_rot) = a Wkv_a          -> c [T, kv_lora_rank], k_rot [T, rot]: ONE rotary key for all heads
+    (k_nope, v) = rmsnorm(c, kv_norm) Wkv_b   -> [T, H, nope], [T, H, v]
+    q_rot, k_rot = rope(q_rot), rope(k_rot)   (the rot columns only; ``rope_interleave``: pairs (2i, 2i + 1))
+    s_h    = (q_nope_h . k_nope_h + q_rot_h . k_rot) * (nope + rot)^-0.5 ; causal softmax ; o_h = p_h v_h
+    part   = o Wo
+
+EvaByte's (:class:`EvaAttention`), ``held`` of the published heads here (one
+chip's share under head parallelism: wq / wk / wv ``[d, held * hd]``, wo
+``[held * hd, d]``; what the other heads would add to ``o Wo`` is left out):
+
+    q, k = rope(a Wq), rope(a Wk) ; v = a Wv   (no QK-norm)
+    part = eva_attention(q, k, v, eva_phi, eva_mu) Wo      (``ops/eva_attention``: exact inside the
+                                               query's ``window_size``, one learned summary a
+                                               ``chunk_size`` of every earlier window, ONE softmax)
+
+``nemotron_h``'s (:class:`GroupedQueryAttention`), no position signal:
+
+    q = a Wq (``q_heads`` heads of ``head_dim``), k, v = a Wk, a Wv (``kv_heads`` heads), the HELD heads;
+    query head h reads key/value head h // (heads / kv heads); causal softmax of q k / sqrt(head_dim); part = o Wo
+
+Over a sharded sequence the first two and the last run over the ring
+(``ops/ring_attention``) with global rotary positions; EVA refuses one.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+from elasticdl_tpu.common.jax_compat import axis_size
+from elasticdl_tpu.models.parts import Draws, Part, rms_norm
+from elasticdl_tpu.ops import eva_attention as eva_ops
+from elasticdl_tpu.ops import remat as remat_lib
+from elasticdl_tpu.ops.ring_attention import ring_attention
+
+#: EVA attention's counts a step reports (``ModelSpec.step_counters``: summed
+#: over devices by the trainer and over steps by the worker; gauges
+#: ``edl_eva_pairs_*_total``): what the TRAFFIC asks of the attention, a
+#: function of the shapes it was called with and of nothing the kernels do.
+EVA_COUNTERS = {
+    "eva_pairs_exact": "(query, key) pairs of a query's own window that the steps' queries were "
+    "scored against, from the shapes the attention was called with, summed over heads, layers, "
+    "training steps and devices",
+    "eva_pairs_summary": "(query, chunk summary) pairs of earlier windows, summed likewise",
+}
+
+
+def _qk_norm(x, scale, eps):
+    """OLMoE's QK-norm: over ALL of the projection's columns (every head's),
+    before the split into heads — not a norm per head."""
+    return rms_norm(x, scale, eps)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions on ``x`` [B, L, H, hd]: element ``i`` of a head is
+    paired with ``i + hd/2`` and the pair turned by ``positions * theta^
+    (-2i/hd)``.  Float32 arithmetic, one downcast."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # [L, half]
+    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
+
+
+def _rotary_columns(w: jax.Array, interleave: bool) -> jax.Array:
+    """The rotary output columns of a projection ``w`` [..., rot] in the
+    order :func:`rope` pairs them, (i, i + rot/2).  A model that pairs
+    (2i, 2i + 1) (``rope_interleave``) has its even columns moved to the
+    first half here, on the WEIGHT: the same permutation of q_rot and k_rot
+    leaves every score as it is, and no activation is shuffled."""
+    if not interleave:
+        return w
+    # a transpose, whose gradient is a transpose (two strided slices'
+    # gradient is a scatter of rows, one at a time on the TPU)
+    pairs = w.reshape(w.shape[:-1] + (w.shape[-1] // 2, 2))
+    return jnp.swapaxes(pairs, -1, -2).reshape(w.shape)
+
+
+@dataclasses.dataclass(frozen=True)
+class QKNormAttention(Part):
+    """OLMoE's: three projections, whole-width QK-norm, rotate-half rope
+    over the whole head."""
+
+    n_heads: int
+    theta: float
+    eps: float
+
+    def init(self, draw: Draws, d: int):
+        return {
+            "wq": draw.normal((d, d)), "wk": draw.normal((d, d)), "wv": draw.normal((d, d)),
+            "wo": draw.normal((d, d)),
+            "q_norm": jnp.ones((d,), jnp.float32),
+            "k_norm": jnp.ones((d,), jnp.float32),
+        }
+
+    def apply(self, a, blk, positions, axis, cast):
+        b, l, dim = a.shape
+        q = _qk_norm(a @ cast(blk["wq"]), blk["q_norm"], self.eps)
+        k = _qk_norm(a @ cast(blk["wk"]), blk["k_norm"], self.eps)
+        v = a @ cast(blk["wv"])
+        heads = lambda t: t.reshape(b, l, self.n_heads, dim // self.n_heads)  # noqa: E731
+        q, k = rope(heads(q), positions, self.theta), rope(heads(k), positions, self.theta)
+        att = ring_attention(q, k, heads(v), axis_name=axis, causal=True)
+        return att.reshape(b, l, dim) @ cast(blk["wo"]), None
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention(Part):
+    """DeepSeek-V3's (module docstring): keys and values through a latent
+    ``rank`` wide, ``rot`` rotary columns a head of q against ONE shared
+    rotary key.  Each projection is multiplied by its own column block of
+    the published matrix (a slice of the WEIGHT): every product is then born
+    in the layout the attention kernels read, [B, L, H * width], with no
+    slice of an activation in between."""
+
+    n_heads: int
+    rank: int
+    nope: int
+    rot: int
+    v: int
+    theta: float
+    eps: float
+    interleave: bool
+
+    def init(self, draw: Draws, d: int):
+        # The published shapes: a head's columns of wq are (nope | rot),
+        # of wkv_b (nope | v); wkv_a's are (the latent | the rotary key).
+        return {
+            "wq": draw.normal((d, self.n_heads * (self.nope + self.rot))),
+            "wkv_a": draw.normal((d, self.rank + self.rot)),
+            "kv_norm": jnp.ones((self.rank,), jnp.float32),
+            "wkv_b": draw.normal((self.rank, self.n_heads * (self.nope + self.v))),
+            "wo": draw.normal((self.n_heads * self.v, d)),
+        }
+
+    def apply(self, a, blk, positions, axis, cast):
+        b, l, dim = a.shape
+        n_heads, rank, nope = self.n_heads, self.rank, self.nope
+        with jax.named_scope("mla_proj"):
+            wq = blk["wq"].reshape(dim, n_heads, -1)
+            wkv_b = blk["wkv_b"].reshape(rank, n_heads, -1)
+            columns = lambda w: cast(w.reshape(w.shape[0], -1))  # noqa: E731
+            heads = lambda t: t.reshape(b, l, n_heads, -1)  # noqa: E731
+            q = heads(a @ columns(wq[..., :nope]))
+            q_rot = heads(a @ columns(_rotary_columns(wq[..., nope:], self.interleave)))
+            c = rms_norm(a @ cast(blk["wkv_a"][:, :rank]), blk["kv_norm"], self.eps)
+            k_rot = a @ cast(_rotary_columns(blk["wkv_a"][:, rank:], self.interleave))
+            k, v = heads(c @ columns(wkv_b[..., :nope])), heads(c @ columns(wkv_b[..., nope:]))
+            q_rot = rope(q_rot, positions, self.theta)
+            k_rot = rope(k_rot[:, :, None, :], positions, self.theta)[:, :, 0]
+        att = ring_attention(q, k, v, axis_name=axis, causal=True, q_rot=q_rot, k_rot=k_rot)
+        with jax.named_scope("mla_proj"):
+            return att.reshape(b, l, -1) @ cast(blk["wo"]), None
+
+
+@dataclasses.dataclass(frozen=True)
+class EvaAttention(Part):
+    """EvaByte's: three projections onto the ``held`` heads, rotate-half
+    rope over the whole head, ``ops/eva_attention``."""
+
+    held: int
+    head_dim: int
+    theta: float
+    window: int
+    chunk: int
+
+    counters = EVA_COUNTERS
+
+    def init(self, draw: Draws, d: int):
+        held, hd = self.held, self.head_dim
+        return {
+            "wq": draw.normal((d, held * hd)), "wk": draw.normal((d, held * hd)),
+            "wv": draw.normal((d, held * hd)), "wo": draw.normal((held * hd, d)),
+            "eva_phi": jnp.zeros((held, hd), jnp.float32),
+            "eva_mu": jnp.zeros((held, hd), jnp.float32),
+        }
+
+    def apply(self, a, blk, positions, axis, cast):
+        if axis is not None and axis_size(axis) > 1:
+            raise ValueError("EVA attention over a sharded sequence is not supported: the summaries of earlier windows live on other shards")
+        b, l, _ = a.shape
+        held, hd = self.held, self.head_dim
+        heads = lambda t: t.reshape(b, l, held, hd)  # noqa: E731
+        with jax.named_scope("eva_proj"):
+            # save sites (ops/remat.py): each projection as the attention reads it
+            wq, wk, wv = cast(blk["wq"]), cast(blk["wk"]), cast(blk["wv"])
+            q = remat_lib.site("q", 2 * a.size * wq.shape[1], rope(heads(a @ wq), positions, self.theta))
+            k = remat_lib.site("k", 2 * a.size * wk.shape[1], rope(heads(a @ wk), positions, self.theta))
+            v = heads(remat_lib.product("v", a, wv))
+        att = eva_ops.eva_attention(q, k, v, blk["eva_phi"], blk["eva_mu"], window=self.window, chunk=self.chunk)
+        with jax.named_scope("eva_proj"):
+            return att.reshape(b, l, held * hd) @ cast(blk["wo"]), None
+
+    def shape_counts(self, batch: int, length: int):
+        exact, far = eva_ops.pairs(length, self.window, self.chunk)  # a head's, of one sequence
+        return {"eva_pairs_exact": batch * self.held * exact, "eva_pairs_summary": batch * self.held * far}
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedQueryAttention(Part):
+    """Attention whose key/value heads are fewer than its query heads
+    (query head h reads key/value head ``h // group``), over the HELD heads;
+    no position signal.  The key/value heads are REPEATED to the queries'
+    ahead of the attention (PERF.md section 7: the flash kernels' contract
+    wants as many; one layer in eleven).  ``into_stream`` scales ``wo``'s
+    draw (``rescale_prenorm_residual``)."""
+
+    q_heads: int
+    kv_heads: int
+    head_dim: int
+    into_stream: float = 1.0
+
+    def init(self, draw: Draws, d: int):
+        q, kv = self.q_heads * self.head_dim, self.kv_heads * self.head_dim
+        return {
+            "wq": draw.normal((d, q)), "wk": draw.normal((d, kv)),
+            "wv": draw.normal((d, kv)), "wo": draw.normal((q, d), self.into_stream),
+        }
+
+    def apply(self, u, blk, positions, axis, cast):
+        b, l, _ = u.shape
+        with jax.named_scope("attn_proj"):
+            heads = lambda t: t.reshape(b, l, -1, self.head_dim)  # noqa: E731
+            q, k, v = (heads(remat_lib.product(name, u, cast(blk["w" + name]))) for name in ("q", "k", "v"))
+            group = self.q_heads // self.kv_heads
+            if group > 1:
+                k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        att = ring_attention(q, k, v, axis_name=axis, causal=True)
+        with jax.named_scope("attn_proj"):
+            return att.reshape(b, l, -1) @ cast(blk["wo"]), None

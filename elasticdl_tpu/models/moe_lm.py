@@ -1,48 +1,40 @@
-"""Decoder-only LM with a modern block: rotary positions, multi-head
-attention with QK-norm or latent attention, a gated (SwiGLU) feed-forward
-that is dense or a dropless mixture of experts layer by layer — with or
+"""Decoder-only LM whose layers are lists of PARTS (``models/parts.py``): an
+attention or a state-space mixer (``models/attentions.py``,
+``models/mamba.py``) and a feed-forward that is dense or a dropless mixture
+of experts layer by layer (here, beside the router's losses) — with or
 without shared experts and a router correction bias — and a tied or untied
-head.  ONE decoder that adapts to the published keys, which are
-``model_spec``'s arguments: its defaults and parameter names are OLMoE's
-(``OLMoE-1B-7B-0125``: every layer ``moe``, 64 experts, 8 a token, softmax
-router, untied head); ``kv_lora_rank`` > 0 and the ``deepseek_v3`` keys make
-it DeepSeek-V3's block (``kanana-2-30b-a3b``: below).
+head.  ONE decoder: ``model_spec``'s arguments are the published keys, from
+which it picks a FAMILY, whose builder turns its own keys into the layer
+list that the one init, the one block and the counters all read.  The
+defaults and parameter names are OLMoE's (``OLMoE-1B-7B-0125``: every layer
+``moe``, 64 experts, 8 a token, softmax router, untied head);
+``kv_lora_rank`` > 0 makes it DeepSeek-V3's block (``kanana-2-30b-a3b``),
+``attention_class`` ``"eva"`` EvaByte's, ``hybrid_override_pattern``
+``nemotron_h``'s.
 
-The block, with ``rmsnorm(x, g) = x * rsqrt(mean(x^2) + eps) * g``:
+The model, with ``rmsnorm(x, g) = x * rsqrt(mean(x^2) + eps) * g``:
 
     x   = tok_emb[tokens]                                 (no position table)
-    a   = rmsnorm(x, attn_norm)
-    q, k, v = a Wq, a Wk, a Wv                            (no bias, no clip)
-    q   = rmsnorm(q, q_norm) ; k = rmsnorm(k, k_norm)     (over ALL heads' columns, before the split)
-    q, k = rope(q), rope(k)                               (per head, rotate-half pairing (i, i + hd/2), theta)
-    x  += causal_attention(q, k, v) Wo                    (scores / sqrt(hd))
-    u   = rmsnorm(x, ffn_norm)
-    moe:    r = u Wg ; p = softmax(r) ; (w_i, e_i) = top-k of p   (float32; NOT renormalised)
-            x += sum_i w_i * (silu(u Wgate[e_i]) * (u Wup[e_i])) Wdown[e_i]
-    dense:  x += (silu(u Wgate) * (u Wup)) Wdown
+    for each layer, for each (norm, part) of it:  x += part(rmsnorm(x, norm))
     logits = rmsnorm(x, norm_f) Whead                     (Whead = tok_emb^T when tied; float32 logits)
     loss = CE(logits, next token) + lb_coef * LB + z_coef * Z
     LB  = E * sum_{i, e} f[i, e] * P[e],   f[i, e] = share of (layer, token) pairs whose i-th choice is e,
                                            P[e] = mean over (layer, token) pairs of p[e]
     Z   = mean over (layer, token) pairs of logsumexp(r)^2
 
-With ``kv_lora_rank`` > 0 (``transformers``' ``DeepseekV3`` modules,
-``q_lora_rank`` null) the attention and the expert layer read instead, H
-heads, ``nope`` / ``rot`` / ``v`` = ``qk_nope_head_dim`` /
-``qk_rope_head_dim`` / ``v_head_dim``:
+OLMoE's, DeepSeek-V3's and EvaByte's layers are ``(attn_norm, an
+attention), (ffn_norm, a feed-forward)``; the feed-forwards, ``u`` the
+normed stream:
 
-    q      = a Wq                 -> [T, H, nope + rot] = (q_nope, q_rot)
-    (c, k_rot) = a Wkv_a          -> c [T, kv_lora_rank], k_rot [T, rot]: ONE rotary key for all heads
-    (k_nope, v) = rmsnorm(c, kv_norm) Wkv_b   -> [T, H, nope], [T, H, v]
-    q_rot, k_rot = rope(q_rot), rope(k_rot)   (the rot columns only; ``rope_interleave``: pairs (2i, 2i + 1))
-    s_h    = (q_nope_h . k_nope_h + q_rot_h . k_rot) * (nope + rot)^-0.5 ; causal softmax ; o_h = p_h v_h
-    x     += o Wo
-    layers < first_k_dense_replace: dense, ``intermediate_size`` wide
-    others: r = u Wg (float32) ; s = sigmoid(r)                  (``scoring_func``)
+    dense:  (silu(u Wgate) * (u Wup)) Wdown                      ``intermediate_size`` wide
+    moe:    r = u Wg ; p = softmax(r) ; (w_i, e_i) = top-k of p   (float32; NOT renormalised)
+            sum_i w_i * (silu(u Wgate[e_i]) * (u Wup[e_i])) Wdown[e_i]
+    moe under the ``deepseek_v3`` keys (layers < ``first_k_dense_replace`` are dense):
+            r = u Wg (float32) ; s = sigmoid(r)                  (``scoring_func``)
             e_1..e_k = top-k of (s + b)        b = the layer's correction bias (``topk_method`` noaux_tc):
                                                it chooses, it never weighs
             w_i = s[e_i] / (sum_j s[e_j] + 1e-20) * routed_scaling_factor      (``norm_topk_prob``)
-            x += sum_i w_i * expert_{e_i}(u) + shared(u)         experts ``moe_intermediate_size`` wide;
+            sum_i w_i * expert_{e_i}(u) + shared(u)              experts ``moe_intermediate_size`` wide;
                                                shared = ONE gated MLP ``n_shared_experts`` times as wide
     after every step, by the model's own rule (``ModelSpec.after_update``; no gradient, no weight decay):
             b_e += bias_update_speed * sign(mean_e'(c_e') - c_e),   c_e = slots the step sent to expert e
@@ -55,41 +47,21 @@ the router keeps its width, the layer computes its own experts' part
 ``LB`` is ``transformers``' ``load_balancing_loss_func`` (the layers'
 router outputs concatenated, one ``f`` and one ``P`` for the model); ``Z``
 is the OLMoE paper's router z-loss.  ``apply`` returns what ``loss`` and
-``metrics`` need — logits, ``f``, ``P``, ``Z`` and the expert layers' slot
-counts: a small pytree, no second forward.
+``metrics`` need — logits, ``f``, ``P``, ``Z``, the expert layers' slot
+counts and the parts' step counters: a small pytree, no second forward.
 
-With ``attention_class`` ``"eva"`` (EvaByte's keys) the block reads instead,
-``heads_held`` of the ``num_attention_heads`` published heads here (one
-chip's share under head parallelism: wq / wk / wv ``[d, held * hd]``, wo
-``[held * hd, d]``; what the other heads would add to ``o Wo`` is left out):
+EvaByte's keys besides its attention: the residual stream is float32
+(``fp32_skip_add``: a part reads it cast to bfloat16 and its output is added
+in float32), every norm multiplies by 1 + its gain (``norm_add_unit_offset``:
+the gains start at 0), and the head is ``[d, num_pred_heads x vocab]``: head
+p at position i predicts token i + 1 + p, the loss the mean over the heads
+of each head's mean CE over the positions whose target the record holds.
 
-    h    = tok_emb[tokens]                     float32 (``fp32_skip_add``): a block reads it cast to
-                                               bfloat16 and adds its output in float32
-    a    = rmsnorm(h, 1 + attn_norm)           (``norm_add_unit_offset``: the gains start at 0)
-    q, k = rope(a Wq), rope(a Wk) ; v = a Wv   (no QK-norm)
-    h   += eva_attention(q, k, v, eva_phi, eva_mu) Wo      (``ops/eva_attention``: exact inside the
-                                               query's ``window_size``, one learned summary a
-                                               ``chunk_size`` of every earlier window, ONE softmax)
-    h   += gated MLP(rmsnorm(h, 1 + ffn_norm))
-    logits = rmsnorm(h, 1 + norm_f) Whead      Whead [d, num_pred_heads x vocab]: head p at position i
-                                               predicts token i + 1 + p
-    loss = mean over the heads of each head's mean CE over the positions whose target the record holds
+With ``hybrid_override_pattern`` (``nemotron_h``'s keys) a layer is ONE
+``(norm, part)``, its kind a letter of the pattern — ``M`` a Mamba-2 mixer,
+``*`` grouped-query attention with no rotary turn, ``E`` a LatentMoE:
 
-With ``hybrid_override_pattern`` (``nemotron_h``'s keys) a layer is ONE norm,
-ONE mixer or feed-forward part and one residual, its kind a letter of the
-pattern (``M`` / ``*`` / ``E``; the kind is read off the layer's parameters),
-with no position table and no rotary turn:
-
-    u  = rmsnorm(x, norm) ;  x += part(u)
-    M (Mamba-2, ``ops/ssm``; H heads of P in G groups, state N, HELD: ``mamba_heads_held`` heads and their groups):
-        (z, xBC, dt) = u W_in                  columns (H P | H P + 2 G N | H) of the held heads and groups
-        (x, B, C) = silu(conv(xBC))            causal, depthwise, ``conv_kernel`` taps and a bias a channel
-        dt = softplus(dt + dt_bias) ; a_t = exp(-exp(A_log) dt_t)
-        S_t = a_t S_{t-1} + dt_t x_t B_t^T ; y_t = S_t C_t + D x_t      S_0 = 0 at each sequence's start
-        part = rmsnorm over each GROUP's channels of (y * silu(z)), times a gain, then W_out
-    * : q = u Wq (``heads_held`` heads of ``head_dim``), k, v = u Wk, u Wv (``kv_heads_held`` heads);
-        query head h reads key/value head h // (heads / kv heads); causal softmax of q k / sqrt(head_dim); part = o Wo
-    E (LatentMoE): r = u Wg (float32), s = sigmoid(r), top-k of s + b, w_i = scaling x s[e_i] / sum_j s[e_j]
+    E : r = u Wg (float32), s = sigmoid(r), top-k of s + b, w_i = scaling x s[e_i] / sum_j s[e_j]
         c = u W_down_lat                       [``moe_latent_size``]
         part = (sum_i w_i relu(c W1[e_i])^2 W2[e_i]) W_up_lat + relu(u Ws1)^2 Ws2     (``mlp_hidden_act`` relu2:
                                                experts and the shared expert are TWO matrices each)
@@ -113,23 +85,26 @@ arithmetic, logits and losses in float32.
 
 from __future__ import annotations
 
+import collections
+import dataclasses
 import functools
-from typing import Any, Dict, Optional, Sequence
+import inspect
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
 from jax import lax
 
-from elasticdl_tpu.common.jax_compat import axis_size
 from elasticdl_tpu.data.codecs import lm_feed
+from elasticdl_tpu.models.attentions import EvaAttention, GroupedQueryAttention, LatentAttention, QKNormAttention
+from elasticdl_tpu.models.mamba import MambaMixer
+from elasticdl_tpu.models.parts import Draws, Part
+from elasticdl_tpu.models.parts import rms_norm as _rms_norm  # looked up HERE by the block and the head (the references tap it)
 from elasticdl_tpu.models.spec import ModelSpec
-from elasticdl_tpu.ops import eva_attention as eva_ops
 from elasticdl_tpu.ops import moe
 from elasticdl_tpu.ops import remat as remat_lib
-from elasticdl_tpu.ops import ssm as ssm_ops
 from elasticdl_tpu.ops.embedding import ParallelContext
-from elasticdl_tpu.ops.ring_attention import ring_attention
 
 #: The expert layers' counts a step reports (``ModelSpec.step_counters``:
 #: summed over devices by the trainer and over steps by the worker), with
@@ -147,268 +122,19 @@ MOE_COUNTERS = {
     "over expert layers, training steps and devices",
     "moe_expert_load_mean": "slots on a device's average held expert, summed likewise",
 }
-#: EVA attention's counts a step reports, likewise (gauges ``edl_eva_pairs_*_total``):
-#: what the TRAFFIC asks of the attention, a function of the shapes it was
-#: called with and of nothing the kernels do.
-EVA_COUNTERS = {
-    "eva_pairs_exact": "(query, key) pairs of a query's own window that the steps' queries were "
-    "scored against, from the shapes the attention was called with, summed over heads, layers, "
-    "training steps and devices",
-    "eva_pairs_summary": "(query, chunk summary) pairs of earlier windows, summed likewise",
-}
-#: The state-space layers' counts a step reports, likewise (gauges
-#: ``edl_ssm_positions*_total``): what the traffic asks of the scan, from
-#: shapes (the operator's measure of scanned work), and the part of it the
-#: scan's kernels took, each layer's counted where its scan is called
-#: (``_mamba_mixer``; no per-layer metric reads them yet: PERF.md section 7).
-SSM_COUNTERS = {
-    "ssm_positions": "(head, position) pairs the state-space scans advanced a state over, from the "
-    "shapes they were called with, summed over layers, training steps and devices",
-    "ssm_positions_kernel": "those of them whose chunks the scan's Pallas kernels computed (ops/ssm_kernels.py: "
-    "on a TPU inside their contract, decided when the step is traced), summed likewise",
-}
 LAYER_TYPES = ("moe", "dense")
 #: ``hybrid_override_pattern``'s letters (``-``, a dense MLP layer, is not one: no cell runs it)
 PATTERN_KINDS = {"M": "a Mamba-2 mixer", "*": "attention", "E": "a latent mixture of experts"}
 ATTENTION_CLASSES = ("mha", "eva")
 TOPK_METHODS = ("greedy", "noaux_tc")
 
-
-def _rms_norm(x, scale, eps):
-    # Statistics and arithmetic in f32, ONE downcast (transformer_lm's form).
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return ((x * lax.rsqrt(var + eps)) * scale).astype(x.dtype)
+#: a layer: its ``(norm's parameter name, part)`` in the order they are applied
+Layer = Tuple[Tuple[str, Part], ...]
 
 
 def _gain(g, unit_offset: bool):
     """What a norm multiplies by: the gain, or 1 + it (``norm_add_unit_offset``)."""
     return 1.0 + g if unit_offset else g
-
-
-def _qk_norm(x, scale, eps):
-    """OLMoE's QK-norm: over ALL of the projection's columns (every head's),
-    before the split into heads — not a norm per head."""
-    return _rms_norm(x, scale, eps)
-
-
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary positions on ``x`` [B, L, H, hd]: element ``i`` of a head is
-    paired with ``i + hd/2`` and the pair turned by ``positions * theta^
-    (-2i/hd)``.  Float32 arithmetic, one downcast."""
-    half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # [L, half]
-    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
-
-
-def _rotary_columns(w: jax.Array, interleave: bool) -> jax.Array:
-    """The rotary output columns of a projection ``w`` [..., rot] in the
-    order :func:`rope` pairs them, (i, i + rot/2).  A model that pairs
-    (2i, 2i + 1) (``rope_interleave``) has its even columns moved to the
-    first half here, on the WEIGHT: the same permutation of q_rot and k_rot
-    leaves every score as it is, and no activation is shuffled."""
-    if not interleave:
-        return w
-    # a transpose, whose gradient is a transpose (two strided slices'
-    # gradient is a scatter of rows, one at a time on the TPU)
-    pairs = w.reshape(w.shape[:-1] + (w.shape[-1] // 2, 2))
-    return jnp.swapaxes(pairs, -1, -2).reshape(w.shape)
-
-
-def _init_params(
-    rng, vocab_size: int, hidden_size: int, intermediate_size: int, num_experts: int,
-    layer_types: Sequence[str], tie_word_embeddings: bool, init_std: float = 0.02,
-    *, n_heads: int = 0, latent: Optional[Dict[str, int]] = None, moe_intermediate_size: int = 0,
-    experts_held: int = 0, n_shared_experts: int = 0, correction_bias: bool = False,
-    eva_heads: int = 0, unit_offset: bool = False, num_pred_heads: int = 1,
-) -> Dict[str, Any]:
-    """``latent`` (``kv_lora_rank``, ``nope``, ``rot``, ``v``) makes every
-    layer's attention latent; the experts are ``moe_intermediate_size`` wide
-    (0: ``intermediate_size``, as the dense layers) and ``experts_held`` of
-    the router's ``num_experts`` are here (0: all).  ``eva_heads`` > 0
-    makes it EVA attention over that many HELD heads of ``n_heads``;
-    ``unit_offset`` starts every gain at 0 (the norms multiply by 1 + g)."""
-    d, f, e = hidden_size, intermediate_size, num_experts
-    gain = jnp.zeros if unit_offset else jnp.ones
-    f_moe, held = moe_intermediate_size or f, experts_held or e
-    # OLMoE's block draws 8 keys a layer; the draws below keep their order.
-    per_layer = 12 if latent or n_shared_experts else 8
-    ks = iter(jax.random.split(rng, 2 + per_layer * len(layer_types)))
-
-    def normal(shape):
-        return jax.random.normal(next(ks), shape, jnp.float32) * init_std
-
-    params: Dict[str, Any] = {
-        "tok_emb": normal((vocab_size, d)),
-        "norm_f": gain((d,), jnp.float32),
-        "blocks": {},
-    }
-    if not tie_word_embeddings:
-        params["head"] = normal((d, num_pred_heads * vocab_size))
-    for i, kind in enumerate(layer_types):
-        blk = {"attn_norm": gain((d,), jnp.float32)}
-        if eva_heads:
-            hd = d // n_heads
-            blk.update({
-                "wq": normal((d, eva_heads * hd)), "wk": normal((d, eva_heads * hd)),
-                "wv": normal((d, eva_heads * hd)), "wo": normal((eva_heads * hd, d)),
-                "eva_phi": jnp.zeros((eva_heads, hd), jnp.float32),
-                "eva_mu": jnp.zeros((eva_heads, hd), jnp.float32),
-            })
-        elif latent:
-            rank, nope, rot, v = (latent[key] for key in ("kv_lora_rank", "nope", "rot", "v"))
-            # The published shapes: a head's columns of wq are (nope | rot),
-            # of wkv_b (nope | v); wkv_a's are (the latent | the rotary key).
-            blk["wq"] = normal((d, n_heads * (nope + rot)))
-            blk["wkv_a"] = normal((d, rank + rot))
-            blk["kv_norm"] = jnp.ones((rank,), jnp.float32)
-            blk["wkv_b"] = normal((rank, n_heads * (nope + v)))
-            blk["wo"] = normal((n_heads * v, d))
-        else:
-            blk.update({
-                "wq": normal((d, d)), "wk": normal((d, d)), "wv": normal((d, d)),
-                "wo": normal((d, d)),
-                "q_norm": jnp.ones((d,), jnp.float32),
-                "k_norm": jnp.ones((d,), jnp.float32),
-            })
-        blk["ffn_norm"] = gain((d,), jnp.float32)
-        if kind == "moe":
-            blk["router"] = normal((d, e))
-            if correction_bias:
-                blk["router_bias"] = jnp.zeros((e,), jnp.float32)
-            blk["w_gate"], blk["w_up"] = normal((held, d, f_moe)), normal((held, d, f_moe))
-            blk["w_down"] = normal((held, f_moe, d))
-            if n_shared_experts:
-                f_shared = n_shared_experts * f_moe
-                blk["ws_gate"], blk["ws_up"] = normal((d, f_shared)), normal((d, f_shared))
-                blk["ws_down"] = normal((f_shared, d))
-        else:
-            blk["w_gate"], blk["w_up"] = normal((d, f)), normal((d, f))
-            blk["w_down"] = normal((f, d))
-        # Zero-padded names keep sorted() in layer order past nine layers.
-        params["blocks"][f"b{i:02d}"] = blk
-    return params
-
-
-def _init_hybrid_params(
-    rng, *, pattern: str, vocab_size: int, hidden_size: int, init_std: float, residual_layers: int,
-    mamba_heads: int, mamba_head_dim: int, groups: int, state: int, conv_kernel: int, dt_range,
-    q_heads: int, kv_heads: int, head_dim: int,
-    num_experts: int, experts_held: int, latent: int, expert_width: int, shared_width: int,
-) -> Dict[str, Any]:
-    """``nemotron_h``'s parameters, a layer's by its letter of ``pattern``;
-    every count is what is HELD here.  Matrices normal(0, ``init_std``),
-    those that write into the residual stream (``ssm_out``, ``wo``,
-    ``w_down``, ``ws_down``) scaled by ``residual_layers``^-1/2
-    (``rescale_prenorm_residual``); ``A_log`` = log uniform(1, 16), ``dt_bias``
-    the inverse softplus of a log-uniform draw in ``dt_range`` (min, max,
-    floor), ``D`` = 1; the taps and their bias uniform(+-``conv_kernel``^-1/2)."""
-    d = hidden_size
-    ks = iter(jax.random.split(rng, 2 + 8 * len(pattern)))
-    into_stream = residual_layers ** -0.5
-
-    def normal(shape, scale=1.0):
-        return jax.random.normal(next(ks), shape, jnp.float32) * (init_std * scale)
-
-    def uniform(shape, lo, hi):
-        return jax.random.uniform(next(ks), shape, jnp.float32, lo, hi)
-
-    params: Dict[str, Any] = {
-        "tok_emb": normal((vocab_size, d)), "norm_f": jnp.ones((d,), jnp.float32),
-        "head": normal((d, vocab_size)), "blocks": {},
-    }
-    for i, kind in enumerate(pattern):
-        blk: Dict[str, Any] = {"norm": jnp.ones((d,), jnp.float32)}
-        if kind == "M":
-            inner, conv_dim = mamba_heads * mamba_head_dim, mamba_heads * mamba_head_dim + 2 * groups * state
-            lo, hi, floor = dt_range
-            dt = jnp.maximum(jnp.exp(uniform((mamba_heads,), jnp.log(lo), jnp.log(hi))), floor)
-            blk.update({
-                "ssm_in": normal((d, inner + conv_dim + mamba_heads)),
-                "conv_w": uniform((conv_kernel, conv_dim), -conv_kernel ** -0.5, conv_kernel ** -0.5),
-                "conv_b": uniform((conv_dim,), -conv_kernel ** -0.5, conv_kernel ** -0.5),
-                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
-                "A_log": jnp.log(uniform((mamba_heads,), 1.0, 16.0)),
-                "D": jnp.ones((mamba_heads,), jnp.float32),
-                "ssm_norm": jnp.ones((inner,), jnp.float32),
-                "ssm_out": normal((inner, d), into_stream),
-            })
-        elif kind == "*":
-            blk.update({
-                "wq": normal((d, q_heads * head_dim)), "wk": normal((d, kv_heads * head_dim)),
-                "wv": normal((d, kv_heads * head_dim)), "wo": normal((q_heads * head_dim, d), into_stream),
-            })
-        else:
-            blk.update({
-                "router": normal((d, num_experts)), "router_bias": jnp.zeros((num_experts,), jnp.float32),
-                "w_lat_down": normal((d, latent)), "w_lat_up": normal((latent, d), into_stream),
-                "w_up": normal((experts_held, latent, expert_width)),
-                "w_down": normal((experts_held, expert_width, latent), into_stream),
-                "ws_up": normal((d, shared_width)), "ws_down": normal((shared_width, d), into_stream),
-            })
-        params["blocks"][f"b{i:02d}"] = blk
-    return params
-
-
-def _attention(a, blk, positions, *, axis, n_heads, theta, eps, cast):
-    """OLMoE's: three projections, whole-width QK-norm, rotate-half rope
-    over the whole head."""
-    b, l, dim = a.shape
-    q = _qk_norm(a @ cast(blk["wq"]), blk["q_norm"], eps)
-    k = _qk_norm(a @ cast(blk["wk"]), blk["k_norm"], eps)
-    v = a @ cast(blk["wv"])
-    heads = lambda t: t.reshape(b, l, n_heads, dim // n_heads)  # noqa: E731
-    q, k = rope(heads(q), positions, theta), rope(heads(k), positions, theta)
-    att = ring_attention(q, k, heads(v), axis_name=axis, causal=True)
-    return att.reshape(b, l, dim) @ cast(blk["wo"])
-
-
-def _eva_attention(a, blk, positions, *, axis, theta, cast, window, chunk):
-    """EvaByte's: three projections onto the HELD heads (read off ``eva_phi``),
-    rotate-half rope over the whole head, ``ops/eva_attention``."""
-    if axis is not None and axis_size(axis) > 1:
-        raise ValueError("EVA attention over a sharded sequence is not supported: the summaries of earlier windows live on other shards")
-    b, l, _ = a.shape
-    held, hd = blk["eva_phi"].shape
-    heads = lambda t: t.reshape(b, l, held, hd)  # noqa: E731
-    with jax.named_scope("eva_proj"):
-        # save sites (ops/remat.py): each projection as the attention reads it
-        wq, wk, wv = cast(blk["wq"]), cast(blk["wk"]), cast(blk["wv"])
-        q = remat_lib.site("q", 2 * a.size * wq.shape[1], rope(heads(a @ wq), positions, theta))
-        k = remat_lib.site("k", 2 * a.size * wk.shape[1], rope(heads(a @ wk), positions, theta))
-        v = heads(remat_lib.product("v", a, wv))
-    att = eva_ops.eva_attention(q, k, v, blk["eva_phi"], blk["eva_mu"], window=window, chunk=chunk)
-    with jax.named_scope("eva_proj"):
-        return att.reshape(b, l, held * hd) @ cast(blk["wo"])
-
-
-def _latent_attention(a, blk, positions, *, axis, n_heads, theta, eps, cast, rot, interleave):
-    """DeepSeek-V3's (module docstring): keys and values through a latent,
-    ``rot`` rotary columns a head of q against ONE shared rotary key.  The
-    widths are read off the parameters.  Each projection is multiplied by
-    its own column block of the published matrix (a slice of the WEIGHT):
-    every product is then born in the layout the attention kernels read,
-    [B, L, H * width], with no slice of an activation in between."""
-    b, l, dim = a.shape
-    rank = blk["kv_norm"].shape[0]
-    with jax.named_scope("mla_proj"):
-        wq = blk["wq"].reshape(dim, n_heads, -1)
-        nope = wq.shape[-1] - rot
-        wkv_b = blk["wkv_b"].reshape(rank, n_heads, -1)
-        columns = lambda w: cast(w.reshape(w.shape[0], -1))  # noqa: E731
-        heads = lambda t: t.reshape(b, l, n_heads, -1)  # noqa: E731
-        q = heads(a @ columns(wq[..., :nope]))
-        q_rot = heads(a @ columns(_rotary_columns(wq[..., nope:], interleave)))
-        c = _rms_norm(a @ cast(blk["wkv_a"][:, :rank]), blk["kv_norm"], eps)
-        k_rot = a @ cast(_rotary_columns(blk["wkv_a"][:, rank:], interleave))
-        k, v = heads(c @ columns(wkv_b[..., :nope])), heads(c @ columns(wkv_b[..., nope:]))
-        q_rot = rope(q_rot, positions, theta)
-        k_rot = rope(k_rot[:, :, None, :], positions, theta)[:, :, 0]
-    att = ring_attention(q, k, v, axis_name=axis, causal=True, q_rot=q_rot, k_rot=k_rot)
-    with jax.named_scope("mla_proj"):
-        return att.reshape(b, l, -1) @ cast(blk["wo"])
 
 
 def _gated_mlp(u, w_gate, w_up, w_down):
@@ -423,67 +149,32 @@ def _relu2_mlp(u, w_up, w_down, site: str):
         return jnp.square(jax.nn.relu(remat_lib.product(site, u, w_up))) @ w_down
 
 
-def _mamba_mixer(u, blk, *, axis, eps, cast, state: int, chunk: int):
-    """Mamba-2's mixer over the HELD heads and groups (read off ``A_log``
-    and the convolution's channels).  Returns (the part, the layer's
-    ``SSM_COUNTERS``)."""
-    if axis is not None and axis_size(axis) > 1:
-        raise ValueError("a state-space layer over a sharded sequence is not supported: the state at a shard's start lives on the shard before it")
-    b, l, _ = u.shape
-    heads, inner, conv_dim = blk["A_log"].shape[0], blk["ssm_norm"].shape[0], blk["conv_w"].shape[1]
-    groups = (conv_dim - inner) // (2 * state)
-    with jax.named_scope("ssm_proj"):
-        # Each part is multiplied by its own column block of the published
-        # matrix (a slice of the WEIGHT, as latent attention's); z and xBC
-        # are save sites (ops/remat.py).
-        w_in = blk["ssm_in"]
-        z = remat_lib.product("ssm_z", u, cast(w_in[:, :inner]))
-        xbc = remat_lib.product("ssm_xbc", u, cast(w_in[:, inner:inner + conv_dim]))
-        dt = (u @ cast(w_in[:, inner + conv_dim:])).astype(jnp.float32)
-    xbc = ssm_ops.causal_conv(xbc, blk["conv_w"], blk["conv_b"])
-    with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(xbc)
-        x = xbc[..., :inner].reshape(b, l, heads, inner // heads)
-        bm = xbc[..., inner:inner + groups * state].reshape(b, l, groups, state)
-        cm = xbc[..., inner + groups * state:].reshape(b, l, groups, state)
-        dt = jax.nn.softplus(dt + blk["dt_bias"])
-    y = ssm_ops.ssm_scan(x, dt, -jnp.exp(blk["A_log"]), bm, cm, blk["D"], chunk=chunk)
-    # counted where the scan is called, from what it was called with
-    by_kernels = ssm_ops.scan_path(x, bm, chunk)[0] != ssm_ops.PATH_XLA_REFERENCE
-    counts = {"ssm_positions": jnp.float32(b * l * heads), "ssm_positions_kernel": jnp.float32(b * l * heads * by_kernels)}
-    y = ssm_ops.gated_group_norm(y.reshape(b, l, inner), z, blk["ssm_norm"], groups, eps)
-    with jax.named_scope("ssm_proj"):
-        return y @ cast(blk["ssm_out"]), counts
+@dataclasses.dataclass(frozen=True)
+class Router:
+    """What the parts that route share: a router ``n_experts`` wide that
+    takes ``top_k`` a token by ``keys``; ``held`` experts from ``first_held``
+    on are computed here.  ``keys`` are ``ops/moe.route``'s published keys,
+    ONLY those that depart from its defaults (OLMoE's): OLMoE's call stays
+    ``route(u, wg, k)``, which is also what the benchmark's tap of it
+    (``olmoe_1b_7b_l1_reference.py``) wraps."""
+
+    n_experts: int
+    top_k: int
+    held: int
+    first_held: int
+    keys: Tuple[Tuple[str, Any], ...] = ()
 
 
-def _grouped_query_attention(u, blk, *, axis, cast, head_dim: int):
-    """Attention whose key/value heads are fewer than its query heads
-    (query head h reads key/value head ``h // group``), over the HELD heads
-    (read off the projections); no position signal.  The key/value heads are
-    REPEATED to the queries' ahead of the attention (PERF.md section 7:
-    the flash kernels' contract wants as many; one layer in eleven)."""
-    b, l, _ = u.shape
-    with jax.named_scope("attn_proj"):
-        heads = lambda t: t.reshape(b, l, -1, head_dim)  # noqa: E731
-        q, k, v = (heads(remat_lib.product(name, u, cast(blk["w" + name]))) for name in ("q", "k", "v"))
-        group = q.shape[2] // k.shape[2]
-        if group > 1:
-            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-    att = ring_attention(q, k, v, axis_name=axis, causal=True)
-    with jax.named_scope("attn_proj"):
-        return att.reshape(b, l, -1) @ cast(blk["wo"])
-
-
-def _routed_stats(routing, slots, given, pairs: int, top_k: int, first_expert_held: int, held: int):
+def _routed_stats(router: Router, routing, slots, given, pairs: int):
     """An expert layer's router sums and slot counts (``_apply`` adds them up)."""
     f, p, z = moe.router_stats(routing)
     slots = slots.astype(jnp.float32)
-    sizes = slots[first_expert_held:first_expert_held + held]
-    here = (routing.choices >= first_expert_held) & (routing.choices < first_expert_held + held)
+    sizes = slots[router.first_held:router.first_held + router.held]
+    here = (routing.choices >= router.first_held) & (routing.choices < router.first_held + router.held)
     return {
         "f": f, "p": p, "z": z, "pairs": jnp.float32(pairs),
         "slots": slots,
-        "moe_slots": jnp.float32(pairs * top_k),
+        "moe_slots": jnp.float32(pairs * router.top_k),
         "moe_slots_held": jnp.sum(here.astype(jnp.float32)),
         "moe_slots_computed": (given.first + given.second).astype(jnp.float32),
         "moe_slots_overflow": given.second.astype(jnp.float32),
@@ -492,100 +183,156 @@ def _routed_stats(routing, slots, given, pairs: int, top_k: int, first_expert_he
     }
 
 
-def _latent_moe(u, blk, *, top_k, router, first_expert_held, cast):
-    """LatentMoE: the router reads the token, the routed experts (two
-    matrices under relu squared) work in a latent between two projections,
-    their weighted sum is taken IN the latent (linear: the same result as
-    after ``w_lat_up``, a quarter of the rows' width); the shared expert
-    reads the token.  Returns (the part, the layer's stats)."""
-    b, l, dim = u.shape
-    tokens = u.reshape(b * l, dim)
-    n_experts, held = blk["router"].shape[1], blk["w_up"].shape[0]
-    routing = moe.route(tokens, blk["router"], top_k, bias=blk["router_bias"], **(router or {}))
-    with jax.named_scope("moe_latent"):
-        c = remat_lib.product("moe_latent_down", tokens, cast(blk["w_lat_down"]))
-    y, slots, given = moe.expert_ffn(
-        c, routing.choices, routing.weights, None, cast(blk["w_up"]), cast(blk["w_down"]),
-        n_experts=n_experts, lo=first_expert_held,
-    )
-    with jax.named_scope("moe_latent"):
-        y = y @ cast(blk["w_lat_up"])
-    with jax.named_scope("moe_shared"):
-        y = y + _relu2_mlp(tokens, cast(blk["ws_up"]), cast(blk["ws_down"]), "shared_up")
-    return y.reshape(b, l, dim), _routed_stats(routing, slots, given, b * l, top_k, first_expert_held, held)
+@dataclasses.dataclass(frozen=True)
+class GatedMLP(Part):
+    """The dense feed-forward, ``width`` wide."""
+
+    width: int
+
+    def init(self, draw: Draws, d: int):
+        return {"w_gate": draw.normal((d, self.width)), "w_up": draw.normal((d, self.width)), "w_down": draw.normal((self.width, d))}
+
+    def apply(self, u, blk, positions, axis, cast):
+        return _gated_mlp(u, cast(blk["w_gate"]), cast(blk["w_up"]), cast(blk["w_down"])), None
 
 
-def _hybrid_layer(x, blk, *, axis, top_k, eps, compute_dtype, router, first_expert_held, hybrid):
-    """One ``nemotron_h`` layer: one norm, ONE mixer or feed-forward part
-    (read off its parameters), one residual."""
-    cast = lambda w: w.astype(compute_dtype)  # noqa: E731
-    u = _rms_norm(cast(x), blk["norm"], eps)
-    if "ssm_in" in blk:
-        y, counts = _mamba_mixer(u, blk, axis=axis, eps=eps, cast=cast, state=hybrid["state"], chunk=hybrid["chunk"])
-        return x + y, counts
-    if "router" in blk:
-        y, stats = _latent_moe(u, blk, top_k=top_k, router=router, first_expert_held=first_expert_held, cast=cast)
-        return x + y, stats
-    return x + _grouped_query_attention(u, blk, axis=axis, cast=cast, head_dim=hybrid["head_dim"]), None
+@dataclasses.dataclass(frozen=True)
+class RoutedExperts(Part):
+    """Gated experts ``width`` wide behind a router, with a correction bias
+    that chooses (``correction_bias``) and ONE gated MLP ``shared_width``
+    wide that every token passes (0: none)."""
 
+    router: Router
+    width: int
+    correction_bias: bool = False
+    shared_width: int = 0
 
-def _block(
-    x, blk, positions, *, axis, n_heads, top_k, theta, eps, compute_dtype,
-    rot=0, interleave=False, router=None, first_expert_held=0, eva=None, unit_offset=False, hybrid=None,
-):
-    """One block: an attention and a feed-forward whose kinds are read off
-    its parameters (``wkv_a`` makes the attention latent, ``eva_phi`` makes
-    it EVA's with ``eva`` = its window and chunk, a ``router`` makes
-    the feed-forward ``moe``, ``ws_gate`` adds the shared experts).
-    ``router`` are ``ops/moe.route``'s published keys.  ``x`` may be wider
-    than ``compute_dtype`` (a float32 residual stream): the block reads it
-    cast and adds in ``x``'s own type.  Returns (x, the
-    layer's router sums and slot counts — None for a dense layer).  A layer
-    with ONE norm (``norm``: ``nemotron_h``) is one part alone
-    (:func:`_hybrid_layer`)."""
-    if "norm" in blk:
-        return _hybrid_layer(
-            x, blk, axis=axis, top_k=top_k, eps=eps, compute_dtype=compute_dtype, router=router,
-            first_expert_held=first_expert_held, hybrid=hybrid,
+    counters = MOE_COUNTERS
+    routes = True
+
+    def init(self, draw: Draws, d: int):
+        e, held, f = self.router.n_experts, self.router.held, self.width
+        blk = {"router": draw.normal((d, e))}
+        if self.correction_bias:
+            blk["router_bias"] = jnp.zeros((e,), jnp.float32)
+        blk["w_gate"], blk["w_up"] = draw.normal((held, d, f)), draw.normal((held, d, f))
+        blk["w_down"] = draw.normal((held, f, d))
+        if self.shared_width:
+            blk["ws_gate"], blk["ws_up"] = draw.normal((d, self.shared_width)), draw.normal((d, self.shared_width))
+            blk["ws_down"] = draw.normal((self.shared_width, d))
+        return blk
+
+    def apply(self, u, blk, positions, axis, cast):
+        b, l, dim = u.shape
+        tokens = u.reshape(b * l, dim)
+        keys = dict(self.router.keys)
+        if self.correction_bias:
+            keys["bias"] = blk["router_bias"]
+        routing = moe.route(tokens, blk["router"], self.router.top_k, **keys)
+        y, slots, given = moe.expert_ffn(
+            tokens, routing.choices, routing.weights,
+            cast(blk["w_gate"]), cast(blk["w_up"]), cast(blk["w_down"]),
+            n_experts=self.router.n_experts, lo=self.router.first_held,
         )
-    b, l, dim = x.shape
-    cast = lambda w: w.astype(compute_dtype)  # noqa: E731
-    a = _rms_norm(cast(x), _gain(blk["attn_norm"], unit_offset), eps)
-    common = dict(axis=axis, n_heads=n_heads, theta=theta, eps=eps, cast=cast)
-    if "wkv_a" in blk:
-        x = x + _latent_attention(a, blk, positions, rot=rot, interleave=interleave, **common)
-    elif "eva_phi" in blk:
-        x = x + _eva_attention(a, blk, positions, axis=axis, theta=theta, cast=cast, **eva).astype(x.dtype)
-    else:
-        x = x + _attention(a, blk, positions, **common)
-    u = _rms_norm(cast(x), _gain(blk["ffn_norm"], unit_offset), eps)
-    if "router" not in blk:
-        y = _gated_mlp(u, cast(blk["w_gate"]), cast(blk["w_up"]), cast(blk["w_down"]))
-        return x + y.astype(x.dtype), None
-    tokens = u.reshape(b * l, dim)
-    n_experts, held = blk["router"].shape[1], blk["w_gate"].shape[0]
-    # Only the keys that depart from ``route``'s defaults (OLMoE's) are
-    # passed: OLMoE's call stays ``route(u, wg, k)``, which is also what the
-    # benchmark's tap of it (``olmoe_1b_7b_l1_reference.py``) wraps.
-    keys = dict(router or {})
-    if "router_bias" in blk:
-        keys["bias"] = blk["router_bias"]
-    routing = moe.route(tokens, blk["router"], top_k, **keys)
-    y, slots, given = moe.expert_ffn(
-        tokens, routing.choices, routing.weights,
-        cast(blk["w_gate"]), cast(blk["w_up"]), cast(blk["w_down"]),
-        n_experts=n_experts, lo=first_expert_held,
-    )
-    if "ws_gate" in blk:
+        if self.shared_width:
+            with jax.named_scope("moe_shared"):
+                y = y + _gated_mlp(tokens, cast(blk["ws_gate"]), cast(blk["ws_up"]), cast(blk["ws_down"]))
+        stats = _routed_stats(self.router, routing, slots, given, b * l)
+        return y.reshape(b, l, dim), stats
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoE(Part):
+    """LatentMoE: the router (always with a correction bias) reads the
+    token, the routed experts (two matrices ``width`` wide under relu
+    squared) work in a latent ``latent`` wide between two projections, their
+    weighted sum is taken IN the latent (linear: the same result as after
+    ``w_lat_up``, a quarter of the rows' width); the shared expert,
+    ``shared_width`` wide, reads the token.  ``into_stream`` scales the draws
+    of the matrices that write into the stream or the latent
+    (``rescale_prenorm_residual``)."""
+
+    router: Router
+    latent: int
+    width: int
+    shared_width: int
+    into_stream: float = 1.0
+
+    counters = MOE_COUNTERS
+    routes = True
+    correction_bias = True
+
+    def init(self, draw: Draws, d: int):
+        e, held, scale = self.router.n_experts, self.router.held, self.into_stream
+        return {
+            "router": draw.normal((d, e)), "router_bias": jnp.zeros((e,), jnp.float32),
+            "w_lat_down": draw.normal((d, self.latent)), "w_lat_up": draw.normal((self.latent, d), scale),
+            "w_up": draw.normal((held, self.latent, self.width)),
+            "w_down": draw.normal((held, self.width, self.latent), scale),
+            "ws_up": draw.normal((d, self.shared_width)), "ws_down": draw.normal((self.shared_width, d), scale),
+        }
+
+    def apply(self, u, blk, positions, axis, cast):
+        b, l, dim = u.shape
+        tokens = u.reshape(b * l, dim)
+        routing = moe.route(tokens, blk["router"], self.router.top_k, bias=blk["router_bias"], **dict(self.router.keys))
+        with jax.named_scope("moe_latent"):
+            c = remat_lib.product("moe_latent_down", tokens, cast(blk["w_lat_down"]))
+        y, slots, given = moe.expert_ffn(
+            c, routing.choices, routing.weights, None, cast(blk["w_up"]), cast(blk["w_down"]),
+            n_experts=self.router.n_experts, lo=self.router.first_held,
+        )
+        with jax.named_scope("moe_latent"):
+            y = y @ cast(blk["w_lat_up"])
         with jax.named_scope("moe_shared"):
-            y = y + _gated_mlp(tokens, cast(blk["ws_gate"]), cast(blk["ws_up"]), cast(blk["ws_down"]))
-    stats = _routed_stats(routing, slots, given, b * l, top_k, first_expert_held, held)
-    return x + y.reshape(b, l, dim), stats
+            y = y + _relu2_mlp(tokens, cast(blk["ws_up"]), cast(blk["ws_down"]), "shared_up")
+        return y.reshape(b, l, dim), _routed_stats(self.router, routing, slots, given, b * l)
+
+
+def _block(x, blk, positions, layer: Layer, *, axis, eps, compute_dtype, unit_offset=False):
+    """One layer: for each ``(norm, part)`` of it the norm (times 1 + the
+    gain where ``unit_offset``), the part, the residual add.  ``x`` may be
+    wider than ``compute_dtype`` (a float32 residual stream): a part reads
+    it cast and its output is added in ``x``'s own type.  Returns (x, each
+    part's stats — None for a part that counts nothing)."""
+    cast = lambda w: w.astype(compute_dtype)  # noqa: E731
+    stats = []
+    for norm, part in layer:
+        u = _rms_norm(cast(x), _gain(blk[norm], unit_offset), eps)
+        y, counted = part.apply(u, blk, positions, axis, cast)
+        x = x + y.astype(x.dtype)
+        stats.append(counted)
+    return x, tuple(stats)
+
+
+def _init(rng, *, layers: Sequence[Layer], draws_a_layer: int, vocab_size: int, hidden_size: int, init_std: float,
+          tie_word_embeddings: bool, unit_offset: bool, num_pred_heads: int) -> Dict[str, Any]:
+    """``tok_emb``, ``norm_f``, ``head`` (unless tied), then each layer's
+    norms and parts in order, every matrix from the next key of ONE stream
+    of 2 + ``draws_a_layer`` a layer.  ``unit_offset`` starts the layers'
+    and the head's gains at 0 (the norms multiply by 1 + g)."""
+    d = hidden_size
+    draw = Draws(rng, 2 + draws_a_layer * len(layers), init_std)
+    gain = jnp.zeros if unit_offset else jnp.ones
+    params: Dict[str, Any] = {"tok_emb": draw.normal((vocab_size, d)), "norm_f": gain((d,), jnp.float32), "blocks": {}}
+    if not tie_word_embeddings:
+        params["head"] = draw.normal((d, num_pred_heads * vocab_size))
+    for i, layer in enumerate(layers):
+        blk: Dict[str, Any] = {}
+        for norm, part in layer:
+            made = {norm: gain((d,), jnp.float32), **part.init(draw, d)}
+            if set(made) & set(blk):
+                raise ValueError(f"layer {i}: two parts name a parameter alike ({sorted(set(made) & set(blk))})")
+            blk.update(made)
+        # Zero-padded names keep sorted() in layer order past nine layers.
+        params["blocks"][f"b{i:02d}"] = blk
+    return params
 
 
 def _apply(
     params, batch, train: bool = False, ctx: ParallelContext = ParallelContext(),
-    *, compute_dtype, remat: bool, residual_dtype=None, num_pred_heads: int = 1, **block_args,
+    *, layers: Sequence[Layer], compute_dtype, remat: bool, eps: float, unit_offset: bool = False,
+    residual_dtype=None, num_pred_heads: int = 1,
 ):
     tokens = batch["tokens"]  # [B, L_local]: sequence-sharded over the axis
     l = tokens.shape[1]
@@ -593,38 +340,38 @@ def _apply(
     offset = lax.axis_index(axis) * l if axis is not None else 0
     positions = offset + jnp.arange(l)
     x = params["tok_emb"][tokens].astype(residual_dtype or compute_dtype)
-    block_fn = functools.partial(_block, axis=axis, compute_dtype=compute_dtype, **block_args)
     names = sorted(params["blocks"])
-    blocks = [block_fn] * len(names)
+    shared = dict(axis=axis, eps=eps, compute_dtype=compute_dtype, unit_offset=unit_offset)
+    blocks = [functools.partial(_block, layer=layer, **shared) for layer in layers]
     if remat and train:
         # Every block rematerialised, each keeping what the byte budget the
         # trainer resolved gives it (ops/remat.py; 0 = nothing, as ever).
-        blocks = remat_lib.plan(
-            block_fn, [(x, params["blocks"][name], positions) for name in names], ctx.remat_keep_bytes
-        )
-    routed, scanned = [], []
-    for name, block in zip(names, blocks):
+        blocks = remat_lib.plan(blocks, [(x, params["blocks"][name], positions) for name in names], ctx.remat_keep_bytes)
+    routed, plain = [], {}  # the routing parts' stats; the other parts' counts by name: both in layer order
+    for name, layer, block in zip(names, layers, blocks):
         x, stats = block(x, params["blocks"][name], positions)
-        if stats is not None:
-            (scanned if "ssm_positions" in stats else routed).append(stats)
+        for (_, part), given in zip(layer, stats):
+            if part.routes:
+                routed.append(given)
+            else:
+                for key, value in (given or {}).items():
+                    plain.setdefault(key, []).append(value)
     with jax.named_scope("lm_head"):
-        norm_f = _gain(params["norm_f"], block_args["unit_offset"])
-        x = _rms_norm(x.astype(compute_dtype), norm_f, block_args["eps"])
+        norm_f = _gain(params["norm_f"], unit_offset)
+        x = _rms_norm(x.astype(compute_dtype), norm_f, eps)
         head = params["head"] if "head" in params else params["tok_emb"].T
         logits = jnp.dot(x, head.astype(compute_dtype), preferred_element_type=jnp.float32)
         if num_pred_heads > 1:  # [B, L, heads of prediction, vocabulary]
             logits = logits.reshape(logits.shape[:2] + (num_pred_heads, -1))
     out = {"logits": logits}
-    eva = [blk["eva_phi"].shape[0] for blk in params["blocks"].values() if "eva_phi" in blk]
-    if eva:
-        exact, far = eva_ops.pairs(l, block_args["eva"]["window"], block_args["eva"]["chunk"])
-        scored = tokens.shape[0] * sum(eva)  # sequences x (heads, all layers)
-        out["eva_counters"] = {
-            "eva_pairs_exact": jnp.float32(scored * exact),
-            "eva_pairs_summary": jnp.float32(scored * far),
-        }
-    if scanned:
-        out["ssm_counters"] = jax.tree.map(lambda *leaves: sum(leaves), *scanned)  # all layers
+    of_shapes = collections.Counter()
+    for layer in layers:
+        for _, part in layer:
+            of_shapes.update(part.shape_counts(tokens.shape[0], l))
+    counters = {key: jnp.float32(n) for key, n in of_shapes.items()}
+    counters.update({key: sum(values) for key, values in plain.items()})  # all layers
+    if counters:
+        out["counters"] = counters
     if routed:
         slots = jnp.stack([stats.pop("slots") for stats in routed])  # [expert layers, E]
         total = jax.tree.map(lambda *leaves: sum(leaves), *routed)
@@ -647,14 +394,14 @@ def _apply(
     return out
 
 
-def _update_correction_bias(params, out, *, speed: float):
+def _update_correction_bias(params, out, *, layers: Sequence[Layer], speed: float):
     """The model's own rule for the routers' correction biases
     (``ModelSpec.after_update``; DeepSeek-V3, arXiv:2412.19437, section
     2.1.2): after a step, an expert that was sent more slots than the mean
     of its layer has its bias lowered by ``speed``, one that was sent fewer
     has it raised.  Each expert layer has its own bias and its own counts."""
     blocks = dict(params["blocks"])
-    routed = [name for name in sorted(blocks) if "router_bias" in blocks[name]]
+    routed = [name for name, layer in zip(sorted(blocks), layers) if any(part.routes for _, part in layer)]
     for name, slots in zip(routed, out["router_slots"]):  # both in layer order
         step = speed * jnp.sign(jnp.mean(slots) - slots)
         blocks[name] = {**blocks[name], "router_bias": blocks[name]["router_bias"] + step}
@@ -713,8 +460,7 @@ def _metrics(out, batch, lb_coef: float, z_coef: float):
     acc = jnp.mean((jnp.argmax(logits, -1) == batch["labels"]).astype(jnp.float32))
     metrics = {"loss": loss, "ce": ce, "lb_loss": lb, "z_loss": z, "accuracy": acc}
     metrics.update(out.get("moe_counters", {}))
-    metrics.update(out.get("eva_counters", {}))
-    metrics.update(out.get("ssm_counters", {}))
+    metrics.update(out.get("counters", {}))
     return metrics
 
 
@@ -742,6 +488,242 @@ def _is_decayed(params, skip=_NEVER_DECAYED):
     """AdamW's weight-decay mask: every leaf but those named in ``skip``."""
     return jax.tree_util.tree_map_with_path(
         lambda path, _: getattr(path[-1], "key", None) not in skip, params
+    )
+
+
+
+def _head_width(hidden_size: int, num_attention_heads: int) -> int:
+    if hidden_size % num_attention_heads or (hidden_size // num_attention_heads) % 2:
+        raise ValueError(
+            f"hidden_size {hidden_size} must split into {num_attention_heads} heads "
+            f"of even width (rotary pairs)"
+        )
+    return hidden_size // num_attention_heads
+
+
+# A family's builders: each takes the published keys it names (``model_spec``
+# hands them over and notes them as read) and checks their ranges.
+
+def _qk_norm_attention(*, hidden_size, num_attention_heads, rope_theta, rms_norm_eps):
+    _head_width(hidden_size, num_attention_heads)
+    return QKNormAttention(num_attention_heads, float(rope_theta), float(rms_norm_eps))
+
+
+def _latent_attention(
+    *, num_attention_heads, kv_lora_rank, q_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+    rope_interleave, rope_theta, rms_norm_eps,
+):
+    if q_lora_rank is not None:
+        raise ValueError("a low-rank query projection (q_lora_rank) is not supported: no cell runs one")
+    if min(qk_nope_head_dim, v_head_dim) <= 0 or qk_rope_head_dim <= 0 or qk_rope_head_dim % 2:
+        raise ValueError(
+            f"latent attention needs qk_nope_head_dim, v_head_dim and an even qk_rope_head_dim, got "
+            f"{qk_nope_head_dim} / {v_head_dim} / {qk_rope_head_dim}"
+        )
+    return LatentAttention(
+        num_attention_heads, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+        float(rope_theta), float(rms_norm_eps), bool(rope_interleave),
+    )
+
+
+def _eva_attention(*, hidden_size, num_attention_heads, heads_held, window_size, chunk_size, rope_theta):
+    head_dim = _head_width(hidden_size, num_attention_heads)
+    if chunk_size <= 0 or window_size <= 0 or window_size % chunk_size:
+        raise ValueError(f"EVA attention needs a window_size in whole chunks, got {window_size} / {chunk_size}")
+    if not 0 <= heads_held <= num_attention_heads:
+        raise ValueError(f"heads_held {heads_held} of {num_attention_heads} heads")
+    return EvaAttention(heads_held or num_attention_heads, head_dim, float(rope_theta), window_size, chunk_size)
+
+
+def _router(
+    *, num_experts, num_experts_per_tok, experts_held, first_expert_held, scoring_func, norm_topk_prob,
+    routed_scaling_factor, topk_method, n_group, topk_group,
+):
+    """(the :class:`Router` of the family's expert layers, whether it has a correction bias)."""
+    if num_experts_per_tok > num_experts:
+        raise ValueError(f"top-{num_experts_per_tok} of {num_experts} experts")
+    if (n_group, topk_group) != (1, 1):
+        raise ValueError(f"group-limited routing (n_group {n_group}, topk_group {topk_group}) is not supported: no cell runs it")
+    if topk_method not in TOPK_METHODS or scoring_func not in moe.SCORING_FUNCS:
+        raise ValueError(
+            f"topk_method {topk_method!r} / scoring_func {scoring_func!r}: known are "
+            f"{TOPK_METHODS} / {moe.SCORING_FUNCS}"
+        )
+    held = experts_held or num_experts
+    if not 0 <= first_expert_held <= num_experts - held:
+        raise ValueError(f"experts [{first_expert_held}, {first_expert_held + held}) are not among the router's {num_experts}")
+    keys = tuple(
+        (key, value)
+        for key, value, default in (
+            ("scoring_func", scoring_func, "softmax"),
+            ("norm_topk_prob", bool(norm_topk_prob), False),
+            ("routed_scaling_factor", float(routed_scaling_factor), 1.0),
+        )
+        if value != default
+    )
+    return Router(num_experts, num_experts_per_tok, held, first_expert_held, keys), topk_method == "noaux_tc"
+
+
+def _gated_feed_forwards(
+    router, correction_bias,
+    *, num_hidden_layers, layer_types, first_k_dense_replace, intermediate_size, moe_intermediate_size, n_shared_experts,
+):
+    """(each layer's feed-forward, the keys a layer takes of the stream: OLMoE's
+    block draws 8, one with shared experts has always been given 12)."""
+    if layer_types is None:
+        dense = min(first_k_dense_replace, num_hidden_layers)
+        layer_types = ("dense",) * dense + ("moe",) * (num_hidden_layers - dense)
+    layer_types = tuple(layer_types)
+    if len(layer_types) != num_hidden_layers or set(layer_types) - set(LAYER_TYPES):
+        raise ValueError(
+            f"layer_types must name {num_hidden_layers} layers from {LAYER_TYPES}, "
+            f"got {layer_types!r}"
+        )
+    width = moe_intermediate_size or intermediate_size
+    kinds = {"moe": RoutedExperts(router, width, correction_bias, n_shared_experts * width), "dense": GatedMLP(intermediate_size)}
+    return [kinds[kind] for kind in layer_types], 12 if n_shared_experts else 8
+
+
+def _two_part_layers(attention, own, draws=0):
+    feed_forwards, needed = own(functools.partial(_gated_feed_forwards, *own(_router)))
+    return tuple((("attn_norm", attention), ("ffn_norm", feed_forward)) for feed_forward in feed_forwards), max(draws, needed)
+
+
+def _nemotron_h_layers(
+    router, _,
+    *, hybrid_override_pattern, num_hidden_layers, hidden_size, num_attention_heads, seq_len, rms_norm_eps,
+    mlp_hidden_act, topk_method, scoring_func, tie_word_embeddings,
+    mamba_num_heads, mamba_head_dim, n_groups, ssm_state_size, conv_kernel, chunk_size,
+    time_step_min, time_step_max, time_step_floor, mamba_heads_held,
+    num_key_value_heads, head_dim, heads_held, kv_heads_held,
+    moe_latent_size, moe_intermediate_size, moe_shared_expert_intermediate_size,
+    rescale_prenorm_residual, residual_layers,
+):
+    pattern = str(hybrid_override_pattern)
+    if len(pattern) != num_hidden_layers or set(pattern) - set(PATTERN_KINDS):
+        raise ValueError(
+            f"hybrid_override_pattern must give {num_hidden_layers} layers a letter of {sorted(PATTERN_KINDS)} "
+            f"({PATTERN_KINDS}), got {pattern!r}"
+        )
+    if mlp_hidden_act != "relu2" or topk_method != "noaux_tc" or scoring_func != "sigmoid" or tie_word_embeddings:
+        raise ValueError(
+            "a hybrid_override_pattern model is nemotron_h's: mlp_hidden_act 'relu2', a sigmoid router with a "
+            f"correction bias (topk_method 'noaux_tc') and an untied head; got {mlp_hidden_act!r} / {scoring_func!r} / "
+            f"{topk_method!r} / tie_word_embeddings {tie_word_embeddings}"
+        )
+    m_held, q_held = mamba_heads_held or mamba_num_heads, heads_held or num_attention_heads
+    kv_all = num_key_value_heads or num_attention_heads
+    kv_held = kv_heads_held or kv_all
+    if "M" in pattern:
+        per_group = mamba_num_heads // max(n_groups, 1)
+        if min(mamba_num_heads, mamba_head_dim, ssm_state_size, chunk_size, conv_kernel) <= 0 or mamba_num_heads % n_groups:
+            raise ValueError(
+                f"a Mamba-2 layer needs mamba_num_heads in whole n_groups, mamba_head_dim, ssm_state_size, conv_kernel "
+                f"and chunk_size, got {mamba_num_heads} / {n_groups} / {mamba_head_dim} / {ssm_state_size} / {conv_kernel} / {chunk_size}"
+            )
+        if not 0 < m_held <= mamba_num_heads or m_held % per_group:
+            raise ValueError(f"mamba_heads_held {m_held} of {mamba_num_heads}: whole groups of {per_group} heads (the gated norm's)")
+        if seq_len % chunk_size:
+            raise ValueError(f"seq_len {seq_len} is not whole chunks of {chunk_size}: the chunked scan needs them")
+    if "*" in pattern and (
+        num_attention_heads % kv_all or not 0 < q_held <= num_attention_heads or not 0 < kv_held <= kv_all
+        or q_held % kv_held or (num_attention_heads // kv_all) % (q_held // kv_held)
+    ):
+        raise ValueError(
+            f"{q_held} of {num_attention_heads} query heads on {kv_held} of {kv_all} key/value heads: a share's "
+            f"query heads sit evenly on its key/value heads, a divisor of the published {num_attention_heads // max(kv_all, 1)} on each"
+        )
+    if "E" in pattern and min(moe_latent_size, moe_intermediate_size, moe_shared_expert_intermediate_size) <= 0:
+        raise ValueError("a LatentMoE layer needs moe_latent_size, moe_intermediate_size and moe_shared_expert_intermediate_size")
+    width = _head_width(hidden_size, num_attention_heads)
+    # the matrices that write into the stream are drawn smaller by the depth's root
+    into_stream = ((residual_layers or num_hidden_layers) if rescale_prenorm_residual else 1) ** -0.5
+    kinds = {
+        "M": MambaMixer(
+            m_held, mamba_head_dim, m_held * n_groups // mamba_num_heads if mamba_num_heads else 0, ssm_state_size,
+            conv_kernel, chunk_size, float(rms_norm_eps), (time_step_min, time_step_max, time_step_floor), into_stream,
+        ),
+        "*": GroupedQueryAttention(q_held, kv_held, head_dim or width, into_stream),
+        "E": LatentMoE(router, moe_latent_size, moe_intermediate_size, moe_shared_expert_intermediate_size, into_stream),
+    }
+    return tuple((("norm", kinds[letter]),) for letter in pattern), 8  # keys of the stream a layer: as the family always split it
+
+
+def _family(*, hybrid_override_pattern, attention_class, kv_lora_rank) -> str:
+    if attention_class not in ATTENTION_CLASSES:
+        raise ValueError(f"attention_class {attention_class!r}: known are {ATTENTION_CLASSES}")
+    named = [
+        family
+        for family, said in (
+            ("nemotron_h", hybrid_override_pattern is not None), ("evabyte", attention_class == "eva"), ("deepseek_v3", bool(kv_lora_rank)),
+        )
+        if said
+    ]
+    if len(named) > 1:
+        raise ValueError(
+            f"hybrid_override_pattern, attention_class 'eva' and kv_lora_rank each name a family and one model is of one: got those of {named}"
+        )
+    return named[0] if named else "olmoe"
+
+
+#: family -> (own) -> (the layers, the keys of the init's stream a layer takes).  ``own(builder)``
+#: calls a builder with the published keys it names.  A new architecture is a part and a line here.
+FAMILIES = {
+    "olmoe": lambda own: _two_part_layers(own(_qk_norm_attention), own),
+    "deepseek_v3": lambda own: _two_part_layers(own(_latent_attention), own, draws=12),  # 12 with or without shared experts
+    "evabyte": lambda own: _two_part_layers(own(_eva_attention), own),
+    "nemotron_h": lambda own: own(functools.partial(_nemotron_h_layers, *own(_router))),
+}
+
+
+def _spec_of_layers(
+    layers: Sequence[Layer], draws_a_layer: int,
+    *, learning_rate, compute_dtype, vocab_size, hidden_size, rms_norm_eps, seq_len, tie_word_embeddings,
+    router_aux_loss_coef, router_z_loss_coef, weight_decay, lr_warmup_steps, remat, bias_update_speed,
+    norm_add_unit_offset, fp32_skip_add, num_pred_heads, init_std, decay_matrices_only,
+) -> ModelSpec:
+    """The model of ``layers`` (what :func:`model_spec` ends in): the init,
+    the block, the step counters and the correction bias's rule all follow
+    from the list."""
+    if num_pred_heads < 1 or (num_pred_heads > 1 and tie_word_embeddings):
+        raise ValueError(f"num_pred_heads {num_pred_heads}: at least one, and more than one only with an untied head")
+    layers = tuple(layers)
+    parts = [part for layer in layers for _, part in layer]
+    correction_bias = any(part.correction_bias for part in parts)
+    apply = functools.partial(
+        _apply, layers=layers, compute_dtype=jnp.dtype(compute_dtype), remat=remat, eps=float(rms_norm_eps),
+        unit_offset=bool(norm_add_unit_offset), residual_dtype=jnp.float32 if fp32_skip_add else None,
+        num_pred_heads=num_pred_heads,
+    )
+    skip = _NOT_MATRICES if decay_matrices_only else _NEVER_DECAYED
+    coefs = dict(lb_coef=router_aux_loss_coef, z_coef=router_z_loss_coef)
+    return ModelSpec(
+        name="moe_lm",
+        init=functools.partial(
+            _init, layers=layers, draws_a_layer=draws_a_layer, vocab_size=vocab_size, hidden_size=hidden_size,
+            init_std=init_std, tie_word_embeddings=tie_word_embeddings, unit_offset=bool(norm_add_unit_offset),
+            num_pred_heads=num_pred_heads,
+        ),
+        apply=apply,
+        loss=functools.partial(_loss, **coefs),
+        metrics=functools.partial(_metrics, **coefs),
+        optimizer=optax.adamw(
+            optax.linear_schedule(0.0, learning_rate, lr_warmup_steps) if lr_warmup_steps else learning_rate,
+            b1=0.9, b2=0.95, eps=1e-8, weight_decay=weight_decay,
+            # a correction bias gets no gradient (it only chooses) and no decay:
+            # Adam's update of a leaf whose gradient is always 0 is exactly 0
+            mask=functools.partial(_is_decayed, skip=skip) if correction_bias or decay_matrices_only else None,
+        ),
+        feed=lm_feed,
+        example_batch=functools.partial(_example_batch, seq_len=seq_len),
+        batch_shard_dim=1,
+        predict=functools.partial(_predict, apply=apply),
+        step_counters={key: text for part in parts for key, text in part.counters.items()},
+        after_update=(
+            functools.partial(_update_correction_bias, layers=layers, speed=float(bias_update_speed))
+            if correction_bias else None
+        ),
+        rematerialises=bool(remat),
     )
 
 
@@ -845,166 +827,31 @@ def model_spec(
     under ``mlp_hidden_act`` relu2, a shared expert
     ``moe_shared_expert_intermediate_size`` wide); ``rescale_prenorm_residual``
     scales the matrices that write into the stream by ``residual_layers``^-1/2
-    (0: this model's depth).  Every held count 0 = all."""
-    hybrid = None
-    if hybrid_override_pattern is not None:
-        pattern = str(hybrid_override_pattern)
-        if len(pattern) != num_hidden_layers or set(pattern) - set(PATTERN_KINDS):
-            raise ValueError(
-                f"hybrid_override_pattern must give {num_hidden_layers} layers a letter of {sorted(PATTERN_KINDS)} "
-                f"({PATTERN_KINDS}), got {pattern!r}"
-            )
-        if layer_types is not None or kv_lora_rank or attention_class != "mha":
-            raise ValueError("hybrid_override_pattern names every layer's kind: layer_types, latent attention and attention_class do not go with it")
-        if mlp_hidden_act != "relu2" or topk_method != "noaux_tc" or scoring_func != "sigmoid" or tie_word_embeddings:
-            raise ValueError(
-                "a hybrid_override_pattern model is nemotron_h's: mlp_hidden_act 'relu2', a sigmoid router with a "
-                f"correction bias (topk_method 'noaux_tc') and an untied head; got {mlp_hidden_act!r} / {scoring_func!r} / "
-                f"{topk_method!r} / tie_word_embeddings {tie_word_embeddings}"
-            )
-        m_held, q_held = mamba_heads_held or mamba_num_heads, heads_held or num_attention_heads
-        kv_all = num_key_value_heads or num_attention_heads
-        kv_held = kv_heads_held or kv_all
-        if "M" in pattern:
-            per_group = mamba_num_heads // max(n_groups, 1)
-            if min(mamba_num_heads, mamba_head_dim, ssm_state_size, chunk_size, conv_kernel) <= 0 or mamba_num_heads % n_groups:
-                raise ValueError(
-                    f"a Mamba-2 layer needs mamba_num_heads in whole n_groups, mamba_head_dim, ssm_state_size, conv_kernel "
-                    f"and chunk_size, got {mamba_num_heads} / {n_groups} / {mamba_head_dim} / {ssm_state_size} / {conv_kernel} / {chunk_size}"
-                )
-            if not 0 < m_held <= mamba_num_heads or m_held % per_group:
-                raise ValueError(f"mamba_heads_held {m_held} of {mamba_num_heads}: whole groups of {per_group} heads (the gated norm's)")
-            if seq_len % chunk_size:
-                raise ValueError(f"seq_len {seq_len} is not whole chunks of {chunk_size}: the chunked scan needs them")
-        if "*" in pattern and (
-            num_attention_heads % kv_all or not 0 < q_held <= num_attention_heads or not 0 < kv_held <= kv_all
-            or q_held % kv_held or (num_attention_heads // kv_all) % (q_held // kv_held)
-        ):
-            raise ValueError(
-                f"{q_held} of {num_attention_heads} query heads on {kv_held} of {kv_all} key/value heads: a share's "
-                f"query heads sit evenly on its key/value heads, a divisor of the published {num_attention_heads // max(kv_all, 1)} on each"
-            )
-        if "E" in pattern and min(moe_latent_size, moe_intermediate_size, moe_shared_expert_intermediate_size) <= 0:
-            raise ValueError("a LatentMoE layer needs moe_latent_size, moe_intermediate_size and moe_shared_expert_intermediate_size")
-        # what the step's counters and the correction bias's rule read
-        layer_types = tuple("moe" if kind == "E" else "dense" for kind in pattern)
-        hybrid = {"state": ssm_state_size, "chunk": chunk_size, "head_dim": head_dim or hidden_size // num_attention_heads}
-    elif mlp_hidden_act != "silu" or mamba_heads_held or kv_heads_held:
-        raise ValueError("mlp_hidden_act, mamba_heads_held and kv_heads_held go with hybrid_override_pattern: every other block's feed-forward is gated silu")
-    if layer_types is None:
-        dense = min(first_k_dense_replace, num_hidden_layers)
-        layer_types = ("dense",) * dense + ("moe",) * (num_hidden_layers - dense)
-    layer_types = tuple(layer_types)
-    if len(layer_types) != num_hidden_layers or set(layer_types) - set(LAYER_TYPES):
+    (0: this model's depth).  Every held count 0 = all.
+
+    Which FAMILY the model is of follows from ``hybrid_override_pattern``,
+    ``attention_class`` and ``kv_lora_rank`` (:func:`_family`); the family's
+    builders take their own keys and check their ranges, and a key that no
+    builder of the chosen family reads, set to other than its default, is
+    refused: it would change nothing."""
+    keys = dict(locals())
+    read = set()
+
+    def own(builder):
+        names = tuple(inspect.signature(builder).parameters)
+        read.update(names)
+        return builder(**{name: keys[name] for name in names})
+
+    family = own(_family)
+    layers, draws_a_layer = FAMILIES[family](own)
+    spec = own(functools.partial(_spec_of_layers, layers, draws_a_layer))
+    foreign = sorted(key for key, value in keys.items() if key not in read and value != _DEFAULTS[key])
+    if foreign:
         raise ValueError(
-            f"layer_types must name {num_hidden_layers} layers from {LAYER_TYPES}, "
-            f"got {layer_types!r}"
+            f"{', '.join(foreign)}: set, but no part of the {family!r} family reads "
+            f"{'it' if len(foreign) == 1 else 'them'} (the family follows from hybrid_override_pattern / attention_class / kv_lora_rank)"
         )
-    latent = None
-    if kv_lora_rank:
-        if q_lora_rank is not None:
-            raise ValueError("a low-rank query projection (q_lora_rank) is not supported: no cell runs one")
-        if min(qk_nope_head_dim, v_head_dim) <= 0 or qk_rope_head_dim <= 0 or qk_rope_head_dim % 2:
-            raise ValueError(
-                f"latent attention needs qk_nope_head_dim, v_head_dim and an even qk_rope_head_dim, got "
-                f"{qk_nope_head_dim} / {v_head_dim} / {qk_rope_head_dim}"
-            )
-        latent = {"kv_lora_rank": kv_lora_rank, "nope": qk_nope_head_dim, "rot": qk_rope_head_dim, "v": v_head_dim}
-    elif hidden_size % num_attention_heads or (hidden_size // num_attention_heads) % 2:
-        raise ValueError(
-            f"hidden_size {hidden_size} must split into {num_attention_heads} heads "
-            f"of even width (rotary pairs)"
-        )
-    if attention_class not in ATTENTION_CLASSES or (latent and attention_class != "mha"):
-        raise ValueError(f"attention_class {attention_class!r}: known are {ATTENTION_CLASSES} (latent attention goes with 'mha')")
-    eva = None
-    if attention_class == "eva":
-        if chunk_size <= 0 or window_size <= 0 or window_size % chunk_size:
-            raise ValueError(f"EVA attention needs a window_size in whole chunks, got {window_size} / {chunk_size}")
-        if not 0 <= heads_held <= num_attention_heads:
-            raise ValueError(f"heads_held {heads_held} of {num_attention_heads} heads")
-        eva = {"window": window_size, "chunk": chunk_size}
-    elif heads_held and hybrid is None:
-        raise ValueError("heads_held goes with attention_class 'eva' or a hybrid_override_pattern: no other attention takes a share of the heads")
-    if num_pred_heads < 1 or (num_pred_heads > 1 and tie_word_embeddings):
-        raise ValueError(f"num_pred_heads {num_pred_heads}: at least one, and more than one only with an untied head")
-    if num_experts_per_tok > num_experts:
-        raise ValueError(f"top-{num_experts_per_tok} of {num_experts} experts")
-    if (n_group, topk_group) != (1, 1):
-        raise ValueError(f"group-limited routing (n_group {n_group}, topk_group {topk_group}) is not supported: no cell runs it")
-    if topk_method not in TOPK_METHODS or scoring_func not in moe.SCORING_FUNCS:
-        raise ValueError(
-            f"topk_method {topk_method!r} / scoring_func {scoring_func!r}: known are "
-            f"{TOPK_METHODS} / {moe.SCORING_FUNCS}"
-        )
-    held = experts_held or num_experts
-    if not 0 <= first_expert_held <= num_experts - held:
-        raise ValueError(f"experts [{first_expert_held}, {first_expert_held + held}) are not among the router's {num_experts}")
-    correction_bias = topk_method == "noaux_tc" and "moe" in layer_types
-    apply = functools.partial(
-        _apply, n_heads=num_attention_heads, top_k=num_experts_per_tok,
-        theta=float(rope_theta), eps=float(rms_norm_eps),
-        compute_dtype=jnp.dtype(compute_dtype), remat=remat,
-        rot=qk_rope_head_dim if latent else 0, interleave=bool(rope_interleave),
-        router={
-            key: value
-            for key, value, default in (
-                ("scoring_func", scoring_func, "softmax"),
-                ("norm_topk_prob", bool(norm_topk_prob), False),
-                ("routed_scaling_factor", float(routed_scaling_factor), 1.0),
-            )
-            if value != default
-        },
-        first_expert_held=first_expert_held, eva=eva, unit_offset=bool(norm_add_unit_offset), hybrid=hybrid,
-        residual_dtype=jnp.float32 if fp32_skip_add else None, num_pred_heads=num_pred_heads,
-    )
-    skip = _NOT_MATRICES if decay_matrices_only else _NEVER_DECAYED
-    coefs = dict(lb_coef=router_aux_loss_coef, z_coef=router_z_loss_coef)
-    init = None
-    if hybrid is not None:
-        init = functools.partial(
-            _init_hybrid_params, pattern=pattern, vocab_size=vocab_size, hidden_size=hidden_size, init_std=init_std,
-            residual_layers=(residual_layers or num_hidden_layers) if rescale_prenorm_residual else 1,
-            mamba_heads=m_held, mamba_head_dim=mamba_head_dim,
-            groups=m_held * n_groups // mamba_num_heads if mamba_num_heads else 0,
-            state=ssm_state_size, conv_kernel=conv_kernel, dt_range=(time_step_min, time_step_max, time_step_floor),
-            q_heads=q_held, kv_heads=kv_held, head_dim=hybrid["head_dim"], num_experts=num_experts,
-            experts_held=held, latent=moe_latent_size, expert_width=moe_intermediate_size,
-            shared_width=moe_shared_expert_intermediate_size,
-        )
-    return ModelSpec(
-        name="moe_lm",
-        init=init or functools.partial(
-            _init_params, vocab_size=vocab_size, hidden_size=hidden_size,
-            intermediate_size=intermediate_size, num_experts=num_experts,
-            layer_types=layer_types, tie_word_embeddings=tie_word_embeddings,
-            n_heads=num_attention_heads, latent=latent,
-            moe_intermediate_size=moe_intermediate_size, experts_held=experts_held,
-            n_shared_experts=n_shared_experts, correction_bias=correction_bias,
-            init_std=init_std, eva_heads=(heads_held or num_attention_heads) if eva else 0,
-            unit_offset=norm_add_unit_offset, num_pred_heads=num_pred_heads,
-        ),
-        apply=apply,
-        loss=functools.partial(_loss, **coefs),
-        metrics=functools.partial(_metrics, **coefs),
-        optimizer=optax.adamw(
-            optax.linear_schedule(0.0, learning_rate, lr_warmup_steps) if lr_warmup_steps else learning_rate,
-            b1=0.9, b2=0.95, eps=1e-8, weight_decay=weight_decay,
-            # a correction bias gets no gradient (it only chooses) and no decay:
-            # Adam's update of a leaf whose gradient is always 0 is exactly 0
-            mask=functools.partial(_is_decayed, skip=skip) if correction_bias or decay_matrices_only else None,
-        ),
-        feed=lm_feed,
-        example_batch=functools.partial(_example_batch, seq_len=seq_len),
-        batch_shard_dim=1,
-        predict=functools.partial(_predict, apply=apply),
-        step_counters={
-            **(MOE_COUNTERS if "moe" in layer_types else {}), **(EVA_COUNTERS if eva else {}),
-            **(SSM_COUNTERS if hybrid and "M" in pattern else {}),
-        },
-        after_update=(
-            functools.partial(_update_correction_bias, speed=float(bias_update_speed))
-            if correction_bias else None
-        ),
-        rematerialises=bool(remat),
-    )
+    return spec
+
+
+_DEFAULTS = {name: parameter.default for name, parameter in inspect.signature(model_spec).parameters.items()}

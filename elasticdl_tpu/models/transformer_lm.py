@@ -170,7 +170,7 @@ def _run_blocks(block_fn, x, blocks, rematerialise: bool, ctx: ParallelContext):
     names = sorted(blocks)
     fns = [block_fn] * len(names)
     if rematerialise:
-        fns = remat_lib.plan(block_fn, [(x, blocks[name]) for name in names], ctx.remat_keep_bytes)
+        fns = remat_lib.plan(fns, [(x, blocks[name]) for name in names], ctx.remat_keep_bytes)
     for name, fn in zip(names, fns):
         x = fn(x, blocks[name])
     return x

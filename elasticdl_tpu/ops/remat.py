@@ -193,36 +193,46 @@ def survey(shapes_only: bool = False, traces: Optional[Dict] = None):
         _local.survey = outer
 
 
-def plan(block_fn: Callable, layer_args: Sequence[tuple], budget: int, inputs: int = 1) -> List[Callable]:
-    """The blocks of a model whose layer ``i`` is ``block_fn(*layer_args[i])``
+def _identity(block_fn: Callable):
+    """What tells two blocks apart: a partial's function and what it binds."""
+    return (getattr(block_fn, "func", block_fn), repr(getattr(block_fn, "args", ())), repr(getattr(block_fn, "keywords", {})))
+
+
+def plan(block_fns: Sequence[Callable], layer_args: Sequence[tuple], budget: int, inputs: int = 1) -> List[Callable]:
+    """The blocks of a model whose layer ``i`` is ``block_fns[i](*layer_args[i])``
     (only the arguments' shapes are read), each rematerialised with the
     keep-set :func:`choose` gives it under ``budget`` bytes.  The first
     ``inputs`` arguments of a layer are what its checkpoint holds until the
     backward (the rest are parameters and constants).  One abstract trace a
-    distinct signature of the arguments, whatever the number of layers."""
+    distinct block and signature of the arguments, whatever the number of layers."""
     import jax.numpy as jnp
 
     seen: Optional[Survey] = getattr(_local, "survey", None)
     traced = {} if seen is None else seen.traces
-    # the block's identity: a partial's function and what it binds
-    fn = (getattr(block_fn, "func", block_fn), repr(getattr(block_fn, "args", ())), repr(getattr(block_fn, "keywords", {})))
 
-    def abstract(args):
+    def abstract(block_fn, args):
         shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
-        key = (fn, jax.tree.structure(shapes), tuple((s.shape, s.dtype) for s in jax.tree.leaves(shapes)))
+        key = (_identity(block_fn), jax.tree.structure(shapes), tuple((s.shape, s.dtype) for s in jax.tree.leaves(shapes)))
         if key not in traced:
             traced[key] = trace_sites(block_fn, *shapes)
         return traced[key]
 
-    layers = [abstract(args)[0] for args in layer_args]
+    layers = [abstract(block_fn, args)[0] for block_fn, args in zip(block_fns, layer_args)]
     keep = choose(layers, budget)
     if seen is not None:
         seen.layers.extend(layers)
         seen.keep.extend(keep)
         seen.block_input_bytes += sum(nbytes(args[:inputs]) for args in layer_args)
         if seen.shapes_only:
-            return [lambda *args: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), abstract(args)[1])] * len(layers)
-    # One callable a distinct keep-set: layers that keep the same names are
-    # the same function to jax, traced and transposed once.
-    blocks = {names: rematerialised(block_fn, names) for names in set(keep)}
-    return [blocks[names] for names in keep]
+            zeros = lambda s: jnp.zeros(s.shape, s.dtype)  # noqa: E731
+            return [lambda *args, block_fn=block_fn: jax.tree.map(zeros, abstract(block_fn, args)[1]) for block_fn in block_fns]
+    # One callable a distinct block and keep-set: layers alike that keep the
+    # same names are the same function to jax, traced and transposed once.
+    blocks: Dict[Any, Callable] = {}
+    planned = []
+    for block_fn, names in zip(block_fns, keep):
+        key = (_identity(block_fn), names)
+        if key not in blocks:
+            blocks[key] = rematerialised(block_fn, names)
+        planned.append(blocks[key])
+    return planned

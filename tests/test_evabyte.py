@@ -9,6 +9,7 @@ CPU only."""
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 import sys
@@ -20,7 +21,7 @@ import optax
 import pytest
 
 from elasticdl_tpu.common.config import JobConfig
-from elasticdl_tpu.models import moe_lm
+from elasticdl_tpu.models import attentions, moe_lm
 from elasticdl_tpu.models.spec import load_model_spec
 from elasticdl_tpu.ops import eva_attention as eva_ops
 from elasticdl_tpu.ops.embedding import ParallelContext
@@ -148,10 +149,10 @@ def test_the_two_head_shares_add_up_to_the_uncut_layer():
     assert blk["wq"].shape == (64, 64)
     x = jax.random.normal(jax.random.key(3), (2, KEYS["seq_len"], 64), jnp.float32)
     positions = jnp.arange(KEYS["seq_len"])
-    args = dict(axis=None, n_heads=4, top_k=1, theta=1e5, eps=1e-5, compute_dtype=jnp.float32,
-                eva={"window": 64, "chunk": 8}, unit_offset=True)
-    attend = functools.partial(
-        moe_lm._eva_attention, positions=positions, axis=None, theta=1e5, cast=lambda w: w, window=64, chunk=8)
+    attention = attentions.EvaAttention(held=4, head_dim=16, theta=1e5, window=64, chunk=8)
+    layer = (("attn_norm", attention), ("ffn_norm", moe_lm.GatedMLP(96)))  # the uncut model's, as its family builds it
+    attend = lambda a, blk: dataclasses.replace(attention, held=blk["eva_phi"].shape[0]).apply(  # noqa: E731
+        a, blk, positions, None, lambda w: w)[0]
     a = moe_lm._rms_norm(x, 1.0 + blk["attn_norm"], 1e-5)
     with jax.default_matmul_precision("highest"):
         whole = attend(a, blk)
@@ -166,8 +167,8 @@ def test_the_two_head_shares_add_up_to_the_uncut_layer():
         h = x + sum(parts)
         u = moe_lm._rms_norm(h, 1.0 + blk["ffn_norm"], 1e-5)
         once = h + moe_lm._gated_mlp(u, blk["w_gate"], blk["w_up"], blk["w_down"])
-        layer, _ = moe_lm._block(x, blk, positions, **args)
-    assert float(jnp.max(jnp.abs(once - layer))) <= 1e-5 * float(jnp.max(jnp.abs(layer)))
+        out, _ = moe_lm._block(x, blk, positions, layer, axis=None, eps=1e-5, compute_dtype=jnp.float32, unit_offset=True)
+    assert float(jnp.max(jnp.abs(once - out))) <= 1e-5 * float(jnp.max(jnp.abs(out)))
 
 
 def test_the_residual_stream_is_float32_and_the_blocks_compute_in_bfloat16():
@@ -231,11 +232,11 @@ def test_keys_that_do_not_go_together_raise():
         _spec(window_size=60)
     with pytest.raises(ValueError, match="heads_held"):
         _spec(heads_held=5)
-    with pytest.raises(ValueError, match="heads_held goes with"):
+    with pytest.raises(ValueError, match="heads_held, window_size: set, but no part of the 'olmoe' family reads them"):
         _spec(attention_class="mha", heads_held=2, norm_add_unit_offset=False)
     with pytest.raises(ValueError, match="num_pred_heads"):
         _spec(tie_word_embeddings=True)
-    with pytest.raises(ValueError, match="latent attention goes with"):
+    with pytest.raises(ValueError, match="each name a family"):
         _spec(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
 
 

@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from elasticdl_tpu.common.config import JobConfig
-from elasticdl_tpu.models import moe_lm
+from elasticdl_tpu.models import attentions, moe_lm
 from elasticdl_tpu.models.spec import load_model_spec
 from elasticdl_tpu.ops import moe
 from elasticdl_tpu.parallel.mesh import create_mesh
@@ -397,18 +397,22 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(reference):
     # the model's block as ``_apply`` calls it, a share's weights at a time
     x = params["tok_emb"][batch["tokens"]]
     positions = jnp.arange(x.shape[1])
-    common = dict(
-        axis=None, n_heads=KEYS["num_attention_heads"], top_k=KEYS["num_experts_per_tok"], theta=KEYS["rope_theta"],
-        eps=KEYS["rms_norm_eps"], compute_dtype=jnp.float32, rot=KEYS["qk_rope_head_dim"], interleave=True,
-        router={key: KEYS[key] for key in ("scoring_func", "norm_topk_prob", "routed_scaling_factor")},
-    )
+    common = dict(axis=None, eps=KEYS["rms_norm_eps"], compute_dtype=jnp.float32)
+    attention = attentions.LatentAttention(
+        n_heads=4, rank=32, nope=16, rot=8, v=16, theta=KEYS["rope_theta"], eps=KEYS["rms_norm_eps"], interleave=True)
+
+    def share_of(lo):  # the layer with ``per`` of the 16 experts held from ``lo`` on
+        router = moe_lm.Router(16, KEYS["num_experts_per_tok"], per, lo, tuple(
+            (key, KEYS[key]) for key in ("scoring_func", "norm_topk_prob", "routed_scaling_factor")))
+        return (("attn_norm", attention), ("ffn_norm", moe_lm.RoutedExperts(router, width=32, correction_bias=True, shared_width=64)))
+
     parts, alike = [], None
     for lo in range(0, shares * per, per):
         cut = {**blk, **{name: blk[name][lo:lo + per] for name in ("w_gate", "w_up", "w_down")}}
-        out, stats = moe_lm._block(x, cut, positions, first_expert_held=lo, **common)
+        out, (_, stats) = moe_lm._block(x, cut, positions, share_of(lo), **common)
         # what every chip computes alike: the same block with its held experts' weights zeroed
         zeroed = {**cut, **{name: jnp.zeros_like(cut[name]) for name in ("w_gate", "w_up", "w_down")}}
-        same, _ = moe_lm._block(x, zeroed, positions, first_expert_held=lo, **common)
+        same, _ = moe_lm._block(x, zeroed, positions, share_of(lo), **common)
         alike = same if alike is None else alike
         np.testing.assert_allclose(same, alike, atol=1e-6)  # attention + shared: every chip's alike
         parts.append(out - same)

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from elasticdl_tpu.common.config import JobConfig
-from elasticdl_tpu.models import moe_lm
+from elasticdl_tpu.models import attentions, moe_lm
 from elasticdl_tpu.models.spec import load_model_spec
 from elasticdl_tpu.ops import moe
 from elasticdl_tpu.parallel.mesh import create_mesh
@@ -263,7 +263,7 @@ def test_rope_is_a_complex_rotation_of_the_pairs(position):
     z = x[..., : hd // 2].astype(np.float64) + 1j * x[..., hd // 2:].astype(np.float64)
     turned = z * np.exp(1j * position * theta ** (-2.0 * np.arange(hd // 2) / hd))
     want = np.concatenate([turned.real, turned.imag], -1)
-    got = moe_lm.rope(jnp.asarray(x), jnp.asarray([position]), theta)
+    got = attentions.rope(jnp.asarray(x), jnp.asarray([position]), theta)
     # float32 angles: 4095 rad x 2^-24 is 2.4e-4 rad of turn
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-3 if position else 0.0)
     if position == 0:
@@ -279,7 +279,7 @@ def test_qk_norm_is_taken_over_all_heads_columns(reference, monkeypatch):
         var = jnp.mean(jnp.square(shaped), -1, keepdims=True)
         return (shaped * jax.lax.rsqrt(var + eps)).reshape(x.shape) * scale
 
-    monkeypatch.setattr(moe_lm, "_qk_norm", per_head)
+    monkeypatch.setattr(attentions, "_qk_norm", per_head)
     with pytest.raises(AssertionError):
         _assert_system_is_the_reference(reference, "rehearsal", 1e-5)
 

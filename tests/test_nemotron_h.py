@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from elasticdl_tpu.common.config import JobConfig
-from elasticdl_tpu.models import moe_lm
+from elasticdl_tpu.models import attentions, mamba, moe_lm
 from elasticdl_tpu.models.spec import load_model_spec
 from elasticdl_tpu.ops import moe
 from elasticdl_tpu.parallel.mesh import create_mesh
@@ -189,14 +189,17 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
         if kind == "M":
             blk = params["blocks"]["b01"]
             assert blk["ssm_in"].shape == (32, 64 + (64 + 64) + 8)
-            part = functools.partial(moe_lm._mamba_mixer, u, axis=None, eps=1e-5, cast=cast, state=8, chunk=16)
+            mixer = lambda heads: mamba.MambaMixer(  # noqa: E731
+                heads, head_dim=8, groups=heads // 2, state=8, conv_kernel=4, chunk=16, eps=1e-5, dt_range=(1e-3, 0.1, 1e-4))
+            part = lambda blk: mixer(blk["A_log"].shape[0]).apply(u, blk, None, None, cast)  # noqa: E731
             (whole, counts), shares = part(blk), [part(_share_of_mamba(blk, lo, 2)) for lo in (0, 2, 4, 6)]
             assert float(counts["ssm_positions"]) == sum(float(held["ssm_positions"]) for _, held in shares) == 2 * KEYS["seq_len"] * 8
             parts = [got for got, _ in shares]
         elif kind == "*":
             blk = params["blocks"]["b04"]
             assert blk["wq"].shape == (32, 64) and blk["wk"].shape == (32, 16)
-            part = functools.partial(moe_lm._grouped_query_attention, u, axis=None, cast=cast, head_dim=8)
+            part = lambda blk: attentions.GroupedQueryAttention(  # noqa: E731
+                blk["wq"].shape[1] // 8, blk["wk"].shape[1] // 8, head_dim=8).apply(u, blk, None, None, cast)[0]
             parts = []
             for lo in (0, 2, 4, 6):  # query heads lo, lo + 1 on key/value head lo // 4
                 q, kv = slice(lo * 8, (lo + 2) * 8), slice(lo // 4 * 8, (lo // 4 + 1) * 8)
@@ -205,13 +208,15 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
         else:
             blk = params["blocks"]["b00"]
             assert blk["w_up"].shape == (16, 16, 24)
-            keys = dict(top_k=5, router={"scoring_func": "sigmoid", "norm_topk_prob": True, "routed_scaling_factor": 5.0}, cast=cast)
-            whole, stats = moe_lm._latent_moe(u, blk, first_expert_held=0, **keys)
+            experts = lambda held, lo: moe_lm.LatentMoE(  # noqa: E731
+                moe_lm.Router(16, 5, held, lo, (("scoring_func", "sigmoid"), ("norm_topk_prob", True), ("routed_scaling_factor", 5.0))),
+                latent=16, width=24, shared_width=40)
+            whole, stats = experts(16, 0).apply(u, blk, None, None, cast)
             alike = moe_lm._relu2_mlp(u, blk["ws_up"], blk["ws_down"], "shared_up")
             parts = []
             for lo in (0, 4, 8, 12):
                 share = {**blk, "w_up": blk["w_up"][lo:lo + 4], "w_down": blk["w_down"][lo:lo + 4]}
-                got, held = moe_lm._latent_moe(u, share, first_expert_held=lo, **keys)
+                got, held = experts(4, lo).apply(u, share, None, None, cast)
                 assert float(held["moe_slots_computed"]) == float(held["moe_slots_held"]) < float(stats["moe_slots"])
                 parts.append(got - alike)
             parts.append(alike)  # once
@@ -221,7 +226,7 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
 
 def test_the_step_counters_are_what_the_shapes_give():
     spec = _spec()
-    assert set(spec.step_counters) == set(moe_lm.MOE_COUNTERS) | set(moe_lm.SSM_COUNTERS)
+    assert set(spec.step_counters) == set(moe_lm.MOE_COUNTERS) | set(mamba.SSM_COUNTERS)
     batch = _batch()
     metrics = spec.metrics(spec.apply(spec.init(jax.random.key(0)), batch), batch)
     assert float(metrics["ssm_positions"]) == 2 * 48 * (4 + 4)
@@ -290,8 +295,8 @@ def test_bfloat16_compute_stays_near_the_float32_reference():
     (dict(mlp_hidden_act="silu"), "relu2"),
     (dict(topk_method="greedy"), "correction bias"),
     (dict(moe_latent_size=0), "moe_latent_size"),
-    (dict(layer_types=("moe",) * 5), "do not go with it"),
-    (dict(hybrid_override_pattern=None, num_hidden_layers=1), "go with hybrid_override_pattern"),
+    (dict(layer_types=("moe",) * 5), "layer_types: set, but no part of the 'nemotron_h' family reads it"),
+    (dict(hybrid_override_pattern=None, num_hidden_layers=1), "mamba_heads_held, .*mlp_hidden_act, .*no part of the 'olmoe' family reads them"),
 ], ids=lambda v: v if isinstance(v, str) else "-".join(f"{k}={x}" for k, x in v.items())[:40])
 def test_keys_that_do_not_go_together_raise(keys, match):
     with pytest.raises(ValueError, match=match):

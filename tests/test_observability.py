@@ -473,12 +473,12 @@ def test_a_models_step_counters_are_summed_not_reported(tmp_path, devices):
 
 
 def test_eva_attentions_pairs_ride_the_reports_as_counters(tmp_path, devices):
-    """EVA attention's two counts (``moe_lm.EVA_COUNTERS``, from the
+    """EVA attention's two counts (``attentions.EVA_COUNTERS``, from the
     shapes the attention was called with) through a real worker loop: a
     ``counter`` record a task that grows by sequences x held heads x layers
     x the pairs of one sequence, gauges ``edl_eva_pairs_*_total``, and no
     task reports them as metrics."""
-    from elasticdl_tpu.models import moe_lm
+    from elasticdl_tpu.models import attentions
     from elasticdl_tpu.ops import eva_attention as eva_ops
 
     train = str(tmp_path / "train.rio")
@@ -497,7 +497,7 @@ def test_eva_attentions_pairs_ride_the_reports_as_counters(tmp_path, devices):
     writer = MetricsWriter(str(tmp_path / "metrics"), tensorboard=False)
     servicer = MasterServicer(dispatcher, metrics_writer=writer)
     worker = Worker(config, DirectMasterProxy(servicer), reader, devices=devices[:1])
-    assert dict(worker.spec.step_counters) == moe_lm.EVA_COUNTERS
+    assert dict(worker.spec.step_counters) == attentions.EVA_COUNTERS
     worker.run()
     writer.close()
     records = read_metrics(str(tmp_path / "metrics"))
@@ -508,7 +508,7 @@ def test_eva_attentions_pairs_ride_the_reports_as_counters(tmp_path, devices):
     assert [r["eva_pairs_summary"] for r in counters] == [float(a_task * far * n) for n in range(1, 5)]
     assert all("eva_pairs_exact" not in r for r in records if r["kind"] == "train")
     families = worker.gauges.snapshot()
-    for key in moe_lm.EVA_COUNTERS:
+    for key in attentions.EVA_COUNTERS:
         assert f"edl_{key}_total" in families, key
 
 

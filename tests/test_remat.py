@@ -154,7 +154,10 @@ def test_budget_zero_is_the_program_it_was(model):
     assert "name[" not in ours and "policy=None" in ours
     by_hand = remat.plan
     try:
-        remat.plan = lambda block_fn, layer_args, budget, inputs=1: [jax.checkpoint(block_fn)] * len(layer_args)
+        wrapped = {}  # one checkpoint a distinct block: layers alike are one function to jax
+        remat.plan = lambda block_fns, layer_args, budget, inputs=1: [
+            wrapped.setdefault(repr(block_fn), jax.checkpoint(block_fn)) for block_fn in block_fns
+        ]
         theirs = str(jax.make_jaxpr(jax.grad(loss))(params))
     finally:
         remat.plan = by_hand
