@@ -16,7 +16,9 @@ modules, ``q_lora_rank`` null), H heads, ``nope`` / ``rot`` / ``v`` =
     q      = a Wq                 -> [T, H, nope + rot] = (q_nope, q_rot)
     (c, k_rot) = a Wkv_a          -> c [T, kv_lora_rank], k_rot [T, rot]: ONE rotary key for all heads
     (k_nope, v) = rmsnorm(c, kv_norm) Wkv_b   -> [T, H, nope], [T, H, v]
-    q_rot, k_rot = rope(q_rot), rope(k_rot)   (the rot columns only; ``rope_interleave``: pairs (2i, 2i + 1))
+    q_rot, k_rot = rope(q_rot), rope(k_rot)   (the rot columns only; ``rope_interleave``: pairs (2i, 2i + 1);
+                                              NOT under ``mla_use_nope`` (``kimi_linear``): the rot columns and the
+                                              shared key stay, unturned, and so do the scores' width and scale)
     s_h    = (q_nope_h . k_nope_h + q_rot_h . k_rot) * (nope + rot)^-0.5 ; causal softmax ; o_h = p_h v_h
     part   = o Wo
 
@@ -140,6 +142,7 @@ class LatentAttention(Part):
     theta: float
     eps: float
     interleave: bool
+    rotary: bool = True
 
     def init(self, draw: Draws, d: int):
         # The published shapes: a head's columns of wq are (nope | rot),
@@ -165,8 +168,9 @@ class LatentAttention(Part):
             c = rms_norm(a @ cast(blk["wkv_a"][:, :rank]), blk["kv_norm"], self.eps)
             k_rot = a @ cast(_rotary_columns(blk["wkv_a"][:, rank:], self.interleave))
             k, v = heads(c @ columns(wkv_b[..., :nope])), heads(c @ columns(wkv_b[..., nope:]))
-            q_rot = rope(q_rot, positions, self.theta)
-            k_rot = rope(k_rot[:, :, None, :], positions, self.theta)[:, :, 0]
+            if self.rotary:
+                q_rot = rope(q_rot, positions, self.theta)
+                k_rot = rope(k_rot[:, :, None, :], positions, self.theta)[:, :, 0]
         att = ring_attention(q, k, v, axis_name=axis, causal=True, q_rot=q_rot, k_rot=k_rot)
         with jax.named_scope("mla_proj"):
             return att.reshape(b, l, -1) @ cast(blk["wo"]), None

@@ -10,7 +10,7 @@ defaults and parameter names are OLMoE's (``OLMoE-1B-7B-0125``: every layer
 ``moe``, 64 experts, 8 a token, softmax router, untied head);
 ``kv_lora_rank`` > 0 makes it DeepSeek-V3's block (``kanana-2-30b-a3b``),
 ``attention_class`` ``"eva"`` EvaByte's, ``hybrid_override_pattern``
-``nemotron_h``'s.
+``nemotron_h``'s, ``linear_attn_config`` ``kimi_linear``'s.
 
 The model, with ``rmsnorm(x, g) = x * rsqrt(mean(x^2) + eps) * g``:
 
@@ -66,6 +66,16 @@ With ``hybrid_override_pattern`` (``nemotron_h``'s keys) a layer is ONE
         part = (sum_i w_i relu(c W1[e_i])^2 W2[e_i]) W_up_lat + relu(u Ws1)^2 Ws2     (``mlp_hidden_act`` relu2:
                                                experts and the shared expert are TWO matrices each)
 
+With ``linear_attn_config`` (``kimi_linear``'s keys) a layer is DeepSeek-V3's
+two parts, its mixer read off the config's two lists of layer NUMBERS (from
+1): Kimi Delta Attention (``models/linear_attention.py``) on ``kda_layers``,
+latent attention on ``full_attn_layers`` — with no rotary turn under
+``mla_use_nope`` — and the feed-forward and router of the ``deepseek_v3``
+block under this family's own spelling of the keys (``num_experts_per_token``,
+``num_shared_experts``, ``moe_router_activation_func``, ``moe_renormalize``,
+``use_grouped_topk`` / ``num_expert_group`` / ``topk_group``; always a
+correction bias).
+
 What the heads and experts that are not held would add is left out; the
 all-reduce of the head shares and the experts' exchange are not here.
 
@@ -98,6 +108,7 @@ from jax import lax
 
 from elasticdl_tpu.data.codecs import lm_feed
 from elasticdl_tpu.models.attentions import EvaAttention, GroupedQueryAttention, LatentAttention, QKNormAttention
+from elasticdl_tpu.models.linear_attention import KimiDeltaAttention
 from elasticdl_tpu.models.mamba import MambaMixer
 from elasticdl_tpu.models.parts import Draws, Part
 from elasticdl_tpu.models.parts import rms_norm as _rms_norm  # looked up HERE by the block and the head (the references tap it)
@@ -480,7 +491,7 @@ def _example_batch(batch_size: int, seq_len: int):
 _NEVER_DECAYED = ("router_bias",)
 _NOT_MATRICES = _NEVER_DECAYED + (
     "attn_norm", "ffn_norm", "norm_f", "kv_norm", "q_norm", "k_norm", "eva_phi", "eva_mu",
-    "norm", "ssm_norm", "A_log", "D", "dt_bias", "conv_b",
+    "norm", "ssm_norm", "A_log", "D", "dt_bias", "conv_b", "kda_norm",
 )
 
 
@@ -511,7 +522,7 @@ def _qk_norm_attention(*, hidden_size, num_attention_heads, rope_theta, rms_norm
 
 def _latent_attention(
     *, num_attention_heads, kv_lora_rank, q_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
-    rope_interleave, rope_theta, rms_norm_eps,
+    rope_interleave, rope_theta, rms_norm_eps, mla_use_nope,
 ):
     if q_lora_rank is not None:
         raise ValueError("a low-rank query projection (q_lora_rank) is not supported: no cell runs one")
@@ -522,7 +533,7 @@ def _latent_attention(
         )
     return LatentAttention(
         num_attention_heads, kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
-        float(rope_theta), float(rms_norm_eps), bool(rope_interleave),
+        float(rope_theta), float(rms_norm_eps), bool(rope_interleave), rotary=not mla_use_nope,
     )
 
 
@@ -649,19 +660,76 @@ def _nemotron_h_layers(
     return tuple((("norm", kinds[letter]),) for letter in pattern), 8  # keys of the stream a layer: as the family always split it
 
 
-def _family(*, hybrid_override_pattern, attention_class, kv_lora_rank) -> str:
+def _kimi_linear_router(
+    *, num_experts, num_experts_per_token, experts_held, first_expert_held, moe_router_activation_func, moe_renormalize,
+    routed_scaling_factor, use_grouped_topk, num_expert_group, topk_group,
+):
+    """``kimi_linear``'s spelling of the router's keys, mapped onto :func:`_router`'s: always a
+    correction bias (``e_score_correction_bias``); ``use_grouped_topk`` with one group of which
+    one is taken limits nothing."""
+    if not use_grouped_topk and (num_expert_group, topk_group) != (1, 1):
+        raise ValueError(f"num_expert_group {num_expert_group} / topk_group {topk_group} without use_grouped_topk")
+    return _router(
+        num_experts=num_experts, num_experts_per_tok=num_experts_per_token, experts_held=experts_held,
+        first_expert_held=first_expert_held, scoring_func=moe_router_activation_func, norm_topk_prob=moe_renormalize,
+        routed_scaling_factor=routed_scaling_factor, topk_method="noaux_tc", n_group=num_expert_group, topk_group=topk_group,
+    )
+
+
+def _kimi_linear_feed_forwards(
+    router, correction_bias,
+    *, num_hidden_layers, first_k_dense_replace, moe_layer_freq, intermediate_size, moe_intermediate_size, num_shared_experts,
+):
+    if moe_layer_freq != 1:
+        raise ValueError(f"moe_layer_freq {moe_layer_freq}: every layer after the leading dense ones is an expert layer (no cell runs another)")
+    return _gated_feed_forwards(
+        router, correction_bias, num_hidden_layers=num_hidden_layers, layer_types=None, first_k_dense_replace=first_k_dense_replace,
+        intermediate_size=intermediate_size, moe_intermediate_size=moe_intermediate_size, n_shared_experts=num_shared_experts,
+    )[0]
+
+
+def _kimi_linear_layers(latent_attention, feed_forwards, *, linear_attn_config, num_hidden_layers, rms_norm_eps):
+    config = dict(linear_attn_config)
+    unread = sorted(set(config) - {"kda_layers", "full_attn_layers", "num_heads", "head_dim", "short_conv_kernel_size"})
+    if unread:
+        raise ValueError(f"linear_attn_config: {unread} would be read by nothing")
+    # the two lists number the layers from 1
+    kda, full = set(config["kda_layers"]), set(config["full_attn_layers"])
+    if kda & full or kda | full != set(range(1, num_hidden_layers + 1)):
+        raise ValueError(
+            f"linear_attn_config: kda_layers {sorted(kda)} and full_attn_layers {sorted(full)} must name each of the "
+            f"layers 1..{num_hidden_layers} once"
+        )
+    heads, head_dim, taps = int(config["num_heads"]), int(config["head_dim"]), int(config["short_conv_kernel_size"])
+    if min(heads, head_dim, taps) <= 0:
+        raise ValueError(f"linear_attn_config: num_heads {heads}, head_dim {head_dim}, short_conv_kernel_size {taps}")
+    linear = KimiDeltaAttention(heads, head_dim, taps, float(rms_norm_eps))
+    mixers = [linear if number in kda else latent_attention for number in range(1, num_hidden_layers + 1)]
+    # keys of the stream a layer: a linear-attention layer draws 14 and shared experts 7
+    return tuple((("attn_norm", mixer), ("ffn_norm", feed_forward)) for mixer, feed_forward in zip(mixers, feed_forwards)), 24
+
+
+def _family(*, hybrid_override_pattern, attention_class, linear_attn_config, kv_lora_rank) -> str:
+    """Which family's builders read the keys.  ``hybrid_override_pattern``,
+    ``attention_class`` ``'eva'``, ``linear_attn_config`` and ``kv_lora_rank``
+    each name one, and one model is of one — with ONE rule for a pair:
+    ``linear_attn_config`` decides over ``kv_lora_rank`` (``kimi_linear``'s
+    full-attention layers ARE latent attention: the rank is one of its own
+    keys).  Any other two together are refused."""
     if attention_class not in ATTENTION_CLASSES:
         raise ValueError(f"attention_class {attention_class!r}: known are {ATTENTION_CLASSES}")
     named = [
         family
         for family, said in (
-            ("nemotron_h", hybrid_override_pattern is not None), ("evabyte", attention_class == "eva"), ("deepseek_v3", bool(kv_lora_rank)),
+            ("nemotron_h", hybrid_override_pattern is not None), ("evabyte", attention_class == "eva"),
+            ("kimi_linear", linear_attn_config is not None), ("deepseek_v3", bool(kv_lora_rank) and linear_attn_config is None),
         )
         if said
     ]
     if len(named) > 1:
         raise ValueError(
-            f"hybrid_override_pattern, attention_class 'eva' and kv_lora_rank each name a family and one model is of one: got those of {named}"
+            "hybrid_override_pattern, attention_class 'eva', linear_attn_config and kv_lora_rank each name a family and "
+            f"one model is of one (linear_attn_config alone decides over kv_lora_rank): got those of {named}"
         )
     return named[0] if named else "olmoe"
 
@@ -673,6 +741,8 @@ FAMILIES = {
     "deepseek_v3": lambda own: _two_part_layers(own(_latent_attention), own, draws=12),  # 12 with or without shared experts
     "evabyte": lambda own: _two_part_layers(own(_eva_attention), own),
     "nemotron_h": lambda own: own(functools.partial(_nemotron_h_layers, *own(_router))),
+    "kimi_linear": lambda own: own(functools.partial(
+        _kimi_linear_layers, own(_latent_attention), own(functools.partial(_kimi_linear_feed_forwards, *own(_kimi_linear_router))))),
 }
 
 
@@ -795,6 +865,16 @@ def model_spec(
     kv_heads_held: int = 0,
     rescale_prenorm_residual: bool = False,
     residual_layers: int = 0,
+    # kimi_linear's keys (defaults: OLMoE's block)
+    linear_attn_config: Optional[Dict[str, Any]] = None,
+    mla_use_nope: bool = False,
+    num_experts_per_token: int = 0,
+    num_shared_experts: int = 0,
+    moe_router_activation_func: str = "softmax",
+    moe_renormalize: bool = False,
+    use_grouped_topk: bool = False,
+    num_expert_group: int = 1,
+    moe_layer_freq: int = 1,
 ) -> ModelSpec:
     """``layer_types`` names each layer's feed-forward, ``"moe"`` or
     ``"dense"`` (both gated; dense layers ``intermediate_size`` wide, experts
@@ -828,9 +908,17 @@ def model_spec(
     ``moe_shared_expert_intermediate_size`` wide); ``rescale_prenorm_residual``
     scales the matrices that write into the stream by ``residual_layers``^-1/2
     (0: this model's depth).  Every held count 0 = all.
+    ``linear_attn_config`` (``kimi_linear``): ``{"kda_layers", "full_attn_layers"``
+    (layer numbers from 1), ``"num_heads", "head_dim", "short_conv_kernel_size"}``:
+    Kimi Delta Attention on the first list's layers, latent attention (the
+    ``deepseek_v3`` keys; ``mla_use_nope``: no rotary turn) on the second's;
+    the router and the feed-forwards are ``deepseek_v3``'s under this family's
+    spelling (``num_experts_per_token``, ``num_shared_experts``,
+    ``moe_router_activation_func``, ``moe_renormalize``, ``use_grouped_topk`` /
+    ``num_expert_group``, ``moe_layer_freq`` 1).
 
     Which FAMILY the model is of follows from ``hybrid_override_pattern``,
-    ``attention_class`` and ``kv_lora_rank`` (:func:`_family`); the family's
+    ``attention_class``, ``linear_attn_config`` and ``kv_lora_rank`` (:func:`_family`); the family's
     builders take their own keys and check their ranges, and a key that no
     builder of the chosen family reads, set to other than its default, is
     refused: it would change nothing."""
@@ -849,7 +937,8 @@ def model_spec(
     if foreign:
         raise ValueError(
             f"{', '.join(foreign)}: set, but no part of the {family!r} family reads "
-            f"{'it' if len(foreign) == 1 else 'them'} (the family follows from hybrid_override_pattern / attention_class / kv_lora_rank)"
+            f"{'it' if len(foreign) == 1 else 'them'} (the family follows from hybrid_override_pattern / attention_class / "
+            f"linear_attn_config / kv_lora_rank)"
         )
     return spec
 

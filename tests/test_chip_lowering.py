@@ -817,6 +817,72 @@ def test_nemotron3_step_compiles_for_v5e_with_its_scopes_and_the_flash_kernels_l
     assert len(patterns) == 3 and params["remat"]
 
 
+def test_kimi_linear_step_compiles_for_v5e_inside_the_line_with_its_scopes_and_the_flash_kernels_lists(
+    v5e_device, olmoe_as_on_the_chip, path_lines, monkeypatch
+):
+    """``kimi_linear_job``'s real step (Kimi Linear's widths: the dense layer
+    and four expert layers of 8 held experts, Kimi Delta Attention on four of
+    the five layers and latent attention on the fourth; ONE sequence of 8192
+    tokens, the traffic's two steps a dispatch, per-layer rematerialisation)
+    compiled for a described v5e with the byte budget the trainer resolves
+    from a v5e's memory: 602.4 M parameters and their moments are 6.73 GiB of
+    arguments, the layers keep every save site and the step stays between
+    11.5 GiB and the trainer's line of 14.25 AT THE FIRST COMPILE (the
+    trainer's loop over a step that reads over the line compiles again, two
+    minutes a time; with a sequence's chunks all at once the op's masks and
+    solves put this step at 15.46 GiB, and at 15.89 with nothing kept: PR
+    47); the device scopes the ``.kda`` / ``.tok`` / ``.mla`` metrics read
+    are there; the latent-attention layer is the three flash kernels at the
+    operand lists ``flash_roofline_pct.mla`` reads (5 / 6 + 1 / 6 + 2: the
+    rotary columns are handed over, unturned), the forward once; the delta
+    rule is XLA products under ``kda_scan`` with a triangular solve a group
+    of chunks and NO array of a sequence's pairwise differences or column
+    factors; the experts are grouped matmuls; and no [*, 8192, 8192] score
+    matrix exists."""
+    import json
+    import os
+
+    from elasticdl_tpu.parallel import trainer as trainer_lib
+
+    monkeypatch.setattr(trainer_lib, "device_bytes_limit", lambda devices: V5E_BYTES_LIMIT[0])
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "kimi_linear_48b_a3b_ep32_l5.json")) as f:
+        params = json.load(f)["model_params"]
+    with open(os.path.join(root, "benchmark", "traffic", "job_seq8k_x1_v20480.json")) as f:
+        traffic = json.load(f)
+    spec = load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", **params)
+    mesh = create_mesh([v5e_device], num_devices=1)
+    trainer = Trainer(spec, JobConfig(distribution_strategy=DistributionStrategy.ALLREDUCE), mesh)
+    step, args = _abstract_scan_step(
+        trainer, mesh, minibatch=traffic["minibatch_size"], steps=traffic["minibatches_per_task"])
+    compiled = step.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    total, plan = trainer_lib.compiled_bytes(compiled), trainer.keep_plan
+    assert 11.5 * 2**30 < total < plan.line == V5E_BYTES_LIMIT[0] - trainer_lib.REMAT_HEADROOM, total / 2**30
+    assert abs(compiled.memory_analysis().argument_size_in_bytes - 12 * 602434432) < 2**20  # parameters and two moments
+    assert plan.kept == plan.tagged <= plan.budget and plan.tagged > 2 * 2**30
+    text = compiled.as_text()
+    scopes = ("kda_proj", "kda_glue", "kda_scan", "ssm_conv", "mla_proj", "flash_attn", "moe_shared", "moe_router",
+              "moe_dispatch", "moe_experts", "moe_combine", "mlp", "lm_head")
+    for scope in scopes:
+        assert re.search(rf'op_name="[^"]*\b{scope}\b', text), scope
+    assert not re.search(r'op_name="[^"]*\bssm_(proj|scan|norm)\b', text)  # the state-space family's: not this model's
+    assert not re.search(r"\[(\d+,)*8192,8192\]", text)
+    flash = _flash_calls(text)
+    assert _signatures("\n".join(flash)) == [(5, 0), (6, 1), (6, 2)]  # ONE latent-attention layer, the forward ONCE: its output is kept
+    mosaic = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    under = lambda scope: [c for c in mosaic if re.search(rf'op_name="[^"]*\b{scope}\b', c)]  # noqa: E731
+    assert under("moe_experts") and len(under("moe_experts")) % 4 == 0 and not under("kda_scan")  # no kernel of the op's yet
+    scoped = [line for line in text.splitlines() if re.search(r'op_name="[^"]*\bkda_scan\b', line)]
+    # a GROUP of 8 chunks at a time: the same-sub-block differences are [.., 8, 32, 4, 16, 16, 128], never a sequence's 128 chunks
+    shapes = set(re.findall(r"f32\[([\d,]+)\]", "\n".join(line.split(" = ")[1].split("(")[0] for line in scoped if " = " in line)))
+    assert any(shape.endswith("4,16,16,128") for shape in shapes) and not any(re.search(r"\b128,32,4,(16,16|64),128$", shape) for shape in shapes)
+    assert any("attention path: pallas-compiled" in line and "rotary=64" in line for line in path_lines), path_lines
+    # the patterns of the roofline entry the cell joined tell exactly these three apart
+    with open(os.path.join(root, "benchmark", "metrics", "flash_roofline_pct.mla.json")) as f:
+        patterns = [k["pattern"] for k in json.load(f)["params"]["kernels"]]
+    assert len(patterns) == 3 and params["remat"]
+
+
 #: sha256 of ``gpt2_medium``'s step lowered for the chip (StableHLO text,
 #: 16 sequences of 1024, two steps a dispatch): a PR that may not move
 #: ``gpt2m_job`` pins that its program is the same to the byte.  A PR that
@@ -889,6 +955,9 @@ MOE_LM_STEP_SHA256 = {
     # PINNED in PR 45 at the values its PARENT commit (9611bdf) gives, ahead of parting the file along its layers
     ("evabyte_6b5_tp2_l4", "job_seq16k"): "87b44ef8533274d8216ec8aaeaaeb214522f60bb60cad971e1e29df4bf74797a",
     ("nemotron3_super_tp4_ep64_l11", "job_seq8k_x1"): "8c69791bf49f57535a34227915cc3bde193bb61cdbe82ca9b0fdda3a4a6edc2e",
+    # PINNED in PR 47, which added the family ``kimi_linear`` (a part, a builder's line, a field of ``LatentAttention``,
+    # a second caller of ``ops/ssm.causal_conv``): the four above are the values they had, and this is the new cell's
+    ("kimi_linear_48b_a3b_ep32_l5", "job_seq8k_x1_v20480"): "7f1534c89a343849bd28f0a666493f38f467dd96791693fe79c6164f083152be",
 }
 
 

@@ -1,0 +1,160 @@
+"""``ops/delta_rule``: the chunked gated delta rule against its
+position-by-position reference, forward and every gradient, at chunks that do
+and do not divide into sub-blocks, with decays at both ends of the published
+range; the three seams; the gated norm a head."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elasticdl_tpu.ops import delta_rule as dr
+
+#: a head's -exp(A_log): the published init's slowest, a middle one and its fastest
+RATES = jnp.array([1.0, 8.0, 16.0])
+
+
+def _operands(seed: int = 0, *, b=2, length=96, dk=8, dv=6, softplus_at: float = -4.0, dtype=jnp.float32):
+    """l2-normalised q (scaled) and k, v, a log-decay a channel ``-rate x
+    softplus(normal + softplus_at)`` and beta in (0, 1), seeded.
+    ``softplus_at`` -4: decays near 1 (a channel keeps 0.98 a position at the
+    slowest); +2: softplus about 2, the fastest head's channels lose exp(-32)
+    and more a position, exp(-500) across a sub-block."""
+    ks = jax.random.split(jax.random.key(seed), 5)
+    heads = RATES.shape[0]
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (b, length, heads, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, length, heads, dk)))
+    v = jax.random.normal(ks[2], (b, length, heads, dv))
+    g = -RATES[None, None, :, None] * jax.nn.softplus(jax.random.normal(ks[3], (b, length, heads, dk)) + softplus_at)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, length, heads)))
+    return tuple(t.astype(dtype) for t in (q, k, v)) + (g, beta)
+
+
+def _close(got, want, rel):
+    assert got.shape == want.shape
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) <= rel * float(jnp.max(jnp.abs(want)))
+
+
+#: (chunk, L): 64 and 32 are whole sub-blocks of 16, 24 is not (padded to 32), 8 is under one
+CHUNKS = [(64, 128), (32, 96), (24, 96), (8, 96)]
+
+
+@pytest.mark.parametrize("softplus_at", [-4.0, 2.0], ids=["decays_near_1", "decays_near_exp_-16_softplus"])
+@pytest.mark.parametrize("chunk,length", CHUNKS, ids=[f"chunk{c}" for c, _ in CHUNKS])
+def test_the_chunked_rule_is_the_recurrence_forward_and_in_every_gradient(chunk, length, softplus_at):
+    args = _operands(length=length, softplus_at=softplus_at)
+    assert (float(args[3].min()) < -50) == (softplus_at > 0)  # exp(50 x 16 positions) leaves float32: a whole-chunk quotient fails here
+    with jax.default_matmul_precision("highest"):
+        want, last = dr.delta_rule_reference(*args)
+        o, aux = dr.delta_rule(*args, chunk=chunk, with_aux=True)
+        _close(o, want, 5e-6)
+        _close(aux.state, last, 5e-6)
+        weigh = jax.random.normal(jax.random.key(9), want.shape)
+        got = jax.grad(lambda *a: jnp.sum(dr.delta_rule(*a, chunk=chunk) * weigh), argnums=(0, 1, 2, 3, 4))(*args)
+        ref = jax.grad(lambda *a: jnp.sum(dr.delta_rule_reference(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(*args)
+    assert all(bool(jnp.all(jnp.isfinite(t))) for t in got)
+    for name, a, b in zip("q k v g beta".split(), got, ref):
+        assert float(jnp.max(jnp.abs(a - b))) <= 5e-5 * float(jnp.max(jnp.abs(b))), name
+
+
+def test_a_quotient_over_a_whole_chunk_would_overflow_where_the_sub_block_form_does_not():
+    """Why the reference points exist: ``exp(G_r) / exp(G_i)`` over a chunk
+    of 64 is ``0 / 0`` at the published range's fast end."""
+    q, k, v, g, beta = _operands(length=64, softplus_at=2.0)
+    cum = jnp.cumsum(g, axis=1)
+    with np.errstate(all="ignore"):
+        quotient = jnp.exp(cum[:, -1]) / jnp.exp(cum[:, 0])
+    assert not bool(jnp.all(jnp.isfinite(quotient))) or float(jnp.min(jnp.exp(cum[:, 20]))) == 0.0
+    assert bool(jnp.all(jnp.isfinite(dr.delta_rule(q, k, v, g, beta, chunk=64))))
+
+
+def test_the_summed_log_decays_are_float32_whatever_the_operands_are():
+    q, k, v, g, beta = _operands(dtype=jnp.bfloat16)
+    o, aux = dr.delta_rule(q, k, v, g, beta, chunk=32, with_aux=True)
+    assert o.dtype == jnp.bfloat16 and aux.log_decay.dtype == aux.state.dtype == jnp.float32
+    want = np.asarray(g, np.float64).reshape(2, 3, 32, 3, 8).cumsum(2).reshape(g.shape)
+    np.testing.assert_allclose(np.asarray(aux.log_decay, np.float64), want, rtol=2e-6)
+    # bfloat16 operands stay near the float32 recurrence on the same operands
+    ref, _ = dr.delta_rule_reference(q, k, v, g, beta)
+    _close(o, ref, 3e-2)
+
+
+def test_the_three_seams_are_called_and_each_changes_the_result(monkeypatch):
+    """The benchmark's controls swap ``_log_decays``, ``_carry`` and
+    ``_solve`` by module attribute: each is looked up at call time, forward
+    and backward, and each one's fault shows."""
+    args = _operands(length=128)
+    sound = dr.delta_rule(*args, chunk=32)
+    loss = lambda *a: jnp.sum(dr.delta_rule(*a, chunk=32) ** 2)  # noqa: E731
+    sound_grad = jax.grad(loss, argnums=1)(*args)
+    decays, carry = dr._log_decays, dr._carry
+    faults = {
+        "_log_decays": lambda g, chunk: decays(g, chunk).astype(jnp.bfloat16).astype(jnp.float32),
+        "_carry": lambda *a, **kw: (lambda starts, last: (jnp.zeros_like(starts), last))(*carry(*a, **kw)),
+        "_solve": lambda a, rhs: rhs,
+    }
+    for name, fault in faults.items():
+        with monkeypatch.context() as patch:
+            patch.setattr(dr, name, fault)
+            off = float(jnp.max(jnp.abs(dr.delta_rule(*args, chunk=32) - sound)) / jnp.max(jnp.abs(sound)))
+            off_grad = float(jnp.max(jnp.abs(jax.grad(loss, argnums=1)(*args) - sound_grad)) / jnp.max(jnp.abs(sound_grad)))
+        assert off > (1e-4 if name == "_log_decays" else 1e-2) and off_grad > 1e-4, (name, off, off_grad)
+
+
+def test_without_the_delta_correction_the_rule_is_gated_linear_attention(monkeypatch):
+    """``T = Diag(beta)``: ``S_t = Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T``
+    inside a chunk — what the control ``no_delta_correction`` runs; with one
+    chunk a sequence it is plain gated linear attention throughout."""
+    q, k, v, g, beta = _operands(length=32)
+    monkeypatch.setattr(dr, "_solve", lambda a, rhs: rhs)
+    with jax.default_matmul_precision("highest"):
+        got = dr.delta_rule(q, k, v, g, beta, chunk=32)
+
+        def step(state, at):
+            q_t, k_t, v_t, g_t, b_t = at
+            state = jnp.exp(g_t)[..., None] * state + (b_t[..., None] * k_t)[..., :, None] * v_t[..., None, :]
+            return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+        first = jnp.zeros((2, 3, 8, 6))
+        _, want = jax.lax.scan(step, first, tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)))
+    _close(got, jnp.moveaxis(want, 0, 1), 1e-5)
+
+
+def test_a_sequence_that_is_not_whole_chunks_takes_the_stepwise_path():
+    args = _operands(length=40)
+    assert dr.rule_path(40, 16) == (dr.PATH_STEPWISE, "L = 40 is not whole chunks of 16") and dr.rule_path(48, 16) == (dr.PATH_CHUNKED, "")
+    with jax.default_matmul_precision("highest"):
+        o, aux = dr.delta_rule(*args, chunk=16, with_aux=True)
+        want, last = dr.delta_rule_reference(*args)
+    _close(o, want, 1e-6)
+    _close(aux.state, last, 1e-6)
+    np.testing.assert_allclose(aux.log_decay, jnp.cumsum(args[3], axis=1), rtol=1e-5)
+    grads = jax.grad(lambda *a: jnp.sum(dr.delta_rule(*a, chunk=16) ** 2), argnums=(0, 1, 2, 3, 4))(*args)
+    assert all(bool(jnp.all(jnp.isfinite(t))) and float(jnp.max(jnp.abs(t))) > 0 for t in grads)
+
+
+def test_shapes_that_do_not_agree_are_refused():
+    q, k, v, g, beta = _operands()
+    with pytest.raises(ValueError, match="shapes do not agree"):
+        dr.delta_rule(q, k, v, g[..., :4], beta)
+    with pytest.raises(ValueError, match="shapes do not agree"):
+        dr.delta_rule(q, k, v, g, beta[:, :, :2])
+
+
+def test_rule_flops_counts_the_chunked_forms_products():
+    # a head and position at chunk 64, dk = dv = 128: the two masks, the solve's triangle, P U, three dk x dv products
+    assert dr.rule_flops(1, 1, 1, 128, 128, 64) == 4 * 64 * 128 + 64 * 256 + 2 * 64 * 128 + 6 * 128 * 128 == 163840
+    assert dr.rule_flops(1, 8192, 32, 128, 128, 64) == 8192 * 32 * 163840
+
+
+def test_the_gated_norm_a_head_norms_first_and_gates_with_a_sigmoid():
+    ks = jax.random.split(jax.random.key(0), 3)
+    o, gate = jax.random.normal(ks[0], (2, 5, 3, 8)), jax.random.normal(ks[1], (2, 5, 3, 8))
+    gain = 1.0 + 0.3 * jax.random.normal(ks[2], (8,))
+    got = dr.gated_head_norm(o, gate, gain, 1e-5)
+    np.testing.assert_allclose(got, dr.gated_head_norm_reference(o, gate, gain, 1e-5), rtol=2e-6, atol=1e-6)
+    # not ops/ssm.gated_group_norm (silu gate BEFORE the norm): a head's rms is the gain's before the gate
+    ungated = dr.gated_head_norm(o, jnp.full_like(gate, 50.0), jnp.ones((8,)), 0.0)
+    np.testing.assert_allclose(jnp.sqrt(jnp.mean(ungated ** 2, -1)), 1.0, rtol=1e-5)
+    assert dr.gated_head_norm(o.astype(jnp.bfloat16), gate, gain, 1e-5).dtype == jnp.bfloat16

@@ -1,0 +1,351 @@
+"""``moe_lm`` under ``kimi_linear``'s keys against its plain reference
+(``benchmark/configs/kimi_linear_48b_a3b_ep32_l5_reference.py``): the part,
+the model's logits, loss, every gradient leaf, two AdamW steps and the
+correction bias; the shares tied to the uncut layer; latent attention
+without a rotary turn; the family's rule and what is refused."""
+
+import dataclasses
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elasticdl_tpu.common.config import JobConfig
+from elasticdl_tpu.models import attentions, linear_attention, moe_lm
+from elasticdl_tpu.models.spec import load_model_spec
+from elasticdl_tpu.parallel.mesh import create_mesh
+from elasticdl_tpu.parallel.trainer import Trainer
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = os.path.join(ROOT, "benchmark")
+if BENCH_DIR not in sys.path:
+    sys.path.insert(0, BENCH_DIR)
+
+import resolve  # noqa: E402
+
+#: kimi_linear's keys at a small size, in the PUBLISHED spelling: a leading dense layer, two KDA layers and one
+#: latent-attention layer, 4 of 16 experts top-3 and one shared, a sequence of two chunks of 64.
+KEYS = dict(
+    vocab_size=96, hidden_size=32, num_attention_heads=4, num_hidden_layers=3,
+    linear_attn_config={"kda_layers": [1, 2], "full_attn_layers": [3], "num_heads": 4, "head_dim": 8, "short_conv_kernel_size": 4},
+    mla_use_nope=True, kv_lora_rank=16, q_lora_rank=None, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, rope_theta=10000,
+    num_experts=16, experts_held=4, first_expert_held=4, num_experts_per_token=3, intermediate_size=48, moe_intermediate_size=24,
+    num_shared_experts=1, first_k_dense_replace=1, moe_layer_freq=1, moe_router_activation_func="sigmoid", moe_renormalize=True,
+    routed_scaling_factor=2.446, use_grouped_topk=True, num_expert_group=1, topk_group=1, bias_update_speed=0.001,
+    rms_norm_eps=1e-5, tie_word_embeddings=False, decay_matrices_only=True, seq_len=128, learning_rate=3e-4, weight_decay=0.1,
+    lr_warmup_steps=10, router_aux_loss_coef=0.0, router_z_loss_coef=0.0,
+)
+KDA = ("kda_wq", "kda_wk", "kda_wv", "kda_conv_q", "kda_conv_k", "kda_conv_v", "A_log", "dt_bias", "kda_wf_a", "kda_wf_b",
+       "kda_wb", "kda_wg_a", "kda_wg_b", "kda_norm", "kda_wo")
+MLA = ("wq", "wkv_a", "kv_norm", "wkv_b", "wo")
+DENSE = ("w_gate", "w_up", "w_down")
+EXPERTS = ("router", "w_gate", "w_up", "w_down", "ws_gate", "ws_up", "ws_down")
+LAYERS = [KDA + DENSE, KDA + EXPERTS, MLA + EXPERTS]
+LEAVES = ["tok_emb", "norm_f", "head"] + [
+    f"blocks/b{i:02d}/{name}" for i, names in enumerate(LAYERS) for name in ("attn_norm", "ffn_norm") + names
+]
+
+
+@functools.lru_cache(maxsize=None)
+def reference():
+    return resolve.load_module(os.path.join(BENCH_DIR, "configs", "kimi_linear_48b_a3b_ep32_l5_reference.py"))
+
+
+def _spec(dtype: str = "float32", **kw):
+    return load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype=dtype, **{**KEYS, **kw})
+
+
+def _weights(spec, seed: int = 0):
+    """Seeded weights away from the init's symmetries: gains that are not 1,
+    matrices five times the init's scale (a trained model's decays and gates
+    are not the init's near-constants)."""
+    params = spec.init(jax.random.key(seed))
+    keys = iter(jax.random.split(jax.random.key(seed + 1), len(jax.tree.leaves(params))))
+
+    def moved(path, a):
+        name = path[-1].key
+        if name in ("A_log", "dt_bias", "router_bias") or name.startswith("kda_conv"):
+            return a
+        if name.endswith("norm") or name == "norm_f":
+            return a + 0.3 * jax.random.normal(next(keys), a.shape)
+        return a * 5.0
+
+    return jax.tree_util.tree_map_with_path(moved, params)
+
+
+def _batch(b: int = 2, seed: int = 0, l: int = KEYS["seq_len"]):
+    toks = np.random.default_rng(seed).integers(0, KEYS["vocab_size"], (b, l + 1)).astype(np.int32)
+    return {"tokens": jnp.asarray(toks[:, :-1]), "labels": jnp.asarray(toks[:, 1:])}
+
+
+@functools.lru_cache(maxsize=None)
+def _system_and_reference():
+    import optax
+
+    spec = _spec()
+    params, batch = _weights(spec), _batch()
+    forward = reference().build(dict(KEYS))
+
+    def ref_loss(w):
+        z, slots = forward(w, batch["tokens"])
+        return optax.softmax_cross_entropy_with_integer_labels(z, batch["labels"]).mean(), (z, slots)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(lambda w: spec.loss(spec.apply(w, batch, train=True), batch))(params)
+        want = jax.value_and_grad(ref_loss, has_aux=True)(params)
+        out = spec.apply(params, batch)
+    return got, want, out
+
+
+def _leaf(tree, path: str):
+    for key in path.split("/"):
+        tree = tree[key]
+    return tree
+
+
+def test_float32_system_gives_the_references_logits_loss_slots_and_gradient_in_every_leaf():
+    (loss, _), ((want, (want_logits, want_slots)), _), out = _system_and_reference()
+    logits = out["logits"]
+    assert logits.shape == want_logits.shape == (2, KEYS["seq_len"], 96) and logits.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(logits - want_logits))) <= 2e-5 * float(jnp.max(jnp.abs(want_logits)))
+    assert abs(float(loss) - float(want)) <= 1e-6 * float(want)
+    # the routers' counts, which the correction bias's rule reads: all 16 experts, 3 slots a token, two expert layers
+    np.testing.assert_array_equal(np.asarray(out["router_slots"]), np.asarray(want_slots))
+    assert out["router_slots"].shape == (2, 16) and float(out["router_slots"].sum()) == 2 * 2 * 128 * 3
+
+
+    # ... and the gradient in EVERY leaf, in the same test: the two sides are computed once a process (two minutes
+    # of compiles) and a test of their own would make them again on another worker of the suite.  3e-4 of a leaf's
+    # largest entry: float32 sums in another order (the chunked rule against 128 steps of the recurrence)
+    (_, grads), (_, want), _ = _system_and_reference()
+    assert len(jax.tree.leaves(grads)) == len(LEAVES) + 2  # and the two correction biases, which get none
+    for leaf in LEAVES:
+        got, ref = _leaf(grads, leaf), _leaf(want, leaf)
+        assert got.shape == ref.shape and float(jnp.max(jnp.abs(ref))) > 0, leaf
+        assert float(jnp.max(jnp.abs(got - ref))) <= 3e-4 * float(jnp.max(jnp.abs(ref))), leaf
+    for name in ("b01", "b02"):
+        assert float(jnp.max(jnp.abs(grads["blocks"][name]["router_bias"]))) == 0.0
+
+
+def test_the_part_alone_is_the_references_linear_attention():
+    """``KimiDeltaAttention.apply`` on a normed stream against the reference's
+    mixer on the same parameters: the convolutions, silu, l2norm, the decay,
+    the rule, the gated norm a head and ``Wo``."""
+    spec = _spec()
+    blk = _weights(spec)["blocks"]["b01"]
+    u = jax.random.normal(jax.random.key(3), (2, KEYS["seq_len"], 32))
+    part = linear_attention.KimiDeltaAttention(heads=4, head_dim=8, conv_kernel=4, eps=1e-5)
+    with jax.default_matmul_precision("highest"):
+        got, counts = part.apply(u, blk, None, None, lambda w: w)
+        # the reference's layer is mixer + feed-forward: the mixer alone is what it adds before the second norm
+        forward = reference().build(dict(KEYS))
+        silent = {**blk, **{name: jnp.zeros_like(blk[name]) for name in ("w_down", "ws_down")}}  # the feed-forward adds nothing
+        want = forward.layer(u, {**silent, "attn_norm": jnp.ones((32,))})[0] - u
+        normed = u * jax.lax.rsqrt(jnp.mean(u * u, -1, keepdims=True) + 1e-5)
+        got_normed, _ = part.apply(normed, blk, None, None, lambda w: w)
+    assert float(jnp.max(jnp.abs(got_normed - want))) <= 2e-5 * float(jnp.max(jnp.abs(want)))
+    assert float(counts["kda_positions"]) == float(counts["kda_positions_chunked"]) == 2 * 128 * 4
+    assert float(jnp.max(jnp.abs(got - got_normed))) > 0
+
+
+def test_two_adamw_steps_and_the_correction_bias_are_the_references():
+    """The trainer's own step twice on one minibatch against AdamW written
+    out on the REFERENCE's gradients (this file's, float32): every leaf's
+    change in each step to 1 % of its size (Adam's first steps are rate x
+    g / (|g| + 1e-8): where float32 noise is a share of a small entry it is
+    the same share of that entry's step), the correction biases TO THE BIT."""
+    import optax
+
+    spec = _spec("float32", lr_warmup_steps=0, learning_rate=1e-3)
+    ref = reference()
+    forward = ref.build(dict(KEYS))
+    batch = _batch()
+    trainer = Trainer(spec, JobConfig(), create_mesh(num_devices=1))
+    state = trainer.init_state(jax.random.key(0))
+    w = jax.tree.map(lambda a: jnp.asarray(np.asarray(a)), state.params)  # a copy: the step donates its state
+    m = jax.tree.map(jnp.zeros_like, w)
+    nu = jax.tree.map(jnp.zeros_like, w)
+    decayed = ref.decayed(w)
+    assert decayed == moe_lm._is_decayed(w, moe_lm._NOT_MATRICES)
+
+    def ref_loss(w):
+        z, slots = forward(w, batch["tokens"])
+        return optax.softmax_cross_entropy_with_integer_labels(z, batch["labels"]).mean(), slots
+
+    with jax.default_matmul_precision("highest"):
+        for t in (1, 2):
+            state, metrics = trainer.train_step(state, trainer.shard_batch({k: np.asarray(v) for k, v in batch.items()}))
+            (loss, slots), grads = jax.value_and_grad(ref_loss, has_aux=True)(w)
+            assert float(metrics["loss"]) == pytest.approx(float(loss), rel=2e-6)
+            m = jax.tree.map(lambda m, g: 0.9 * m + 0.1 * g, m, grads)
+            nu = jax.tree.map(lambda v, g: 0.95 * v + 0.05 * g * g, nu, grads)
+            step = jax.tree.map(
+                lambda m, v, p, d: (m / (1 - 0.9 ** t)) / (jnp.sqrt(v / (1 - 0.95 ** t)) + 1e-8) + (0.1 * p if d else 0.0), m, nu, w, decayed)
+            before = w
+            w = ref.update_bias(jax.tree_util.tree_map_with_path(
+                lambda path, p, s: p if path[-1].key == "router_bias" else p - 1e-3 * s, w, step), slots, KEYS["bias_update_speed"])
+            for (path, got), want, was in zip(jax.tree_util.tree_leaves_with_path(state.params), jax.tree.leaves(w), jax.tree.leaves(before)):
+                if path[-1].key == "router_bias":
+                    np.testing.assert_array_equal(np.asarray(got), np.asarray(want), err_msg=str(path))
+                    assert float(jnp.max(jnp.abs(got))) <= t * 0.001 + 1e-9 and float(jnp.max(jnp.abs(got - was))) == pytest.approx(0.001)
+                else:
+                    assert float(jnp.linalg.norm(got - want)) <= 1e-2 * float(jnp.linalg.norm(want - was)), (t, path)
+
+
+def test_the_parameters_are_the_held_share_of_the_published_shapes():
+    shapes = jax.tree.map(lambda a: a.shape, jax.eval_shape(_spec().init, jax.random.key(0)))
+    assert sorted(shapes["blocks"]) == ["b00", "b01", "b02"]
+    for i, names in enumerate(LAYERS):
+        bias = ("router_bias",) if "router" in names else ()
+        assert sorted(shapes["blocks"][f"b{i:02d}"]) == sorted(("attn_norm", "ffn_norm") + names + bias), i
+    kda, mla = shapes["blocks"]["b01"], shapes["blocks"]["b02"]
+    assert kda["kda_wq"] == kda["kda_wk"] == kda["kda_wv"] == (32, 32) and kda["kda_wo"] == (32, 32)
+    assert kda["kda_conv_q"] == (4, 32) and kda["A_log"] == (4,) and kda["dt_bias"] == (32,) and kda["kda_norm"] == (8,)
+    assert kda["kda_wf_a"] == kda["kda_wg_a"] == (32, 8) and kda["kda_wf_b"] == kda["kda_wg_b"] == (8, 32) and kda["kda_wb"] == (32, 4)
+    assert mla["wq"] == (32, 4 * 12) and mla["wkv_a"] == (32, 16 + 4) and mla["wkv_b"] == (16, 4 * 16) and mla["wo"] == (32, 32)
+    assert kda["router"] == (32, 16) and kda["w_up"] == (4, 32, 24) and kda["ws_up"] == (32, 24)  # 4 of 16 held; ONE shared expert
+    assert shapes["blocks"]["b00"]["w_up"] == (32, 48)
+
+
+def test_the_expert_shares_add_up_to_the_uncut_layer_with_the_shared_expert_counted_once():
+    """Guide section 4: 4 shares of 4 of 16 experts (a stand-in for 32
+    shares of 8 of 256) give parts that add up to the uncut layer's; what
+    every chip computes alike (the shared expert) is counted once."""
+    uncut = _spec(experts_held=0, first_expert_held=0)
+    blk = _weights(uncut)["blocks"]["b01"]
+    assert blk["w_up"].shape == (16, 32, 24)
+    u = jax.random.normal(jax.random.key(3), (2, KEYS["seq_len"], 32), jnp.float32)
+    cast = lambda w: w  # noqa: E731
+    keys = (("scoring_func", "sigmoid"), ("norm_topk_prob", True), ("routed_scaling_factor", 2.446))
+    experts = lambda held, lo: moe_lm.RoutedExperts(moe_lm.Router(16, 3, held, lo, keys), width=24, correction_bias=True, shared_width=24)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        whole, stats = experts(16, 0).apply(u, blk, None, None, cast)
+        alike = moe_lm._gated_mlp(u, blk["ws_gate"], blk["ws_up"], blk["ws_down"])
+        parts = []
+        for lo in (0, 4, 8, 12):
+            share = {**blk, **{name: blk[name][lo:lo + 4] for name in ("w_gate", "w_up", "w_down")}}
+            got, held = experts(4, lo).apply(u, share, None, None, cast)
+            assert float(held["moe_slots_computed"]) == float(held["moe_slots_held"]) < float(stats["moe_slots"])
+            parts.append(got - alike)
+        parts.append(alike)  # once
+    assert float(jnp.max(jnp.abs(parts[0]))) > 0 and float(jnp.max(jnp.abs(parts[0] - parts[1]))) > 0
+    assert float(jnp.max(jnp.abs(sum(parts) - whole))) <= 2e-5 * float(jnp.max(jnp.abs(whole)))
+    # and the model's own layer IS that part: the family's builder maps its keys onto these
+    ((_, mixer), (_, ffn)) = _layers(_spec())[1]
+    assert ffn == experts(4, 4) and isinstance(mixer, linear_attention.KimiDeltaAttention)
+
+
+def _layers(spec):
+    return spec.init.keywords["layers"]
+
+
+def test_latent_attention_without_a_rotary_turn_is_kanana2s_with_theta_irrelevant():
+    """``mla_use_nope``: the same part as ``deepseek_v3``'s with its rotary
+    columns and shared key UNTURNED: equal to the turned part at position 0
+    everywhere (a turn by nothing), whatever ``rope_theta`` is."""
+    kimi = _layers(_spec())[2][0][1]
+    assert isinstance(kimi, attentions.LatentAttention) and not kimi.rotary and kimi == _layers(_spec(rope_theta=10000))[2][0][1]
+    other_theta = _layers(_spec(rope_theta=500000.0))[2][0][1]
+    turned = attentions.LatentAttention(4, 16, 8, 4, 8, 10000.0, 1e-5, False)
+    assert turned.rotary and dataclasses.replace(kimi, rotary=True) == turned
+    blk = _weights(_spec())["blocks"]["b02"]
+    u = jax.random.normal(jax.random.key(3), (2, 64, 32))
+    positions = jnp.arange(64)
+    with jax.default_matmul_precision("highest"):
+        got, _ = kimi.apply(u, blk, positions, None, lambda w: w)
+        same, _ = other_theta.apply(u, blk, positions, None, lambda w: w)
+        at_zero, _ = turned.apply(u, blk, jnp.zeros_like(positions), None, lambda w: w)
+        moved, _ = turned.apply(u, blk, positions, None, lambda w: w)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
+    np.testing.assert_allclose(got, at_zero, rtol=1e-6, atol=1e-7)
+    assert float(jnp.max(jnp.abs(moved - got))) > 1e-3 * float(jnp.max(jnp.abs(got)))
+    # kanana2's own builder is as it was: a deepseek_v3 model turns
+    deepseek = {k: v for k, v in KEYS.items() if k not in (
+        "linear_attn_config", "mla_use_nope", "num_experts_per_token", "num_shared_experts", "moe_router_activation_func",
+        "moe_renormalize", "use_grouped_topk", "num_expert_group", "moe_layer_freq", "decay_matrices_only")}
+    spec = load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", num_experts_per_tok=3, scoring_func="sigmoid", **deepseek)
+    assert all(layer[0][1] == turned for layer in _layers(spec))
+
+
+def test_the_step_counters_are_what_the_shapes_give_and_a_ragged_sequence_is_stepwise():
+    spec = _spec()
+    assert set(spec.step_counters) == set(moe_lm.MOE_COUNTERS) | set(linear_attention.KDA_COUNTERS)
+    assert all(spec.step_counters[name] for name in linear_attention.KDA_COUNTERS)
+    batch = _batch()
+    metrics = spec.metrics(spec.apply(spec.init(jax.random.key(0)), batch), batch)
+    assert float(metrics["kda_positions"]) == float(metrics["kda_positions_chunked"]) == 2 * 128 * 4 * 2  # two KDA layers of four heads
+    assert float(metrics["moe_slots"]) == 2 * 2 * 128 * 3
+    ragged = _batch(l=100)  # not whole chunks of 64: the op's stepwise path, counted as such
+    metrics = spec.metrics(spec.apply(spec.init(jax.random.key(0)), ragged), ragged)
+    assert float(metrics["kda_positions"]) == 2 * 100 * 8 and float(metrics["kda_positions_chunked"]) == 0
+
+
+def test_the_job_trains_through_the_trainer():
+    spec = _spec("float32", lr_warmup_steps=0, learning_rate=1e-2)
+    trainer = Trainer(spec, JobConfig(), create_mesh(num_devices=1))
+    state = trainer.init_state(jax.random.key(0))
+    batch = {k: np.asarray(v) for k, v in _batch(b=2).items()}
+    losses = []
+    for _ in range(4):
+        state, metrics = trainer.train_step(state, trainer.shard_batch(batch))
+        losses.append(float(metrics["loss"]))
+    assert losses[-1] < losses[0] - 0.03, losses
+    assert float(metrics["kda_positions"]) == 2 * 128 * 8
+    mask = moe_lm._is_decayed(state.params, moe_lm._NOT_MATRICES)
+    block = mask["blocks"]["b01"]
+    assert block["kda_wq"] and block["kda_conv_q"] and block["kda_wo"] and mask["head"]
+    assert not any(block[name] for name in ("attn_norm", "ffn_norm", "kda_norm", "A_log", "dt_bias", "router_bias"))
+
+
+def test_bfloat16_compute_stays_near_the_float32_reference():
+    spec, batch = _spec("bfloat16"), _batch()
+    params = _weights(spec)
+    logits = spec.apply(params, batch)["logits"]
+    want, _ = reference().build(dict(KEYS))(params, batch["tokens"])
+    assert logits.dtype == jnp.float32
+    # three layers at five times the init's scale (five read 0.10; nemotron_h's toy reads under 0.05 at its scale)
+    assert float(jnp.sqrt(jnp.mean((logits - want) ** 2) / jnp.mean(want ** 2))) < 0.2
+
+
+def test_linear_attn_config_decides_the_family_over_kv_lora_rank_and_no_other_pair_goes_together():
+    family = lambda **keys: moe_lm._family(**{  # noqa: E731
+        "hybrid_override_pattern": None, "attention_class": "mha", "linear_attn_config": None, "kv_lora_rank": 0, **keys})
+    assert family() == "olmoe" and family(kv_lora_rank=512) == "deepseek_v3"
+    assert family(linear_attn_config=KEYS["linear_attn_config"], kv_lora_rank=512) == "kimi_linear"  # the ONE rule
+    assert family(linear_attn_config=KEYS["linear_attn_config"]) == "kimi_linear"
+    for pair in (dict(hybrid_override_pattern="M", kv_lora_rank=512), dict(attention_class="eva", linear_attn_config={}),
+                 dict(hybrid_override_pattern="M", linear_attn_config={})):
+        with pytest.raises(ValueError, match="each name a family"):
+            family(**pair)
+
+
+@pytest.mark.parametrize("keys,match", [
+    (dict(linear_attn_config={**KEYS["linear_attn_config"], "kda_layers": [1]}), "must name each of the layers 1..3 once"),
+    (dict(linear_attn_config={**KEYS["linear_attn_config"], "full_attn_layers": [2, 3]}), "must name each of the layers 1..3 once"),
+    (dict(linear_attn_config={**KEYS["linear_attn_config"], "chunk": 64}), "would be read by nothing"),
+    (dict(moe_layer_freq=2), "moe_layer_freq 2"),
+    (dict(num_expert_group=4, topk_group=2), "group-limited routing"),
+    (dict(moe_router_activation_func="tanh"), "scoring_func 'tanh'"),
+    (dict(head_dim=72), "head_dim: set, but no part of the 'kimi_linear' family reads it"),
+    (dict(num_experts_per_tok=3), "num_experts_per_tok: set, but no part of the 'kimi_linear' family reads it"),
+    (dict(linear_attn_config=None), "mla_use_nope.*no part of the 'deepseek_v3' family reads|num_experts_per_token"),
+], ids=lambda v: v if isinstance(v, str) else "-".join(f"{k}" for k in v)[:40])
+def test_keys_that_do_not_go_together_raise(keys, match):
+    with pytest.raises(ValueError, match=match):
+        _spec(**keys)
+
+
+def test_a_sharded_sequence_is_refused():
+    """The state at a shard's start lives on the shard before it: no silent
+    wrong answer."""
+    spec = _spec()
+    mesh = create_mesh(num_devices=2)
+    with pytest.raises(ValueError, match="sharded sequence"):
+        trainer = Trainer(spec, JobConfig(), mesh)
+        state = trainer.init_state(jax.random.key(0))
+        batch = {k: np.asarray(v) for k, v in _batch(l=256).items()}
+        trainer.train_step(state, trainer.shard_batch(batch))
