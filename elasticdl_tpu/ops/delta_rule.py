@@ -73,14 +73,25 @@ module attribute and that a later kernel must keep calling:
 ``_carry(ends, decay, left, right, first, reverse)`` (the recurrence over the
 chunks, forward and — transposed — backward; ``no_carried_state`` zeroes the
 start states) and ``_solve(a, rhs)`` (``no_delta_correction`` returns ``rhs``:
-``T = Diag(beta)``, plain gated linear attention).  A kernel that summed the
-decays itself or kept the state in VMEM across a sequential grid axis would
-disarm the controls in silence (``ops/ssm.py`` has the same warning).
+``T = Diag(beta)``, plain gated linear attention).  ``_solve`` is ``(I +
+a)^-1 rhs`` by BLOCKED FORWARD SUBSTITUTION in float32 (``_inverse``: the
+``SUB``-row diagonal blocks the masks already have row by row, the block rows
+over them by products that keep float32's digits, then ONE product with the
+right-hand sides) under a ``custom_vjp`` of its own (two products from the
+inverse and the solution); XLA's general ``triangular_solve`` was a third of
+the scope's time on the chip (PR 48) and is the tests' reference now.  There
+is ONE implementation: ``_chunk_parts`` looks ``_solve`` up in the module at
+call time, forward and inside ``_rule_bwd``'s ``jax.vjp``, so the control
+still takes the solve out of both passes.  A kernel that summed the decays
+itself, solved by itself or kept the state in VMEM across a sequential grid
+axis would disarm the controls in silence (``ops/ssm.py`` has the same
+warning).
 
-What runs where: the chunked form as XLA products everywhere (no kernel yet:
-PERF.md section 7); a sequence that is not whole chunks takes the STEPWISE
-path (``delta_rule_reference`` under AD), which ``rule_path`` says and the
-part counts (``kda_positions_chunked``).  Scope ``kda_scan`` (forward and
+What runs where: the chunked form as XLA products and multiply-adds
+everywhere, the solve included (no kernel yet, no custom call: PERF.md section
+7); a sequence that is not whole chunks takes the STEPWISE path
+(``delta_rule_reference`` under AD), which ``rule_path`` says and the part
+counts (``kda_positions_chunked``).  Scope ``kda_scan`` (forward and
 backward); ``gated_head_norm`` is traced under the caller's scope.
 """
 
@@ -148,12 +159,62 @@ def _log_decays(g, chunk: int):
     return lax.associative_scan(jnp.add, by_chunk, axis=2)
 
 
+def _product(x, y):
+    """A float32 product that keeps float32's digits on the MXU (the TPU's
+    default rounds a float32 operand to bfloat16)."""
+    return jnp.matmul(x, y, precision=lax.Precision.HIGHEST)
+
+
+def _inverse(a):
+    """``(I + a)^-1`` [..., C, C] float32 for ``a`` strictly lower triangular
+    (what lies on or above the diagonal is not read), by blocks of ``sub`` =
+    min(SUB, C) rows.  Each diagonal block ``I + N`` by FORWARD SUBSTITUTION
+    row by row (row r of the inverse is ``e_r - N[r, :r] X[:r]``), every block
+    of the operand at once and in the minor dimension (the lanes), so that a
+    step is a few full-width multiply-adds: ``sub - 1`` small steps.  Then the
+    block rows one after the other, ``T[I, :I] = -D_I (a[I, :I] T[:I, :I])``:
+    two products a block row.  NOT the nilpotent product ``(I - a)(I +
+    a^2)(I + a^4)...``: where keys repeat its powers reach 1e17 at 64 rows."""
+    size = a.shape[-1]
+    sub = min(SUB, size)
+    diagonal = jnp.stack([a[..., at:at + sub, at:at + sub] for at in range(0, size, sub)], axis=-3)  # [.., s, sub, sub]
+    by_block = jnp.moveaxis(diagonal.reshape(-1, sub, sub), 0, -1)  # [r, j, blocks]
+    eye = jnp.eye(sub, dtype=a.dtype)
+    rows = [jnp.broadcast_to(eye[0][:, None], by_block.shape[1:])]
+    for r in range(1, sub):
+        rows.append(eye[r][:, None] - jnp.sum(by_block[r, :r, None, :] * jnp.stack(rows), axis=0))
+    inverses = jnp.moveaxis(jnp.stack(rows), -1, 0).reshape(diagonal.shape)
+    t = inverses[..., 0, :, :]  # the inverse of the leading [at, at] of I + a
+    for at in range(sub, size, sub):
+        d = inverses[..., at // sub, :, :]
+        row = -_product(d, _product(a[..., at:at + sub, :at], t))
+        t = jnp.concatenate([jnp.pad(t, [(0, 0)] * (t.ndim - 1) + [(0, sub)]), jnp.concatenate([row, d], axis=-1)], axis=-2)
+    return t
+
+
+@jax.custom_vjp
 def _solve(a, rhs):
     """``(I + a)^-1 rhs`` for ``a`` [..., C, C] strictly lower triangular
     (what lies on or above the diagonal is not read) and ``rhs`` [..., C, n],
-    float32: forward substitution (XLA's triangular solve, whose own products
-    run at the highest precision)."""
-    return lax.linalg.triangular_solve(a, rhs, left_side=True, lower=True, unit_diagonal=True)
+    float32: ``_inverse`` (blocked forward substitution) and ONE product.
+    Its gradients are two products from the inverse and the solution, not a
+    derivative through the substitution's steps."""
+    return _solve_fwd(a, rhs)[0]
+
+
+def _solve_fwd(a, rhs):
+    t = _inverse(a)
+    x = _product(t, rhs)
+    return x, (t, x)
+
+
+def _solve_bwd(res, g_x):
+    t, x = res
+    g_rhs = _product(jnp.swapaxes(t, -1, -2), g_x)  # (I + a)^-T g_x
+    return -jnp.tril(_product(g_rhs, jnp.swapaxes(x, -1, -2)), -1), g_rhs
+
+
+_solve.defvjp(_solve_fwd, _solve_bwd)
 
 
 def _carry(ends, decay, left, right, first, reverse: bool = False):

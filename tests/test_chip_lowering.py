@@ -835,10 +835,12 @@ def test_kimi_linear_step_compiles_for_v5e_inside_the_line_with_its_scopes_and_t
     are there; the latent-attention layer is the three flash kernels at the
     operand lists ``flash_roofline_pct.mla`` reads (5 / 6 + 1 / 6 + 2: the
     rotary columns are handed over, unturned), the forward once; the delta
-    rule is XLA products under ``kda_scan`` with a triangular solve a group
-    of chunks and NO array of a sequence's pairwise differences or column
-    factors; the experts are grouped matmuls; and no [*, 8192, 8192] score
-    matrix exists."""
+    rule is XLA products under ``kda_scan``, a group of chunks at a time,
+    with NO array of a sequence's pairwise differences or column factors and
+    NO triangular solve of XLA's (a group's systems are solved by
+    ``ops/delta_rule._solve``'s blocked forward substitution: multiply-adds
+    and products, no custom call; PR 48); the experts are grouped matmuls;
+    and no [*, 8192, 8192] score matrix exists."""
     import json
     import os
 
@@ -873,6 +875,12 @@ def test_kimi_linear_step_compiles_for_v5e_inside_the_line_with_its_scopes_and_t
     under = lambda scope: [c for c in mosaic if re.search(rf'op_name="[^"]*\b{scope}\b', c)]  # noqa: E731
     assert under("moe_experts") and len(under("moe_experts")) % 4 == 0 and not under("kda_scan")  # no kernel of the op's yet
     scoped = [line for line in text.splitlines() if re.search(r'op_name="[^"]*\bkda_scan\b', line)]
+    # the solve a chunk is ``ops/delta_rule._solve``'s blocked forward substitution (PR 48): XLA's general triangular
+    # solve (on the chip a custom call that is no Mosaic kernel, ``InvertDiagBlocksLowerTriangular``: 95 ms of the
+    # step's 623) is nowhere in the step, and under the scope no custom call is left but XLA's own buffer bookkeeping
+    targets = set(re.findall(r'custom_call_target="(\w+)"', "\n".join(line for line in scoped if " custom-call(" in line)))
+    assert "triangular_solve" not in text and "Triangular" not in text
+    assert targets <= {"AllocateBuffer", "AssumeGatherIndicesInBound", "ConcatBitcast"}, targets
     # a GROUP of 8 chunks at a time: the same-sub-block differences are [.., 8, 32, 4, 16, 16, 128], never a sequence's 128 chunks
     shapes = set(re.findall(r"f32\[([\d,]+)\]", "\n".join(line.split(" = ")[1].split("(")[0] for line in scoped if " = " in line)))
     assert any(shape.endswith("4,16,16,128") for shape in shapes) and not any(re.search(r"\b128,32,4,(16,16|64),128$", shape) for shape in shapes)
@@ -956,8 +964,10 @@ MOE_LM_STEP_SHA256 = {
     ("evabyte_6b5_tp2_l4", "job_seq16k"): "87b44ef8533274d8216ec8aaeaaeb214522f60bb60cad971e1e29df4bf74797a",
     ("nemotron3_super_tp4_ep64_l11", "job_seq8k_x1"): "8c69791bf49f57535a34227915cc3bde193bb61cdbe82ca9b0fdda3a4a6edc2e",
     # PINNED in PR 47, which added the family ``kimi_linear`` (a part, a builder's line, a field of ``LatentAttention``,
-    # a second caller of ``ops/ssm.causal_conv``): the four above are the values they had, and this is the new cell's
-    ("kimi_linear_48b_a3b_ep32_l5", "job_seq8k_x1_v20480"): "7f1534c89a343849bd28f0a666493f38f467dd96791693fe79c6164f083152be",
+    # a second caller of ``ops/ssm.causal_conv``): the four above are the values they had, and the fifth was the new cell's
+    # RE-PINNED in PR 48 (was 7f1534c8...83152be since PR 47): ``ops/delta_rule._solve`` is a blocked forward substitution
+    # under a ``custom_vjp`` of its own where it was ``lax.linalg.triangular_solve``; the four above are untouched
+    ("kimi_linear_48b_a3b_ep32_l5", "job_seq8k_x1_v20480"): "8afaf5cdc5fdf58af306b1aa1b4c1c814fa78860745ee0b3a55b110bad675096",
 }
 
 

@@ -102,6 +102,57 @@ def test_the_three_seams_are_called_and_each_changes_the_result(monkeypatch):
         assert off > (1e-4 if name == "_log_decays" else 1e-2) and off_grad > 1e-4, (name, off, off_grad)
 
 
+def _system(keys: str, size: int, seed: int = 0, lead=(2, 3), dk: int = 8, n: int = 24):
+    """``(a, rhs)`` as the rule builds them: ``a = tril(beta_r k_r . k_i,
+    -1)`` [.., size, size] of l2-normalised keys.  ``random``: distinct keys,
+    beta in (0, 1); ``repeated``: ONE key a system and beta near 1, so ``I +
+    a`` is near the triangle of ones (its inverse is bidiagonal, the powers
+    of ``a`` are binomials: 1e17 at 64 rows); ``alternating``: one key with
+    alternating sign."""
+    ks = jax.random.split(jax.random.key(seed), 3)
+    k = jax.random.normal(ks[0], (*lead, size, dk))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[1], (*lead, size)))
+    if keys != "random":
+        k = jnp.broadcast_to(k[..., :1, :], k.shape)
+        beta = 1.0 - 1e-3 * beta
+    if keys == "alternating":
+        k = k * jnp.where(jnp.arange(size) % 2, -1.0, 1.0)[:, None]
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    a = jnp.tril(beta[..., None] * jnp.einsum("...rc,...ic->...ri", k, k, precision="highest"), -1)
+    return a, jax.random.normal(ks[2], (*lead, size, n))
+
+
+SYSTEMS = [(keys, size) for keys in ("random", "repeated", "alternating") for size in (64, 32, 16, 8)]
+
+
+@pytest.mark.parametrize("keys,size", SYSTEMS, ids=lambda v: f"C{v}" if isinstance(v, int) else v)
+def test_the_blocked_solve_is_the_float64_solve_and_its_gradients_are_the_triangular_solves(keys, size):
+    """``_solve`` alone: blocked forward substitution on sub-blocks of 16 (a
+    chunk under one sub-block is one diagonal block) against numpy's float64
+    solve of ``I + a`` forward, and against XLA's ``triangular_solve`` at the
+    highest precision in both gradients."""
+    a, rhs = _system(keys, size)
+    exact = np.linalg.solve(np.eye(size) + np.asarray(a, np.float64), np.asarray(rhs, np.float64))
+    assert np.max(np.abs(np.asarray(dr._solve(a, rhs), np.float64) - exact)) <= 2e-6 * np.max(np.abs(exact))
+    weigh = jax.random.normal(jax.random.key(7), rhs.shape)
+    with jax.default_matmul_precision("highest"):
+        xla = lambda a, rhs: jax.lax.linalg.triangular_solve(a, rhs, left_side=True, lower=True, unit_diagonal=True)  # noqa: E731
+        got = jax.grad(lambda a, rhs: jnp.sum(dr._solve(a, rhs) * weigh), argnums=(0, 1))(a, rhs)
+        ref = jax.grad(lambda a, rhs: jnp.sum(xla(a, rhs) * weigh), argnums=(0, 1))(a, rhs)
+    for g, r in zip(got, ref):
+        _close(g, r, 1e-5)
+    assert bool(jnp.all(jnp.triu(got[0]) == 0))  # the gradient lies where the operand is read
+
+
+@pytest.mark.parametrize("size", [64, 8], ids=lambda v: f"C{v}")
+def test_the_solve_does_not_read_what_lies_on_or_above_the_diagonal(size):
+    a, rhs = _system("random", size)
+    nan_above = jnp.where(jnp.arange(size)[:, None] <= jnp.arange(size)[None, :], jnp.nan, a)
+    both = lambda a: (dr._solve(a, rhs),) + jax.grad(lambda a, rhs: jnp.sum(dr._solve(a, rhs) ** 2), argnums=(0, 1))(a, rhs)  # noqa: E731
+    for got, want in zip(both(nan_above), both(a)):
+        assert bool(jnp.all(jnp.isfinite(got))) and bool(jnp.all(got == want))
+
+
 def test_without_the_delta_correction_the_rule_is_gated_linear_attention(monkeypatch):
     """``T = Diag(beta)``: ``S_t = Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T``
     inside a chunk — what the control ``no_delta_correction`` runs; with one
