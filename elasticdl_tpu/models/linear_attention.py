@@ -39,13 +39,20 @@ from elasticdl_tpu.ops import ssm as ssm_ops
 
 #: The linear-attention layers' counts a step reports (``ModelSpec.step_counters``;
 #: gauges ``edl_kda_positions*_total``): what the traffic asks of the op, from
-#: the shapes it was called with, and the part of it the chunked form took
-#: (``benchmark/metrics/kda_chunked_pct.kda.json`` reads the pair).
+#: the shapes it was called with, the part of it the chunked form took
+#: (``benchmark/metrics/kda_chunked_pct.kda.json`` reads the pair) and the part
+#: of THAT whose same-sub-block decay masks the Pallas kernels computed
+#: (no entry reads it yet: PERF.md section 7).  Each is counted where the op is called,
+#: through the very function the op asks (``rule_path``, ``mask_path``), of the
+#: very operands: a constant re-derived elsewhere would read 100 whatever ran.
 KDA_COUNTERS = {
     "kda_positions": "(head, position) pairs the gated delta rule advanced a state over, from the "
     "shapes it was called with, summed over layers, training steps and devices",
     "kda_positions_chunked": "those of them the op's chunked form computed (ops/delta_rule.rule_path: a sequence "
     "of whole chunks; the others took the stepwise fallback), summed likewise",
+    "kda_positions_mask_kernel": "those of the chunked ones whose same-sub-block decay masks ops/delta_rule_kernels.py "
+    "computed (ops/delta_rule.mask_path: a TPU, dk whole lanes, a chunk that divides 128; the others took the XLA "
+    "differences), summed likewise",
 }
 L2_EPS = 1e-6
 
@@ -134,7 +141,8 @@ class KimiDeltaAttention(Part):
         o = delta_ops.delta_rule(q, k, v, g, beta, chunk=self.chunk)
         # counted where the op is called, from what it was called with
         chunked = delta_ops.rule_path(l, self.chunk)[0] == delta_ops.PATH_CHUNKED
-        counts = {"kda_positions": jnp.float32(b * l * heads), "kda_positions_chunked": jnp.float32(b * l * heads * chunked)}
+        by_kernel = chunked and delta_ops.mask_path(k, self.chunk)[0] != delta_ops.PATH_XLA_REFERENCE
+        counts = {name: jnp.float32(b * l * heads * part) for name, part in zip(KDA_COUNTERS, (1, chunked, by_kernel))}
         with jax.named_scope("kda_glue"):
             o = jax.checkpoint(delta_ops.gated_head_norm, static_argnums=(3,))(o, by_head(gate), blk["kda_norm"], self.eps)
         with jax.named_scope("kda_proj"):

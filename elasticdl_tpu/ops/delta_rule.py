@@ -68,7 +68,7 @@ alive (the op's backward at the published shapes compiles to 0.6 GiB of
 temporaries; with every chunk at once it was 3.2).
 
 Seams.  Three functions of their own that the benchmark's controls swap by
-module attribute and that a later kernel must keep calling:
+module attribute and that every kernel must keep calling:
 ``_log_decays(g, chunk)`` (the sums; ``bfloat16_decay`` rounds them),
 ``_carry(ends, decay, left, right, first, reverse)`` (the recurrence over the
 chunks, forward and — transposed — backward; ``no_carried_state`` zeroes the
@@ -85,26 +85,50 @@ call time, forward and inside ``_rule_bwd``'s ``jax.vjp``, so the control
 still takes the solve out of both passes.  A kernel that summed the decays
 itself, solved by itself or kept the state in VMEM across a sequential grid
 axis would disarm the controls in silence (``ops/ssm.py`` has the same
-warning).
+warning).  The ONE kernel pair there is (below) stands clear of all three: it
+CONSUMES ``cum``, the sums ``_log_decays`` made (rounded sums give rounded
+masks: the control bites through it), and hands its masks to the same
+``_solve`` and ``_carry``.
 
-What runs where: the chunked form as XLA products and multiply-adds
-everywhere, the solve included (no kernel yet, no custom call: PERF.md section
-7); a sequence that is not whole chunks takes the STEPWISE path
+What runs where.  The SAME-SUB-BLOCK differences inside ``_masks_of`` have
+two implementations of the one arithmetic, chosen by what a call can observe
+(``mask_path``; no flag):
+
+- the Pallas kernel pair of ``ops/delta_rule_kernels.py`` on a TPU inside its
+  contract (``outside_mask_contract``: ``dk`` whole multiples of 128, the
+  padded chunk 16, 32, 64 or 128; the published widths are inside), joined by
+  ONE ``custom_vjp`` (``_same_sub_block_kernels``) whose residuals are the
+  operands: forward ``(q, k, cum) -> (kk, qk)`` written as the block diagonal
+  of [C, C], backward ``(q, k, cum, g_kk, g_qk) -> (g_q, g_k, g_cum)`` with
+  the exponent computed again in VMEM.  As XLA fusions the [SUB, SUB, dk]
+  float32 factors went through HBM, 134 MB a scan step and several times over
+  in the backward: 71 ms of a 534 ms step (PERF.md section 6, PR 49);
+- ``_same_sub_block`` (XLA) and its product onto the diagonal everywhere
+  else: off the TPU (every CPU test and rehearsal), any width that is not
+  whole lanes.
+
+Everything else is XLA products and multiply-adds on either path: the sums,
+the masks' cross-sub-block products, the solve, the carry, the outputs.  A
+sequence that is not whole chunks takes the STEPWISE path
 (``delta_rule_reference`` under AD), which ``rule_path`` says and the part
-counts (``kda_positions_chunked``).  Scope ``kda_scan`` (forward and
-backward); ``gated_head_norm`` is traced under the caller's scope.
+counts (``kda_positions_chunked``; ``kda_positions_mask_kernel`` counts the
+kernels' share through ``mask_path``, and ONE ``attention path:`` line a
+distinct call says which).  Scopes: ``kda_scan`` (forward and backward) and,
+nested in it, ``kda_mask`` around ``_masks_of``'s arithmetic in both passes;
+``gated_head_norm`` is traced under the caller's scope.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from elasticdl_tpu.ops import remat
+from elasticdl_tpu.ops import delta_rule_kernels, remat
+from elasticdl_tpu.ops.ring_attention import PATH_PALLAS_COMPILED, PATH_PALLAS_INTERPRET, PATH_XLA_REFERENCE, announce_path
 
 #: positions a sub-block: the reference points of the decay mask (module docstring)
 SUB = 16
@@ -260,6 +284,66 @@ def _same_sub_block(q, k, cum):
     return jnp.sum(k[..., :, None, :] * factor, -1), jnp.sum(q[..., :, None, :] * factor, -1)
 
 
+def outside_mask_contract(k, chunk: int) -> str:
+    """Why the same-sub-block masks of ``k`` [..., dk] in chunks of ``chunk``
+    are outside the kernels' contract (``""``: inside): a position's channels
+    are whole lanes and a 128-lane group whole chunks of whole sub-blocks
+    (ops/delta_rule_kernels.py)."""
+    size = chunk + -chunk % min(SUB, chunk)  # the padded chunk
+    if k.shape[-1] % 128:
+        return f"dk = {k.shape[-1]} is not whole multiples of 128"
+    if size % SUB or 128 % size:
+        return f"the padded chunk {size} is not 16, 32, 64 or 128"
+    return ""
+
+
+def mask_path(k, chunk: int, interpret: Optional[bool] = None):
+    """Which path ``_masks_of`` takes for the same-sub-block differences of
+    ``k`` [..., dk] (its width is read) in chunks of ``chunk``, from what the
+    code can observe: ``(one of ring_attention's PATH_*, why not the
+    kernels)``.  The kernels compiled on a TPU inside their contract,
+    ``_same_sub_block`` in XLA everywhere else; ``interpret`` given (tests):
+    the kernels, in the Pallas interpreter or compiled."""
+    outside = outside_mask_contract(k, chunk)
+    if interpret is not None:
+        if outside:
+            raise ValueError(f"the mask kernels were asked for outside their contract: {outside}")
+        return (PATH_PALLAS_INTERPRET if interpret else PATH_PALLAS_COMPILED), ""
+    backend = jax.default_backend()
+    why_not = f"backend={backend}" if backend != "tpu" else outside
+    return (PATH_XLA_REFERENCE if why_not else PATH_PALLAS_COMPILED), why_not
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _same_sub_block_kernels(q, k, cum, interpret: bool):
+    """``_same_sub_block`` by ops/delta_rule_kernels.py, ONTO the diagonal:
+    ``q``, ``k`` [..., C, dk] (the operands' type) and ``cum`` [..., C, dk]
+    float32 -> the two masks [..., C, C] float32, zero outside the
+    sub-blocks' diagonal blocks.  The residuals are the operands: nothing of
+    [SUB, SUB, dk] size reaches HBM in either pass."""
+    return _same_sub_block_fwd(q, k, cum, interpret)[0]
+
+
+def _flat(t):
+    """[..., C, width] -> the kernels' [N, width]: a free view."""
+    return t.reshape(-1, t.shape[-1])
+
+
+def _same_sub_block_fwd(q, k, cum, interpret):
+    size = cum.shape[-2]  # whole sub-blocks of SUB (``outside_mask_contract``)
+    masks = delta_rule_kernels.masks(_flat(q), _flat(k), _flat(cum), chunk=size, sub=SUB, interpret=interpret)
+    return tuple(t.reshape(*cum.shape[:-1], size) for t in masks), (q, k, cum)
+
+
+def _same_sub_block_bwd(interpret, res, grads):
+    cum = res[2]
+    of_operands = delta_rule_kernels.mask_grads(*map(_flat, (*res, *grads)), chunk=cum.shape[-2], sub=SUB, interpret=interpret)
+    return tuple(t.reshape(cum.shape) for t in of_operands)
+
+
+_same_sub_block_kernels.defvjp(_same_sub_block_fwd, _same_sub_block_bwd)
+
+
 @functools.partial(jax.checkpoint, static_argnums=(3,))
 def _masks_of(q, k, cum, sub: int):
     """``(sum_c k_r k_i exp(G_r - G_i), sum_c q_r k_i exp(G_r - G_i))`` [B, n,
@@ -267,22 +351,29 @@ def _masks_of(q, k, cum, sub: int):
     dk] and the sums ``cum`` [.., C, dk] float32, by the module docstring's
     reference point a sub-block.  Rematerialised: a gradient holds its
     operands, not the column factors (C / SUB x the size of k) or the
-    same-sub-block differences (SUB x)."""
-    lead, (size, dk) = cum.shape[:3], cum.shape[3:]
-    n_sub = size // sub
-    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
-    blocks = lambda t: t.reshape(*lead, n_sub, sub, dk)  # noqa: E731
-    # R_I: the sums at the last position before sub-block I
-    before = jnp.concatenate([jnp.zeros_like(cum[..., :1, :]), cum[..., sub - 1:size - 1:sub, :]], axis=-2)  # [.., s, dk]
-    rows = jnp.exp(blocks(cum) - before[..., :, None, :])  # exp(G_r - R_I) <= 1
-    earlier = (lax.iota(jnp.int32, size)[None, :] // sub) < lax.iota(jnp.int32, n_sub)[:, None]  # [s, C]: column i before sub-block I
-    columns = jnp.exp(jnp.where(earlier[:, :, None], before[..., :, None, :] - cum[..., None, :, :], -jnp.inf))  # [.., s, C, dk]
-    columns = (f32(k)[..., None, :, :] * columns).astype(k.dtype)
-    across = lambda x: jnp.einsum(  # noqa: E731 — a sub-block row's [SUB, dk] x [dk, C]
-        "...src,...sic->...sri", (blocks(f32(x)) * rows).astype(k.dtype), columns, preferred_element_type=jnp.float32).reshape(*lead, size, size)
-    kk, qk = _same_sub_block(blocks(f32(q)), blocks(f32(k)), blocks(cum))
-    onto_diagonal = lambda t: jnp.einsum("...sri,st->...srti", t, jnp.eye(n_sub, dtype=jnp.float32)).reshape(*lead, size, size)  # noqa: E731
-    return across(k) + onto_diagonal(kk), across(q) + onto_diagonal(qk)
+    same-sub-block differences (SUB x; on the kernels' path they never exist
+    outside VMEM).  Scope ``kda_mask`` (under ``kda_scan``, both passes)."""
+    with jax.named_scope("kda_mask"):
+        lead, (size, dk) = cum.shape[:3], cum.shape[3:]
+        n_sub = size // sub
+        f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+        blocks = lambda t: t.reshape(*lead, n_sub, sub, dk)  # noqa: E731
+        # R_I: the sums at the last position before sub-block I
+        before = jnp.concatenate([jnp.zeros_like(cum[..., :1, :]), cum[..., sub - 1:size - 1:sub, :]], axis=-2)  # [.., s, dk]
+        rows = jnp.exp(blocks(cum) - before[..., :, None, :])  # exp(G_r - R_I) <= 1
+        earlier = (lax.iota(jnp.int32, size)[None, :] // sub) < lax.iota(jnp.int32, n_sub)[:, None]  # [s, C]: column i before sub-block I
+        columns = jnp.exp(jnp.where(earlier[:, :, None], before[..., :, None, :] - cum[..., None, :, :], -jnp.inf))  # [.., s, C, dk]
+        columns = (f32(k)[..., None, :, :] * columns).astype(k.dtype)
+        across = lambda x: jnp.einsum(  # noqa: E731 — a sub-block row's [SUB, dk] x [dk, C]
+            "...src,...sic->...sri", (blocks(f32(x)) * rows).astype(k.dtype), columns, preferred_element_type=jnp.float32).reshape(*lead, size, size)
+        path, _ = mask_path(k, size)
+        if path == PATH_XLA_REFERENCE:
+            kk, qk = _same_sub_block(blocks(f32(q)), blocks(f32(k)), blocks(cum))
+            onto_diagonal = lambda t: jnp.einsum("...sri,st->...srti", t, jnp.eye(n_sub, dtype=jnp.float32)).reshape(*lead, size, size)  # noqa: E731
+            kk, qk = onto_diagonal(kk), onto_diagonal(qk)
+        else:  # the kernels write the block diagonal of [C, C] themselves
+            kk, qk = _same_sub_block_kernels(q, k, cum, path == PATH_PALLAS_INTERPRET)
+        return across(k) + kk, across(q) + qk
 
 
 def _chunk_parts(q, k, v, g, beta, chunk: int) -> _Parts:
@@ -435,6 +526,8 @@ def delta_rule(q, k, v, g, beta, *, chunk: int = 64, with_aux: bool = False):
             o = o.astype(v.dtype)
             cum_chunk = length  # one chunk: the sums from the sequence's start
         else:
+            path, why_not = mask_path(k, chunk)  # what ``_masks_of`` will ask, of the same width and chunk
+            announce_path(path, k, True, f"kda_mask chunk={chunk}" + f"; {why_not}" * bool(why_not))
             # ``remat.kept``: asked here, while the primal is traced (as ops/ssm asks)
             o, last = _rule(q, k, v, g, beta, chunk, remat.kept("kda_scan_out"))
             cum_chunk = chunk

@@ -836,11 +836,16 @@ def test_kimi_linear_step_compiles_for_v5e_inside_the_line_with_its_scopes_and_t
     operand lists ``flash_roofline_pct.mla`` reads (5 / 6 + 1 / 6 + 2: the
     rotary columns are handed over, unturned), the forward once; the delta
     rule is XLA products under ``kda_scan``, a group of chunks at a time,
-    with NO array of a sequence's pairwise differences or column factors and
+    with NO array of a sequence's pairwise differences or column factors,
     NO triangular solve of XLA's (a group's systems are solved by
     ``ops/delta_rule._solve``'s blocked forward substitution: multiply-adds
-    and products, no custom call; PR 48); the experts are grouped matmuls;
-    and no [*, 8192, 8192] score matrix exists."""
+    and products, no custom call; PR 48) and NO [SUB, SUB, dk] array of a
+    sub-block's differences at all: the same-sub-block masks are the two
+    Mosaic kernels of ``ops/delta_rule_kernels.py`` under ``kda_scan`` /
+    ``kda_mask`` (PR 49: the masks a layer's forward scan and again in its
+    backward scan, their transpose there once), which the op's ``attention
+    path:`` line says; the experts are grouped matmuls; and no [*, 8192,
+    8192] score matrix exists."""
     import json
     import os
 
@@ -873,17 +878,25 @@ def test_kimi_linear_step_compiles_for_v5e_inside_the_line_with_its_scopes_and_t
     assert _signatures("\n".join(flash)) == [(5, 0), (6, 1), (6, 2)]  # ONE latent-attention layer, the forward ONCE: its output is kept
     mosaic = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     under = lambda scope: [c for c in mosaic if re.search(rf'op_name="[^"]*\b{scope}\b', c)]  # noqa: E731
-    assert under("moe_experts") and len(under("moe_experts")) % 4 == 0 and not under("kda_scan")  # no kernel of the op's yet
+    assert under("moe_experts") and len(under("moe_experts")) % 4 == 0
+    # the op's ONE kernel pair (PR 49), in each of the four KDA layers: the masks in the forward scan's body and again
+    # (rematerialised) in the backward scan's, their transpose there; every call under ``kda_scan`` AND ``kda_mask``
+    kernels = [re.search(r"kda_sub_block_\w+", call).group(0) for call in under("kda_scan") if "kda_sub_block_" in call]
+    assert len(kernels) == len(under("kda_scan")) == len(under("kda_mask")) == 12, (len(kernels), len(under("kda_scan")), len(under("kda_mask")))
+    assert sorted(set(kernels)) == ["kda_sub_block_mask_grads", "kda_sub_block_masks"] and kernels.count("kda_sub_block_mask_grads") == 4
+    assert any("attention path: pallas-compiled" in line and "kda_mask chunk=64" in line for line in path_lines), path_lines
     scoped = [line for line in text.splitlines() if re.search(r'op_name="[^"]*\bkda_scan\b', line)]
     # the solve a chunk is ``ops/delta_rule._solve``'s blocked forward substitution (PR 48): XLA's general triangular
     # solve (on the chip a custom call that is no Mosaic kernel, ``InvertDiagBlocksLowerTriangular``: 95 ms of the
     # step's 623) is nowhere in the step, and under the scope no custom call is left but XLA's own buffer bookkeeping
     targets = set(re.findall(r'custom_call_target="(\w+)"', "\n".join(line for line in scoped if " custom-call(" in line)))
     assert "triangular_solve" not in text and "Triangular" not in text
-    assert targets <= {"AllocateBuffer", "AssumeGatherIndicesInBound", "ConcatBitcast"}, targets
-    # a GROUP of 8 chunks at a time: the same-sub-block differences are [.., 8, 32, 4, 16, 16, 128], never a sequence's 128 chunks
+    assert targets <= {"AllocateBuffer", "AssumeGatherIndicesInBound", "ConcatBitcast", "tpu_custom_call"}, targets
+    # a GROUP of 8 chunks at a time: the masks' column factors are [.., 8, 32, 4, 64, 128], never a sequence's 128 chunks;
+    # the same-sub-block differences ([.., 8, 32, 4, 16, 16, 128] as XLA fusions until PR 49) are in no array of the step
     shapes = set(re.findall(r"f32\[([\d,]+)\]", "\n".join(line.split(" = ")[1].split("(")[0] for line in scoped if " = " in line)))
-    assert any(shape.endswith("4,16,16,128") for shape in shapes) and not any(re.search(r"\b128,32,4,(16,16|64),128$", shape) for shape in shapes)
+    assert any(shape.endswith("8,32,4,64,128") for shape in shapes), sorted(shapes)[:40]
+    assert not any(re.search(r"\b16,16,128$", shape) or re.search(r"\b128,32,4,(16,16|64),128$", shape) for shape in shapes)
     assert any("attention path: pallas-compiled" in line and "rotary=64" in line for line in path_lines), path_lines
     # the patterns of the roofline entry the cell joined tell exactly these three apart
     with open(os.path.join(root, "benchmark", "metrics", "flash_roofline_pct.mla.json")) as f:
@@ -967,7 +980,10 @@ MOE_LM_STEP_SHA256 = {
     # a second caller of ``ops/ssm.causal_conv``): the four above are the values they had, and the fifth was the new cell's
     # RE-PINNED in PR 48 (was 7f1534c8...83152be since PR 47): ``ops/delta_rule._solve`` is a blocked forward substitution
     # under a ``custom_vjp`` of its own where it was ``lax.linalg.triangular_solve``; the four above are untouched
-    ("kimi_linear_48b_a3b_ep32_l5", "job_seq8k_x1_v20480"): "8afaf5cdc5fdf58af306b1aa1b4c1c814fa78860745ee0b3a55b110bad675096",
+    # RE-PINNED in PR 49 (was 8afaf5cd...bad675096 since PR 48): the part counts a third number (``kda_positions_mask_kernel``)
+    # and ``ops/delta_rule._masks_of`` traces under the scope ``kda_mask``; lowered from the CPU the same-sub-block masks
+    # are the XLA differences they were (the kernels are not in this text); the four above and ``gpt2_medium``'s are untouched
+    ("kimi_linear_48b_a3b_ep32_l5", "job_seq8k_x1_v20480"): "559b0adf19c4eb66f79db89bdd5f051bcdb029be80cf9e620633b6500d75f734",
 }
 
 

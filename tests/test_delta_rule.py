@@ -1,7 +1,9 @@
 """``ops/delta_rule``: the chunked gated delta rule against its
 position-by-position reference, forward and every gradient, at chunks that do
 and do not divide into sub-blocks, with decays at both ends of the published
-range; the three seams; the gated norm a head."""
+range; the three seams, on the XLA path and on the kernels'; the same-sub-block
+kernel pair (``ops/delta_rule_kernels.py`` under the interpreter) against the
+XLA differences; which path a call takes; the gated norm a head."""
 
 import jax
 import jax.numpy as jnp
@@ -80,14 +82,48 @@ def test_the_summed_log_decays_are_float32_whatever_the_operands_are():
     _close(o, ref, 3e-2)
 
 
-def test_the_three_seams_are_called_and_each_changes_the_result(monkeypatch):
+@pytest.fixture
+def on_the_kernel_path(monkeypatch):
+    """``mask_path`` answers as if a test had given ``interpret``: the
+    same-sub-block masks of every call below are the Pallas kernels under the
+    interpreter.  ``_masks_of`` is a ``jax.checkpoint``, whose trace jax keeps
+    by shapes: the caches are dropped on both sides, so that no trace made on
+    one path answers for the other."""
+    path = dr.mask_path
+    jax.clear_caches()
+    monkeypatch.setattr(dr, "mask_path", lambda k, chunk, interpret=None: path(k, chunk, True))
+    yield
+    jax.clear_caches()
+
+
+def _kernel_operands(softplus_at: float = -4.0):
+    """The smallest call inside the kernels' contract: ONE sequence of two
+    chunks of 64, three heads (the three rates) of dk = 128."""
+    return _operands(b=1, length=128, dk=128, dv=8, softplus_at=softplus_at)
+
+
+@pytest.mark.parametrize("path", ["xla", "kernels"])
+def test_the_three_seams_are_called_and_each_changes_the_result(monkeypatch, request, path):
     """The benchmark's controls swap ``_log_decays``, ``_carry`` and
     ``_solve`` by module attribute: each is looked up at call time, forward
-    and backward, and each one's fault shows."""
-    args = _operands(length=128)
-    sound = dr.delta_rule(*args, chunk=32)
-    loss = lambda *a: jnp.sum(dr.delta_rule(*a, chunk=32) ** 2)  # noqa: E731
-    sound_grad = jax.grad(loss, argnums=1)(*args)
+    and backward, and each one's fault shows, with the same-sub-block masks
+    in XLA and in the kernels (which consume the seam's sums and hand their
+    masks to the seams' solve and carry)."""
+    if path == "kernels":
+        request.getfixturevalue("on_the_kernel_path")
+        args = _kernel_operands()
+    else:
+        args = _operands(length=128)
+    chunk = 64 if path == "kernels" else 32
+
+    def read():  # traced anew: with the fault in place, where there is one
+        def loss(*a):
+            o = dr.delta_rule(*a, chunk=chunk)
+            return jnp.sum(o ** 2), o
+        (_, o), grad = jax.jit(jax.value_and_grad(loss, argnums=1, has_aux=True))(*args)
+        return o, grad
+
+    sound, sound_grad = read()
     decays, carry = dr._log_decays, dr._carry
     faults = {
         "_log_decays": lambda g, chunk: decays(g, chunk).astype(jnp.bfloat16).astype(jnp.float32),
@@ -97,9 +133,111 @@ def test_the_three_seams_are_called_and_each_changes_the_result(monkeypatch):
     for name, fault in faults.items():
         with monkeypatch.context() as patch:
             patch.setattr(dr, name, fault)
-            off = float(jnp.max(jnp.abs(dr.delta_rule(*args, chunk=32) - sound)) / jnp.max(jnp.abs(sound)))
-            off_grad = float(jnp.max(jnp.abs(jax.grad(loss, argnums=1)(*args) - sound_grad)) / jnp.max(jnp.abs(sound_grad)))
+            o, grad = read()
+        off = float(jnp.max(jnp.abs(o - sound)) / jnp.max(jnp.abs(sound)))
+        off_grad = float(jnp.max(jnp.abs(grad - sound_grad)) / jnp.max(jnp.abs(sound_grad)))
         assert off > (1e-4 if name == "_log_decays" else 1e-2) and off_grad > 1e-4, (name, off, off_grad)
+
+
+def _onto_diagonal(q, k, cum):
+    """``_same_sub_block`` and its product onto the diagonal, as
+    ``_masks_of`` joins them on the XLA path: [.., C, C]."""
+    lead, (size, dk) = cum.shape[:-2], cum.shape[-2:]
+    blocks = lambda t: t.astype(jnp.float32).reshape(*lead, size // dr.SUB, dr.SUB, dk)  # noqa: E731
+    onto = lambda t: jnp.einsum("...sri,st->...srti", t, jnp.eye(size // dr.SUB)).reshape(*lead, size, size)  # noqa: E731
+    return tuple(onto(t) for t in dr._same_sub_block(blocks(q), blocks(k), blocks(cum)))
+
+
+#: (lead, C): one 128-lane group of two chunks; three chunks of 32 (N = 96: padded to a group); one chunk of 128
+KERNEL_TILES = [((2,), 64), ((3,), 32), ((1,), 128)]
+
+
+#: every type and both ends of the decays' range at the cell's chunk; the other chunks at two corners of the four
+KERNEL_CASES = [(lead, size, softplus_at, dtype) for lead, size in KERNEL_TILES for softplus_at in (-4.0, 2.0) for dtype in (jnp.float32, jnp.bfloat16)
+                if size == 64 or (softplus_at < 0) == (dtype == jnp.float32)]
+
+
+@pytest.mark.parametrize("lead,size,softplus_at,dtype", KERNEL_CASES, ids=[
+    f"C{c}-{'decays_near_1' if at < 0 else 'decays_near_exp_-16_softplus'}-{jnp.dtype(dtype).name}" for _, c, at, dtype in KERNEL_CASES])
+def test_the_kernel_pair_is_the_xla_differences_forward_and_in_all_three_gradients(lead, size, softplus_at, dtype):
+    """``_same_sub_block_kernels`` under the interpreter against
+    ``_same_sub_block`` onto the diagonal: both masks, and the gradients of
+    ``q``, ``k`` and the sums under DENSE cotangents (what lies outside the
+    sub-blocks is not read).  Near ``exp(-16 softplus)`` a sub-block's
+    differences reach -500 and their negatives would overflow: the mask is
+    applied before the ``exp`` and every number is finite."""
+    ks = jax.random.split(jax.random.key(size), 5)
+    q, k = (jax.random.normal(key, (*lead, size, 128)).astype(dtype) for key in ks[:2])
+    cum = jnp.cumsum(-16.0 * jax.nn.softplus(jax.random.normal(ks[2], (*lead, size, 128)) + softplus_at), axis=-2)
+    assert (float(cum.min()) < -800) == (softplus_at > 0)
+    weigh = tuple(jax.random.normal(key, (*lead, size, size)) for key in ks[3:])
+    got, got_vjp = jax.vjp(lambda *a: dr._same_sub_block_kernels(*a, True), q, k, cum)
+    want, want_vjp = jax.vjp(_onto_diagonal, q, k, cum)
+    for g, w in zip(got, want):
+        _close(g, w, 2e-6)
+    for name, g, w in zip("q k cum".split(), got_vjp(weigh), want_vjp(weigh)):
+        assert g.dtype == w.dtype and bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))), name
+        # q, k: one rounding to bfloat16, each path from its own float32 sum.  The sums' gradient adds and takes away
+        # each pair's z: where all but a few pairs underflow, XLA's transpose is the looser of the two against
+        # float64 (6e-7 of a largest 0.04, the kernels 2e-9)
+        _close(g, w.astype(jnp.float32), 1e-2 if dtype == jnp.bfloat16 and name != "cum" else 5e-5 if name == "cum" else 5e-6)
+
+
+@pytest.mark.parametrize("softplus_at", [-4.0, 2.0], ids=["decays_near_1", "decays_near_exp_-16_softplus"])
+def test_the_rule_on_the_kernel_path_is_the_recurrence_forward_and_in_every_gradient(on_the_kernel_path, softplus_at):
+    args = _kernel_operands(softplus_at)
+    assert "pallas_call" in str(jax.make_jaxpr(lambda *a: dr.delta_rule(*a, chunk=64))(*args))
+    with jax.default_matmul_precision("highest"):
+        want, last = dr.delta_rule_reference(*args)
+        weigh = jax.random.normal(jax.random.key(9), want.shape)
+
+        def read(*a):  # ONE program: the kernels are compiled for the interpreter once a pass
+            o, aux = dr.delta_rule(*a, chunk=64, with_aux=True)
+            return jnp.sum(o * weigh), (o, aux.state)
+
+        (_, (o, state)), got = jax.jit(jax.value_and_grad(read, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+        _close(o, want, 5e-6)
+        _close(state, last, 1e-5)  # sums over 128 channels, sixteen times the other cases'
+        ref = jax.grad(lambda *a: jnp.sum(dr.delta_rule_reference(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip("q k v g beta".split(), got, ref):
+        assert bool(jnp.all(jnp.isfinite(a))) and float(jnp.max(jnp.abs(a - b))) <= 5e-5 * float(jnp.max(jnp.abs(b))), name
+
+
+@pytest.fixture
+def path_lines(monkeypatch):
+    from elasticdl_tpu.ops import ring_attention
+
+    lines = []
+    monkeypatch.setattr(ring_attention, "_log_once", lines.append)
+    return lines
+
+
+@pytest.mark.parametrize("backend,dk,chunk,path,why", [
+    ("cpu", 128, 64, "xla-reference", "backend=cpu"),
+    ("tpu", 64, 64, "xla-reference", "dk = 64 is not whole multiples of 128"),
+    ("tpu", 128, 48, "xla-reference", "the padded chunk 48 is not 16, 32, 64 or 128"),
+    ("tpu", 128, 8, "xla-reference", "the padded chunk 8 is not 16, 32, 64 or 128"),
+    ("tpu", 128, 24, "pallas-compiled", ""),  # padded to 32
+    ("tpu", 256, 64, "pallas-compiled", ""),
+], ids=["off_the_tpu", "narrow_channels", "chunk_of_three_sub_blocks", "chunk_under_a_sub_block", "padded_chunk", "on_the_tpu_inside_the_contract"])
+def test_the_backend_and_the_shapes_alone_choose_the_mask_path(monkeypatch, path_lines, backend, dk, chunk, path, why):
+    """No flag: ``mask_path`` reads the backend, ``k``'s width and the chunk;
+    the op's ``attention path:`` line says what it answered; asked for by
+    name (``interpret``), the kernels refuse what is outside their contract."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    jax.clear_caches()  # ``_masks_of``'s kept traces: see ``on_the_kernel_path``
+    args = _operands(b=1, length=192, dk=dk)
+    assert dr.mask_path(args[1], chunk) == (path, why)
+    traced = str(jax.make_jaxpr(lambda *a: dr.delta_rule(*a, chunk=chunk))(*args))
+    assert ("pallas_call" in traced) == (path != "xla-reference")
+    (line,) = path_lines
+    assert line == f"attention path: {path} (q=(1, 192, 3, {dk}) float32 causal=True; kda_mask chunk={chunk}{'; ' + why if why else ''})"
+    if why and backend == "tpu":
+        with pytest.raises(ValueError, match="outside their contract"):
+            dr.mask_path(args[1], chunk, True)
+    else:
+        assert dr.mask_path(args[1], chunk, True) == ("pallas-interpret", "") and dr.mask_path(args[1], chunk, False) == ("pallas-compiled", "")
+    jax.clear_caches()
 
 
 def _system(keys: str, size: int, seed: int = 0, lead=(2, 3), dk: int = 8, n: int = 24):

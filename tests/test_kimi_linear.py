@@ -149,6 +149,7 @@ def test_the_part_alone_is_the_references_linear_attention():
         got_normed, _ = part.apply(normed, blk, None, None, lambda w: w)
     assert float(jnp.max(jnp.abs(got_normed - want))) <= 2e-5 * float(jnp.max(jnp.abs(want)))
     assert float(counts["kda_positions"]) == float(counts["kda_positions_chunked"]) == 2 * 128 * 4
+    assert float(counts["kda_positions_mask_kernel"]) == 0  # off the TPU (and dk = 8): the XLA differences
     assert float(jnp.max(jnp.abs(got - got_normed))) > 0
 
 
@@ -282,6 +283,35 @@ def test_the_step_counters_are_what_the_shapes_give_and_a_ragged_sequence_is_ste
     ragged = _batch(l=100)  # not whole chunks of 64: the op's stepwise path, counted as such
     metrics = spec.metrics(spec.apply(spec.init(jax.random.key(0)), ragged), ragged)
     assert float(metrics["kda_positions"]) == 2 * 100 * 8 and float(metrics["kda_positions_chunked"]) == 0
+
+
+@pytest.mark.parametrize("backend,head_dim,length,kernel", [("tpu", 128, 128, 1), ("tpu", 64, 128, 0), ("cpu", 128, 128, 0), ("tpu", 128, 100, 0)],
+                         ids=["on_the_tpu_inside_the_contract", "narrow_channels", "off_the_tpu", "a_ragged_sequence_is_stepwise"])
+def test_the_mask_kernels_share_is_counted_where_the_op_is_called_from_what_it_was_called_with(monkeypatch, backend, head_dim, length, kernel):
+    """``kda_positions_mask_kernel`` asks
+    ``ops/delta_rule.mask_path`` of the very ``k`` the op is given, and only
+    of a call that is chunked at all (``tests/test_delta_rule.py`` holds the
+    traced op to the same answer: the Pallas calls are there or not)."""
+    from elasticdl_tpu.models.parts import Draws
+    from elasticdl_tpu.ops import delta_rule
+
+    part = linear_attention.KimiDeltaAttention(heads=2, head_dim=head_dim, conv_kernel=4, eps=1e-5)
+    blk = part.init(Draws(jax.random.key(0), 32, 0.02), 16)
+    given = []
+
+    def rule(q, k, v, g, beta, *, chunk):  # the op's place: what it is given, and nothing computed
+        given.append((k, chunk))
+        return jnp.zeros_like(v)
+
+    monkeypatch.setattr(delta_rule, "delta_rule", rule)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    _, counts = part.apply(jnp.zeros((1, length, 16)), blk, None, None, lambda w: w)
+    ((k, chunk),) = given
+    assert k.shape == (1, length, 2, head_dim) and chunk == 64
+    assert float(counts["kda_positions"]) == length * 2 and float(counts["kda_positions_chunked"]) == length * 2 * (length % 64 == 0)
+    assert float(counts["kda_positions_mask_kernel"]) == length * 2 * kernel
+    # the same question the op asks (``_masks_of``, of the same width and chunk), where the op is chunked at all
+    assert (delta_rule.mask_path(k, chunk)[0] == "pallas-compiled") == bool(kernel or length % 64)
 
 
 def test_the_job_trains_through_the_trainer():
