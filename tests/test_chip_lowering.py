@@ -7,6 +7,7 @@ for it, Mosaic included.  Nothing here runs on a TPU; ``chip_smoke.py`` does.
 from __future__ import annotations
 
 import math
+import os
 import re
 
 import jax
@@ -87,6 +88,29 @@ def v5e_host():
 @pytest.fixture(scope="module")
 def v5e_device(v5e_host):
     return v5e_host[0]
+
+
+def test_the_harness_compile_level_does_not_reach_a_tpu_compile(v5e_device):
+    """``tests/conftest.py`` has XLA:CPU generate its code at level 1
+    (``--xla_backend_optimization_level``, in ``XLA_FLAGS``), and that option
+    rides EVERY compile's debug options, a described TPU's too.  libtpu does
+    not read it: asked for the default 3, it gives the same program with the
+    same memory, so this file's cases hold the steps the chip runs."""
+    assert "--xla_backend_optimization_level=1" in os.environ["XLA_FLAGS"]
+
+    def step(x, w_in, w_out):
+        def loss(w_in, w_out):
+            return jnp.mean((jax.nn.softmax(jax.nn.gelu(x @ w_in), axis=-1) @ w_out - x) ** 2)
+
+        return jax.value_and_grad(loss, argnums=(0, 1))(w_in, w_out)
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=jax.sharding.SingleDeviceSharding(v5e_device))
+
+    lowered = jax.jit(step).trace(arg(1024, 512), arg(512, 2048), arg(2048, 512)).lower(lowering_platforms=("tpu",))
+    the_runs, the_defaults = lowered.compile(), lowered.compile(compiler_options={"xla_backend_optimization_level": 3})
+    assert "fusion" in the_runs.as_text() and the_runs.as_text() == the_defaults.as_text()
+    assert the_runs.memory_analysis().temp_size_in_bytes == the_defaults.memory_analysis().temp_size_in_bytes
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES)
@@ -926,42 +950,6 @@ def test_kimi_linear_step_compiles_for_v5e_inside_the_line_with_its_scopes_and_t
 GPT2_MEDIUM_STEP_SHA256 = "c635201162024437d79e8ea579e276f9e51d2fe2b21fb4c4d2987008ca98cdc2"
 
 
-def test_gpt2_medium_lowered_step_is_the_pinned_program():
-    import hashlib
-    import subprocess
-    import sys
-
-    script = (
-        "import hashlib, sys, jax\n"
-        "sys.path.insert(0, 'tests')\n"
-        "from elasticdl_tpu.common.config import DistributionStrategy, JobConfig\n"
-        "from elasticdl_tpu.models.spec import load_model_spec\n"
-        "from elasticdl_tpu.parallel.mesh import create_mesh\n"
-        "from elasticdl_tpu.parallel.trainer import Trainer\n"
-        "import test_chip_lowering as T\n"
-        "spec = load_model_spec('elasticdl_tpu.models', 'transformer_lm.model_spec', vocab=50257, dim=1024,"
-        " n_heads=16, n_layers=24, seq_len=1024, max_seq=1024, remat=True, parallelism='sequence')\n"
-        "mesh = create_mesh(jax.devices()[:1], num_devices=1)\n"
-        "trainer = Trainer(spec, JobConfig(distribution_strategy=DistributionStrategy.ALLREDUCE), mesh)\n"
-        "step, args = T._abstract_scan_step(trainer, mesh, minibatch=16, steps=2)\n"
-        "text = step.trace(*args).lower(lowering_platforms=('tpu',)).as_text()\n"
-        "print('SHA', hashlib.sha256(text.encode()).hexdigest())\n"
-    )
-    # A fresh process: inner jits cached by earlier tests of this one would
-    # carry other names into the text.
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    done = subprocess.run(
-        [sys.executable, "-c", script], cwd=root, capture_output=True, text=True,
-        timeout=600, env=dict(os.environ, PYTHONPATH=root),
-    )
-    assert done.returncode == 0, done.stderr[-2000:]
-    (sha,) = re.findall(r"^SHA (\w+)$", done.stdout, re.M)
-    assert len(hashlib.sha256(b"").hexdigest()) == len(sha)
-    assert sha == GPT2_MEDIUM_STEP_SHA256
-
-
 #: sha256 of the four ``moe_lm`` cells' steps lowered for the chip
 #: from their configuration and traffic files (StableHLO text, lowered from
 #: the CPU as ``GPT2_MEDIUM_STEP_SHA256`` is: the attention is the XLA
@@ -987,7 +975,11 @@ MOE_LM_STEP_SHA256 = {
 }
 
 
-@pytest.mark.parametrize("config,traffic", sorted(MOE_LM_STEP_SHA256))
+#: ... and ``gpt2_medium``'s beside them: ONE script lowers a cell from its two files, whatever its ``model_def``
+LOWERED_STEP_SHA256 = {("gpt2_medium", "job_seq1k"): GPT2_MEDIUM_STEP_SHA256, **MOE_LM_STEP_SHA256}
+
+
+@pytest.mark.parametrize("config,traffic", sorted(LOWERED_STEP_SHA256))
 def test_the_moe_lm_cells_lowered_steps_are_the_pinned_programs(config, traffic):
     import hashlib
     import os
@@ -1011,6 +1003,8 @@ def test_the_moe_lm_cells_lowered_steps_are_the_pinned_programs(config, traffic)
         "text = step.trace(*args).lower(lowering_platforms=('tpu',)).as_text()\n"
         "print('SHA', hashlib.sha256(text.encode()).hexdigest())\n"
     )
+    # A fresh process: inner jits cached by earlier tests of this one would
+    # carry other names into the text.
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     done = subprocess.run(
         [sys.executable, "-c", script], cwd=root, capture_output=True, text=True,
@@ -1018,4 +1012,5 @@ def test_the_moe_lm_cells_lowered_steps_are_the_pinned_programs(config, traffic)
     )
     assert done.returncode == 0, done.stderr[-2000:]
     (sha,) = re.findall(r"^SHA (\w+)$", done.stdout, re.M)
-    assert sha == MOE_LM_STEP_SHA256[config, traffic]
+    assert len(hashlib.sha256(b"").hexdigest()) == len(sha)
+    assert sha == LOWERED_STEP_SHA256[config, traffic]

@@ -103,10 +103,10 @@ def test_forward_and_loss_match_the_reference(devices, reference, task):
     first = {k: v[:MB] for k, v in task.items()}
     touched, rows, params = _reference_inputs(reference, spec, task, MB)
     assert len(touched) < MB * 26  # there ARE duplicates
-    logits = np.asarray(reference.logits_fn(params, rows, first["dense"]))
+    logits = np.asarray(jax.jit(reference.logits_fn)(params, rows, first["dense"]))
     got = np.asarray(trainer.run_predict_step(state, first))  # sigmoid(logit)
     np.testing.assert_allclose(got, 1 / (1 + np.exp(-logits)), rtol=0, atol=2e-6)
-    want = float(reference.loss_fn(params, rows, first["dense"], first["labels"].astype(np.float32)))
+    want = float(jax.jit(reference.loss_fn)(params, rows, first["dense"], first["labels"].astype(np.float32)))
     _, metrics = trainer.train_step(state, trainer.shard_batch(first))
     assert float(metrics["loss"]) == pytest.approx(want, rel=2e-6)
 
@@ -118,7 +118,7 @@ def test_table_gradient_matches_the_reference(devices, reference, task):
     spec, trainer, state = _system(devices, "float32", optimizer=optax.sgd(1.0))
     first = {k: v[:MB] for k, v in task.items()}
     touched, rows, params = _reference_inputs(reference, spec, task, MB)
-    grads = jax.grad(reference.loss_fn)(params, rows, first["dense"], first["labels"].astype(np.float32))
+    grads = jax.jit(jax.grad(reference.loss_fn))(params, rows, first["dense"], first["labels"].astype(np.float32))
     want = np.concatenate([np.asarray(grads["v"]), np.asarray(grads["w"])[:, None]], -1)
     before = np.asarray(state.params["fm_table"])
     after, _ = trainer.train_step(state, trainer.shard_batch(first))

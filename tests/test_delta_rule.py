@@ -47,14 +47,21 @@ CHUNKS = [(64, 128), (32, 96), (24, 96), (8, 96)]
 def test_the_chunked_rule_is_the_recurrence_forward_and_in_every_gradient(chunk, length, softplus_at):
     args = _operands(length=length, softplus_at=softplus_at)
     assert (float(args[3].min()) < -50) == (softplus_at > 0)  # exp(50 x 16 positions) leaves float32: a whole-chunk quotient fails here
-    with jax.default_matmul_precision("highest"):
-        want, last = dr.delta_rule_reference(*args)
-        o, aux = dr.delta_rule(*args, chunk=chunk, with_aux=True)
-        _close(o, want, 5e-6)
-        _close(aux.state, last, 5e-6)
-        weigh = jax.random.normal(jax.random.key(9), want.shape)
-        got = jax.grad(lambda *a: jnp.sum(dr.delta_rule(*a, chunk=chunk) * weigh), argnums=(0, 1, 2, 3, 4))(*args)
-        ref = jax.grad(lambda *a: jnp.sum(dr.delta_rule_reference(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(*args)
+    weigh = jax.random.normal(jax.random.key(9), args[2].shape)
+    every = (0, 1, 2, 3, 4)
+
+    def rule(*a):
+        o, aux = dr.delta_rule(*a, chunk=chunk, with_aux=True)
+        return o, aux.state, jax.grad(lambda *a: jnp.sum(dr.delta_rule(*a, chunk=chunk) * weigh), argnums=every)(*a)
+
+    def recurrence(*a):
+        return *dr.delta_rule_reference(*a), jax.grad(lambda *a: jnp.sum(dr.delta_rule_reference(*a)[0] * weigh), argnums=every)(*a)
+
+    with jax.default_matmul_precision("highest"):  # ONE program a side: op by op, the gradients are thousands of dispatches
+        o, state, got = jax.jit(rule)(*args)
+        want, last, ref = jax.jit(recurrence)(*args)
+    _close(o, want, 5e-6)
+    _close(state, last, 5e-6)
     assert all(bool(jnp.all(jnp.isfinite(t))) for t in got)
     for name, a, b in zip("q k v g beta".split(), got, ref):
         assert float(jnp.max(jnp.abs(a - b))) <= 5e-5 * float(jnp.max(jnp.abs(b))), name
@@ -116,24 +123,31 @@ def test_the_three_seams_are_called_and_each_changes_the_result(monkeypatch, req
         args = _operands(length=128)
     chunk = 64 if path == "kernels" else 32
 
-    def read():  # traced anew: with the fault in place, where there is one
+    # ONE trace and ONE compile (a minute each on the kernels' path): every seam is swapped for itself with its fault
+    # beside it, and ``on`` [3], an operand, says at run time which fault, if any, the result takes
+    decays, carry, solve = dr._log_decays, dr._carry, dr._solve
+
+    def read(on, *a):
+        faults = {
+            "_log_decays": lambda g, chunk: (lambda sums: jnp.where(on[0], sums.astype(jnp.bfloat16).astype(jnp.float32), sums))(decays(g, chunk)),
+            "_carry": lambda *a, **kw: (lambda starts, last: (jnp.where(on[1], jnp.zeros_like(starts), starts), last))(*carry(*a, **kw)),
+            "_solve": lambda a, rhs: jnp.where(on[2], rhs, solve(a, rhs)),
+        }
+
         def loss(*a):
             o = dr.delta_rule(*a, chunk=chunk)
             return jnp.sum(o ** 2), o
-        (_, o), grad = jax.jit(jax.value_and_grad(loss, argnums=1, has_aux=True))(*args)
+
+        with monkeypatch.context() as patch:
+            for name, fault in faults.items():
+                patch.setattr(dr, name, fault)
+            (_, o), grad = jax.value_and_grad(loss, argnums=1, has_aux=True)(*a)
         return o, grad
 
-    sound, sound_grad = read()
-    decays, carry = dr._log_decays, dr._carry
-    faults = {
-        "_log_decays": lambda g, chunk: decays(g, chunk).astype(jnp.bfloat16).astype(jnp.float32),
-        "_carry": lambda *a, **kw: (lambda starts, last: (jnp.zeros_like(starts), last))(*carry(*a, **kw)),
-        "_solve": lambda a, rhs: rhs,
-    }
-    for name, fault in faults.items():
-        with monkeypatch.context() as patch:
-            patch.setattr(dr, name, fault)
-            o, grad = read()
+    read = jax.jit(read)
+    sound, sound_grad = read(jnp.zeros(3, bool), *args)
+    for at, name in enumerate(("_log_decays", "_carry", "_solve")):
+        o, grad = read(jnp.arange(3) == at, *args)
         off = float(jnp.max(jnp.abs(o - sound)) / jnp.max(jnp.abs(sound)))
         off_grad = float(jnp.max(jnp.abs(grad - sound_grad)) / jnp.max(jnp.abs(sound_grad)))
         assert off > (1e-4 if name == "_log_decays" else 1e-2) and off_grad > 1e-4, (name, off, off_grad)
@@ -171,11 +185,18 @@ def test_the_kernel_pair_is_the_xla_differences_forward_and_in_all_three_gradien
     cum = jnp.cumsum(-16.0 * jax.nn.softplus(jax.random.normal(ks[2], (*lead, size, 128)) + softplus_at), axis=-2)
     assert (float(cum.min()) < -800) == (softplus_at > 0)
     weigh = tuple(jax.random.normal(key, (*lead, size, size)) for key in ks[3:])
-    got, got_vjp = jax.vjp(lambda *a: dr._same_sub_block_kernels(*a, True), q, k, cum)
-    want, want_vjp = jax.vjp(_onto_diagonal, q, k, cum)
+
+    def masks_and_gradients(masks):  # ONE program a side
+        def both(*a):
+            out, vjp = jax.vjp(masks, *a)
+            return out, vjp(weigh)
+        return jax.jit(both)(q, k, cum)
+
+    got, got_grads = masks_and_gradients(lambda *a: dr._same_sub_block_kernels(*a, True))
+    want, want_grads = masks_and_gradients(_onto_diagonal)
     for g, w in zip(got, want):
         _close(g, w, 2e-6)
-    for name, g, w in zip("q k cum".split(), got_vjp(weigh), want_vjp(weigh)):
+    for name, g, w in zip("q k cum".split(), got_grads, want_grads):
         assert g.dtype == w.dtype and bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))), name
         # q, k: one rounding to bfloat16, each path from its own float32 sum.  The sums' gradient adds and takes away
         # each pair's z: where all but a few pairs underflow, XLA's transpose is the looser of the two against
@@ -188,17 +209,18 @@ def test_the_rule_on_the_kernel_path_is_the_recurrence_forward_and_in_every_grad
     args = _kernel_operands(softplus_at)
     assert "pallas_call" in str(jax.make_jaxpr(lambda *a: dr.delta_rule(*a, chunk=64))(*args))
     with jax.default_matmul_precision("highest"):
-        want, last = dr.delta_rule_reference(*args)
-        weigh = jax.random.normal(jax.random.key(9), want.shape)
+        weigh = jax.random.normal(jax.random.key(9), args[2].shape)
 
-        def read(*a):  # ONE program: the kernels are compiled for the interpreter once a pass
-            o, aux = dr.delta_rule(*a, chunk=64, with_aux=True)
-            return jnp.sum(o * weigh), (o, aux.state)
+        def read(rule):  # ONE program a side: the kernels are compiled for the interpreter once a pass
+            def loss(*a):
+                o, state = rule(*a)
+                return jnp.sum(o * weigh), (o, state)
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
 
-        (_, (o, state)), got = jax.jit(jax.value_and_grad(read, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+        (_, (o, state)), got = read(lambda *a: (lambda o, aux: (o, aux.state))(*dr.delta_rule(*a, chunk=64, with_aux=True)))
+        (_, (want, last)), ref = read(dr.delta_rule_reference)
         _close(o, want, 5e-6)
         _close(state, last, 1e-5)  # sums over 128 channels, sixteen times the other cases'
-        ref = jax.grad(lambda *a: jnp.sum(dr.delta_rule_reference(*a)[0] * weigh), argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip("q k v g beta".split(), got, ref):
         assert bool(jnp.all(jnp.isfinite(a))) and float(jnp.max(jnp.abs(a - b))) <= 5e-5 * float(jnp.max(jnp.abs(b))), name
 
@@ -275,8 +297,8 @@ def test_the_blocked_solve_is_the_float64_solve_and_its_gradients_are_the_triang
     weigh = jax.random.normal(jax.random.key(7), rhs.shape)
     with jax.default_matmul_precision("highest"):
         xla = lambda a, rhs: jax.lax.linalg.triangular_solve(a, rhs, left_side=True, lower=True, unit_diagonal=True)  # noqa: E731
-        got = jax.grad(lambda a, rhs: jnp.sum(dr._solve(a, rhs) * weigh), argnums=(0, 1))(a, rhs)
-        ref = jax.grad(lambda a, rhs: jnp.sum(xla(a, rhs) * weigh), argnums=(0, 1))(a, rhs)
+        got = jax.jit(jax.grad(lambda a, rhs: jnp.sum(dr._solve(a, rhs) * weigh), argnums=(0, 1)))(a, rhs)
+        ref = jax.jit(jax.grad(lambda a, rhs: jnp.sum(xla(a, rhs) * weigh), argnums=(0, 1)))(a, rhs)
     for g, r in zip(got, ref):
         _close(g, r, 1e-5)
     assert bool(jnp.all(jnp.triu(got[0]) == 0))  # the gradient lies where the operand is read
@@ -286,7 +308,7 @@ def test_the_blocked_solve_is_the_float64_solve_and_its_gradients_are_the_triang
 def test_the_solve_does_not_read_what_lies_on_or_above_the_diagonal(size):
     a, rhs = _system("random", size)
     nan_above = jnp.where(jnp.arange(size)[:, None] <= jnp.arange(size)[None, :], jnp.nan, a)
-    both = lambda a: (dr._solve(a, rhs),) + jax.grad(lambda a, rhs: jnp.sum(dr._solve(a, rhs) ** 2), argnums=(0, 1))(a, rhs)  # noqa: E731
+    both = jax.jit(lambda a: (dr._solve(a, rhs),) + jax.grad(lambda a, rhs: jnp.sum(dr._solve(a, rhs) ** 2), argnums=(0, 1))(a, rhs))
     for got, want in zip(both(nan_above), both(a)):
         assert bool(jnp.all(jnp.isfinite(got))) and bool(jnp.all(got == want))
 
@@ -314,12 +336,12 @@ def test_a_sequence_that_is_not_whole_chunks_takes_the_stepwise_path():
     args = _operands(length=40)
     assert dr.rule_path(40, 16) == (dr.PATH_STEPWISE, "L = 40 is not whole chunks of 16") and dr.rule_path(48, 16) == (dr.PATH_CHUNKED, "")
     with jax.default_matmul_precision("highest"):
-        o, aux = dr.delta_rule(*args, chunk=16, with_aux=True)
-        want, last = dr.delta_rule_reference(*args)
+        o, aux = jax.jit(lambda *a: dr.delta_rule(*a, chunk=16, with_aux=True))(*args)
+        want, last = jax.jit(dr.delta_rule_reference)(*args)
     _close(o, want, 1e-6)
     _close(aux.state, last, 1e-6)
     np.testing.assert_allclose(aux.log_decay, jnp.cumsum(args[3], axis=1), rtol=1e-5)
-    grads = jax.grad(lambda *a: jnp.sum(dr.delta_rule(*a, chunk=16) ** 2), argnums=(0, 1, 2, 3, 4))(*args)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(dr.delta_rule(*a, chunk=16) ** 2), argnums=(0, 1, 2, 3, 4)))(*args)
     assert all(bool(jnp.all(jnp.isfinite(t))) and float(jnp.max(jnp.abs(t))) > 0 for t in grads)
 
 

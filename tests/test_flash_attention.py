@@ -64,8 +64,8 @@ def test_vjp_matches_reference(causal, h, d):
     def loss_ref(q, k, v):
         return jnp.vdot(attention_reference(q, k, v, causal=causal), cot)
 
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
             gf, gr, atol=5e-5, rtol=5e-5, err_msg=f"d{name}"
@@ -120,8 +120,8 @@ def test_vjp_matches_reference_at_length(dtype, tol, l, causal):
     def loss_ref(q, k, v):
         return jnp.vdot(attention_reference(q, k, v, causal=causal), cot)
 
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(*_f32(q, k, v))
+    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(*_f32(q, k, v))
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
             np.asarray(gf, np.float32), np.asarray(gr),
@@ -151,8 +151,8 @@ def test_small_blocks_carry_state_across_pairs(monkeypatch, block, l, causal, h,
     flash = lambda q, k, v: fa.flash_attention(q, k, v, causal)  # noqa: E731
     ref = lambda q, k, v: attention_reference(q, k, v, causal=causal)  # noqa: E731
     np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=2e-5, rtol=2e-5)
-    g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(gf, gr, atol=5e-5, rtol=5e-5, err_msg=f"d{name}")
 
@@ -256,7 +256,7 @@ def _explicit_masked_softmax(q, k, v, q_rot, k_rot, causal):
 
 
 def _rotary_grads(attn, args, cot):
-    return jax.grad(lambda *a: jnp.vdot(attn(*a).astype(jnp.float32), cot), argnums=(0, 1, 2, 3, 4))(*args)
+    return jax.jit(jax.grad(lambda *a: jnp.vdot(attn(*a).astype(jnp.float32), cot), argnums=(0, 1, 2, 3, 4)))(*args)
 
 
 ROTARY = [(2, 64), (4, 64), (4, 32), (1, 128)]

@@ -82,22 +82,32 @@ def _batch(b: int = 2, seed: int = 0, l: int = KEYS["seq_len"]):
     return {"tokens": jnp.asarray(toks[:, :-1]), "labels": jnp.asarray(toks[:, 1:])}
 
 
-@functools.lru_cache(maxsize=None)
-def _system_and_reference():
+def _reference_loss_and_gradients(batch):
+    """``w -> ((loss, (logits, slots)), gradients)`` of the plain reference on
+    ``batch``, compiled: ONE program for every test that asks (a second
+    worker of the suite finds it in the run's compile cache)."""
     import optax
 
-    spec = _spec()
-    params, batch = _weights(spec), _batch()
     forward = reference().build(dict(KEYS))
 
     def ref_loss(w):
         z, slots = forward(w, batch["tokens"])
         return optax.softmax_cross_entropy_with_integer_labels(z, batch["labels"]).mean(), (z, slots)
 
-    with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(lambda w: spec.loss(spec.apply(w, batch, train=True), batch))(params)
-        want = jax.value_and_grad(ref_loss, has_aux=True)(params)
-        out = spec.apply(params, batch)
+    return jax.jit(jax.value_and_grad(ref_loss, has_aux=True))
+
+
+@functools.lru_cache(maxsize=None)
+def _system_and_reference():
+    spec = _spec()
+    params, batch = _weights(spec), _batch()
+
+    def system(w):
+        return jax.value_and_grad(lambda w: spec.loss(spec.apply(w, batch, train=True), batch))(w), spec.apply(w, batch)
+
+    with jax.default_matmul_precision("highest"):  # ONE program a side: op by op, three times the seconds for the same bits
+        got, out = jax.jit(system)(params)
+        want = _reference_loss_and_gradients(batch)(params)
     return got, want, out
 
 
@@ -159,11 +169,8 @@ def test_two_adamw_steps_and_the_correction_bias_are_the_references():
     change in each step to 1 % of its size (Adam's first steps are rate x
     g / (|g| + 1e-8): where float32 noise is a share of a small entry it is
     the same share of that entry's step), the correction biases TO THE BIT."""
-    import optax
-
     spec = _spec("float32", lr_warmup_steps=0, learning_rate=1e-3)
     ref = reference()
-    forward = ref.build(dict(KEYS))
     batch = _batch()
     trainer = Trainer(spec, JobConfig(), create_mesh(num_devices=1))
     state = trainer.init_state(jax.random.key(0))
@@ -173,14 +180,11 @@ def test_two_adamw_steps_and_the_correction_bias_are_the_references():
     decayed = ref.decayed(w)
     assert decayed == moe_lm._is_decayed(w, moe_lm._NOT_MATRICES)
 
-    def ref_loss(w):
-        z, slots = forward(w, batch["tokens"])
-        return optax.softmax_cross_entropy_with_integer_labels(z, batch["labels"]).mean(), slots
-
     with jax.default_matmul_precision("highest"):
+        ref_grads = _reference_loss_and_gradients(batch)  # compiled once, for both steps
         for t in (1, 2):
             state, metrics = trainer.train_step(state, trainer.shard_batch({k: np.asarray(v) for k, v in batch.items()}))
-            (loss, slots), grads = jax.value_and_grad(ref_loss, has_aux=True)(w)
+            (loss, (_, slots)), grads = ref_grads(w)
             assert float(metrics["loss"]) == pytest.approx(float(loss), rel=2e-6)
             m = jax.tree.map(lambda m, g: 0.9 * m + 0.1 * g, m, grads)
             nu = jax.tree.map(lambda v, g: 0.95 * v + 0.05 * g * g, nu, grads)
@@ -277,11 +281,12 @@ def test_the_step_counters_are_what_the_shapes_give_and_a_ragged_sequence_is_ste
     assert set(spec.step_counters) == set(moe_lm.MOE_COUNTERS) | set(linear_attention.KDA_COUNTERS)
     assert all(spec.step_counters[name] for name in linear_attention.KDA_COUNTERS)
     batch = _batch()
-    metrics = spec.metrics(spec.apply(spec.init(jax.random.key(0)), batch), batch)
+    metrics_of = jax.jit(lambda batch: spec.metrics(spec.apply(spec.init(jax.random.key(0)), batch), batch))  # a program a length
+    metrics = metrics_of(batch)
     assert float(metrics["kda_positions"]) == float(metrics["kda_positions_chunked"]) == 2 * 128 * 4 * 2  # two KDA layers of four heads
     assert float(metrics["moe_slots"]) == 2 * 2 * 128 * 3
     ragged = _batch(l=100)  # not whole chunks of 64: the op's stepwise path, counted as such
-    metrics = spec.metrics(spec.apply(spec.init(jax.random.key(0)), ragged), ragged)
+    metrics = metrics_of(ragged)
     assert float(metrics["kda_positions"]) == 2 * 100 * 8 and float(metrics["kda_positions_chunked"]) == 0
 
 
@@ -334,8 +339,8 @@ def test_the_job_trains_through_the_trainer():
 def test_bfloat16_compute_stays_near_the_float32_reference():
     spec, batch = _spec("bfloat16"), _batch()
     params = _weights(spec)
-    logits = spec.apply(params, batch)["logits"]
-    want, _ = reference().build(dict(KEYS))(params, batch["tokens"])
+    logits = jax.jit(lambda w: spec.apply(w, batch)["logits"])(params)
+    want, _ = jax.jit(reference().build(dict(KEYS)))(params, batch["tokens"])
     assert logits.dtype == jnp.float32
     # three layers at five times the init's scale (five read 0.10; nemotron_h's toy reads under 0.05 at its scale)
     assert float(jnp.sqrt(jnp.mean((logits - want) ** 2) / jnp.mean(want ** 2))) < 0.2

@@ -259,9 +259,9 @@ def test_a_held_range_computes_its_own_experts_part(lo, held):
     args = (u, part(wg), part(wu), part(wd))
     with jax.default_matmul_precision("highest"):
         want = dense(*args)
-        g_want = jax.grad(lambda *a: jnp.sum(dense(*a) ** 2), argnums=(0, 1, 2, 3))(*args)
+        g_want = jax.jit(jax.grad(lambda *a: jnp.sum(dense(*a) ** 2), argnums=(0, 1, 2, 3)))(*args)
     np.testing.assert_allclose(system(*args), want, atol=2e-5, rtol=2e-5)
-    g_got = jax.grad(lambda *a: jnp.sum(system(*a) ** 2), argnums=(0, 1, 2, 3))(*args)
+    g_got = jax.jit(jax.grad(lambda *a: jnp.sum(system(*a) ** 2), argnums=(0, 1, 2, 3)))(*args)
     for a, b in zip(g_got, g_want):
         assert _rel(a, b) <= 2e-5
     slots = moe.expert_ffn(*args[:1], choices, weights, *args[1:], n_experts=16, lo=lo)[1]
@@ -449,11 +449,12 @@ def test_bias_rule_over_two_steps_is_the_references_to_the_bit(reference):
         logits, slots = forward(params, batch["tokens"])
         return optax.softmax_cross_entropy_with_integer_labels(logits, batch["labels"]).mean(), slots
 
+    ref_grads = jax.jit(jax.value_and_grad(loss, has_aux=True))  # compiled once, for both steps
     for step in range(2):
         batch = _batch(seed=step)
         state, _ = trainer.train_step(state, trainer.shard_batch(batch))
         with jax.default_matmul_precision("highest"):
-            (_, slots), grads = jax.value_and_grad(loss, has_aux=True)(ref_params, batch)
+            (_, slots), grads = ref_grads(ref_params, batch)
         updates, opt_state = optimizer.update(grads, opt_state, ref_params)
         ref_params = reference.update_bias(optax.apply_updates(ref_params, updates), slots, keys["bias_update_speed"])
     for name in ("b01", "b02"):
@@ -485,7 +486,7 @@ def test_step_counters_count_the_overflow(reference, tilt):
     state = state.replace(params={**state.params, "blocks": {"b00": {**blk, "router_bias": bias}}})
     batch = _batch()
     with jax.default_matmul_precision("highest"):
-        _, ref_slots = reference.build(_keys(shape))(jax.device_get(state.params), batch["tokens"])
+        _, ref_slots = jax.jit(reference.build(_keys(shape)))(jax.device_get(state.params), batch["tokens"])
     sent = float(np.asarray(ref_slots)[0, lo:lo + held].sum())
     slots = batch["tokens"].size * KEYS["num_experts_per_tok"]
     bound = moe.held_rows_bound(slots, held, KEYS["num_experts"])

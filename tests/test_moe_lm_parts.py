@@ -63,7 +63,7 @@ def test_a_part_defined_outside_the_file_trains_between_two_real_layers():
     def loss(p):
         return spec.loss(spec.apply(p, batch, train=True, ctx=ParallelContext()), batch)
 
-    grads = jax.grad(loss)(params)
+    grads = jax.jit(jax.grad(loss))(params)
     assert all(bool(jnp.all(jnp.isfinite(g))) for g in jax.tree.leaves(grads))
     assert float(jnp.abs(grads["blocks"]["b01"]["toy_scale"])) > 0
     assert "policy=None" in str(jax.make_jaxpr(jax.grad(loss))(params))  # jax.checkpoint, keeping nothing

@@ -726,8 +726,9 @@ def _run_pair(workers, memberships):
     threads = [threading.Thread(target=run, args=(w,)) for w in workers]
     for t in threads:
         t.start()
+    deadline = time.monotonic() + 120  # ONE bound for the pair: a hang costs it once
     for t in threads:
-        t.join(timeout=120)
+        t.join(timeout=max(0.0, deadline - time.monotonic()))
     assert not errors, errors
     return results
 

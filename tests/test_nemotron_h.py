@@ -98,10 +98,12 @@ def _system_and_reference():
         z, slots = forward(w, batch["tokens"])
         return optax.softmax_cross_entropy_with_integer_labels(z, batch["labels"]).mean(), (z, slots)
 
-    with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(lambda w: spec.loss(spec.apply(w, batch, train=True), batch))(params)
-        want = jax.value_and_grad(ref_loss, has_aux=True)(params)
-        out = spec.apply(params, batch)
+    def system(w):
+        return jax.value_and_grad(lambda w: spec.loss(spec.apply(w, batch, train=True), batch))(w), spec.apply(w, batch)
+
+    with jax.default_matmul_precision("highest"):  # ONE program a side: op by op, three times the seconds for the same bits
+        got, out = jax.jit(system)(params)
+        want = jax.jit(jax.value_and_grad(ref_loss, has_aux=True))(params)
     return got, want, out
 
 
@@ -140,7 +142,7 @@ def test_the_correction_bias_moves_by_the_models_rule():
     # (that it gets no gradient: the gradient test above, which holds the gradients)
     spec = _spec()
     params = _weights(spec)
-    out = spec.apply(params, _batch())
+    out = jax.jit(lambda w: spec.apply(w, _batch()))(params)
     moved = spec.after_update(params, out)
     want = reference().update_bias(params, out["router_slots"], KEYS["bias_update_speed"])
     for name in ("b00", "b02"):
@@ -191,7 +193,7 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
             assert blk["ssm_in"].shape == (32, 64 + (64 + 64) + 8)
             mixer = lambda heads: mamba.MambaMixer(  # noqa: E731
                 heads, head_dim=8, groups=heads // 2, state=8, conv_kernel=4, chunk=16, eps=1e-5, dt_range=(1e-3, 0.1, 1e-4))
-            part = lambda blk: mixer(blk["A_log"].shape[0]).apply(u, blk, None, None, cast)  # noqa: E731
+            part = jax.jit(lambda blk: mixer(blk["A_log"].shape[0]).apply(u, blk, None, None, cast))  # a program a shape: the whole layer's, a share's
             (whole, counts), shares = part(blk), [part(_share_of_mamba(blk, lo, 2)) for lo in (0, 2, 4, 6)]
             assert float(counts["ssm_positions"]) == sum(float(held["ssm_positions"]) for _, held in shares) == 2 * KEYS["seq_len"] * 8
             parts = [got for got, _ in shares]
@@ -228,7 +230,7 @@ def test_the_step_counters_are_what_the_shapes_give():
     spec = _spec()
     assert set(spec.step_counters) == set(moe_lm.MOE_COUNTERS) | set(mamba.SSM_COUNTERS)
     batch = _batch()
-    metrics = spec.metrics(spec.apply(spec.init(jax.random.key(0)), batch), batch)
+    metrics = jax.jit(lambda: spec.metrics(spec.apply(spec.init(jax.random.key(0)), batch), batch))()
     assert float(metrics["ssm_positions"]) == 2 * 48 * (4 + 4)
     assert float(metrics["ssm_positions_kernel"]) == 0  # the CPU's XLA path (and chunks of 16 are outside the kernels' contract)
     assert float(metrics["moe_slots"]) == 2 * 2 * 48 * 5
@@ -246,7 +248,8 @@ def test_the_kernel_counter_is_all_of_the_positions_where_the_scans_kernels_run(
     spec = _spec(num_hidden_layers=2, hybrid_override_pattern="MM", mamba_num_heads=4, mamba_heads_held=4, mamba_head_dim=64,
                  n_groups=2, ssm_state_size=128, chunk_size=128, seq_len=256)
     params, batch = _weights(spec), _batch(b=1, l=256)
-    read = lambda: (lambda out: (out["logits"], spec.metrics(out, batch)))(spec.apply(params, batch))  # noqa: E731
+    # traced anew at each call: the backend is asked at trace time
+    read = lambda: jax.jit(lambda w: (lambda out: (out["logits"], spec.metrics(out, batch)))(spec.apply(w, batch)))(params)  # noqa: E731
     logits, metrics = read()
     assert float(metrics["ssm_positions"]) == 256 * (4 + 4) and float(metrics["ssm_positions_kernel"]) == 0
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -279,8 +282,8 @@ def test_adamw_decays_the_matrices_alone_and_the_job_trains():
 def test_bfloat16_compute_stays_near_the_float32_reference():
     spec, batch = _spec("bfloat16"), _batch()
     params = _weights(spec)
-    logits = spec.apply(params, batch)["logits"]
-    want, _ = reference().build(dict(KEYS))(params, batch["tokens"])
+    logits = jax.jit(lambda w: spec.apply(w, batch)["logits"])(params)
+    want, _ = jax.jit(reference().build(dict(KEYS)))(params, batch["tokens"])
     assert logits.dtype == jnp.float32
     assert float(jnp.sqrt(jnp.mean((logits - want) ** 2) / jnp.mean(want ** 2))) < 0.05
 
@@ -353,8 +356,8 @@ def test_two_matrix_experts_through_the_overflow_tier_stay_dropless(under_checkp
         assert int(given.first) == bound and int(given.first + given.second) == 40 * k == int(jnp.sum(slots[lo:lo + held]))
         np.testing.assert_allclose(y, dense(u, w_up, w_down, weights), rtol=1e-5, atol=1e-5)
         g = jax.random.normal(jax.random.key(9), y.shape)
-        got = jax.grad(lambda *a: jnp.sum(run(*a) * g), argnums=(0, 1, 2, 3))(u, w_up, w_down, weights)
-        want = jax.grad(lambda *a: jnp.sum(dense(*a) * g), argnums=(0, 1, 2, 3))(u, w_up, w_down, weights)
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(run(*a) * g), argnums=(0, 1, 2, 3)))(u, w_up, w_down, weights)
+        want = jax.jit(jax.grad(lambda *a: jnp.sum(dense(*a) * g), argnums=(0, 1, 2, 3)))(u, w_up, w_down, weights)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 

@@ -105,6 +105,44 @@ def test_second_process_reports_cache_hits_not_fresh_compiles(tmp_path):
     assert os.listdir(cache)  # every entry landed where the variable said
 
 
+#: a process of a tier-1 run: ``tests/conftest.py``'s rule for the compile
+#: cache at import, one program, the session's end
+_A_TIER1_PROCESS = (
+    "import os, sys\nsys.path.insert(0, 'tests')\nimport conftest\n"
+    + _COMPILE_ONCE
+    + "conftest.pytest_sessionfinish(None, 0)\n"
+    "print(json.dumps({'stats': compile_cache_stats(), 'there_at_the_end': os.path.isdir(compile_cache_stats()['dir'])}))\n"
+)
+
+
+def test_two_processes_of_a_run_share_the_cache_one_of_them_made(tmp_path):
+    """What an xdist worker or a child pytest inherits: a directory that
+    exists and carries the rule's prefix.  It is kept, the second process
+    reads what the first compiled, and neither removes it (its maker does)."""
+    made = tmp_path / "edl_tier1_jax_cache_of_the_run"
+    made.mkdir()
+    first, second = (
+        json.loads(_fresh(_A_TIER1_PROCESS, JAX_COMPILATION_CACHE_DIR=str(made)).stdout.splitlines()[-1])
+        for _ in range(2)
+    )
+    assert first["stats"]["dir"] == second["stats"]["dir"] == str(made)
+    assert first["stats"]["functions"]["jit(smoke_fn)"]["cache"] == "miss"
+    assert second["stats"]["functions"]["jit(smoke_fn)"]["cache"] == "hit"
+    assert first["there_at_the_end"] and second["there_at_the_end"] and os.listdir(made)
+
+
+def test_a_cache_directory_the_rule_did_not_make_is_not_adopted(tmp_path):
+    """A developer's own ``JAX_COMPILATION_CACHE_DIR``: the process makes a
+    throwaway directory instead, and removes it when its session ends."""
+    own = tmp_path / "a_developers_cache"
+    own.mkdir()
+    out = json.loads(_fresh(_A_TIER1_PROCESS, JAX_COMPILATION_CACHE_DIR=str(own)).stdout.splitlines()[-1])
+    used = out["stats"]["dir"]
+    assert used != str(own) and os.path.basename(used).startswith("edl_tier1_jax_cache_")
+    assert out["stats"]["functions"]["jit(smoke_fn)"]["cache"] == "miss"
+    assert not out["there_at_the_end"] and not os.path.exists(used) and os.listdir(own) == []
+
+
 def test_device_summary_names_what_answered():
     import jax
 

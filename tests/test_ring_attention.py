@@ -58,10 +58,10 @@ def test_ring_gradients_match(devices):
     q, k, v = _qkv(1)
     cot = jax.random.normal(jax.random.key(9), (B, L, H, D))
 
-    ref_grads = jax.grad(
+    ref_grads = jax.jit(jax.grad(
         lambda q, k, v: jnp.sum(attention_reference(q, k, v, causal=True) * cot),
         argnums=(0, 1, 2),
-    )(q, k, v)
+    ))(q, k, v)
 
     axis = mesh.axis_names[0]
     spec = P(None, axis)
