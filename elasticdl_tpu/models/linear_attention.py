@@ -16,9 +16,14 @@ No projection carries a bias.  A sharded sequence is refused: the state at a
 shard's start lives on the shard before it.
 
 Scopes: ``kda_proj`` (the seven projections and ``Wo``), ``kda_glue`` (the
-convolutions — ``ops/ssm.causal_conv``, whose own ``ssm_conv`` nests under it
-— silu, l2norm, the decay's softplus, the gated norm a head), ``kda_scan``
-(the op, forward and backward).
+three chains conv -> silu -> l2norm, the decay's softplus, the gated norm a
+head), ``kda_scan`` (the op, forward and backward).  A chain is ONE op where
+``ops/short_conv.conv_path`` says the kernels take it (a TPU, whole lanes and
+heads: its ``kda_conv`` nests under ``kda_glue``, both passes) and the XLA
+chain everywhere else (``ops/ssm.causal_conv``, whose own ``ssm_conv`` nests
+under it, silu, l2norm, under a ``jax.checkpoint`` of its own); ``apply``
+asks, counts (``kda_positions_conv_kernel``) and logs ONE ``attention path:``
+line a distinct chain.
 """
 
 from __future__ import annotations
@@ -35,16 +40,20 @@ from elasticdl_tpu.common.jax_compat import axis_size
 from elasticdl_tpu.models.parts import Draws, Part
 from elasticdl_tpu.ops import delta_rule as delta_ops
 from elasticdl_tpu.ops import remat as remat_lib
+from elasticdl_tpu.ops import short_conv as conv_ops
 from elasticdl_tpu.ops import ssm as ssm_ops
+from elasticdl_tpu.ops.ring_attention import PATH_PALLAS_INTERPRET, PATH_XLA_REFERENCE, announce_path
 
 #: The linear-attention layers' counts a step reports (``ModelSpec.step_counters``;
 #: gauges ``edl_kda_positions*_total``): what the traffic asks of the op, from
 #: the shapes it was called with, the part of it the chunked form took
-#: (``benchmark/metrics/kda_chunked_pct.kda.json`` reads the pair) and the part
-#: of THAT whose same-sub-block decay masks the Pallas kernels computed
-#: (no entry reads it yet: PERF.md section 7).  Each is counted where the op is called,
-#: through the very function the op asks (``rule_path``, ``mask_path``), of the
-#: very operands: a constant re-derived elsewhere would read 100 whatever ran.
+#: (``benchmark/metrics/kda_chunked_pct.kda.json`` reads the pair), the part
+#: of THAT whose same-sub-block decay masks the Pallas kernels computed, and
+#: the part of the first whose three convolution chains the Pallas kernels
+#: computed (no entry reads either yet: PERF.md section 7).  Each is counted in
+#: ``apply``, where the op is called, through the very function the op asks
+#: (``rule_path``, ``mask_path``, ``conv_path``), of the very operands: a
+#: constant re-derived elsewhere would read 100 whatever ran.
 KDA_COUNTERS = {
     "kda_positions": "(head, position) pairs the gated delta rule advanced a state over, from the "
     "shapes it was called with, summed over layers, training steps and devices",
@@ -53,8 +62,11 @@ KDA_COUNTERS = {
     "kda_positions_mask_kernel": "those of the chunked ones whose same-sub-block decay masks ops/delta_rule_kernels.py "
     "computed (ops/delta_rule.mask_path: a TPU, dk whole lanes, a chunk that divides 128; the others took the XLA "
     "differences), summed likewise",
+    "kda_positions_conv_kernel": "those of kda_positions whose three convolution chains (conv, silu, l2norm of q, k, v) "
+    "ops/short_conv_kernels.py computed (ops/short_conv.conv_path: a TPU, whole lanes and heads, L whole halos; the "
+    "others took the XLA chain over ops/ssm.causal_conv), summed likewise",
 }
-L2_EPS = 1e-6
+L2_EPS = conv_ops.L2_EPS
 
 
 def l2norm(x):
@@ -76,6 +88,19 @@ def _short_conv_l2(t, taps, head_dim: int):
     """``l2norm(silu(conv(t)))``, the norm a head of ``head_dim`` of the last axis."""
     y = _conv_silu(t, taps)
     return l2norm(y.reshape(*y.shape[:-1], -1, head_dim)).reshape(y.shape)
+
+
+def _chain(t, taps, head_dim=None):
+    """One chain of the glue, ``l2norm(silu(conv(t)))`` a head of ``head_dim``
+    (none: ``silu(conv(t))``), by the path ``conv_path`` names for these very
+    operands: ``(the chain's output, whether the kernels computed it)``.  The
+    kernels' op holds what the XLA chain's ``jax.checkpoint`` holds, the
+    chain's input."""
+    path, why_not = conv_ops.conv_path(t, taps, head_dim)
+    announce_path(path, t, True, f"kda_conv taps={taps.shape[0]} norm={head_dim}" + f"; {why_not}" * bool(why_not))
+    if path == PATH_XLA_REFERENCE:
+        return (_short_conv(t, taps) if head_dim is None else _short_conv_l2(t, taps, head_dim)), False
+    return conv_ops.short_conv(t, taps, head_dim, interpret=path == PATH_PALLAS_INTERPRET), True
 
 
 @jax.checkpoint
@@ -132,17 +157,19 @@ class KimiDeltaAttention(Part):
             beta = (u @ cast(blk["kda_wb"])).astype(jnp.float32)
             gate = remat_lib.product("kda_gate", u @ cast(blk["kda_wg_a"]), cast(blk["kda_wg_b"]))
         with jax.named_scope("kda_glue"):
-            # Each chain of the glue is rematerialised on its own (inside the layer's): a gradient holds the
-            # chain's bfloat16 input and computes its float32 intermediates again, not eight [L, H x dk] float32 arrays
-            q, k = (by_head(_short_conv_l2(t, blk[f"kda_conv_{name}"], hd)) for name, t in (("q", q), ("k", k)))
-            q, v = q * hd ** -0.5, by_head(_short_conv(v, blk["kda_conv_v"]))
+            # Each chain of the glue holds its bfloat16 input for the gradient (inside the layer's rematerialisation)
+            # and computes its float32 intermediates again, not eight [L, H x dk] float32 arrays
+            chains = (("q", q, hd), ("k", k, hd), ("v", v, None))
+            (q, k, v), by_conv_kernels = zip(*(_chain(t, blk[f"kda_conv_{name}"], norm) for name, t, norm in chains))
+            q, k, v = by_head(q) * hd ** -0.5, by_head(k), by_head(v)
             g = by_head(_log_decay(decay, blk["dt_bias"])) * -jnp.exp(blk["A_log"])[:, None]
             beta = jax.nn.sigmoid(beta)
         o = delta_ops.delta_rule(q, k, v, g, beta, chunk=self.chunk)
         # counted where the op is called, from what it was called with
         chunked = delta_ops.rule_path(l, self.chunk)[0] == delta_ops.PATH_CHUNKED
         by_kernel = chunked and delta_ops.mask_path(k, self.chunk)[0] != delta_ops.PATH_XLA_REFERENCE
-        counts = {name: jnp.float32(b * l * heads * part) for name, part in zip(KDA_COUNTERS, (1, chunked, by_kernel))}
+        parts = (1, chunked, by_kernel, all(by_conv_kernels))
+        counts = {name: jnp.float32(b * l * heads * part) for name, part in zip(KDA_COUNTERS, parts)}
         with jax.named_scope("kda_glue"):
             o = jax.checkpoint(delta_ops.gated_head_norm, static_argnums=(3,))(o, by_head(gate), blk["kda_norm"], self.eps)
         with jax.named_scope("kda_proj"):

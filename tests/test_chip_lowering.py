@@ -868,8 +868,14 @@ def test_kimi_linear_step_compiles_for_v5e_inside_the_line_with_its_scopes_and_t
     Mosaic kernels of ``ops/delta_rule_kernels.py`` under ``kda_scan`` /
     ``kda_mask`` (PR 49: the masks a layer's forward scan and again in its
     backward scan, their transpose there once), which the op's ``attention
-    path:`` line says; the experts are grouped matmuls; and no [*, 8192,
-    8192] score matrix exists."""
+    path:`` line says; each of a layer's three convolution chains (conv ->
+    silu -> l2norm) is the Mosaic pair of ``ops/short_conv_kernels.py`` under
+    ``kda_glue`` / ``kda_conv`` and nothing else (PR 53: the forward in the
+    layer's forward and in its rematerialised repeat, the gradient once; no
+    float32 array of a chain's [8192, 4096], no padded copy, and
+    ``ops/ssm.causal_conv``'s ``ssm_conv`` is gone from THIS step), which
+    the part's two ``attention path:`` lines say; the experts are grouped
+    matmuls; and no [*, 8192, 8192] score matrix exists."""
     import json
     import os
 
@@ -892,11 +898,12 @@ def test_kimi_linear_step_compiles_for_v5e_inside_the_line_with_its_scopes_and_t
     assert abs(compiled.memory_analysis().argument_size_in_bytes - 12 * 602434432) < 2**20  # parameters and two moments
     assert plan.kept == plan.tagged <= plan.budget and plan.tagged > 2 * 2**30
     text = compiled.as_text()
-    scopes = ("kda_proj", "kda_glue", "kda_scan", "ssm_conv", "mla_proj", "flash_attn", "moe_shared", "moe_router",
+    scopes = ("kda_proj", "kda_glue", "kda_conv", "kda_scan", "mla_proj", "flash_attn", "moe_shared", "moe_router",
               "moe_dispatch", "moe_experts", "moe_combine", "mlp", "lm_head")
     for scope in scopes:
         assert re.search(rf'op_name="[^"]*\b{scope}\b', text), scope
-    assert not re.search(r'op_name="[^"]*\bssm_(proj|scan|norm)\b', text)  # the state-space family's: not this model's
+    # the state-space family's are not this model's; ``ssm_conv`` (the XLA chains' convolution) left with the chains (PR 53)
+    assert not re.search(r'op_name="[^"]*\bssm_(proj|scan|norm|conv)\b', text)
     assert not re.search(r"\[(\d+,)*8192,8192\]", text)
     flash = _flash_calls(text)
     assert _signatures("\n".join(flash)) == [(5, 0), (6, 1), (6, 2)]  # ONE latent-attention layer, the forward ONCE: its output is kept
@@ -909,6 +916,20 @@ def test_kimi_linear_step_compiles_for_v5e_inside_the_line_with_its_scopes_and_t
     assert len(kernels) == len(under("kda_scan")) == len(under("kda_mask")) == 12, (len(kernels), len(under("kda_scan")), len(under("kda_mask")))
     assert sorted(set(kernels)) == ["kda_sub_block_mask_grads", "kda_sub_block_masks"] and kernels.count("kda_sub_block_mask_grads") == 4
     assert any("attention path: pallas-compiled" in line and "kda_mask chunk=64" in line for line in path_lines), path_lines
+    # the three chains of a layer (PR 53), in each of the four KDA layers: ONE forward kernel a chain in the layer's forward
+    # and again in its rematerialised repeat, ONE gradient kernel; every call under ``kda_glue`` AND ``kda_conv``, and
+    # nothing else of Mosaic's under either
+    chains = [re.search(r"%(kda_conv_chain\w*?)(\.\d+)? = ", call).group(1) for call in under("kda_glue")]
+    assert len(chains) == len(under("kda_conv")) == 3 * 4 * 3, (len(chains), len(under("kda_conv")))
+    assert chains.count("kda_conv_chain") == 3 * 4 * 2 and chains.count("kda_conv_chain_grads") == 3 * 4
+    assert all("bf16[1,8192,4096]" in call.split(" = ")[1].split("custom-call(")[0] for call in under("kda_conv"))
+    for norm in (128, None):
+        assert any("attention path: pallas-compiled" in line and f"kda_conv taps=4 norm={norm})" in line for line in path_lines), path_lines
+    # what a chain keeps in HBM is its bfloat16 operand and result: no float32 array of its size under its scope, and the
+    # XLA convolution's padded float32 copy ([1, 8192 + 3, 4096]) nowhere in the step
+    of_chains = [line.split(" = ")[1].split("(")[0] for line in text.splitlines() if re.search(r'op_name="[^"]*\bkda_conv\b', line) and " = " in line]
+    assert of_chains and not any(re.search(r"f32\[(\d+,)*8192,(\d+,)*4096\]|f32\[(\d+,)*8192,32,128\]", out) for out in of_chains), of_chains
+    assert "8195,4096]" not in text
     scoped = [line for line in text.splitlines() if re.search(r'op_name="[^"]*\bkda_scan\b', line)]
     # the solve a chunk is ``ops/delta_rule._solve``'s blocked forward substitution (PR 48): XLA's general triangular
     # solve (on the chip a custom call that is no Mosaic kernel, ``InvertDiagBlocksLowerTriangular``: 95 ms of the
@@ -971,7 +992,11 @@ MOE_LM_STEP_SHA256 = {
     # RE-PINNED in PR 49 (was 8afaf5cd...bad675096 since PR 48): the part counts a third number (``kda_positions_mask_kernel``)
     # and ``ops/delta_rule._masks_of`` traces under the scope ``kda_mask``; lowered from the CPU the same-sub-block masks
     # are the XLA differences they were (the kernels are not in this text); the four above and ``gpt2_medium``'s are untouched
-    ("kimi_linear_48b_a3b_ep32_l5", "job_seq8k_x1_v20480"): "559b0adf19c4eb66f79db89bdd5f051bcdb029be80cf9e620633b6500d75f734",
+    # RE-PINNED in PR 53 (was 559b0adf...3b6500d75f734 since PR 49): the part counts a fourth number (``kda_positions_conv_kernel``)
+    # and asks ``ops/short_conv.conv_path`` of each chain; lowered from the CPU the three chains are the XLA ones they were
+    # (``_short_conv`` / ``_short_conv_l2`` over ``ops/ssm.causal_conv``: as many pads and rsqrts as before, no kernel in this
+    # text; the text is 11 lines longer, the counter's sum and output); the four above and ``gpt2_medium``'s are untouched
+    ("kimi_linear_48b_a3b_ep32_l5", "job_seq8k_x1_v20480"): "98fb0198e63160b282a73f00f50d094d430255d714e2d300d3d71a21053ffae2",
 }
 
 

@@ -160,6 +160,7 @@ def test_the_part_alone_is_the_references_linear_attention():
     assert float(jnp.max(jnp.abs(got_normed - want))) <= 2e-5 * float(jnp.max(jnp.abs(want)))
     assert float(counts["kda_positions"]) == float(counts["kda_positions_chunked"]) == 2 * 128 * 4
     assert float(counts["kda_positions_mask_kernel"]) == 0  # off the TPU (and dk = 8): the XLA differences
+    assert float(counts["kda_positions_conv_kernel"]) == 0  # likewise: the XLA chains over ops/ssm.causal_conv
     assert float(jnp.max(jnp.abs(got - got_normed))) > 0
 
 
@@ -298,7 +299,7 @@ def test_the_mask_kernels_share_is_counted_where_the_op_is_called_from_what_it_w
     of a call that is chunked at all (``tests/test_delta_rule.py`` holds the
     traced op to the same answer: the Pallas calls are there or not)."""
     from elasticdl_tpu.models.parts import Draws
-    from elasticdl_tpu.ops import delta_rule
+    from elasticdl_tpu.ops import delta_rule, short_conv
 
     part = linear_attention.KimiDeltaAttention(heads=2, head_dim=head_dim, conv_kernel=4, eps=1e-5)
     blk = part.init(Draws(jax.random.key(0), 32, 0.02), 16)
@@ -309,6 +310,8 @@ def test_the_mask_kernels_share_is_counted_where_the_op_is_called_from_what_it_w
         return jnp.zeros_like(v)
 
     monkeypatch.setattr(delta_rule, "delta_rule", rule)
+    # ... and the chains' (their kernels compile for a TPU alone: the case after this one holds their share)
+    monkeypatch.setattr(short_conv, "short_conv", lambda t, taps, head_dim=None, *, interpret=False: jnp.zeros_like(t))
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     _, counts = part.apply(jnp.zeros((1, length, 16)), blk, None, None, lambda w: w)
     ((k, chunk),) = given
@@ -317,6 +320,92 @@ def test_the_mask_kernels_share_is_counted_where_the_op_is_called_from_what_it_w
     assert float(counts["kda_positions_mask_kernel"]) == length * 2 * kernel
     # the same question the op asks (``_masks_of``, of the same width and chunk), where the op is chunked at all
     assert (delta_rule.mask_path(k, chunk)[0] == "pallas-compiled") == bool(kernel or length % 64)
+
+
+@pytest.mark.parametrize("backend,head_dim,length,kernel,why", [
+    ("tpu", 128, 128, 1, ""), ("tpu", 64, 128, 0, "; a head of 64 is not whole multiples of 128 that divide C = 128"),
+    ("cpu", 128, 128, 0, "; backend=cpu"), ("tpu", 128, 100, 0, "; L = 100 is not whole multiples of 16"),
+], ids=["on_the_tpu_inside_the_contract", "narrow_heads", "off_the_tpu", "a_ragged_sequence"])
+def test_the_conv_kernels_share_is_counted_where_the_chains_are_called_and_the_path_is_logged(monkeypatch, backend, head_dim, length, kernel, why):
+    """``kda_positions_conv_kernel`` asks ``ops/short_conv.conv_path`` of the
+    very products and taps a chain is given, ``apply`` calls the kernels' op
+    or the XLA chain by the same answer, and ONE ``attention path:`` line a
+    distinct chain says which, with the reason (``tests/test_short_conv.py``
+    holds ``conv_path`` itself).  A head narrower than a lane tile leaves the
+    two NORMED chains outside the contract and the third inside: the pairs
+    count where all three are the kernels'."""
+    from elasticdl_tpu.models.parts import Draws
+    from elasticdl_tpu.ops import delta_rule, ring_attention, short_conv
+
+    part = linear_attention.KimiDeltaAttention(heads=2, head_dim=head_dim, conv_kernel=4, eps=1e-5)
+    blk = part.init(Draws(jax.random.key(0), 32, 0.02), 16)
+    by_kernels, lines = [], []
+
+    def op(t, taps, head_dim=None, *, interpret=False):  # the kernels' place (they compile for a TPU alone): what they are given
+        by_kernels.append((t.shape, taps.shape, head_dim, interpret))
+        return jnp.zeros_like(t)
+
+    monkeypatch.setattr(short_conv, "short_conv", op)
+    monkeypatch.setattr(delta_rule, "delta_rule", lambda q, k, v, g, beta, *, chunk: jnp.zeros_like(v))
+    monkeypatch.setattr(ring_attention, "_log_once", lines.append)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    _, counts = part.apply(jnp.zeros((1, length, 16)), blk, None, None, lambda w: w)
+    width = 2 * head_dim
+    normed_by_kernels = backend == "tpu" and head_dim % 128 == 0 and length % 16 == 0
+    unnormed_by_kernels = backend == "tpu" and length % 16 == 0
+    assert by_kernels == [((1, length, width), (4, width), head_dim, False)] * 2 * normed_by_kernels + [((1, length, width), (4, width), None, False)] * unnormed_by_kernels
+    assert float(counts["kda_positions"]) == length * 2 and float(counts["kda_positions_conv_kernel"]) == length * 2 * kernel
+    line = "attention path: {path} (q=(1, {length}, {width}) float32 causal=True; kda_conv taps=4 norm={norm}{why})"
+    assert lines[:3] == [
+        line.format(path="pallas-compiled" if normed_by_kernels else "xla-reference", length=length, width=width, norm=head_dim, why=why)
+    ] * 2 + [line.format(path="pallas-compiled" if unnormed_by_kernels else "xla-reference", length=length, width=width, norm=None,
+                         why=why if head_dim % 128 == 0 else "")]
+
+
+@pytest.fixture
+def on_the_conv_kernels(monkeypatch):
+    """``conv_path`` answers as if a test had given ``interpret``: every chain
+    below is the Pallas pair under the interpreter.  The blocks are
+    rematerialised, and jax keeps a ``jax.checkpoint``'s trace by shapes: the
+    caches are dropped on both sides, so that no trace made on one path
+    answers for the other."""
+    from elasticdl_tpu.ops import short_conv
+
+    path = short_conv.conv_path
+    jax.clear_caches()
+    monkeypatch.setattr(short_conv, "conv_path", lambda t, taps, head_dim, interpret=None: path(t, taps, head_dim, True))
+    yield
+    jax.clear_caches()
+
+
+def test_the_model_on_the_conv_kernels_is_the_model_on_the_xla_chains_in_loss_and_every_gradient(request):
+    """Heads of one lane tile put all three chains inside the kernels'
+    contract: with ``interpret`` forced the part counts every pair by the
+    kernels, and the model's loss and every gradient leaf are the XLA
+    path's (float32: both sides the same arithmetic but for the order of a
+    sum).  The model is its first layer alone (a KDA part and a dense
+    feed-forward) over one chunk: what the two programs have to differ in."""
+    wide = {"kda_layers": [1], "full_attn_layers": [], "num_heads": 2, "head_dim": 128, "short_conv_kernel_size": 4}
+    spec, batch = _spec(linear_attn_config=wide, num_hidden_layers=1, seq_len=64), _batch(l=64)
+    params = _weights(spec)
+
+    def read():  # ONE program a side
+        def loss(w):
+            out = spec.apply(w, batch, train=True)
+            return spec.loss(out, batch), spec.metrics(out, batch)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+
+    (want, counted), want_grads = read()
+    assert float(counted["kda_positions_conv_kernel"]) == 0 and float(counted["kda_positions"]) == 2 * 64 * 2
+    request.getfixturevalue("on_the_conv_kernels")
+    (got, counted), got_grads = read()  # the count below is ``_chain``'s own answer: the kernels' op was what it called
+    assert float(counted["kda_positions_conv_kernel"]) == float(counted["kda_positions"]) == 2 * 64 * 2
+    assert abs(float(got) - float(want)) <= 1e-6 * abs(float(want))
+    leaves = jax.tree_util.tree_leaves_with_path(want_grads)
+    assert {path[-1].key for path, _ in leaves} >= {"kda_conv_q", "kda_conv_k", "kda_conv_v", "kda_wq", "tok_emb"}
+    for (path, b), a in zip(leaves, jax.tree.leaves(got_grads)):
+        assert bool(jnp.all(jnp.isfinite(a))) and float(jnp.max(jnp.abs(a - b))) <= 2e-5 * max(float(jnp.max(jnp.abs(b))), 1e-12), path
 
 
 def test_the_job_trains_through_the_trainer():
