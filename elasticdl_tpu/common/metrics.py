@@ -82,6 +82,18 @@ def finalize_metrics(metrics: Dict) -> Dict[str, float]:
     return out
 
 
+class _ThreadPhases:
+    """One thread's state in a ``PhaseTimers``: its nesting stack and the
+    phase it is in.  Written by that thread alone."""
+
+    __slots__ = ("stack", "open")
+
+    def __init__(self):
+        self.stack: list = []
+        #: (name, perf_counter at entry, task) of the innermost open phase.
+        self.open: Optional[tuple] = None
+
+
 class PhaseTimers:
     """Cumulative wall-clock per named worker task-loop phase.
 
@@ -136,6 +148,10 @@ class PhaseTimers:
         self._seconds: Dict[str, float] = {}
         self._counts: Dict[str, int] = {}
         self._local = threading.local()
+        # The per-thread state (``_ThreadPhases``) of the ONE thread that
+        # called ``watch_this_thread`` (the worker's task loop, once), for
+        # a reader on another thread: see ``watched_open``.
+        self._watched: Optional[_ThreadPhases] = None  # single-writer: main
         # graftgauge (r14): with a registry wired, every phase ENTRY also
         # observes into a per-phase duration histogram (shared log grid),
         # so a live scrape shows the phase tail SHAPE — the cumulative
@@ -146,14 +162,20 @@ class PhaseTimers:
         self._gauges = gauges
         self._phase_hists: Dict[str, object] = {}  # guarded-by: _lock
 
+    def _thread(self) -> "_ThreadPhases":
+        """The calling thread's state, made at its first phase."""
+        mine = getattr(self._local, "mine", None)
+        if mine is None:
+            mine = self._local.mine = _ThreadPhases()
+        return mine
+
     @contextlib.contextmanager
     def phase(self, name: str, **attrs):
         """``attrs`` go to the phase's trace span only (the task id that
         ties spans of one task together across threads); the cumulative
         timers are keyed by ``name`` alone."""
-        stack = getattr(self._local, "stack", None)
-        if stack is None:
-            stack = self._local.stack = []
+        mine = getattr(self._local, "mine", None) or self._thread()
+        stack = mine.stack
         child_wall = [0.0]
         stack.append(child_wall)
         # Every phase doubles as a trace span (category "phase") when the
@@ -164,9 +186,14 @@ class PhaseTimers:
         sp = trace.span(name, cat="phase", **attrs)
         sp.__enter__()
         t0 = time.perf_counter()
+        # What this thread is in, for a reader on another thread (one
+        # store here, one on the way out, ring on or off, no lock).
+        enclosing = mine.open
+        mine.open = (name, t0, attrs.get("task"))
         try:
             yield
         finally:
+            mine.open = enclosing
             elapsed = time.perf_counter() - t0
             sp.__exit__(None, None, None)
             stack.pop()
@@ -175,6 +202,21 @@ class PhaseTimers:
                 # subtract; this phase keeps only its self-time.
                 stack[-1][0] += elapsed
             self.add(name, elapsed - child_wall[0])
+
+    def watch_this_thread(self) -> None:
+        """Publish the calling thread's open phase to ``watched_open``:
+        the task loop calls this once, before its first phase."""
+        self._watched = self._thread()
+
+    def watched_open(self) -> Optional[tuple]:
+        """``(name, perf_counter at entry, task)`` of the innermost phase
+        the watched thread is in right now, or None (outside every phase,
+        or no thread is watched).  Read from ANOTHER thread, racily by
+        design: one attribute load of a tuple its writer replaces whole,
+        so the reader sees a phase that was open a moment ago, never a
+        torn one."""
+        watched = self._watched
+        return None if watched is None else watched.open
 
     def add(self, name: str, seconds: float) -> None:
         with self._lock:
@@ -353,13 +395,18 @@ class MetricsWriter:
     def write(
         self, kind: str, step: int, metrics: Dict[str, float],
         tensorboard: bool = True,
+        text: Optional[Dict] = None,
     ) -> None:
         """Record one scalar group: kind is "train" | "eval" | custom.
         ``tensorboard=False`` keeps the group out of the TensorBoard
         mirror (0.14 ms per five scalars on the chip machine's host, five
         unbuffered appends, beside 0.02 ms for the JSONL line: PERF.md
-        section 6, PR 43; on a report handler's path)."""
+        section 6, PR 43; on a report handler's path).  ``text`` holds what
+        of the group is no scalar (a ``stall`` record's cause and stacks):
+        JSON values, in the JSONL line as they are and never in the
+        mirror."""
         record = {
+            **(text or {}),
             "ts": time.time(),
             "kind": kind,
             "step": int(step),

@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import random
+import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -155,8 +156,16 @@ MASTER_SCHEMAS: Dict[str, MessageSchema] = {
             # master writes it as one "setup" record of metrics.jsonl.
             # Additive and optional.
             "setup": _DICT,
+            # stall (PR 54): the worker's record of ONE stalled gap between
+            # two of its training reports (common/stall.py: what the loop,
+            # its threads and the device were in), on the report that
+            # ended the gap and on no other.  The master writes it as one
+            # "stall" record of metrics.jsonl.  Additive and optional.
+            "stall": _DICT,
         },
-        since={"requeue": 9, "seq": 18, "counters": 24, "setup": 35},
+        since={
+            "requeue": 9, "seq": 18, "counters": 24, "setup": 35, "stall": 54,
+        },
     ),
     "ReportVersion": MessageSchema(
         required={"model_version": _INT}, optional={"worker_id": _STR}
@@ -691,6 +700,61 @@ def make_generic_handler(
     return grpc.method_handlers_generic_handler(service_name, handlers)
 
 
+class _InFlight:
+    """The call each thread is inside of, and the longest call that has
+    returned since ``take_longest``: what ``rpc:<Method>`` spans say in a
+    trace, kept where a reader on ANOTHER thread (the worker's stall
+    recorder, ``common/stall.py``) finds it with the ring off.
+
+    No lock.  ``open`` is keyed by thread id and a thread stores and
+    deletes its own key alone (dict item operations, GIL-atomic);
+    ``longest`` is replaced whole.  Two threads finishing at once can lose
+    the shorter-lived of two candidates for ``longest``: it names a
+    suspect, it accounts nothing."""
+
+    def __init__(self):
+        self.open: Dict[int, Tuple[str, float]] = {}
+        self.longest: Tuple[float, str] = (0.0, "")  # gil-atomic
+
+    def of(self, method: str) -> "_Call":
+        return _Call(self, method)
+
+    def of_thread(self, ident: int, now: float) -> Tuple[float, str]:
+        """(seconds so far, method) of the call thread ``ident`` is in."""
+        method, t0 = self.open.get(ident, ("", now))
+        return now - t0, method
+
+    def take_longest(self, ident: int) -> Tuple[float, str]:
+        """(seconds, method) of the longest call since the last take,
+        finished on any thread or still open on thread ``ident``."""
+        finished, self.longest = self.longest, (0.0, "")
+        return max(finished, self.of_thread(ident, time.perf_counter()))
+
+
+class _Call:
+    __slots__ = ("_all", "_method", "_ident", "_t0")
+
+    def __init__(self, all_calls: _InFlight, method: str):
+        self._all, self._method = all_calls, method
+
+    def __enter__(self) -> "_Call":
+        self._ident, self._t0 = threading.get_ident(), time.perf_counter()
+        self._all.open[self._ident] = (self._method, self._t0)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        took = time.perf_counter() - self._t0
+        self._all.open.pop(self._ident, None)
+        if took > self._all.longest[0]:
+            self._all.longest = (took, self._method)
+        return False
+
+
+#: This process's calls in flight (every ``JsonRpcClient`` and the
+#: worker's in-process master proxy publish here).
+IN_FLIGHT = _InFlight()
+
+
 class JsonRpcClient:
     """Typed-enough client for a JSON-over-gRPC service.
 
@@ -743,7 +807,7 @@ class JsonRpcClient:
             f"rpc:{method}", cat="rpc.client",
             method=method, deadline_s=timeout_s,
         )
-        with sp:
+        with sp, IN_FLIGHT.of(method):
             if sp.span_id and isinstance(request, dict):
                 envelope = dict(request.get("trace") or {})
                 envelope["ctx"] = [sp.span_id]

@@ -399,6 +399,15 @@ def set_bridge(factory) -> None:
     _REC.bridge = factory
 
 
+def bridge_span(name: str, **attrs):
+    """A span of the BRIDGE's trace alone: entered through the installed
+    bridge like every other span, never written to the ring (its writer
+    puts the ring's event there itself, once it knows more than the span's
+    beginning did).  The shared no-op while no bridge is installed."""
+    bridge = _REC.bridge
+    return _NULL_SPAN if bridge is None else _BridgeSpan(bridge(name, attrs))
+
+
 def name_os_thread() -> None:
     """Give the calling thread's Python name to the OS (Linux; 15 bytes).
     Python 3.12 names threads for itself only, and a profiler names a

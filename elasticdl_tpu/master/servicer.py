@@ -620,6 +620,7 @@ class MasterServicer:
         task_type = req.get("task_type", "")
         self._record_phase_times(req)
         self._record_counters(req)
+        self._record_stall(req)
         self._record_trace(req)
         # stream=True: one JSONL "gauge" record per successful training
         # report, beside the "phase" record — the same crash-safe channel
@@ -759,11 +760,13 @@ class MasterServicer:
             self._stream_report_record("phase", req, phases)
 
     def _stream_report_record(
-        self, kind: str, req: dict, values: dict, tensorboard: bool = True
+        self, kind: str, req: dict, values: dict, tensorboard: bool = True,
+        text: Optional[dict] = None,
     ) -> None:
         """One JSONL record of a report-borne cumulative snapshot: written
         for successful non-eval reports only, ``ts`` by this master,
-        ``step`` = the report's model version."""
+        ``step`` = the report's model version.  ``text``: what of it is no
+        number (``MetricsWriter.write``)."""
         if (
             self.metrics_writer is None
             or not req.get("success", True)
@@ -778,6 +781,7 @@ class MasterServicer:
                 int(req.get("model_version", fallback_version)),
                 {k: float(v) for k, v in values.items()},
                 tensorboard=tensorboard,
+                text=text,
             )
         except Exception:  # malformed values must not fail the report
             logger.exception("%s metrics write failed", kind)
@@ -804,6 +808,25 @@ class MasterServicer:
             setup["setup:first_step_t0"] = last
             setup["setup:first_step_t1"] = max(trace.now_s(), last)
         self._stream_report_record("setup", req, setup, tensorboard=False)
+
+    def _record_stall(self, req: dict) -> None:
+        """A worker's record of one stalled gap between two of its
+        training reports (common/stall.py), which rides the report that
+        ended the gap: one ``stall`` record, its numbers as floats and the
+        rest (the cause, the phase, the RPC, the live samples with their
+        stacks) as it came."""
+        stall = req.get("stall")
+        if not stall:
+            return
+        numbers = {
+            k: v for k, v in stall.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)
+        }
+        text = {k: v for k, v in stall.items() if k not in numbers}
+        text["worker_id"] = req.get("worker_id", "")
+        self._stream_report_record(
+            "stall", req, numbers, tensorboard=False, text=text
+        )
 
     # hot-path: rides every report
     def _record_counters(self, req: dict) -> None:

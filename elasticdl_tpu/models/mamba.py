@@ -29,7 +29,7 @@ from elasticdl_tpu.ops import ssm as ssm_ops
 #: gauges ``edl_ssm_positions*_total``): what the traffic asks of the scan,
 #: from shapes (the operator's measure of scanned work), and the part of it
 #: the scan's kernels took, each layer's counted where its scan is called
-#: (no per-layer metric reads them yet: PERF.md section 7).
+#: (read by ``ssm_scan_kernel_pct.ssm``: the second over the first).
 SSM_COUNTERS = {
     "ssm_positions": "(head, position) pairs the state-space scans advanced a state over, from the "
     "shapes they were called with, summed over layers, training steps and devices",

@@ -494,8 +494,7 @@ class Trainer:
         self.ctx = self._make_ctx()
         self._state_specs = None
         self.keep_plan: Optional[KeepPlan] = None
-        #: Wall seconds of the last ``init_state`` (the worker's
-        #: ``init_state_s`` counter).
+        #: Wall seconds of the last ``init_state``.
         self.init_state_s = 0.0
         # ZeRO-style optimizer-state shard plan (opt_shard_plan) — set by
         # shard_state once the mode resolves against this mesh; None =
@@ -830,10 +829,8 @@ class Trainer:
         chip's memory initialises; nothing is built whole on device 0).
         jax's counter-based threefry makes the values independent of the
         number of devices."""
-        # ``init_state_s`` feeds the worker's counter of that name
-        # (worker._counter_snapshot; no metric reads it since PR 39: ROADMAP
-        # D16); the worker's set-up chain stamps the same call as its
-        # ``init_state`` span.
+        # The worker's set-up chain stamps the same call as its
+        # ``init_state`` span (metric ``setup_init_state_s``).
         t0 = time.monotonic()
         with trace.span("init_state"):
             state = jax.block_until_ready(self._init_program(rng)(rng))

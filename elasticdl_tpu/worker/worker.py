@@ -41,11 +41,13 @@ from elasticdl_tpu.common.platform import (
     device_peak_bytes,
 )
 from elasticdl_tpu.common.rpc import (
+    IN_FLIGHT,
     PROTOCOL_VERSION,
     BackoffPolicy,
     JsonRpcClient,
     call_with_backoff,
 )
+from elasticdl_tpu.common.stall import StallRecorder
 from elasticdl_tpu.data.ingest_pool import IngestPool, plan_chunks
 from elasticdl_tpu.data.prefetch import prefetch
 from elasticdl_tpu.data.reader import AbstractDataReader, Shard
@@ -77,9 +79,17 @@ COUNTER_GAUGES = {
         "edl_dispatches_device_idle_total",
         "training dispatches that found the previous one's output ready: "
         "the device queue had run dry and the chip waited for the host"),
-    "init_state_s": (
-        "edl_init_state_seconds",
-        "wall seconds of the last Trainer.init_state (the sharded jitted init)"),
+    "stalls": (
+        "edl_stalls_total",
+        "gaps between two training reports that exceeded the job's own pace "
+        "and that the next reports did not catch up (common/stall.py)"),
+    "stall_s": (
+        "edl_stall_seconds_total",
+        "seconds those gaps lost: their excess over the median gap, less "
+        "what the next gaps caught up"),
+    "stall_unnamed_s": (
+        "edl_stall_unnamed_seconds_total",
+        "those of them the recorder found no cause for (cause 'unnamed')"),
     "route_rows_recv_max": (
         "edl_route_rows_recv_max_total",
         "table rows the fullest shard served over the ragged route, summed "
@@ -144,7 +154,8 @@ class DirectMasterProxy:
         from elasticdl_tpu.common.rpc import MASTER_SCHEMAS, validate_message
 
         validate_message(method, request, MASTER_SCHEMAS)
-        return self._s.method_table()[method](request)
+        with IN_FLIGHT.of(method):  # as JsonRpcClient.call does
+            return self._s.method_table()[method](request)
 
 
 class RpcMasterProxy:
@@ -453,6 +464,7 @@ class Worker:
         # Task whose report closes the window (the last traced one).
         self._profile_last_task: Optional[int] = None  # single-writer: main
         self._profile_closer: Optional[threading.Thread] = None  # single-writer: main
+        self._profile_stops = 0  # single-writer: main (profiler stops begun)
         # Training dispatches, and those among them that began with the
         # previous dispatch's output already ready (the device had nothing
         # queued): see _dispatch_training_task.  _last_output is that
@@ -564,6 +576,12 @@ class Worker:
         # phases.  The registry hook adds a per-entry duration histogram
         # per phase (edl_phase_ms) to the live scrape.
         self.phases = PhaseTimers(gauges=self.gauges)
+        # The stall recorder (common/stall.py): judges the gap between two
+        # training reports from the job's own pace, at each report; its
+        # watchdog thread lives from run() to run()'s end.
+        self._stalls = StallRecorder(
+            self.phases, self._stall_probes, self._output_ready
+        )
         # grafttrace: --trace turns the per-process span recorder on (every
         # phase above doubles as a span; RPC boundaries, gang waits and
         # elastic transitions add their own).  Bounded slices ship to the
@@ -1587,8 +1605,12 @@ class Worker:
         self._profile_state = "closed"
         # graftlint: allow[shared-state] the _parked spin-wait handshake serializes the preemption thread's _flush_pending against the loop (see preemption_snapshot)
         self._profile_last_task = None
+        # A stall's span the watchdog still holds ends inside the window.
+        self._stalls.close_span()
         trace.set_bridge(None)
         traced = self._profile_traced
+        # graftlint: allow[shared-state] the _parked spin-wait handshake serializes the preemption thread's _flush_pending against the loop (see preemption_snapshot)
+        self._profile_stops += 1
 
         def _stop():
             t0 = time.perf_counter()
@@ -1662,6 +1684,40 @@ class Worker:
         setup.emit()
         return setup.flat()
 
+    def _stall_probes(self) -> Dict[str, Any]:
+        """What the stall recorder marks at every report besides its own
+        clocks (``StallRecorder``'s ``probes``): cumulative numbers whose
+        growth over a stalled gap names its cause, and what else is alive
+        in this process right now."""
+        compiles, compile_s = compile_counts()
+        injected_ms = 0.0
+        if chaos.enabled():
+            injected_ms = sum(
+                f["fired"] * f["ms"] for f in chaos.default().stats()
+                if f["kind"] in ("stall", "delay_rpc")
+            )
+        closer = self._profile_closer
+        with self._ckpt_lock:
+            saver = self._ckpt_thread
+        return {
+            "compiles": compiles,
+            "compile_s": compile_s,
+            "dispatches_device_idle": self._dispatches_idle,
+            "injected_s": injected_ms / 1e3,
+            "profile": self._profile_state or "none",
+            "profile_stops": self._profile_stops,
+            "profile_stopping": closer is not None and closer.is_alive(),
+            "saving": saver is not None and saver.is_alive(),
+            "prepping": any(not e[2].done() for e in self._prep_queue),
+        }
+
+    def _output_ready(self) -> Optional[bool]:
+        """Whether the newest training dispatch's output is ready (None
+        before the first): one non-blocking call, made by the stall
+        recorder's watchdog thread while a report is late."""
+        output = self._last_output
+        return None if output is None else bool(output.is_ready())
+
     def _counter_snapshot(self) -> Dict[str, float]:
         """The worker's cumulative counters, read once per report on the
         settle path (one ``memory_stats()`` per local device; nothing per
@@ -1675,12 +1731,7 @@ class Worker:
             "hbm_peak_bytes": device_peak_bytes(),
             "dispatches": self._dispatches,
             "dispatches_device_idle": self._dispatches_idle,
-            # No metric reads it since PR 39 retired init_state_s.ex4 (the
-            # set-up chain's ``init_state`` span is the same seconds, once):
-            # ROADMAP D16 takes this key out.
-            "init_state_s": round(
-                self.trainer.init_state_s if self.trainer else 0.0, 6
-            ),
+            **self._stalls.counters(),
             **step_counts,
         }
         return self._counters
@@ -2335,10 +2386,22 @@ class Worker:
         computable downstream, not just cumulative sums."""
         report["phase_times"] = self.phases.snapshot()
         report["phase_counts"] = self.phases.counts()
+        report["seq"] = self._next_report_seq()
+        # The stall recorder judges the gap this report ends (gaps run
+        # between successful training reports); a stalled gap's record
+        # rides this report and no other.  Before the counters, which
+        # count it.
+        if report["success"] and report.get("task_type") == TASK_TRAINING:
+            stall = self._stalls.on_report(
+                report["task_id"], report["seq"], report["phase_times"]
+            )
+            if stall is not None:
+                report["stall"] = stall
+        else:
+            self._stalls.taint()
         # Before the gauge envelope: its collector republishes this
         # snapshot.
         report["counters"] = self._counter_snapshot()
-        report["seq"] = self._next_report_seq()
         # Gauge envelope on every task report (forced past the ship
         # throttle: reports are bounded frequency by construction) — the
         # carrier of the master's per-report JSONL gauge mirror.
@@ -2785,12 +2848,14 @@ class Worker:
     def run(self, membership: Optional[dict] = None) -> Dict[str, Any]:
         """The task loop (``_run``) until the job ends, the world changes
         or something fails."""
+        self._stalls.start()  # this thread is the task loop
         try:
             return self._run(membership)
         finally:
             # Whatever ends the loop (job end, a restart for a re-form, a
             # failure): an open profile window is closed and written.
             self._profile_settle()
+            self._stalls.stop()
 
     # hot-path: the task loop itself — every deliberate blocking point is
     # either phase-accounted or individually waived with its reason
@@ -2931,6 +2996,8 @@ class Worker:
                 # model_version) until they land, and idling on unreported
                 # tasks would eventually look like a timeout/requeue.
                 self._drain_prep()
+                # Waiting for work is not a stall of this worker's.
+                self._stalls.taint()
                 # graftlint: allow[hot-path-sync] dispatcher idle: nothing to dispatch, the poll IS the work
                 time.sleep(self._poll)
                 continue

@@ -186,8 +186,13 @@ def test_the_chain_rides_the_first_training_report_and_no_other(tmp_path, device
     for a, b in zip(chain, chain[1:]):
         assert spans[a][1] == spans[b][0], (a, b)
     assert all(t0 <= t1 for t0, t1 in spans.values())
+    # The order, each pair of stamps on ONE clock: the master's ``ts`` are ``time.time()``, the chain's stamps
+    # ``trace.now_s()`` (an anchor plus ``perf_counter``), and the two drift apart by tens of microseconds in a
+    # process that has lived for minutes (16 us lost this line the driver's run of PR 53's tree, under -n 6).
     first_train = next(r for r in records if r["kind"] == "train")
-    assert first_train["ts"] <= spans["setup:first_step"][1] <= record["ts"] <= first_train["ts"] + 0.05
+    stamps = [r["ts"] for r in records]
+    assert stamps == sorted(stamps) and first_train["ts"] <= record["ts"]
+    assert spans["setup:first_dispatch"][1] == spans["setup:first_step"][0] <= spans["setup:first_step"][1]
     # who sent it, and what the first dispatch's compile was made of
     assert record["pid"] == os.getpid() and record["step"] == first_train["step"]
     assert record["compile_requests"] >= 1
