@@ -48,15 +48,24 @@ Two collective lookup implementations, selected at trace time:
 
 - ``ragged`` (default on multi-chip TPU) — the north-star **ragged
   all-to-all** route: sort local ids by owner shard, exchange
-  per-destination counts (n² int32), ``lax.ragged_all_to_all`` the ids to
-  their owners, lane-packed gather locally, ``lax.ragged_all_to_all`` the
-  vectors straight back, unsort.  Each vector crosses ICI exactly once, so
-  per-device vector traffic is ~``B_local·dim`` (id-distribution dependent),
-  independent of mesh size.  XLA:CPU does not implement the
-  ``ragged-all-to-all`` HLO, so tests exercise the identical
-  routing/offset/unsort code through a dense all_gather emulation of the
-  collective (``ragged_emulated``) that is semantically equivalent by
-  construction.
+  per-destination counts (n² int32), ``all_gather`` every chip's sorted
+  ids (``n·L`` int32: the size of the owner's slots either way), mask the
+  slots that belong to other owners, lane-packed gather locally,
+  ``lax.ragged_all_to_all`` the vectors straight back, unsort.  The ids do
+  NOT go by ``ragged_all_to_all``: the op splits its operand by rows of
+  the leading dimension and the TPU gives each row a 128-lane tile, so an
+  ``int32[L]`` of ids arrives as ``s32[n·L, 1, 128]`` (109 MB for 0.85 MB
+  at the benchmark's size) and reading lane 0 back out of it costs a
+  sixth of the step (PERF.md, PR 55).  A chunk stays where the all-gather
+  lands it (row k of the owner's ``[n, L]`` slots, at its place in sender
+  k's sorted list), so the two float legs' offsets are those places and
+  nothing is packed or searched.  Each vector crosses ICI exactly once, so
+  per-device vector traffic is ~``B_local·dim`` (id-distribution
+  dependent), independent of mesh size.  XLA:CPU does not implement the
+  ``ragged-all-to-all`` HLO, so tests run the two float legs through a
+  dense all_gather emulation of the collective (``ragged_emulated``) that
+  is semantically equivalent by construction; the plan, the ids' leg, the
+  mask and the unsort are the chip's own code.
 - ``dense`` (CPU fallback; also the n=1 degenerate) — ``all_gather`` every
   device's ids, masked lane-packed gather over the full global id list, then
   ``psum_scatter`` a ``[n·B_local, dim]`` array so each device receives its
@@ -701,7 +710,7 @@ def _dense_lookup(local_table: jax.Array, ids: jax.Array, axis_name: str, dim: i
 
 
 # ---------------------------------------------------------------------------
-# ragged route: sort by owner -> ragged all-to-all ids -> local gather ->
+# ragged route: sort by owner -> all_gather ids, mask -> local gather ->
 # ragged all-to-all vectors back -> unsort        (custom_vjp: retrace route)
 # ---------------------------------------------------------------------------
 
@@ -710,7 +719,8 @@ def _ragged_collective(operand, output, in_off, send, out_off, recv, axis_name,
                        emulate: bool):
     """``lax.ragged_all_to_all`` or a semantically-identical dense emulation.
 
-    The emulation exists because XLA:CPU lacks the ragged-all-to-all HLO: it
+    The route's two float legs (vectors back, cotangents out).  The
+    emulation exists because XLA:CPU lacks the ragged-all-to-all HLO: it
     all_gathers every device's operand and offset metadata, then each device
     assembles its output buffer position-by-position from the senders' chunks
     — exactly the op's documented placement semantics (chunk ``j`` of device
@@ -749,10 +759,15 @@ def _ragged_collective(operand, output, in_off, send, out_off, recv, axis_name,
 def _routing_plan(ids: jax.Array, axis_name: str, rows_local: int):
     """Per-device routing metadata for the ragged route.
 
-    Returns (perm, sorted_ids, send_sizes, in_off, out_off, recv_sizes,
-    back_out_off).  ``S[k, j]`` (how many ids device k sends to shard j) is
-    shared via one tiny [n, n] int32 all_gather; every offset both directions
-    derives from it, so forward and backward use one consistent plan.
+    Returns (perm, sorted_ids, send_sizes, in_off, recv_sizes, chunk_off).
+    ``S[k, j]`` (how many ids device k sends to shard j) is shared via one
+    tiny [n, n] int32 all_gather, and every offset, both directions, is a
+    row or a column of its exclusive cumsum along j: ``in_off[j]`` is where
+    my ids for shard j start in MY sorted list, ``chunk_off[k]`` where
+    sender k's ids for ME start in ITS sorted list (k's ``in_off`` for me).
+    Nothing is packed on the owner's side: a chunk keeps, in the owner's
+    [n, L] slots, the place it has in its sender's list, so these two are
+    all the plan there is.
     """
     n = axis_size(axis_name)
     me = lax.axis_index(axis_name)
@@ -765,13 +780,9 @@ def _routing_plan(ids: jax.Array, axis_name: str, rows_local: int):
     in_off = _exclusive_cumsum(send_sizes)
     S = lax.all_gather(send_sizes, axis_name)          # [n, n]
     recv_sizes = S[:, me]
-    # Where my chunk starts in shard j's recv buffer: senders before me.
-    before_me = (jnp.arange(n) < me)[:, None]
-    out_off = jnp.sum(jnp.where(before_me, S, 0), axis=0).astype(jnp.int32)
-    # Where shard j's RETURN chunk starts in my [L] buffer: my ids are sorted
-    # by owner, so it's my in_off — but computed on j's side it must be the
-    # same value; return routing reuses in_off/out_off with roles swapped.
-    return perm, sorted_ids, send_sizes, in_off, out_off, recv_sizes, S
+    before_me = (jnp.arange(n) < me)[None, :]
+    chunk_off = jnp.sum(jnp.where(before_me, S, 0), axis=1).astype(jnp.int32)
+    return perm, sorted_ids, send_sizes, in_off, recv_sizes, chunk_off
 
 
 def _exclusive_cumsum(x: jax.Array) -> jax.Array:
@@ -802,16 +813,19 @@ def _ragged_lookup_fwd(local_table, ids, carrier, axis_name: str, dim: int, emul
     L = flat_ids.shape[0]
 
     with jax.named_scope("route_plan"):
-        (perm, sorted_ids, send, in_off, out_off, recv, S) = _routing_plan(
+        perm, sorted_ids, send, in_off, recv, chunk_off = _routing_plan(
             flat_ids, axis_name, rows_local
         )
-    # ids -> owners.  Buffer statically sized n*L (worst-case skew: every
-    # shard's batch hits my rows); -1 padding = OOB = NaN row if ever read.
+    # ids -> owners: every chip's sorted list, whole, by one all_gather
+    # (why not a ragged leg: module docstring).  Sender k's chunk for me
+    # stays where it lands, [chunk_off[k], + recv[k]) of row k; the rest of
+    # the n * L slots (worst-case skew: every shard's batch hits my rows)
+    # belongs to other owners and reads -1 = OOB = a NaN row if ever read.
     with jax.named_scope("route_ids"):
-        id_buf = jnp.full((n * L,), -1, dtype=flat_ids.dtype)
-        recv_ids = _ragged_collective(
-            sorted_ids, id_buf, in_off, send, out_off, recv, axis_name, emulate
-        )
+        all_sorted = lax.all_gather(sorted_ids, axis_name)          # [n, L]
+        slot = lax.broadcasted_iota(jnp.int32, (n, L), 1)
+        mine = (slot >= chunk_off[:, None]) & (slot < (chunk_off + recv)[:, None])
+        recv_ids = jnp.where(mine, all_sorted, -1).reshape(-1)
     with jax.named_scope("route_gather"):
         local_rows = recv_ids - lax.axis_index(axis_name) * rows_local
         vecs = gather_rows(local_table, local_rows, dim)   # [n*L, dim], NaN on OOB
@@ -821,45 +835,42 @@ def _ragged_lookup_fwd(local_table, ids, carrier, axis_name: str, dim: int, emul
             _pack_geometry(local_table.shape[1], dim)[0],
         )
 
-    # vectors -> requesters: exactly the reverse plan.  My block offsets are
-    # recv's exclusive cumsum (received chunks are sender-ordered); my chunk
-    # lands back where requester j's sorted block for me starts — j's in_off
-    # for me, which is S[j, :me].sum() row-wise.
+    # vectors -> requesters: requester j's rows sit in row j of my slots
+    # where its ids landed, and go back to where j's sorted block for me
+    # starts, which is the same chunk_off[j].
     with jax.named_scope("route_vectors"):
-        me = lax.axis_index(axis_name)
-        back_in_off = _exclusive_cumsum(recv)
-        before = (jnp.arange(n) < me)[None, :]
-        back_out_off = jnp.sum(jnp.where(before, S, 0), axis=1).astype(jnp.int32)
         vec_buf = jnp.zeros((L, dim), vecs.dtype)
         sorted_out = _ragged_collective(
-            vecs, vec_buf, back_in_off, recv, back_out_off, send, axis_name, emulate
+            vecs, vec_buf, jnp.arange(n) * L + chunk_off, recv, chunk_off, send,
+            axis_name, emulate,
         )
     with jax.named_scope("route_unsort"):
         inv = jnp.zeros_like(perm).at[perm].set(jnp.arange(L))
         out = sorted_out[inv].reshape(ids_shape + (dim,))
-    residuals = (perm, send, in_off, out_off, recv, back_in_off, back_out_off,
-                 local_rows, local_table.shape, ids_shape,
-                 None if carrier is None else (lane,))
+    residuals = (perm, send, in_off, recv, local_rows, local_table.shape,
+                 ids_shape, None if carrier is None else (lane,))
     return (out, jnp.sum(recv), rows_in_range, physical.astype(jnp.int32)), residuals
 
 
 def _ragged_lookup_bwd(axis_name: str, dim: int, emulate: bool, residuals, g):
-    (perm, send, in_off, out_off, recv, back_in_off, back_out_off,
-     local_rows, table_shape_, ids_shape, handed) = residuals
+    (perm, send, in_off, recv, local_rows, table_shape_, ids_shape,
+     handed) = residuals
     g = g[0]  # the row counts and the row numbers are integers: no cotangent
     n = axis_size(axis_name)
     L = perm.shape[0]
-    # Cotangents retrace the forward id route (requester -> owner): sort by
-    # owner, ragged a2a with the SAME plan, then whole-physical-row
-    # scatter-add into the local shard.  Stale buffer slots hold
-    # local_rows=-1 (OOB), so the fill-mode transpose drops them — as it
-    # drops junk-id cotangents.
+    # Cotangents go the ids' way (requester -> owner): sorted by owner, each
+    # chunk into row ``me`` of its owner's slots at the place it has in my
+    # sorted list, which is where its ids sit there; then whole-physical-row
+    # scatter-add into the local shard.  The slots between chunks stay zero
+    # and hold local_rows < 0 (OOB), so the fill-mode transpose drops them —
+    # as it drops junk-id cotangents.
     with jax.named_scope("route_bwd_sort"):
         g_sorted = g.reshape(L, dim)[perm]
     with jax.named_scope("route_bwd_vectors"):
         g_buf = jnp.zeros((n * L, dim), g_sorted.dtype)
         g_at_owner = _ragged_collective(
-            g_sorted, g_buf, in_off, send, out_off, recv, axis_name, emulate
+            g_sorted, g_buf, in_off, send,
+            lax.axis_index(axis_name) * L + in_off, recv, axis_name, emulate,
         )
     ids_bar = np.zeros(ids_shape, jax.dtypes.float0)
     with jax.named_scope("route_bwd_scatter"):

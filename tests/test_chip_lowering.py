@@ -169,8 +169,8 @@ def test_deepfm_ragged_step_lowers_with_ragged_all_to_all(devices):
         .lower(lowering_platforms=("tpu",))
         .as_text()
     )
-    # ids out, vectors back, cotangents out.
-    assert text.count("ragged_all_to_all") == 3
+    # vectors back, cotangents out; the ids go by all_gather (PR 55).
+    assert text.count("ragged_all_to_all") == 2
 
 
 @pytest.fixture
@@ -285,7 +285,9 @@ def test_x4_init_and_step_compile_for_a_v5e_host(v5e_host, as_on_the_chip):
     through the chip's own compiler: the jitted init bears every output
     sharded with a per-device temporary far under a shard (the eager init
     needed 8 x the table on device 0), the step holds the real
-    ragged-all-to-all three times and fits a chip, and what the
+    ragged-all-to-all twice (vectors back, cotangents out: the ids reach
+    their owners by an all-gather under ``route_ids`` and no id is laid out
+    as a 512-byte row, PR 55) and fits a chip, and what the
     ``*_ms_step.ex4`` metrics match in a device trace is there: the route's
     named scopes, and the shard's dense Adam update applied by the merge
     sweep itself under ``table_apply``, in place: no gradient buffer."""
@@ -325,7 +327,9 @@ def test_x4_init_and_step_compile_for_a_v5e_host(v5e_host, as_on_the_chip):
     assert memory.temp_size_in_bytes < 0.15 * shard
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 8 * 2**30
     text = compiled.as_text()
-    assert len(re.findall(r" ragged-all-to-all\(", text)) == 3
+    assert len(re.findall(r" ragged-all-to-all\(", text)) == 2
+    assert "s32[212992,1,128]" not in text
+    assert re.search(r" all-gather(-start)?\([^\n]*op_name=\"[^\"]*\broute_ids\b", text)
     for scope in X4_ROUTE_SCOPES:
         assert re.search(rf"op_name=\"[^\"]*\b{scope}\b", text), scope
     _assert_the_sweep_applies_the_table_update(text, rows // 4)
