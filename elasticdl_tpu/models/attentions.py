@@ -36,8 +36,19 @@ chip's share under head parallelism: wq / wk / wv ``[d, held * hd]``, wo
     q = a Wq (``q_heads`` heads of ``head_dim``), k, v = a Wk, a Wv (``kv_heads`` heads), the HELD heads;
     query head h reads key/value head h // (heads / kv heads); causal softmax of q k / sqrt(head_dim); part = o Wo
 
-Over a sharded sequence the first two and the last run over the ring
-(``ops/ring_attention``) with global rotary positions; EVA refuses one.
+``afmoe``'s (:class:`GatedWindowAttention`: Trinity), H query heads over G key/value heads of ``head_dim``;
+a layer is ``sliding_attention`` (``window`` = ``sliding_window`` keys) or ``full_attention`` (``window`` 0):
+
+    q = a Wq [H, hd] ; k, v = a Wk, a Wv [G, hd] ; z = a Wz [H x hd]      (no bias; z the output's gate)
+    q = rmsnorm(q, q_norm) ; k = rmsnorm(k, k_norm)       (a norm A HEAD over its hd columns, ONE gain [hd] for all heads)
+    sliding layers only: q, k = rope(q), rope(k)          (rotate-half over the whole head; a FULL layer has NO position signal)
+    query head h reads key/value head h // (H / G) ; scores / sqrt(hd) ; softmax over the keys j <= p (full) or
+    p - window < j <= p (sliding: ``window`` keys, the query's own included)
+    part = (o * sigmoid(z)) Wo
+
+Over a sharded sequence the first two and ``nemotron_h``'s run over the ring
+(``ops/ring_attention``) with global rotary positions; EVA refuses one, and
+so does a window (the ring visits every block).
 """
 
 from __future__ import annotations
@@ -50,6 +61,7 @@ import jax.numpy as jnp
 from elasticdl_tpu.common.jax_compat import axis_size
 from elasticdl_tpu.models.parts import Draws, Part, rms_norm
 from elasticdl_tpu.ops import eva_attention as eva_ops
+from elasticdl_tpu.ops import flash_attention as flash_ops
 from elasticdl_tpu.ops import remat as remat_lib
 from elasticdl_tpu.ops.ring_attention import ring_attention
 
@@ -62,6 +74,20 @@ EVA_COUNTERS = {
     "scored against, from the shapes the attention was called with, summed over heads, layers, "
     "training steps and devices",
     "eva_pairs_summary": "(query, chunk summary) pairs of earlier windows, summed likewise",
+}
+
+
+#: What a model of window and full attention layers asks of its attention a
+#: step (gauges ``edl_attn_pairs_*_total``), from the shapes it was called
+#: with; the third also from the flash kernels' plan for those shapes.
+WINDOW_COUNTERS = {
+    "attn_pairs_window": "(query, key) pairs inside the queries' windows (a sequence of L under a window of W: W (W + 1) / 2 + "
+    "(L - W) W a head) that the sliding-attention layers' queries were scored against, from the shapes the attention was called "
+    "with, summed over heads, layers, training steps and devices",
+    "attn_pairs_full": "(query, key) pairs at or before the query (L (L + 1) / 2 a head) of the full-attention layers, summed likewise",
+    "attn_pairs_window_computed": "(query, key) pairs that a pass of the flash kernels multiplies for the sliding-attention "
+    "layers: the visited (block, block) pairs' sub-tiles as ops/flash_attention's plan cuts them, the mean of its three kernels "
+    "(forward, dQ, dK/dV), summed likewise; equals attn_pairs_window for a kernel that multiplies nothing the mask hides",
 }
 
 
@@ -251,3 +277,59 @@ class GroupedQueryAttention(Part):
         att = ring_attention(q, k, v, axis_name=axis, causal=True)
         with jax.named_scope("attn_proj"):
             return att.reshape(b, l, -1) @ cast(blk["wo"]), None
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedWindowAttention(Part):
+    """``afmoe``'s (module docstring): ``q_heads`` query heads over
+    ``kv_heads`` key/value heads, a norm a head on q and k, a sigmoid gate on
+    the output; under a ``window`` (> 0: a sliding layer) the keys a query
+    sees are its own and the ``window - 1`` before it and q, k take the
+    rotary turn, without one (0: a full layer) neither.  The key/value heads
+    are REPEATED to the queries' ahead of the attention, as
+    :class:`GroupedQueryAttention` does (the flash kernels' contract)."""
+
+    q_heads: int
+    kv_heads: int
+    head_dim: int
+    window: int
+    theta: float
+    eps: float
+
+    counters = WINDOW_COUNTERS
+
+    def init(self, draw: Draws, d: int):
+        q, kv = self.q_heads * self.head_dim, self.kv_heads * self.head_dim
+        return {
+            "wq": draw.normal((d, q)), "wk": draw.normal((d, kv)), "wv": draw.normal((d, kv)), "wz": draw.normal((d, q)),
+            "wo": draw.normal((q, d)),
+            "q_norm": jnp.ones((self.head_dim,), jnp.float32), "k_norm": jnp.ones((self.head_dim,), jnp.float32),
+        }
+
+    def apply(self, u, blk, positions, axis, cast):
+        b, l, _ = u.shape
+        with jax.named_scope("attn_proj"):
+            heads = lambda t: t.reshape(b, l, -1, self.head_dim)  # noqa: E731
+            # save sites (ops/remat.py): each product as the glue reads it
+            q, k, v = (heads(remat_lib.product(name, u, cast(blk["w" + name]))) for name in ("q", "k", "v"))
+            z = remat_lib.product("attn_gate", u, cast(blk["wz"]))
+        with jax.named_scope("attn_glue"):
+            q, k = rms_norm(q, blk["q_norm"], self.eps), rms_norm(k, blk["k_norm"], self.eps)
+            if self.window:
+                q, k = rope(q, positions, self.theta), rope(k, positions, self.theta)
+            group = self.q_heads // self.kv_heads
+            if group > 1:
+                k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        att = ring_attention(q, k, v, axis_name=axis, causal=True, window=self.window or None)
+        with jax.named_scope("attn_glue"):
+            gated = (att.reshape(b, l, -1) * jax.nn.sigmoid(z.astype(jnp.float32))).astype(att.dtype)
+        with jax.named_scope("attn_proj"):
+            return gated @ cast(blk["wo"]), None
+
+    def shape_counts(self, batch: int, length: int):
+        every = batch * self.q_heads
+        if not self.window or self.window >= length:
+            return {"attn_pairs_full": every * (length * (length + 1) // 2)}
+        w = self.window
+        computed = flash_ops.window_pairs_computed(length, w)
+        return {"attn_pairs_window": every * (w * (w + 1) // 2 + (length - w) * w), "attn_pairs_window_computed": every * computed}

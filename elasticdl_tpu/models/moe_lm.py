@@ -10,12 +10,14 @@ defaults and parameter names are OLMoE's (``OLMoE-1B-7B-0125``: every layer
 ``moe``, 64 experts, 8 a token, softmax router, untied head);
 ``kv_lora_rank`` > 0 makes it DeepSeek-V3's block (``kanana-2-30b-a3b``),
 ``attention_class`` ``"eva"`` EvaByte's, ``hybrid_override_pattern``
-``nemotron_h``'s, ``linear_attn_config`` ``kimi_linear``'s.
+``nemotron_h``'s, ``linear_attn_config`` ``kimi_linear``'s, ``sliding_window``
+> 0 ``afmoe``'s (Trinity).
 
 The model, with ``rmsnorm(x, g) = x * rsqrt(mean(x^2) + eps) * g``:
 
-    x   = tok_emb[tokens]                                 (no position table)
+    x   = tok_emb[tokens]                                 (no position table; times sqrt(d) under ``mup_enabled``)
     for each layer, for each (norm, part) of it:  x += part(rmsnorm(x, norm))
+                 (an entry ``(norm, part, norm after)``: x += rmsnorm(part(rmsnorm(x, norm)), norm after))
     logits = rmsnorm(x, norm_f) Whead                     (Whead = tok_emb^T when tied; float32 logits)
     loss = CE(logits, next token) + lb_coef * LB + z_coef * Z
     LB  = E * sum_{i, e} f[i, e] * P[e],   f[i, e] = share of (layer, token) pairs whose i-th choice is e,
@@ -76,6 +78,26 @@ block under this family's own spelling of the keys (``num_experts_per_token``,
 ``use_grouped_topk`` / ``num_expert_group`` / ``topk_group``; always a
 correction bias).
 
+With ``sliding_window`` > 0 (``afmoe``'s keys: Trinity) a layer is attention and
+a feed-forward, each BETWEEN two norms (``attn_norm`` / ``post_attn_norm``,
+``ffn_norm`` / ``post_ffn_norm``):
+
+    x  = tok_emb[tokens] * sqrt(d)                         (``mup_enabled``)
+    x += rmsnorm(attention_i(rmsnorm(x, attn_norm)), post_attn_norm)
+              attention_i (``models/attentions.GatedWindowAttention``): ``layer_types[i]`` is ``sliding_attention``
+              (the ``sliding_window`` keys up to the query's own, rotary) or ``full_attention`` (every earlier key,
+              NO position signal); H query heads over G key/value heads, a norm a head, a sigmoid gate on the output
+    x += rmsnorm(ffn_i(rmsnorm(x, ffn_norm)), post_ffn_norm)
+              ffn_i: dense for i < ``num_dense_layers``, else the ``deepseek_v3`` expert layer under this family's
+              spelling (``score_func``, ``route_norm``, ``route_scale``, ``num_shared_experts``; always a correction
+              bias, moved by ``load_balance_coeff``)
+
+ONE collision of spellings, settled by the family's builder: this family PUBLISHES
+``layer_types`` as each layer's ATTENTION kind, where ``model_spec(layer_types=...)``
+otherwise names feed-forwards (``"moe"`` / ``"dense"``); under ``sliding_window`` the
+key is read the published way and the feed-forwards follow from ``num_dense_layers``
+(``first_k_dense_replace``'s role).
+
 What the heads and experts that are not held would add is left out; the
 all-reduce of the head shares and the experts' exchange are not here.
 
@@ -107,7 +129,13 @@ import optax
 from jax import lax
 
 from elasticdl_tpu.data.codecs import lm_feed
-from elasticdl_tpu.models.attentions import EvaAttention, GroupedQueryAttention, LatentAttention, QKNormAttention
+from elasticdl_tpu.models.attentions import (
+    EvaAttention,
+    GatedWindowAttention,
+    GroupedQueryAttention,
+    LatentAttention,
+    QKNormAttention,
+)
 from elasticdl_tpu.models.linear_attention import KimiDeltaAttention
 from elasticdl_tpu.models.mamba import MambaMixer
 from elasticdl_tpu.models.parts import Draws, Part
@@ -134,13 +162,20 @@ MOE_COUNTERS = {
     "moe_expert_load_mean": "slots on a device's average held expert, summed likewise",
 }
 LAYER_TYPES = ("moe", "dense")
+#: ``layer_types`` as ``afmoe`` publishes it: each layer's ATTENTION
+ATTENTION_LAYER_TYPES = ("sliding_attention", "full_attention")
 #: ``hybrid_override_pattern``'s letters (``-``, a dense MLP layer, is not one: no cell runs it)
 PATTERN_KINDS = {"M": "a Mamba-2 mixer", "*": "attention", "E": "a latent mixture of experts"}
 ATTENTION_CLASSES = ("mha", "eva")
 TOPK_METHODS = ("greedy", "noaux_tc")
 
-#: a layer: its ``(norm's parameter name, part)`` in the order they are applied
-Layer = Tuple[Tuple[str, Part], ...]
+#: a layer: its ``(norm's parameter name, part)`` in the order they are applied; an entry may name a
+#: second norm, ``(norm, part, norm after)``, applied to the part's OUTPUT before the residual add
+Layer = Tuple[tuple, ...]
+
+
+def _parts(layer: Layer):
+    return [entry[1] for entry in layer]
 
 
 def _gain(g, unit_offset: bool):
@@ -302,15 +337,18 @@ class LatentMoE(Part):
 
 def _block(x, blk, positions, layer: Layer, *, axis, eps, compute_dtype, unit_offset=False):
     """One layer: for each ``(norm, part)`` of it the norm (times 1 + the
-    gain where ``unit_offset``), the part, the residual add.  ``x`` may be
+    gain where ``unit_offset``), the part, the residual add — behind a
+    second norm where the entry names one.  ``x`` may be
     wider than ``compute_dtype`` (a float32 residual stream): a part reads
     it cast and its output is added in ``x``'s own type.  Returns (x, each
     part's stats — None for a part that counts nothing)."""
     cast = lambda w: w.astype(compute_dtype)  # noqa: E731
     stats = []
-    for norm, part in layer:
+    for norm, part, *after in layer:
         u = _rms_norm(cast(x), _gain(blk[norm], unit_offset), eps)
         y, counted = part.apply(u, blk, positions, axis, cast)
+        for norm_after in after:
+            y = _rms_norm(y, _gain(blk[norm_after], unit_offset), eps)
         x = x + y.astype(x.dtype)
         stats.append(counted)
     return x, tuple(stats)
@@ -330,8 +368,9 @@ def _init(rng, *, layers: Sequence[Layer], draws_a_layer: int, vocab_size: int, 
         params["head"] = draw.normal((d, num_pred_heads * vocab_size))
     for i, layer in enumerate(layers):
         blk: Dict[str, Any] = {}
-        for norm, part in layer:
-            made = {norm: gain((d,), jnp.float32), **part.init(draw, d)}
+        for norm, part, *after in layer:
+            made = {name: gain((d,), jnp.float32) for name in (norm, *after)}
+            made.update(part.init(draw, d))
             if set(made) & set(blk):
                 raise ValueError(f"layer {i}: two parts name a parameter alike ({sorted(set(made) & set(blk))})")
             blk.update(made)
@@ -343,14 +382,17 @@ def _init(rng, *, layers: Sequence[Layer], draws_a_layer: int, vocab_size: int, 
 def _apply(
     params, batch, train: bool = False, ctx: ParallelContext = ParallelContext(),
     *, layers: Sequence[Layer], compute_dtype, remat: bool, eps: float, unit_offset: bool = False,
-    residual_dtype=None, num_pred_heads: int = 1,
+    residual_dtype=None, num_pred_heads: int = 1, embed_scale: float = 1.0,
 ):
     tokens = batch["tokens"]  # [B, L_local]: sequence-sharded over the axis
     l = tokens.shape[1]
     axis = ctx.axis_name
     offset = lax.axis_index(axis) * l if axis is not None else 0
     positions = offset + jnp.arange(l)
-    x = params["tok_emb"][tokens].astype(residual_dtype or compute_dtype)
+    x = params["tok_emb"][tokens]
+    if embed_scale != 1.0:
+        x = x * embed_scale  # in the table's float32, ahead of the one downcast
+    x = x.astype(residual_dtype or compute_dtype)
     names = sorted(params["blocks"])
     shared = dict(axis=axis, eps=eps, compute_dtype=compute_dtype, unit_offset=unit_offset)
     blocks = [functools.partial(_block, layer=layer, **shared) for layer in layers]
@@ -361,7 +403,7 @@ def _apply(
     routed, plain = [], {}  # the routing parts' stats; the other parts' counts by name: both in layer order
     for name, layer, block in zip(names, layers, blocks):
         x, stats = block(x, params["blocks"][name], positions)
-        for (_, part), given in zip(layer, stats):
+        for part, given in zip(_parts(layer), stats):
             if part.routes:
                 routed.append(given)
             else:
@@ -377,7 +419,7 @@ def _apply(
     out = {"logits": logits}
     of_shapes = collections.Counter()
     for layer in layers:
-        for _, part in layer:
+        for part in _parts(layer):
             of_shapes.update(part.shape_counts(tokens.shape[0], l))
     counters = {key: jnp.float32(n) for key, n in of_shapes.items()}
     counters.update({key: sum(values) for key, values in plain.items()})  # all layers
@@ -412,7 +454,7 @@ def _update_correction_bias(params, out, *, layers: Sequence[Layer], speed: floa
     of its layer has its bias lowered by ``speed``, one that was sent fewer
     has it raised.  Each expert layer has its own bias and its own counts."""
     blocks = dict(params["blocks"])
-    routed = [name for name, layer in zip(sorted(blocks), layers) if any(part.routes for _, part in layer)]
+    routed = [name for name, layer in zip(sorted(blocks), layers) if any(part.routes for part in _parts(layer))]
     for name, slots in zip(routed, out["router_slots"]):  # both in layer order
         step = speed * jnp.sign(jnp.mean(slots) - slots)
         blocks[name] = {**blocks[name], "router_bias": blocks[name]["router_bias"] + step}
@@ -491,7 +533,7 @@ def _example_batch(batch_size: int, seq_len: int):
 _NEVER_DECAYED = ("router_bias",)
 _NOT_MATRICES = _NEVER_DECAYED + (
     "attn_norm", "ffn_norm", "norm_f", "kv_norm", "q_norm", "k_norm", "eva_phi", "eva_mu",
-    "norm", "ssm_norm", "A_log", "D", "dt_bias", "conv_b", "kda_norm",
+    "norm", "ssm_norm", "A_log", "D", "dt_bias", "conv_b", "kda_norm", "post_attn_norm", "post_ffn_norm",
 )
 
 
@@ -709,10 +751,60 @@ def _kimi_linear_layers(latent_attention, feed_forwards, *, linear_attn_config, 
     return tuple((("attn_norm", mixer), ("ffn_norm", feed_forward)) for mixer, feed_forward in zip(mixers, feed_forwards)), 24
 
 
-def _family(*, hybrid_override_pattern, attention_class, linear_attn_config, kv_lora_rank) -> str:
+def _afmoe_router(
+    *, num_experts, num_experts_per_tok, experts_held, first_expert_held, score_func, route_norm, route_scale, n_group, topk_group,
+):
+    """``afmoe``'s spelling of the router's keys, mapped onto :func:`_router`'s: always a
+    correction bias (``expert_bias``: it chooses, it never weighs)."""
+    return _router(
+        num_experts=num_experts, num_experts_per_tok=num_experts_per_tok, experts_held=experts_held,
+        first_expert_held=first_expert_held, scoring_func=score_func, norm_topk_prob=route_norm,
+        routed_scaling_factor=route_scale, topk_method="noaux_tc", n_group=n_group, topk_group=topk_group,
+    )
+
+
+def _afmoe_layers(
+    router, correction_bias,
+    *, layer_types, num_hidden_layers, num_dense_layers, hidden_size, num_attention_heads, num_key_value_heads, head_dim,
+    sliding_window, rope_theta, rms_norm_eps, intermediate_size, moe_intermediate_size, num_shared_experts,
+    load_balance_coeff, bias_update_speed,
+):
+    """``afmoe``'s layers: ``layer_types`` names each layer's ATTENTION here (module docstring: the one
+    collision of spellings), the first ``num_dense_layers`` feed-forwards are dense; every part between
+    two norms.  Its third return renames the family's own key onto the one the spec reads."""
+    kinds = tuple(layer_types if layer_types is not None else ("sliding_attention",) * num_hidden_layers)
+    if len(kinds) != num_hidden_layers or set(kinds) - set(ATTENTION_LAYER_TYPES):
+        raise ValueError(
+            f"under sliding_window, layer_types must name the ATTENTION of {num_hidden_layers} layers from "
+            f"{ATTENTION_LAYER_TYPES}, got {kinds!r}"
+        )
+    kv_heads = num_key_value_heads or num_attention_heads
+    if num_attention_heads % kv_heads or head_dim <= 0 or head_dim % 2:
+        raise ValueError(f"{num_attention_heads} query heads over {kv_heads} key/value heads of head_dim {head_dim} (even: rotary pairs)")
+    if bias_update_speed != _DEFAULTS["bias_update_speed"]:
+        raise ValueError("bias_update_speed: this family's key for the correction bias's speed is load_balance_coeff")
+    attentions = {
+        kind: GatedWindowAttention(
+            num_attention_heads, kv_heads, head_dim, sliding_window if kind == "sliding_attention" else 0,
+            float(rope_theta), float(rms_norm_eps),
+        )
+        for kind in ATTENTION_LAYER_TYPES
+    }
+    feed_forwards, draws = _gated_feed_forwards(
+        router, correction_bias, num_hidden_layers=num_hidden_layers, layer_types=None, first_k_dense_replace=num_dense_layers,
+        intermediate_size=intermediate_size, moe_intermediate_size=moe_intermediate_size, n_shared_experts=num_shared_experts,
+    )
+    layers = tuple(
+        (("attn_norm", attentions[kind], "post_attn_norm"), ("ffn_norm", feed_forward, "post_ffn_norm"))
+        for kind, feed_forward in zip(kinds, feed_forwards)
+    )
+    return layers, draws, {"bias_update_speed": float(load_balance_coeff)}  # 12 keys a layer: five projections, the experts' seven
+
+
+def _family(*, hybrid_override_pattern, attention_class, linear_attn_config, kv_lora_rank, sliding_window=0) -> str:
     """Which family's builders read the keys.  ``hybrid_override_pattern``,
-    ``attention_class`` ``'eva'``, ``linear_attn_config`` and ``kv_lora_rank``
-    each name one, and one model is of one — with ONE rule for a pair:
+    ``attention_class`` ``'eva'``, ``linear_attn_config``, ``kv_lora_rank`` and
+    ``sliding_window`` each name one, and one model is of one — with ONE rule for a pair:
     ``linear_attn_config`` decides over ``kv_lora_rank`` (``kimi_linear``'s
     full-attention layers ARE latent attention: the rank is one of its own
     keys).  Any other two together are refused."""
@@ -723,19 +815,21 @@ def _family(*, hybrid_override_pattern, attention_class, linear_attn_config, kv_
         for family, said in (
             ("nemotron_h", hybrid_override_pattern is not None), ("evabyte", attention_class == "eva"),
             ("kimi_linear", linear_attn_config is not None), ("deepseek_v3", bool(kv_lora_rank) and linear_attn_config is None),
+            ("afmoe", sliding_window > 0),
         )
         if said
     ]
     if len(named) > 1:
         raise ValueError(
-            "hybrid_override_pattern, attention_class 'eva', linear_attn_config and kv_lora_rank each name a family and "
+            "hybrid_override_pattern, attention_class 'eva', linear_attn_config, kv_lora_rank and sliding_window each name a family and "
             f"one model is of one (linear_attn_config alone decides over kv_lora_rank): got those of {named}"
         )
     return named[0] if named else "olmoe"
 
 
-#: family -> (own) -> (the layers, the keys of the init's stream a layer takes).  ``own(builder)``
-#: calls a builder with the published keys it names.  A new architecture is a part and a line here.
+#: family -> (own) -> (the layers, the keys of the init's stream a layer takes[, the family's own spelling
+#: of keys the spec reads, renamed]).  ``own(builder)`` calls a builder with the published keys it names.
+#: A new architecture is a part and a line here.
 FAMILIES = {
     "olmoe": lambda own: _two_part_layers(own(_qk_norm_attention), own),
     "deepseek_v3": lambda own: _two_part_layers(own(_latent_attention), own, draws=12),  # 12 with or without shared experts
@@ -743,6 +837,7 @@ FAMILIES = {
     "nemotron_h": lambda own: own(functools.partial(_nemotron_h_layers, *own(_router))),
     "kimi_linear": lambda own: own(functools.partial(
         _kimi_linear_layers, own(_latent_attention), own(functools.partial(_kimi_linear_feed_forwards, *own(_kimi_linear_router))))),
+    "afmoe": lambda own: own(functools.partial(_afmoe_layers, *own(_afmoe_router))),
 }
 
 
@@ -750,7 +845,7 @@ def _spec_of_layers(
     layers: Sequence[Layer], draws_a_layer: int,
     *, learning_rate, compute_dtype, vocab_size, hidden_size, rms_norm_eps, seq_len, tie_word_embeddings,
     router_aux_loss_coef, router_z_loss_coef, weight_decay, lr_warmup_steps, remat, bias_update_speed,
-    norm_add_unit_offset, fp32_skip_add, num_pred_heads, init_std, decay_matrices_only,
+    norm_add_unit_offset, fp32_skip_add, num_pred_heads, init_std, decay_matrices_only, mup_enabled,
 ) -> ModelSpec:
     """The model of ``layers`` (what :func:`model_spec` ends in): the init,
     the block, the step counters and the correction bias's rule all follow
@@ -758,12 +853,12 @@ def _spec_of_layers(
     if num_pred_heads < 1 or (num_pred_heads > 1 and tie_word_embeddings):
         raise ValueError(f"num_pred_heads {num_pred_heads}: at least one, and more than one only with an untied head")
     layers = tuple(layers)
-    parts = [part for layer in layers for _, part in layer]
+    parts = [part for layer in layers for part in _parts(layer)]
     correction_bias = any(part.correction_bias for part in parts)
     apply = functools.partial(
         _apply, layers=layers, compute_dtype=jnp.dtype(compute_dtype), remat=remat, eps=float(rms_norm_eps),
         unit_offset=bool(norm_add_unit_offset), residual_dtype=jnp.float32 if fp32_skip_add else None,
-        num_pred_heads=num_pred_heads,
+        num_pred_heads=num_pred_heads, embed_scale=float(hidden_size) ** 0.5 if mup_enabled else 1.0,
     )
     skip = _NOT_MATRICES if decay_matrices_only else _NEVER_DECAYED
     coefs = dict(lb_coef=router_aux_loss_coef, z_coef=router_z_loss_coef)
@@ -875,6 +970,14 @@ def model_spec(
     use_grouped_topk: bool = False,
     num_expert_group: int = 1,
     moe_layer_freq: int = 1,
+    # afmoe's keys (defaults: OLMoE's block)
+    sliding_window: int = 0,
+    num_dense_layers: int = 0,
+    score_func: str = "softmax",
+    route_norm: bool = False,
+    route_scale: float = 1.0,
+    load_balance_coeff: float = 0.001,
+    mup_enabled: bool = False,
 ) -> ModelSpec:
     """``layer_types`` names each layer's feed-forward, ``"moe"`` or
     ``"dense"`` (both gated; dense layers ``intermediate_size`` wide, experts
@@ -916,9 +1019,18 @@ def model_spec(
     spelling (``num_experts_per_token``, ``num_shared_experts``,
     ``moe_router_activation_func``, ``moe_renormalize``, ``use_grouped_topk`` /
     ``num_expert_group``, ``moe_layer_freq`` 1).
+    ``sliding_window`` > 0 (``afmoe``): ``layer_types`` names each layer's ATTENTION,
+    ``"sliding_attention"`` (the ``sliding_window`` keys up to the query's own, rotary) or
+    ``"full_attention"`` (every earlier key, NO position signal) — not its feed-forward, which
+    follows from ``num_dense_layers`` (the leading ones dense, ``intermediate_size`` wide; the rest
+    ``num_experts`` experts ``moe_intermediate_size`` wide with ``num_shared_experts`` shared);
+    ``num_attention_heads`` query heads over ``num_key_value_heads`` key/value heads of ``head_dim``,
+    a norm a head on q and k, a sigmoid gate on the output, a norm before AND after every part; the
+    router ``score_func`` / ``route_norm`` / ``route_scale`` with a correction bias moved by
+    ``load_balance_coeff``.  ``mup_enabled``: the embedding's output times ``hidden_size``^0.5.
 
     Which FAMILY the model is of follows from ``hybrid_override_pattern``,
-    ``attention_class``, ``linear_attn_config`` and ``kv_lora_rank`` (:func:`_family`); the family's
+    ``attention_class``, ``linear_attn_config``, ``kv_lora_rank`` and ``sliding_window`` (:func:`_family`); the family's
     builders take their own keys and check their ranges, and a key that no
     builder of the chosen family reads, set to other than its default, is
     refused: it would change nothing."""
@@ -931,14 +1043,15 @@ def model_spec(
         return builder(**{name: keys[name] for name in names})
 
     family = own(_family)
-    layers, draws_a_layer = FAMILIES[family](own)
+    layers, draws_a_layer, *renamed = FAMILIES[family](own)
+    keys.update(*renamed)
     spec = own(functools.partial(_spec_of_layers, layers, draws_a_layer))
     foreign = sorted(key for key, value in keys.items() if key not in read and value != _DEFAULTS[key])
     if foreign:
         raise ValueError(
             f"{', '.join(foreign)}: set, but no part of the {family!r} family reads "
             f"{'it' if len(foreign) == 1 else 'them'} (the family follows from hybrid_override_pattern / attention_class / "
-            f"linear_attn_config / kv_lora_rank)"
+            f"linear_attn_config / kv_lora_rank / sliding_window)"
         )
     return spec
 

@@ -79,18 +79,23 @@ def _scores(q, k, q_rot, k_rot):
 def attention_reference(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
     q_rot: Optional[jax.Array] = None, k_rot: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Plain full attention ([B, L, H, D] layout) — the numerics oracle."""
+    """Plain full attention ([B, L, H, D] layout) — the numerics oracle.
+    Under a ``window`` (causal) position p sees the keys ``p - window < j
+    <= p``."""
     scores = _scores(q, k, q_rot, k_rot)
     if causal:
         lq, lk = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((lq, lk), bool), lk - lq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((lq, lk), bool), lk - lq - window)
         scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _local_attention(q, k, v, causal: bool, q_rot=None, k_rot=None) -> jax.Array:
+def _local_attention(q, k, v, causal: bool, q_rot=None, k_rot=None, window=None) -> jax.Array:
     """Exact single-shard attention: the Pallas flash kernel on TPU (no
     O(L^2) HBM tensors — at the MFU-bench shape the XLA path's saved
     probability tensors alone are ~19 GB at b=32, the difference between
@@ -106,14 +111,16 @@ def _local_attention(q, k, v, causal: bool, q_rot=None, k_rot=None) -> jax.Array
     if backend != "tpu":
         why_not = f"backend={backend}"
     else:
-        why_not = outside_contract(q, k, v, q_rot, k_rot)
+        why_not = outside_contract(q, k, v, q_rot, k_rot, window)
         if not why_not:
-            return flash_attention(q, k, v, causal, q_rot, k_rot)
+            return flash_attention(q, k, v, causal, q_rot, k_rot, window)
         why_not = f"outside the flash kernels' contract: {why_not}"
     if q_rot is not None:
         why_not += f" rotary={q_rot.shape[-1]}"
+    if window is not None:
+        why_not += f" window={window}"
     announce_path(PATH_XLA_REFERENCE, q, causal, why_not)
-    return attention_reference(q, k, v, causal, q_rot, k_rot)
+    return attention_reference(q, k, v, causal, q_rot, k_rot, window)
 
 
 def ring_attention(
@@ -124,6 +131,7 @@ def ring_attention(
     causal: bool = False,
     q_rot: Optional[jax.Array] = None,
     k_rot: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Blockwise attention with K/V ring rotation over ``axis_name``.
 
@@ -133,15 +141,22 @@ def ring_attention(
     it degrades to exact single-device attention.  ``q_rot`` [B, L_local,
     H, R] and ``k_rot`` [B, L_local, R] add a second product to the score
     (:func:`_scores`); the shared key rides the ring with K and V.
+    ``window`` (``causal`` only): position p sees the keys ``p - window < j
+    <= p``; over a sequence that IS sharded it raises (the ring is not
+    taught which blocks a window lets it skip).
     """
+    if window is not None and not causal:
+        raise ValueError("a window is a causal call's")
     if axis_name is None:
-        return _local_attention(q, k, v, causal, q_rot, k_rot)
+        return _local_attention(q, k, v, causal, q_rot, k_rot, window)
 
     n = axis_size(axis_name)
     if n == 1:
         # Degenerate ring (1-device mesh under shard_map): exact local
         # attention, flash-kernelled on TPU.
-        return _local_attention(q, k, v, causal, q_rot, k_rot)
+        return _local_attention(q, k, v, causal, q_rot, k_rot, window)
+    if window is not None:
+        raise ValueError("attention under a window over a sharded sequence is not supported: the ring visits every block")
     announce_path(PATH_XLA_RING, q, causal, f"n={n}")
     my = lax.axis_index(axis_name)
     b, lq, h, d = q.shape

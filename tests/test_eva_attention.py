@@ -113,6 +113,32 @@ def test_with_lse_the_op_hands_out_the_same_o_and_each_querys_logsumexp(path, ca
     assert float(jnp.max(jnp.abs(lse - want_lse))) <= 2e-5 and float(jnp.max(jnp.abs(o - want_o))) <= 5e-6 * float(jnp.max(jnp.abs(want_o)))
 
 
+@functools.lru_cache(maxsize=None)
+def _with_sub_tiles_of(t: int):
+    """``results("kernels", "two_windows")`` with the kernels' sub-tiles cut to
+    ``t`` rows, so that a window is SEVERAL (on the chip a window of 2048 is
+    four of 512; at this file's window of 128 it is one, and which side of a
+    sub-tile its window's other positions lie on is never asked)."""
+    real = eva_ops._T_FWD, eva_ops._T_BWD
+    eva_ops._T_FWD = eva_ops._T_BWD = t
+    try:
+        args, g = operands(LENGTHS["two_windows"])
+        with jax.default_matmul_precision("highest"):
+            return dict(zip(LEAVES, forward_and_gradients(by_kernels, args, g)))
+    finally:
+        eva_ops._T_FWD, eva_ops._T_BWD = real
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_a_window_of_several_sub_tiles_is_the_reference_too(leaf):
+    """PR 56's near miss: ``flash_attention._visible`` read the kernels' plain
+    ``True`` for the diagonal pair as a window's far edge, so a sub-tile was
+    paired with the window's positions AFTER it and not before: every case
+    here passed (one sub-tile a window) and the chip's check read 1.3."""
+    got, want = _with_sub_tiles_of(32)[leaf], results("reference", "two_windows")[leaf]
+    assert float(jnp.max(jnp.abs(got - want))) <= 5e-6 * float(jnp.max(jnp.abs(want))), leaf
+
+
 def test_one_window_is_flash_attention_causal_to_the_bit():
     """A sequence of one window that is one sub-tile: the same pieces in
     the same order as the flash kernel's one block, so the same bits.  (A

@@ -345,3 +345,178 @@ def test_attention_path_line_names_the_rotary_width(monkeypatch):
     flash_attention(*_rotary_inputs(jnp.bfloat16, l=128)[:3], True, *_rotary_inputs(jnp.bfloat16, l=128)[3:])
     (line,) = lines
     assert "attention path: pallas-interpret" in line and line.endswith("heads_per_block=1 rotary=64)")
+
+
+# -- a window that moves with the query (PR 56) -------------------------------
+# Position p sees the keys p - W < j <= p.  The kernels skip every (block,
+# block) pair wholly outside the window; the pair a whole window back is the
+# far edge, masked by the complement of the diagonal's mask.
+
+
+def _window_case(monkeypatch, block, t_fwd, t_bwd):
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_BLOCK", block)
+    monkeypatch.setattr(fa, "_T_FWD", t_fwd)
+    monkeypatch.setattr(fa, "_T_BWD", t_bwd)
+    return fa
+
+
+@pytest.mark.parametrize(
+    "block,l,window,h,d",
+    [(128, 512, 128, 2, 64), (128, 640, 384, 1, 128)],
+    ids=["one_block_back_two_heads_a_group", "three_of_five_blocks"],
+)
+def test_a_window_over_several_blocks_is_the_masked_softmax_and_one_key_either_way_is_not(monkeypatch, block, l, window, h, d):
+    """Forward and VJP under a window SHORTER than the sequence and several
+    blocks long, against the explicit mask: the first visited pair is the far
+    edge (whose last row sees nothing: no NaN), the skipped pairs fetch
+    nothing, the state is carried across the visited ones; sub-tiles smaller
+    than a block so that a far-edge pair has both an unmasked and a masked
+    piece.  A window of W - 1 or W + 1 keys is a different answer."""
+    fa = _window_case(monkeypatch, block, block // 2, block // 4)
+    q, k, v = _qkv(jnp.float32, b=1, l=l, h=h, d=d, seed=window)
+    cot = jax.random.normal(jax.random.key(2), q.shape, jnp.float32)
+    flash = lambda q, k, v: fa.flash_attention(q, k, v, True, window=window)  # noqa: E731
+    ref = lambda w: lambda q, k, v: attention_reference(q, k, v, causal=True, window=w)  # noqa: E731
+    out = flash(q, k, v)
+    np.testing.assert_allclose(out, ref(window)(q, k, v), atol=2e-5, rtol=2e-5)
+    for other in (window - 1, window + 1):
+        assert float(jnp.max(jnp.abs(out - ref(other)(q, k, v)))) > 1e-3
+    loss = lambda attn: lambda q, k, v: jnp.vdot(attn(q, k, v), cot)  # noqa: E731
+    g_flash = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss(ref(window)), argnums=(0, 1, 2)))(q, k, v)
+    g_more = jax.jit(jax.grad(loss(ref(window + 1)), argnums=(0, 1, 2)))(q, k, v)
+    for gf, gr, gm, name in zip(g_flash, g_ref, g_more, "qkv"):
+        np.testing.assert_allclose(gf, gr, atol=5e-5, rtol=5e-5, err_msg=f"d{name}")
+        assert float(jnp.max(jnp.abs(gf - gm))) > 1e-3, name
+
+
+def test_a_window_visits_no_pair_wholly_outside_it(monkeypatch):
+    """The plan at the cell's shape: L = 8192 in 8 blocks of 1024 under a
+    window of two blocks visits 21 (block, block) pairs of 64 a pass, the
+    causal rule alone 36; by sub-tiles the forward multiplies 17.5 blocks'
+    worth of pairs and each backward kernel 15.75, where the window holds
+    14.0 and full causal attention multiplies 24 / 22."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    windowed, causal = fa._Plan((1, 8192, 32, 128), True, 0, 2048), fa._Plan((1, 8192, 32, 128), True)
+    assert (windowed.n, windowed.rows, windowed.far, causal.far) == (8, 1024, 2, 0)
+    visited = lambda plan: sum(  # noqa: E731
+        1 for i in range(8) for j in range(8) if j <= i and (not plan.far or i - j <= plan.far))
+    assert visited(windowed) == 21 and visited(causal) == 36
+    blocks = lambda plan, *a: plan.pairs_computed(*a) / 1024**2  # noqa: E731
+    assert (blocks(windowed, fa._T_FWD), blocks(windowed, fa._T_BWD), blocks(windowed, fa._T_BWD, False)) == (17.5, 15.75, 15.75)
+    assert (blocks(causal, fa._T_FWD), blocks(causal, fa._T_BWD)) == (34.0, 33.0)
+    assert fa.window_pairs_computed(8192, 2048) == (17.5 + 2 * 15.75) / 3 * 1024**2
+    needed = 2048 * 2049 // 2 + 6144 * 2048
+    assert needed / 1024**2 == pytest.approx(14.0, abs=1e-3) and needed <= 15.75 * 1024**2
+    assert fa.key_tiles(16, 16, True, 4) == (70, 256) and fa.key_tiles(16, 16, True) == (136, 256)
+    # outside the contract (not whole blocks of the plan) the XLA path multiplies every pair
+    assert fa.window_pairs_computed(8192, 1000) == 8192 * 8192
+
+
+def test_a_window_outside_the_contract_takes_the_reference_path_and_one_that_hides_nothing_is_dropped(monkeypatch):
+    from elasticdl_tpu.ops import flash_attention as fa
+    from elasticdl_tpu.ops import ring_attention
+
+    lines = []
+    monkeypatch.setattr(ring_attention, "_log_once", lines.append)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_use_interpret", lambda: True)
+    q, k, v = _qkv(jnp.float32, b=1, l=256, h=1, d=128)
+    out = ring_attention._local_attention(q, k, v, True, window=100)  # ONE block of 256: a window of 100 is not whole blocks
+    assert "attention path: xla-reference" in lines[-1] and "a window of 100 is not whole blocks of 256 rows" in lines[-1]
+    assert lines[-1].endswith("window=100)")
+    np.testing.assert_array_equal(out, attention_reference(q, k, v, causal=True, window=100))
+    with pytest.raises(ValueError, match="a window of 100 is not whole blocks"):
+        flash_attention(q, k, v, True, window=100)
+    with pytest.raises(ValueError, match="a window is a causal call's"):
+        flash_attention(q, k, v, False, window=128)
+    # at least the sequence long: the full call, announced as one
+    same = ring_attention._local_attention(q, k, v, True, window=256)
+    assert "window" not in lines[-1] and "pallas" in lines[-1]
+    np.testing.assert_array_equal(same, flash_attention(q, k, v, True))
+
+
+def test_attention_path_line_names_the_window(monkeypatch):
+    from elasticdl_tpu.ops import ring_attention
+
+    fa = _window_case(monkeypatch, 128, 64, 32)
+    lines = []
+    monkeypatch.setattr(ring_attention, "_log_once", lines.append)
+    q, k, v = _qkv(jnp.bfloat16, b=1, l=512, h=1, d=128)
+    fa.flash_attention(q, k, v, True, window=256)
+    (line,) = lines
+    assert "attention path: pallas-interpret" in line and line.endswith("heads_per_block=1 window=256)")
+    assert "key_tiles=30/64 fwd, 108/256 bwd" in line
+
+
+#: sha256 of the jaxpr (forward AND gradient, the kernels' bodies in it) of the calls the older cells make — causal or not,
+#: one block or several, two heads a lane group, a rotary part — and of EVA's at ``evabyte_job``'s shape, whose kernels take
+#: ``_visible`` / ``_sub_tiles`` / ``_causal_mask`` from this module.  PINNED in PR 56 at the values its PARENT (3fa6822) gives:
+#: the window was threaded through ``_visit``, ``_visible`` and ``_Plan`` and a call without one traces to the byte as it did.
+#: The lowered steps ``tests/test_chip_lowering.py`` pins hold no kernel (lowered from the CPU the attention is the XLA
+#: reference) and every EVA case here has ONE sub-tile a window: a ``_visible`` that paired EVA's sub-tiles with the positions
+#: AFTER them passed all of tier-1 and read 1.3 on the chip's check.  A PR that changes a kernel's body on purpose re-pins.
+KERNEL_JAXPR_SHA256 = {
+    "flash causal [1, 8192, 16, 128]": "138bae355d569a0e",
+    "flash causal [2, 1024, 16, 64]": "8e96fe343a8e22ea",
+    "flash causal [1, 4096, 16, 128]": "523c9166f64bb995",
+    "flash full [1, 1024, 4, 64]": "f8a1fcd73c9682e4",
+    "flash causal [1, 8192, 32, 128] rotary 64": "d0886d638be895a1",
+    "flash causal [1, 2048, 8, 128]": "d14fbf11fa3e7420",
+    "flash causal [1, 384, 2, 64]": "c327d37e50e8a066",
+    "eva [1, 16384, 16, 128] window 2048 chunk 16": "41c3b7b5b2b5960b",
+}
+
+_KERNEL_JAXPRS = """
+import hashlib, json, re
+import jax, jax.numpy as jnp
+from elasticdl_tpu.ops import eva_attention as eva_ops, flash_attention as fa
+bf, f32 = jnp.bfloat16, jnp.float32
+def sha(fn, *shapes):
+    text = str(jax.make_jaxpr(fn)(*(jax.ShapeDtypeStruct(s, dt) for s, dt in shapes)))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+def flash(causal, rot):
+    def loss(q, k, v, *r):
+        return jnp.sum(fa.flash_attention(q, k, v, causal, **(dict(q_rot=r[0], k_rot=r[1]) if rot else {})).astype(f32) ** 2)
+    return jax.grad(loss, argnums=tuple(range(5 if rot else 3)))
+out = {}
+for name in NAMES:
+    kind, how, shape, *rot = re.match(r"(\\w+) (\\w+ )?(\\[[^\\]]*\\])(?: rotary (\\d+))?", name).groups()
+    shape, rot = tuple(json.loads(shape)), int(rot[0] or 0)
+    if kind == "flash":
+        b, l, h, _ = shape
+        rots = [((b, l, h, rot), bf), ((b, l, rot), bf)] if rot else []
+        out[name] = sha(flash(how.strip() == "causal", rot), *[(shape, bf)] * 3, *rots)
+    else:
+        eva_ops._why_not_kernels = lambda *a: ""
+        loss = lambda *a: jnp.sum(eva_ops.eva_attention(*a, window=2048, chunk=16).astype(f32) ** 2)
+        out[name] = sha(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *[(shape, bf)] * 3, *[(shape[2:], f32)] * 2)
+print("SHAS", json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def kernel_jaxprs():
+    """Traced in ONE fresh process (nothing runs: abstract operands), so no jit an earlier case cached names anything."""
+    import json
+    import os
+    import re
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = f"NAMES = {sorted(KERNEL_JAXPR_SHA256)!r}\n" + _KERNEL_JAXPRS
+    done = subprocess.run(
+        [sys.executable, "-c", script], cwd=root, capture_output=True, text=True, timeout=300, env=dict(os.environ, PYTHONPATH=root),
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    (shas,) = re.findall(r"^SHAS (.*)$", done.stdout, re.M)
+    return json.loads(shas)
+
+
+@pytest.mark.parametrize("call", sorted(KERNEL_JAXPR_SHA256))
+def test_the_older_cells_calls_trace_to_the_pinned_kernels(kernel_jaxprs, call):
+    assert kernel_jaxprs[call] == KERNEL_JAXPR_SHA256[call]

@@ -377,7 +377,60 @@ def test_no_row_buffer_of_all_the_slots_in_the_held_path(held):
         assert "cond" in text and tokens * k not in rows
 
 
-def test_the_eight_shares_add_up_to_the_uncut_layer(reference):
+def _afmoe_shares_add_up_to_the_uncut_layer():
+    """The same for a layer whose parts sit between TWO norms (``afmoe``,
+    through that family's builder): the norm after the feed-forward is not
+    linear, so what the chips' shares add up to is the PART's output — the
+    routed parts of the eight, the shared expert counted once — and the
+    layer is the residual add of its norm; against the family's plain
+    reference with all 16 held."""
+    keys = dict(
+        vocab_size=96, hidden_size=32, num_hidden_layers=1, num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        layer_types=["sliding_attention"], sliding_window=32, num_dense_layers=0, intermediate_size=48, moe_intermediate_size=24,
+        num_shared_experts=1, num_experts=16, num_experts_per_tok=3, score_func="sigmoid", route_norm=True, route_scale=2.826,
+        mup_enabled=True, rms_norm_eps=1e-5, rope_theta=10000, seq_len=128, router_aux_loss_coef=0.0, router_z_loss_coef=0.0,
+    )
+    spec_of = lambda **kw: load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype="float32", **{**keys, **kw})  # noqa: E731
+    layer_of = lambda spec: spec.init.keywords["layers"][0]  # noqa: E731
+    reference = resolve.load_module(os.path.join(BENCH_DIR, "configs", "trinity_mini_26b_a3b_ep8_l5_reference.py"))
+    params = _weights(spec_of())
+    blk = params["blocks"]["b00"]
+    assert blk["w_up"].shape == (16, 32, 24)
+    x = jax.random.normal(jax.random.key(3), (2, 128, 32), jnp.float32)
+    positions, cast = jnp.arange(128), lambda w: w  # noqa: E731
+    common = dict(axis=None, eps=1e-5, compute_dtype=jnp.float32)
+    (attention_entry, (_, _, post_ffn_norm)) = layer_of(spec_of())
+
+    @jax.jit  # ONE program for what every chip computes alike ...
+    def alike_parts(x, blk):
+        want, want_slots = reference.build({**keys, "experts_held": 16}).layer(x, blk, "sliding_attention")
+        whole, _ = moe_lm._block(x, blk, positions, layer_of(spec_of()), **common)
+        # the stream the feed-forward reads, and the shared expert on it
+        h, _ = moe_lm._block(x, blk, positions, (attention_entry,), **common)
+        u = moe_lm._rms_norm(h, blk["ffn_norm"], 1e-5)
+        return want, want_slots, whole, h, u, moe_lm._gated_mlp(u, blk["ws_gate"], blk["ws_up"], blk["ws_down"])
+
+    def share(lo):  # ... and one a share: its part, through the family's own builder
+        experts = layer_of(spec_of(experts_held=2, first_expert_held=lo))[1][1]
+        assert experts.router == moe_lm.Router(16, 3, 2, lo, experts.router.keys) and experts.shared_width == 24
+        return jax.jit(lambda u, cut: experts.apply(u, cut, positions, None, cast))
+
+    with jax.default_matmul_precision("highest"):
+        want, want_slots, whole, h, u, alike = alike_parts(x, blk)
+        np.testing.assert_allclose(whole, want, atol=2e-5 * float(jnp.max(jnp.abs(want))))  # the uncut layer IS the reference's
+        parts = []
+        for lo in range(0, 16, 2):
+            cut = {**blk, **{name: blk[name][lo:lo + 2] for name in ("w_gate", "w_up", "w_down")}}
+            got, stats = share(lo)(u, cut)
+            np.testing.assert_array_equal(np.asarray(stats["slots"]), np.asarray(want_slots))
+            parts.append(got - alike)
+        assert sum(float(jnp.abs(p).max()) > 0 for p in parts) >= 6  # (the seeded bias keeps a pair of experts from every token)
+        layer = h + moe_lm._rms_norm(alike + sum(parts), blk[post_ffn_norm], 1e-5)  # the shared expert counted ONCE
+    assert _rel(layer, want) <= 2e-5
+
+
+@pytest.mark.parametrize("family", ["deepseek_v3", "afmoe"])
+def test_the_eight_shares_add_up_to_the_uncut_layer(reference, family):
     """The share tied to the model.  ONE expert layer, 16 experts, at 8
     chips of 2: every chip runs the model's block with its own range held,
     on the same weights and tokens.  What differs between the chips' block
@@ -386,6 +439,8 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(reference):
     routed parts — their output less what every chip computes alike, i.e.
     less a run with NO slot held — is the uncut layer, which the plain
     reference gives with all 16 held."""
+    if family == "afmoe":
+        return _afmoe_shares_add_up_to_the_uncut_layer()
     shares, per = 8, 2
     whole = _keys("expert_layer", experts_held=16, first_expert_held=0)
     full = load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype="float32", **whole)

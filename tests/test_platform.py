@@ -143,6 +143,18 @@ def test_a_cache_directory_the_rule_did_not_make_is_not_adopted(tmp_path):
     assert not out["there_at_the_end"] and not os.path.exists(used) and os.listdir(own) == []
 
 
+def test_the_runs_longest_serial_block_is_collected_first_with_nothing_that_weighs_behind_it(request):
+    """``tests/conftest.py``'s order (``_COLLECTED_FIRST``): wherever the lowering file is collected with other
+    files it opens the collection, whole and in its own order, and the 24th of the collection that xdist hands
+    the first worker to begin with holds only it and cases of the file named second."""
+    names = [item.path.name for item in request.session.items]
+    if "test_chip_lowering.py" not in names or len(set(names)) < 24:
+        pytest.skip("not a whole run: nothing to order")
+    n_first = names.count("test_chip_lowering.py")
+    assert set(names[:n_first]) == {"test_chip_lowering.py"}
+    assert set(names[n_first:len(names) // 24]) == {"test_renamed_metrics.py"}
+
+
 def test_device_summary_names_what_answered():
     import jax
 
