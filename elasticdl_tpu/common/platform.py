@@ -123,6 +123,13 @@ def enable_compile_cache() -> None:
     (program, topology) pair the cache may already hold; a hit loads the
     executable from disk instead of paying the XLA compile again.
 
+    Which cache serves what: this one is keyed by the LOWERED module, so a
+    hit still pays the trace and the lowering that make the key.  It serves
+    every program a process traces (init_state, eval, predict, serving, a
+    first launch's train step).  A relaunched worker's TRAIN STEP is served
+    by the program store instead (common/program_store.py, a sibling
+    directory ``<this cache's directory>_programs``), with nothing traced.
+
     Placement rule: when ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
     itself and this function sets NO directory, so whoever launches the
     process decides where entries land; unset, every process of the

@@ -161,6 +161,11 @@ class ModelSpec:
     rematerialises: bool = False
     # The Adam record ``optimizer`` was declared as, if it was.
     adam: Optional[Adam] = dataclasses.field(default=None, init=False)
+    # ``(model_zoo, model_def, params)`` where :func:`load_model_spec` made
+    # this spec: the parameters a key of the worker's program store digests
+    # (common/program_store.py).  None for a spec built any other way, a
+    # ``dataclasses.replace`` of a loaded one included: its step is traced.
+    loaded_with: Optional[Tuple[str, str, Mapping[str, Any]]] = dataclasses.field(default=None, init=False)
 
     def __post_init__(self):
         if isinstance(self.optimizer, Adam):
@@ -184,6 +189,7 @@ def load_model_spec(model_zoo: str, model_def: str, **params: Any) -> ModelSpec:
     spec = fn(**params)
     if not isinstance(spec, ModelSpec):
         raise TypeError(f"{model_def} returned {type(spec)}, expected ModelSpec")
+    spec.loaded_with = (model_zoo, model_def, dict(params))
     return spec
 
 

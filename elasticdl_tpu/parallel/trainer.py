@@ -41,11 +41,11 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from elasticdl_tpu.common import trace
+from elasticdl_tpu.common import log_utils, program_store, trace
 from elasticdl_tpu.common.config import DistributionStrategy, JobConfig
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.metrics import HIST_PREFIX
-from elasticdl_tpu.common.platform import device_bytes_limit
+from elasticdl_tpu.common.platform import compile_phase_seconds, device_bytes_limit
 from elasticdl_tpu.models.spec import EmbeddingTableSpec, ModelSpec
 from elasticdl_tpu.parallel import collectives as coll
 logger = get_logger("trainer")
@@ -469,6 +469,81 @@ def _resolve_keep_budget(spec: ModelSpec, ctx: ParallelContext, plan: KeepPlan, 
     return dataclasses.replace(ctx, remat_keep_bytes=plan.budget), held.traces
 
 
+#: The ``JobConfig`` fields NO traced code reads, each with why: every other
+#: field is in a program store key (``Trainer._program_key``; a field added
+#: to ``JobConfig`` is in until it is named here,
+#: ``tests/test_program_store.py``).  Observability is out so that a traced
+#: (profiled) launch and a plain one share one entry; an address, a port or a
+#: path of this launch is out so that a RELAUNCH finds its program.
+CONFIG_FIELDS_NO_PROGRAM_READS = (
+    ("job_name", "a label of the job's pods and logs"),
+    ("training_data", "where records come from: what the program sees of them is the batch, whose shapes are in the key"),
+    ("validation_data", "as training_data"),
+    ("prediction_data", "as training_data"),
+    ("prediction_outputs", "where predict-mode outputs are written, on the host"),
+    ("checkpoint_dir", "where the host writes the state"),
+    ("master_addr", "this launch's address of the master"),
+    ("master_port", "the master's own bind"),
+    ("master_advertise_host", "the master's own address"),
+    ("coordinator_port", "the jax.distributed world's port: the world's shape is in the key by devices and processes"),
+    ("log_level", "observability"),
+    ("trace", "observability: the span ring records on the host"),
+    ("trace_buffer_events", "observability: the ring's size"),
+    ("gauge_port", "observability: the /metrics endpoint"),
+    ("profile_dir", "observability: the profiler's window wraps dispatches, it changes none"),
+    ("profile_tasks", "observability: the window's length"),
+    ("profile_inline", "observability: which thread stops the profiler"),
+    ("metrics_dir", "observability: the master's metrics.jsonl"),
+    ("pod_log_dir", "observability: the pods' logs"),
+)
+_NOT_IN_A_KEY = frozenset(name for name, _ in CONFIG_FIELDS_NO_PROGRAM_READS)
+
+
+def _plain(v: Any) -> Any:
+    """``v`` as a program store key digests it: JSON with nothing in it that
+    differs between two processes for the same thing (a function by its
+    name, never by its address; an object with no ``repr`` of its own by its
+    attributes)."""
+    if v is None or isinstance(v, (bool, int, float, str)):
+        return v
+    if isinstance(v, dict) or type(v).__name__ == "mappingproxy":
+        return {str(k): _plain(x) for k, x in sorted(v.items(), key=lambda kv: str(kv[0]))}
+    if isinstance(v, (list, tuple, set, frozenset)) and not isinstance(v, P):
+        return [_plain(x) for x in (sorted(v, key=str) if isinstance(v, (set, frozenset)) else v)]
+    if dataclasses.is_dataclass(v) and not isinstance(v, type):
+        return [type(v).__name__, {f.name: _plain(getattr(v, f.name)) for f in dataclasses.fields(v)}]
+    if callable(v):
+        return f"{getattr(v, '__module__', '?')}.{getattr(v, '__qualname__', type(v).__name__)}"
+    if type(v).__repr__ is object.__repr__:
+        return [type(v).__name__, _plain(getattr(v, "__dict__", {}))]
+    return repr(v)
+
+
+class _RestoredStep:
+    """A program restored from the store, in its cache slot until its FIRST
+    call has returned: the slot then holds the ``Compiled`` itself.  A first
+    call that raises before it has consumed its arguments (a layout the
+    ``Compiled`` refuses, an executable the runtime cannot run) drops the
+    entry and puts the traced step in the slot; one that has consumed them
+    raises, as a jitted step's would."""
+
+    def __init__(self, trainer: "Trainer", cache: Dict, slot: Any, key: str, compiled: Any, traced: Callable):
+        self.trainer, self.cache, self.slot, self.key = trainer, cache, slot, key
+        self.compiled, self.traced = compiled, traced
+
+    def __call__(self, *args):
+        try:
+            out = self.compiled(*args)
+        except Exception as e:  # noqa: BLE001 - whatever the first call raises, the traced step is the answer
+            if not _state_alive(args[0]):
+                raise
+            self.trainer.programs.discard(self.key, f"its first call failed ({type(e).__name__}: {str(e)[:200]})")
+            step = self.cache[self.slot] = self.traced(args)
+            return step(*args)
+        self.cache[self.slot] = self.compiled
+        return out
+
+
 def compiled_bytes(compiled) -> int:
     """What a compiled program occupies on a device, by the compiler's own
     account: arguments + outputs - aliased + temporaries."""
@@ -482,10 +557,25 @@ def compiled_bytes(compiled) -> int:
 class Trainer:
     """Builds and runs jitted train/eval steps for a ModelSpec over a mesh."""
 
-    def __init__(self, spec: ModelSpec, config: JobConfig, mesh: Mesh):
+    def __init__(
+        self, spec: ModelSpec, config: JobConfig, mesh: Mesh,
+        programs: Optional[program_store.ProgramStore] = None,
+    ):
         self.spec = spec
         self.config = config
         self.mesh = mesh
+        #: Where this trainer's compiled train steps are kept and looked for
+        #: (common/program_store.py): the WORKER's trainer has one, and a
+        #: relaunch restores its step instead of tracing it.  None, for
+        #: everybody else: every step is traced (:meth:`_train_program`).
+        self.programs = programs
+        #: Train steps this trainer restored from the store, and those it
+        #: traced (with or without one).
+        self.programs_restored = 0
+        self.programs_traced = 0
+        # (key, compiled, host side) of the steps traced with a store,
+        # written once the first dispatch is out (:meth:`_store_traced`).
+        self._unstored: List[Tuple[str, Any, Dict[str, Any]]] = []
         self._adopt_mesh_axes(mesh)
         self.sharded_embeddings = (
             config.distribution_strategy == DistributionStrategy.PARAMETER_SERVER
@@ -1568,7 +1658,7 @@ class Trainer:
     # jit still handles shape/dtype retraces within a structure.
 
     def _structured(self, cache: Dict, build, batch: Any, fit_args=None, **kwargs):
-        key = jax.tree.structure(batch)
+        key = self._slot(batch, fit_args)
         fn = cache.get(key)
         if fn is None:
             make = functools.partial(
@@ -1581,8 +1671,18 @@ class Trainer:
                 batch_axes=self.batch_axes,
                 **kwargs,
             )
-            fn = cache[key] = make() if fit_args is None else self._held_to_the_line(make, fit_args)
+            fn = cache[key] = make() if fit_args is None else self._train_program(cache, key, make, fit_args)
         return fn
+
+    def _slot(self, batch: Any, fit_args) -> Any:
+        """A step cache's key for ``batch``: its tree structure, and for a
+        train step of a trainer with a program store its leaves' shapes too
+        (a restored program serves ONE set of shapes, where a jitted step
+        retraces: each set gets a slot, and a store entry, of its own)."""
+        key = jax.tree.structure(batch)
+        if self.programs is not None and fit_args is not None:
+            key = (key, tuple((leaf.shape, str(leaf.dtype)) for leaf in jax.tree.leaves(batch)))
+        return key
 
     def _train_build_kwargs(self) -> Dict[str, Any]:
         """The build_train_step kwargs shared by the per-step and scan
@@ -1611,18 +1711,22 @@ class Trainer:
         self.keep_plan = KeepPlan(line=line, aim=line - REMAT_REFIT_MARGIN - over)
         return self.keep_plan
 
-    def _held_to_the_line(self, make: Callable, args: Tuple) -> Callable:
+    def _held_to_the_line(self, make: Callable, args: Tuple, compile_anyway: bool = False) -> Tuple[Callable, Any]:
         """The train step ``make(keep_plan=...)`` builds, for its first
-        call's ``args``.  Where the model is given a :class:`KeepPlan` the
-        step is compiled here and held to the plan's line: while the
+        call's ``args``, and the ``Compiled`` of its last compile here (None
+        where there was none).  Where the model is given a :class:`KeepPlan`
+        the step is compiled here and held to the plan's line: while the
         compiler's own account of it reads over the line and something is
         kept, it is made again with the overshoot and a margin off the aim.
         The last compile is the one the first call would have made: the
-        call finds it."""
+        call finds it.  ``compile_anyway``: also a step without a plan is
+        compiled here, for the program store to keep."""
         plan, compiles, gib = self._new_keep_plan(), 0, 2.0**30
         step = make(keep_plan=plan)
+        compiled = step.lower(*args).compile() if compile_anyway and plan is None else None
         while plan is not None:
-            total = compiled_bytes(step.lower(*args).compile())
+            compiled = step.lower(*args).compile()
+            total = compiled_bytes(compiled)
             compiles += 1
             logger.info(
                 "rematerialised blocks keep %.3f of %.3f GiB tagged (budget %.3f): the step compiled to "
@@ -1634,7 +1738,114 @@ class Trainer:
                 break
             plan = self._new_keep_plan(over=total - plan.aim)
             step = make(keep_plan=plan)
+        return step, compiled
+
+    def _train_program(self, cache: Dict, slot: Any, make: Callable, args: Tuple) -> Callable:
+        """The train step for ``args``, in the cache slot it is for.  A
+        trainer WITHOUT a program store traces it
+        (:meth:`_held_to_the_line`), and so does one whose spec does not
+        say what it was loaded with.  With a store: the stored program of
+        this key if there is one, called as the jitted step is (same
+        arguments, same donation, same outputs), with what its trace left
+        on the host (the keep plan, the lines it logged) put back; else the
+        traced step, whose compiled program is written to the store once
+        the first dispatch is out (:meth:`_store_traced`)."""
+        store, t0 = self.programs, time.monotonic()
+        key = None if store is None else self._program_key(make, args)
+        if key is None:
+            self.programs_traced += 1
+            return self._held_to_the_line(make, args)[0]
+        found = store.restore(key, list(self.mesh.devices.flat))
+        store.spent(time.monotonic() - t0)
+        if found is not None:
+            compiled, host = found
+            self.programs_restored += 1
+            if host.get("keep_plan") is not None:
+                self.keep_plan = KeepPlan(**host["keep_plan"])
+            log_utils.replay(host.get("said", ()))
+            return _RestoredStep(self, cache, slot, key, compiled, functools.partial(self._traced_for_the_store, key, make))
+        return self._traced_for_the_store(key, make, args)
+
+    def _traced_for_the_store(self, key: str, make: Callable, args: Tuple) -> Callable:
+        """Today's path, with what the store will keep of it noted: the
+        step is compiled here whatever its model (the first call finds that
+        compile, as a rematerialising model's always did)."""
+        self.programs_traced += 1
+        hits = compile_phase_seconds()["cache_hits"]
+        with log_utils.capture() as said:
+            step, compiled = self._held_to_the_line(make, args, compile_anyway=True)
+        if compile_phase_seconds()["cache_hits"] > hits and self.mesh.devices.flat[0].platform == "cpu":
+            # XLA:CPU serializes an executable it LOADED (a compile the
+            # persistent cache served) without its object code: restored in
+            # another process it fails inside its first execution, after
+            # the state is donated (jax 0.9.0).  Only what this process
+            # compiled is kept there.
+            logger.info("the compile cache served the train step's compile: on the CPU it is not kept in the program store")
+            return step
+        plan = self.keep_plan if self.spec.rematerialises else None
+        host = {"keep_plan": None if plan is None else dataclasses.asdict(plan), "said": list(said)}
+        self._unstored.append((key, compiled, host))
         return step
+
+    def _store_traced(self) -> None:
+        """After a dispatch: hand the programs traced since the last one to
+        the store's writer thread (nothing here waits for the disk)."""
+        unstored, self._unstored = self._unstored, []
+        for key, compiled, host in unstored:
+            self.programs.save_later(key, compiled, host)
+
+    def _program_key(self, make: Callable, args: Tuple) -> Optional[str]:
+        """The program store's key of the train step ``make`` builds for
+        ``args``: a digest of everything that step's program depends on,
+        from what can be read BEFORE anything is traced.  When in doubt a
+        thing is in: a stale hit is a silently wrong program, a needless
+        miss costs one trace.  None where the spec does not say what it was
+        loaded with (``ModelSpec.loaded_with``): no key, no store."""
+        spec = self.spec
+        if spec.loaded_with is None:
+            return None
+        zoo, model_def, params = spec.loaded_with
+        module = inspect.getmodule(spec.apply)
+        devices = list(self.mesh.devices.flat)
+        client = devices[0].client
+
+        def described(tree):
+            return [str(jax.tree.structure(tree))] + [
+                (tuple(leaf.shape), str(leaf.dtype), str(getattr(leaf, "sharding", None)))
+                for leaf in jax.tree.leaves(tree)
+            ]
+
+        return program_store.key_of({
+            # the code: the package, and the model's module where a zoo outside it holds it
+            "source": program_store.source_digest(also=(getattr(module, "__file__", "") or "",)),
+            "environment": program_store.environment(client),
+            # the devices and their layout
+            "devices": [(d.id, d.process_index, d.device_kind) for d in devices],
+            "process": (jax.process_index(), jax.process_count()),
+            "mesh": (tuple(self.mesh.axis_names), tuple(self.mesh.devices.shape)),
+            "bytes_limit": device_bytes_limit(devices),
+            "keep_line": (REMAT_HEADROOM, REMAT_REFIT_MARGIN),
+            # the arguments: trees, shapes, dtypes, shardings
+            "arguments": described(args),
+            # the model
+            "model": (zoo, model_def, _plain(params)),
+            # what the spec declares beside its functions (the optimizer is made from the parameters)
+            "spec": {
+                f.name: _plain(getattr(spec, f.name))
+                for f in dataclasses.fields(spec)
+                if f.name not in ("optimizer", "loaded_with", "feed", "example_batch")
+            },
+            # the job
+            "config": {
+                f.name: getattr(self.config, f.name)
+                for f in dataclasses.fields(self.config)
+                if f.name not in _NOT_IN_A_KEY
+            },
+            # the trainer's own choices for this mesh, and the step's variant (make's keywords:
+            # host keys, the optimizer's shard plan, donation, the collective's topology, scan or step)
+            "context": (_plain(self.ctx), self.batch_axes, self.reduce_axes, self.contributor_axes, self.sharded_embeddings),
+            "build": (_plain(make.func), _plain(make.args[3]), _plain(make.keywords)),
+        })
 
     # jit-boundary: returns device buffers fresh off the compiled step
     def build_train_step(self, state: Any, batch: Any) -> Callable:
@@ -1657,7 +1868,10 @@ class Trainer:
         return self._train_step
 
     def train_step(self, state: TrainState, batch: Any):
-        return self.build_train_step(state, batch)(state, batch, self._active_device())
+        out = self.build_train_step(state, batch)(state, batch, self._active_device())
+        if self._unstored:
+            self._store_traced()
+        return out
 
     def shard_stacked_batch(self, stacked: Any) -> Any:
         """Place a HOST batch of stacked minibatches ([T, mb, ...] per leaf)
@@ -1685,7 +1899,7 @@ class Trainer:
     def _scanned(self, cache: Dict, build, stacked: Any, fit_args=None, **kwargs):
         """Scan-variant twin of _structured: build (or fetch) the fused
         lax.scan step for this stacked batch's tree structure."""
-        key = ("scan", jax.tree.structure(stacked))
+        key = ("scan", self._slot(stacked, fit_args))
         fn = cache.get(key)
         if fn is None:
             make = functools.partial(
@@ -1699,7 +1913,7 @@ class Trainer:
                 scan_steps=True,
                 **kwargs,
             )
-            fn = cache[key] = make() if fit_args is None else self._held_to_the_line(make, fit_args)
+            fn = cache[key] = make() if fit_args is None else self._train_program(cache, key, make, fit_args)
         return fn
 
     # jit-boundary: returns device buffers fresh off the compiled scan
@@ -1714,7 +1928,10 @@ class Trainer:
             variant_budget=self.jit_budgets["train_scan"],
             **self._train_build_kwargs(),
         )
-        return self._train_step(state, stacked, self._active_device())
+        out = self._train_step(state, stacked, self._active_device())
+        if self._unstored:
+            self._store_traced()
+        return out
 
     # jit-boundary: returns device metrics fresh off the compiled step
     def eval_step(self, state: TrainState, batch: Any) -> Dict[str, jax.Array]:

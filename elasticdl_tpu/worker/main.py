@@ -7,6 +7,14 @@ PodManager) with CLI flags as a fallback, and the worker id comes from
 ``ELASTICDL_WORKER_ID`` (the pod name).
 
 Run as ``python -m elasticdl_tpu.worker.main``.
+
+Two caches serve this process's programs (``main`` switches both on before
+the first touch of jax's backend): jax's persistent compile cache
+(``common/platform.enable_compile_cache``) serves every program the process
+TRACES, at the price of the trace; the program store
+(``common/program_store.py``, the directory beside it) serves a relaunched
+worker its compiled train step with nothing traced.  Only this entry point
+makes a store: a ``Trainer`` built anywhere else traces its step.
 """
 
 from __future__ import annotations
@@ -278,9 +286,17 @@ def main(argv: Optional[List[str]] = None) -> int:
     # XLA:CPU's Gloo context init times out (hard 30 s) if one process is
     # still compiling while its peer already executes — observed when the
     # fused-scan compile ran under CPU contention.
+    #
+    # Which cache serves what: the compile cache serves every program this
+    # process still TRACES (init_state, eval, predict, the snapshot copy, and
+    # the train step of a first launch) at the price of the trace; the
+    # program store, a sibling directory of it, serves a relaunch its
+    # compiled TRAIN STEP with nothing traced (common/program_store.py).
     from elasticdl_tpu.common.platform import enable_compile_cache
+    from elasticdl_tpu.common.program_store import ProgramStore
 
     enable_compile_cache()
+    programs = ProgramStore.beside_compile_cache()
     setup.mark("setup:imports")
 
     # Call deadline + outage ride-through budget come off the config bus
@@ -430,6 +446,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     worker = Worker(
         config, master, reader, worker_id=worker_id,
         gauges=gauge.default(), incarnation=incarnation, setup=setup,
+        programs=programs,
     )
     worker_holder["worker"] = worker
     metrics_server = maybe_start(
@@ -458,8 +475,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         hb_stop.set()
         if metrics_server is not None:
             metrics_server.stop()
+    programs.settle()  # a job shorter than the store's write
     result["device"] = boot
     result["compile_cache"] = compile_cache_stats()
+    result["program_store"] = dict(programs.counts(), dir=programs.directory)
     logger.info(
         "worker %s finished: %s", worker_id, json.dumps(result, default=str)
     )

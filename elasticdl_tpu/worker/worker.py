@@ -33,6 +33,7 @@ from elasticdl_tpu.common.checkpoint import CheckpointManager
 from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.metrics import PhaseTimers, finalize_metrics
+from elasticdl_tpu.common.program_store import ProgramStore
 from elasticdl_tpu.common.platform import (
     compile_counts,
     compile_phase_seconds,
@@ -381,7 +382,14 @@ class Worker:
         gauges: Optional[gaugelib.Registry] = None,
         incarnation: Optional[str] = None,
         setup: Optional[trace.SetupChain] = None,
+        programs: Optional[ProgramStore] = None,
     ):
+        # Where this worker's trainer keeps and looks for its compiled
+        # train steps (common/program_store.py; worker.main passes the
+        # process's).  Only a worker that loads its spec from the config
+        # itself uses it: a spec handed in is not described by the config a
+        # key is made of.
+        self._programs = programs if spec is None else None
         # This incarnation's set-up chain (common/trace.py SetupChain):
         # worker.main hands over the process's, already marked up to the
         # device's opening; a standalone Worker's starts here.  _run and
@@ -790,7 +798,7 @@ class Worker:
                 self._pool, num_devices=n_dev, dcn_parallelism=dcn
             )
         if initial or self.trainer is None:
-            self.trainer = Trainer(self.spec, self.config, mesh)
+            self.trainer = Trainer(self.spec, self.config, mesh, programs=self._programs)
         elif (
             list(self.trainer.mesh.devices.flat) == list(mesh.devices.flat)
             and self.trainer.mesh.shape == mesh.shape
@@ -1655,7 +1663,9 @@ class Worker:
         end less those at the beginning."""
         setup.mark("setup:first_prep" if begins else "setup:first_dispatch")
         parts, sign = compile_phase_seconds(), -1.0 if begins else 1.0
-        for part in ("trace_s", "lower_s", "compile_s", "cache_load_s"):
+        # what the program store cost (a key's digest, an entry's read and load)
+        parts["restore_s"] = self._programs.counts()["restore_s"] if self._programs is not None else 0.0
+        for part in ("trace_s", "lower_s", "compile_s", "cache_load_s", "restore_s"):
             key = f"setup:first_dispatch.{part}"
             setup.extras[key] = setup.extras.get(key, 0.0) + sign * parts[part]
 
@@ -1665,16 +1675,32 @@ class Worker:
         ``edl_setup_seconds{phase=}`` and, with the ring on, as
         ``cat="setup"`` spans: one set of stamps, three sinks."""
         parts = compile_phase_seconds()
+        restored = float(self.trainer.programs_restored)
         setup.extras.update(
             # the nonce is "<pid>-<epoch ms>" (worker.main, __init__)
             incarnation_ms=float(self._incarnation.rpartition("-")[2]),
             # every compile request up to this report, and how the
-            # persistent cache served them
+            # persistent cache served them; a train step restored from the
+            # program store made no request and WAS served from disk: it
+            # counts as a hit
             compile_requests=float(compile_counts()[0]),
-            cache_hits=parts["cache_hits"],
+            cache_hits=parts["cache_hits"] + restored,
             cache_misses=parts["cache_misses"],
+            # train steps this incarnation restored from the program store,
+            # and those it traced (all of them, without a store)
+            programs_restored=restored,
+            programs_traced=float(self.trainer.programs_traced),
         )
-        for phase, seconds in setup.durations().items():
+        durations = dict(
+            setup.durations(),
+            **{"setup:first_dispatch.restore_s": setup.extras.get("setup:first_dispatch.restore_s", 0.0)},
+        )
+        for name in ("programs_restored", "programs_traced"):
+            self.gauges.gauge(
+                f"edl_{name}",
+                "train steps this incarnation had restored from the program store / traced by its first training report",
+            ).set(setup.extras[name])
+        for phase, seconds in durations.items():
             self.gauges.gauge(
                 "edl_setup_seconds",
                 "wall seconds of each span of this incarnation's set-up "

@@ -116,6 +116,8 @@ def pytest_collection_modifyitems(items):
 def pytest_sessionfinish(session, exitstatus):
     if _MADE_JAX_CACHE_DIR:
         shutil.rmtree(_JAX_CACHE_DIR, ignore_errors=True)
+        # its sibling: the program store of the run's worker processes (common/program_store.py)
+        shutil.rmtree(_JAX_CACHE_DIR + "_programs", ignore_errors=True)
 
 
 @pytest.fixture

@@ -197,15 +197,20 @@ def test_the_chain_rides_the_first_training_report_and_no_other(tmp_path, device
     assert record["pid"] == os.getpid() and record["step"] == first_train["step"]
     assert record["compile_requests"] >= 1
     parts = {k.rsplit(".", 1)[1]: v for k, v in record.items() if k.startswith("setup:first_dispatch.")}
-    assert set(parts) == {"trace_s", "lower_s", "compile_s", "cache_load_s"}
+    assert set(parts) == {"trace_s", "lower_s", "compile_s", "cache_load_s", "restore_s"}
     assert parts["compile_s"] > 0 and parts["trace_s"] > 0 and all(v >= 0 for v in parts.values())
+    # a worker handed its spec has no program store (PR 57): its step is traced, nothing restored
+    assert (record["programs_restored"], record["programs_traced"], parts["restore_s"]) == (0.0, 1.0, 0.0)
     assert parts["compile_s"] <= spans["setup:first_dispatch"][1] - spans["setup:first_dispatch"][0]
     # the same stamps as gauges of the worker's registry
     family = worker.gauges.snapshot()["edl_setup_seconds"]
     by_phase = {s["labels"]["phase"]: s["value"] for s in family["samples"]}
-    assert set(by_phase) == set(chain) - {"setup:first_step"}
+    assert set(by_phase) == set(chain) - {"setup:first_step"} | {"setup:first_dispatch.restore_s"}
+    assert by_phase.pop("setup:first_dispatch.restore_s") == 0.0
     for name, seconds in by_phase.items():
         assert seconds == pytest.approx(spans[name][1] - spans[name][0], abs=1e-6)
+    assert {name: worker.gauges.snapshot()[name]["samples"][0]["value"] for name in ("edl_programs_restored", "edl_programs_traced")} == {
+        "edl_programs_restored": 0.0, "edl_programs_traced": 1.0}
     # ... and none of it among the counters that ride EVERY report
     assert not any(k.startswith("setup") for r in seen for k in r["counters"])
 
