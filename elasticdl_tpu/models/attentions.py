@@ -451,8 +451,8 @@ class IndexedSparseAttention(Part):
         with jax.named_scope("attn_proj"):
             heads = lambda t: t.reshape(b, l, -1, self.head_dim)  # noqa: E731
             # NOT save sites: a kept byte of this step costs its peak 1.5 bytes (PERF.md section 7, `keye_vl2_job` (f)), and
-            # the three products are the cheapest thing here to make again (2.5 ms a layer); the selection and
-            # the attention's output are the sites
+            # the three products are the cheapest thing here to make again (2.5 ms a layer); the selection, the
+            # attention's output and the indexer's loss's gradients (``ops/sparse_select.indexer_loss``) are the sites
             q, k, v = (heads(u @ cast(blk["w" + name])) for name in ("q", "k", "v"))
         with jax.named_scope("attn_glue"):
             q, k = rms_norm(q, blk["q_norm"], self.eps), rms_norm(k, blk["k_norm"], self.eps)

@@ -10,8 +10,8 @@ key s <= t (float32):
     I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])
 
 - :func:`index_scores`: a block of query rows against all keys, ``-inf``
-  after the query's own position; a ``custom_vjp`` (the indexer's loss is
-  the only thing that differentiates it).  On the TPU three Pallas kernels
+  after the query's own position; a ``custom_vjp`` of its own (the loss
+  below calls its gradient kernels directly).  On the TPU three Pallas kernels
   (the score; its gradient to qI and w; its gradient to kI): J products
   contracted over a 128-lane group that holds ``128 // E`` heads, each head
   with the other heads' lanes zeroed against the key tiled to the group
@@ -33,13 +33,23 @@ key s <= t (float32):
   (``softmax_{S_t}(I) - p`` on the selected set).  ``p``'s head sum is one
   more pass over the pairs from the attention's saved logsumexps
   (:func:`head_summed_probs`: a Pallas kernel on the TPU, the head the
-  grid's last axis and the [rows, keys] sum resident in VMEM).
+  grid's last axis and the [rows, keys] sum resident in VMEM).  The loss is
+  a scalar, so it walks the pairs ONCE a step: a ``custom_vjp`` whose
+  forward rule makes, in each chunk, the scores, the probabilities, the KL's
+  rows AND the rows' gradient to the scores, handed at once to the score's
+  gradient kernels; the three gradients (to qI, kI, w: 35.7 MB a layer at L
+  = 16,384 where a chunk's scores alone are 33.5) are the residuals, the
+  save site ``dsa_index_grads`` of a rematerialised block (``ops/remat.py``),
+  and the backward multiplies them by the loss's cotangent.  Kept, the
+  block's backward walks nothing; not kept, its recomputation runs the rule
+  once.  A forward nobody differentiates computes the loss and no gradient.
 
-Everything walks the queries ``chunk`` rows at a time (``q_chunk_size``)
-under ``lax.map``, so that one chunk's [rows, L] float32 arrays are alive
-at a time.  Scopes: ``dsa_index`` (the score products, forward and
-backward), ``dsa_select`` (threshold, ties, mask), ``dsa_loss`` (the
-head-summed probabilities and the KL).
+Everything walks the queries ``chunk`` rows at a time (``q_chunk_size``),
+so that one chunk's [rows, L] float32 arrays are alive at a time: a layer a
+step launches the score kernel twice (the selection, the loss), its
+gradient pair once and the head sum once.  Scopes: ``dsa_index`` (the score
+products, forward and backward), ``dsa_select`` (threshold, ties, mask),
+``dsa_loss`` (the head-summed probabilities and the KL).
 """
 
 from __future__ import annotations
@@ -52,6 +62,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from elasticdl_tpu.ops import remat
 from elasticdl_tpu.ops.flash_attention import _LANE, _NN, _NT, _TN, _use_interpret
 from elasticdl_tpu.ops.ring_attention import PATH_PALLAS_COMPILED, PATH_PALLAS_INTERPRET, PATH_XLA_REFERENCE, announce_path
 
@@ -228,15 +239,23 @@ def _score_specs(b, rows, length, heads, e, queries_first: bool):
     return grid, bq, bk, specs
 
 
-def _launch(kernel, grid, in_specs, out_specs, out_shape, scratch, flops, operands):
+def _cost(flops: int, operands=(), transcendentals: int = 0):
+    return pl.CostEstimate(flops=flops, transcendentals=transcendentals, bytes_accessed=remat.nbytes(operands))
+
+
+def _score_cost(b, rows, length, heads, operands=(), products: int = 1):
+    """The score kernel's declared cost: ``products`` (1 forward, 2 in each gradient kernel) a head a pair over the
+    causal half of the pairs, each contracted over a whole block of 128 lanes."""
+    return _cost(products * 2 * b * rows * length * heads * _LANE // 2, operands)
+
+
+def _launch(kernel, grid, in_specs, out_specs, out_shape, scratch, cost):
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch,
         ),
-        out_shape=out_shape, interpret=_use_interpret(),
-        cost_estimate=pl.CostEstimate(
-            flops=flops, transcendentals=0, bytes_accessed=sum(x.size * x.dtype.itemsize for x in operands)),
+        out_shape=out_shape, interpret=_use_interpret(), cost_estimate=cost,
     )
 
 
@@ -254,32 +273,35 @@ def _scores_by_kernel(qi, ki, w, offset):
     out = jax.ShapeDtypeStruct((b, rows, length), jnp.float32)
     return _launch(
         functools.partial(_score_kernel, e=e, heads=heads), grid, [specs["qi"], specs["ki"], specs["w"]], specs["pairs"], out, [],
-        2 * b * rows * length * heads * _LANE // 2, (flat, tiled, w, out),
+        _score_cost(b, rows, length, heads, (flat, tiled, w, out)),
     )(jnp.reshape(offset, (1,)).astype(jnp.int32), flat, tiled, w)
 
 
 def _score_grads_by_kernel(qi, ki, w, offset, g):
+    """``(dqI, dkI, dw)`` of the score for its cotangent ``g`` [B, rows, L]; ``dkI`` in FLOAT32 (a caller that walks
+    the queries in chunks sums the chunks' before it rounds)."""
     b, rows, heads, e = qi.shape
     length = ki.shape[1]
     flat, tiled = _score_operands(qi, ki)
     at = jnp.reshape(offset, (1,)).astype(jnp.int32)
     grid, bq, bk, specs = _score_specs(b, rows, length, heads, e, True)
-    flops = 2 * b * rows * length * heads * _LANE     # two products a head a pair, over the causal half of the pairs
     dq, dw = _launch(
         functools.partial(_score_dq_kernel, e=e, heads=heads), grid,
         [specs["qi"], specs["ki"], specs["w"], specs["pairs"]], [specs["qi"], specs["dw"]],
         [jax.ShapeDtypeStruct(flat.shape, flat.dtype), jax.ShapeDtypeStruct((b, rows, _LANE), jnp.float32)],
-        [pltpu.VMEM((bq, heads * e), jnp.float32), pltpu.VMEM((bq, _LANE), jnp.float32)], flops, (flat, tiled, w, g, flat),
+        [pltpu.VMEM((bq, heads * e), jnp.float32), pltpu.VMEM((bq, _LANE), jnp.float32)],
+        _score_cost(b, rows, length, heads, (flat, tiled, w, g, flat), products=2),
     )(at, flat, tiled, w, g)
     grid, bq, bk, specs = _score_specs(b, rows, length, heads, e, False)
     dk = _launch(
         functools.partial(_score_dk_kernel, e=e, heads=heads), grid,
         [specs["qi"], specs["ki"], specs["w"], specs["pairs"]], specs["ki"],
-        jax.ShapeDtypeStruct((b, length, _LANE), jnp.float32), [pltpu.VMEM((bk, _LANE), jnp.float32)], flops, (flat, tiled, w, g),
+        jax.ShapeDtypeStruct((b, length, _LANE), jnp.float32), [pltpu.VMEM((bk, _LANE), jnp.float32)],
+        _score_cost(b, rows, length, heads, (flat, tiled, w, g), products=2),
     )(at, flat, tiled, w, g)
     # the heads of a lane group each added their own lanes: the ONE key's gradient is their sum
     dk = jnp.sum(dk.reshape(b, length, _LANE // e, e), axis=2)
-    return dq.reshape(qi.shape), dk.astype(ki.dtype), dw[:, :, :heads].astype(w.dtype)
+    return dq.reshape(qi.shape), dk, dw[:, :, :heads].astype(w.dtype)
 
 
 def _index_scores(qi, ki, w, offset):
@@ -302,15 +324,22 @@ def _index_scores_fwd(qi, ki, w, offset):
     return _index_scores(qi, ki, w, offset), (qi, ki, w, offset)
 
 
-def _index_scores_bwd(res, g):
-    qi, ki, w, offset = res
+def _score_grads(qi, ki, w, offset, g):
+    """``(dqI, dkI, dw)`` of :func:`index_scores` for its cotangent ``g``, which counts up to a query's own position
+    alone; ``dqI`` and ``dw`` in their operands' types, ``dkI`` in float32 (:func:`_score_grads_by_kernel` has why)."""
     with jax.named_scope("dsa_index"):
         g = jnp.where(_causal(offset, g.shape[1], g.shape[2]), g, 0.0)
         if kernel_path(qi, ki):
-            grads = jax.vjp(lambda qi, ki, w: index_scores_reference(qi, ki, w, offset), qi, ki, w)[1](g)
-        else:
-            grads = _score_grads_by_kernel(qi, ki, w, offset, g)
-    return (*grads, None)
+            # the key widened and rounded back: the same scores, and its gradient comes in the float32 it is summed in
+            plain = lambda qi, wide, w: index_scores_reference(qi, wide.astype(ki.dtype), w, offset)  # noqa: E731
+            return jax.vjp(plain, qi, ki.astype(jnp.float32), w)[1](g)
+        return _score_grads_by_kernel(qi, ki, w, offset, g)
+
+
+def _index_scores_bwd(res, g):
+    qi, ki, w, offset = res
+    dq, dk, dw = _score_grads(qi, ki, w, offset, g)
+    return dq, dk.astype(ki.dtype), dw, None
 
 
 index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)
@@ -441,6 +470,11 @@ def _probs_kernel(summary_ref, q_ref, k_ref, lse_ref, mask_ref, p_ref, *, scale,
         p_ref[0] = jnp.where(mask_ref[0].astype(jnp.int32) != 0, p_ref[0], 0.0)
 
 
+def _probs_cost(b, rows, length, heads, d, operands=()):
+    """The probabilities kernel's declared cost: a product and an exponential a head a pair over the causal half."""
+    return _cost(2 * b * heads * rows * length * d // 2, operands, transcendentals=b * heads * rows * length // 2)
+
+
 def _probs_by_kernel(q, k, lse, mask):
     b, rows, heads, d = q.shape
     length, groups = k.shape[1], k.shape[2]
@@ -470,10 +504,7 @@ def _probs_by_kernel(q, k, lse, mask):
             out_specs=spec((1, bq, bk), lambda bb, i, j, h: (bb, i, j)),
             scratch_shapes=[],
         ),
-        out_shape=out, interpret=_use_interpret(),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * b * heads * rows * length * d // 2, transcendentals=b * heads * rows * length // 2,
-            bytes_accessed=sum(x.size * x.dtype.itemsize for x in (qk, kk, lse, mask, out))),
+        out_shape=out, interpret=_use_interpret(), cost_estimate=_probs_cost(b, rows, length, heads, d, (qk, kk, lse, mask, out)),
     )(summary, qk, kk, lse, mask)
 
 
@@ -505,25 +536,73 @@ def kl_rows(scores, probs, mask):
         return jnp.sum(jnp.where(on, p * (jnp.log(jnp.where(on, p, 1.0)) - jnp.where(on, log_q, 0.0)), 0.0), axis=-1)
 
 
-def indexer_loss(qi, ki, w, q, k, lse, mask, chunk: int):
-    """``mean_t KL(p[t, .] || softmax_{S_t}(I[t, .]))`` over every row of
-    the batch.  ``q`` [B, L, H, D], ``k`` [B, L, G, D] and ``lse`` [B * H, 1,
-    L] are the attention's (its probabilities are a constant here: their
-    gradient is stopped); the gradient reaches ``qi``, ``ki``, ``w``."""
-    q, k, lse = (lax.stop_gradient(x) for x in (q, k, lse))
+def _walk(qi, ki, w, q, k, lse, mask, chunk: int, grads: bool):
+    """The ONE walk of the pairs the loss takes, ``chunk`` query rows at a time: ``(loss, (dqI, dkI, dw))``, the
+    gradients (``None`` without ``grads``) those of the loss itself, made in each chunk from the scores, the
+    probabilities and the mask while they are alive there: the KL's rows' gradient to the scores (the mean's
+    ``1 / (B L)`` in it; zero off the selected set) handed at once to the score's gradient (:func:`_score_grads`).
+    ``dqI`` and ``dw`` leave chunk by chunk, ``dkI`` is the chunks' sum in float32; no [rows, L] array leaves a chunk."""
     b, length, heads, _ = q.shape
     rows = chunk_rows(length, chunk)
     lse = lse.reshape(b, heads, length)
 
-    @jax.checkpoint
-    def of_a_chunk(ki, k, part):
+    def of_a_chunk(dk, part):
         qi_c, w_c, q_c, lse_c, mask_c, offset = part
         scores = index_scores(qi_c, ki, w_c, offset)
         probs = head_summed_probs(q_c, k, lse_c, mask_c)
-        return jnp.sum(kl_rows(scores, probs, mask_c))
+        if not grads:
+            return dk, (jnp.sum(kl_rows(scores, probs, mask_c)), None, None)
+        kl, to_scores = jax.vjp(lambda scores: kl_rows(scores, probs, mask_c), scores)
+        dq_c, dk_c, dw_c = _score_grads(qi_c, ki, w_c, offset, *to_scores(jnp.full(kl.shape, 1.0 / (b * length), kl.dtype)))
+        return dk + dk_c, (jnp.sum(kl), dq_c, dw_c)
 
     parts = (
         _chunks(qi, rows), _chunks(w, rows), _chunks(q, rows), _chunks(lse, rows, axis=2), _chunks(mask, rows),
         jnp.arange(0, length, rows),
     )
-    return jnp.sum(lax.map(functools.partial(of_a_chunk, ki, k), parts)) / (b * length)
+    dk, (kl, dq, dw) = lax.scan(of_a_chunk, jnp.zeros(ki.shape, jnp.float32) if grads else None, parts)
+    return jnp.sum(kl) / (b * length), (_unchunk(dq), dk.astype(ki.dtype), _unchunk(dw)) if grads else None
+
+
+def grads_work(qi, q) -> float:
+    """What keeping the loss's gradients spares a rematerialised block (``ops/remat.py``'s FLOPs): the walk, which is
+    the score kernel, the probabilities kernel and the score's two gradient kernels over every chunk, by the costs
+    they declare."""
+    b, length, heads, _ = qi.shape
+    kernels = [_score_cost(b, length, length, heads), _probs_cost(b, length, length, q.shape[2], q.shape[3])]
+    return sum(map(remat.kernel_work, kernels + 2 * [_score_cost(b, length, length, heads, products=2)]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _indexer_loss(qi, ki, w, q, k, lse, mask, chunk, keep=False):
+    # a block's survey (``remat.trace_sites``) traces this primal and runs no forward rule: the site is told here, by
+    # the three operands whose shapes and types the gradients have
+    remat.site("dsa_index_grads", grads_work(qi, q), qi, ki, w, keep=False)
+    return _walk(qi, ki, w, q, k, lse, mask, chunk, grads=False)[0]
+
+
+def _indexer_loss_fwd(qi, ki, w, q, k, lse, mask, chunk, keep):
+    loss, grads = _walk(qi, ki, w, q, k, lse, mask, chunk, grads=True)
+    # A save site (ops/remat.py): a rematerialised block that keeps the three gradients walks the pairs no second time.
+    return loss, remat.site("dsa_index_grads", grads_work(qi, q), *grads, keep=keep)
+
+
+def _indexer_loss_bwd(chunk, keep, grads, g):
+    # the loss is a scalar: its cotangent scales what the forward's walk made (exactly, at the coefficient 1.0)
+    return (*((g * x).astype(x.dtype) for x in grads), None, None, None, None)
+
+
+_indexer_loss.defvjp(_indexer_loss_fwd, _indexer_loss_bwd)
+
+
+def indexer_loss(qi, ki, w, q, k, lse, mask, chunk: int):
+    """``mean_t KL(p[t, .] || softmax_{S_t}(I[t, .]))`` over every row of
+    the batch.  ``q`` [B, L, H, D], ``k`` [B, L, G, D] and ``lse`` [B * H, 1,
+    L] are the attention's (its probabilities are a constant here: their
+    gradient is stopped); the gradient reaches ``qi``, ``ki``, ``w``.  A
+    ``custom_vjp``: differentiated, the forward's one walk makes the three
+    gradients too (:func:`_walk`) and they are the residuals, the save site
+    ``dsa_index_grads`` of a rematerialised block; the backward scales them."""
+    q, k, lse = (lax.stop_gradient(x) for x in (q, k, lse))
+    # ``remat.kept``: asked here, while the primal is traced (as the flash kernels ask)
+    return _indexer_loss(qi, ki, w, q, k, lse, mask, chunk, remat.kept("dsa_index_grads"))

@@ -5,7 +5,10 @@ indexer's own loss, every gradient leaf and which loss reaches which
 parameter; the selection (``ops/sparse_select``) against ``lax.top_k`` on
 short rows, ties and chunks; the masked flash kernels, the score kernels and
 the head-summed probabilities in the interpreter against their XLA forms,
-forward and gradients; the eight expert shares that add up to the uncut
+forward and gradients; the indexer's loss's own gradients (made in its
+forward's ONE walk of the pairs, a save site of the block) against
+``jax.grad`` of the plain form, and the launches a training step makes of
+the indexer's kernels; the eight expert shares that add up to the uncut
 layer.  CPU only."""
 
 import functools
@@ -426,3 +429,124 @@ def test_bfloat16_compute_keeps_the_index_scores_the_router_and_the_losses_in_fl
         out = spec.apply(params, batch, train=False)
     assert seen == {"operands": (jnp.bfloat16, jnp.bfloat16, jnp.float32), "scores": jnp.float32}
     assert out["logits"].dtype == out["indexer_loss"].dtype == jnp.float32 and bool(jnp.isfinite(out["indexer_loss"]))
+
+
+# ---- the indexer's loss: ONE walk of the pairs, its gradients made in the forward's and kept ----
+
+
+def _loss_operands(topk: int, chunk: int, dtype, seed: int = 4, l: int = 256):
+    """The loss's operands as the part hands them over: the indexer's own selection of ``topk`` keys a query (its relus
+    make exact zeros: rows that tie there) and the logsumexps of the attention over it."""
+    qi, ki, w = _indexer_operands(seed, l=l, dtype=dtype)
+    q, k, v, _, _ = _attention_operands(seed + 1, 2, l, 4, 2, 1)
+    mask, _ = ss.select(qi, ki, w, topk, chunk)
+    assert bool(jnp.any(ss.index_scores_reference(qi, ki, w, 0) == 0.0))
+    _, lse = attentions.selected_attention_reference(q, jnp.repeat(k, 2, 2), jnp.repeat(v, 2, 2), mask)
+    return (qi, ki, w), (q, k, lse, mask)
+
+
+def _plain_indexer_loss(qi, ki, w, q, k, lse, mask):
+    """The loss with no ``custom_vjp`` and no walk: the XLA score, the XLA head sum, the KL's rows."""
+    b, l, h, _ = q.shape
+    probs = ss.head_summed_probs_reference(q, k, lse.reshape(b, h, l), mask)
+    return jnp.sum(ss.kl_rows(ss.index_scores_reference(qi, ki, w, 0), probs, mask)) / (b * l)
+
+
+def _launches(jaxpr, primitive: str = "pallas_call", into=None) -> dict:
+    """The equations of one primitive in a jaxpr and everything it holds: Pallas launches by the kernel's name."""
+    into = {} if into is None else into
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            name = eqn.params["jaxpr"].debug_info.func_name if primitive == "pallas_call" else primitive
+            into[name] = into.get(name, 0) + 1
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _launches(inner, primitive, into)
+    return into
+
+
+@pytest.fixture(params=["xla", "kernels"])
+def loss_path(request):
+    """``(the operands' type, the comparison's limit, whether the kernels run)``: the XLA forms on float32 operands (a
+    tight comparison), or the Pallas kernels through the interpreter on bfloat16 (they round ds to bfloat16 and return
+    bfloat16 gradients, as the flash kernels do)."""
+    if request.param == "xla":
+        return jnp.float32, 1e-4, False
+    request.getfixturevalue("on_the_kernels")
+    return jnp.bfloat16, 2e-2, True
+
+
+@pytest.mark.parametrize("cotangent", [1.0, 0.0, 0.5])
+@pytest.mark.parametrize("topk", [48, 300], ids=["topk48", "every_row_shorter_than_topk"])
+@pytest.mark.parametrize("chunk", [0, 128], ids=["one_chunk", "two_chunks"])
+def test_the_losss_own_gradients_are_jax_grads_of_the_plain_form(loss_path, chunk, topk, cotangent):
+    dtype, limit, _ = loss_path
+    own, constants = _loss_operands(topk, chunk, dtype)
+    got = jax.jit(jax.grad(lambda *a: cotangent * ss.indexer_loss(*a, *constants, chunk), (0, 1, 2)))(*own)
+    want = jax.jit(jax.grad(lambda *a: _plain_indexer_loss(*a, *constants), (0, 1, 2)))(*own)
+    for name, got_g, want_g in zip(("dqI", "dkI", "dw"), got, want):
+        assert got_g.dtype == want_g.dtype and got_g.shape == want_g.shape, name
+        got_g, want_g = got_g.astype(jnp.float32), cotangent * want_g.astype(jnp.float32)
+        assert float(jnp.max(jnp.abs(want_g))) > 0 or cotangent == 0.0, name
+        if cotangent == 0.0:      # the references' control ``no_indexer_loss``: exactly nothing
+            assert float(jnp.max(jnp.abs(got_g))) == 0.0, name
+        assert float(jnp.max(jnp.abs(got_g - want_g))) <= limit * float(jnp.max(jnp.abs(want_g))), name
+
+
+@pytest.mark.parametrize("chunk", [0, 128], ids=["one_chunk", "two_chunks"])
+def test_the_loss_nobody_differentiates_is_the_differentiated_calls_and_makes_no_gradient(loss_path, chunk):
+    dtype, _, kernels = loss_path
+    own, constants = _loss_operands(48, chunk, dtype)
+    loss = lambda *a: ss.indexer_loss(*a, *constants, chunk)  # noqa: E731
+    alone, (value, _) = jax.jit(loss)(*own), jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(*own)
+    want = _plain_indexer_loss(*own, *constants)
+    assert float(alone) > 0 and abs(float(alone) - float(value)) <= 1e-6 * float(value) and abs(float(value) - float(want)) <= 1e-5 * float(want)
+    # the primal walks without the score's gradient: the score and the head sum, one product of the pairs each
+    primal, both = jax.make_jaxpr(loss)(*own).jaxpr, jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2)))(*own).jaxpr
+    if kernels:
+        assert _launches(primal) == {"_score_kernel": 1, "_probs_kernel": 1}
+        assert _launches(both) == {"_score_kernel": 1, "_probs_kernel": 1, "_score_dq_kernel": 1, "_score_dk_kernel": 1}
+    else:
+        # the score's two einsums and the head sum's one
+        assert _launches(primal, "dot_general") == {"dot_general": 3} and _launches(both, "dot_general")["dot_general"] > 3
+
+
+@pytest.mark.parametrize("chunk", [0, 128], ids=["one_chunk", "two_chunks"])
+def test_a_rematerialised_block_gives_the_same_gradients_with_the_losss_site_kept_and_not(loss_path, chunk, capsys):
+    from elasticdl_tpu.ops import remat
+
+    dtype, _, _ = loss_path
+    own, constants = _loss_operands(48, chunk, dtype)
+    block = lambda *a: ss.indexer_loss(*a, *constants, chunk)  # noqa: E731
+    (site,), _ = remat.trace_sites(block, *own)
+    assert site.name == "dsa_index_grads" and site.nbytes == remat.nbytes(own) and site.work == ss.grads_work(own[0], constants[0]) > 0
+    grads = {
+        keep: jax.jit(jax.value_and_grad(remat.rematerialised(block, keep), (0, 1, 2)))(*own) for keep in ((), ("dsa_index_grads",))
+    }
+    (loss, not_kept), (kept_loss, kept) = grads[()], grads[("dsa_index_grads",)]
+    assert float(loss) == float(kept_loss)
+    for a, b in zip(not_kept, kept):     # the same arithmetic, fused by XLA in two programs
+        assert a.dtype == b.dtype and float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))) <= 1e-5 * float(jnp.max(jnp.abs(b.astype(jnp.float32))))
+    # kept, the block's backward holds the three gradients and walks nothing; not kept, it holds the block's inputs alone
+    capsys.readouterr()
+    for keep, named in (("dsa_index_grads",), 3), ((), 0):
+        jax.ad_checkpoint.print_saved_residuals(remat.rematerialised(block, keep), *own)
+        assert capsys.readouterr().out.count("named 'dsa_index_grads'") == named
+
+
+@pytest.mark.parametrize("blocks,score,probs", [("plain", 2, 1), ("rematerialised_keeping_all", 2, 1), ("rematerialised_keeping_nothing", 4, 2)])
+def test_a_training_step_walks_the_pairs_for_the_loss_once_a_layer(on_the_kernels, blocks, score, probs):
+    """The mechanism's counter, on the CPU: in one training step of the small model, per layer, the score kernel is
+    launched TWICE (the selection, the loss), the head-summed probabilities ONCE and the score's gradient pair once
+    — three, two and one while the loss's backward walked the chunks again.  A rematerialised block that keeps its
+    sites (the chip's) launches the same; one that keeps nothing walks once more in its recomputation, selection and all."""
+    from elasticdl_tpu.ops.embedding import ParallelContext
+
+    layers = 2
+    sa = dict(SA, indexer_head_dim=32, q_chunk_size=128, kv_chunk_size=128)     # inside the score kernels' contract
+    spec = _spec(sa_config=sa, seq_len=256, num_hidden_layers=layers, remat=blocks != "plain")
+    ctx = ParallelContext(remat_keep_bytes=2**40 if blocks == "rematerialised_keeping_all" else 0)
+    params, batch = jax.eval_shape(spec.init, jax.random.key(0)), _batch(l=256)
+    step = jax.value_and_grad(lambda w: spec.loss(spec.apply(w, batch, train=True, ctx=ctx), batch))
+    seen = _launches(jax.make_jaxpr(step)(params).jaxpr)
+    indexer = {name: n for name, n in seen.items() if name.startswith(("_score", "_probs"))}
+    assert indexer == {"_score_kernel": score * layers, "_probs_kernel": probs * layers, "_score_dq_kernel": layers, "_score_dk_kernel": layers}
