@@ -203,7 +203,7 @@ def _locksan_meta(node: ast.Call) -> Tuple[Optional[str], bool, Tuple[str, ...]]
 
 
 #: One-entry memo for :func:`shared_graph`: both v2 passes (and the CLI's
-#: --callgraph/--artifact stats) consume the SAME parsed file set within a
+#: --callgraph view) consume the SAME parsed file set within a
 #: run; rebuilding the graph per consumer tripled the pre-commit cost.
 #: Keyed by the identity of every SourceFile (the cached entry keeps a
 #: strong reference to them, so the ids stay valid while it lives).

@@ -515,7 +515,7 @@ class ThreadMap:
 
 
 #: One-entry memo, keyed on the (memoized) CallGraph identity — the
-#: shared-state pass and the CLI --threadmap/--artifact consumers reuse
+#: shared-state pass and the CLI's --threadmap view reuse
 #: one map per run, like shared_graph.
 _MAP_MEMO: dict = {}
 

@@ -37,9 +37,8 @@ that half, the locksan/racesan pattern:
   (values materialized through parameters, dynamic dispatch).
 
 ``GRAFT_JITSAN_DUMP=<path>`` writes the per-name stats as JSON at
-process exit — ``tools/graftlint.py --artifact`` merges that file into
-the LINT artifact beside the declared budgets; ``tests/test_jitsan.py``
-is what fails when a compile count passes its budget.
+process exit, for a reader of one run; ``tests/test_jitsan.py`` is what
+fails when a compile count passes its budget.
 
 Pure stdlib at import time (jax is imported only inside
 :func:`transfer_guard` when armed): importable by gauge/watch tooling
@@ -221,10 +220,9 @@ def dump_stats(path: Optional[str] = None) -> Optional[str]:
     if not path:
         return None
     payload = stats()
-    # Provenance for consumers (graftlint --artifact): counts are only
-    # meaningful for the code that produced them, and this module cannot
-    # reach git — the wall-clock stamp lets the artifact writer compare
-    # against HEAD's commit time and flag a stale dump.
+    # Provenance for a reader: counts are only meaningful for the code
+    # that produced them, and this module cannot reach git — the
+    # wall-clock stamp can be compared against HEAD's commit time.
     payload["_meta"] = {"utc_s": time.time()}
     from elasticdl_tpu.common import durable
 

@@ -460,6 +460,11 @@ class Worker:
         # (graftlint lock-discipline); nothing blocking ever runs under it.
         self._ckpt_lock = locksan.lock("Worker._ckpt_lock", leaf=True)  # lock-order: leaf
         self._last_ckpt_step = 0  # guarded-by: _ckpt_lock
+        # The newest step whose dense save is KNOWN committed (the manager's
+        # wait returned on the thread that saved it).  NOT the watermark: a
+        # failed group save keeps its watermark on purpose, so only this
+        # says that the job-end save has nothing left to write.
+        self._ckpt_committed_step = 0  # guarded-by: _ckpt_lock
         self.reforms = 0  # elastic mesh re-formations (observability/tests)
         self._training_tasks_done = 0  # training tasks dispatched
         # --profile_dir window (_profile_open_if_due): "" until it opens,
@@ -1271,7 +1276,7 @@ class Worker:
         state = self.state if state is None else state
         # Canonical layout on disk (trainer.host_state): restores must work
         # into a DIFFERENT world size / optimizer_sharding mode.
-        self._ckpt.save(step, self.trainer.host_state(state), wait=wait)
+        self._save_dense(step, self.trainer.host_state(state), wait=wait)
         self.trainer.save_host_stores(self._ckpt.directory, step)
         if wait:
             # Publish LAST: the manifest is the serving watcher's only
@@ -1285,6 +1290,26 @@ class Worker:
         self.master.call(
             "ReportCheckpoint", self._checkpoint_report(step)
         )
+
+    def _save_dense(self, step: int, payload, wait: bool) -> None:
+        """The dense state's save, for every path that writes one.  A save
+        that FAILED stays with Orbax's manager, which raises it again out of
+        the next call of every thread that has not seen it yet — so one torn
+        step would fail the next boundary's save (a new thread each time)
+        and the job-end save with it, and "the next boundary retries" would
+        never hold.  Settle it first: it was logged by the thread it failed
+        on, and this save is the retry."""
+        try:
+            self._ckpt.wait()
+        except Exception:
+            logger.warning(
+                "an earlier checkpoint save failed (logged where it did); "
+                "the save of step %d is the retry", step, exc_info=True,
+            )
+        self._ckpt.save(step, payload, wait=wait)
+        if wait:
+            with self._ckpt_lock:
+                self._ckpt_committed_step = step
 
     def _checkpoint_report(self, step: int) -> dict:
         """The ReportCheckpoint payload: path/step plus the phase snapshot
@@ -1391,7 +1416,7 @@ class Worker:
         def _bg():
             try:
                 with self.phases.phase("checkpoint_bg"):
-                    self._ckpt.save(step, snap, wait=True)
+                    self._save_dense(step, snap, wait=True)
                     if self._rank == 0:
                         # Host-tier PS snapshot: ONE process fans the Save
                         # out to the PS shards (each dumps its own slice);
@@ -3179,15 +3204,26 @@ class Worker:
                 # thread here before entering the final collective save.
                 self._join_ckpt()
                 step = int(self.state.step)
-                # Canonical layout either way: group mode canonicalizes on
-                # device (collective saves stream device arrays), the
-                # single-process path on host.
-                payload = (
-                    self.trainer.snapshot_state(self.state)
-                    if self._group_mode
-                    else self.trainer.host_state(self.state)
-                )
-                self._ckpt.save(step, payload, wait=True)
+                with self._ckpt_lock:
+                    committed = self._ckpt_committed_step == step
+                # A step is written ONCE: when the background save just
+                # joined is KNOWN to have committed this very step (the job
+                # ended on a checkpoint boundary), its dense state is on
+                # disk and a second save of it would only meet the first
+                # one's directories.  A background save that failed kept its
+                # watermark (group policy) but committed nothing, so the
+                # final save below still runs: a completed job ends with a
+                # checkpoint that restores.
+                if not committed:
+                    # Canonical layout either way: group mode canonicalizes
+                    # on device (collective saves stream device arrays), the
+                    # single-process path on host.
+                    payload = (
+                        self.trainer.snapshot_state(self.state)
+                        if self._group_mode
+                        else self.trainer.host_state(self.state)
+                    )
+                    self._save_dense(step, payload, wait=True)
                 if self._rank == 0:
                     # Rank-gated like _maybe_checkpoint: one Save fan-out
                     # per step (plain RPC, not collective — no deadlock

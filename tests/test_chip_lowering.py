@@ -1001,6 +1001,11 @@ MOE_LM_STEP_SHA256 = {
     # (``_short_conv`` / ``_short_conv_l2`` over ``ops/ssm.causal_conv``: as many pads and rsqrts as before, no kernel in this
     # text; the text is 11 lines longer, the counter's sum and output); the four above and ``gpt2_medium``'s are untouched
     ("kimi_linear_48b_a3b_ep32_l5", "job_seq8k_x1_v20480"): "98fb0198e63160b282a73f00f50d094d430255d714e2d300d3d71a21053ffae2",
+    # PINNED in PR 61 at the values its PARENT commit (ead6e50) gives, computed before any other edit of that PR: the two
+    # newest cells had no pin, so a refactor of ``moe_lm`` / ``flash_attention`` / ``sparse_select`` had nothing that said
+    # "unchanged" for them (the windowed attention and the selected attention are the XLA references in this text too)
+    ("trinity_mini_26b_a3b_ep8_l5", "job_seq8k_x1_v25024"): "372ec5300c085754d6aca3eca4ac98cc78cd0b100a5faf8058bc68e3282d8141",
+    ("keye_vl2_30b_a3b_ep8_l5", "job_seq16k_x1_v18992"): "af5e2c59b966aa9627e5c71611aea71dc0bf590a9a01c243b12743400eebb5ef",
 }
 
 

@@ -11,8 +11,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -20,20 +18,14 @@ import numpy as np
 import optax
 import pytest
 
+import lm_family
 from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.models import attentions, moe_lm
-from elasticdl_tpu.models.spec import load_model_spec
 from elasticdl_tpu.ops import eva_attention as eva_ops
-from elasticdl_tpu.ops.embedding import ParallelContext
 from elasticdl_tpu.parallel.mesh import create_mesh
 from elasticdl_tpu.parallel.trainer import Trainer
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_DIR = os.path.join(ROOT, "benchmark")
-if BENCH_DIR not in sys.path:
-    sys.path.insert(0, BENCH_DIR)
-
-import resolve  # noqa: E402
+CONFIG = "evabyte_6b5_tp2_l4"
 
 #: EvaByte's keys at a small size: 4 heads of 16 of which 2 are held, windows of 64 in chunks of 8,
 #: a sequence of three windows and a ragged tail that is not whole chunks.
@@ -48,52 +40,34 @@ BLOCK_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo", "eva_phi", "eva_mu", "ffn_n
 LEAVES = ["tok_emb", "norm_f", "head"] + [f"blocks/{b}/{name}" for b in ("b00", "b01") for name in BLOCK_LEAVES]
 
 
-@functools.lru_cache(maxsize=None)
-def reference():
-    return resolve.load_module(os.path.join(BENCH_DIR, "configs", "evabyte_6b5_tp2_l4_reference.py"))
+def _moved(name, a, noise):
+    """Gains that are not 0, EVA's vectors that are not 0, matrices five times the init's scale."""
+    return a * 5.0 if name.startswith("w") or name in ("head", "tok_emb") else a + 0.3 * noise()
 
 
-def _spec(dtype: str = "float32", **kw):
-    return load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype=dtype, **{**KEYS, **kw})
+_spec = functools.partial(lm_family.spec, KEYS)
+_batch = functools.partial(lm_family.batch, KEYS)
+_weights = functools.partial(lm_family.weights, move=_moved)
+_leaf = lm_family.leaf
 
 
-def _weights(spec, seed: int = 0):
-    """Seeded weights away from the init's symmetries: gains that are not
-    0, EVA's vectors that are not 0, matrices five times the init's scale."""
-    params = spec.init(jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 1), len(jax.tree.leaves(params))))
-    return jax.tree_util.tree_map_with_path(
-        lambda path, a: a * 5.0 if path[-1].key.startswith("w") or path[-1].key in ("head", "tok_emb")
-        else a + 0.3 * jax.random.normal(next(keys), a.shape), params)
-
-
-def _batch(b: int = 2, seed: int = 0, l: int = KEYS["seq_len"]):
-    toks = np.random.default_rng(seed).integers(0, KEYS["vocab_size"], (b, l + 1)).astype(np.int32)
-    return {"tokens": jnp.asarray(toks[:, :-1]), "labels": jnp.asarray(toks[:, 1:])}
-
-
-@functools.lru_cache(maxsize=None)
-def _system_and_reference():
-    spec = _spec()
-    params, batch = _weights(spec), _batch()
-    ref_forward, ref_loss = reference().build(dict(KEYS))
-
+def _system(spec, batch):
+    """``w -> ((loss, gradients), logits)``"""
     def system(w):
         return jax.value_and_grad(lambda w: spec.loss(spec.apply(w, batch, train=True), batch))(w), spec.apply(w, batch)["logits"]
 
-    def plain(w):
-        return jax.value_and_grad(ref_loss)(w, batch["tokens"], batch["labels"]), ref_forward(w, batch["tokens"])
+    return system
 
-    with jax.default_matmul_precision("highest"):  # ONE program a side, which a second worker of the suite finds compiled
-        got, logits = jax.jit(system)(params)
-        want, want_logits = jax.jit(plain)(params)
+
+def _plain(ref, keys, batch):
+    """The same of the plain reference, whose ``build`` gives the forward and the eight heads' loss."""
+    ref_forward, ref_loss = ref.build(dict(keys))
+    return lambda w: (jax.value_and_grad(ref_loss)(w, batch["tokens"], batch["labels"]), ref_forward(w, batch["tokens"]))
+
+
+def _system_and_reference():
+    (got, logits), (want, want_logits) = lm_family.system_and_reference(CONFIG, KEYS, _moved, _system, _plain)
     return got, want, (logits, want_logits)
-
-
-def _leaf(tree, path: str):
-    for key in path.split("/"):
-        tree = tree[key]
-    return tree
 
 
 def test_float32_system_gives_the_references_logits_and_loss():

@@ -129,14 +129,31 @@ def test_vjp_matches_reference_at_length(dtype, tol, l, causal):
         )
 
 
+def _output_and_gradients(attn, cot):
+    """``operands -> (attn's output, the gradients of <output, cot> in each)`` as ONE
+    program: the interpreter's forward is traced and compiled once for both,
+    where a call of its own and a ``jax.grad`` beside it made it twice."""
+    def both(*operands):
+        def loss(*operands):
+            out = attn(*operands)
+            return jnp.vdot(out.astype(jnp.float32), cot), out
+
+        (_, out), grads = jax.value_and_grad(loss, argnums=tuple(range(len(operands))), has_aux=True)(*operands)
+        return out, grads
+
+    return jax.jit(both)
+
+
 @pytest.mark.parametrize("h,d", [(1, 64), (2, 64), (1, 128)], ids=["1x64", "2x64", "1x128"])
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("block,l", [(128, 512), (256, 512), (256, 384)])
+@pytest.mark.parametrize("block,l", [(128, 384), (256, 512), (256, 384)])
 def test_small_blocks_carry_state_across_pairs(monkeypatch, block, l, causal, h, d):
     """Many pairs a head (blocks of 128 / 256): first/last visited pair, the
     skipped ones and the scratch carry, forward and VJP; L = 384 in blocks of
     256 leaves 128 positions of padding in the last block.  With two heads
-    to a block each head carries its own state across the pairs."""
+    to a block each head carries its own state across the pairs.  THREE
+    blocks a side is the least that has a pair that is neither a row's first
+    nor its last (384 in blocks of 128); two whole blocks of 256 are 512."""
     from elasticdl_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(fa, "_BLOCK", block)
@@ -144,15 +161,9 @@ def test_small_blocks_carry_state_across_pairs(monkeypatch, block, l, causal, h,
     monkeypatch.setattr(fa, "_T_BWD", 128)
     q, k, v = _qkv(jnp.float32, b=1, l=l, h=h, d=d, seed=block)
     cot = jax.random.normal(jax.random.key(2), q.shape, jnp.float32)
-
-    def loss(attn):
-        return lambda q, k, v: jnp.vdot(attn(q, k, v), cot)
-
-    flash = lambda q, k, v: fa.flash_attention(q, k, v, causal)  # noqa: E731
-    ref = lambda q, k, v: attention_reference(q, k, v, causal=causal)  # noqa: E731
-    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=2e-5, rtol=2e-5)
-    g_flash = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
-    g_ref = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(q, k, v)
+    out, g_flash = _output_and_gradients(lambda q, k, v: fa.flash_attention(q, k, v, causal), cot)(q, k, v)
+    want, g_ref = _output_and_gradients(lambda q, k, v: attention_reference(q, k, v, causal=causal), cot)(q, k, v)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(gf, gr, atol=5e-5, rtol=5e-5, err_msg=f"d{name}")
 
@@ -255,10 +266,6 @@ def _explicit_masked_softmax(q, k, v, q_rot, k_rot, causal):
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
 
 
-def _rotary_grads(attn, args, cot):
-    return jax.jit(jax.grad(lambda *a: jnp.vdot(attn(*a).astype(jnp.float32), cot), argnums=(0, 1, 2, 3, 4)))(*args)
-
-
 ROTARY = [(2, 64), (4, 64), (4, 32), (1, 128)]
 rotary = pytest.mark.parametrize("h,r", ROTARY, ids=[f"{h}x128+{r}" for h, r in ROTARY])
 
@@ -272,22 +279,22 @@ def test_rotary_part_forward_and_gradients_match_an_explicit_masked_softmax(dtyp
     args = _rotary_inputs(dtype, h=h, r=r, seed=h + r)
     f32 = tuple(x.astype(jnp.float32) for x in args)
     cot = jax.random.normal(jax.random.key(9), args[0].shape, jnp.float32)
-    flash = lambda q, k, v, qr, kr: flash_attention(q, k, v, causal, qr, kr)  # noqa: E731
-    ref = lambda *a: _explicit_masked_softmax(*a, causal)  # noqa: E731
-    np.testing.assert_allclose(np.asarray(flash(*args), np.float32), np.asarray(ref(*f32)), atol=tol, rtol=tol)
-    for got, want, name in zip(_rotary_grads(flash, args, cot), _rotary_grads(ref, f32, cot),
-                               ("dq", "dk", "dv", "dq_rot", "dk_rot")):
+    out, grads = _output_and_gradients(lambda q, k, v, qr, kr: flash_attention(q, k, v, causal, qr, kr), cot)(*args)
+    ref, ref_grads = _output_and_gradients(lambda *a: _explicit_masked_softmax(*a, causal), cot)(*f32)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref), atol=tol, rtol=tol)
+    for got, want, name in zip(grads, ref_grads, ("dq", "dk", "dv", "dq_rot", "dk_rot")):
         assert got.shape == want.shape, name
         np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=tol, rtol=tol, err_msg=name)
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("block,l", [(128, 512), (256, 384)])
+@pytest.mark.parametrize("block,l", [(128, 384), (256, 384)])
 def test_rotary_part_carries_state_across_pairs(monkeypatch, block, l, causal):
     """Many pairs a head, the head a grid axis BEFORE the key blocks: the
     first / last visited pair, the skipped ones, the scratch carry and the
     two heads that share a block of q_rot; L = 384 in blocks of 256 leaves
-    padding in the last block."""
+    padding in the last block, and in blocks of 128 it is the three blocks
+    a side that a pair neither first nor last of its row wants."""
     from elasticdl_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(fa, "_BLOCK", block)
@@ -295,12 +302,11 @@ def test_rotary_part_carries_state_across_pairs(monkeypatch, block, l, causal):
     monkeypatch.setattr(fa, "_T_BWD", 128)
     args = _rotary_inputs(jnp.float32, b=2, l=l, h=4, r=64, seed=block)
     cot = jax.random.normal(jax.random.key(2), args[0].shape, jnp.float32)
-    flash = lambda q, k, v, qr, kr: fa.flash_attention(q, k, v, causal, qr, kr)  # noqa: E731
-    ref = lambda *a: _explicit_masked_softmax(*a, causal)  # noqa: E731
-    np.testing.assert_allclose(flash(*args), ref(*args), atol=2e-5, rtol=2e-5)
-    for got, want, name in zip(_rotary_grads(flash, args, cot), _rotary_grads(ref, args, cot),
-                               ("dq", "dk", "dv", "dq_rot", "dk_rot")):
-        np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5, err_msg=name)
+    out, grads = _output_and_gradients(lambda q, k, v, qr, kr: fa.flash_attention(q, k, v, causal, qr, kr), cot)(*args)
+    want, want_grads = _output_and_gradients(lambda *a: _explicit_masked_softmax(*a, causal), cot)(*args)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    for got, ref, name in zip(grads, want_grads, ("dq", "dk", "dv", "dq_rot", "dk_rot")):
+        np.testing.assert_allclose(got, ref, atol=5e-5, rtol=5e-5, err_msg=name)
 
 
 def test_rotary_part_is_the_dispatchers_reference_too():
@@ -364,7 +370,7 @@ def _window_case(monkeypatch, block, t_fwd, t_bwd):
 
 @pytest.mark.parametrize(
     "block,l,window,h,d",
-    [(128, 512, 128, 2, 64), (128, 640, 384, 1, 128)],
+    [(128, 384, 128, 2, 64), (128, 640, 384, 1, 128)],
     ids=["one_block_back_two_heads_a_group", "three_of_five_blocks"],
 )
 def test_a_window_over_several_blocks_is_the_masked_softmax_and_one_key_either_way_is_not(monkeypatch, block, l, window, h, d):
@@ -373,20 +379,23 @@ def test_a_window_over_several_blocks_is_the_masked_softmax_and_one_key_either_w
     edge (whose last row sees nothing: no NaN), the skipped pairs fetch
     nothing, the state is carried across the visited ones; sub-tiles smaller
     than a block so that a far-edge pair has both an unmasked and a masked
-    piece.  A window of W - 1 or W + 1 keys is a different answer."""
+    piece.  A window of W - 1 or W + 1 keys is a different answer.  (A window
+    of one block wants three blocks of sequence, no more: the last row of
+    blocks then skips a pair, visits the far edge and ends on the diagonal.)"""
     fa = _window_case(monkeypatch, block, block // 2, block // 4)
     q, k, v = _qkv(jnp.float32, b=1, l=l, h=h, d=d, seed=window)
     cot = jax.random.normal(jax.random.key(2), q.shape, jnp.float32)
-    flash = lambda q, k, v: fa.flash_attention(q, k, v, True, window=window)  # noqa: E731
     ref = lambda w: lambda q, k, v: attention_reference(q, k, v, causal=True, window=w)  # noqa: E731
-    out = flash(q, k, v)
-    np.testing.assert_allclose(out, ref(window)(q, k, v), atol=2e-5, rtol=2e-5)
-    for other in (window - 1, window + 1):
-        assert float(jnp.max(jnp.abs(out - ref(other)(q, k, v)))) > 1e-3
-    loss = lambda attn: lambda q, k, v: jnp.vdot(attn(q, k, v), cot)  # noqa: E731
-    g_flash = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
-    g_ref = jax.jit(jax.grad(loss(ref(window)), argnums=(0, 1, 2)))(q, k, v)
-    g_more = jax.jit(jax.grad(loss(ref(window + 1)), argnums=(0, 1, 2)))(q, k, v)
+    out, g_flash = _output_and_gradients(lambda q, k, v: fa.flash_attention(q, k, v, True, window=window), cot)(q, k, v)
+
+    @jax.jit  # the three explicit masks in ONE program
+    def references(q, k, v):
+        return [_output_and_gradients(ref(w), cot)(q, k, v) for w in (window, window - 1, window + 1)]
+
+    (want, g_ref), (fewer, _), (more, g_more) = references(q, k, v)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    for other in (fewer, more):
+        assert float(jnp.max(jnp.abs(out - other))) > 1e-3
     for gf, gr, gm, name in zip(g_flash, g_ref, g_more, "qkv"):
         np.testing.assert_allclose(gf, gr, atol=5e-5, rtol=5e-5, err_msg=f"d{name}")
         assert float(jnp.max(jnp.abs(gf - gm))) > 1e-3, name

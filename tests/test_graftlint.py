@@ -30,6 +30,7 @@ from elasticdl_tpu.analysis.wire_discipline import (
     WireEvolutionPass,
     wire_fingerprint,
 )
+from tools import graftlint
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -1241,20 +1242,34 @@ def test_gauge_discipline_waivable_and_exempts_error_paths():
 
 # ---- the repo-wide gate ----
 
-def test_repo_lints_clean():
-    findings = run_lint(
-        [os.path.join(REPO, "elasticdl_tpu"), os.path.join(REPO, "tools")],
-        rel_to=REPO,
-    )
+@pytest.fixture(scope="module")
+def repo_lint():
+    """ONE analysis of the tree a process (``tools/graftlint.py``'s default
+    roots, every pass): ``(findings, sources, waivers)``.  Every mode of the
+    CLI prints a view of exactly this, so the cases below read the views
+    in-process, and one case keeps the subprocess for the exit codes."""
+    from elasticdl_tpu.analysis import collect_waivers
+    from elasticdl_tpu.analysis.core import run_lint_full
+    roots = [os.path.join(REPO, p) for p in graftlint.DEFAULT_PATHS]
+    findings, sources = run_lint_full(roots, all_passes(), rel_to=REPO)
+    return findings, sources, collect_waivers(sources)
+
+
+def test_repo_lints_clean(repo_lint):
+    findings, _, _ = repo_lint
     assert findings == [], "\n".join(f.render() for f in findings)
 
 
 def test_cli_exits_zero_on_repo_and_one_on_violation(tmp_path):
+    """The ONE case that runs the tool as a process: its exit code, and
+    which stream carries what, are the CLI's contract with the pre-commit
+    hook and with whoever pipes a dump."""
     out = subprocess.run(
-        [sys.executable, "tools/graftlint.py", "elasticdl_tpu", "tools"],
+        [sys.executable, "tools/graftlint.py"],  # the default roots
         cwd=REPO, capture_output=True, text=True, timeout=300,
     )
     assert out.returncode == 0, out.stdout + out.stderr
+    assert out.stdout == "" and "graftlint: 0 finding(s) across" in out.stderr
     bad = tmp_path / "bad.py"
     bad.write_text(
         "import threading\n"
@@ -1266,77 +1281,49 @@ def test_cli_exits_zero_on_repo_and_one_on_violation(tmp_path):
     )
     assert out.returncode == 1
     assert "thread-hygiene" in out.stdout
-
-
-def test_cli_artifact_stamps_counts_and_code_rev(tmp_path):
-    art = tmp_path / "LINT_test.json"
+    # a dump of a tree with a finding: exit 1 still, the finding on stderr, and stdout parseable all the same
     out = subprocess.run(
-        [
-            sys.executable, "tools/graftlint.py", "elasticdl_tpu", "tools",
-            "--artifact", str(art),
-        ],
+        [sys.executable, "tools/graftlint.py", str(bad), "--callgraph"],
         cwd=REPO, capture_output=True, text=True, timeout=300,
     )
-    assert out.returncode == 0, out.stdout + out.stderr
-    rec = json.loads(art.read_text())
-    assert rec["findings"] == 0
-    assert rec["files_scanned"] > 50
-    assert "code_rev" in rec and "rules" in rec
-    assert "command" in rec  # write_artifact's shared stamp
+    assert out.returncode == 1 and "thread-hygiene" in out.stderr
+    assert "lock_edges" in json.loads(out.stdout)
 
 
-def test_cli_json_includes_waiver_inventory():
-    out = subprocess.run(
-        [sys.executable, "tools/graftlint.py", "elasticdl_tpu", "--json"],
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    doc = json.loads(out.stdout)
+def test_cli_json_includes_waiver_inventory(repo_lint):
+    findings, _, waivers = repo_lint
+    doc = json.loads(json.dumps(graftlint.findings_json(findings, waivers), sort_keys=True))
     assert set(doc) == {"findings", "waivers"}
     assert doc["findings"] == []
-    # The repo carries reasoned waivers; each inventory entry is complete.
+    # The repo carries reasoned waivers; each inventory entry is complete,
+    # and names a rule there is (the per-rule counts of the waivers).
     assert len(doc["waivers"]) > 0
+    rules = {p.name for p in all_passes()}
+    assert "shared-state" in rules
     for w in doc["waivers"]:
         assert set(w) == {"path", "line", "rule", "reason"}
-        assert w["reason"]
+        assert w["reason"] and w["rule"] in rules
+    # a finding prints as its fields
+    (finding,) = _lint("import threading\nthreading.Thread(target=print).start()\n", [ThreadHygienePass()])
+    (printed,) = graftlint.findings_json([finding], [])["findings"]
+    assert printed["rule"] == "thread-hygiene" and {"path", "line", "message"} <= set(printed)
 
 
-def test_cli_artifact_has_lock_graph_and_blocking_roots(tmp_path):
-    art = tmp_path / "LINT_test.json"
-    out = subprocess.run(
-        [
-            sys.executable, "tools/graftlint.py", "elasticdl_tpu", "tools",
-            "--artifact", str(art),
-        ],
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    rec = json.loads(art.read_text())
-    assert rec["blocking_roots"]["count"] > 0
-    assert rec["lock_graph"]["locks"] > 10
-    assert rec["lock_graph"]["locksan_wrapped"] > 10
-    # The one statically visible nesting: GetGroupTask -> GetTask.
-    assert [
-        "elasticdl_tpu.master.servicer:MasterServicer._group_lock",
-        "elasticdl_tpu.master.servicer:MasterServicer._lock",
-    ] in rec["lock_graph"]["edges"]
-    assert "Worker._ckpt_lock" in " ".join(rec["lock_graph"]["leaf"])
-    assert rec["waivers"] == len(
-        [None] * sum(rec["waivers_by_rule"].values())
-    )
-
-
-def test_cli_callgraph_dump():
-    out = subprocess.run(
-        [sys.executable, "tools/graftlint.py", "elasticdl_tpu", "--callgraph"],
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-    )
-    assert out.returncode == 0, out.stderr
-    doc = json.loads(out.stdout)
+def test_cli_callgraph_dump(repo_lint):
+    doc = json.loads(json.dumps(graftlint.VIEWS["callgraph"](repo_lint[1])))
     assert doc["functions"] > 100
     assert any("Worker._run" in q for q in doc["hot_path_functions"])
     assert "elasticdl_tpu.worker.worker:Worker._ckpt_lock" in doc["locks"]
     assert doc["locks"]["elasticdl_tpu.worker.worker:Worker._ckpt_lock"]["leaf"]
+    # the lock graph and the blocking roots
+    assert len(doc["blocking_roots"]) > 0
+    assert len(doc["locks"]) > 10
+    assert sum(1 for d in doc["locks"].values() if d["locksan"]) > 10
+    # The one statically visible nesting: GetGroupTask -> GetTask.
+    assert [
+        "elasticdl_tpu.master.servicer:MasterServicer._group_lock",
+        "elasticdl_tpu.master.servicer:MasterServicer._lock",
+    ] in [[e["held"], e["acquired"]] for e in doc["lock_edges"]]
 
 
 def test_cli_changed_fails_loud_when_git_unreadable():
@@ -1350,15 +1337,11 @@ def test_cli_changed_fails_loud_when_git_unreadable():
     assert "git" in out.stderr
 
 
-def test_cli_changed_mode_runs(tmp_path):
+def test_cli_changed_mode_runs(capsys):
     # --changed must run and exit cleanly whatever the current diff is;
     # findings it reports are restricted to changed files.
-    out = subprocess.run(
-        [sys.executable, "tools/graftlint.py", "--changed", "--json"],
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-    )
-    assert out.returncode in (0, 1), out.stderr
-    json.loads(out.stdout)  # valid JSON either way
+    assert graftlint.main(["--changed", "--json"]) in (0, 1), capsys.readouterr().err
+    json.loads(capsys.readouterr().out)  # valid JSON either way
 
 
 # ---- thread-hygiene v5: Timer + executor shapes ----
@@ -1816,13 +1799,10 @@ def test_shared_state_full_suite_keeps_waiver_live():
 
 # ---- --threadmap CLI ----
 
-def test_cli_threadmap_dump():
-    out = subprocess.run(
-        [sys.executable, "tools/graftlint.py", "elasticdl_tpu", "--threadmap"],
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-    )
-    assert out.returncode == 0, out.stderr
-    doc = json.loads(out.stdout)
+def test_cli_threadmap_dump(repo_lint):
+    from collections import Counter
+
+    doc = json.loads(json.dumps(graftlint.VIEWS["threadmap"](repo_lint[1])))
     assert doc["functions_with_role"] > 100
     assert "grpc:MasterServicer" in doc["roles"]
     assert any(
@@ -1830,27 +1810,12 @@ def test_cli_threadmap_dump():
         for q in doc["roles"].get("pool:_prep_fused_host", [])
     )
     assert "thread:heartbeat" in doc["roles"]
-    kinds = {e["kind"] for e in doc["entries"]}
-    assert {"thread", "timer", "pool", "grpc", "main", "annotation"} <= kinds
-
-
-def test_cli_artifact_has_thread_map_stats(tmp_path):
-    art = tmp_path / "LINT_test.json"
-    out = subprocess.run(
-        [
-            sys.executable, "tools/graftlint.py", "elasticdl_tpu", "tools",
-            "--artifact", str(art),
-        ],
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    rec = json.loads(art.read_text())
-    assert rec["metric"] == "lint_findings"
-    tm = rec["thread_map"]
-    assert tm["roles"] > 10 and tm["entries"] > 20
-    assert 0 < tm["functions_with_role"] <= tm["functions_total"]
-    assert tm["entries_by_kind"]["grpc"] >= 15
-    assert "shared-state" in rec["rules"]
+    kinds = Counter(e["kind"] for e in doc["entries"])
+    assert {"thread", "timer", "pool", "grpc", "main", "annotation"} <= set(kinds)
+    # the map's size
+    assert len(doc["roles"]) > 10 and len(doc["entries"]) > 20
+    assert 0 < doc["functions_with_role"] <= doc["functions_total"]
+    assert kinds["grpc"] >= 15
 
 
 def test_shared_state_container_mutation_is_a_write():
@@ -2284,7 +2249,7 @@ def test_shared_state_sees_through_partial_spawn():
     assert "_hits" in findings[0].message
 
 
-# ---- declared_sites (the artifact's static budget table) ----
+# ---- declared_sites (the static budget table) ----
 
 def test_declared_sites_harvest():
     from elasticdl_tpu.analysis.jit_discipline import declared_sites
@@ -2494,16 +2459,8 @@ def test_v7_passes_registered():
     assert RecoveryReadDisciplinePass in kinds
 
 
-def test_cli_durables_dump():
-    out = subprocess.run(
-        [
-            sys.executable, "tools/graftlint.py", "elasticdl_tpu", "tools",
-            "--durables",
-        ],
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-    )
-    assert out.returncode == 0, out.stderr
-    doc = json.loads(out.stdout)
+def test_cli_durables_dump(repo_lint):
+    doc = json.loads(json.dumps(graftlint.VIEWS["durables"](repo_lint[1])))
     assert {
         "JOURNAL_FILENAME", "MANIFEST_NAME", "METRICS_FILENAME",
         "PROGRESS_FILENAME", "REGISTRY_FILENAME",
@@ -2750,16 +2707,8 @@ def test_v8_passes_registered():
     assert WireEvolutionPass in kinds
 
 
-def test_cli_wire_dump():
-    out = subprocess.run(
-        [
-            sys.executable, "tools/graftlint.py", "elasticdl_tpu", "tools",
-            "--wire",
-        ],
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-    )
-    assert out.returncode == 0, out.stderr
-    doc = json.loads(out.stdout)
+def test_cli_wire_dump(repo_lint):
+    doc = json.loads(json.dumps(graftlint.VIEWS["wire"](repo_lint[1])))
     assert doc["protocol_version"] == 1
     methods = doc["methods"]
     assert {"GetTask", "ReportTaskResult", "Heartbeat", "Predict"} <= set(

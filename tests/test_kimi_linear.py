@@ -6,26 +6,20 @@ without a rotary turn; the family's rule and what is refused."""
 
 import dataclasses
 import functools
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lm_family
 from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.models import attentions, linear_attention, moe_lm
 from elasticdl_tpu.models.spec import load_model_spec
 from elasticdl_tpu.parallel.mesh import create_mesh
 from elasticdl_tpu.parallel.trainer import Trainer
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_DIR = os.path.join(ROOT, "benchmark")
-if BENCH_DIR not in sys.path:
-    sys.path.insert(0, BENCH_DIR)
-
-import resolve  # noqa: E402
+CONFIG = "kimi_linear_48b_a3b_ep32_l5"
 
 #: kimi_linear's keys at a small size, in the PUBLISHED spelling: a leading dense layer, two KDA layers and one
 #: latent-attention layer, 4 of 16 experts top-3 and one shared, a sequence of two chunks of 64.
@@ -50,71 +44,26 @@ LEAVES = ["tok_emb", "norm_f", "head"] + [
 ]
 
 
-@functools.lru_cache(maxsize=None)
-def reference():
-    return resolve.load_module(os.path.join(BENCH_DIR, "configs", "kimi_linear_48b_a3b_ep32_l5_reference.py"))
+def _moved(name, a, noise):
+    """Gains that are not 1, matrices five times the init's scale (a trained
+    model's decays and gates are not the init's near-constants)."""
+    if name in ("A_log", "dt_bias", "router_bias") or name.startswith("kda_conv"):
+        return a
+    if name.endswith("norm") or name == "norm_f":
+        return a + 0.3 * noise()
+    return a * 5.0
 
 
-def _spec(dtype: str = "float32", **kw):
-    return load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype=dtype, **{**KEYS, **kw})
+reference = functools.partial(lm_family.reference, CONFIG)
+_spec = functools.partial(lm_family.spec, KEYS)
+_batch = functools.partial(lm_family.batch, KEYS)
+_weights = functools.partial(lm_family.weights, move=_moved)
+_layers, _leaf = lm_family.layers, lm_family.leaf
 
 
-def _weights(spec, seed: int = 0):
-    """Seeded weights away from the init's symmetries: gains that are not 1,
-    matrices five times the init's scale (a trained model's decays and gates
-    are not the init's near-constants)."""
-    params = spec.init(jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 1), len(jax.tree.leaves(params))))
-
-    def moved(path, a):
-        name = path[-1].key
-        if name in ("A_log", "dt_bias", "router_bias") or name.startswith("kda_conv"):
-            return a
-        if name.endswith("norm") or name == "norm_f":
-            return a + 0.3 * jax.random.normal(next(keys), a.shape)
-        return a * 5.0
-
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-def _batch(b: int = 2, seed: int = 0, l: int = KEYS["seq_len"]):
-    toks = np.random.default_rng(seed).integers(0, KEYS["vocab_size"], (b, l + 1)).astype(np.int32)
-    return {"tokens": jnp.asarray(toks[:, :-1]), "labels": jnp.asarray(toks[:, 1:])}
-
-
-def _reference_loss_and_gradients(batch):
-    """``w -> ((loss, (logits, slots)), gradients)`` of the plain reference on
-    ``batch``, compiled: ONE program for every test that asks (a second
-    worker of the suite finds it in the run's compile cache)."""
-    import optax
-
-    forward = reference().build(dict(KEYS))
-
-    def ref_loss(w):
-        z, slots = forward(w, batch["tokens"])
-        return optax.softmax_cross_entropy_with_integer_labels(z, batch["labels"]).mean(), (z, slots)
-
-    return jax.jit(jax.value_and_grad(ref_loss, has_aux=True))
-
-
-@functools.lru_cache(maxsize=None)
 def _system_and_reference():
-    spec = _spec()
-    params, batch = _weights(spec), _batch()
-
-    def system(w):
-        return jax.value_and_grad(lambda w: spec.loss(spec.apply(w, batch, train=True), batch))(w), spec.apply(w, batch)
-
-    with jax.default_matmul_precision("highest"):  # ONE program a side: op by op, three times the seconds for the same bits
-        got, out = jax.jit(system)(params)
-        want = _reference_loss_and_gradients(batch)(params)
+    (got, out), want = lm_family.system_and_reference(CONFIG, KEYS, _moved)
     return got, want, out
-
-
-def _leaf(tree, path: str):
-    for key in path.split("/"):
-        tree = tree[key]
-    return tree
 
 
 def test_float32_system_gives_the_references_logits_loss_slots_and_gradient_in_every_leaf():
@@ -182,7 +131,7 @@ def test_two_adamw_steps_and_the_correction_bias_are_the_references():
     assert decayed == moe_lm._is_decayed(w, moe_lm._NOT_MATRICES)
 
     with jax.default_matmul_precision("highest"):
-        ref_grads = _reference_loss_and_gradients(batch)  # compiled once, for both steps
+        ref_grads = lm_family.reference_program(CONFIG, KEYS, _moved)  # the memo's program: on this batch, compiled already
         for t in (1, 2):
             state, metrics = trainer.train_step(state, trainer.shard_batch({k: np.asarray(v) for k, v in batch.items()}))
             (loss, (_, slots)), grads = ref_grads(w)
@@ -243,10 +192,6 @@ def test_the_expert_shares_add_up_to_the_uncut_layer_with_the_shared_expert_coun
     # and the model's own layer IS that part: the family's builder maps its keys onto these
     ((_, mixer), (_, ffn)) = _layers(_spec())[1]
     assert ffn == experts(4, 4) and isinstance(mixer, linear_attention.KimiDeltaAttention)
-
-
-def _layers(spec):
-    return spec.init.keywords["layers"]
 
 
 def test_latent_attention_without_a_rotary_turn_is_kanana2s_with_theta_irrelevant():
@@ -429,7 +374,7 @@ def test_bfloat16_compute_stays_near_the_float32_reference():
     spec, batch = _spec("bfloat16"), _batch()
     params = _weights(spec)
     logits = jax.jit(lambda w: spec.apply(w, batch)["logits"])(params)
-    want, _ = jax.jit(reference().build(dict(KEYS)))(params, batch["tokens"])
+    want, _ = jax.jit(reference().build(dict(KEYS)))(params, batch["tokens"])  # (not the memo's: this case may run on another worker)
     assert logits.dtype == jnp.float32
     # three layers at five times the init's scale (five read 0.10; nemotron_h's toy reads under 0.05 at its scale)
     assert float(jnp.sqrt(jnp.mean((logits - want) ** 2) / jnp.mean(want ** 2))) < 0.2

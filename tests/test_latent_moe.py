@@ -9,17 +9,17 @@ up to the whole layer.  CPU only."""
 
 from __future__ import annotations
 
+import functools
 import json
 import math
-import os
 import re
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lm_family
 from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.models import attentions, moe_lm
 from elasticdl_tpu.models.spec import load_model_spec
@@ -27,12 +27,9 @@ from elasticdl_tpu.ops import moe
 from elasticdl_tpu.parallel.mesh import create_mesh
 from elasticdl_tpu.parallel.trainer import Trainer
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_DIR = os.path.join(ROOT, "benchmark")
-if BENCH_DIR not in sys.path:
-    sys.path.insert(0, BENCH_DIR)
+from lm_family import ROOT, resolve
 
-import resolve  # noqa: E402
+CONFIG = "kanana2_30b_a3b_ep8_l5"
 
 #: kanana-2's keys at a small size: 16 experts of which 4 are held, top-3.
 KEYS = dict(
@@ -54,7 +51,7 @@ SHAPES = {
 
 @pytest.fixture(scope="module")
 def reference():
-    return resolve.load_module(os.path.join(BENCH_DIR, "configs", "kanana2_30b_a3b_ep8_l5_reference.py"))
+    return lm_family.reference(CONFIG)
 
 
 def _keys(shape: str, **kw):
@@ -62,23 +59,18 @@ def _keys(shape: str, **kw):
 
 
 def _spec(shape: str, dtype: str = "float32", **kw):
-    return load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype=dtype, **_keys(shape, **kw))
+    return lm_family.spec(_keys(shape, **kw), dtype)
 
 
-def _weights(spec, seed: int = 0):
-    """Seeded weights, away from the init's symmetries: gains that are not
-    1, matrices five times the init's scale (a router whose scores are not
-    all 1/2), and a correction bias that is NOT zero and as large as the
-    scores' spread, so that it changes choices."""
-    params = spec.init(jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 1), len(jax.tree.leaves(params))))
-    return jax.tree.map(
-        lambda a: a * 5.0 if a.ndim > 1 else a + 0.2 * jax.random.normal(next(keys), a.shape), params)
+def _moved(name, a, noise):
+    """Gains that are not 1, matrices five times the init's scale (a router
+    whose scores are not all 1/2), and a correction bias that is NOT zero and
+    as large as the scores' spread, so that it changes choices."""
+    return a * 5.0 if a.ndim > 1 else a + 0.2 * noise()
 
 
-def _batch(b: int = 2, seed: int = 0):
-    toks = np.random.default_rng(seed).integers(0, KEYS["vocab_size"], (b, KEYS["seq_len"] + 1)).astype(np.int32)
-    return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+_weights = functools.partial(lm_family.weights, move=_moved)
+_batch = functools.partial(lm_family.batch, KEYS)
 
 
 def _rel(got, want) -> float:
@@ -86,25 +78,13 @@ def _rel(got, want) -> float:
     return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
 
 
-def _system(spec, params, batch):
+def _system(spec, batch):
+    """``w -> ((loss, the training forward's outputs), gradients)``"""
     def total(params):
         out = spec.apply(params, batch, train=True)
         return spec.loss(out, batch), out
 
-    return jax.jit(jax.value_and_grad(total, has_aux=True))(params)
-
-
-def _reference(reference, keys, params, batch):
-    import optax
-
-    forward = reference.build(keys)
-
-    def total(params):
-        logits, slots = forward(params, batch["tokens"])
-        return optax.softmax_cross_entropy_with_integer_labels(logits, batch["labels"]).mean(), (logits, slots)
-
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(total, has_aux=True))(params)
+    return jax.value_and_grad(total, has_aux=True)
 
 
 @pytest.mark.parametrize("shape", sorted(SHAPES))
@@ -114,10 +94,8 @@ def test_float32_system_equals_the_plain_reference(reference, shape):
     experts was sent, to 1e-5 of the largest value.  The bias is seeded
     non-zero: it gets NO gradient on either side (it chooses, it never
     weighs), and the choices it makes are the reference's."""
-    spec = _spec(shape)
-    params, batch = _weights(spec), _batch()
-    (loss, out), grads = _system(spec, params, batch)
-    (ref_loss, (ref_logits, ref_slots)), ref_grads = _reference(reference, _keys(shape), params, batch)
+    ((loss, out), grads), ((ref_loss, (ref_logits, ref_slots)), ref_grads) = lm_family.system_and_reference(
+        CONFIG, KEYS, _moved, _system, **SHAPES[shape])
     assert _rel(out["logits"], ref_logits) <= 1e-5, "logits"
     assert abs(float(loss) - float(ref_loss)) <= 1e-5 * abs(float(ref_loss))
     flat, ref_flat = jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(ref_grads)
@@ -212,7 +190,7 @@ def _parents_expert_ffn(u, choices, weights, w_gate, w_up, w_down):
     return jnp.sum(y * weights[..., None], axis=1).astype(u.dtype), sizes
 
 
-def _layer_inputs(dtype, tokens=256, d=64, f=32, experts=16, k=3, seed=0):
+def _layer_inputs(dtype, tokens=64, d=64, f=32, experts=16, k=3, seed=0):
     rng = np.random.default_rng(seed)
     u = jnp.asarray(rng.standard_normal((tokens, d)), dtype)
     logits = rng.standard_normal((tokens, experts))
@@ -392,7 +370,7 @@ def _afmoe_shares_add_up_to_the_uncut_layer():
     )
     spec_of = lambda **kw: load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype="float32", **{**keys, **kw})  # noqa: E731
     layer_of = lambda spec: spec.init.keywords["layers"][0]  # noqa: E731
-    reference = resolve.load_module(os.path.join(BENCH_DIR, "configs", "trinity_mini_26b_a3b_ep8_l5_reference.py"))
+    reference = lm_family.reference("trinity_mini_26b_a3b_ep8_l5")
     params = _weights(spec_of())
     blk = params["blocks"]["b00"]
     assert blk["w_up"].shape == (16, 32, 24)
@@ -410,18 +388,18 @@ def _afmoe_shares_add_up_to_the_uncut_layer():
         u = moe_lm._rms_norm(h, blk["ffn_norm"], 1e-5)
         return want, want_slots, whole, h, u, moe_lm._gated_mlp(u, blk["ws_gate"], blk["ws_up"], blk["ws_down"])
 
-    def share(lo):  # ... and one a share: its part, through the family's own builder
+    def share(lo):  # ... and the eight shares' parts, each through the family's own builder, in ONE more
         experts = layer_of(spec_of(experts_held=2, first_expert_held=lo))[1][1]
         assert experts.router == moe_lm.Router(16, 3, 2, lo, experts.router.keys) and experts.shared_width == 24
-        return jax.jit(lambda u, cut: experts.apply(u, cut, positions, None, cast))
+        return lambda u, blk: experts.apply(
+            u, {**blk, **{name: blk[name][lo:lo + 2] for name in ("w_gate", "w_up", "w_down")}}, positions, None, cast)
 
+    shares = [share(lo) for lo in range(0, 16, 2)]
     with jax.default_matmul_precision("highest"):
         want, want_slots, whole, h, u, alike = alike_parts(x, blk)
         np.testing.assert_allclose(whole, want, atol=2e-5 * float(jnp.max(jnp.abs(want))))  # the uncut layer IS the reference's
         parts = []
-        for lo in range(0, 16, 2):
-            cut = {**blk, **{name: blk[name][lo:lo + 2] for name in ("w_gate", "w_up", "w_down")}}
-            got, stats = share(lo)(u, cut)
+        for got, stats in jax.jit(lambda u, blk: [part(u, blk) for part in shares])(u, blk):
             np.testing.assert_array_equal(np.asarray(stats["slots"]), np.asarray(want_slots))
             parts.append(got - alike)
         assert sum(float(jnp.abs(p).max()) > 0 for p in parts) >= 6  # (the seeded bias keeps a pair of experts from every token)

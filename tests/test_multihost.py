@@ -869,6 +869,43 @@ def test_group_prep_drained_on_preemption(tmp_path, devices):
     assert isinstance(errors.get("w-b"), WorkerRestartRequired), errors
 
 
+#: Orbax numbers a process's save operations with ONE process-wide counter
+#: (``synchronization.OperationIdGenerator``) and a handler waits for the
+#: directory-creation signal of "the current" one.  A real rank has one
+#: manager; this harness puts two ranks' managers in one process, and where
+#: their saves overlap one handler takes the other's signal for its own and
+#: writes into a temporary directory that its own creation then finds
+#: existing (``FileExistsError``, or a wait for a signal nobody sends: D13's
+#: two in seven red runs and its two 125 s hangs).  One save at a time ACROSS
+#: the emulated ranks keeps the harness to what two processes would do; a
+#: worker's own saves are ordered by the worker (what these cases are about).
+_ONE_ORBAX_OPERATION_A_PROCESS = threading.Lock()
+
+
+def _spied_checkpoint_managers(tmp_path, workers, before_save=None):
+    """A ``CheckpointManager`` a worker under ``tmp_path`` (per-worker
+    directories: two managers racing one directory would test the filesystem,
+    not the worker), each ``save`` recorded as ``(thread name, step)``;
+    ``before_save(w, thread_name, step)`` runs first, outside the lock."""
+    from elasticdl_tpu.common.checkpoint import CheckpointManager
+
+    saves = {w: [] for w in workers}
+    for w, worker in workers.items():
+        worker._ckpt = CheckpointManager(str(tmp_path / f"ckpt_{w}"))
+        orig_save = worker._ckpt.save
+
+        def spy_save(step, state, wait=False, _w=w, _orig=orig_save):
+            name = threading.current_thread().name
+            saves[_w].append((name, int(step)))
+            if before_save is not None:
+                before_save(_w, name, int(step))
+            with _ONE_ORBAX_OPERATION_A_PROCESS:
+                return _orig(step, state, wait=wait)
+
+        worker._ckpt.save = spy_save
+    return saves
+
+
 def test_group_checkpoint_nonblocking(tmp_path, devices):
     """r6 tentpole: the group-mode periodic checkpoint pays only the
     device-side snapshot at the lockstep boundary — the shard write runs on
@@ -876,27 +913,14 @@ def test_group_checkpoint_nonblocking(tmp_path, devices):
     the job-end final save settles any in-flight background save first."""
     path, reader, shards = _shards(tmp_path, n_records=128)
     servicer = MasterServicer(TaskDispatcher(shards))
-    # Per-worker checkpoint dirs: the in-process harness emulates two
-    # processes, and two CheckpointManagers racing one directory would test
-    # the filesystem, not the worker.
+    # 8 steps, a boundary every 3: steps 3 and 6 in the background, and the
+    # job ends OFF a boundary, so step 8 is the final save's alone (a job
+    # that ends ON one is the next case's)
     workers, memberships = _lockstep_pair(
         tmp_path, devices, reader, servicer,
-        training_data=path, checkpoint_steps=2,
+        training_data=path, checkpoint_steps=3,
     )
-    from elasticdl_tpu.common.checkpoint import CheckpointManager
-
-    save_threads = {w: [] for w in workers}
-    for w, worker in workers.items():
-        worker._ckpt = CheckpointManager(str(tmp_path / f"ckpt_{w}"))
-        orig_save = worker._ckpt.save
-
-        def spy_save(step, state, wait=False, _w=w, _orig=orig_save):
-            save_threads[_w].append(
-                (threading.current_thread().name, int(step))
-            )
-            return _orig(step, state, wait=wait)
-
-        worker._ckpt.save = spy_save
+    save_threads = _spied_checkpoint_managers(tmp_path, workers)
 
     results = _run_pair(workers, memberships)
     assert results["w-a"]["tasks_done"] == results["w-b"]["tasks_done"] == 8
@@ -908,16 +932,87 @@ def test_group_checkpoint_nonblocking(tmp_path, devices):
         assert results[w]["phase_times"].get("checkpoint_bg", 0) > 0.0, w
     for w, worker in workers.items():
         names = [n for n, _ in save_threads[w]]
-        assert names, (w, save_threads)
         # every periodic save ran OFF the task loop, on the background
         # checkpoint thread — every rank participates (collective saves)
-        assert any(n.startswith("edl-ckpt") for n in names), (w, names)
+        assert [n.startswith("edl-ckpt") for n in names] == [True, True, False], (w, save_threads)
         # the job-end final save runs ON the worker thread, after joining
-        # the in-flight background save
-        assert not names[-1].startswith("edl-ckpt"), (w, names)
+        # the in-flight background save; every step was written once
+        assert [step for _, step in save_threads[w]] == [3, 6, 8], (w, save_threads)
         # background saves completed durably
-        steps_on_disk = worker._ckpt.all_steps()
-        assert len(steps_on_disk) >= 2, (w, steps_on_disk)
+        assert worker._ckpt.all_steps() == [8, 6, 3], w
+        worker._ckpt.close()
+
+
+@pytest.mark.parametrize("background_save", ["commits", "fails"])
+def test_a_job_that_ends_on_a_checkpoint_boundary_writes_that_step_once_and_ends_restorable(
+    tmp_path, devices, monkeypatch, background_save
+):
+    """D13: the job ends at step 8 while the background save OF step 8 is
+    still open — the order is forced (the save's thread is held until the
+    task loop stands in the job-end join), not slept for.  Where that save
+    commits, the job-end block writes nothing again: one save a step.  Where
+    it FAILS (a group save keeps its watermark, so the watermark says
+    "saved"; Orbax keeps the failure and raises it out of the next call of
+    every thread that has not seen it), the job-end save still runs, is not
+    failed by the old failure, and the job ends with step 8 on disk."""
+    import jax
+
+    path, reader, shards = _shards(tmp_path, n_records=128)
+    servicer = MasterServicer(TaskDispatcher(shards))
+    workers, memberships = _lockstep_pair(
+        tmp_path, devices, reader, servicer,
+        training_data=path, checkpoint_steps=2,
+    )
+    opened = {w: threading.Event() for w in workers}
+    job_end = {w: threading.Event() for w in workers}
+
+    def before_save(w, thread_name, step):
+        if step == 8 and thread_name.startswith("edl-ckpt"):
+            opened[w].set()
+            assert job_end[w].wait(60), "the task loop never reached the job-end join"
+
+    saves = _spied_checkpoint_managers(tmp_path, workers, before_save)
+    for w, worker in workers.items():
+        orig_join = worker._join_ckpt
+
+        def spy_join(timeout=None, _w=w, _worker=worker, _orig=orig_join):
+            with _worker._ckpt_lock:
+                at_job_end = _worker._last_ckpt_step == 8  # the watermark moves AFTER the join that precedes step 8's save
+            if at_job_end:
+                assert opened[_w].wait(60), "step 8's background save never opened"
+                job_end[_w].set()
+            return _orig(timeout)
+
+        worker._join_ckpt = spy_join
+
+    torn = set()  # a rank's step 8 fails ONCE, at its commit (the rename of the temporary directory): the retry goes through
+    rename = os.rename
+
+    def rename_that_tears_step_8_once(src, dst, *args, **kwargs):
+        if str(src).endswith("8.orbax-checkpoint-tmp") and str(src) not in torn:
+            torn.add(str(src))
+            raise OSError(5, "Input/output error", str(src))
+        return rename(src, dst, *args, **kwargs)
+
+    if background_save == "fails":
+        monkeypatch.setattr(os, "rename", rename_that_tears_step_8_once)
+
+    results = _run_pair(workers, memberships)
+    assert results["w-a"]["tasks_done"] == results["w-b"]["tasks_done"] == 8
+    assert len(torn) == (2 if background_save == "fails" else 0)
+    for w, worker in workers.items():
+        in_background = [step for name, step in saves[w] if name.startswith("edl-ckpt")]
+        at_job_end = [step for name, step in saves[w] if not name.startswith("edl-ckpt")]
+        assert in_background == [2, 4, 6, 8], (w, saves)
+        # one save a step where the first committed; the retry, on the task loop's thread, where it did not
+        assert at_job_end == ([] if background_save == "commits" else [8]), (w, saves)
+        assert worker._ckpt.latest_step() == 8, (w, worker._ckpt.all_steps())
+        restored = worker._ckpt.restore(worker.trainer.snapshot_state(worker.state), step=8)
+        assert int(restored.step) == 8
+        assert all(
+            bool((a == b).all())
+            for a, b in zip(jax.tree.leaves(restored.params), jax.tree.leaves(worker.state.params))
+        ), w
         worker._ckpt.close()
 
 

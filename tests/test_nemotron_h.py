@@ -7,13 +7,13 @@ import functools
 import hashlib
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lm_family
 from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.models import attentions, mamba, moe_lm
 from elasticdl_tpu.models.spec import load_model_spec
@@ -21,12 +21,7 @@ from elasticdl_tpu.ops import moe
 from elasticdl_tpu.parallel.mesh import create_mesh
 from elasticdl_tpu.parallel.trainer import Trainer
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_DIR = os.path.join(ROOT, "benchmark")
-if BENCH_DIR not in sys.path:
-    sys.path.insert(0, BENCH_DIR)
-
-import resolve  # noqa: E402
+CONFIG = "nemotron3_super_tp4_ep64_l11"
 
 #: nemotron_h's keys at a small size: a period of the three kinds, 4 of 8 state-space heads (2 of 4 groups),
 #: 2 of 8 query heads on 1 of 2 key/value heads, 4 of 16 experts top-5, a sequence of three chunks.
@@ -51,66 +46,28 @@ LEAVES = ["tok_emb", "norm_f", "head"] + [
 ]
 
 
-@functools.lru_cache(maxsize=None)
-def reference():
-    return resolve.load_module(os.path.join(BENCH_DIR, "configs", "nemotron3_super_tp4_ep64_l11_reference.py"))
+def _moved(name, a, noise):
+    """Gains, biases and ``D`` that are not 0 or 1, matrices five times the
+    init's scale (the writers into the stream, scaled down by 88^-1/2, fifty times)."""
+    if name in ("ssm_out", "wo", "w_down", "ws_down", "w_lat_up"):
+        return a * 50.0
+    if name.startswith("w") or name in ("head", "tok_emb", "router", "ssm_in"):
+        return a * 5.0
+    if name in ("A_log", "dt_bias", "conv_w"):
+        return a
+    return a + 0.3 * noise()
 
 
-def _spec(dtype: str = "float32", **kw):
-    return load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype=dtype, **{**KEYS, **kw})
+reference = functools.partial(lm_family.reference, CONFIG)
+_spec = functools.partial(lm_family.spec, KEYS)
+_batch = functools.partial(lm_family.batch, KEYS)
+_weights = functools.partial(lm_family.weights, move=_moved)
+_leaf = lm_family.leaf
 
 
-def _weights(spec, seed: int = 0):
-    """Seeded weights away from the init's symmetries: gains, biases and
-    ``D`` that are not 0 or 1, matrices five times the init's scale (the
-    writers into the stream, scaled down by 88^-1/2, fifty times)."""
-    params = spec.init(jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 1), len(jax.tree.leaves(params))))
-    writers = ("ssm_out", "wo", "w_down", "ws_down", "w_lat_up")
-
-    def moved(path, a):
-        name = path[-1].key
-        if name in writers:
-            return a * 50.0
-        if name.startswith("w") or name in ("head", "tok_emb", "router", "ssm_in"):
-            return a * 5.0
-        if name in ("A_log", "dt_bias", "conv_w"):
-            return a
-        return a + 0.3 * jax.random.normal(next(keys), a.shape)
-
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-def _batch(b: int = 2, seed: int = 0, l: int = KEYS["seq_len"]):
-    toks = np.random.default_rng(seed).integers(0, KEYS["vocab_size"], (b, l + 1)).astype(np.int32)
-    return {"tokens": jnp.asarray(toks[:, :-1]), "labels": jnp.asarray(toks[:, 1:])}
-
-
-@functools.lru_cache(maxsize=None)
 def _system_and_reference():
-    import optax
-
-    spec = _spec()
-    params, batch = _weights(spec), _batch()
-    forward = reference().build(dict(KEYS))
-
-    def ref_loss(w):
-        z, slots = forward(w, batch["tokens"])
-        return optax.softmax_cross_entropy_with_integer_labels(z, batch["labels"]).mean(), (z, slots)
-
-    def system(w):
-        return jax.value_and_grad(lambda w: spec.loss(spec.apply(w, batch, train=True), batch))(w), spec.apply(w, batch)
-
-    with jax.default_matmul_precision("highest"):  # ONE program a side: op by op, three times the seconds for the same bits
-        got, out = jax.jit(system)(params)
-        want = jax.jit(jax.value_and_grad(ref_loss, has_aux=True))(params)
+    (got, out), want = lm_family.system_and_reference(CONFIG, KEYS, _moved)
     return got, want, out
-
-
-def _leaf(tree, path: str):
-    for key in path.split("/"):
-        tree = tree[key]
-    return tree
 
 
 def test_float32_system_gives_the_references_logits_loss_and_slots():
@@ -283,7 +240,7 @@ def test_bfloat16_compute_stays_near_the_float32_reference():
     spec, batch = _spec("bfloat16"), _batch()
     params = _weights(spec)
     logits = jax.jit(lambda w: spec.apply(w, batch)["logits"])(params)
-    want, _ = jax.jit(reference().build(dict(KEYS)))(params, batch["tokens"])
+    want, _ = jax.jit(reference().build(dict(KEYS)))(params, batch["tokens"])  # (not the memo's: this case may run on another worker)
     assert logits.dtype == jnp.float32
     assert float(jnp.sqrt(jnp.mean((logits - want) ** 2) / jnp.mean(want ** 2))) < 0.05
 
@@ -375,7 +332,7 @@ TREES = {
 def test_the_older_configurations_build_the_parameter_trees_they_did(config):
     keys = {}
     if config != "defaults":
-        with open(os.path.join(BENCH_DIR, "configs", config + ".json")) as f:
+        with open(os.path.join(lm_family.BENCH_DIR, "configs", config + ".json")) as f:
             keys = json.load(f)["model_params"]
     spec = load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", **keys)
     shapes = jax.eval_shape(spec.init, jax.random.key(0))

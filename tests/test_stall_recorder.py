@@ -200,10 +200,18 @@ class _Harness:
 
     def settled(self):
         """The late gap just reported, settled: one clean gap after it
-        shows the loop back in step (nothing was caught up)."""
-        with self.phases.phase("step_wait", task=self.seq):
-            time.sleep(self.GAP_S)
-        return self.report()
+        shows the loop back in step (nothing was caught up).  Beside busy
+        neighbours the pace the recorder learnt is a little slower than a
+        clean gap, which then counts as CATCHING UP and leaves the late
+        gap held: one more gap, ``CATCH_UP_GAPS`` at most, is the rule's
+        own answer to that (D24).  With nothing held it is one gap."""
+        for _ in range(stall.CATCH_UP_GAPS):
+            with self.phases.phase("step_wait", task=self.seq):
+                time.sleep(self.GAP_S)
+            record = self.report()
+            if record is not None or self.recorder._held is None:
+                break
+        return record
 
     def warm(self, gaps=6):
         self.recorder.start()
@@ -288,31 +296,43 @@ def test_an_unnamed_stall_counts_into_the_closure_and_keeps_its_stack(harness):
     _sleeping_frame()  # in no phase, nothing grew, nobody injected it
     assert harness.report() is None
     record = harness.settled()
-    assert (record["cause"], record["phase"], record["device"]) == ("unnamed", stall.LOOP, "")
+    # Beside busy neighbours the host may take the loop's thread off its core inside the gap, and the recorder is BUILT
+    # to say so (``descheduled``: ``name_cause``'s last two tests read the host's load, not this job): either word is
+    # right here; what the case holds is that no phase and no device took the blame, and that the stack is kept.
+    assert record["cause"] in ("unnamed", "descheduled") and (record["phase"], record["device"]) == (stall.LOOP, "")
     assert record["samples"][0]["phase"] == "" and record["samples"][0]["stack"][0].endswith(":_sleeping_frame")
     counters = harness.recorder.counters()
-    assert counters["stalls"] == 1 and counters["stall_unnamed_s"] == counters["stall_s"] == round(record["lost_s"], 6)
+    assert counters["stalls"] == 1 and counters["stall_s"] == round(record["lost_s"], 6)
+    assert counters["stall_unnamed_s"] == (counters["stall_s"] if record["cause"] == "unnamed" else 0.0)
+
+
+def _one_call_that_keeps_the_gil(n):
+    """``sum(range(n))``: ONE call into C, so no thread of this process runs while it does.
+    Returns the wall and the thread's own CPU seconds of it."""
+    t0, c0 = time.perf_counter(), time.thread_time()
+    sum(range(n))
+    return time.perf_counter() - t0, time.thread_time() - c0
 
 
 def test_a_call_that_keeps_the_gil_shows_as_the_watchdogs_own_lateness_and_a_sleep_does_not(harness, said):
     """``watchdog_late_s``: the loop's thread alone was blocked (the
     watchdog woke on time all through), or the whole interpreter stood
     still (it could not wake: a call that kept the GIL, a frozen process)."""
+    # Sized by the thread's CPU time, the fastest of three: a timing of the wall beside busy neighbours counts the
+    # time the thread was off its core, and a hold sized from it comes out short (D24).  0.8 s of CPU is at least
+    # 0.8 s of wall, whatever the host does meanwhile.  Sized BEFORE the recorder runs: the timings are no gap of its.
+    n = int(2_000_000 * 0.8 / min(_one_call_that_keeps_the_gil(2_000_000)[1] for _ in range(3)))
     harness.warm()
-    t0 = time.perf_counter()
-    sum(range(2_000_000))  # ONE call into C: no thread of this process runs while it does
-    n = int(2_000_000 * 0.8 / (time.perf_counter() - t0))
-    assert harness.report() is None and harness.settled() is None
     with harness.phases.phase("step_wait"):
-        t0 = time.perf_counter()
-        sum(range(n))
-        held = time.perf_counter() - t0
+        held, burned = _one_call_that_keeps_the_gil(n)
         time.sleep(0.05)  # the watchdog wakes now, and samples
     assert held > 0.45 and harness.report() is None
     record = harness.settled()
     # the first sample could be taken only when the call returned, late by all that lay past the limit
     assert record["watchdog_late_s"] == max(s["watchdog_late_s"] for s in record["samples"])
-    assert held - 0.45 < record["watchdog_late_s"] <= record["gap_s"] and record["cpu_loop_s"] > held / 2
+    # ... and the loop's thread BURNED the hold: its CPU seconds over the gap are the call's own (the wall of a
+    # loaded host is longer than they are, so the wall is not what they are held to)
+    assert held - 0.45 < record["watchdog_late_s"] <= record["gap_s"] and record["cpu_loop_s"] > 0.9 * burned > 0.4
     assert "this watchdog overslept" in said[0]
     with harness.phases.phase("step_wait"):
         _sleeping_frame()  # asleep: the GIL is free, and the watchdog wakes ten times a second

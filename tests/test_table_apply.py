@@ -9,9 +9,6 @@ benchmark metric files that read the new scope and counter."""
 from __future__ import annotations
 
 import dataclasses
-import os
-import re
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -250,57 +247,5 @@ def test_the_hand_over_is_one_mosaic_call_and_no_table_shaped_cotangent(devices,
     assert "input_output_aliases=()" in plain
 
 
-# ---------------------------------------------------------------------------
-# The benchmark's four new metrics: data files beside the ones that were there.
-# ---------------------------------------------------------------------------
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ONE_CHIP = ["deepfm_job", "deepfm_job_zipf"]
-TABLE_APPLY = {
-    "table_apply_ms_step.ex": ONE_CHIP, "table_apply_ms_step.ex4": ["deepfm_x4_job"],
-    "table_apply_fused_pct.ex": ONE_CHIP, "table_apply_fused_pct.ex4": ["deepfm_x4_job"],
-}
-
-
-@pytest.mark.parametrize("name", sorted(TABLE_APPLY))
-def test_a_table_apply_metric_reads_the_kernels_scope_or_counters(name):
-    bench_dir = os.path.join(ROOT, "benchmark")
-    if bench_dir not in sys.path:
-        sys.path.insert(0, bench_dir)
-    import resolve
-
-    bench = resolve.Bench(ROOT)
-    spec = bench.metric_file(name)
-    (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
-    assert all(cell in entry["workloads"] for cell in TABLE_APPLY[name])
-    for key in ("unit", "layer", "moves", "better", "source"):
-        assert spec[key] == entry[key], key
-    assert entry["moves"] == "examples_per_s_chip"
-    assert callable(bench.reader(spec["reader"]).read)
-    if name.startswith("table_apply_ms_step"):
-        assert entry["layer"] == "trainer"
-        assert spec["params"] == {
-            "module": "jit_local_scan", "on": "scope", "pattern": r"\btable_apply\b",
-        }
-        # the scope as the compiled step spells it (tests/test_chip_lowering.py)
-        pattern = spec["params"]["pattern"]
-        assert re.search(pattern, "jit(local_scan)/while/body/closed_call/table_apply/pallas_call")
-        assert not re.search(pattern, "jit(local_scan)/while/body/closed_call/table_grad/sort")
-        assert not re.search(
-            bench.metric_file("table_grad_ms_step.ex")["params"]["pattern"],
-            "jit(local_scan)/while/body/closed_call/table_apply/pallas_call",
-        )
-    else:
-        from elasticdl_tpu.worker.worker import COUNTER_GAUGES, STEP_COUNTERS
-
-        params = spec["params"]
-        assert entry["layer"] == "ops"
-        assert params["scale"] == 100
-        assert (params["counter"], params["over"]) == ("table_grad_rows_fused", "table_grad_rows")
-        for counter in (params["counter"], params["over"]):
-            assert counter in STEP_COUNTERS and counter in COUNTER_GAUGES
-        assert COUNTER_GAUGES[params["counter"]][0] == "edl_table_grad_rows_fused_total"
-        # a program without the counter (the parent commit) reports nothing
-        old = {"window": {"ts": [0.0, 1e12]}, "config": {"name": "x"},
-               "traffic": {"name": "y"}, "chips": 1}
-        assert bench.reader(spec["reader"]).read(old, params) is None
+# The benchmark's four metrics of this kernel (its scope, its counters) are held with the table gradient's, by what they
+# report and in which cells, in ``tests/test_deepfm_zipf_cell.py`` (D25: no entry is named outside ``tests/benchmark``).

@@ -7,26 +7,20 @@ the family's rule and what is refused.  The window INSIDE the flash kernels is
 ``tests/test_latent_moe.py``'s.  CPU only."""
 
 import functools
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import lm_family
 from elasticdl_tpu.common.config import JobConfig
 from elasticdl_tpu.models import attentions, moe_lm
 from elasticdl_tpu.models.spec import load_model_spec
 from elasticdl_tpu.parallel.mesh import create_mesh
 from elasticdl_tpu.parallel.trainer import Trainer
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_DIR = os.path.join(ROOT, "benchmark")
-if BENCH_DIR not in sys.path:
-    sys.path.insert(0, BENCH_DIR)
-
-import resolve  # noqa: E402
+CONFIG = "trinity_mini_26b_a3b_ep8_l5"
 
 #: afmoe's keys at a small size, in the PUBLISHED spelling: a leading dense layer, two sliding layers around a full
 #: one, 4 query heads over 2 key/value heads, a window of 32 in a sequence of 128, 4 of 16 experts top-3 and one shared.
@@ -47,66 +41,23 @@ LAYERS = [ATTENTION + DENSE, ATTENTION + EXPERTS, ATTENTION + EXPERTS]
 LEAVES = ["tok_emb", "norm_f", "head"] + [f"blocks/b{i:02d}/{name}" for i, names in enumerate(LAYERS) for name in NORMS + names]
 
 
-@functools.lru_cache(maxsize=None)
-def reference():
-    return resolve.load_module(os.path.join(BENCH_DIR, "configs", "trinity_mini_26b_a3b_ep8_l5_reference.py"))
+def _moved(name, a, noise):
+    """Gains that are not 1 (the per-head ones too), matrices five times the init's scale."""
+    if name == "router_bias":
+        return a
+    return a * 5.0 if a.ndim > 1 else a + 0.3 * noise()
 
 
-def _spec(dtype: str = "float32", **kw):
-    return load_model_spec("elasticdl_tpu.models", "moe_lm.model_spec", compute_dtype=dtype, **{**KEYS, **kw})
-
-
-def _layers(spec):
-    return spec.init.keywords["layers"]
-
-
-def _weights(spec, seed: int = 0):
-    """Seeded weights away from the init's symmetries: gains that are not 1
-    (the per-head ones too), matrices five times the init's scale."""
-    params = spec.init(jax.random.key(seed))
-    keys = iter(jax.random.split(jax.random.key(seed + 1), len(jax.tree.leaves(params))))
-
-    def moved(path, a):
-        if path[-1].key == "router_bias":
-            return a
-        return a * 5.0 if a.ndim > 1 else a + 0.3 * jax.random.normal(next(keys), a.shape)
-
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-def _batch(b: int = 2, seed: int = 0, l: int = KEYS["seq_len"]):
-    toks = np.random.default_rng(seed).integers(0, KEYS["vocab_size"], (b, l + 1)).astype(np.int32)
-    return {"tokens": jnp.asarray(toks[:, :-1]), "labels": jnp.asarray(toks[:, 1:])}
-
-
-def _reference_loss_and_gradients(batch, **kw):
-    import optax
-
-    forward = reference().build({**KEYS, **kw})
-
-    def ref_loss(w):
-        z, slots = forward(w, batch["tokens"])
-        return optax.softmax_cross_entropy_with_integer_labels(z, batch["labels"]).mean(), (z, slots)
-
-    return jax.jit(jax.value_and_grad(ref_loss, has_aux=True))
-
-
-def _leaf(tree, path: str):
-    for key in path.split("/"):
-        tree = tree[key]
-    return tree
+reference = functools.partial(lm_family.reference, CONFIG)
+_spec = functools.partial(lm_family.spec, KEYS)
+_batch = functools.partial(lm_family.batch, KEYS)
+_weights = functools.partial(lm_family.weights, move=_moved)
+_layers, _leaf = lm_family.layers, lm_family.leaf
 
 
 def test_float32_system_gives_the_references_logits_loss_slots_and_gradient_in_every_leaf():
-    spec = _spec()
-    params, batch = _weights(spec), _batch()
-
-    def system(w):
-        return jax.value_and_grad(lambda w: spec.loss(spec.apply(w, batch, train=True), batch))(w), spec.apply(w, batch)
-
-    with jax.default_matmul_precision("highest"):
-        (loss, grads), out = jax.jit(system)(params)
-        (want, (want_logits, want_slots)), want_grads = _reference_loss_and_gradients(batch)(params)
+    spec, batch = _spec(), _batch()
+    ((loss, grads), out), ((want, (want_logits, want_slots)), want_grads) = lm_family.system_and_reference(CONFIG, KEYS, _moved)
     logits = out["logits"]
     assert logits.shape == want_logits.shape == (2, KEYS["seq_len"], 96) and logits.dtype == jnp.float32
     assert float(jnp.max(jnp.abs(logits - want_logits))) <= 2e-5 * float(jnp.max(jnp.abs(want_logits)))

@@ -261,8 +261,8 @@ def test_disabled_mode_is_identity(monkeypatch):
 def test_version_skew_roundtrip_real_grpc():
     # The additive-compat proof: a v1-masked worker (no lease batching,
     # no seq ledger, no envelopes) completes a real gRPC job against a
-    # current master — zero violations, zero double-trains.  Same driver
-    # that stamps artifacts/wire_skew.json into the LINT artifact.
+    # current master — zero violations, zero double-trains.  The driver
+    # ``python tools/wire_skew.py`` runs.
     from tools.wire_skew import run_skew
 
     assert os.environ.get("GRAFT_WIRESAN") == "1"  # conftest arms it

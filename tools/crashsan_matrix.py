@@ -29,16 +29,14 @@ class (docs/robustness.md "Durability contracts"):
 Anything else — records that are not a prefix, an unexpected exception,
 silent acceptance of mid-file garbage — is an UNRECOVERED crash point and
 fails the row (exit 1 here; ``tests/test_crashsan.py`` asserts the same
-matrix in-process).  ``tools/graftlint.py --artifact`` copies the
-summary from artifacts/crashsan_matrix.json into the LINT stamp.
+matrix in-process).
 
 Usage:
     python tools/crashsan_matrix.py            # print summary, exit 1 on
                                                # any unrecovered point
-    python tools/crashsan_matrix.py --artifact # also stamp the artifact
 
 tests/test_crashsan.py drives the same scenario functions in-process, so
-the committed artifact and the tier-1 gate exercise one definition.
+this tool and the tier-1 gate exercise one definition.
 """
 
 from __future__ import annotations
@@ -56,8 +54,6 @@ if _REPO_ROOT not in sys.path:
 # The sanitizer must be armed before any scenario runs (crash_at refuses
 # to arm otherwise — a sweep that never crashes proves nothing).
 os.environ.setdefault("GRAFT_CRASHSAN", "1")
-
-ARTIFACT_NAME = "crashsan_matrix.json"
 
 #: pids beyond any live process (default pid_max) — the registry scan's
 #: liveness probe must classify them dead deterministically.
@@ -311,8 +307,7 @@ def run_matrix() -> dict:
     return {"rows": rows, "summary": summary}
 
 
-def main(argv: Optional[List[str]] = None) -> int:
-    argv = sys.argv[1:] if argv is None else argv
+def main() -> int:
     out = run_matrix()
     s = out["summary"]
     for r in out["rows"]:
@@ -324,19 +319,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                 file=sys.stderr,
             )
     print(json.dumps(s, indent=1, sort_keys=True))
-    if "--artifact" in argv:
-        from tools.artifact import code_rev, write_artifact
-
-        write_artifact(
-            {
-                "metric": "crashsan_matrix",
-                "summary": s,
-                "rows": out["rows"],
-                "code_rev": code_rev(),
-            },
-            ARTIFACT_NAME,
-            env_var="CRASHSAN_MATRIX_OUT",
-        )
     return 1 if s["unrecovered"] else 0
 
 

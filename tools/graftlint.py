@@ -9,7 +9,6 @@ Usage:
     python tools/graftlint.py --durables         # dump the v7 durable inventory
     python tools/graftlint.py --wire             # dump the v8 wire inventory
     python tools/graftlint.py --update-wire-lock # regenerate the schema lock
-    python tools/graftlint.py --artifact [PATH]  # stamp LINT artifact
     python tools/graftlint.py --list-rules
 
 Exit code 0 = clean, 1 = findings, 2 = usage/internal error.  Pure stdlib
@@ -35,7 +34,6 @@ import json
 import os
 import subprocess
 import sys
-from collections import Counter
 from typing import List, Optional
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -43,24 +41,6 @@ if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
 
 DEFAULT_PATHS = ("elasticdl_tpu", "tools")
-ARTIFACT_NAME = "LINT_r22.json"
-
-#: jitsan runtime stats (common/jitsan.py dump, GRAFT_JITSAN_DUMP) merged
-#: into the artifact when present: the static tool stays jax-free, so the
-#: measured compile counts come from a jitsan-armed run's dump file.
-JITSAN_STATS_DEFAULT = os.path.join("artifacts", "jitsan_stats.json")
-
-#: crashsan matrix summary (tools/crashsan_matrix.py) merged into the
-#: artifact when present — same stance as the jitsan dump: the static tool
-#: proves the write routing, the matrix proves the crash states recover.
-CRASHSAN_MATRIX_DEFAULT = os.path.join("artifacts", "crashsan_matrix.json")
-
-#: version-skew roundtrip verdict (tools/wire_skew.py) merged into the
-#: artifact when present — same stance again: the static wire rules prove
-#: the field-access grammar, the skew run proves a v1-masked worker
-#: completes a real gRPC job against a current master with zero wire
-#: violations and zero double-trains.
-WIRE_SKEW_DEFAULT = os.path.join("artifacts", "wire_skew.json")
 
 
 def _changed_files(repo: str) -> Optional[List[str]]:
@@ -126,6 +106,34 @@ def _threadmap_dump(sources) -> dict:
     return shared_thread_map(sources).dump()
 
 
+def _durables_dump(sources) -> dict:
+    from elasticdl_tpu.analysis.durability import durables_inventory
+
+    return durables_inventory(sources)
+
+
+def _wire_dump(sources) -> dict:
+    from elasticdl_tpu.analysis.wire_discipline import wire_inventory
+
+    return wire_inventory(sources)
+
+
+#: The dump modes: each a VIEW of the sources one analysis loaded, so a
+#: caller that holds ``run_lint_full``'s result (tests/test_graftlint.py
+#: analyses the tree once a process) reads any of them without another.
+VIEWS = {
+    "callgraph": _callgraph_dump,
+    "threadmap": _threadmap_dump,
+    "durables": _durables_dump,
+    "wire": _wire_dump,
+}
+
+
+def findings_json(findings, waivers) -> dict:
+    """What ``--json`` prints: the findings and the waiver inventory."""
+    return {"findings": [f.__dict__ for f in findings], "waivers": waivers}
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     parser = argparse.ArgumentParser(
         prog="graftlint", description=__doc__,
@@ -170,13 +178,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         help="regenerate artifacts/wire_schema.lock.json from the current "
         "MessageSchema tables (the wire-evolution baseline) and exit — "
         "run it in the SAME diff as any schema change",
-    )
-    parser.add_argument(
-        "--artifact", nargs="?", const="", default=None, metavar="PATH",
-        help="write a LINT artifact (findings + per-rule counts + waiver "
-        "inventory + lock-graph/blocking-root stats + code_rev) via "
-        f"tools/artifact.py; optional explicit path, else "
-        f"artifacts/{ARTIFACT_NAME} (env override LINT_OUT)",
     )
     parser.add_argument(
         "--list-rules", action="store_true", help="list rules and exit"
@@ -265,34 +266,17 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     waivers = collect_waivers(sources, only_paths=only_paths)
 
-    if args.callgraph or args.threadmap or args.durables or args.wire:
+    view = next((name for name in VIEWS if getattr(args, name)), None)
+    if view is not None:
         # Findings still gate the exit code — render them (stderr, so the
         # stdout JSON stays parseable) or a failing dump is undiagnosable.
         for f in findings:
             print(f.render(), file=sys.stderr)
-        if args.callgraph:
-            dump = _callgraph_dump(sources)
-        elif args.threadmap:
-            dump = _threadmap_dump(sources)
-        elif args.wire:
-            from elasticdl_tpu.analysis.wire_discipline import wire_inventory
-
-            dump = wire_inventory(sources)
-        else:
-            from elasticdl_tpu.analysis.durability import durables_inventory
-
-            dump = durables_inventory(sources)
-        print(json.dumps(dump, indent=1, sort_keys=True))
+        print(json.dumps(VIEWS[view](sources), indent=1, sort_keys=True))
         return 1 if findings else 0
 
     if args.as_json:
-        print(json.dumps(
-            {
-                "findings": [f.__dict__ for f in findings],
-                "waivers": waivers,
-            },
-            indent=1, sort_keys=True,
-        ))
+        print(json.dumps(findings_json(findings, waivers), indent=1, sort_keys=True))
     else:
         for f in findings:
             print(f.render())
@@ -303,176 +287,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(
             f"graftlint: {len(findings)} finding(s) across {scope} file(s)",
             file=sys.stderr,
-        )
-
-    if args.artifact is not None:
-        from elasticdl_tpu.analysis.jit_discipline import declared_sites
-        from tools.artifact import code_rev, write_artifact
-
-        by_rule = Counter(f.rule for f in findings)
-        waivers_by_rule = Counter(w["rule"] for w in waivers)
-        cg = _callgraph_dump(sources)
-        tm = _threadmap_dump(sources)
-        # v6 jitsan section: the statically declared name/budget table,
-        # plus the runtime lowering counts when a jitsan-armed run left a
-        # dump (env JITSAN_STATS overrides the default path).  The
-        # budget itself is held live by tests/test_jitsan.py: a compile
-        # count past its declared budget fails there.
-        stats_path = os.environ.get(
-            "JITSAN_STATS", os.path.join(_REPO_ROOT, JITSAN_STATS_DEFAULT)
-        )
-        jitsan_runtime = None
-        jitsan_meta: dict = {}
-        if os.path.exists(stats_path):
-            try:
-                with open(stats_path, encoding="utf-8") as f:
-                    loaded = json.load(f)
-                if isinstance(loaded, dict):
-                    meta = loaded.pop("_meta", None)
-                    jitsan_runtime = loaded
-                    if isinstance(meta, dict):
-                        jitsan_meta = dict(meta)
-            except (OSError, ValueError):
-                pass  # a torn dump must not fail the lint artifact
-        if jitsan_runtime is not None:
-            # Staleness flag: a dump written before the last CODE commit
-            # measured different code — stamp the mismatch rather than
-            # silently certifying old counts as this revision's (the
-            # consumer decides; the honest default is to re-run the
-            # armed suite with GRAFT_JITSAN_DUMP and re-stamp).  The
-            # reference excludes artifacts/-only commits: the stamp
-            # workflow (commit code, refresh dump, commit artifacts)
-            # must not mark its own dump stale — committing artifacts
-            # changes no measured code.
-            dumped_s = jitsan_meta.get("utc_s") or os.path.getmtime(stats_path)
-            try:
-                r = subprocess.run(
-                    ["git", "log", "-1", "--format=%ct", "--",
-                     ".", ":(exclude)artifacts"],
-                    cwd=_REPO_ROOT, capture_output=True, text=True,
-                    timeout=10,
-                )
-                code_s = int(r.stdout.strip()) if r.returncode == 0 else None
-            except Exception:
-                code_s = None
-            jitsan_meta["stale_vs_code"] = (
-                bool(code_s is not None and dumped_s < code_s)
-            )
-        # v7 crashsan section: the matrix driver's summary (crash points
-        # injected / recovered / contract class per scenario) when a run
-        # left one (env CRASHSAN_MATRIX overrides the default path).
-        # tests/test_crashsan.py holds unrecovered at zero, in-process.
-        matrix_path = os.environ.get(
-            "CRASHSAN_MATRIX",
-            os.path.join(_REPO_ROOT, CRASHSAN_MATRIX_DEFAULT),
-        )
-        crashsan_summary = None
-        if os.path.exists(matrix_path):
-            try:
-                with open(matrix_path, encoding="utf-8") as f:
-                    loaded = json.load(f)
-                if isinstance(loaded, dict):
-                    crashsan_summary = loaded.get("summary", loaded)
-            except (OSError, ValueError):
-                pass  # a torn matrix file must not fail the lint artifact
-        # v8 wire section: the static inventory (methods, schemas,
-        # resolved sender/receiver sites) plus the version-skew roundtrip
-        # verdict when a tools/wire_skew.py run left one (env WIRE_SKEW
-        # overrides the default path).  tests/test_wiresan.py's
-        # run_skew holds unknown fields at zero, and the repo-clean run
-        # in tests/test_graftlint.py the finding counts.
-        from elasticdl_tpu.analysis.wire_discipline import wire_inventory
-
-        skew_path = os.environ.get(
-            "WIRE_SKEW", os.path.join(_REPO_ROOT, WIRE_SKEW_DEFAULT)
-        )
-        skew_verdict = None
-        if os.path.exists(skew_path):
-            try:
-                with open(skew_path, encoding="utf-8") as f:
-                    loaded = json.load(f)
-                if isinstance(loaded, dict):
-                    skew_verdict = loaded
-            except (OSError, ValueError):
-                pass  # a torn skew dump must not fail the lint artifact
-        wire_inv = wire_inventory(sources)
-        unknown_fields = (
-            (skew_verdict.get("wiresan") or {}).get("unknown_fields") or {}
-            if skew_verdict else {}
-        )
-        from elasticdl_tpu.analysis.durability import durables_inventory
-
-        write_artifact(
-            {
-                # A stamp for a reader of the tree; what holds the count
-                # at zero is tests/test_graftlint.py's repo-clean run.
-                "metric": "lint_findings",
-                "findings": len(findings),
-                "by_rule": dict(sorted(by_rule.items())),
-                "waivers": len(waivers),
-                "waivers_by_rule": dict(sorted(waivers_by_rule.items())),
-                "files_scanned": len(all_files),
-                "changed_only": bool(args.changed),
-                "rules": sorted(p.name for p in passes),
-                "blocking_roots": {
-                    "count": len(cg["blocking_roots"]),
-                    "functions": cg["blocking_roots"],
-                },
-                "lock_graph": {
-                    "locks": len(cg["locks"]),
-                    "locksan_wrapped": sum(
-                        1 for d in cg["locks"].values() if d["locksan"]
-                    ),
-                    "leaf": sorted(
-                        k for k, d in cg["locks"].items() if d["leaf"]
-                    ),
-                    "edges": [
-                        [e["held"], e["acquired"]] for e in cg["lock_edges"]
-                    ],
-                },
-                "hot_path_functions": len(cg["hot_path_functions"]),
-                "jitsan": {
-                    "declared": declared_sites(sources),
-                    "runtime": jitsan_runtime,
-                    "runtime_meta": jitsan_meta,
-                    "stats_file": (
-                        os.path.relpath(stats_path, _REPO_ROOT)
-                        if jitsan_runtime is not None else None
-                    ),
-                },
-                "durables": durables_inventory(sources),
-                "wire": {
-                    "protocol_version": wire_inv["protocol_version"],
-                    "methods": len(wire_inv["methods"]),
-                    "lock_file": "artifacts/wire_schema.lock.json",
-                    "unknown_total": sum(unknown_fields.values()),
-                    "skew": skew_verdict,
-                    "skew_file": (
-                        os.path.relpath(skew_path, _REPO_ROOT)
-                        if skew_verdict is not None else None
-                    ),
-                },
-                "crashsan": {
-                    "summary": crashsan_summary,
-                    "matrix_file": (
-                        os.path.relpath(matrix_path, _REPO_ROOT)
-                        if crashsan_summary is not None else None
-                    ),
-                },
-                "thread_map": {
-                    "roles": len(tm["roles"]),
-                    "entries": len(tm["entries"]),
-                    "functions_with_role": tm["functions_with_role"],
-                    "functions_total": tm["functions_total"],
-                    "entries_by_kind": dict(sorted(Counter(
-                        e["kind"] for e in tm["entries"]
-                    ).items())),
-                },
-                "code_rev": code_rev(),
-            },
-            ARTIFACT_NAME,
-            env_var="LINT_OUT",
-            path=args.artifact or None,
         )
 
     return 1 if findings else 0

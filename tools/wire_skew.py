@@ -8,12 +8,8 @@ response is stripped to exactly the fields a peer built at wire revision
 ``trace``/``gauge`` envelopes, no ``server_ts_us`` clock stamp.  The
 additive-compat stance ("optional field, no PROTOCOL_VERSION bump",
 r9/r12/r14/r18) is only real if that worker still completes the job with
-ZERO wire violations and ZERO double-trains; this tool proves it and
-stamps the verdict into ``artifacts/wire_skew.json``, which
-``tools/graftlint.py --artifact`` merges into the LINT artifact (env
-``WIRE_SKEW`` overrides the read path there, ``WIRE_SKEW_OUT`` the write
-path here) — the same static-tool/runtime-dump split as the jitsan stats
-and the crashsan matrix.
+ZERO wire violations and ZERO double-trains; this tool proves it
+(``tests/test_wiresan.py`` holds the same ``run_skew`` in tier-1).
 
 Usage:
     python tools/wire_skew.py [--shards N]
@@ -178,20 +174,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         "--shards", type=int, default=8,
         help="training shards the masked worker must complete (default 8)",
     )
-    parser.add_argument(
-        "--out", default=None, metavar="PATH",
-        help="artifact path (default artifacts/wire_skew.json; "
-        "env WIRE_SKEW_OUT overrides)",
-    )
     args = parser.parse_args(argv)
-
-    from tools.artifact import ArtifactRun
-
-    run = ArtifactRun()  # capture code_rev before the run dirties anything
-    verdict = run_skew(args.shards)
-    run.write(verdict, "wire_skew.json", env_var="WIRE_SKEW_OUT",
-              path=args.out)
-    return 0 if verdict["ok"] else 1
+    return 0 if run_skew(args.shards)["ok"] else 1
 
 
 if __name__ == "__main__":
