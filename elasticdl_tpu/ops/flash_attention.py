@@ -113,15 +113,32 @@ its own position — and so are the blocks worth visiting.  These calls are
 a fourth family with kernels of their own (``_masked_fwd_kernel``,
 ``_masked_dq_kernel``, ``_masked_dkv_kernel``; the three older bodies are
 untouched and lower byte for byte as they did): a head a lane group (D =
-128 alone), grid ``(B * H, i, j)`` over blocks of up to 1024 rows that
-DIVIDE L (no padding: a padded key would need a mask row), the mask's
-(i, j) block fetched beside K and V and applied to every sub-tile
-(``_selected``: widen, compare, select — no iota), and a BLOCK SUMMARY
-int32 [B, n, n] — how many keys of block j the queries of block i keep —
-as a scalar-prefetch operand: a grid step whose count is 0 runs no code
-(above the diagonal always: those steps are pinned to the diagonal's
-blocks and fetch nothing; below it wherever the selection left a block
-empty).  A row may keep no key of a visited block, so the forward's
+128 alone), blocks of up to 1024 rows that DIVIDE L (no padding: a padded
+key would need a mask row), the mask's (i, j) block fetched beside K and V
+and applied to every sub-tile (``_selected``: widen, compare, select — no
+iota), and a BLOCK SUMMARY int32 [B, n, n] — how many keys of block j the
+queries of block i keep — as a scalar-prefetch operand: a grid step whose
+count is 0 runs no code (wherever the selection left a block of the
+triangle empty; it is a visited step still).  The GRID holds the pairs a
+causal selection can hold and nothing else (PR 62; ``folded_pair``): the
+lower triangle of n row blocks folds into a rectangle without a hole —
+row r (r + 1 pairs) and then row n - 1 - r (n - r pairs) are the n + 1
+steps of one grid row — so the grid is ``(B * H, ceil(n / 2), n + 1)``,
+136 steps a head for 136 pairs at n = 16 where the square ``(i, j)`` grid
+had 256, of which 120 ran no code and, in the forward and dQ, left each
+row's first blocks to arrive uncovered (they were asked for one EMPTY step
+ahead; now the step before a row's first pair is the last live pair of
+another row).  A row's pairs stay consecutive steps in ascending j, so the
+output block is resident over its row and every sum is taken in the order
+it was: the kernels start a row at ``j == 0`` and write it at ``j == i``,
+the last pair a causal row has.  dK/dV reads the same fold with rows as
+KEY blocks (``_folded_key_pair``: key block n - 1 - r with its r + 1 query
+blocks, then key block r with its n - r; start at ``i == j``, write at
+``i == n - 1``).  Only an odd n has idle steps: the middle row's second
+run, (n + 1) / 2 a head, which stay on the pair before them and fetch
+nothing (n = 1: one live step, one idle).  The ``attention path:`` line
+says ``steps=<grid steps a head>/<pairs a head>``.  A row may keep no key
+of a visited block, so the forward's
 exponentials are taken from 0 while a row's running maximum is still
 ``-inf``, as under a window.  dK/dV takes the scores queries by keys, as
 the mask lies, and contracts the ROWS of both operands for the two sums
@@ -1046,12 +1063,49 @@ def _selected(mask_ref, rt):
     return mask_ref[0, rt, :].astype(jnp.int32) != 0
 
 
-def _masked_fwd_kernel(summary_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_s, l_s, acc_s, *, scale, heads, n):
-    bh, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    rows = q_ref.shape[1]
-    live = summary_ref[(bh // heads * n + i) * n + j] > 0
+def folded_pair(r, c, n: int):
+    """``(i, j, holds_work)``: the pair of a causal triangle of ``n`` row
+    blocks that step ``(r, c)`` of the FOLDED grid ``(ceil(n / 2), n + 1)``
+    visits — the one place that maps a grid step to its pair (the BlockSpecs'
+    index maps and the kernels both call it; integer compares and selects
+    only, on traced scalars or plain ints).  Row ``r``'s ``r + 1`` pairs
+    ``(r, 0) .. (r, r)`` and then row ``n - 1 - r``'s ``n - r`` pairs fill the
+    ``n + 1`` steps of grid row ``r``: every pair ``j <= i`` once, a row's
+    pairs on consecutive steps in ascending ``j``, no step above the
+    diagonal.  Where ``n`` is odd the middle row would come twice: its second
+    run holds no work and stays on the pair before it, ``(r, r)``, so nothing
+    is fetched or written back for it (at ``n`` = 1: one live step, one
+    idle)."""
+    first = c <= r
+    work = first | (n - 1 - r != r)
+    i = jnp.where(first, r, n - 1 - r)
+    j = jnp.where(first, c, jnp.where(work, c - r - 1, r))
+    return i, j, work
 
-    @pl.when(j == 0)
+
+def _folded_key_pair(r, c, n: int):
+    """``(i, j, holds_work)`` of the same fold read for dK/dV, whose rows are
+    KEY blocks ``j`` and whose inner axis is the queries ``i >= j``: a key
+    block has as many pairs as the row that mirrors it (``n - 1 - j``), so
+    grid row ``r`` is key block ``n - 1 - r`` with its ``r + 1`` query blocks
+    and then key block ``r`` with its ``n - r``, each in ascending ``i``."""
+    row, col, work = folded_pair(r, c, n)
+    j = n - 1 - row
+    return j + col, j, work
+
+
+def folded_grid(n: int) -> tuple[int, int]:
+    """The folded grid of a head at ``n`` row blocks: ``n * (n + 1) / 2`` pairs in ``ceil(n / 2) * (n + 1)`` steps."""
+    return (n + 1) // 2, n + 1
+
+
+def _masked_fwd_kernel(summary_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_s, l_s, acc_s, *, scale, heads, n):
+    bh = pl.program_id(0)
+    i, j, work = folded_pair(pl.program_id(1), pl.program_id(2), n)
+    rows = q_ref.shape[1]
+    live = work & (summary_ref[(bh // heads * n + i) * n + j] > 0)
+
+    @pl.when(work & (j == 0))
     def _():
         m_s[...] = jnp.full(m_s.shape, -jnp.inf, jnp.float32)
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
@@ -1074,18 +1128,19 @@ def _masked_fwd_kernel(summary_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_re
             acc_s[rt] = alpha * acc_s[rt] + _dot(p.astype(v.dtype), v, _NN)
             m_s[rt] = m_new
 
-    @pl.when(j == n - 1)
+    @pl.when(work & (j == i))      # the last pair a causal row HAS
     def _():
         o_ref[0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
         lse_ref[0, 0, :] = (m_s[...] + jnp.log(l_s[...]))[:, 0]
 
 
 def _masked_dq_kernel(summary_ref, q_ref, k_ref, v_ref, do_ref, mask_ref, stats_ref, dq_ref, acc_s, *, scale, heads, n):
-    bh, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    bh = pl.program_id(0)
+    i, j, work = folded_pair(pl.program_id(1), pl.program_id(2), n)
     rows = q_ref.shape[1]
-    live = summary_ref[(bh // heads * n + i) * n + j] > 0
+    live = work & (summary_ref[(bh // heads * n + i) * n + j] > 0)
 
-    @pl.when(j == 0)
+    @pl.when(work & (j == 0))
     def _():
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
@@ -1100,7 +1155,7 @@ def _masked_dq_kernel(summary_ref, q_ref, k_ref, v_ref, do_ref, mask_ref, stats_
             dp = _dot(do_ref[0, rt], v, _NT)
             acc_s[rt] += _dot((p * (dp - delta)).astype(k.dtype), k, _NN)
 
-    @pl.when(j == n - 1)
+    @pl.when(work & (j == i))
     def _():
         dq_ref[0] = (acc_s[...] * scale).astype(dq_ref.dtype)
 
@@ -1109,15 +1164,17 @@ _TN = ((0,), (0,))     # a.T @ b: contract both operands' rows
 
 
 def _masked_dkv_kernel(summary_ref, q_ref, k_ref, v_ref, do_ref, mask_ref, stats_ref, dk_ref, dv_ref, dk_s, dv_s, *, scale, heads, n):
-    """Rows are KEYS (block j), the other axis the queries (block i, the
-    grid's last axis).  The scores are taken queries by keys, as the mask
-    lies, and the two products that sum over queries contract the ROWS of
-    both operands: the mask is never transposed."""
-    bh, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    """Rows are KEYS (block j), the other axis the queries (block i >= j,
+    ascending along the grid's last axis: ``_folded_key_pair``).  The scores
+    are taken queries by keys, as the mask lies, and the two products that
+    sum over queries contract the ROWS of both operands: the mask is never
+    transposed."""
+    bh = pl.program_id(0)
+    i, j, work = _folded_key_pair(pl.program_id(1), pl.program_id(2), n)
     rows = q_ref.shape[1]
-    live = summary_ref[(bh // heads * n + i) * n + j] > 0
+    live = work & (summary_ref[(bh // heads * n + i) * n + j] > 0)
 
-    @pl.when(i == 0)
+    @pl.when(work & (i == j))      # the first query block that can keep a key of block j
     def _():
         dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
         dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
@@ -1135,35 +1192,35 @@ def _masked_dkv_kernel(summary_ref, q_ref, k_ref, v_ref, do_ref, mask_ref, stats
             dp = _dot(do, v, _NT)
             dk_s[...] += _dot((p * (dp - delta)).astype(q.dtype), q, _TN)
 
-    @pl.when(i == n - 1)
+    @pl.when(work & (i == n - 1))
     def _():
         dk_ref[0] = (dk_s[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
 class _MaskedPlan:
-    """Grid and BlockSpecs of the three masked calls for one shape: grid
-    ``(B * H, i, j)`` (dK/dV: ``(B * H, j, i)``), a head a lane group; a
-    step above the diagonal — where a causal selection keeps nothing — is
-    pinned to the diagonal's blocks and fetches nothing."""
+    """Grid and BlockSpecs of the three masked calls for one shape: a head
+    a lane group, grid ``(B * H, ceil(n / 2), n + 1)`` — the causal
+    triangle's pairs folded two rows a grid row (``folded_pair``; dK/dV:
+    ``_folded_key_pair``), so no step lies above the diagonal; the idle
+    steps an odd ``n`` leaves stay on the pair before them and fetch
+    nothing."""
 
     def __init__(self, shape):
         b, l, h, d = shape
         self.b, self.h = b, h
         self.rows = masked_rows(l)
         self.n = l // self.rows
-        self.grid = (b * h, self.n, self.n)
+        self.pairs = self.n * (self.n + 1) // 2       # a head's causal pairs: what the grid visits and the cost counts
+        self.grid = (b * h, *folded_grid(self.n))
         self.kernel_args = dict(scale=d ** -0.5, heads=h, n=self.n)
 
     def specs(self, queries_first: bool):
-        h, rows = self.h, self.rows
-        if queries_first:       # grid (bh, i, j)
-            ij = lambda x, y: (x, jnp.minimum(x, y))       # noqa: E731
-        else:                   # grid (bh, j, i)
-            ij = lambda x, y: (jnp.maximum(x, y), x)       # noqa: E731
+        h, rows, n = self.h, self.rows, self.n
+        pair = folded_pair if queries_first else _folded_key_pair
 
         def spec(block, index):
-            return pl.BlockSpec(block, lambda bh, x, y, summary: index(bh, *ij(x, y)), memory_space=pltpu.VMEM)
+            return pl.BlockSpec(block, lambda bh, r, c, summary: index(bh, *pair(r, c, n)[:2]), memory_space=pltpu.VMEM)
 
         return dict(
             q=spec((1, rows, _LANE), lambda bh, i, j: (bh // h, i, bh % h)),
@@ -1173,7 +1230,7 @@ class _MaskedPlan:
         )
 
     def cost(self, matmuls, operands):
-        scores = self.b * self.h * (self.n * (self.n + 1) // 2) * self.rows * self.rows
+        scores = self.b * self.h * self.pairs * self.rows * self.rows
         return pl.CostEstimate(
             flops=2 * matmuls * scores * _LANE, transcendentals=scores,
             bytes_accessed=sum(x.size * x.dtype.itemsize for x in operands),
@@ -1197,7 +1254,7 @@ def _masked_fwd_impl(q, k, v, mask, summary, keep=False):
     b, l, h, d = q.shape
     announce_path(
         PATH_PALLAS_INTERPRET if _use_interpret() else PATH_PALLAS_COMPILED, q, True,
-        (f"backend={jax.default_backend()} " if _use_interpret() else "") + f"mask=int8[{l},{l}] blocks={plan.n}x{plan.n} of {plan.rows} rows",
+        (f"backend={jax.default_backend()} " if _use_interpret() else "") + f"mask=int8[{l},{l}] blocks={plan.n}x{plan.n} of {plan.rows} rows steps={plan.grid[1] * plan.grid[2]}/{plan.pairs}",
     )
     with jax.named_scope("dsa_attn"):
         qk, kk, vk = (x.reshape(b, l, h * d) for x in (q, k, v))

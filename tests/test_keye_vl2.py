@@ -225,15 +225,65 @@ def _attention_operands(seed: int, b: int, l: int, h: int, g: int, topk: int, re
     return q, k, v, ss.select_rows(scores, jnp.minimum(jnp.arange(l) + 1, topk)), keys[4]
 
 
-@pytest.mark.parametrize("l,topk", [(256, 48), (384, 40), (384, 384)])
-def test_the_masked_flash_kernels_are_the_xla_path_forward_and_gradients(l, topk):
-    """One block (256), three blocks of 128 rows (384) with a selection too
-    small to reach every block — rows that select nothing in a visited block,
-    the carried state — and every causal key (the dense limit)."""
-    q, k, v, mask, key = _attention_operands(0, 2, l, 4, 2, topk, recent=(l, topk) == (384, 40))
+@pytest.mark.parametrize("order", ["queries_first", "keys_first"])
+@pytest.mark.parametrize("n", range(1, 18))
+def test_the_folded_grid_visits_every_causal_pair_once_a_row_at_a_time_and_nothing_else(n, order):
+    """The enumeration alone (``fa.folded_pair``; dK/dV's reading of it, ``fa._folded_key_pair``): grid
+    ``(ceil(n / 2), n + 1)``; every pair ``j <= i`` exactly once; a row's pairs (dK/dV: a key block's) on
+    consecutive steps, from its first to its last, ascending; idle steps only in the second run of an odd ``n``'s
+    middle row (``n`` = 1 among them), staying on the pair before."""
+    grid = fa.folded_grid(n)
+    assert grid == ((n + 1) // 2, n + 1)
+    r, c = (x.reshape(-1) for x in np.meshgrid(np.arange(grid[0]), np.arange(grid[1]), indexing="ij"))    # in the grid's order
+    i, j, work = (np.asarray(x) for x in (fa.folded_pair if order == "queries_first" else fa._folded_key_pair)(r, c, n))
+    steps = list(zip(i.tolist(), j.tolist()))
+    visited = [pair for pair, w in zip(steps, work) if w]
+    assert sorted(visited) == [(a, b) for a in range(n) for b in range(a + 1)]
+    row, inner = (i, j) if order == "queries_first" else (j, i)
+    for block in range(n):
+        at = [s for s in range(len(steps)) if work[s] and row[s] == block]
+        want = list(range(block + 1)) if order == "queries_first" else list(range(block, n))
+        assert at == list(range(at[0], at[0] + len(at))) and [int(inner[s]) for s in at] == want, (block, at)
+    idle = [s for s in range(len(steps)) if not work[s]]
+    assert len(idle) == ((n + 1) // 2 if n % 2 else 0)
+    assert idle == list(range(len(steps) - len(idle), len(steps))) and all(steps[s] == steps[s - 1] for s in idle)     # the tail of the middle row's grid row
+    if idle:
+        assert steps[idle[0]] == ((n // 2, n // 2) if order == "queries_first" else (n - 1, n // 2))     # the middle row's last pair
+
+
+@pytest.mark.parametrize("shape,said", [((1, 128, 2, 128), "blocks=1x1 of 128 rows steps=2/1"), ((1, 384, 2, 128), "blocks=3x3 of 128 rows steps=8/6"),
+                                        ((1, 16384, 32, 128), "mask=int8[16384,16384] blocks=16x16 of 1024 rows steps=136/136")])
+def test_the_masked_calls_line_says_the_grids_steps_beside_its_pairs(monkeypatch, shape, said):
+    from elasticdl_tpu.ops import ring_attention
+
+    lines = []
+    monkeypatch.setattr(ring_attention, "_log_once", lines.append)
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    jax.eval_shape(fa.masked_flash_attention, q, q, q, jax.ShapeDtypeStruct((1, shape[1], shape[1]), jnp.int8))      # traced, never run
+    assert len(lines) == 1 and "attention path: pallas-interpret" in lines[0] and lines[0].endswith(said + ")"), lines
+    plan = fa._MaskedPlan(shape)
+    assert plan.grid == (shape[0] * shape[2], *fa.folded_grid(plan.n)) and said.endswith(f"steps={plan.grid[1] * plan.grid[2]}/{plan.pairs}")
+
+
+#: (L, keys a query, rows of a block where the test sets them): one block (256); three blocks of 128 rows (384) with a
+#: selection too small to reach every block, and with every causal key (the dense limit); EVEN n, which the contract
+#: reaches only from L = 2,048 on, at a size the interpreter can run — four and six blocks of 128 rows, recent keys
+#: only, so the far blocks of the late rows are visited steps that run no code — and two blocks of 1,024 rows as the contract cuts them
+MASKED_CASES = [(256, 48, 0), (384, 40, 0), (384, 384, 0), (512, 40, 128), (768, 40, 128), (2048, 64, 0)]
+
+
+@pytest.mark.parametrize("l,topk,rows", MASKED_CASES)
+def test_the_masked_flash_kernels_are_the_xla_path_forward_and_gradients(monkeypatch, l, topk, rows):
+    """Forward and the three gradients against ``selected_attention_reference`` (``MASKED_CASES``): rows that select
+    nothing in a visited block, the carried state, skipped blocks inside the triangle, odd and even ``n``."""
+    if rows:
+        monkeypatch.setattr(fa, "masked_rows", lambda l: rows)
+    recent = topk < l and l > 256
+    b, h = (1, 2) if l > 1024 else (2, 4)
+    q, k, v, mask, key = _attention_operands(0, b, l, h, h // 2, topk, recent=recent)
     wide = lambda t: jnp.repeat(t, 2, axis=2)  # noqa: E731
     (o, lse), (want_o, want_lse) = fa.masked_flash_attention(q, wide(k), wide(v), mask), attentions.selected_attention_reference(q, wide(k), wide(v), mask)
-    assert o.dtype == jnp.bfloat16 and lse.shape == (2 * 4, 1, l)
+    assert o.dtype == jnp.bfloat16 and lse.shape == (b * h, 1, l)
     assert float(jnp.max(jnp.abs(o.astype(jnp.float32) - want_o.astype(jnp.float32)))) <= 2e-2 * float(jnp.max(jnp.abs(want_o.astype(jnp.float32))))
     assert float(jnp.max(jnp.abs(lse - want_lse))) <= 1e-5 * float(jnp.max(jnp.abs(want_lse)))
     cot = jax.random.normal(key, q.shape)
@@ -242,11 +292,12 @@ def test_the_masked_flash_kernels_are_the_xla_path_forward_and_gradients(l, topk
     wants = jax.jit(jax.grad(loss(attentions.selected_attention_reference), (0, 1, 2)))(q, k, v)
     for got_g, want_g in zip(grads, wants):
         assert float(jnp.max(jnp.abs(got_g.astype(jnp.float32) - want_g.astype(jnp.float32)))) <= 2e-2 * float(jnp.max(jnp.abs(want_g.astype(jnp.float32))))
-    if (l, topk) == (384, 40):
-        # a block that holds no selected key is skipped (the last queries' 40 keys are among the latest hundred), a
+    if recent and l < 1024:
+        # blocks that hold no selected key are skipped (the last queries' 40 keys are among the latest hundred), a
         # visited one may hold rows that select nothing in it, and the part's counter says what was multiplied
         summary = fa.block_summary(mask, 128)
-        assert int(jnp.sum(summary[:, 2, 0])) == 0 and int(jnp.sum(summary > 0)) == 2 * 5
+        n = l // 128
+        assert int(jnp.sum(summary[:, n - 1, 0])) == 0 and int(jnp.sum(summary > 0)) == 2 * (2 * n - 1)     # the diagonal and the block before it
         assert bool(jnp.any(jnp.sum(mask[:, 128:256, :128], -1) == 0)) and bool(jnp.any(jnp.sum(mask[:, 128:256, :128], -1) > 0))
         assert float(attentions.selected_attention_reference(q, wide(k), wide(v), mask)[1].max()) < 1e4
 
