@@ -28,6 +28,16 @@ ROOT = os.path.dirname(BENCH_DIR)
 VFIO_DIR = "/dev/vfio"
 CHIPS_FREE_DEADLINE_S = 60.0
 
+#: What the job's environment holds besides the harness's own paths.  The TPU
+#: runtime pins a host buffer for its transfers when a process opens the chip;
+#: at its default size that is most of an open of 5.6-11.1 s which wanders by
+#: seconds from run to run on one machine, at 256 MiB the open is about 2 s
+#: (PERF.md, section 6, PR 63: the noise `setup_s` was refused for).  The cells
+#: move a few MB a step from the host; a cell that moves more than the buffer
+#: in one transfer (a checkpoint's save) sizes it in its own data file: a
+#: configuration's or a traffic mix's ``job_env`` comes after this one.
+JOB_ENV = {"TPU_PREMAPPED_BUFFER_SIZE": str(256 << 20)}
+
 
 class JobFailed(Exception):
     """The job cannot give a measurement (no chip, a dead worker, ...)."""
@@ -134,7 +144,8 @@ def wait_for_chips(groups: list, deadline_s: float) -> float:
 
 class Job:
     def __init__(self, argv: list, work: str, platform: str, cache_dir: str,
-                 vfio_dir: str = VFIO_DIR, chips_deadline_s: float = CHIPS_FREE_DEADLINE_S):
+                 vfio_dir: str = VFIO_DIR, chips_deadline_s: float = CHIPS_FREE_DEADLINE_S,
+                 job_env: dict | None = None):
         self.work = work
         self.metrics_path = os.path.join(work, "metrics", "metrics.jsonl")
         self.master_log = os.path.join(work, "master.log")
@@ -145,6 +156,8 @@ class Job:
         env["JAX_PLATFORMS"] = platform
         env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
         env["EDL_BENCH_PROBE_DIR"] = self.probe_dir
+        env.update(JOB_ENV)
+        env.update(job_env or {})
         env["PYTHONPATH"] = os.pathsep.join(
             [os.path.join(BENCH_DIR, "probe"), ROOT]
             + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])

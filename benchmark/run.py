@@ -168,7 +168,8 @@ def main() -> int:
     profile_dir = os.path.join(work, "profile")
     if args.trace:
         extra["profile_dir"] = profile_dir
-    job = Job(job_argv(config, traffic, data_dir, work, extra), work, platform, CACHE_DIR)
+    job_env = {**config.get("job_env", {}), **traffic.get("job_env", {})}
+    job = Job(job_argv(config, traffic, data_dir, work, extra), work, platform, CACHE_DIR, job_env=job_env)
     warmup = int(traffic["warmup_tasks"])
     try:
         # -- set-up: everything up to the warm-up tasks' last report --

@@ -323,8 +323,6 @@ def add_cell_like(root, name: str, like: str, chips: int, own_config: bool, tag:
         metric, again = models[i % len(models)], i // len(models)
         twin = metric["name"].rsplit(".", 1)[0] + (f"_{again + 1}" if again else "") + f".{tag}"
         described = json.load(open(os.path.join(bdir, "metrics", metric["name"] + ".json")))
-        described.pop("cells", None)  # the two keys a few files keep for tests outside `paths`: a new file has neither
-        described["params"].pop("how", None)
         json.dump(dict(described, name=twin), open(os.path.join(bdir, "metrics", twin + ".json"), "x"))
         spec["per_layer"].append(dict(metric, name=twin, workloads=[name]))
     next(m for m in spec["end_to_end"] if m["name"] == traffic["rate_metric"])["workloads"].append(name)

@@ -1,6 +1,8 @@
 """What PR 26 added to the benchmark: the configuration
 ``deepfm_criteo_tb_x4``, the four-chip cell ``deepfm_x4_job``, its cost
-model, the reader ``op_ms_step`` and the ``.ex4`` metrics.  CPU only."""
+model, the reader ``op_ms_step`` and the ``.ex4`` metrics (what only the
+sharded table has; what the cell reads as the one-chip cells do is under
+their ``.ex`` names since PR 63).  CPU only."""
 
 from __future__ import annotations
 
@@ -26,10 +28,12 @@ TRACE = os.path.join(HERE, "data", "deepfm_two_steps.xplane.pb")
 CELL = "deepfm_x4_job"
 EX4 = [
     "step_ms.ex", "step_roofline_pct.ex4", "device_idle_pct.ex", "host_loop_pct.ex",
-    "prep_wait_pct.ex", "starved_dispatch_pct.ex4", "compiles_in_window.ex4",
-    "hbm_peak_reported_gib.ex4", "task_gap_max_ms.ex", "setup_init_state_s",
+    "prep_wait_pct.ex", "starved_dispatch_pct.ex", "compiles_in_window.ex",
+    "hbm_peak_reported_gib.ex", "task_gap_max_ms.ex", "setup_init_state_s",
     "collective_ms_step.ex4", "route_ms_step.ex4", "optimizer_ms_step.ex4",
     "route_recv_max_pct_mean.ex4",
+    # the table's gradient and update, under the names the one-chip cells read them by since PR 63 (the same scope and counters)
+    "table_grad_ms_step.ex", "table_grad_sweep_pct.ex", "table_apply_ms_step.ex", "table_apply_fused_pct.ex",
 ]
 
 
@@ -187,7 +191,7 @@ def test_rehearsal_runs_the_cells_control_flow_on_four_cpu_devices(tmp_path):
     metrics = result["metrics"]
     assert metrics["setup_init_state_s"]["value"] > 0
     assert 100.0 <= metrics["route_recv_max_pct_mean.ex4"]["value"] <= 400.0
-    for name in ("host_loop_pct.ex", "prep_wait_pct.ex", "starved_dispatch_pct.ex4", "compiles_in_window.ex4", "task_gap_max_ms.ex",
+    for name in ("host_loop_pct.ex", "prep_wait_pct.ex", "starved_dispatch_pct.ex", "compiles_in_window.ex", "task_gap_max_ms.ex",
                  "lease_ms_task.ex"):  # the last joined the cell in PR 39
         assert name in metrics, name
     assert "examples_per_s_chip" not in metrics  # a traced run reports per-layer metrics only

@@ -35,11 +35,21 @@ JOINED = [
     "lm_head_ms_step.tok", "moe_experts_ms_step.tok", "moe_glue_ms_step.tok",
     "remat_kept_pct.tok", "moe_slots_computed_pct.mla", "moe_slots_held_pct.mla",
     "moe_slots_overflow_pct.mla", "expert_mxu_pct.mla", "expert_load_max_pct_mean.moe",
+    # PR 63: the part's four projections and its glue (the norm a head, the rotary turn, the 4 -> 32 repeat) are traced under the
+    # scopes ``trinity_mini_job``'s part has; an own entry may be joined since, and a sixth of the step had no entry
+    "attn_proj_ms_step.ssm", "attn_glue_ms_step.swa",
 ]
-#: exactly TWO (ISSUE 58, "Room")
-OWN = ["dsa_attn_roofline_pct.dsa", "dsa_index_roofline_pct.dsa"]
+#: the two shares of a roofline the cell brought (ISSUE 58, "Room": exactly two were free) ...
+ROOFLINES = {"dsa_attn_roofline_pct.dsa": {"dsa_attn"}, "dsa_index_roofline_pct.dsa": {"dsa_index", "dsa_select"}}
+#: ... and the five that waited for room since PR 58 and landed in PR 63: the four scopes of the mechanism in ms, one entry a
+#: scope (``dsa_attn_ms_step.dsa`` is the selection its roofline share has; ``dsa_index`` + ``dsa_select`` are the other's, apart), and
+#: how much of what the masked kernels multiply the selection keeps
+MS_ENTRIES = {"dsa_index_ms_step.dsa": {"dsa_index"}, "dsa_select_ms_step.dsa": {"dsa_select"}, "dsa_attn_ms_step.dsa": {"dsa_attn"},
+              "dsa_loss_ms_step.dsa": {"dsa_loss"}}
+PAIRS_ENTRY = "dsa_pairs_computed_pct.dsa"
+OWN = [*ROOFLINES, *MS_ENTRIES, PAIRS_ENTRY]
 #: entry -> the scopes of the step it reads; every other scope of the step is a neighbour it must not read
-SCOPE_ENTRIES = {"dsa_attn_roofline_pct.dsa": {"dsa_attn"}, "dsa_index_roofline_pct.dsa": {"dsa_index", "dsa_select"}}
+SCOPE_ENTRIES = {**ROOFLINES, **MS_ENTRIES, "attn_proj_ms_step.ssm": {"attn_proj"}, "attn_glue_ms_step.swa": {"attn_glue"}}
 GROUPS = ("attention", "attention_out", "indexer", "experts", "experts_out", "router", "head", "embedding", "norms")
 CHECKS = sorted([
     "dsa_index_scores", "dsa_selected_differing", "dsa_output", "indexer_loss", "indexer_input_detached", "router_logits", "router_choices_differing",
@@ -98,10 +108,10 @@ def test_the_cell_its_configuration_traffic_rehearsal_and_reference_resolve_by_n
     assert os.path.isfile(os.path.join(BENCH_DIR, "sizing", "keye_vl2_against_reference.py"))
     assert [m["name"] for m in bench.metrics_of(CELL, "end_to_end")] == ["tokens_per_s_chip", "setup_s"]
     assert sorted(m["name"] for m in bench.metrics_of(CELL, "per_layer")) == sorted(JOINED + OWN)
-    assert len(OWN) == 2
-    # NOT joined, each for its reason (PERF.md section 4): no full flash call runs in this cell, there is no shared
-    # expert, and the ``.swa`` scope entries are ``trinity_mini_job``'s own
-    for name in ("flash_attn_ms_step.tok", "flash_roofline_pct.tok", "moe_shared_ms_step.mla", "attn_glue_ms_step.swa", "attn_proj_ms_step.swa"):
+    assert len(OWN) == 7  # two the cell brought (PR 58), five a ``benchmark`` PR added when there was room (PR 63)
+    # NOT joined, each for its reason (PERF.md section 4): no full flash call and no window call runs in this cell, there is no
+    # shared expert, and the glue's share of the bandwidth counts ``trinity_mini_job``'s bytes (its cost model's, a gate among them)
+    for name in ("flash_attn_ms_step.tok", "flash_roofline_pct.tok", "moe_shared_ms_step.mla", "window_attn_ms_step.swa", "attn_glue_hbm_pct.swa"):
         (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
         assert CELL not in entry["workloads"], name
     gen = traffic["generator"]
@@ -236,7 +246,8 @@ def test_keye_vl2_flops_counts_what_its_docstring_says():
 def test_every_metric_the_cell_reports_resolves_to_a_file_and_a_reader(name):
     bench = resolve.Bench(ROOT)
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
-    assert CELL in entry["workloads"] and (name in JOINED) == (entry["workloads"] != [CELL])
+    # a JOINED name is another cell's entry too; an OWN name is this cell's, its list STARTS with the cell and a later cell may join it
+    assert CELL in entry["workloads"] and (entry["workloads"] != [CELL] if name in JOINED else entry["workloads"][0] == CELL)
     spec = bench.metric_file(name)
     assert callable(bench.reader(spec["reader"]).read)
     for key in ("unit", "layer", "moves", "better", "source"):
@@ -245,11 +256,17 @@ def test_every_metric_the_cell_reports_resolves_to_a_file_and_a_reader(name):
         if key in spec.get("params", {}):
             assert spec["params"][key] in _costs(), (name, key)
     if name in OWN:
+        assert (entry["layer"], entry["moves"]) == ("ops", "tokens_per_s_chip")
+    if name in ROOFLINES:
         assert spec["reader"] == "scope_roofline" and spec["unit"] == "%" and name.split(".")[0].endswith("_roofline_pct")
-        assert (entry["layer"], entry["moves"], entry["source"], entry["better"]) == ("ops", "tokens_per_s_chip", "device_trace", "higher")
+        assert (entry["source"], entry["better"]) == ("device_trace", "higher")
+    if name in MS_ENTRIES:
+        (scope,) = MS_ENTRIES[name]
+        assert name == f"{scope}_ms_step.dsa" and (spec["reader"], spec["unit"], entry["source"], entry["better"]) == ("op_ms_step", "ms", "device_trace", "lower")
+        assert spec["params"] == {"module": "jit_local_scan", "on": "scope", "pattern": rf"\b{scope}\b"}
 
 
-def test_the_two_new_entries_are_the_cells_alone_and_the_rate_lists_the_cell():
+def test_the_cells_own_entries_are_the_cells_alone_and_the_rate_lists_the_cell():
     """Membership, by name: nothing here counts the benchmark's entries or
     says where in a list one stands, so a later PR's cell, configuration or
     entry (and the fold of the entries) leaves this file as it is."""
@@ -260,6 +277,48 @@ def test_the_two_new_entries_are_the_cells_alone_and_the_rate_lists_the_cell():
     (rate,) = [m for m in spec["end_to_end"] if m["name"] == "tokens_per_s_chip"]
     assert CELL in rate["workloads"]
     assert [c["name"] for c in spec["configs"]].count(CONFIG) == 1 and [w["name"] for w in spec["workloads"]].count(CELL) == 1
+
+
+def test_the_kept_share_reads_the_selections_two_counters_and_nothing_where_a_program_has_none(monkeypatch):
+    """``dsa_pairs_computed_pct.dsa``: the growth of ``dsa_pairs_needed`` (the
+    pairs the selection keeps, from shapes) over that of
+    ``dsa_pairs_computed`` (the pairs of the (block, block) steps the masked
+    kernels really multiply, from the selection the step made), x 100: how
+    much of what the kernels multiply the selection keeps.  22.06 while
+    every causal block of 1024 x 1024 holds a selected key (31,458,304 of
+    136 x 1,048,576 a head: the grid's fold of PR 62 dropped steps that
+    multiplied nothing and leaves it there); it rises only when a kernel
+    skips a block or gathers.  The pair is among the model's step counters."""
+    import runfiles
+
+    from elasticdl_tpu.models.spec import load_model_spec
+    from elasticdl_tpu.worker import worker
+
+    bench = resolve.Bench(ROOT)
+    spec = bench.metric_file(PAIRS_ENTRY)
+    assert spec["reader"] == "counter_delta" and (spec["unit"], spec["better"], spec["source"], spec["layer"]) == ("%", "higher", "program_counter", "ops")
+    assert spec["params"] == {"counter": "dsa_pairs_needed", "over": "dsa_pairs_computed", "scale": 100}
+    pair = {spec["params"]["counter"], spec["params"]["over"]}
+    counters = lambda config: load_model_spec("elasticdl_tpu.models", config["model_def"], **config["model_params"]).step_counters  # noqa: E731
+    ours = counters(bench.config(CONFIG))
+    assert pair <= set(ours) and all(ours[name] for name in pair)  # each with its gauge's help text
+    assert not pair & set(worker.STEP_COUNTERS) and not pair & set(worker.COUNTER_GAUGES)  # the model's own, not the trainer's
+    assert not pair & set(counters(bench.config("trinity_mini_26b_a3b_ep8_l5")))  # a model that selects no keys counts neither
+
+    def read(records):
+        monkeypatch.setattr(runfiles, "counter_records", lambda ctx: records)
+        return bench.reader("counter_delta").read({}, spec["params"])
+
+    costs = _costs()
+    needed, blocks = 32 * 5 * costs["pairs_selected"], 32 * 5 * 136 * 1024 * 1024  # a step's: 32 heads, five layers; 136 causal blocks a head
+    assert costs["pairs_selected"] == 31458304
+    dense_walk = [{"dsa_pairs_needed": float(i * needed), "dsa_pairs_computed": float(i * blocks), "moe_slots": 3.0 * i} for i in range(1, 5)]
+    assert round(read(dense_walk), 2) == 22.06
+    assert read([dict(r, dsa_pairs_computed=r["dsa_pairs_needed"]) for r in dense_walk]) == 100.0  # a kernel that multiplies the kept pairs alone
+    assert read([dict(r, dsa_pairs_needed=0.0) for r in dense_walk]) == 0.0  # nought is a reading
+    without = [{"moe_slots": 3.0 * i, "compiles": 5.0} for i in range(1, 5)]  # another model's records, or a program before PR 58
+    assert read(without) is None and read(dense_walk[:1]) is None and read([]) is None
+    assert read([dict(r, dsa_pairs_computed=7.0) for r in dense_walk]) is None  # a denominator that did not grow: nothing, not a division
 
 
 @pytest.fixture(scope="module")
@@ -303,6 +362,21 @@ def test_a_scope_entry_reads_its_scopes_as_the_compiled_step_spells_them_and_not
             assert not re.search(params["pattern"], f"jit(local_scan)/jvp({neighbour})/dot_general"), (name, neighbour)
         for scope in wanted:
             assert re.search(params["pattern"], f"jit(local_scan)/transpose(jvp({scope}))/pallas_call")
+    # one entry a scope in ms, and no instruction under two of the four: their sum is the mechanism's time, counted once.
+    # ``dsa_attn_ms_step.dsa`` counts what the scope holds in EVERY pass (on the chip the forward's ``tpu_custom_call`` and the
+    # backward's ``jvp_dsa_attn_`` fusions of a breakdown both): the selection of its roofline share; the other share's is two entries
+    mechanism = set().union(*MS_ENTRIES.values())
+    assert all(len(_scopes_of(op) & mechanism) <= 1 for op in step_op_names)
+    pattern = lambda name: bench.metric_file(name)["params"]["pattern"]  # noqa: E731
+    assert pattern("dsa_attn_ms_step.dsa") == pattern("dsa_attn_roofline_pct.dsa")
+    both = [op for op in step_op_names if re.search(pattern("dsa_index_roofline_pct.dsa"), op)]
+    assert sorted(both) == sorted(op for name in ("dsa_index_ms_step.dsa", "dsa_select_ms_step.dsa") for op in step_op_names if re.search(pattern(name), op))
+    for scope in ("dsa_attn", "dsa_loss"):  # forward, the rematerialised block's repeat and the backward, as the compiled step spells them
+        under = [op for op in step_op_names if scope in _scopes_of(op)]
+        assert any("transpose(" not in op for op in under) and any("rematted_computation" in op for op in under), scope
+    assert any("transpose(jvp(dsa_loss))" in op for op in step_op_names) and any("jvp(dsa_attn)" in op for op in step_op_names)
+    for stranger in ("dsa_attns", "dsa_attn_roofline", "dsa_mask", "dsa_index_grads", "attn", "dsa"):  # a save site's or a longer name is not a scope's
+        assert not any(re.search(pattern(name), f"jit(local_scan)/jvp({stranger})/mul") for name in MS_ENTRIES), stranger
     # the indexer's score products run forward AND in the backward of its loss
     index = [op for op in step_op_names if "dsa_index" in _scopes_of(op)]
     assert any("transpose(" in op for op in index) and any("transpose(" not in op for op in index)

@@ -30,6 +30,7 @@ JOINED = [
     "compiles_in_window.tok", "hbm_peak_reported_gib.tok", "task_gap_max_ms.tok", "lease_ms_task.tok", "mfu_pct.tok",
     "setup_master_s", "setup_index_scan_s", "setup_worker_imports_s", "setup_device_open_s", "setup_init_state_s",
     "setup_worker_build_s", "setup_compile_s", "setup_cache_served_pct", "setup_warmup_s", "setup_unattributed_s",
+    "stalls_in_window.tok", "stall_ms_dispatch.tok", "stall_unnamed_ms_dispatch.tok",  # PR 63: the recorder runs in every worker loop (PR 54)
     "lm_head_ms_step.tok", "moe_experts_ms_step.tok", "moe_glue_ms_step.tok", "flash_attn_ms_step.tok",
     "flash_roofline_pct.mla", "mla_proj_ms_step.mla", "remat_kept_pct.tok", "moe_shared_ms_step.mla",
     "moe_slots_computed_pct.mla", "moe_slots_held_pct.mla", "moe_slots_overflow_pct.mla", "expert_mxu_pct.mla",
@@ -41,7 +42,16 @@ SCOPE_ENTRIES = {
     "kda_glue_ms_step.kda": {"kda_glue"},
     "kda_scan_ms_step.kda": {"kda_scan"},
 }
-OWN = [*SCOPE_ENTRIES, "kda_scan_roofline_pct.kda", "kda_glue_hbm_pct.kda", "kda_chunked_pct.kda"]
+#: a share of the part's (head, position) pairs by a step counter over ``kda_positions``: entry -> the counter.  The second and
+#: the third waited for room in ``per_layer`` since PR 49 and PR 53 and landed in PR 63
+COUNTER_ENTRIES = {
+    "kda_chunked_pct.kda": "kda_positions_chunked",
+    "kda_mask_kernel_pct.kda": "kda_positions_mask_kernel",
+    "kda_conv_kernel_pct.kda": "kda_positions_conv_kernel",
+}
+#: the scope ``kda_mask`` nests under ``kda_scan`` (PR 49; the entry PR 63): a part of ``kda_scan_ms_step.kda``, held by a case of its own
+MASK_ENTRY = "kda_mask_ms_step.kda"
+OWN = [*SCOPE_ENTRIES, "kda_scan_roofline_pct.kda", "kda_glue_hbm_pct.kda", *COUNTER_ENTRIES, MASK_ENTRY]
 CHECKS = sorted([
     "kda_output", "kda_decay", "router_logits", "router_choices_differing", "head_logits", "logits", "adamw_update",
     "grad_kda", "grad_attention", "grad_dense", "grad_experts", "grad_shared", "grad_router", "grad_head",
@@ -107,7 +117,9 @@ def test_the_cell_its_configuration_traffic_rehearsal_and_reference_resolve_by_n
     assert os.path.isfile(os.path.join(BENCH_DIR, "sizing", "kimi_linear_against_reference.py"))
     assert [m["name"] for m in bench.metrics_of(CELL, "end_to_end")] == ["tokens_per_s_chip", "setup_s"]
     assert sorted(m["name"] for m in bench.metrics_of(CELL, "per_layer")) == sorted(JOINED + OWN)
-    assert len(OWN) <= 8  # what a cell with a configuration of its own may bring (PERF.md section 7)
+    # a cell with a configuration of its own may BRING eight (PERF.md section 7: this one brought six, PR 47); a later
+    # ``benchmark`` PR may add what waited for room (PR 63: the three of PR 49 and PR 53)
+    assert len(OWN) == 9
     gen = traffic["generator"]
     assert (gen["kind"], gen["vocab"], gen["seq_len"], gen["container"]) == ("lm_tokens", 20480, 8192, "recordio")
     assert gen["vocab"] == config["model_params"]["vocab_size"] == config["vocab_size"]
@@ -236,7 +248,8 @@ def test_the_share_is_the_arithmetic_the_file_states():
 def test_every_metric_the_cell_reports_resolves_to_a_file_and_a_reader(name):
     bench = resolve.Bench(ROOT)
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == name]
-    assert CELL in entry["workloads"] and (name in JOINED) == (entry["workloads"] != [CELL])
+    # a JOINED name is another cell's entry too; an OWN name is this cell's, its list STARTS with the cell and a later cell may join it
+    assert CELL in entry["workloads"] and (entry["workloads"] != [CELL] if name in JOINED else entry["workloads"][0] == CELL)
     spec = bench.metric_file(name)
     assert callable(bench.reader(spec["reader"]).read)
     for key in ("unit", "layer", "moves", "better", "source"):
@@ -309,6 +322,32 @@ def _reads_its_scope_alone(step_op_names, name):
     assert not any(CELL in m["workloads"] for m in bench.spec["per_layer"] if m["name"].endswith(".ssm"))
 
 
+@on_the_tree_itself
+def test_the_mask_entry_reads_a_proper_part_of_the_scans_scope_in_both_passes_and_no_neighbour(step_op_names):
+    """``kda_mask_ms_step.kda``: everything ``ops/delta_rule._masks_of`` emits,
+    under the scope ``kda_mask`` INSIDE ``kda_scan`` in both passes (the two
+    Mosaic calls on the chip, the cross-sub-block products, the add that
+    joins them): a part of ``kda_scan_ms_step.kda``, never beside it."""
+    bench = resolve.Bench(ROOT)
+    params = bench.metric_file(MASK_ENTRY)["params"]
+    assert params == {"module": "jit_local_scan", "on": "scope", "pattern": r"\bkda_mask\b"}
+    whole = [op for op in step_op_names if op.startswith("jit(")]  # a whole path; XLA's merged instructions also leave tails of paths
+    mask = [op for op in whole if re.search(params["pattern"], op)]
+    scan = [op for op in whole if re.search(bench.metric_file("kda_scan_ms_step.kda")["params"]["pattern"], op)]
+    assert mask and set(mask) < set(scan) and all(_scopes_of(op) == {"kda_scan"} for op in mask)
+    # as the COMPILED step spells it: the forward scan, the backward scan's own forward (jvp inside the transpose) and its
+    # transpose, under the layer's checkpoint and inside the rematerialised repeat
+    forward, backward = [op for op in mask if "transpose(" not in op], [op for op in mask if "transpose(" in op]
+    assert any("jvp(kda_scan)" in op and "checkpoint/kda_mask/" in op for op in forward)
+    assert any("jvp(kda_mask)" in op for op in backward) and any("rematted_computation/kda_mask/" in op for op in backward)
+    assert any(re.search(r"transpose\(jvp\(jvp\(\)\)\)/checkpoint/kda_mask/", op) for op in backward)
+    for spelt in ("jit(local_scan)/jvp(kda_scan)/while/body/checkpoint/kda_mask/pallas_call", "jit(local_scan)/transpose(jvp(kda_mask))/pallas_call"):
+        assert re.search(params["pattern"], spelt)
+    # ... and nothing of a neighbour's, by name: not a plural, not a bare ``mask``, no other scope of the step or of another family
+    for neighbour in ("kda_masks", "mask", "kda_mask_grads", "dsa_mask", *SCOPES, *OTHER_FAMILIES):
+        assert not re.search(params["pattern"], f"jit(local_scan)/jvp({neighbour})/dot_general"), neighbour
+
+
 def test_the_glue_in_ms_is_the_selection_its_share_of_the_bandwidth_has_and_so_for_the_scan():
     bench = resolve.Bench(ROOT)
     for ms_name, share_name, reader in (("kda_glue_ms_step.kda", "kda_glue_hbm_pct.kda", "scope_hbm_roofline"),
@@ -322,20 +361,25 @@ def test_the_glue_in_ms_is_the_selection_its_share_of_the_bandwidth_has_and_so_f
     assert {params["flops"], params["bytes"]} <= set(_costs()) and "kernel" not in json.dumps(params["pattern"])
 
 
-def test_the_chunked_share_reads_the_ops_two_counters_and_nothing_where_a_program_has_none(monkeypatch):
+@pytest.mark.parametrize("name", sorted(COUNTER_ENTRIES))
+def test_a_share_reads_its_counter_over_the_parts_positions_and_nothing_where_a_program_has_none(monkeypatch, name):
     """``kda_chunked_pct.kda``: the growth of ``kda_positions_chunked`` over
     that of ``kda_positions``, x 100: 100 while every call of the op is on
-    its chunked form.  The pair is among the step counters the worker sums
-    and publishes (``ModelSpec.step_counters``)."""
+    its chunked form; ``kda_mask_kernel_pct.kda`` and
+    ``kda_conv_kernel_pct.kda`` likewise for the positions whose
+    same-sub-block masks and whose three convolution chains a Pallas kernel
+    computed (100 on the chip, 0 off a TPU: a silent fall-back to XLA's
+    fusions reads under 100).  Each pair is among the step counters the
+    worker sums and publishes (``ModelSpec.step_counters``)."""
     import runfiles
 
     from elasticdl_tpu.models.spec import load_model_spec
     from elasticdl_tpu.worker import worker
 
     bench = resolve.Bench(ROOT)
-    spec = bench.metric_file("kda_chunked_pct.kda")
-    assert spec["reader"] == "counter_delta" and (spec["better"], spec["source"]) == ("higher", "program_counter")
-    assert spec["params"] == {"counter": "kda_positions_chunked", "over": "kda_positions", "scale": 100}
+    spec, counter = bench.metric_file(name), COUNTER_ENTRIES[name]
+    assert spec["reader"] == "counter_delta" and (spec["unit"], spec["better"], spec["source"], spec["layer"]) == ("%", "higher", "program_counter", "ops")
+    assert spec["params"] == {"counter": counter, "over": "kda_positions", "scale": 100}
     pair = {spec["params"]["counter"], spec["params"]["over"]}
     counters = lambda config: load_model_spec("elasticdl_tpu.models", config["model_def"], **config["model_params"]).step_counters  # noqa: E731
     ours = counters(bench.config(CONFIG))
@@ -348,11 +392,12 @@ def test_the_chunked_share_reads_the_ops_two_counters_and_nothing_where_a_progra
         return bench.reader("counter_delta").read({}, spec["params"])
 
     positions = 8192 * 32 * 4 * 2  # a task's (head, position) pairs: 8192 tokens, 32 heads, four KDA layers, two steps
-    chunked = [{"kda_positions": float(i * positions), "kda_positions_chunked": float(i * positions), "moe_slots": 3.0 * i} for i in range(1, 5)]
-    assert read(chunked) == 100.0
-    assert read([dict(r, kda_positions_chunked=0.0) for r in chunked]) == 0.0  # every call stepwise: nought is a reading
+    every = [{"kda_positions": float(i * positions), counter: float(i * positions), "moe_slots": 3.0 * i} for i in range(1, 5)]
+    assert read(every) == 100.0
+    assert read([dict(r, **{counter: r[counter] / 4}) for r in every]) == 25.0  # one layer of four on the path: a share
+    assert read([dict(r, **{counter: 0.0}) for r in every]) == 0.0  # every call off the path: nought is a reading
     without = [{"moe_slots": 3.0 * i, "compiles": 5.0} for i in range(1, 5)]  # the parent's program, or another model's
-    assert read(without) is None and read(chunked[:1]) is None and read([]) is None
+    assert read(without) is None and read(every[:1]) is None and read([]) is None
 
 
 def test_kimi_linear_flops_counts_what_its_docstring_says():

@@ -5,7 +5,15 @@ lists, and the ledger's per-layer keys change name once.  ``RENAMED`` holds,
 for every one of the 128 old names, the name that reports its reading now,
 the cells the old entry listed, and the old file's ``reader`` and ``params``
 (``data/renamed_pr39.json``, written from the parent commit's files when the
-fold was made, not read from git here).  CPU only."""
+fold was made, not read from git here).
+
+PR 63 folded the sixteen twins that tests outside ``paths`` had held apart
+until PR 61 (the eight ``.exz``, seven ``.ex4`` and ``attn_proj_ms_step.swa``:
+112 -> 96 entries), so the keys change name once more: ``RENAMED_63``
+(``data/renamed_pr63.json``) holds those sixteen the same way, and the rows of
+PR 39's table whose name went with them point at the survivor.  Since then no
+metric file carries ``cells`` or ``params["how"]``, and ONE standing case
+keeps a twin from coming back.  CPU only."""
 
 from __future__ import annotations
 
@@ -25,6 +33,8 @@ import resolve  # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 with open(os.path.join(HERE, "data", "renamed_pr39.json")) as f:
     RENAMED = json.load(f)
+with open(os.path.join(HERE, "data", "renamed_pr63.json")) as f:
+    RENAMED_63 = json.load(f)
 
 #: Retired, not folded: the reading lives on under a metric of ANOTHER reader
 #: that gave the same number on every line of the ledger (PERF.md section 6, PR 39).
@@ -32,23 +42,9 @@ RETIRED = {"peak_hbm_gib.ex", "peak_hbm_gib.tok", "init_state_s.ex4"}
 #: Said in prose, or read by no reader: not what a reading is made from.
 PROSE = ("why", "how")
 
-#: The files that still carry ``cells`` (and four of them ``params["how"]``):
-#: tests OUTSIDE ``paths`` read the key, and a ``benchmark`` PR may edit no
-#: file there.  file -> the test that pins it.  When those tests let go, the
-#: key goes and this table with it (PERF.md section 7).
-PINNED = {
-    **{f"{family}.exz": "tests/test_deepfm_zipf_cell.py" for family in (
-        "step_ms", "step_roofline_pct", "device_idle_pct", "host_loop_pct", "prep_wait_pct",
-        "starved_dispatch_pct", "compiles_in_window", "hbm_peak_reported_gib")},
-    **{name: "tests/test_deepfm_zipf_cell.py" for name in (
-        "step_ms.ex", "step_roofline_pct.ex", "device_idle_pct.ex", "host_loop_pct.ex", "prep_wait_pct.ex",
-        "starved_dispatch_pct.ex4", "compiles_in_window.ex4", "hbm_peak_reported_gib.ex4",
-        "table_grad_ms_step.ex", "table_grad_ms_step.ex4", "table_grad_sweep_pct.ex", "table_grad_sweep_pct.ex4")},
-    **{name: "tests/test_table_apply.py" for name in (
-        "table_apply_ms_step.ex", "table_apply_ms_step.ex4", "table_apply_fused_pct.ex", "table_apply_fused_pct.ex4")},
-    "moe_slots_overflow_pct.mla": "tests/test_latent_moe.py",
-}
-PINNED_HOW = {"table_grad_sweep_pct.ex", "table_grad_sweep_pct.ex4", "table_apply_fused_pct.ex", "table_apply_fused_pct.ex4"}
+#: The growth rehearsal (test_benchmark_yardstick.py) runs this module on grown copies of the tree, and what its added cells
+#: BRING there are copies of entries that are there under names of their own (``<metric>.<tag>``): twins by construction.
+on_the_tree_itself = pytest.mark.skipif("EDL_BENCH_GROWTH_REHEARSAL" in os.environ, reason="the rehearsal's own entries are copies")
 
 
 def _read_from(spec: dict) -> dict:
@@ -60,13 +56,22 @@ def test_the_table_covers_the_128_entries_the_benchmark_had():
     assert {old for old, row in RENAMED.items() if row["new"] != old} >= RETIRED
 
 
-@pytest.mark.parametrize("old", sorted(RENAMED))
-def test_an_old_name_reads_on_under_its_new_name_in_every_cell_it_had(old):
+def test_the_second_table_holds_the_sixteen_twins_that_went_and_the_first_points_past_them():
+    assert len(RENAMED_63) == 16 and all(row["new"] != old for old, row in RENAMED_63.items())
+    # a name PR 39 kept and PR 63 folded is not a `new` of the first table any more: one step from any old name to a live one
+    assert not {row["new"] for row in RENAMED.values()} & set(RENAMED_63)
+    assert set(RENAMED_63) <= set(RENAMED) | {"attn_proj_ms_step.swa"}  # the one twin that came after PR 39 (PR 56)
+    survivors = {row["new"] for row in RENAMED_63.values()}
+    assert survivors <= {m["name"] for m in resolve.Bench(ROOT).spec["per_layer"]} and len(survivors) == 13
+
+
+@pytest.mark.parametrize("old,table", [(old, "pr39") for old in sorted(RENAMED)] + [(old, "pr63") for old in sorted(RENAMED_63)])
+def test_an_old_name_reads_on_under_its_new_name_in_every_cell_it_had(old, table):
     """The new entry lists every cell the old one listed, and its file's
     reader and parameters are the old file's: the same reading under
     another name."""
     bench = resolve.Bench(ROOT)
-    row = RENAMED[old]
+    row = {"pr39": RENAMED, "pr63": RENAMED_63}[table][old]
     (entry,) = [m for m in bench.spec["per_layer"] if m["name"] == row["new"]]
     assert set(row["cells"]) <= set(entry["workloads"])
     spec = bench.metric_file(row["new"])
@@ -88,19 +93,41 @@ def test_one_entry_one_file_and_the_cells_are_the_entrys_list_alone():
     cells = {w["name"] for w in bench.spec["workloads"]}
     for entry in bench.spec["per_layer"]:
         assert entry["workloads"] and set(entry["workloads"]) <= cells, entry["name"]
+
+
+def test_no_metric_file_lists_cells_and_none_says_how_a_counter_is_read():
+    """``BENCHMARK.json``'s ``workloads`` is the ONE place a metric's cells
+    live (PR 39).  25 files kept a ``cells`` key, and four of them
+    ``params["how"]``, for tests outside ``paths`` that read them; since
+    PR 61 none does, and PR 63 dropped both keys (the reader's name says how
+    a counter is read)."""
+    bench = resolve.Bench(ROOT)
+    for entry in bench.spec["per_layer"]:
         spec = bench.metric_file(entry["name"])
-        # `cells` and `how` only where a test outside `paths` still reads them; `cells` is the list as PR 39 left it
-        assert ("cells" in spec) == (entry["name"] in PINNED), entry["name"]
-        assert ("how" in spec.get("params", {})) == (entry["name"] in PINNED_HOW), entry["name"]
-        if "cells" in spec:
-            assert entry["workloads"][: len(spec["cells"])] == spec["cells"], entry["name"]
-    for name, pinned_by in PINNED.items():
-        assert name in names, (name, pinned_by)
+        assert "cells" not in spec and "how" not in spec.get("params", {}), entry["name"]
+        assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}, entry["name"]
+
+
+@on_the_tree_itself
+def test_no_two_entries_read_the_same_thing():
+    """No two entries of ``per_layer`` agree in reader, parameters (less the
+    prose), unit, direction and ``moves``: such a pair is ONE metric under
+    two names, and its second cell belongs in the first entry's
+    ``workloads``.  This comparison is what found PR 63's sixteen; it stands
+    so that a later PR's cell joins an entry that is there and brings
+    entries only for what no entry reads."""
+    bench = resolve.Bench(ROOT)
+    read = {}
+    for entry in bench.spec["per_layer"]:
+        spec = bench.metric_file(entry["name"])
+        what = json.dumps([spec["reader"], _read_from(spec), entry["unit"], entry["better"], entry["moves"]], sort_keys=True)
+        read.setdefault(what, []).append(entry["name"])
+    assert [names for names in read.values() if len(names) > 1] == []
 
 
 def test_a_suffix_says_which_end_to_end_metric_an_entry_moves():
-    """``.tok`` moves ``tokens_per_s_chip`` and ``.ex`` (with the DeepFM
-    cells' ``.ex4`` / ``.exz`` that stay apart) ``examples_per_s_chip``;
+    """``.tok`` moves ``tokens_per_s_chip`` and ``.ex`` (with the ``.ex4``
+    entries of what only the sharded table has) ``examples_per_s_chip``;
     ``setup_*`` moves ``setup_s``.  No suffix names a cell any more: ``.moe``,
     ``.mla``, ``.eva`` stay on metrics that only that architecture has."""
     bench = resolve.Bench(ROOT)
@@ -109,7 +136,7 @@ def test_a_suffix_says_which_end_to_end_metric_an_entry_moves():
         name, moves = entry["name"], entry["moves"]
         if name.endswith(".tok"):
             assert moves == "tokens_per_s_chip", name
-        if name.endswith((".ex", ".ex4", ".exz")):
+        if name.endswith((".ex", ".ex4")):
             assert moves == "examples_per_s_chip", name
         if name.startswith("setup_"):
             assert moves == "setup_s", name

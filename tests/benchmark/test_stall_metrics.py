@@ -24,6 +24,9 @@ import runfiles  # noqa: E402
 
 EX = ["deepfm_job", "deepfm_x4_job", "deepfm_job_zipf"]
 TOK = ["gpt2m_job", "olmoe_job", "kanana2_job", "evabyte_job"]
+#: joined since, at the end: ``trinity_mini_job`` (PR 56), ``keye_vl2_job`` (PR 58) with their cells; ``nemotron3_job`` and
+#: ``kimi_linear_job`` in PR 63 (the recorder runs in every worker loop; their tests' ``JOINED`` waited for a ``benchmark`` PR)
+LATER_TOK = ["trinity_mini_job", "keye_vl2_job", "nemotron3_job", "kimi_linear_job"]
 #: metric -> (unit, the reader's parameters)
 STALL_METRICS = {
     "stalls_in_window": ("count", {"counter": "stalls", "scale": 1}),
@@ -130,6 +133,8 @@ def test_the_entries_say_what_their_files_say(name):
     assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
     moves, cells = {".ex": ("examples_per_s_chip", EX), ".tok": ("tokens_per_s_chip", TOK)}[suffix]
     assert entry["moves"] == moves and entry["workloads"][:len(cells)] == cells  # a later cell joins at the end
+    if suffix == ".tok":
+        assert entry["workloads"][len(cells):len(cells) + len(LATER_TOK)] == LATER_TOK
     # every listed cell reports the end-to-end metric the entry moves, and resolves the entry
     for cell in cells:
         assert moves in [m["name"] for m in bench.metrics_of(cell, "end_to_end")]
@@ -140,7 +145,8 @@ def test_the_six_were_appended_together_and_the_counters_are_the_workers_own():
     bench = resolve.Bench(ROOT)
     names = [m["name"] for m in bench.spec["per_layer"]]
     first = names.index(NAMES[0])
-    assert names[first:first + 6] == NAMES and first >= 98  # after PR 53's 98 entries; a later PR's come after
+    # after what PR 53 left (98 entries then; PR 63's fold took fifteen from before them) and before what later PRs brought
+    assert names[first:first + 6] == NAMES and names.index("kda_chunked_pct.kda") < first < names.index("window_attn_ms_step.swa")
     assert len(names) <= 128
     from elasticdl_tpu.worker.worker import COUNTER_GAUGES
 

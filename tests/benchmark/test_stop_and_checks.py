@@ -98,6 +98,37 @@ def test_a_run_directory_stands_in_for_work_in_job_flags():
     assert flag("profile_dir") == "/p/{work}"  # the harness's own flags are taken as they are
 
 
+#: A job that writes its environment where its command line says.
+ENV_DUMP = "import json, os, sys; json.dump(dict(os.environ), open(sys.argv[1] + '.tmp', 'w')); os.rename(sys.argv[1] + '.tmp', sys.argv[1])"
+
+
+@pytest.mark.parametrize("case,outside,own,wanted", [
+    ("the_harness_sizes_the_transfer_buffer", {}, None, str(256 << 20)),
+    ("whatever_the_caller_exported", {"TPU_PREMAPPED_BUFFER_SIZE": "17179869184"}, None, str(256 << 20)),
+    ("a_data_file_comes_last", {"TPU_PREMAPPED_BUFFER_SIZE": "1"}, {"TPU_PREMAPPED_BUFFER_SIZE": "4294967296", "OWN": "x"}, "4294967296"),
+])
+def test_the_jobs_environment_is_the_harnesses_then_the_data_files(tmp_path, monkeypatch, case, outside, own, wanted):
+    """PR 63: the TPU's open pins a host buffer whose default size made
+    ``setup_s`` wander by seconds; the job gets ``JOB_ENV`` whatever the
+    caller's environment says (both sides of a check run alike), and a
+    configuration's or a traffic mix's ``job_env`` after it."""
+    for key, value in outside.items():
+        monkeypatch.setenv(key, value)
+    monkeypatch.setenv("BENCH_RUN", "7")
+    out = tmp_path / "env.json"
+    running = job.Job([sys.executable, "-c", ENV_DUMP, str(out)], str(tmp_path / "work"), "cpu", str(tmp_path / "cache"), job_env=own)
+    try:
+        running.wait_for(out.exists, 30.0, "the job's environment")
+    except job.JobFailed:  # it wrote and left between two polls
+        assert out.exists()
+    finally:
+        running.stop()
+    env = json.loads(out.read_text())
+    assert job.JOB_ENV == {"TPU_PREMAPPED_BUFFER_SIZE": str(256 << 20)}
+    assert env["TPU_PREMAPPED_BUFFER_SIZE"] == wanted and env.get("OWN") == (own or {}).get("OWN")
+    assert env["JAX_PLATFORMS"] == "cpu" and env["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path / "cache") and "BENCH_RUN" not in env
+
+
 # ------------------------------------------- the reference child's report
 
 LOSS = {"loss": 11.34, "step_losses": [11.35, 11.33]}
