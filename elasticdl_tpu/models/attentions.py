@@ -45,6 +45,8 @@ a layer is ``sliding_attention`` (``window`` = ``sliding_window`` keys) or ``ful
     query head h reads key/value head h // (H / G) ; scores / sqrt(hd) ; softmax over the keys j <= p (full) or
     p - window < j <= p (sliding: ``window`` keys, the query's own included)
     part = (o * sigmoid(z)) Wo
+    ``lfm2_moe``'s attention layers are this part WITHOUT the gate (``gate`` false: no Wz, part = o Wo) and with the rotary
+    turn on a FULL layer (``rotary`` true): fewer key/value heads, a norm a head, THEN the turn, every earlier key
 
 ``KeyeVL2``'s (:class:`IndexedSparseAttention`: DeepSeek-V3.2-Exp's sparse attention under ``sa_config``), H query
 heads over G key/value heads of ``head_dim``, an INDEXER of J heads E wide over ONE index key a position, ``topk`` keys a query:
@@ -68,6 +70,7 @@ so does a window (the ring visits every block), and so does an indexer (a query'
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -319,7 +322,9 @@ class GatedWindowAttention(Part):
     sees are its own and the ``window - 1`` before it and q, k take the
     rotary turn, without one (0: a full layer) neither.  The key/value heads
     are REPEATED to the queries' ahead of the attention, as
-    :class:`GroupedQueryAttention` does (the flash kernels' contract)."""
+    :class:`GroupedQueryAttention` does (the flash kernels' contract).
+    ``gate`` false: no ``wz`` and no gate (``lfm2_moe``); ``rotary``: whether
+    q, k take the turn (None: as ``afmoe`` has it, the sliding layers alone)."""
 
     q_heads: int
     kv_heads: int
@@ -327,16 +332,24 @@ class GatedWindowAttention(Part):
     window: int
     theta: float
     eps: float
+    gate: bool = True
+    rotary: Optional[bool] = None
 
-    counters = WINDOW_COUNTERS
+    @property
+    def counters(self):
+        """A full layer counts its causal pairs alone (a model of full layers only reports nothing of a window)."""
+        return WINDOW_COUNTERS if self.window else {"attn_pairs_full": WINDOW_COUNTERS["attn_pairs_full"]}
 
     def init(self, draw: Draws, d: int):
         q, kv = self.q_heads * self.head_dim, self.kv_heads * self.head_dim
-        return {
-            "wq": draw.normal((d, q)), "wk": draw.normal((d, kv)), "wv": draw.normal((d, kv)), "wz": draw.normal((d, q)),
+        made = {"wq": draw.normal((d, q)), "wk": draw.normal((d, kv)), "wv": draw.normal((d, kv))}
+        if self.gate:
+            made["wz"] = draw.normal((d, q))
+        made.update({
             "wo": draw.normal((q, d)),
             "q_norm": jnp.ones((self.head_dim,), jnp.float32), "k_norm": jnp.ones((self.head_dim,), jnp.float32),
-        }
+        })
+        return made
 
     def apply(self, u, blk, positions, axis, cast):
         b, l, _ = u.shape
@@ -344,19 +357,20 @@ class GatedWindowAttention(Part):
             heads = lambda t: t.reshape(b, l, -1, self.head_dim)  # noqa: E731
             # save sites (ops/remat.py): each product as the glue reads it
             q, k, v = (heads(remat_lib.product(name, u, cast(blk["w" + name]))) for name in ("q", "k", "v"))
-            z = remat_lib.product("attn_gate", u, cast(blk["wz"]))
+            z = remat_lib.product("attn_gate", u, cast(blk["wz"])) if self.gate else None
         with jax.named_scope("attn_glue"):
             q, k = rms_norm(q, blk["q_norm"], self.eps), rms_norm(k, blk["k_norm"], self.eps)
-            if self.window:
+            if bool(self.window) if self.rotary is None else self.rotary:
                 q, k = rope(q, positions, self.theta), rope(k, positions, self.theta)
             group = self.q_heads // self.kv_heads
             if group > 1:
                 k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
         att = ring_attention(q, k, v, axis_name=axis, causal=True, window=self.window or None)
-        with jax.named_scope("attn_glue"):
-            gated = (att.reshape(b, l, -1) * jax.nn.sigmoid(z.astype(jnp.float32))).astype(att.dtype)
+        if self.gate:
+            with jax.named_scope("attn_glue"):
+                att = (att.reshape(b, l, -1) * jax.nn.sigmoid(z.astype(jnp.float32))).astype(att.dtype)
         with jax.named_scope("attn_proj"):
-            return gated @ cast(blk["wo"]), None
+            return att.reshape(b, l, -1) @ cast(blk["wo"]), None
 
     def shape_counts(self, batch: int, length: int):
         every = batch * self.q_heads

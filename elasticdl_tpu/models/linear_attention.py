@@ -50,7 +50,7 @@ from elasticdl_tpu.ops.ring_attention import PATH_PALLAS_INTERPRET, PATH_XLA_REF
 #: (``benchmark/metrics/kda_chunked_pct.kda.json`` reads the pair), the part
 #: of THAT whose same-sub-block decay masks the Pallas kernels computed, and
 #: the part of the first whose three convolution chains the Pallas kernels
-#: computed (no entry reads either yet: PERF.md section 7).  Each is counted in
+#: computed (``kda_mask_kernel_pct.kda`` / ``kda_conv_kernel_pct.kda`` since PR 63).  Each is counted in
 #: ``apply``, where the op is called, through the very function the op asks
 #: (``rule_path``, ``mask_path``, ``conv_path``), of the very operands: a
 #: constant re-derived elsewhere would read 100 whatever ran.

@@ -12,7 +12,9 @@ defaults and parameter names are OLMoE's (``OLMoE-1B-7B-0125``: every layer
 ``attention_class`` ``"eva"`` EvaByte's, ``hybrid_override_pattern``
 ``nemotron_h``'s, ``linear_attn_config`` ``kimi_linear``'s, ``sliding_window``
 > 0 ``afmoe``'s (Trinity), ``sa_config`` ``KeyeVL2``'s (a learned indexer's
-sparse attention; its own loss is a second term of the total).
+sparse attention; its own loss is a second term of the total),
+``conv_L_cache`` > 0 ``lfm2_moe``'s (LFM2: a double-gated short convolution
+in place of attention on most layers).
 
 The model, with ``rmsnorm(x, g) = x * rsqrt(mean(x^2) + eps) * g``:
 
@@ -99,6 +101,24 @@ otherwise names feed-forwards (``"moe"`` / ``"dense"``); under ``sliding_window`
 key is read the published way and the feed-forwards follow from ``num_dense_layers``
 (``first_k_dense_replace``'s role).
 
+With ``conv_L_cache`` > 0 (``lfm2_moe``'s keys: LFM2) a layer is an OPERATOR and a
+feed-forward, ``(operator_norm, operator), (ffn_norm, feed-forward)``:
+
+    x += operator_i(rmsnorm(x, operator_norm))             eps = ``norm_eps``
+              ``layer_types[i]`` ``conv`` (``models/gated_conv.GatedShortConv``): (B, C, z) = split3(u W_in), W_in [d, 3d];
+              c = conv(B * z) (causal, depthwise, ``conv_L_cache`` taps a channel, no bias); (C * c) W_out: NO attention, NO
+              recurrence, NO activation; or ``full_attention`` (``models/attentions.GatedWindowAttention`` without its gate):
+              H query heads over G key/value heads of d / H, a norm a head THEN the rotary turn, every earlier key
+    x += ffn_i(rmsnorm(x, ffn_norm))
+              ffn_i: dense for i < ``num_dense_layers``, else the ``deepseek_v3`` expert layer under this family's spelling
+              (a sigmoid router always; ``use_expert_bias``: the correction bias; ``norm_topk_prob``, ``routed_scaling_factor``;
+              no shared expert)
+    logits = rmsnorm(x, norm_f) tok_emb^T                  (``tie_word_embeddings``)
+
+The THIRD reading of ``layer_types`` (beside the collision above): this family PUBLISHES
+it as each layer's OPERATOR kind, ``conv`` / ``full_attention``; under ``conv_L_cache`` the
+key is read that way and the feed-forwards follow from ``num_dense_layers``.
+
 What the heads and experts that are not held would add is left out; the
 all-reduce of the head shares and the experts' exchange are not here.
 
@@ -138,6 +158,7 @@ from elasticdl_tpu.models.attentions import (
     LatentAttention,
     QKNormAttention,
 )
+from elasticdl_tpu.models.gated_conv import GatedShortConv
 from elasticdl_tpu.models.linear_attention import KimiDeltaAttention
 from elasticdl_tpu.models.mamba import MambaMixer
 from elasticdl_tpu.models.parts import Draws, Part
@@ -166,6 +187,8 @@ MOE_COUNTERS = {
 LAYER_TYPES = ("moe", "dense")
 #: ``layer_types`` as ``afmoe`` publishes it: each layer's ATTENTION
 ATTENTION_LAYER_TYPES = ("sliding_attention", "full_attention")
+#: ``layer_types`` as ``lfm2_moe`` publishes it: each layer's OPERATOR
+OPERATOR_LAYER_TYPES = ("conv", "full_attention")
 #: ``hybrid_override_pattern``'s letters (``-``, a dense MLP layer, is not one: no cell runs it)
 PATTERN_KINDS = {"M": "a Mamba-2 mixer", "*": "attention", "E": "a latent mixture of experts"}
 ATTENTION_CLASSES = ("mha", "eva")
@@ -550,7 +573,7 @@ _NEVER_DECAYED = ("router_bias",)
 _NOT_MATRICES = _NEVER_DECAYED + (
     "attn_norm", "ffn_norm", "norm_f", "kv_norm", "q_norm", "k_norm", "eva_phi", "eva_mu",
     "norm", "ssm_norm", "A_log", "D", "dt_bias", "conv_b", "kda_norm", "post_attn_norm", "post_ffn_norm",
-    "idx_norm", "idx_norm_bias",
+    "idx_norm", "idx_norm_bias", "operator_norm", "gconv_taps",
 )
 
 
@@ -850,10 +873,60 @@ def _afmoe_layers(
     return layers, draws, {"bias_update_speed": float(load_balance_coeff)}  # 12 keys a layer: five projections, the experts' seven
 
 
-def _family(*, hybrid_override_pattern, attention_class, linear_attn_config, kv_lora_rank, sliding_window=0, sa_config=None) -> str:
+def _lfm2_router(
+    *, num_experts, num_experts_per_tok, experts_held, first_expert_held, use_expert_bias, norm_topk_prob, routed_scaling_factor,
+    n_group, topk_group,
+):
+    """``lfm2_moe``'s spelling of the router's keys, mapped onto :func:`_router`'s: a sigmoid router
+    (the family has no other) whose ``use_expert_bias`` is the correction bias (it chooses, it never weighs)."""
+    if not use_expert_bias:
+        raise ValueError("use_expert_bias false (a router that chooses by its scores alone) is not supported under conv_L_cache: no cell runs it")
+    return _router(
+        num_experts=num_experts, num_experts_per_tok=num_experts_per_tok, experts_held=experts_held,
+        first_expert_held=first_expert_held, scoring_func="sigmoid", norm_topk_prob=norm_topk_prob,
+        routed_scaling_factor=routed_scaling_factor, topk_method="noaux_tc", n_group=n_group, topk_group=topk_group,
+    )
+
+
+def _lfm2_layers(
+    router, correction_bias,
+    *, layer_types, num_hidden_layers, num_dense_layers, hidden_size, num_attention_heads, num_key_value_heads,
+    conv_L_cache, conv_bias, rope_theta, norm_eps, rms_norm_eps, intermediate_size, moe_intermediate_size,
+):
+    """``lfm2_moe``'s layers: ``layer_types`` names each layer's OPERATOR here (module docstring: the THIRD
+    reading of the key), ``conv`` a double-gated short convolution of ``conv_L_cache`` taps or
+    ``full_attention`` (fewer key/value heads, a norm a head, then the rotary turn; no gate, no window); the
+    first ``num_dense_layers`` feed-forwards are dense.  Its third return renames the family's own key
+    onto the one the spec reads."""
+    kinds = tuple(layer_types if layer_types is not None else ("conv",) * num_hidden_layers)
+    if len(kinds) != num_hidden_layers or set(kinds) - set(OPERATOR_LAYER_TYPES):
+        raise ValueError(
+            f"under conv_L_cache, layer_types must name the OPERATOR of {num_hidden_layers} layers from "
+            f"{OPERATOR_LAYER_TYPES}, got {kinds!r}"
+        )
+    if norm_eps <= 0 or rms_norm_eps != _DEFAULTS["rms_norm_eps"]:
+        raise ValueError(f"norm_eps {norm_eps}: this family's key for the norms' epsilon (positive), not rms_norm_eps")
+    kv_heads = num_key_value_heads or num_attention_heads
+    head_dim = _head_width(hidden_size, num_attention_heads)
+    if num_attention_heads % kv_heads:
+        raise ValueError(f"{num_attention_heads} query heads over {kv_heads} key/value heads")
+    operators = {
+        "conv": GatedShortConv(int(conv_L_cache), bool(conv_bias)),
+        "full_attention": GatedWindowAttention(
+            num_attention_heads, kv_heads, head_dim, 0, float(rope_theta), float(norm_eps), gate=False, rotary=True),
+    }
+    feed_forwards, draws = _gated_feed_forwards(
+        router, correction_bias, num_hidden_layers=num_hidden_layers, layer_types=None, first_k_dense_replace=num_dense_layers,
+        intermediate_size=intermediate_size, moe_intermediate_size=moe_intermediate_size, n_shared_experts=0,
+    )
+    layers = tuple((("operator_norm", operators[kind]), ("ffn_norm", feed_forward)) for kind, feed_forward in zip(kinds, feed_forwards))
+    return layers, draws, {"rms_norm_eps": float(norm_eps)}  # 8 keys a layer: an operator's 3 or 4, the experts' 4
+
+
+def _family(*, hybrid_override_pattern, attention_class, linear_attn_config, kv_lora_rank, sliding_window=0, sa_config=None, conv_L_cache=0) -> str:
     """Which family's builders read the keys.  ``hybrid_override_pattern``,
-    ``attention_class`` ``'eva'``, ``linear_attn_config``, ``kv_lora_rank`` and
-    ``sliding_window`` each name one, and one model is of one — with ONE rule for a pair:
+    ``attention_class`` ``'eva'``, ``linear_attn_config``, ``kv_lora_rank``,
+    ``sliding_window``, ``sa_config`` and ``conv_L_cache`` each name one, and one model is of one — with ONE rule for a pair:
     ``linear_attn_config`` decides over ``kv_lora_rank`` (``kimi_linear``'s
     full-attention layers ARE latent attention: the rank is one of its own
     keys).  Any other two together are refused."""
@@ -864,13 +937,13 @@ def _family(*, hybrid_override_pattern, attention_class, linear_attn_config, kv_
         for family, said in (
             ("nemotron_h", hybrid_override_pattern is not None), ("evabyte", attention_class == "eva"),
             ("kimi_linear", linear_attn_config is not None), ("deepseek_v3", bool(kv_lora_rank) and linear_attn_config is None),
-            ("afmoe", sliding_window > 0), ("keye_vl2", sa_config is not None),
+            ("afmoe", sliding_window > 0), ("keye_vl2", sa_config is not None), ("lfm2_moe", conv_L_cache > 0),
         )
         if said
     ]
     if len(named) > 1:
         raise ValueError(
-            "hybrid_override_pattern, attention_class 'eva', linear_attn_config, kv_lora_rank, sliding_window and sa_config each name a family and "
+            "hybrid_override_pattern, attention_class 'eva', linear_attn_config, kv_lora_rank, sliding_window, sa_config and conv_L_cache each name a family and "
             f"one model is of one (linear_attn_config alone decides over kv_lora_rank): got those of {named}"
         )
     return named[0] if named else "olmoe"
@@ -888,6 +961,7 @@ FAMILIES = {
         _kimi_linear_layers, own(_latent_attention), own(functools.partial(_kimi_linear_feed_forwards, *own(_kimi_linear_router))))),
     "afmoe": lambda own: own(functools.partial(_afmoe_layers, *own(_afmoe_router))),
     "keye_vl2": _keye_vl2_layers,
+    "lfm2_moe": lambda own: own(functools.partial(_lfm2_layers, *own(_lfm2_router))),
 }
 
 
@@ -1030,6 +1104,11 @@ def model_spec(
     mup_enabled: bool = False,
     # KeyeVL2's keys (defaults: OLMoE's block)
     sa_config: Optional[Dict[str, Any]] = None,
+    # lfm2_moe's keys (defaults: OLMoE's block)
+    conv_L_cache: int = 0,
+    conv_bias: bool = False,
+    norm_eps: float = 0.0,
+    use_expert_bias: bool = True,
 ) -> ModelSpec:
     """``layer_types`` names each layer's feed-forward, ``"moe"`` or
     ``"dense"`` (both gated; dense layers ``intermediate_size`` wide, experts
@@ -1087,9 +1166,16 @@ def model_spec(
     the feed-forwards and the router are OLMoE's keys (``norm_topk_prob``, ``moe_intermediate_size``, ``experts_held``);
     the matrices that write into the stream — the attention's ``wo`` and the routed experts' ``w_down`` — are drawn at
     :data:`KEYE_VL2_INTO_STREAM` of ``init_std``.
+    ``conv_L_cache`` > 0 (``lfm2_moe``): ``layer_types`` names each layer's OPERATOR, ``"conv"`` (a double-gated short
+    convolution of ``conv_L_cache`` taps, ``models/gated_conv.GatedShortConv``; ``conv_bias`` true is refused) or
+    ``"full_attention"`` (``num_attention_heads`` query heads over ``num_key_value_heads`` key/value heads of
+    ``hidden_size / num_attention_heads``, a norm a head THEN the rotary turn, no gate, no window); the first
+    ``num_dense_layers`` feed-forwards dense, the rest ``num_experts`` sigmoid-routed experts ``moe_intermediate_size`` wide
+    with a correction bias (``use_expert_bias``; false is refused), ``norm_topk_prob`` / ``routed_scaling_factor``; the
+    norms' epsilon is ``norm_eps``; a layer's two norms are ``operator_norm`` and ``ffn_norm``.
 
     Which FAMILY the model is of follows from ``hybrid_override_pattern``,
-    ``attention_class``, ``linear_attn_config``, ``kv_lora_rank`` and ``sliding_window`` (:func:`_family`); the family's
+    ``attention_class``, ``linear_attn_config``, ``kv_lora_rank``, ``sliding_window``, ``sa_config`` and ``conv_L_cache`` (:func:`_family`); the family's
     builders take their own keys and check their ranges, and a key that no
     builder of the chosen family reads, set to other than its default, is
     refused: it would change nothing."""
@@ -1110,7 +1196,7 @@ def model_spec(
         raise ValueError(
             f"{', '.join(foreign)}: set, but no part of the {family!r} family reads "
             f"{'it' if len(foreign) == 1 else 'them'} (the family follows from hybrid_override_pattern / attention_class / "
-            f"linear_attn_config / kv_lora_rank / sliding_window)"
+            f"linear_attn_config / kv_lora_rank / sliding_window / sa_config / conv_L_cache)"
         )
     return spec
 

@@ -37,6 +37,19 @@ the taps' partial sums float32 (``ops/short_conv.py`` adds them).
 Contract (``ops/short_conv.outside_conv_contract``): C whole multiples of 128
 and of the head width, the head width whole multiples of 128, L whole
 multiples of ``HALO``, K - 1 <= ``HALO``.
+
+The SECOND chain (``gated`` / ``gated_grads``: ``ops/short_conv.gated_conv``)
+is a double-gated convolution over THREE operands a position, ``y = C * conv(B
+* z, taps)``, on the same blocks, halo and launch: the product ``p = B z`` is
+made in float32 straight into the scratch (the rows before the block from the
+two operands' halos), the convolution is ``_pre_of``'s, and there is no
+activation.  Its gradient, from the operands and ``g``:
+
+    c = conv(p) ;  dC = g c ;  dc = g C                   dc also for the K - 1 rows AFTER the block (the halos of g and C)
+    dtaps[j] = sum_r dc[r] p[r - (K - 1) + j]             the block's own rows: a partial sum a block
+    dp[r] = sum_j taps[j] dc[r + (K - 1) - j] ;  dB = dp z ;  dz = dp B
+
+four reads (``B``, ``C``, ``z``, ``g``) and three writes of [B, L, C] arrays.
 """
 
 from __future__ import annotations
@@ -191,3 +204,72 @@ def chain_grads(t, taps, g, *, head_dim: Optional[int], eps: float, interpret: b
                    [block, pl.BlockSpec((None, None, taps.shape[0], cols), lambda b, i, j: (b, i, 0, j))],
                    [jax.ShapeDtypeStruct(t.shape, t.dtype), of_taps],
                    [pltpu.VMEM((HALO + rows + HALO, cols), _F32), pltpu.VMEM((rows + HALO, cols), _F32)], 60, interpret)
+
+
+def _fill_product(ext, b_before_ref, b_ref, z_before_ref, z_ref):
+    """``p = b z`` in float32 into ``ext``: the HALO rows before the block (zeros at a sequence's start), then the block."""
+    rows = b_ref.shape[0]
+    ext[0:HALO, :] = jnp.where(pl.program_id(1) > 0, b_before_ref[...].astype(_F32) * z_before_ref[...].astype(_F32), 0.0)
+    ext[HALO:HALO + rows, :] = b_ref[...].astype(_F32) * z_ref[...].astype(_F32)
+
+
+def _gated_kernel(b_before_ref, b_ref, z_before_ref, z_ref, c_ref, w_ref, y_ref, ext, *, tile: int):
+    rows, _ = b_ref.shape
+    _fill_product(ext, b_before_ref, b_ref, z_before_ref, z_ref)
+    for at, size in _tiles(rows, tile):
+        _, conv = _pre_of(ext, w_ref, at, size)
+        y_ref[at:at + size, :] = (c_ref[at:at + size, :].astype(_F32) * conv).astype(y_ref.dtype)
+
+
+def _gated_grads_kernel(b_before_ref, b_ref, z_before_ref, z_ref, c_ref, c_after_ref, g_ref, g_after_ref, w_ref,
+                        db_ref, dc_ref, dz_ref, dw_ref, ext, dconv, *, tile: int):
+    rows, cols = b_ref.shape
+    taps = w_ref.shape[0]
+    inside = pl.program_id(1) < pl.num_programs(1) - 1  # a block after this one: the sequence goes on
+    _fill_product(ext, b_before_ref, b_ref, z_before_ref, z_ref)
+    # the convolution's cotangent on the K - 1 rows after the block (a whole halo), which the block's last rows fed
+    dconv[rows:, :] = jnp.where(inside, g_after_ref[...].astype(_F32) * c_after_ref[...].astype(_F32), 0.0)
+    of_taps = [jnp.zeros((8, cols), _F32) for _ in range(taps)]
+    for at, size in _tiles(rows, tile):
+        g = g_ref[at:at + size, :].astype(_F32)
+        xs, conv = _pre_of(ext, w_ref, at, size)
+        dc_ref[at:at + size, :] = (g * conv).astype(dc_ref.dtype)
+        d = g * c_ref[at:at + size, :].astype(_F32)
+        dconv[at:at + size, :] = d
+        for j in range(taps):  # sublane groups added vreg on vreg; the last eight rows become one below
+            of_taps[j] += jnp.sum((d * xs[j]).reshape(size // 8, 8, cols), axis=0)
+    for j in range(taps):
+        dw_ref[j:j + 1, :] = jnp.sum(of_taps[j], axis=0, keepdims=True)
+    for at, size in _tiles(rows, tile):
+        dp = dconv[pl.ds(at + taps - 1, size), :] * w_ref[0:1, :]
+        for j in range(1, taps):
+            dp += dconv[pl.ds(at + taps - 1 - j, size), :] * w_ref[j:j + 1, :]
+        db_ref[at:at + size, :] = (dp * z_ref[at:at + size, :].astype(_F32)).astype(db_ref.dtype)
+        dz_ref[at:at + size, :] = (dp * b_ref[at:at + size, :].astype(_F32)).astype(dz_ref.dtype)
+
+
+def gated(b, c, z, taps, *, interpret: bool):
+    """``y = c * conv(b * z, taps)`` [B, L, C] in ``b``'s type from three
+    operands [B, L, C] and ``taps`` [K, C] (module docstring, the second chain)."""
+    rows, cols = _block(b.shape[1], b.shape[2], LANES)
+    block, before, _ = _specs(b, rows, cols)
+    kernel = functools.partial(_gated_kernel, tile=min(_TILE, rows))
+    return _launch(kernel, "gated_conv_chain", (rows, cols), taps, [before, block, before, block, block], (b, b, z, z, c), block,
+                   jax.ShapeDtypeStruct(b.shape, b.dtype), [pltpu.VMEM((HALO + rows, cols), _F32)], 4 + 2 * taps.shape[0], interpret)
+
+
+def gated_grads(b, c, z, taps, g, *, interpret: bool):
+    """``gated`` transposed: ``(db, dc, dz [B, L, C] in the operands' type, the
+    taps' gradient a block of rows [B, L / rows, K, C] float32)`` from the
+    operands and the cotangent ``g`` [B, L, C] of ``y``."""
+    bsz, length, channels = b.shape
+    rows, cols = _block(length, channels, LANES)
+    block, before, after = _specs(b, rows, cols)
+    kernel = functools.partial(_gated_grads_kernel, tile=min(_TILE, rows))
+    like = jax.ShapeDtypeStruct(b.shape, b.dtype)
+    of_taps = jax.ShapeDtypeStruct((bsz, length // rows, taps.shape[0], channels), _F32)
+    return _launch(kernel, "gated_conv_chain_grads", (rows, cols), taps, [before, block, before, block, block, after, block, after],
+                   (b, b, z, z, c, c, g, g),
+                   [block, block, block, pl.BlockSpec((None, None, taps.shape[0], cols), lambda b, i, j: (b, i, 0, j))],
+                   [like, like, like, of_taps],
+                   [pltpu.VMEM((HALO + rows, cols), _F32), pltpu.VMEM((rows + HALO, cols), _F32)], 12 + 6 * taps.shape[0], interpret)

@@ -140,6 +140,46 @@ def test_flash_fwd_bwd_compiles_for_v5e(compiled_kernel, v5e_device, shape):
     assert signatures == [(3, 0), (4, 1), (4, 2)]
 
 
+def test_the_gated_convolution_pair_compiles_for_v5e_at_the_cells_shape_and_no_flash_entry_reads_it(v5e_device, monkeypatch):
+    """``lfm2_job``'s operator (``ops/short_conv.gated_conv``) at the cell's
+    shape [4, 8192, 2048], 3 taps, forward and gradient: Mosaic accepts the
+    kernel pair (the scratch of a 512 x 512 block with its halo under the
+    kernels' 32 MiB limit), each ONE call under the scope ``gated_conv``; and
+    their operand lists (5 bfloat16 + the taps; 8 bfloat16 + the taps) are no
+    flash kernel's, so neither ``flash_roofline_pct`` entry counts them."""
+    import json
+
+    from elasticdl_tpu.ops import short_conv
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    here = jax.sharding.SingleDeviceSharding(v5e_device)
+    arg = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16, sharding=here)
+    taps = jax.ShapeDtypeStruct((3, 2048), jnp.float32, sharding=here)
+    assert short_conv.gated_path(arg, arg, arg, taps) == ("pallas-compiled", "")
+
+    def loss(b, c, z, w):
+        y, by_kernels = short_conv.gated_conv(b, c, z, w)
+        assert by_kernels
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).trace(arg, arg, arg, taps).lower(lowering_platforms=("tpu",)).compile().as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and all(re.search(r'op_name="[^"]*\bgated_conv\b', call) for call in calls)
+    operands = [re.search(r"operand_layout_constraints=\{(.*?)\}, frontend_attributes", call).group(1) for call in calls]
+    assert sorted((ops.count("bf16["), ops.count("f32[")) for ops in operands) == [(5, 1), (8, 1)]
+    # a trace event names a call by its operands' shapes with their layouts, each ahead of its name
+    events = [
+        "custom-call(" + ", ".join(f"{shape} %p.{i}" for i, shape in enumerate(re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", ops)))
+        + '), custom_call_target="tpu_custom_call"' for ops in operands
+    ]
+    assert all(event.count(" %p.") in (6, 9) for event in events)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for metric in ("flash_roofline_pct.tok", "flash_roofline_pct.mla"):
+        with open(os.path.join(root, "benchmark", "metrics", metric + ".json")) as f:
+            for kernel in json.load(f)["params"]["kernels"]:
+                assert not any(re.search(kernel["pattern"], event) for event in events), (metric, kernel["what"])
+
+
 def test_deepfm_ragged_step_lowers_with_ragged_all_to_all(devices):
     """The 4-device DeepFM step on the explicit ragged route lowers for TPU
     with the real collective (XLA:CPU refuses the op outright, so tier-1
