@@ -38,11 +38,25 @@ How a kernel walks the score matrix.  The sequence is cut into as few
 equal blocks as ``_BLOCK`` = 1024 rows allows (``_blocks``: up to L = 1024
 a head is one block; where the blocks do not divide L the operands are
 zero-padded by less than a tile a block, which ``_visit`` shows to be
-harmless) and the grid is ``(B * lane groups, blocks, blocks[, heads of a
-group])``: a grid step computes, for one head, one (block i of the kernel's
-own rows, block j of the other axis) pair.
-Under ``causal=True`` only pairs at or below the diagonal are visited; the
-others run no code and fetch nothing.  Inside a pair the work is
+harmless) and a grid step computes, for one head, one (block i of the
+kernel's own rows, block j of the other axis) pair.  The GRID holds the
+pairs the call's rule can hold and nothing else (PR 65; ``_walk`` is the
+one place that reads a step back into its pair, for the BlockSpecs' index
+maps and for the kernels alike).  ``causal=False``: every pair, ``(B * lane
+groups, blocks, blocks[, heads of a group])``.  ``causal=True``: the pairs
+at or below the diagonal, the triangle FOLDED two rows a grid row
+(``folded_pair``, the masked calls' fold: row r's r + 1 pairs and then row
+n - 1 - r's n - r are the n + 1 steps of grid row r), ``(B * lane groups,
+ceil(n / 2), n + 1[, heads])`` — 36 steps a head for 36 pairs at L = 8192
+where the square grid ran 64, of which 28 ran no code and, in the forward
+and dQ, left each row's first blocks to arrive uncovered (they were asked
+for one EMPTY step ahead).  A row's pairs stay consecutive steps in
+ascending j (dK/dV, whose rows are keys: ``_folded_key_pair``, ascending
+i), so the carried state, the resident output block and the order of every
+sum are the square grid's, and results are equal to the bit.  Only an odd
+n has idle steps (the middle row's second run), which stay on the pair
+before them and fetch nothing; a head of ONE block keeps its one-step grid
+(the fold's ``(1, 2)`` would add an idle step).  Inside a pair the work is
 straight-line code over static sub-tiles of ``_T_FWD`` / ``_T_BWD`` rows:
 in a diagonal pair a sub-tile multiplies against exactly the positions it
 can see — everything before (after, in dK/dV) its own span unmasked, and
@@ -69,16 +83,20 @@ masked as ever; the pairs between are whole; the pair a whole window away
 rule would HIDE from r (key ``c`` of block ``i - w`` is inside the window
 of query ``r`` of block ``i`` iff ``c > r``), so it is the diagonal pair
 with "before" and "after" swapped and the mask complemented: the same
-static sub-tile scheme (``_visible``), no second kind of code.  A skipped
-step's block index is pinned inside the visited range (``_Plan.specs``:
-``clip(j, i - w, i)``), so it fetches nothing.  One thing is new in the
+static sub-tile scheme (``_visible``), no second kind of code.  The grid
+is ``(B * lane groups, blocks, w + 1[, heads])`` (PR 65; ``window_pair``):
+step c of row block i is the pair ``j = i - w + c``, the far edge first and
+the diagonal last (dK/dV: ``i + c``, the diagonal first), 24 steps a head
+for the 21 pairs at L = 8192, W = 2048 where the square grid ran 64.  The
+``w (w + 1) / 2`` steps a head whose index falls outside the sequence (rows
+``i < w``; dK/dV: the last w) hold no work and are pinned to the row's
+nearest live pair, so they fetch nothing and, in the forward and dQ, a
+row's first blocks are asked for a step early.  One thing is new in the
 forward: a row block's FIRST visited pair is now the far edge, where the
 last row of a sub-tile sees no key of its own span, so the running maximum
 can still be -inf when the exponentials are taken; they are taken from 0
 there (``m_at``), which leaves such a row's sums 0 until a later pair — at
-the latest the diagonal — shows it a key.  The grid keeps its ``blocks x
-blocks`` steps (the skipped ones run no code); a grid of ``w + 1`` steps a
-row block is what a later PR may make of it.  A sequence that is sharded
+the latest the diagonal — shows it a key.  A sequence that is sharded
 raises under a window (``ops/ring_attention``).
 
 A score of TWO products (PR 33; latent attention: 128 + 64 wide queries and
@@ -94,7 +112,13 @@ those heads, i, head of the group, j)`` — the head BEFORE j, so that a
 head's q, q_rot, output and carried state stay put while its key blocks
 stream by, and the group's q_rot / dq_rot / dk_rot block stays in VMEM
 over its heads, each of which masks (``_only``) or stores (``_put``) its
-own lanes as the 64-wide heads of a plain call do.  ``k_rot`` is tiled to
+own lanes as the 64-wide heads of a plain call do.  On the folded grid of
+a causal call the head axis and the fold's n + 1 columns are read together
+(``_folded_heads``): query row r head by head, then row n - 1 - r head by
+head, so a group's q_rot is still fetched once a query row and its dq_rot
+/ dk_rot block written once (the head between the fold's two axes as they
+lie would visit a row's block once a HEAD, twice the fetches, and write an
+output block back before its last head had stored its lanes).  ``k_rot`` is tiled to
 one block of lanes ([B, L, 128], 1 / H of q: the only array the wrapper
 makes), so that a head's rotary product is "zero the other heads' lanes of
 q_rot, contract 128 lanes" like every other product here; q and k are
@@ -334,65 +358,180 @@ def _dot(a, b, dims):
     )
 
 
+def folded_pair(r, c, n: int):
+    """``(i, j, holds_work)``: the pair of a causal triangle of ``n`` row
+    blocks that step ``(r, c)`` of the FOLDED grid ``(ceil(n / 2), n + 1)``
+    visits — the one place that maps a grid step to its pair, for both plans:
+    ``_MaskedPlan.specs`` and the masked kernels call it, and ``_Plan.specs``
+    and the causal full / rotary kernels through ``_walk`` (integer compares
+    and selects only, on traced scalars or plain ints).  Row ``r``'s ``r + 1`` pairs
+    ``(r, 0) .. (r, r)`` and then row ``n - 1 - r``'s ``n - r`` pairs fill the
+    ``n + 1`` steps of grid row ``r``: every pair ``j <= i`` once, a row's
+    pairs on consecutive steps in ascending ``j``, no step above the
+    diagonal.  Where ``n`` is odd the middle row would come twice: its second
+    run holds no work and stays on the pair before it, ``(r, r)``, so nothing
+    is fetched or written back for it (at ``n`` = 1: one live step, one
+    idle)."""
+    first = c <= r
+    work = first | (n - 1 - r != r)
+    i = jnp.where(first, r, n - 1 - r)
+    j = jnp.where(first, c, jnp.where(work, c - r - 1, r))
+    return i, j, work
+
+
+def _folded_key_pair(r, c, n: int):
+    """``(i, j, holds_work)`` of the same fold read for dK/dV, whose rows are
+    KEY blocks ``j`` and whose inner axis is the queries ``i >= j``: a key
+    block has as many pairs as the row that mirrors it (``n - 1 - j``), so
+    grid row ``r`` is key block ``n - 1 - r`` with its ``r + 1`` query blocks
+    and then key block ``r`` with its ``n - r``, each in ascending ``i``."""
+    row, col, work = folded_pair(r, c, n)
+    j = n - 1 - row
+    return j + col, j, work
+
+
+def folded_grid(n: int) -> tuple[int, int]:
+    """The folded grid of a head at ``n`` row blocks: ``n * (n + 1) / 2`` pairs in ``ceil(n / 2) * (n + 1)`` steps."""
+    return (n + 1) // 2, n + 1
+
+
+def window_pair(i, c, w, n: int, before: bool = True):
+    """``(own block, paired block, holds_work)`` of step ``c`` of row block
+    ``i``'s ``w + 1`` steps under a window of ``w`` blocks — ``folded_pair``'s
+    twin for the window calls, and like it the one place that maps their grid
+    step to its pair (``_walk``: the BlockSpecs' index maps and ``_visit``
+    both; integer compares and selects on traced scalars or plain ints, ``w``
+    the scalar operand's value in an index map).  A row of QUERIES
+    (``before``) walks ``j = i - w + c``: the far edge first, the diagonal
+    last.  A row of KEYS (dK/dV) walks ``i + c``: the diagonal first.  The
+    rule differs from the fold's: a row holds ``min(i, w) + 1`` pairs (keys:
+    ``min(n - 1 - i, w) + 1``), so the ``w (w + 1) / 2`` steps a head whose
+    index falls outside ``[0, n - 1]`` hold no work; they are pinned to the
+    row's nearest live pair — for queries its FIRST, so they fetch nothing
+    and the row's first blocks are asked for a step early; for keys its
+    last."""
+    other = i - w + c if before else i + c
+    if before:
+        return i, jnp.maximum(other, 0), other >= 0
+    return i, jnp.minimum(other, n - 1), other <= n - 1
+
+
+def _folded_heads(r, t, n: int, heads: int):
+    """``(column of the fold, head)`` of step ``t`` of folded row ``r`` where
+    the head comes BEFORE the paired block (the rotary order): the row's
+    ``heads * (n + 1)`` steps are query row ``r`` head by head (``r + 1``
+    pairs each) and then row ``n - 1 - r`` head by head (``n - r`` each), so
+    a row's q_rot / dq_rot / dk_rot block stays in VMEM over its heads as it
+    does on the square grid, and is fetched once a query row.  An odd ``n``'s
+    idle steps (``folded_pair``: the middle row's second run) stay on the
+    LAST head, where the pair before them is."""
+    split = heads * (r + 1)
+    second = t >= split
+    run = jnp.where(second, n - r, r + 1)       # pairs a head of this run
+    u = jnp.where(second, t - split, t)
+    hh = sum((u >= k * run).astype(jnp.int32) for k in range(1, heads))
+    idle = second & (n - 1 - r == r)            # column n: past the middle row's pairs, whatever the head
+    return jnp.where(idle, n, u - hh * run + jnp.where(second, r + 1, 0)), jnp.where(idle, heads - 1, hh)
+
+
+def _walk_grid(n: int, causal: bool, far: int) -> tuple[int, int]:
+    """The grid's two block axes, which ``_walk`` reads back into a pair: a
+    causal call's hold the pairs its rule can hold and nothing else — the
+    triangle folded (``folded_grid``), or under a window of ``far`` blocks
+    ``far + 1`` steps a row block (``far < n``: a window at least L long is
+    dropped).  A head of one block keeps its one step, a call that is not
+    causal its square: every step is live there."""
+    if not causal or n == 1:
+        return n, n
+    return (n, far + 1) if far else folded_grid(n)
+
+
+def _walk(a, b, hh, *, n, causal, far, before, rot_heads=0, w=None):
+    """``(own block, paired block, holds_work, head)`` of the grid step whose
+    two block axes read ``(a, b)`` — ``own`` the kernel's own row block
+    (queries with ``before``, keys in dK/dV), the other the block it is paired
+    with.  Called by ``_Plan.specs``' index maps (``w``: the window in blocks
+    as the call's scalar operand gives it) and by the kernels (``_step``: the
+    plan's static ``far``) alike, so a block is fetched for exactly the pair
+    the kernel computes.  A causal call's grid is the triangle FOLDED
+    (``folded_pair`` / ``_folded_key_pair``; with ``rot_heads`` the head axis
+    ``hh`` lies between the two and is decoded with ``b``: ``_folded_heads``),
+    a window call's ``w + 1`` steps a row (``window_pair``); a step that holds
+    no work comes back on a live neighbour's pair.  A call that is not causal
+    walks its square grid as it lies, and a head of ONE block has one step:
+    every step is live."""
+    if not causal:
+        return a, b, True, hh
+    if n == 1:
+        # the one step of a one-block head, its pair spelt as the causal rule
+        # always clamped it: ``gpt2m_job``'s calls lower as they did (PR 65)
+        return a, (jnp.minimum if before else jnp.maximum)(a, b), True, hh
+    if far:
+        return (*window_pair(a, b, far if w is None else w, n, before), hh)
+    if rot_heads:
+        b, hh = _folded_heads(a, hh * (n + 1) + b, n, rot_heads)
+    i, j, work = (folded_pair if before else _folded_key_pair)(a, b, n)
+    return (i, j, work, hh) if before else (j, i, work, hh)
+
+
 def _visit(state, inits, hh, causal, before, rows, tail, block, finish,
-           j_axis=2, window=0):
-    """One grid step ``(lane group, i, j[, head])`` — with a rotary part
-    ``(lane group, i, head, j)``, ``j_axis`` = 3 —: block ``i`` of the
-    kernel's own rows against block ``j`` of the other axis, for head ``hh``
-    of the lane group; ``block(diag, cols)`` with ``cols`` the positions of
-    block ``j`` to attend.  Visited under the causal mask are ``j <= i``
-    (keys before queries, ``before=True``) or ``j >= i``; ``j == i`` is the
-    diagonal pair, ``block(_DIAG, rows)``, every other visited pair
-    ``block(False, rows)``; the rest do nothing (and fetch nothing:
-    ``_Plan.specs`` pins their block index).  Non-causal visits all, and of
-    the last block only its ``tail`` real positions: the zero padding behind
-    them (``_blocks``) would otherwise count in the softmax.  Under the
-    causal mask padding needs no care: padded keys lie after every real
-    query, padded rows are sliced away, and zeros keep them finite.
+           step, window=0):
+    """One grid step: block ``own`` of the kernel's own rows against block
+    ``other`` of the other axis, for head ``hh`` of the lane group, as
+    ``step`` = ``(own, other, holds_work, n)`` (``_step``: read off the grid
+    at the kernel's top) says; ``block(diag, cols)`` with ``cols`` the
+    positions of the other block to attend.  A causal row's pairs are
+    ``other <= own`` (keys before queries, ``before=True``) or ``other >=
+    own``, on consecutive steps in ascending ``other``; ``other == own`` is
+    the diagonal pair, ``block(_DIAG, rows)``, every other pair
+    ``block(False, rows)``; a step that holds no work does nothing (and
+    fetches nothing: ``_walk`` leaves it on a live neighbour's pair).
+    Non-causal visits all, and of the last block only its ``tail`` real
+    positions: the zero padding behind them (``_blocks``) would otherwise
+    count in the softmax.  Under the causal mask padding needs no care:
+    padded keys lie after every real query, padded rows are sliced away, and
+    zeros keep them finite.
     ``state`` are the scratch refs that carry a row block's partial result
     from pair to pair, a slab a head: ``state[..][hh]`` is set to ``inits``
-    at the first visited pair, handed to ``finish()`` after the last.  A
+    at the row's first pair, handed to ``finish()`` after its last.  A
     sequence of one block has no state: ``block`` finishes.
     Under a ``window`` of that many whole blocks (causal, several blocks)
-    the visited pairs are ``i - window <= j <= i`` (``i <= j <= i + window``
-    in dK/dV): the diagonal pair as ever, the pair a whole window away
-    ``block(_EDGE, rows)`` (``_visible``: the complement of the diagonal's
-    mask), the pairs between them unmasked."""
+    a row's pairs are ``own - window <= other <= own`` (``own <= other <=
+    own + window`` in dK/dV): the diagonal pair as ever, the pair a whole
+    window away ``block(_EDGE, rows)`` (``_visible``: the complement of the
+    diagonal's mask), the pairs between them unmasked."""
     if not state:
         block(causal and _DIAG, rows if causal else tail)
         return
-    i, j, n = pl.program_id(1), pl.program_id(j_axis), pl.num_programs(j_axis)
-    far = window and (i - window if before else i + window)  # a window's far-edge pair (no window: not an op of the kernel)
+    own, other, work, n = step
+    live = (lambda x: x) if work is True else (lambda x: work & x)
+    far = window and (own - window if before else own + window)  # a window's far-edge pair (no window: not an op of the kernel)
     if not causal:
-        first, last = j == 0, j == n - 1
-    elif window and before:
-        first, last = j == jnp.maximum(far, 0), j == i
-    elif window:
-        first, last = j == i, j == jnp.minimum(far, n - 1)
+        first, last = other == 0, other == n - 1
     elif before:
-        first, last = j == 0, j == i
+        first, last = other == (jnp.maximum(far, 0) if window else 0), other == own
     else:
-        first, last = j == i, j == n - 1
+        first, last = other == own, other == (jnp.minimum(far, n - 1) if window else n - 1)
 
-    @pl.when(first)
+    @pl.when(live(first))
     def _():
         for ref, x in zip(state, inits):
             ref[hh] = jnp.full(ref.shape[1:], x, ref.dtype)
 
     if window:
-        between = (j < i) & (j > far) if before else (j > i) & (j < far)
-        pl.when(between)(lambda: block(False, rows))
-        pl.when(j == i)(lambda: block(_DIAG, rows))
-        pl.when(j == far)(lambda: block(_EDGE, rows))
+        between = (other < own) & (other > far) if before else (other > own) & (other < far)
+        pl.when(live(between))(lambda: block(False, rows))
+        pl.when(live(other == own))(lambda: block(_DIAG, rows))
+        pl.when(live(other == far))(lambda: block(_EDGE, rows))
     elif causal:
-        pl.when(j < i if before else j > i)(lambda: block(False, rows))
-        pl.when(j == i)(lambda: block(_DIAG, rows))
+        pl.when(live(other < own if before else other > own))(lambda: block(False, rows))
+        pl.when(live(other == own))(lambda: block(_DIAG, rows))
     elif tail == rows:
         block(False, rows)
     else:
-        pl.when(j < n - 1)(lambda: block(False, rows))
-        pl.when(j == n - 1)(lambda: block(False, tail))
-    pl.when(last)(finish)
+        pl.when(other < n - 1)(lambda: block(False, rows))
+        pl.when(other == n - 1)(lambda: block(False, tail))
+    pl.when(live(last))(finish)
 
 
 def _keep(state, hh, rt, values, finish):
@@ -438,21 +577,30 @@ _NT = ((1,), (1,))     # a @ b.T: contract both operands' lanes
 _NN = ((1,), (0,))     # a @ b
 
 
-def _rot_head(r):
-    """The head of its q_rot lane group that this grid step computes: the
-    grid's third axis where the call has a rotary part.  (Read, like
-    ``_head``, at the kernel's top: the interpreter has no ``program_id``
-    inside a ``pl.when``.)"""
-    return pl.program_id(2) if r else None
+def _step(n, causal, window, before, r):
+    """``((own, other, holds_work, n), rotary head)``: this grid step as
+    ``_visit`` reads it, and the head of its q_rot lane group that it computes
+    (None without a rotary part) — ``_walk`` of the grid's indices, ``(lane
+    group, a, b[, head])`` or with a rotary part ``(lane group, a, head, b)``.
+    A head of one block has no walk: its rotary head is the grid's third
+    axis.  (Read, like ``_head``, at the kernel's top: the interpreter has no
+    ``program_id`` inside a ``pl.when``.)"""
+    if n == 1:
+        return None, pl.program_id(2) if r else None
+    hh, b = (pl.program_id(2), pl.program_id(3)) if r else (None, pl.program_id(2))
+    own, other, work, hh = _walk(
+        pl.program_id(1), b, hh, n=n, causal=causal, far=window, before=before, rot_heads=r and _LANE // r,
+    )
+    return (own, other, work, n), hh
 
 
-def _fwd_kernel(*refs, causal, scale, tail, d, r, window=0):
+def _fwd_kernel(*refs, causal, scale, tail, d, r, n, window=0):
     refs = refs[bool(window):]      # the window's scalar operand is the BlockSpecs' (``_Plan.specs``)
     n_in = 5 if r else 3
     q_ref, k_ref, v_ref, *rot = refs[:n_in]
     o_ref, lse_ref, *state = refs[n_in:]
     rows = q_ref.shape[1]
-    hh, rh = _head(d), _rot_head(r)
+    hh, (step, rh) = _head(d), _step(n, causal, window, True, r)
 
     def finish(rt=slice(None), stats=None):
         m, l, acc = stats or [ref[hh] for ref in state]
@@ -503,18 +651,18 @@ def _fwd_kernel(*refs, causal, scale, tail, d, r, window=0):
 
     _visit(
         state, (-jnp.inf, 0.0, 0.0), hh, causal, True, rows, tail, block,
-        finish, 3 if r else 2, window,
+        finish, step, window,
     )
 
 
-def _dq_kernel(*refs, causal, scale, tail, d, r, window=0):
+def _dq_kernel(*refs, causal, scale, tail, d, r, n, window=0):
     refs = refs[bool(window):]
     n_in = 7 if r else 5
     q_ref, k_ref, v_ref, do_ref, *rot, stats_ref = refs[:n_in]
     dq_ref, *outs = refs[n_in:]
     dqr_ref, state = (outs[0], outs[1:]) if r else (None, outs)
     rows = q_ref.shape[1]
-    hh, rh = _head(d), _rot_head(r)
+    hh, (step, rh) = _head(d), _step(n, causal, window, True, r)
 
     def finish(rt=slice(None), dqs=None):
         dq, *dqr = dqs or [ref[hh] for ref in state]
@@ -553,18 +701,18 @@ def _dq_kernel(*refs, causal, scale, tail, d, r, window=0):
 
     _visit(
         state, (0.0,) * len(state), hh, causal, True, rows, tail, block,
-        finish, 3 if r else 2, window,
+        finish, step, window,
     )
 
 
-def _dkv_kernel(*refs, causal, scale, tail, d, r, window=0):
+def _dkv_kernel(*refs, causal, scale, tail, d, r, n, window=0):
     refs = refs[bool(window):]
     n_in = 8 if r else 6
     q_ref, k_ref, v_ref, do_ref, *rot, lse_ref, stats_ref = refs[:n_in]
     dk_ref, dv_ref, *outs = refs[n_in:]
     dkr_ref, state = (outs[0], outs[1:]) if r else (None, outs)
     rows = k_ref.shape[1]
-    hh, rh = _head(d), _rot_head(r)
+    hh, (step, rh) = _head(d), _step(n, causal, window, False, r)
 
     def finish(rt=slice(None), dkv=None):
         dk, dv, *dkr = dkv or [ref[hh] for ref in state]
@@ -603,7 +751,7 @@ def _dkv_kernel(*refs, causal, scale, tail, d, r, window=0):
 
     _visit(
         state, (0.0,) * len(state), hh, causal, False, rows, tail, block,
-        finish, 3 if r else 2, window,
+        finish, step, window,
     )
 
 
@@ -707,53 +855,51 @@ class _Plan:
         self.lp = self.n * self.rows
         self.wp = self.hp * d if rot else self.groups * _LANE
         self.bh = b * self.hp
+        blocks = _walk_grid(self.n, causal, self.far)
         if rot:
-            # The head BEFORE j: a head's q, q_rot, output and carried state
-            # stay put while its key blocks stream by, and the group's q_rot
-            # and dq_rot / dk_rot blocks stay in VMEM over its heads.
-            self.grid = (b * self.groups, self.n, self.heads, self.n)
+            # The head BEFORE the paired block: a head's q, q_rot, output and
+            # carried state stay put while its key blocks stream by, and the
+            # group's q_rot and dq_rot / dk_rot blocks stay in VMEM over its
+            # heads (on the folded grid too: ``_folded_heads``).
+            self.grid = (b * self.groups, blocks[0], self.heads, blocks[1])
         else:
             # A lane group's heads are the LAST axis: consecutive steps, so
             # the q / k / v blocks are fetched once for all of them and each
             # head stores its lanes into the output block while it is still
             # in VMEM.
-            self.grid = (b * self.groups, self.n, self.n) + (self.heads,) * (
+            self.grid = (b * self.groups, *blocks) + (self.heads,) * (
                 self.heads > 1
             )
+        # grid steps a head / (block, block) pairs a head that hold work: what
+        # the ``attention path:`` line says of the grid
+        self.steps = blocks[0] * blocks[1], key_tiles(self.n, self.n, causal, self.far)[0]
         self.kernel_args = dict(
             causal=causal, scale=(d + rot) ** -0.5,
-            tail=l - (self.n - 1) * self.rows, d=d, r=rot, window=self.far,
+            tail=l - (self.n - 1) * self.rows, d=d, r=rot, n=self.n, window=self.far,
         )
 
     def specs(self, before):
-        """BlockSpecs over the grid ``(lane group, i, j[, head])`` (with a
-        rotary part ``(lane group, i, head, j)``) for [B, lp, wp] operands
-        (rows of block ``i`` or ``j``, 128 lanes) and for ``n`` per-query
-        f32 vectors [B*H, n, lp]: ``own`` follows the kernel's own row
-        block ``i``, ``other`` the block ``j`` it is paired with — pinned to
-        the nearest visited pair's where the causal mask (or a window: to
-        inside ``[i - w, i]``, ``[i, i + w]`` in dK/dV, ``w`` the window in
-        blocks as the call's scalar operand gives it) skips the pair, so
-        a skipped step fetches nothing.  Returns ``(own, other, vec_own,
-        vec_other)`` and, with a rotary part, ``(q_rot's own, q_rot's other,
-        k_rot's own, k_rot's other)`` after them: q_rot's lanes are the
-        group's, the tiled k_rot (``_fwd_impl``) has one block of lanes."""
+        """BlockSpecs over the grid ``(lane group, a, b[, head])`` (with a
+        rotary part ``(lane group, a, head, b)``) for [B, lp, wp] operands
+        (rows of one block, 128 lanes) and for ``n`` per-query f32 vectors
+        [B*H, n, lp]: ``own`` follows the kernel's own row block, ``other``
+        the block it is paired with, both as ``_walk`` reads them off the step
+        (under a window with ``w``, the window in blocks, as the call's scalar
+        operand gives it) — the kernels read the same, so a step that holds
+        no work stays on a live neighbour's pair and fetches nothing.  Returns
+        ``(own, other, vec_own, vec_other)`` and, with a rotary part,
+        ``(q_rot's own, q_rot's other, k_rot's own, k_rot's other)`` after
+        them: q_rot's lanes are the group's, the tiled k_rot (``_fwd_impl``)
+        has one block of lanes."""
         groups, rows, heads, rot = self.groups, self.rows, self.heads, self.rot
-        last = self.n - 1
-        if not self.causal:
-            pair = lambda i, j, w: j                           # noqa: E731
-        elif self.far and before:
-            pair = lambda i, j, w: jnp.clip(j, jnp.maximum(i - w, 0), i)  # noqa: E731
-        elif self.far:
-            pair = lambda i, j, w: jnp.clip(j, i, jnp.minimum(i + w, last))  # noqa: E731
-        elif before:
-            pair = lambda i, j, w: jnp.minimum(i, j)           # noqa: E731
-        else:
-            pair = lambda i, j, w: jnp.maximum(i, j)           # noqa: E731
-        own = lambda i, j, w: i                                # noqa: E731
+        walk = functools.partial(
+            _walk, n=self.n, causal=self.causal, far=self.far, before=before, rot_heads=rot and heads,
+        )
+        own = lambda i, j: i                                   # noqa: E731
+        pair = lambda i, j: j                                  # noqa: E731
 
         def spec(block, index):
-            """``index(lane group, i, j, head, window in blocks)`` under
+            """``index(lane group, own block, paired block, head)`` under
             either grid order; a window call's scalar operand comes last."""
             def at(*ids):
                 w = None
@@ -761,26 +907,27 @@ class _Plan:
                     *ids, w_ref = ids
                     w = w_ref[0]
                 if rot:
-                    bg, i, hh, j = ids
+                    bg, a, hh, b = ids
                 else:
-                    bg, i, j, *hh = ids
+                    bg, a, b, *hh = ids
                     hh = hh[0] if hh else 0
-                return index(bg, i, j, hh, w)
+                i, j, _, hh = walk(a, b, hh, w=w)
+                return index(bg, i, j, hh)
 
             return pl.BlockSpec(block, at, memory_space=pltpu.VMEM)
 
         def mat(row, lanes):
             return spec(
                 (1, rows, _LANE),
-                lambda bg, i, j, hh, w: (
-                    bg // groups, row(i, j, w), lanes(bg % groups, hh)
+                lambda bg, i, j, hh: (
+                    bg // groups, row(i, j), lanes(bg % groups, hh)
                 ),
             )
 
         def vec(n, row):
             return spec(
                 (1, n, rows),
-                lambda bg, i, j, hh, w: (bg * heads + hh, 0, row(i, j, w)),
+                lambda bg, i, j, hh: (bg * heads + hh, 0, row(i, j)),
             )
 
         if rot:       # D = 128: a head's q / k / v lanes are its own block
@@ -886,7 +1033,7 @@ def _fwd_impl(q, k, v, causal, rot=(), keep=False, window=0):
     plan = _Plan(q.shape, causal, r, window)
     lq = q.shape[1]
     interpret = _use_interpret()
-    why = "key_tiles=" + ", ".join(
+    why = "steps=%d/%d " % plan.steps + "key_tiles=" + ", ".join(
         "%d/%d %s" % (*key_tiles(n_t, n_t, causal, -(-window // t)), name)
         for name, t, n_t in (
             ("fwd", _T_FWD, -(-lq // _T_FWD)), ("bwd", _T_BWD, -(-lq // _T_BWD))
@@ -1061,42 +1208,6 @@ def block_summary(mask, rows: int, counts=None):
 def _selected(mask_ref, rt):
     """bool [t, block]: the (query, key) pairs of the sub-tile ``rt`` that the mask keeps."""
     return mask_ref[0, rt, :].astype(jnp.int32) != 0
-
-
-def folded_pair(r, c, n: int):
-    """``(i, j, holds_work)``: the pair of a causal triangle of ``n`` row
-    blocks that step ``(r, c)`` of the FOLDED grid ``(ceil(n / 2), n + 1)``
-    visits — the one place that maps a grid step to its pair (the BlockSpecs'
-    index maps and the kernels both call it; integer compares and selects
-    only, on traced scalars or plain ints).  Row ``r``'s ``r + 1`` pairs
-    ``(r, 0) .. (r, r)`` and then row ``n - 1 - r``'s ``n - r`` pairs fill the
-    ``n + 1`` steps of grid row ``r``: every pair ``j <= i`` once, a row's
-    pairs on consecutive steps in ascending ``j``, no step above the
-    diagonal.  Where ``n`` is odd the middle row would come twice: its second
-    run holds no work and stays on the pair before it, ``(r, r)``, so nothing
-    is fetched or written back for it (at ``n`` = 1: one live step, one
-    idle)."""
-    first = c <= r
-    work = first | (n - 1 - r != r)
-    i = jnp.where(first, r, n - 1 - r)
-    j = jnp.where(first, c, jnp.where(work, c - r - 1, r))
-    return i, j, work
-
-
-def _folded_key_pair(r, c, n: int):
-    """``(i, j, holds_work)`` of the same fold read for dK/dV, whose rows are
-    KEY blocks ``j`` and whose inner axis is the queries ``i >= j``: a key
-    block has as many pairs as the row that mirrors it (``n - 1 - j``), so
-    grid row ``r`` is key block ``n - 1 - r`` with its ``r + 1`` query blocks
-    and then key block ``r`` with its ``n - r``, each in ascending ``i``."""
-    row, col, work = folded_pair(r, c, n)
-    j = n - 1 - row
-    return j + col, j, work
-
-
-def folded_grid(n: int) -> tuple[int, int]:
-    """The folded grid of a head at ``n`` row blocks: ``n * (n + 1) / 2`` pairs in ``ceil(n / 2) * (n + 1)`` steps."""
-    return (n + 1) // 2, n + 1
 
 
 def _masked_fwd_kernel(summary_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_s, l_s, acc_s, *, scale, heads, n):

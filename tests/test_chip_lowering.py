@@ -67,6 +67,10 @@ def test_flash_fwd_bwd_lowers_to_three_mosaic_calls(
     visited, total = map(int, re.search(r"key_tiles=(\d+)/(\d+)", line).groups())
     assert visited < total
     assert f"heads_per_block={128 // shape[3]})" in line
+    # the grid holds the pairs the causal rule can hold and no step besides (PR 65): a head of one block its one
+    # step, eight blocks a side the triangle's 36 pairs in 4 x 9 steps, four blocks 10 in 2 x 5
+    blocks = fa._blocks(shape[1])[0]
+    assert f" steps={blocks * (blocks + 1) // 2}/{blocks * (blocks + 1) // 2} key_tiles=" in line
 
 
 @pytest.fixture(scope="module")
@@ -608,6 +612,26 @@ def test_flash_with_a_rotary_part_compiles_for_v5e(compiled_kernel, v5e_device, 
     assert all("bf16[2,8192,4096]" in c and "bf16[2,8192,2048]" in c and "bf16[2,8192,128]" in c for c in calls)
     (line,) = set(path_lines)
     assert "attention path: pallas-compiled" in line and line.endswith("heads_per_block=1 rotary=64)")
+    assert " steps=36/36 key_tiles=136/256 fwd" in line      # the folded grid, a query row's two heads together (``_folded_heads``)
+
+
+def test_flash_under_a_window_and_over_an_odd_number_of_blocks_compiles_for_v5e(compiled_kernel, v5e_device, path_lines):
+    """Mosaic accepts the grids that hold idle steps: a window call at ``trinity_mini_job``'s shape (``w + 1`` = 3
+    steps a row block, the three a head that rows 0 and 1 leave idle pinned to the row's first pair), the window's
+    int32 [1] still AHEAD of the operand lists every call has; and a causal call over THREE blocks a head, whose
+    folded grid's middle row runs once and idles twice."""
+    def compiled(shape, **window):
+        arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=jax.sharding.SingleDeviceSharding(v5e_device))
+        loss = lambda q, k, v: jnp.sum(fa.flash_attention(q, k, v, True, **window).astype(jnp.float32) ** 2)  # noqa: E731
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).trace(arg, arg, arg).lower(lowering_platforms=("tpu",)).compile().as_text()
+
+    text = compiled((1, 8192, 32, 128), window=2048)
+    assert _signatures(text) == [(3, 0), (4, 1), (4, 2)]
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3 and all(re.search(r"operand_layout_constraints=\{s32\[1\]\{0\}, bf16\[", c) for c in calls), calls
+    assert any(" steps=24/21 key_tiles=70/256 fwd, 252/1024 bwd" in line and line.endswith("window=2048)") for line in path_lines)
+    assert _signatures(compiled((2, 3072, 4, 128))) == [(3, 0), (4, 1), (4, 2)]
+    assert any(" steps=8/6 key_tiles=" in line for line in path_lines)
 
 
 def test_kanana2_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(

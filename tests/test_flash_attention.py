@@ -237,6 +237,7 @@ def test_attention_path_line_reports_key_tiles(monkeypatch, causal):
     visited, total = map(int, re.search(r"key_tiles=(\d+)/(\d+)", line).groups())
     assert (visited < total) == causal
     assert "key_tiles=3/4 fwd, 10/16 bwd" in line or not causal
+    assert "steps=1/1 key_tiles=" in line       # a head of one block: one step, one pair
     assert line.endswith("heads_per_block=2)")
 
 
@@ -458,7 +459,135 @@ def test_attention_path_line_names_the_window(monkeypatch):
     fa.flash_attention(q, k, v, True, window=256)
     (line,) = lines
     assert "attention path: pallas-interpret" in line and line.endswith("heads_per_block=1 window=256)")
-    assert "key_tiles=30/64 fwd, 108/256 bwd" in line
+    assert "steps=12/9 key_tiles=30/64 fwd, 108/256 bwd" in line      # four rows of w + 1 = 3 steps; rows 0 and 1 hold 1 and 2 pairs
+
+
+# -- the grid holds the pairs a call's rule can hold (PR 65) --------------------
+# A causal call's grid is the triangle folded (``folded_pair``), a window call's ``w + 1`` steps a row block
+# (``window_pair``); ``_walk`` is the one place that reads a grid step back into its pair, for the BlockSpecs' index
+# maps and for the kernels.  Evaluated here on plain ints, step by step over a plan's own grid.
+
+
+def _plan_of(fa, n, w, rot=0):
+    return fa._Plan((1, 128 * n, 128 // rot if rot else 1, 128), True, rot, 128 * w)
+
+
+def _steps_of(fa, plan, before):
+    """``[(own, other, holds_work, head)]`` of a lane group's grid steps in the order the grid runs them."""
+    import itertools
+
+    walk = lambda a, b, hh: tuple(  # noqa: E731
+        int(x) for x in fa._walk(a, b, hh, n=plan.n, causal=True, far=plan.far, before=before, rot_heads=plan.rot and plan.heads))
+    if plan.rot:
+        return [walk(a, b, hh) for a, hh, b in itertools.product(*map(range, plan.grid[1:]))]
+    return [walk(a, b, 0) for a, b in itertools.product(*map(range, plan.grid[1:3]))]
+
+
+WALKS = [(n, w) for n in range(1, 10) for w in range(n)]       # w = 0: no window, the fold
+
+
+def _runs(keys):
+    """``keys`` with consecutive repeats dropped."""
+    return [key for k, key in enumerate(keys) if k == 0 or key != keys[k - 1]]
+
+
+@pytest.mark.parametrize("before", [True, False], ids=["rows_are_queries", "rows_are_keys"])
+@pytest.mark.parametrize("n,w", WALKS, ids=[f"{n}_blocks" + f"_window_{w}" * bool(w) for n, w in WALKS])
+def test_a_grid_visits_every_pair_its_rule_holds_once_and_in_row_order(monkeypatch, n, w, before):
+    """Every (block, block) pair the rule holds is visited exactly once; a row's pairs are consecutive steps in
+    ascending order of the paired block (so the carried state, the resident output block and the order of every sum are
+    the square grid's); a step that holds no work sits on a live neighbour's pair (nothing is fetched for it) — under a
+    window with rows as queries on the row's FIRST pair, whose blocks are so asked for a step early; the live steps are
+    what ``_Plan.pairs_computed`` multiplies, counted in blocks, what ``key_tiles`` counts and what the path line says."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_BLOCK", 128)
+    plan = _plan_of(fa, n, w)
+    assert (plan.n, plan.far) == (n, w)
+    assert plan.grid[1:] == ((1, 1) if n == 1 else (n, w + 1) if w else fa.folded_grid(n))
+    steps = _steps_of(fa, plan, before)
+    live = [(own, other) for own, other, work, _ in steps if work]
+    held = [(i, j) if before else (j, i) for i in range(n) for j in range(n) if j <= i and (not w or i - j <= w)]
+    assert sorted(live) == sorted(held) and len(set(live)) == len(live)
+    assert len(_runs([own for own, _ in live])) == len({own for own, _ in live})     # a row's pairs are consecutive steps
+    for own in {own for own, _ in live}:
+        others = [other for o, other in live if o == own]
+        assert others == sorted(others)
+    width = plan.grid[2]
+    for k, (own, other, work, _) in enumerate(steps):
+        if work:
+            continue
+        row = steps[k - k % width:k - k % width + width]        # the grid row's steps: its live pairs before and after step k
+        nearest = [s[:2] for s in row[:k % width] if s[2]][-1:] + [s[:2] for s in row[k % width + 1:] if s[2]][:1]
+        assert (own, other) in nearest, (k, steps)
+        if w and before:     # the idle steps of a window's first rows come AHEAD of the row's first live pair
+            assert (own, other) == (own, 0) == nearest[0] and own < w
+    assert len(live) == plan.pairs_computed(plan.rows, before) // plan.rows ** 2 == fa.key_tiles(n, n, True, w)[0]
+    assert plan.steps == (len(steps), len(live))
+    assert len(steps) - len(live) == (w * (w + 1) // 2 if w else (n % 2) * (n + 1) // 2 if n > 1 else 0)
+
+
+@pytest.mark.parametrize("before", [True, False], ids=["rows_are_queries", "rows_are_keys"])
+@pytest.mark.parametrize("heads", [2, 4])
+@pytest.mark.parametrize("n", range(1, 10))
+def test_the_folded_grid_keeps_a_rows_heads_together_where_the_head_comes_before_the_paired_block(monkeypatch, n, heads, before):
+    """The rotary order: every head visits every causal pair once, a (row, head)'s pairs are consecutive and ascending
+    (ONE slab of carried state), and a row's heads are consecutive — so the group's q_rot / dq_rot / dk_rot block is
+    fetched and written once a row, as on the square grid; idle steps (odd ``n``) stay on the last head's last pair."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_BLOCK", 128)
+    plan = _plan_of(fa, n, 0, rot=128 // heads)
+    assert plan.grid[1:] == ((1, heads, 1) if n == 1 else (fa.folded_grid(n)[0], heads, n + 1))
+    steps = _steps_of(fa, plan, before)
+    live = [(own, hh, other) for own, other, work, hh in steps if work]
+    held = [(i, j) if before else (j, i) for i in range(n) for j in range(i + 1)]
+    assert sorted(live) == sorted((own, hh, other) for own, other in held for hh in range(heads)) and len(set(live)) == len(live)
+    runs = _runs([s[:2] for s in live])
+    assert len(runs) == n * heads                           # a (row, head)'s pairs in one run
+    assert len(_runs([own for own, _ in runs])) == n        # and a row's heads in one
+    for own, hh in runs:
+        others = [other for o, h, other in live if (o, h) == (own, hh)]
+        assert others == sorted(others)
+    for k, (own, other, work, hh) in enumerate(steps):
+        if not work:
+            assert (own, other, hh) == (steps[k - 1][0], steps[k - 1][1], steps[k - 1][3])
+    assert plan.steps == (len(steps) // heads, len(live) // heads)
+
+
+GRIDS = [
+    ("causal_a_head_a_lane_group", dict(h=1, d=128)),
+    ("causal_two_heads_a_lane_group", dict(h=2, d=64)),
+    ("rotary_two_heads_a_group", dict(h=2, d=128, r=64)),
+    ("window_of_one_block", dict(h=1, d=128, w=1)),
+    ("window_one_block_short_of_the_sequence_two_heads", dict(h=2, d=64, w=-1)),
+]
+
+
+@pytest.mark.parametrize("name,case", GRIDS, ids=[name for name, _ in GRIDS])
+@pytest.mark.parametrize("n", [2, 3, 4])
+def test_folded_and_window_grids_give_the_references_outputs_and_gradients(monkeypatch, n, name, case):
+    """The interpreter over the folded grid and the window's ``w + 1`` steps a row at two, three (odd: idle steps) and
+    four blocks a head, against the XLA reference: outputs and every gradient, at the limits this file has."""
+    fa = _window_case(monkeypatch, 128, 64, 32)
+    h, d, r, w = case["h"], case["d"], case.get("r", 0), case.get("w", 0) % n
+    l = 128 * n
+    if r:
+        args = _rotary_inputs(jnp.float32, b=1, l=l, h=h, r=r, seed=n)
+        attend = lambda *a: fa.flash_attention(*a[:3], True, *a[3:])  # noqa: E731
+        reference = lambda *a: _explicit_masked_softmax(*a, True)     # noqa: E731
+    else:
+        args = _qkv(jnp.float32, b=1, l=l, h=h, d=d, seed=n)
+        attend = lambda q, k, v: fa.flash_attention(q, k, v, True, window=128 * w or None)   # noqa: E731
+        reference = lambda q, k, v: attention_reference(q, k, v, causal=True, window=128 * w or None)  # noqa: E731
+    grid = fa._Plan(args[0].shape, True, r, 128 * w).grid
+    assert (grid[1], grid[3 if r else 2]) == ((n, w + 1) if w else fa.folded_grid(n))
+    cot = jax.random.normal(jax.random.key(2), args[0].shape, jnp.float32)
+    out, grads = _output_and_gradients(attend, cot)(*args)
+    want, want_grads = _output_and_gradients(reference, cot)(*args)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+    for got, ref, which in zip(grads, want_grads, ("dq", "dk", "dv", "dq_rot", "dk_rot")):
+        np.testing.assert_allclose(got, ref, atol=5e-5, rtol=5e-5, err_msg=which)
 
 
 #: sha256 of the jaxpr (forward AND gradient, the kernels' bodies in it) of the calls the older cells make — causal or not,
@@ -468,13 +597,15 @@ def test_attention_path_line_names_the_window(monkeypatch):
 #: The lowered steps ``tests/test_chip_lowering.py`` pins hold no kernel (lowered from the CPU the attention is the XLA
 #: reference) and every EVA case here has ONE sub-tile a window: a ``_visible`` that paired EVA's sub-tiles with the positions
 #: AFTER them passed all of tier-1 and read 1.3 on the chip's check.  A PR that changes a kernel's body on purpose re-pins.
+#: RE-PINNED in PR 65 for the four calls of SEVERAL blocks a head (8192, 4096, 2048 and the rotary call: ``_visit`` reads its pair
+#: from the folded grid, ``_walk``); the three of ONE block a head and EVA's are the values of PR 56's parent still.
 KERNEL_JAXPR_SHA256 = {
-    "flash causal [1, 8192, 16, 128]": "138bae355d569a0e",
+    "flash causal [1, 8192, 16, 128]": "ccaecbe80d5b67fe",
     "flash causal [2, 1024, 16, 64]": "8e96fe343a8e22ea",
-    "flash causal [1, 4096, 16, 128]": "523c9166f64bb995",
+    "flash causal [1, 4096, 16, 128]": "c1cb096c83c47516",
     "flash full [1, 1024, 4, 64]": "f8a1fcd73c9682e4",
-    "flash causal [1, 8192, 32, 128] rotary 64": "d0886d638be895a1",
-    "flash causal [1, 2048, 8, 128]": "d14fbf11fa3e7420",
+    "flash causal [1, 8192, 32, 128] rotary 64": "7f0a093e6e6fbf4e",
+    "flash causal [1, 2048, 8, 128]": "6b6c9bc4bb49ff69",
     "flash causal [1, 384, 2, 64]": "c327d37e50e8a066",
     "eva [1, 16384, 16, 128] window 2048 chunk 16": "41c3b7b5b2b5960b",
 }
