@@ -96,16 +96,22 @@ import jax  # noqa: E402
 import pytest  # noqa: E402
 
 
-#: Collected FIRST, in this order (PR 56).  The driver's command (``-n 6 --dist load``) deals the collection out in
-#: CONSECUTIVE chunks: a 24th of it to each worker to begin with, smaller ones as a worker runs dry, and nothing is ever
-#: taken back.  ``tests/test_chip_lowering.py`` (AOT compiles of the cells' whole steps for a described v5e, half a
-#: minute a case, up to 7 GiB a compile: one at a time is how they should run) is the longest block any worker is
-#: handed: as collected it fell into a third chunk, started at 580 s and ended at 1,370 of a run whose other five workers
-#: were done at 1,100 (the junit file of the driver's run of PR 56's tree, 1,404 s of the 1,470 allowed).  First, it starts
-#: at second 0; and behind it go 131 cases that take 0.2 s together, so that the chunk it opens holds nothing else that
-#: weighs (the benchmark's growth rehearsal, 660 s, is what the collection begins with otherwise).  Within a file the
-#: order is the collection's.
-_COLLECTED_FIRST = ("test_chip_lowering.py", "test_renamed_metrics.py")
+#: Collected FIRST, in this order (PR 56; two lowering files since PR 66).  The driver's command (``-n 6 --dist load``)
+#: deals the collection out in CONSECUTIVE chunks: a 24th of it (``N // 24`` cases: 111 of 2,683) to each of the six
+#: workers to begin with, in the workers' order, smaller ones as a worker runs dry, and nothing is ever taken back.
+#: The two lowering files (AOT compiles of the cells' whole steps for a described v5e, up to a minute a case, one after
+#: another within a file: two at once fit the host, 6.9 + 4.7 GiB for the two largest, which are ``slow`` now; and the
+#: cases that lower a whole step and pin or read its text) are the longest blocks any worker is handed that this order
+#: can place: as ONE file, collected where its name falls, the block started at 580 s and ended at 1,370 of a run whose
+#: other workers were done at 1,100 (the driver's run of PR 56's tree), and collected first it was 923 s of one worker
+#: from second 0 (PR 66's sitting).  So the first file opens the FIRST worker's deal, with 150 cases behind it that
+#: take 0.2 s together; the second file then lies whole inside the SECOND worker's deal and starts at second 0 too; and
+#: the cases that fill that deal up behind it are a file's that takes 27 s in all (what the collection begins with
+#: otherwise is the benchmark's growth rehearsal, 640 s, which now opens the third worker's).  That holds while ``first
+#: + 150 >= N // 24``, ``first + 150 + second <= 2 * (N // 24)`` and ``first + 150 + second + 89 >= 2 * (N // 24)``:
+#: with 12 + 150 + 19 cases, for 2,184 <= N < 3,264 (``tests/test_platform.py`` holds the run to it).  Within a file
+#: the order is the collection's.
+_COLLECTED_FIRST = ("test_chip_lowering.py", "test_renamed_metrics.py", "test_chip_lowering_pins.py", "test_data.py")
 
 
 def pytest_collection_modifyitems(items):

@@ -2,11 +2,26 @@
 platform ``tpu`` as the real ops (``tpu_custom_call``, ``ragged_all_to_all``),
 and — where libtpu can describe a v5e topology without a chip — they COMPILE
 for it, Mosaic included.  Nothing here runs on a TPU; ``chip_smoke.py`` does.
+
+ONE of two files (PR 66): a file's cases run one after another on one xdist
+worker, and all of them together were the run's longest serial block.  HERE:
+the fixtures, helpers and pins both files read (the pins' child script and two
+cells' cases under ``tests/benchmark/`` import this module by name) and the
+cases that COMPILE: one kernel pair or a small program, and a cell's whole
+step.  In ``tests/test_chip_lowering_pins.py``: the cases that only lower
+(the pins and the four steps read as lowered text among them) and
+``gpt2m_job``'s two compiles, which weigh as much as the rest of this file.
+``tests/conftest.py`` (``_COLLECTED_FIRST``) opens a different worker's first
+deal with each.  The four whole-step compiles over a minute each under the
+driver's command (``kimi_linear`` 184 s, ``kanana2`` 127, ``nemotron3`` 119,
+``deepfm_job`` 60: PR 66's sitting) are marked ``slow``: the driver compiles
+each of those steps on a real v5e in every PR's check of its cell, and what
+their cases assert beyond "it compiles and fits" is read off the LOWERED step
+by ``test_*_step_lowers_for_v5e_*`` in the other file.
 """
 
 from __future__ import annotations
 
-import math
 import os
 import re
 
@@ -48,29 +63,6 @@ def compiled_kernel(monkeypatch):
     monkeypatch.setattr(fa, "_use_interpret", lambda: False)
 
 
-@pytest.mark.parametrize("shape", FLASH_SHAPES)
-def test_flash_fwd_bwd_lowers_to_three_mosaic_calls(
-    compiled_kernel, path_lines, shape
-):
-    arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-    lowered = (
-        jax.jit(jax.value_and_grad(_flash_loss, argnums=(0, 1, 2)))
-        .trace(arg, arg, arg)
-        .lower(lowering_platforms=("tpu",))
-    )
-    # fwd, dq, dkv — compiled kernels, not the interpreter's XLA expansion.
-    assert lowered.as_text().count("tpu_custom_call") == 3
-    # ... announced as such, in the line benchmark/run.py and chip_smoke.py
-    # match, with fewer key tiles visited than there are.
-    (line,) = set(path_lines)
-    assert re.search(r"attention path: ([\w-]+)", line).group(1) == "pallas-compiled"
-    visited, total = map(int, re.search(r"key_tiles=(\d+)/(\d+)", line).groups())
-    assert visited < total
-    assert f"heads_per_block={128 // shape[3]})" in line
-    # the grid holds the pairs the causal rule can hold and no step besides (PR 65): a head of one block its one
-    # step, eight blocks a side the triangle's 36 pairs in 4 x 9 steps, four blocks 10 in 2 x 5
-    blocks = fa._blocks(shape[1])[0]
-    assert f" steps={blocks * (blocks + 1) // 2}/{blocks * (blocks + 1) // 2} key_tiles=" in line
 
 
 @pytest.fixture(scope="module")
@@ -184,37 +176,6 @@ def test_the_gated_convolution_pair_compiles_for_v5e_at_the_cells_shape_and_no_f
                 assert not any(re.search(kernel["pattern"], event) for event in events), (metric, kernel["what"])
 
 
-def test_deepfm_ragged_step_lowers_with_ragged_all_to_all(devices):
-    """The 4-device DeepFM step on the explicit ragged route lowers for TPU
-    with the real collective (XLA:CPU refuses the op outright, so tier-1
-    otherwise only ever sees ``ragged_emulated``)."""
-    spec = load_model_spec(
-        "elasticdl_tpu.models", "deepfm.model_spec",
-        buckets_per_feature=256, embedding_dim=8, hidden=(16,),
-        compute_dtype="float32",
-    )
-    trainer = Trainer(
-        spec,
-        JobConfig(
-            distribution_strategy=DistributionStrategy.PARAMETER_SERVER,
-            embedding_lookup_impl="ragged",
-        ),
-        create_mesh(devices, num_devices=4),
-    )
-    assert trainer.ctx.embedding_impl == "ragged"
-    state = trainer.init_state(jax.random.key(0))
-    batch = trainer.shard_batch(spec.example_batch(32))
-    step = trainer._structured(
-        trainer._train_steps, build_train_step, batch,
-        host_keys=(), variant_budget=1, **trainer._train_build_kwargs(),
-    )
-    text = (
-        step.trace(state, batch, trainer._active_device())
-        .lower(lowering_platforms=("tpu",))
-        .as_text()
-    )
-    # vectors back, cotangents out; the ids go by all_gather (PR 55).
-    assert text.count("ragged_all_to_all") == 2
 
 
 @pytest.fixture
@@ -287,6 +248,7 @@ def _assert_the_sweep_applies_the_table_update(text: str, rows: int):
         assert f"f32[{rows},128]" not in header, header[:300]
 
 
+@pytest.mark.slow  # 60 s under the driver's command (PR 66); its lowered twin, which runs: ``test_deepfm_job_step_lowers_for_v5e_with_the_sweep_applying_the_table_update``
 def test_deepfm_job_step_builds_its_table_gradient_by_the_sweep(
     v5e_device, as_on_the_chip
 ):
@@ -379,32 +341,6 @@ def test_x4_init_and_step_compile_for_a_v5e_host(v5e_host, as_on_the_chip):
     _assert_the_sweep_applies_the_table_update(text, rows // 4)
 
 
-def test_gpt2_medium_step_has_no_table_update_in_it(as_on_the_chip):
-    """``gpt2_medium`` declares no table and never calls
-    ``embedding_lookup``: its step, lowered for the chip at the real size,
-    holds no Mosaic call (its attention takes the XLA reference path off the
-    TPU, so any would be a sweep's), where DeepFM's, the control, holds the
-    one under ``table_apply``.  (Scope names are not asked of the lowered
-    text: inner jits cached by earlier tests of the process carry theirs.)"""
-    def lowered_text(model_def, strategy, minibatch, **params):
-        spec = load_model_spec("elasticdl_tpu.models", model_def, **params)
-        mesh = create_mesh(jax.devices()[:1], num_devices=1)
-        trainer = Trainer(spec, JobConfig(distribution_strategy=strategy), mesh)
-        step, args = _abstract_scan_step(trainer, mesh, minibatch=minibatch, steps=2)
-        return step.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
-
-    text = lowered_text(
-        "transformer_lm.model_spec", DistributionStrategy.ALLREDUCE, 16,
-        vocab=50257, dim=1024, n_heads=16, n_layers=24, seq_len=1024,
-        max_seq=1024, remat=True, parallelism="sequence",
-    )
-    assert "tpu_custom_call" not in text
-    control = lowered_text(
-        "deepfm.model_spec", DistributionStrategy.PARAMETER_SERVER, 64,
-        buckets_per_feature=786432, embedding_dim=10, hidden=(400, 400, 400),
-        host_tier=False,
-    )
-    assert control.count("tpu_custom_call") == 1
 
 
 # --------------------------------------------------------------- olmoe_job
@@ -432,61 +368,6 @@ def _flash_calls(text: str):
 V5E_BYTES_LIMIT = [int(15.75 * 2**30), None]
 
 
-@pytest.mark.parametrize("bytes_limit", V5E_BYTES_LIMIT, ids=["v5e_budget", "budget_0"])
-def test_gpt2_medium_step_compiles_for_v5e_with_no_layout_glue_at_flash(
-    v5e_device, olmoe_as_on_the_chip, path_lines, monkeypatch, bytes_limit
-):
-    """``gpt2m_job``'s real step (24 layers, 16 sequences of 1024, remat)
-    compiled for a described v5e: the flash kernels' operands ARE the
-    model's ``[B, L, H*D]`` arrays, two 64-wide heads to a 128-lane block.
-    Nothing pads a head to 128 lanes, and no transpose, copy or relayout of
-    an array the size of q stands between the projections and a kernel (the
-    parent had some thirty such passes a layer: PERF.md, PR 31).  With the
-    budget the trainer resolves from a v5e's memory every layer keeps its
-    flash output and logsumexp (the forward ONCE a layer) and the step stays
-    under the trainer's line; with none (budget 0) it is the program it
-    was: the forward twice a layer."""
-    from elasticdl_tpu.parallel import trainer as trainer_lib
-
-    monkeypatch.setattr(trainer_lib, "device_bytes_limit", lambda devices: bytes_limit)
-    layers = 24
-    spec = load_model_spec(
-        "elasticdl_tpu.models", "transformer_lm.model_spec", vocab=50257,
-        dim=1024, n_heads=16, n_layers=layers, seq_len=1024, max_seq=1024,
-        remat=True, parallelism="sequence",
-    )
-    mesh = create_mesh([v5e_device], num_devices=1)
-    trainer = Trainer(
-        spec, JobConfig(distribution_strategy=DistributionStrategy.ALLREDUCE), mesh
-    )
-    step, args = _abstract_scan_step(trainer, mesh, minibatch=16, steps=2)
-    compiled = step.trace(*args).lower(lowering_platforms=("tpu",)).compile()
-    text = compiled.as_text()
-    (line,) = set(path_lines)
-    assert "attention path: pallas-compiled" in line
-    assert line.endswith("heads_per_block=2)")
-    # forward, its re-run under remat unless the layer keeps its output, dQ,
-    # dK + dV, every layer; each over the model's own [B, L, H * D]
-    calls = _flash_calls(text)
-    plan = trainer.keep_plan
-    if bytes_limit is None:
-        assert plan is None and len(calls) == 4 * layers
-    else:
-        assert len(calls) == 3 * layers
-        assert 0.5 * plan.tagged < plan.kept <= plan.budget < plan.tagged
-        assert 12.5 * 2**30 < trainer_lib.compiled_bytes(compiled) < plan.line == bytes_limit - trainer_lib.REMAT_HEADROOM
-        # the estimate of the step with nothing kept (the compiler's own account: 9.795 GiB)
-        assert abs(plan.estimate - 9.795 * 2**30) < 0.15 * 2**30
-    assert all("bf16[16,1024,1024]" in c and "bf16[256," not in c for c in calls)
-    # q is 16 x 1024 x 16 x 64 elements; padded to 128 lanes, twice that
-    q_sized = {16 * 1024 * 16 * 64, 16 * 1024 * 16 * 128}
-    glue = [
-        found.group(0) for found in re.finditer(
-            r"= bf16\[([\d,]+)\]\S* (pad|transpose|copy|reshape)\(", text
-        )
-        if math.prod(map(int, found.group(1).split(","))) in q_sized
-    ]
-    assert not glue, glue[:5]
 
 
 def test_olmoe_step_compiles_for_v5e_with_its_scopes_and_no_row_scatter(
@@ -634,6 +515,7 @@ def test_flash_under_a_window_and_over_an_odd_number_of_blocks_compiles_for_v5e(
     assert any(" steps=8/6 key_tiles=" in line for line in path_lines)
 
 
+@pytest.mark.slow  # 127 s under the driver's command (PR 66); its lowered twin, which runs: ``test_kanana2_step_lowers_for_v5e_with_its_scopes_kernels_and_no_score_matrix``
 def test_kanana2_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
     v5e_device, olmoe_as_on_the_chip, path_lines
 ):
@@ -841,6 +723,7 @@ def test_evabyte_step_compiles_for_v5e_with_its_scopes_and_no_score_matrix(
     assert any("attention path: pallas-compiled" in line and "eva window=2048" in line for line in path_lines), path_lines
 
 
+@pytest.mark.slow  # 119 s under the driver's command (PR 66); its lowered twin, which runs: ``test_nemotron3_step_lowers_for_v5e_with_its_scopes_kernels_and_no_score_matrix``
 def test_nemotron3_step_compiles_for_v5e_with_its_scopes_and_the_flash_kernels_lists(
     v5e_device, olmoe_as_on_the_chip, path_lines, monkeypatch
 ):
@@ -909,6 +792,7 @@ def test_nemotron3_step_compiles_for_v5e_with_its_scopes_and_the_flash_kernels_l
     assert len(patterns) == 3 and params["remat"]
 
 
+@pytest.mark.slow  # 184 s under the driver's command (PR 66); its lowered twin, which runs: ``test_kimi_linear_step_lowers_for_v5e_with_its_scopes_kernels_and_no_score_matrix``
 def test_kimi_linear_step_compiles_for_v5e_inside_the_line_with_its_scopes_and_the_flash_kernels_lists(
     v5e_device, olmoe_as_on_the_chip, path_lines, monkeypatch
 ):
@@ -1075,40 +959,3 @@ MOE_LM_STEP_SHA256 = {
 
 #: ... and ``gpt2_medium``'s beside them: ONE script lowers a cell from its two files, whatever its ``model_def``
 LOWERED_STEP_SHA256 = {("gpt2_medium", "job_seq1k"): GPT2_MEDIUM_STEP_SHA256, **MOE_LM_STEP_SHA256}
-
-
-@pytest.mark.parametrize("config,traffic", sorted(LOWERED_STEP_SHA256))
-def test_the_moe_lm_cells_lowered_steps_are_the_pinned_programs(config, traffic):
-    import hashlib
-    import os
-    import subprocess
-    import sys
-
-    script = (
-        "import hashlib, json, sys, jax\n"
-        "sys.path.insert(0, 'tests')\n"
-        "from elasticdl_tpu.common.config import DistributionStrategy, JobConfig\n"
-        "from elasticdl_tpu.models.spec import load_model_spec\n"
-        "from elasticdl_tpu.parallel.mesh import create_mesh\n"
-        "from elasticdl_tpu.parallel.trainer import Trainer\n"
-        "import test_chip_lowering as T\n"
-        f"c = json.load(open('benchmark/configs/{config}.json'))\n"
-        f"t = json.load(open('benchmark/traffic/{traffic}.json'))\n"
-        "spec = load_model_spec('elasticdl_tpu.models', c['model_def'], **c['model_params'])\n"
-        "mesh = create_mesh(jax.devices()[:1], num_devices=1)\n"
-        "trainer = Trainer(spec, JobConfig(distribution_strategy=DistributionStrategy.ALLREDUCE), mesh)\n"
-        "step, args = T._abstract_scan_step(trainer, mesh, minibatch=t['minibatch_size'], steps=t['minibatches_per_task'])\n"
-        "text = step.trace(*args).lower(lowering_platforms=('tpu',)).as_text()\n"
-        "print('SHA', hashlib.sha256(text.encode()).hexdigest())\n"
-    )
-    # A fresh process: inner jits cached by earlier tests of this one would
-    # carry other names into the text.
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    done = subprocess.run(
-        [sys.executable, "-c", script], cwd=root, capture_output=True, text=True,
-        timeout=600, env=dict(os.environ, PYTHONPATH=root),
-    )
-    assert done.returncode == 0, done.stderr[-2000:]
-    (sha,) = re.findall(r"^SHA (\w+)$", done.stdout, re.M)
-    assert len(hashlib.sha256(b"").hexdigest()) == len(sha)
-    assert sha == LOWERED_STEP_SHA256[config, traffic]

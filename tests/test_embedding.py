@@ -6,6 +6,8 @@ Tables are lane-packed [P, pack*dim] (pack = 128//dim logical rows per
 physical row — ops/embedding.py module docstring); a plain [V, dim] table is
 the pack == 1 case.  Tests cover both, since models use pack > 1 layouts."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -406,26 +408,17 @@ def _route_ids(case: str, n: int) -> np.ndarray:
     return ids.reshape(-1).astype(np.int32)
 
 
-@pytest.mark.parametrize("case", ["uniform", "one_owner", "a_starved_shard", "junk", "duplicates"])
-@pytest.mark.parametrize("n_dev", [2, 4, 8])
-def test_ragged_route_is_the_dense_one_and_hands_over_what_it_scatters(devices, n_dev, case):
-    """On the emulated route: forward equal to ``_dense_lookup``'s exactly,
-    table gradient equal to the dense route's to float32 summation order,
-    the rows a shard received and those inside its range what the ids say,
-    and the handed carrier's rows, added at the physical rows handed beside
-    them, the plain path's table gradient."""
+@functools.lru_cache(maxsize=None)
+def _routes(n_dev: int):
+    """``{route: (table, ids, cot) -> (vectors, the table's gradient, [rows received, rows in range] a shard)}`` on a
+    mesh of ``n_dev`` devices, each ONE jitted program: the dense route, the emulated ragged one, and the ragged one
+    with its rows HANDED over (the carrier's rows added, as the trainer's sweep does, by a scatter-add).  The ids are
+    an operand, so the five id cases of a mesh read one compiled triple (each case compiled its own three: PR 66)."""
     from elasticdl_tpu.ops.embedding import route_taps
 
-    mesh = create_mesh(devices, num_devices=n_dev)
+    mesh = create_mesh(jax.devices(), num_devices=n_dev)
     axis = mesh.axis_names[0]
-    rng = np.random.default_rng(7)
-    table = pack_table(
-        jnp.asarray(rng.standard_normal((ROUTE_VOCAB, ROUTE_DIM)), jnp.float32), ROUTE_DIM
-    )
-    ids = _route_ids(case, n_dev)
-    cot = jnp.asarray(rng.standard_normal((ids.shape[0], ROUTE_DIM)), jnp.float32)
-    rows_local = ROUTE_VOCAB // n_dev
-    P_local, W = table.shape[0] // n_dev, table.shape[1]
+    P_local, W = ROUTE_VOCAB // 8 // n_dev, 128       # ``pack_table``'s: 8 rows of 11 floats to a physical row of 128
 
     def run(impl, handed=False):
         ctx = ParallelContext(axis_name=axis, sharded_embeddings=True, embedding_impl=impl)
@@ -456,10 +449,32 @@ def test_ragged_route_is_the_dense_one_and_hands_over_what_it_scatters(devices, 
         return jax.jit(shard_map(
             local, mesh=mesh, in_specs=(P(axis),) * 3, out_specs=(P(axis),) * 3,
             check_vma=False,
-        ))(table, jnp.asarray(ids), cot)
+        ))
+
+    return {"dense": run("dense"), "ragged": run("ragged_emulated"), "handed": run("ragged_emulated", handed=True)}
+
+
+@pytest.mark.parametrize("case", ["uniform", "one_owner", "a_starved_shard", "junk", "duplicates"])
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+def test_ragged_route_is_the_dense_one_and_hands_over_what_it_scatters(devices, n_dev, case):
+    """On the emulated route: forward equal to ``_dense_lookup``'s exactly,
+    table gradient equal to the dense route's to float32 summation order,
+    the rows a shard received and those inside its range what the ids say,
+    and the handed carrier's rows, added at the physical rows handed beside
+    them, the plain path's table gradient."""
+    rng = np.random.default_rng(7)
+    table = pack_table(
+        jnp.asarray(rng.standard_normal((ROUTE_VOCAB, ROUTE_DIM)), jnp.float32), ROUTE_DIM
+    )
+    assert table.shape == (ROUTE_VOCAB // 8, 128)
+    ids = _route_ids(case, n_dev)
+    cot = jnp.asarray(rng.standard_normal((ids.shape[0], ROUTE_DIM)), jnp.float32)
+    rows_local = ROUTE_VOCAB // n_dev
+    routes = _routes(n_dev)
+    run = lambda route: routes[route](table, jnp.asarray(ids), cot)  # noqa: E731
 
     dense_vec, dense_bar, _ = run("dense")
-    vec, bar, counts = run("ragged_emulated")
+    vec, bar, counts = run("ragged")
     np.testing.assert_array_equal(np.asarray(vec), np.asarray(dense_vec))
     np.testing.assert_allclose(np.asarray(bar), np.asarray(dense_bar), rtol=1e-6, atol=1e-6)
     assert float(jnp.abs(dense_bar).max()) > 0.5
@@ -477,7 +492,7 @@ def test_ragged_route_is_the_dense_one_and_hands_over_what_it_scatters(devices, 
     if case == "a_starved_shard":
         assert received[0] == 0
 
-    handed_vec, handed_bar, handed_counts = run("ragged_emulated", handed=True)
+    handed_vec, handed_bar, handed_counts = run("handed")
     np.testing.assert_array_equal(np.asarray(handed_vec), np.asarray(vec))
     np.testing.assert_array_equal(np.asarray(handed_counts), np.asarray(counts))
     np.testing.assert_allclose(np.asarray(handed_bar), np.asarray(bar), rtol=1e-6, atol=1e-6)

@@ -108,14 +108,14 @@ def test_the_eight_heads_are_held_to_the_targets_the_record_has(l):
     rng = np.random.default_rng(l)
     logits = jnp.asarray(rng.normal(size=(2, l, 8, 320)), jnp.float32)
     labels = jnp.asarray(rng.integers(0, 320, (2, l)), jnp.int32)
-    got = moe_lm._cross_entropy({"logits": logits}, {"labels": labels})
-    per_head = [
-        float(jnp.mean(optax.softmax_cross_entropy_with_integer_labels(logits[:, : l - p, p], labels[:, p:])))
-        for p in range(min(8, l))
-    ]
-    assert abs(float(got) - float(np.mean(per_head))) < 1e-5
+    loss = jax.jit(lambda logits: moe_lm._cross_entropy({"logits": logits}, {"labels": labels}))  # ONE program, not a few dozen eager ones a head
+    got = loss(logits)
+    per_head = jax.jit(lambda logits: jnp.stack([
+        jnp.mean(optax.softmax_cross_entropy_with_integer_labels(logits[:, : l - p, p], labels[:, p:])) for p in range(min(8, l))
+    ]))(logits)
+    assert abs(float(got) - float(np.mean(np.asarray(per_head)))) < 1e-5
     # no head reads past the record: heads 1..7 have no target at the last position
-    moved = moe_lm._cross_entropy({"logits": logits.at[:, l - 1, 1:].add(100.0)}, {"labels": labels})
+    moved = loss(logits.at[:, l - 1, 1:].add(100.0))
     assert float(moved) == float(got)
 
 

@@ -6,6 +6,8 @@ path everywhere; the chip runs the same kernel compiled).  The oracle is
 path is also tested against.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,13 +32,51 @@ HEADS = [(1, 64), (2, 64), (4, 64), (1, 128), (2, 128)]
 heads = pytest.mark.parametrize("h,d", HEADS, ids=[f"{h}x{d}" for h, d in HEADS])
 
 
+def _output_and_gradients(attn, cot):
+    """``operands -> (attn's output, the gradients of <output, cot> in each)`` as ONE
+    program: the interpreter's forward is traced and compiled once for both,
+    where a call of its own and a ``jax.grad`` beside it made it twice."""
+    def both(*operands):
+        def loss(*operands):
+            out = attn(*operands)
+            return jnp.vdot(out.astype(jnp.float32), cot), out
+
+        (_, out), grads = jax.value_and_grad(loss, argnums=tuple(range(len(operands))), has_aux=True)(*operands)
+        return out, grads
+
+    return jax.jit(both)
+
+
+def _f32(*xs):
+    return tuple(x.astype(jnp.float32) for x in xs)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernels_and_reference(causal: bool, h: int, d: int, b: int = 2, l: int = 256, dtype=jnp.float32, seed: int = 0):
+    """``((output, gradients) by the kernels, the same by the reference on the float32 operands)`` at one shape: the
+    forward case and the VJP case of a shape read ONE compiled pair (the forward was a program of its own, and the VJP
+    case compiled it again inside its gradient's: PR 66)."""
+    q, k, v = _qkv(dtype, b=b, l=l, h=h, d=d, seed=seed)
+    cot = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
+    got = _output_and_gradients(lambda q, k, v: flash_attention(q, k, v, causal), cot)(q, k, v)
+    return got, _output_and_gradients(lambda q, k, v: attention_reference(q, k, v, causal=causal), cot)(*_f32(q, k, v))
+
+
 @heads
 @pytest.mark.parametrize("causal", [False, True])
 def test_forward_matches_reference_f32(causal, h, d):
-    q, k, v = _qkv(jnp.float32, h=h, d=d)
-    out = flash_attention(q, k, v, causal)
-    ref = attention_reference(q, k, v, causal=causal)
+    (out, _), (ref, _) = _kernels_and_reference(causal, h, d)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+@heads
+@pytest.mark.parametrize("causal", [False, True])
+def test_vjp_matches_reference(causal, h, d):
+    (_, g_flash), (_, g_ref) = _kernels_and_reference(causal, h, d)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(
+            gf, gr, atol=5e-5, rtol=5e-5, err_msg=f"d{name}"
+        )
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -50,26 +90,6 @@ def test_forward_matches_reference_bf16(causal):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref), atol=3e-2, rtol=3e-2
     )
-
-
-@heads
-@pytest.mark.parametrize("causal", [False, True])
-def test_vjp_matches_reference(causal, h, d):
-    q, k, v = _qkv(jnp.float32, b=1, l=128, h=h, d=d, seed=3)
-    cot = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
-
-    def loss_flash(q, k, v):
-        return jnp.vdot(flash_attention(q, k, v, causal), cot)
-
-    def loss_ref(q, k, v):
-        return jnp.vdot(attention_reference(q, k, v, causal=causal), cot)
-
-    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
-    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
-    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
-        np.testing.assert_allclose(
-            gf, gr, atol=5e-5, rtol=5e-5, err_msg=f"d{name}"
-        )
 
 
 def test_shape_contract_fails_loud():
@@ -87,19 +107,13 @@ def test_shape_contract_fails_loud():
 LENGTHS = [128, 384, 1024, 1152]
 
 
-def _f32(*xs):
-    return tuple(x.astype(jnp.float32) for x in xs)
-
-
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("l", LENGTHS)
 @pytest.mark.parametrize(
     "dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)], ids=["f32", "bf16"]
 )
 def test_forward_matches_reference_at_length(dtype, tol, l, causal):
-    q, k, v = _qkv(dtype, b=1, l=l, h=2, d=64, seed=l)
-    out = flash_attention(q, k, v, causal)
-    ref = attention_reference(*_f32(q, k, v), causal=causal)
+    (out, _), (ref, _) = _kernels_and_reference(causal, 2, 64, b=1, l=l, dtype=dtype, seed=l)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref), atol=tol, rtol=tol
     )
@@ -111,37 +125,12 @@ def test_forward_matches_reference_at_length(dtype, tol, l, causal):
     "dtype,tol", [(jnp.float32, 5e-5), (jnp.bfloat16, 3e-2)], ids=["f32", "bf16"]
 )
 def test_vjp_matches_reference_at_length(dtype, tol, l, causal):
-    q, k, v = _qkv(dtype, b=1, l=l, h=2, d=64, seed=l + 1)
-    cot = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
-
-    def loss_flash(q, k, v):
-        return jnp.vdot(flash_attention(q, k, v, causal).astype(jnp.float32), cot)
-
-    def loss_ref(q, k, v):
-        return jnp.vdot(attention_reference(q, k, v, causal=causal), cot)
-
-    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
-    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(*_f32(q, k, v))
+    (_, g_flash), (_, g_ref) = _kernels_and_reference(causal, 2, 64, b=1, l=l, dtype=dtype, seed=l)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
             np.asarray(gf, np.float32), np.asarray(gr),
             atol=tol, rtol=tol, err_msg=f"d{name}",
         )
-
-
-def _output_and_gradients(attn, cot):
-    """``operands -> (attn's output, the gradients of <output, cot> in each)`` as ONE
-    program: the interpreter's forward is traced and compiled once for both,
-    where a call of its own and a ``jax.grad`` beside it made it twice."""
-    def both(*operands):
-        def loss(*operands):
-            out = attn(*operands)
-            return jnp.vdot(out.astype(jnp.float32), cot), out
-
-        (_, out), grads = jax.value_and_grad(loss, argnums=tuple(range(len(operands))), has_aux=True)(*operands)
-        return out, grads
-
-    return jax.jit(both)
 
 
 @pytest.mark.parametrize("h,d", [(1, 64), (2, 64), (1, 128)], ids=["1x64", "2x64", "1x128"])

@@ -11,6 +11,7 @@ forward's ONE walk of the pairs, a save site of the block) against
 the indexer's kernels; the eight expert shares that add up to the uncut
 layer.  CPU only."""
 
+import contextlib
 import functools
 
 import jax
@@ -185,14 +186,22 @@ def test_the_walked_selection_is_the_whole_ones_whatever_the_chunk(chunk):
     np.testing.assert_array_equal(np.asarray(fa.block_summary(mask, 128, counts)), np.asarray(fa.block_summary(mask, 128)))
 
 
+@contextlib.contextmanager
+def _the_kernels_chosen():
+    """The indexer's calls take their Pallas kernels, through the interpreter; jax's caches are dropped on both sides
+    (the blocks' ``jax.checkpoint`` keeps traces by shape across a patched path)."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ss, "kernel_path", lambda qi, ki: ss.kernels_outside_contract(qi, ki.shape[1]))
+        patch.setattr(ss, "probs_path", lambda q, k: "")
+        jax.clear_caches()
+        yield
+    jax.clear_caches()
+
+
 @pytest.fixture
-def on_the_kernels(monkeypatch):
-    """The indexer's calls take their Pallas kernels, through the interpreter."""
-    monkeypatch.setattr(ss, "kernel_path", lambda qi, ki: ss.kernels_outside_contract(qi, ki.shape[1]))
-    monkeypatch.setattr(ss, "probs_path", lambda q, k: "")
-    jax.clear_caches()
-    yield
-    jax.clear_caches()
+def on_the_kernels():
+    with _the_kernels_chosen():
+        yield
 
 
 @pytest.mark.parametrize("e,j", [(64, 4), (128, 2), (32, 8)])
@@ -282,14 +291,19 @@ def test_the_masked_flash_kernels_are_the_xla_path_forward_and_gradients(monkeyp
     b, h = (1, 2) if l > 1024 else (2, 4)
     q, k, v, mask, key = _attention_operands(0, b, l, h, h // 2, topk, recent=recent)
     wide = lambda t: jnp.repeat(t, 2, axis=2)  # noqa: E731
-    (o, lse), (want_o, want_lse) = fa.masked_flash_attention(q, wide(k), wide(v), mask), attentions.selected_attention_reference(q, wide(k), wide(v), mask)
+    cot = jax.random.normal(key, q.shape)
+
+    def read(attend):  # ONE program a side: the output, the logsumexp and the three gradients (the forward was compiled twice: PR 66)
+        def loss(q, k, v):
+            o, lse = attend(q, wide(k), wide(v), mask)
+            return jnp.vdot(o.astype(jnp.float32), cot), (o, lse)
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))(q, k, v)
+
+    (_, (o, lse)), grads = read(fa.masked_flash_attention)
+    (_, (want_o, want_lse)), wants = read(attentions.selected_attention_reference)
     assert o.dtype == jnp.bfloat16 and lse.shape == (b * h, 1, l)
     assert float(jnp.max(jnp.abs(o.astype(jnp.float32) - want_o.astype(jnp.float32)))) <= 2e-2 * float(jnp.max(jnp.abs(want_o.astype(jnp.float32))))
     assert float(jnp.max(jnp.abs(lse - want_lse))) <= 1e-5 * float(jnp.max(jnp.abs(want_lse)))
-    cot = jax.random.normal(key, q.shape)
-    loss = lambda attend: lambda q, k, v: jnp.vdot(attend(q, wide(k), wide(v), mask)[0].astype(jnp.float32), cot)  # noqa: E731
-    grads = jax.jit(jax.grad(loss(fa.masked_flash_attention), (0, 1, 2)))(q, k, v)
-    wants = jax.jit(jax.grad(loss(attentions.selected_attention_reference), (0, 1, 2)))(q, k, v)
     for got_g, want_g in zip(grads, wants):
         assert float(jnp.max(jnp.abs(got_g.astype(jnp.float32) - want_g.astype(jnp.float32)))) <= 2e-2 * float(jnp.max(jnp.abs(want_g.astype(jnp.float32))))
     if recent and l < 1024:
@@ -508,15 +522,19 @@ def _launches(jaxpr, primitive: str = "pallas_call", into=None) -> dict:
     return into
 
 
-@pytest.fixture(params=["xla", "kernels"])
+@pytest.fixture(scope="module", params=["xla", "kernels"])
 def loss_path(request):
     """``(the comparison's limit, whether the kernels run)``: the XLA forms on float32 operands (a tight comparison), or
     the Pallas kernels through the interpreter on bfloat16 (they round ds to bfloat16 and return bfloat16 gradients, as
-    the flash kernels do)."""
+    the flash kernels do).  MODULE-scoped, so that pytest runs the cases of a path together (the three functions
+    below stand together, and nothing that wants the XLA forms stands behind them in this file): the kernels are
+    chosen (``_the_kernels_chosen``: jax's caches dropped on both sides) ONCE for the sixteen cases that take them, where
+    ``on_the_kernels`` did it for each of them and took every compiled program of the worker along each time (PR 66)."""
     if request.param == "xla":
-        return 1e-4, False
-    request.getfixturevalue("on_the_kernels")
-    return 2e-2, True
+        yield 1e-4, False
+        return
+    with _the_kernels_chosen():
+        yield 2e-2, True
 
 
 @functools.lru_cache(maxsize=None)

@@ -232,17 +232,22 @@ def test_a_held_range_computes_its_own_experts_part(lo, held):
         return jnp.einsum("tk,tke,ted->td", weights, onehot, y)
 
     def system(u, wg, wu, wd):
-        return moe.expert_ffn(u, choices, weights, wg, wu, wd, n_experts=16, lo=lo)[0]
+        out, slots, _ = moe.expert_ffn(u, choices, weights, wg, wu, wd, n_experts=16, lo=lo)
+        return out, slots
+
+    def read(layer):  # ONE program a side: the output, what rides with it, the four gradients (the system's forward was compiled three times)
+        def loss(*a):
+            out, *rest = layer(*a)
+            return jnp.sum(out ** 2), (out, *rest)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True))(*args)
 
     args = (u, part(wg), part(wu), part(wd))
     with jax.default_matmul_precision("highest"):
-        want = dense(*args)
-        g_want = jax.jit(jax.grad(lambda *a: jnp.sum(dense(*a) ** 2), argnums=(0, 1, 2, 3)))(*args)
-    np.testing.assert_allclose(system(*args), want, atol=2e-5, rtol=2e-5)
-    g_got = jax.jit(jax.grad(lambda *a: jnp.sum(system(*a) ** 2), argnums=(0, 1, 2, 3)))(*args)
+        (_, (want,)), g_want = read(lambda *a: (dense(*a),))
+    (_, (got, slots)), g_got = read(system)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     for a, b in zip(g_got, g_want):
         assert _rel(a, b) <= 2e-5
-    slots = moe.expert_ffn(*args[:1], choices, weights, *args[1:], n_experts=16, lo=lo)[1]
     np.testing.assert_array_equal(np.asarray(slots), np.bincount(np.asarray(choices).ravel(), minlength=16))
     with pytest.raises(ValueError, match="are not among the router's"):
         moe.expert_ffn(u, choices, weights, part(wg), part(wu), part(wd), n_experts=16, lo=16 - held + 1)
@@ -280,6 +285,28 @@ def _choices_with(held_slots: int, lo: int, one_expert: bool, tokens=40, k=3, ex
     return jnp.asarray(choices, jnp.int32)
 
 
+@functools.lru_cache(maxsize=None)
+def _windowed_and_dense(lo: int, remat: bool, experts: int = 16, held: int = 4):
+    """``(the windowed path, the dense masked sum over the held experts)``, each ``(choices, u, weights, wg, wu, wd) ->
+    ((loss, its outputs), the five gradients)`` as ONE jitted program: the choices are an OPERAND, as they are in the
+    model, so the edges that differ only in where the slots fall read one compiled pair (each case closed over its
+    own and compiled the interpreter's grouped matmuls again: six of the eight share ``lo`` = 4; PR 66)."""
+    def dense(choices, u, weights, wg, wu, wd):
+        h = jax.nn.silu(jnp.einsum("td,edf->tef", u, wg)) * jnp.einsum("td,edf->tef", u, wu)
+        y = jnp.einsum("tef,efd->ted", h, wd)
+        onehot = jax.nn.one_hot(choices - lo, held, dtype=jnp.float32)  # out of range: all zero
+        out = jnp.einsum("tk,tke,ted->td", weights, onehot, y)
+        return jnp.sum(jnp.sin(out)), out
+
+    def system(choices, u, weights, wg, wu, wd):
+        out, _, given = moe.expert_ffn(u, choices, weights, wg, wu, wd, n_experts=experts, lo=lo)
+        return jnp.sum(jnp.sin(out)), (out, given)
+
+    ours = jax.checkpoint(system) if remat else system
+    return (jax.jit(jax.value_and_grad(ours, argnums=range(1, 6), has_aux=True)),
+            jax.jit(jax.value_and_grad(dense, argnums=range(1, 6), has_aux=True)))
+
+
 @pytest.mark.parametrize("name,held_slots,lo,one_expert,remat", HELD_EDGES, ids=[e[0] for e in HELD_EDGES])
 def test_the_held_range_is_exact_at_every_edge_of_its_row_buffers(name, held_slots, lo, one_expert, remat):
     """The windowed path (``C < T * k``) against the dense masked sum over
@@ -298,23 +325,11 @@ def test_the_held_range_is_exact_at_every_edge_of_its_row_buffers(name, held_slo
     weights = jnp.asarray(rng.uniform(0.05, 0.5, (tokens, k)), jnp.float32)
     wg, wu, wd = (jnp.asarray(rng.standard_normal(shape) * 0.3, jnp.float32)
                   for shape in ((held, d, f), (held, d, f), (held, f, d)))
-    args = (u, weights, wg, wu, wd)
-
-    def dense(u, weights, wg, wu, wd):
-        h = jax.nn.silu(jnp.einsum("td,edf->tef", u, wg)) * jnp.einsum("td,edf->tef", u, wu)
-        y = jnp.einsum("tef,efd->ted", h, wd)
-        onehot = jax.nn.one_hot(choices - lo, held, dtype=jnp.float32)  # out of range: all zero
-        out = jnp.einsum("tk,tke,ted->td", weights, onehot, y)
-        return jnp.sum(jnp.sin(out)), out
-
-    def system(u, weights, wg, wu, wd):
-        out, _, given = moe.expert_ffn(u, choices, weights, wg, wu, wd, n_experts=experts, lo=lo)
-        return jnp.sum(jnp.sin(out)), (out, given)
-
-    ours = jax.checkpoint(system) if remat else system
-    (_, (out, given)), grads = jax.jit(jax.value_and_grad(ours, argnums=range(5), has_aux=True))(*args)
+    args = (choices, u, weights, wg, wu, wd)
+    windowed, dense = _windowed_and_dense(lo, remat)
+    (_, (out, given)), grads = windowed(*args)
     with jax.default_matmul_precision("highest"):
-        (_, want), want_grads = jax.jit(jax.value_and_grad(dense, argnums=range(5), has_aux=True))(*args)
+        (_, want), want_grads = dense(*args)
     assert int(given.first) + int(given.second) == held_slots  # moe_slots_computed == moe_slots_held
     assert int(given.second) == max(held_slots - bound, 0)  # moe_slots_overflow
     assert _rel(out, want) <= 1e-5

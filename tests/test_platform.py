@@ -144,15 +144,24 @@ def test_a_cache_directory_the_rule_did_not_make_is_not_adopted(tmp_path):
 
 
 def test_the_runs_longest_serial_block_is_collected_first_with_nothing_that_weighs_behind_it(request):
-    """``tests/conftest.py``'s order (``_COLLECTED_FIRST``): wherever the lowering file is collected with other
-    files it opens the collection, whole and in its own order, and the 24th of the collection that xdist hands
-    the first worker to begin with holds only it and cases of the file named second."""
+    """``tests/conftest.py``'s order (``_COLLECTED_FIRST``): wherever the lowering files are collected with other
+    files the first opens the collection, whole and in its own order, and the 24th of the collection that xdist hands
+    the first worker to begin with holds only it and cases of the file named second; the second lowering file lies
+    WHOLE inside the second 24th, the second worker's (so added cases cannot silently put both blocks back on one
+    worker), and what fills that 24th up behind it is the light file named last."""
+    from conftest import _COLLECTED_FIRST
+
+    first, light, second, behind = _COLLECTED_FIRST
     names = [item.path.name for item in request.session.items]
-    if "test_chip_lowering.py" not in names or len(set(names)) < 24:
+    if first not in names or len(set(names)) < 24:
         pytest.skip("not a whole run: nothing to order")
-    n_first = names.count("test_chip_lowering.py")
-    assert set(names[:n_first]) == {"test_chip_lowering.py"}
-    assert set(names[n_first:len(names) // 24]) == {"test_renamed_metrics.py"}
+    deal = len(names) // 24
+    n_first = names.count(first)
+    assert set(names[:n_first]) == {first}
+    assert set(names[n_first:deal]) == {light}
+    at, n_second = names.index(second), names.count(second)
+    assert set(names[at:at + n_second]) == {second} and deal <= at and at + n_second <= 2 * deal, (deal, at, n_second)
+    assert set(names[deal:at]) == {light} and set(names[at + n_second:2 * deal]) <= {behind}
 
 
 def test_device_summary_names_what_answered():

@@ -37,8 +37,8 @@ SHAPES = {
 def test_scan_forward_and_last_state_match_the_recurrence(name):
     *shape, chunk = SHAPES[name]
     args, _ = _operands(0, *shape)
-    y, aux = ssm.ssm_scan(*args, chunk=chunk, with_aux=True)
-    want, last = ssm.ssm_scan_reference(*args)
+    y, aux = jax.jit(lambda *a: ssm.ssm_scan(*a, chunk=chunk, with_aux=True))(*args)  # ONE program a side, not a few dozen eager ones
+    want, last = jax.jit(ssm.ssm_scan_reference)(*args)
     np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(aux.state, last, rtol=2e-5, atol=2e-5)
     # the log-decays restart at every chunk
@@ -46,15 +46,21 @@ def test_scan_forward_and_last_state_match_the_recurrence(name):
     np.testing.assert_allclose(aux.log_decay, log.reshape(aux.log_decay.shape), rtol=1e-5, atol=1e-6)
 
 
+@functools.lru_cache(maxsize=None)
+def _scan_gradients(name: str):
+    """``(the chunked scan's, the recurrence's)`` gradients in all six operands at one shape, each ONE jitted program:
+    the six cases of a shape read one compiled pair (each compiled two programs of its own: PR 66)."""
+    *shape, chunk = SHAPES[name]
+    args, g = _operands(1, *shape)
+    every = lambda f: jax.jit(jax.grad(lambda *a: jnp.sum(f(*a) * g), argnums=tuple(range(6))))(*args)  # noqa: E731
+    return every(lambda *a: ssm.ssm_scan(*a, chunk=chunk)), every(lambda *a: ssm.ssm_scan_reference(*a)[0])
+
+
 @pytest.mark.parametrize("operand", range(6), ids=["x", "dt", "a", "b", "c", "d"])
 @pytest.mark.parametrize("name", ["two_groups", "heads_are_groups"])
 def test_scan_gradient_matches_the_recurrence(name, operand):
-    *shape, chunk = SHAPES[name]
-    args, g = _operands(1, *shape)
-    loss = lambda f: (lambda *a: jnp.sum(f(*a) * g))  # noqa: E731
-    got = jax.jit(jax.grad(loss(lambda *a: ssm.ssm_scan(*a, chunk=chunk)), argnums=operand))(*args)
-    want = jax.jit(jax.grad(loss(lambda *a: ssm.ssm_scan_reference(*a)[0]), argnums=operand))(*args)
-    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    got, want = _scan_gradients(name)
+    np.testing.assert_allclose(got[operand], want[operand], rtol=2e-4, atol=2e-4)
 
 
 def test_scan_last_state_carries_a_gradient():

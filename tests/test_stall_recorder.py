@@ -434,9 +434,9 @@ def test_with_a_bridge_installed_the_stall_span_is_entered_and_left_once_on_the_
         assert harness.report() is None
         harness.recorder.close_span()  # what _profile_close does before it takes the bridge away
         assert spans()[1:] == [("exit", "stall", "edl-watchdog")]
-        with harness.phases.phase("prep_wait"):
-            time.sleep(harness.GAP_S)
-        assert harness.report()["cause"] == "ingest"
+        # settled as every other case settles a late gap (one clean gap, ``CATCH_UP_GAPS`` at most beside busy neighbours):
+        # its own single gap read as CATCHING UP under load and left the record held (PR 66's sitting: the one ``F``)
+        assert harness.settled()["cause"] == "ingest"
     finally:
         trace.set_bridge(None)
     assert [e[0] for e in spans()] == ["enter", "exit"]
