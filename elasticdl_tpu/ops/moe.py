@@ -34,11 +34,22 @@ held experts' even share ``T * k * n / E``, at most ``T * k``):
   each result row is weighted and summed into its token, at most ``k`` rows
   a token, in float32 (``_token_sum``: the rows sorted by token and one
   sweep of ``ops/table_grad.merge_sweep`` over the tokens' tiles).  The
-  transpose of "gather a window's token rows" is that same sum, and the
-  transpose of the sum is the gather (``_rows_of_tokens`` /
-  ``_sum_to_tokens`` spell it as ``custom_vjp``); the weights' gradient
+  sweep reads the rows in the dtype the experts WROTE them (PR 68): the
+  ``C`` bfloat16 rows are permuted into token order as they are, the
+  router's weight rides into the kernel beside its row's token and is
+  multiplied in there (exact products, a float32 sum), and no float32
+  copy of the ``[C, D]`` rows is made, permuted or swept on the way to the
+  tokens or back from them (the one float32 ``[C, D]`` array left is the
+  weighted sum's gathered cotangent, float32 because the sum is).  The
+  transpose of "gather a window's token rows" is that same sum without
+  weights — the grouped matmuls' bfloat16 dx summed in float32 and rounded
+  once, leaving the kernel as bfloat16 ``[T, D]`` — and the transpose of
+  the weighted sum is the gather times the weight (``_rows_of_tokens`` /
+  ``_sum_to_tokens`` spell both as ``custom_vjp``); the weights' gradient
   returns to ``[T, k]`` by a gather of scalars through the inverse order.
-  No array of ``T * k`` rows exists in this path.
+  No array of ``T * k`` rows exists in this path: what walks all ``T * k``
+  slots is two int32 sorts, a ``searchsorted`` and those scalar gathers.
+  (A float32 model's rows take the float32 sweep, weighted in XLA first.)
 * Every one of a token's ``k`` slots may fall on a held expert, so the run
   can be longer than ``C``.  The rows past ``C`` are a second tier
   (``_overflow``): the same function on the same window of ``C`` rows,
@@ -277,7 +288,11 @@ SLACK = 1.5
 #: Token rows a grid step of the token sum builds (``ops/table_grad``'s
 #: ``tile``): at 2048-wide float32 rows 128 fits the 16 MiB of VMEM a
 #: kernel may scope on a v5e, 256 asks for 18.5 (compiled for a described
-#: v5e, PR 34).
+#: v5e, PR 34).  Bfloat16 chunk buffers leave room for 256, and it is SLOWER
+#: (PERF.md, PR 68, step 0 at [49152, 2048] -> [32768, 2048]: the weighted
+#: sum's kernel 0.87 ms at 128 and 1.11 at 256, the one-piece sum 0.65 and
+#: 0.72): the one-hot is [tile, 128] and mostly zeros, so a tile twice as
+#: tall does twice the MXU's work a chunk.
 TOKEN_TILE = 128
 
 
@@ -294,14 +309,17 @@ def held_rows_bound(n_slots: int, n_held: int, n_experts: int) -> int:
 
 # graftlint: allow[jit-shim] an inner jit, a trace cache inside the step's one compile (as megablox's gmm is), never a compile of its own
 @functools.partial(jax.jit, static_argnames=("n_tokens",))
-def _token_sum(rows: jax.Array, tok: jax.Array, n_tokens: int) -> jax.Array:
-    """``zeros([n_tokens, D]).at[tok].add(rows)`` (float32; ``tok ==
-    n_tokens`` is dropped) without the scatter-add: the rows sorted by token
-    and one sweep over the tokens' tiles (``ops/table_grad``).  Jitted: a
-    model's layers and their backward passes share ONE trace and one
-    lowered kernel a shape (a Pallas kernel is otherwise traced and lowered
-    anew at every call: ``setup_s``)."""
-    return table_grad.sweep_table_grad(tok, rows, n_tokens, tile=min(TOKEN_TILE, n_tokens))
+def _token_sum(rows: jax.Array, tok: jax.Array, n_tokens: int, weights: Optional[jax.Array] = None) -> jax.Array:
+    """``zeros([n_tokens, D]).at[tok].add(rows)`` (``tok == n_tokens`` is
+    dropped) without the scatter-add: the rows sorted by token and one
+    sweep over the tokens' tiles (``ops/table_grad``), which reads the rows
+    in the dtype they have, sums them in float32 and rounds the sum once to
+    that dtype; with ``weights`` (float32, one a row) the sum is of
+    ``weights[j] * rows[j]`` and stays float32.  Jitted: a model's layers
+    and their backward passes share ONE trace and one lowered kernel a
+    shape (a Pallas kernel is otherwise traced and lowered anew at every
+    call: ``setup_s``)."""
+    return table_grad.sweep_table_grad(tok, rows, n_tokens, weights, tile=min(TOKEN_TILE, n_tokens))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -318,28 +336,34 @@ def _rows_of_tokens_fwd(u, tok, n_tokens):
 
 def _rows_of_tokens_bwd(n_tokens, tok, g):
     with jax.named_scope("moe_dispatch"):
-        return _token_sum(g.astype(jnp.float32), tok, n_tokens).astype(g.dtype), None
+        return _token_sum(g, tok, n_tokens), None
 
 
 _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _sum_to_tokens(rows, tok, n_tokens):
-    """The transpose of :func:`_rows_of_tokens`: a window's rows [W, D]
-    (float32) summed into their tokens [T, D], at most ``k`` a token."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_to_tokens(y, w_rows, tok, n_tokens):
+    """A window's rows ``y`` [W, D], as the experts wrote them, each times
+    its weight ``w_rows`` [W] (float32) and summed into its token: [T, D]
+    float32, at most ``k`` rows a token.  Unweighted it is the transpose of
+    :func:`_rows_of_tokens`, and its own transpose is that gather."""
     with jax.named_scope("moe_combine"):
-        return _token_sum(rows, tok, n_tokens)
+        return _token_sum(y, tok, n_tokens, w_rows)
 
 
-def _sum_to_tokens_fwd(rows, tok, n_tokens):
-    return _sum_to_tokens(rows, tok, n_tokens), tok
+def _sum_to_tokens_fwd(y, w_rows, tok, n_tokens):
+    return _sum_to_tokens(y, w_rows, tok, n_tokens), (y, w_rows, tok)
 
 
-def _sum_to_tokens_bwd(n_tokens, tok, g):
+def _sum_to_tokens_bwd(n_tokens, res, g):
+    y, w_rows, tok = res
     with jax.named_scope("moe_combine"):
         rows = _take(g, jnp.minimum(tok, n_tokens - 1))
-        return jnp.where((tok < n_tokens)[:, None], rows, 0), None
+        rows = jnp.where((tok < n_tokens)[:, None], rows, 0)
+        dy = (rows * w_rows[:, None]).astype(y.dtype)
+        dw = jnp.sum(rows * y.astype(jnp.float32), axis=-1)
+        return dy, dw.astype(w_rows.dtype), None
 
 
 _sum_to_tokens.defvjp(_sum_to_tokens_fwd, _sum_to_tokens_bwd)
@@ -392,8 +416,7 @@ def _held_window(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, 
     y = _experts(_rows_of_tokens(u, tok, n_tokens), w_gate, w_up, w_down, sizes, 0)
     with jax.named_scope("moe_combine"):
         w_rows = _weights_of_rows(weights, slots, inverse, start + first, count)
-        rows = y.astype(jnp.float32) * w_rows[:, None]
-    return _sum_to_tokens(rows, tok, n_tokens), jnp.sum(given)
+    return _sum_to_tokens(y, w_rows, tok, n_tokens), jnp.sum(given)
 
 
 def _windows(ends, bound: int):

@@ -18,10 +18,31 @@ sequential write deliver them on its way:
    rows that belongs to it, and the pipeline writes the tile out: every
    buffer row is written exactly once, sequentially, and no HBM row is
    read back.  The adds run on the MXU as ``onehot[TILE, CHUNK] @
-   rows[CHUNK, W]`` with ``onehot = (tile's row numbers == chunk's ids)``;
-   the f32 rows are split into three bfloat16 pieces that add up to them
-   exactly, so every product is exact, the sum of the pieces of ONE row is
-   that row to the bit, and duplicates accumulate in f32.
+   rows[CHUNK, W]`` with ``onehot = (tile's row numbers == chunk's ids)``,
+   every factor a bfloat16 piece that is EXACT, as many products a chunk
+   as what the kernel can see of its input needs (PR 68):
+
+   * f32 rows are split into three bfloat16 pieces that add up to them
+     exactly, so every product is exact, the sum of the pieces of ONE row
+     is that row to the bit, and duplicates accumulate in f32 (DeepFM's
+     table gradient; three products);
+   * bfloat16 rows ARE their one exact piece: one product, chunk buffers
+     of half the bytes, the tile summed in an f32 scratch and rounded once
+     into a bfloat16 output (``ops/moe.py``: the transpose of a gather of
+     token rows — a float32 copy of the rows would be cast, permuted and
+     read at twice the bytes to feed two products of zeros);
+   * bfloat16 rows with a float32 WEIGHT a row (``sum_j [id_j == r] w_j
+     rows_j``, ``ops/moe.py``'s sum of the experts' results into their
+     tokens): the weight is sorted with its id and travels as its bits, a
+     second line under the chunk's ids; ITS three exact pieces stand where
+     the one-hot's ones stood (``where(onehot, w_piece, 0) @ rows``), so
+     every product is exact and the sum is f32 — nothing is rounded that
+     ``f32(rows) * w`` would not round, and no f32 array of the rows'
+     shape exists (step 0, PERF.md, PR 68: forming ``f32(rows) * w`` on
+     the chunk in VMEM and splitting it costs the VPU some twenty passes
+     over [CHUNK, W] where this costs six over [TILE, CHUNK]).  F32 rows
+     with a weight take it in XLA ahead of the sort (their three pieces
+     times the weight's three would be nine products).
 
 The slice of a tile starts anywhere in the sorted list, so it is read in
 whole chunks of ``CHUNK`` rows from the chunk that holds its first row on:
@@ -59,7 +80,7 @@ AD transpose.
 
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, reduce
 from typing import Optional
 
 import jax
@@ -102,13 +123,24 @@ def _merge_tile(offs, ids_a, rows_a, ids_b, rows_b, ids_any, rows_any,
     row_numbers = t * tile + lax.broadcasted_iota(jnp.int32, (tile, chunk), 0)
 
     def merged(ids, rows):
-        """[tile, W]: the chunk's rows added up at this tile's row numbers."""
-        onehot = (row_numbers == ids).astype(jnp.bfloat16)
-        hi, mid, lo = (
-            jnp.dot(onehot, piece, preferred_element_type=jnp.float32)
-            for piece in _exact_bf16_pieces(rows)
-        )
-        return (hi + mid) + lo
+        """[tile, W]: the chunk's rows added up at this tile's row numbers,
+        as one-hot products whose every factor is an exact bfloat16 piece."""
+        weighted = ids.shape[0] == 2  # the weights' bits ride under the ids
+        hit = row_numbers == (ids[:1] if weighted else ids)
+        if weighted:
+            # the weight's three pieces stand where the one-hot's ones stood
+            weight = lax.bitcast_convert_type(ids[1:], jnp.float32)
+            lhs = [jnp.where(hit, piece.astype(jnp.float32), 0.0).astype(jnp.bfloat16)
+                   for piece in _exact_bf16_pieces(weight)]
+            rhs = [rows] * 3
+        elif rows.dtype == jnp.bfloat16:
+            lhs, rhs = [hit.astype(jnp.bfloat16)], [rows]  # its own one piece
+        else:
+            lhs, rhs = [hit.astype(jnp.bfloat16)] * 3, _exact_bf16_pieces(rows)
+        return reduce(jnp.add, [
+            jnp.dot(one_hot, piece, preferred_element_type=jnp.float32)
+            for one_hot, piece in zip(lhs, rhs)
+        ])
 
     acc[...] = merged(ids_a[...], rows_a[...])
 
@@ -148,6 +180,15 @@ def _merge_tile(offs, ids_a, rows_a, ids_b, rows_b, ids_any, rows_any,
         lax.fori_loop(0, extra, body, 0)
 
 
+def _merge_tile_rounded(offs, *refs, tile: int, chunk: int):
+    """:func:`_merge_tile` for a sum that leaves in a narrower dtype than it
+    is made in (bfloat16 rows, no weight): built in a float32 scratch,
+    rounded ONCE on its way into the output block."""
+    *chunks, out, acc, ids_buf, rows_buf, sems = refs
+    _merge_tile(offs, *chunks, acc, ids_buf, rows_buf, sems, tile=tile, chunk=chunk)
+    out[...] = acc[...].astype(out.dtype)
+
+
 def _apply_kernel(offs, bias, ids_a, rows_a, ids_b, rows_b, ids_any, rows_any,
                   p_in, m_in, v_in, p_out, m_out, v_out,
                   grad, ids_buf, rows_buf, sems, *, tile: int, chunk: int,
@@ -165,22 +206,37 @@ def _apply_kernel(offs, bias, ids_a, rows_a, ids_b, rows_b, ids_any, rows_any,
     p_out[...] = p_in[...] + step_size * ((m / bias[0]) / (jnp.sqrt(v / bias[1]) + eps))
 
 
-def sort_updates(ids: jax.Array, rows: jax.Array, num_rows: int, *,
+def sort_updates(ids: jax.Array, rows: jax.Array, num_rows: int,
+                 weights: Optional[jax.Array] = None, *,
                  tile: int = TILE, chunk: int = CHUNK):
     """The XLA half: (offsets [tiles + 1], sorted ids [n_pad], rows in that
     order [n_pad, W]), ``n_pad`` a whole number of chunks (padded with the
     filler id ``num_rows``).  ``offsets[t]`` is where tile ``t`` starts in
-    the sorted list; the last one counts the rows that are not dropped."""
+    the sorted list; the last one counts the rows that are not dropped.
+    ``weights`` (f32 [N]: the sum is of ``weights[j] * rows[j]``) go through
+    the same sort and come back as their BITS under the ids, [2, n_pad] int32
+    — one block and one copy a chunk in the kernel — where the rows are
+    bfloat16; float32 rows take their weight here (their three pieces times
+    the weight's three would be nine products a chunk)."""
     n, width = rows.shape
+    if weights is not None and rows.dtype != jnp.bfloat16:
+        rows, weights = rows * weights[:, None].astype(rows.dtype), None
     n_pad = -(-n // chunk) * chunk
     if n_pad > n:
         ids = jnp.concatenate([ids, jnp.full((n_pad - n,), num_rows, ids.dtype)])
         rows = jnp.concatenate([rows, jnp.zeros((n_pad - n, width), rows.dtype)])
-    sorted_ids, order = lax.sort_key_val(ids, lax.iota(jnp.int32, n_pad))
+    slots = lax.iota(jnp.int32, n_pad)
+    if weights is None:
+        sorted_ids, order = lax.sort_key_val(ids, slots)
+        ids_out = sorted_ids
+    else:
+        bits = lax.bitcast_convert_type(jnp.pad(weights.astype(jnp.float32), (0, n_pad - n)), jnp.int32)
+        sorted_ids, order, bits = lax.sort((ids, slots, bits), num_keys=1)
+        ids_out = jnp.stack([sorted_ids, bits])
     tiles = -(-num_rows // tile)
     bounds = jnp.minimum(lax.iota(jnp.int32, tiles + 1) * tile, num_rows)
     offsets = jnp.searchsorted(sorted_ids, bounds).astype(jnp.int32)
-    return offsets, sorted_ids, rows[order]
+    return offsets, ids_out, rows[order]
 
 
 def _chunk_operands(sorted_ids, sorted_rows, chunk: int):
@@ -189,7 +245,11 @@ def _chunk_operands(sorted_ids, sorted_rows, chunk: int):
     of a tile are pipelined blocks, the rest stay in HBM."""
     n_pad, width = sorted_rows.shape
     chunks = n_pad // chunk
-    ids3 = sorted_ids.reshape(chunks, 1, chunk)
+    if sorted_ids.ndim == 1:
+        ids3 = sorted_ids.reshape(chunks, 1, chunk)
+    else:  # [2, n_pad], the weights' bits under the ids: a chunk's two lines together
+        ids3 = sorted_ids.reshape(2, chunks, chunk).swapaxes(0, 1)
+    lines = ids3.shape[1]
 
     def block(step):
         """Specs of the (ids, rows) blocks ``step`` chunks after the one
@@ -197,7 +257,7 @@ def _chunk_operands(sorted_ids, sorted_rows, chunk: int):
         def at(t, offs):
             return jnp.minimum(offs[t] // chunk + step, chunks - 1)
         return (  # ``*_``: merge_sweep_adam prefetches a second scalar array
-            pl.BlockSpec((None, 1, chunk), lambda t, offs, *_: (at(t, offs), 0, 0)),
+            pl.BlockSpec((None, lines, chunk), lambda t, offs, *_: (at(t, offs), 0, 0)),
             pl.BlockSpec((chunk, width), lambda t, offs, *_: (at(t, offs), 0)),
         )
 
@@ -207,7 +267,7 @@ def _chunk_operands(sorted_ids, sorted_rows, chunk: int):
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     scratch = [
-        pltpu.VMEM((2, 1, chunk), jnp.int32),
+        pltpu.VMEM((2, lines, chunk), jnp.int32),
         pltpu.VMEM((2, chunk, width), sorted_rows.dtype),
         pltpu.SemaphoreType.DMA((2, 2)),
     ]
@@ -218,14 +278,20 @@ def merge_sweep(offsets: jax.Array, sorted_ids: jax.Array, sorted_rows: jax.Arra
                 num_rows: int, *, tile: int = TILE, chunk: int = CHUNK,
                 interpret: Optional[bool] = None) -> jax.Array:
     """The kernel half: the [num_rows, W] buffer from :func:`sort_updates`'
-    three outputs (same ``tile`` and ``chunk``)."""
+    three outputs (same ``tile`` and ``chunk``), in the rows' dtype, or in
+    float32 where they came with weights."""
     if interpret is None:
         interpret = _use_interpret()
     width = sorted_rows.shape[1]
     operands, specs, scratch = _chunk_operands(sorted_ids, sorted_rows, chunk)
+    out_dtype = sorted_rows.dtype if sorted_ids.ndim == 1 else jnp.float32
+    if out_dtype == jnp.float32:
+        kernel = _merge_tile  # acc = the output block
+    else:
+        kernel, scratch = _merge_tile_rounded, [pltpu.VMEM((tile, width), jnp.float32), *scratch]
     return pl.pallas_call(
-        partial(_merge_tile, tile=tile, chunk=chunk),  # acc = the output block
-        out_shape=jax.ShapeDtypeStruct((num_rows, width), sorted_rows.dtype),
+        partial(kernel, tile=tile, chunk=chunk),
+        out_shape=jax.ShapeDtypeStruct((num_rows, width), out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(-(-num_rows // tile),),
@@ -275,15 +341,19 @@ def merge_sweep_adam(
 
 
 def sweep_table_grad(
-    ids: jax.Array, rows: jax.Array, num_rows: int, *,
+    ids: jax.Array, rows: jax.Array, num_rows: int,
+    weights: Optional[jax.Array] = None, *,
     tile: int = TILE, chunk: int = CHUNK, interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """``zeros([num_rows, W]).at[ids].add(rows, mode="drop")`` for f32
-    ``rows`` [N, W] and int32 ``ids`` [N] in ``[0, num_rows]`` (``num_rows``
-    itself, the filler, is dropped): to the bit where ids are distinct, to
-    f32 summation order where they repeat."""
+    """``zeros([num_rows, W]).at[ids].add(rows, mode="drop")`` for float32
+    or bfloat16 ``rows`` [N, W] and int32 ``ids`` [N] in ``[0, num_rows]``
+    (``num_rows`` itself, the filler, is dropped).  Float32 rows: to the bit
+    where ids are distinct, to f32 summation order where they repeat.
+    Bfloat16 rows: summed in float32, rounded once to bfloat16.  With
+    ``weights`` (f32 [N]) the sum is of ``weights[j] * rows[j]``, float32
+    whatever the rows are: of bfloat16 rows every product is exact."""
     return merge_sweep(
-        *sort_updates(ids, rows, num_rows, tile=tile, chunk=chunk),
+        *sort_updates(ids, rows, num_rows, weights, tile=tile, chunk=chunk),
         num_rows, tile=tile, chunk=chunk, interpret=interpret,
     )
 

@@ -933,10 +933,16 @@ GPT2_MEDIUM_STEP_SHA256 = "c635201162024437d79e8ea579e276f9e51d2fe2b21fb4c4d2987
 #: trainer's step for these cells on purpose re-pins and says so.
 MOE_LM_STEP_SHA256 = {
     ("olmoe_1b_7b_l1", "job_seq4k"): "6610fc1c02aea4649af2155ba6b4899fa16010f6156736d9da8378fde6ca8792",
-    ("kanana2_30b_a3b_ep8_l5", "job_seq8k"): "1853512d7a5e8eb7375aca1b6187ee2f8d6d66976aab2ee556e22afc4dd5438e",
+    # RE-PINNED in PR 68 (was 1853512d...d5438e since PR 37): ``ops/moe.py``'s token sums hand ``ops/table_grad``'s sweep the
+    # experts' bfloat16 rows themselves — no float32 ``[C, D]`` product, cast or permutation, the router's weight inside the
+    # kernel's one-hot, the gather's transpose leaving in bfloat16 (lowered from the CPU the sweep is the interpreter's
+    # expansion, so it IS in this text); ``olmoe_1b_7b_l1`` (every expert held: no token sum), ``evabyte_6b5_tp2_l4`` and
+    # ``gpt2_medium`` are untouched
+    ("kanana2_30b_a3b_ep8_l5", "job_seq8k"): "a6a155d523f28f5aa49d5cde57d3a03434b2667409db9e2fde66babbd8366da1",
     # PINNED in PR 45 at the values its PARENT commit (9611bdf) gives, ahead of parting the file along its layers
     ("evabyte_6b5_tp2_l4", "job_seq16k"): "87b44ef8533274d8216ec8aaeaaeb214522f60bb60cad971e1e29df4bf74797a",
-    ("nemotron3_super_tp4_ep64_l11", "job_seq8k_x1"): "8c69791bf49f57535a34227915cc3bde193bb61cdbe82ca9b0fdda3a4a6edc2e",
+    # RE-PINNED in PR 68 (was 8c69791b...6edc2e since PR 45): the token sums read bfloat16 rows, as ``kanana2``'s above
+    ("nemotron3_super_tp4_ep64_l11", "job_seq8k_x1"): "b02861f2b547f4631d6f700909c3eda3b04838b3e5377e77db85e9b89fbbad63",
     # PINNED in PR 47, which added the family ``kimi_linear`` (a part, a builder's line, a field of ``LatentAttention``,
     # a second caller of ``ops/ssm.causal_conv``): the four above are the values they had, and the fifth was the new cell's
     # RE-PINNED in PR 48 (was 7f1534c8...83152be since PR 47): ``ops/delta_rule._solve`` is a blocked forward substitution
@@ -948,12 +954,15 @@ MOE_LM_STEP_SHA256 = {
     # and asks ``ops/short_conv.conv_path`` of each chain; lowered from the CPU the three chains are the XLA ones they were
     # (``_short_conv`` / ``_short_conv_l2`` over ``ops/ssm.causal_conv``: as many pads and rsqrts as before, no kernel in this
     # text; the text is 11 lines longer, the counter's sum and output); the four above and ``gpt2_medium``'s are untouched
-    ("kimi_linear_48b_a3b_ep32_l5", "job_seq8k_x1_v20480"): "98fb0198e63160b282a73f00f50d094d430255d714e2d300d3d71a21053ffae2",
+    # RE-PINNED in PR 68 (was 98fb0198...3ffae2 since PR 53): the token sums read bfloat16 rows, as ``kanana2``'s above
+    ("kimi_linear_48b_a3b_ep32_l5", "job_seq8k_x1_v20480"): "3bfac7a3c7e472faf5263217ccf39c9c2231002eb224f800dda4339469ae84e1",
     # PINNED in PR 61 at the values its PARENT commit (ead6e50) gives, computed before any other edit of that PR: the two
     # newest cells had no pin, so a refactor of ``moe_lm`` / ``flash_attention`` / ``sparse_select`` had nothing that said
     # "unchanged" for them (the windowed attention and the selected attention are the XLA references in this text too)
-    ("trinity_mini_26b_a3b_ep8_l5", "job_seq8k_x1_v25024"): "372ec5300c085754d6aca3eca4ac98cc78cd0b100a5faf8058bc68e3282d8141",
-    ("keye_vl2_30b_a3b_ep8_l5", "job_seq16k_x1_v18992"): "af5e2c59b966aa9627e5c71611aea71dc0bf590a9a01c243b12743400eebb5ef",
+    # RE-PINNED in PR 68 (was 372ec530...2d8141 since PR 61): the token sums read bfloat16 rows, as ``kanana2``'s above
+    ("trinity_mini_26b_a3b_ep8_l5", "job_seq8k_x1_v25024"): "8e960704489982205aea5de76b7735009d1a895d1dde77d733399115dfb366ba",
+    # RE-PINNED in PR 68 (was af5e2c59...ebb5ef since PR 61): the token sums read bfloat16 rows, as ``kanana2``'s above
+    ("keye_vl2_30b_a3b_ep8_l5", "job_seq16k_x1_v18992"): "61263b940e81962ed90a3ced1edc6689d736d4b85c5523d557d5a0299ae63ca6",
 }
 
 

@@ -240,8 +240,13 @@ def _moe_lm_cell_lowered(config: str, traffic: str, device):
     return params, traffic, trainer, text, state_bytes
 
 
-#: the grouped matmuls (megablox ``gmm`` / ``tgmm``: "kernel") and the token sums' sweep ("_merge_tile"), every ``moe_lm`` cell's
-GROUPED = {("kernel", (2, 0)), ("_merge_tile", (0, 3))}
+#: the grouped matmuls (megablox ``gmm`` / ``tgmm``: "kernel") and the token sums' sweeps, every ``moe_lm`` cell's: since PR
+#: 68 both read the rows three times over (a tile's first two chunks as blocks, the rest from HBM) in the experts' bfloat16,
+#: behind the prefetched int32 offsets and each time behind the chunk's int32 ids — "_merge_tile" the weighted sum into the
+#: tokens (the weights' bits ride under the ids; float32 out), "_merge_tile_rounded" the gather's transpose (bfloat16 out).
+#: No float32 operand: ``(0, 3)`` was the one call they both were.  Neither STARTS with a bfloat16 operand, which is what the
+#: flash rooflines' patterns (``benchmark/metrics/flash_roofline_pct.*.json``) tell the attention kernels by.
+GROUPED = {("kernel", (2, 0)), ("_merge_tile", (3, 0)), ("_merge_tile_rounded", (3, 0))}
 #: the three flash kernels at the operand lists ``flash_roofline_pct.tok`` / ``.mla`` (a rotary part: two more bfloat16 each) read
 FLASH_TOK = {("_fwd_kernel", (3, 0)), ("_dq_kernel", (4, 1)), ("_dkv_kernel", (4, 2))}
 FLASH_MLA = {("_fwd_kernel", (5, 0)), ("_dq_kernel", (6, 1)), ("_dkv_kernel", (6, 2))}
@@ -302,6 +307,14 @@ def test_kanana2_step_lowers_for_v5e_with_its_scopes_kernels_and_no_score_matrix
     assert grouped and all(f"tensor<{bound}x" in line and "tensor<16x" in line for line in grouped), grouped[:1]
     for rows in (f"<{slots}x2048x", f"<{slots}x768x", f"<{slots - bound}x", "<16384x6x2048x"):
         assert rows not in text, rows
+    # the token sums read the experts' bfloat16 rows themselves (PR 68, in place of a counter): no float32 copy of the
+    # row buffer is permuted into token order (a gather's operand) or handed to a kernel, and each sweep's first operands
+    # are its int32 offsets and ids
+    moved = [line for line in text.splitlines() if '"stablehlo.gather"' in line or "stablehlo.custom_call @tpu_custom_call" in line]
+    operands = [line[line.rindex(" : (") + 4:line.rindex(") -> ")] for line in moved]
+    assert len(operands) > 12 and not any(f"tensor<{bound}x2048xf32>" in given for given in operands)
+    sweeps = [given for line, given in zip(moved, operands) if 'kernel_name = "_merge_tile' in line]
+    assert sweeps and all(re.match(rf"tensor<\d+xi32>, tensor<\d+x[12]x128xi32>, tensor<{bound}x2048xbf16>, ", given) for given in sweeps), sweeps
     assert _row_scatters(text) == _loss_and_embedding_scatters(traffic["minibatch_size"], params["seq_len"], params["vocab_size"], params["hidden_size"])
 
 

@@ -7,6 +7,7 @@ physical row — ops/embedding.py module docstring); a plain [V, dim] table is
 the pack == 1 case.  Tests cover both, since models use pack > 1 layouts."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -557,26 +558,79 @@ SWEEP_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(SWEEP_CASES))
-def test_merge_sweep_builds_the_scatter_adds_buffer(case):
+#: What the sweep can observe of its input (PR 68): kind -> (the rows' dtype, a weight a row?, the sum's dtype, one-hot
+#: products a chunk).  Float32 rows are split into three exact bfloat16 pieces; a bfloat16 row IS its one piece, the sum
+#: is made in float32 and rounded once; with a weight its three pieces stand in the one-hot and the sum stays float32.
+SWEEP_KINDS = {
+    "float32": (jnp.float32, False, jnp.float32, 3),
+    "bfloat16": (jnp.bfloat16, False, jnp.bfloat16, 1),
+    "bfloat16_weighted": (jnp.bfloat16, True, jnp.float32, 3),
+    "float32_weighted": (jnp.float32, True, jnp.float32, 3),
+}
+#: distinct ids, repeated ids, a filler id, a hot tile that enters the double-buffered loop, a padded last chunk
+NARROW_CASES = ["distinct", "zipf", "three_quarters_filler", "a_tile_with_three_chunks", "n_not_a_multiple_of_the_chunk"]
+
+
+@pytest.mark.parametrize("kind,case", [
+    *(("float32", case) for case in sorted(SWEEP_CASES)),
+    *((kind, case) for kind in ("bfloat16", "bfloat16_weighted") for case in NARROW_CASES),
+    ("float32_weighted", "zipf"), ("float32_weighted", "three_quarters_filler"),
+])
+def test_merge_sweep_builds_the_scatter_adds_buffer(kind, case):
     from elasticdl_tpu.ops.table_grad import sweep_table_grad
 
     make_ids, exact = SWEEP_CASES[case]
+    dtype, weighted, out_dtype, _ = SWEEP_KINDS[kind]
     rng = np.random.default_rng(7)
     ids = jnp.asarray(make_ids(rng), jnp.int32)
     num_rows = 200 if case == "rows_not_a_multiple_of_the_tile" else SWEEP_ROWS
-    rows = jnp.asarray(rng.standard_normal((ids.shape[0], 128)), jnp.float32)
-    expected = jnp.zeros((num_rows, 128), jnp.float32).at[ids].add(rows, mode="drop")
-    got = jax.jit(
-        lambda i, r: sweep_table_grad(i, r, num_rows, tile=64, chunk=128)
-    )(ids, rows)
-    if exact:
+    rows = jnp.asarray(rng.standard_normal((ids.shape[0], 128)), jnp.float32).astype(dtype)
+    weights = jnp.asarray(rng.uniform(0.01, 2.0, ids.shape[0]), jnp.float32) if weighted else None
+    sweep = jax.jit(lambda i, r, w: sweep_table_grad(i, r, num_rows, w, tile=64, chunk=128))
+    got = sweep(ids, rows, weights)
+    assert got.dtype == out_dtype
+    if kind == "float32_weighted":
+        # float32 rows take their weight in XLA, ahead of the sweep: today's ``f32(y) * w`` and sum, to the bit
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(sweep(ids, rows * weights[:, None], None)))
+        return
+    if kind == "bfloat16_weighted":
+        # exact products summed in float32 against one float32 rounding a product, summed: float32's rounding
+        want = jax.ops.segment_sum(rows.astype(jnp.float32) * weights[:, None], ids, num_rows + 1)[:num_rows]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=300 * 2**-23 * 16)
+        return
+    expected = jnp.zeros((num_rows, 128), jnp.float32).at[ids].add(rows.astype(jnp.float32), mode="drop")
+    if kind == "bfloat16":
+        # a float32 sum of the bfloat16 rows, rounded once: where ids are distinct the rows themselves
+        want = expected.astype(jnp.bfloat16)
+        if exact:
+            np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+        else:  # another summation order may round the last bit the other way
+            np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=2**-7, atol=1e-6)
+    elif exact:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(expected))
     else:
         # Same addends, another order: f32 summation error of <= 300 terms.
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(expected), rtol=0, atol=300 * 2**-23 * 8
         )
+
+
+@pytest.mark.parametrize("kind", sorted(SWEEP_KINDS))
+def test_the_sweep_takes_as_many_pieces_as_its_rows_need(kind):
+    """Read off the jaxpr: the kernel body makes its one-hot product at three places (a tile's first two chunks and
+    the hot tiles' loop), three products each for float32 rows or a weight, ONE for bfloat16 rows; no float32 copy of
+    bfloat16 rows is made on the way to the kernel, and the unweighted bfloat16 sum leaves it in bfloat16."""
+    from elasticdl_tpu.ops.table_grad import sweep_table_grad
+
+    dtype, weighted, out_dtype, products = SWEEP_KINDS[kind]
+    ids, rows = jnp.zeros((300,), jnp.int32), jnp.zeros((300, 128), dtype)
+    weights = jnp.ones((300,), jnp.float32) if weighted else None
+    text = str(jax.make_jaxpr(lambda i, r, w: sweep_table_grad(i, r, SWEEP_ROWS, w, tile=64, chunk=128))(ids, rows, weights))
+    assert text.count("dot_general") == 3 * products
+    assert text.count("pallas_call") == 1
+    if dtype == jnp.bfloat16:
+        assert "f32[384,128]" not in text and "f32[300,128]" not in text
+    assert re.search(r"\w+:(\w+)\[256,128\] = pallas_call", text).group(1) == {jnp.bfloat16: "bf16", jnp.float32: "f32"}[out_dtype]
 
 
 @pytest.fixture

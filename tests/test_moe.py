@@ -231,6 +231,46 @@ def test_expert_ffn_is_the_dense_masked_sum_forward_and_backward(routing):
         assert _rel(g, w) <= 1e-5
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_windowed_paths_row_movements_are_each_others_transposes(dtype):
+    """``_sum_to_tokens`` (a window's rows, as the experts wrote them, times
+    their float32 weights and summed into their tokens) and
+    ``_rows_of_tokens`` (the gather out) against plain ``segment_sum`` /
+    indexing and what autodiff derives from them — values and both
+    cotangents, a filler token and repeated tokens included.  The weighted
+    sum is float32 whatever the rows are, to float32's rounding; the
+    gather's transpose leaves in the rows' dtype, a float32 sum rounded
+    once; a filler row reads 0 both ways."""
+    n_tokens, width, d = 40, 72, 32
+    rng = np.random.default_rng(5)
+    tok = jnp.asarray(np.concatenate([rng.integers(0, n_tokens, 60), np.full(12, n_tokens)]), jnp.int32)
+    y = jnp.asarray(rng.standard_normal((width, d)), dtype)
+    w = jnp.asarray(rng.uniform(0.05, 0.5, width), jnp.float32)
+    g = jnp.asarray(rng.standard_normal((n_tokens, d)), jnp.float32)
+
+    def plain(y, w):
+        return jax.ops.segment_sum(y.astype(jnp.float32) * w[:, None], tok, n_tokens + 1)[:n_tokens]
+
+    out, back = jax.vjp(lambda y, w: moe._sum_to_tokens(y, w, tok, n_tokens), y, w)
+    want, want_back = jax.vjp(plain, y, w)
+    assert out.dtype == jnp.float32 and _rel(out, want) <= 1e-6
+    (dy, dw), (want_dy, want_dw) = back(g), want_back(g)
+    assert (dy.dtype, dw.dtype) == (dtype, jnp.float32)
+    np.testing.assert_array_equal(np.asarray(dy, np.float32), np.asarray(want_dy, np.float32))
+    assert _rel(dw, want_dw) <= 1e-6 and not np.any(np.asarray(dy[60:], np.float32)) and not np.any(np.asarray(dw[60:]))
+    # the gather out and its transpose, the unweighted sum
+    u = jnp.asarray(rng.standard_normal((n_tokens, d)), dtype)
+    rows, back = jax.vjp(lambda u: moe._rows_of_tokens(u, tok, n_tokens), u)
+    np.testing.assert_array_equal(np.asarray(rows[:60], np.float32), np.asarray(u, np.float32)[np.asarray(tok[:60])])
+    (du,) = back(y)
+    summed = jax.ops.segment_sum(y.astype(jnp.float32), tok, n_tokens + 1)[:n_tokens]
+    assert du.dtype == dtype
+    if dtype == jnp.float32:
+        assert _rel(du, summed) <= 1e-6
+    else:  # (another order of at most a few addends may round the last bit the other way)
+        np.testing.assert_allclose(np.asarray(du, np.float32), np.asarray(summed.astype(dtype), np.float32), rtol=2**-7, atol=1e-6)
+
+
 def test_no_row_scatter_in_the_expert_layer():
     """Both directions of the row movement are gathers, forward and
     backward: the jaxpr of the gradient holds sorts and gathers and no
