@@ -47,6 +47,9 @@ a layer is ``sliding_attention`` (``window`` = ``sliding_window`` keys) or ``ful
     part = (o * sigmoid(z)) Wo
     ``lfm2_moe``'s attention layers are this part WITHOUT the gate (``gate`` false: no Wz, part = o Wo) and with the rotary
     turn on a FULL layer (``rotary`` true): fewer key/value heads, a norm a head, THEN the turn, every earlier key
+    ``smallthinker``'s are it without the gate AND without the norm a head (``head_norm`` false: no q_norm, no k_norm), a
+    group of SEVEN query heads a key/value head (28 over 4), the turn on the sliding layers alone (theta 1.5e6), a FULL
+    layer with no position signal: q = a Wq ; k, v = a Wk, a Wv ; part = o Wo
 
 ``KeyeVL2``'s (:class:`IndexedSparseAttention`: DeepSeek-V3.2-Exp's sparse attention under ``sa_config``), H query
 heads over G key/value heads of ``head_dim``, an INDEXER of J heads E wide over ONE index key a position, ``topk`` keys a query:
@@ -339,7 +342,14 @@ class GatedWindowAttention(Part):
     noisy copy's L rows and then the clean copy's, ``positions`` as the model
     hands them (``0..L-1`` twice), and the keys a query sees follow the rule of
     diffusion over blocks (:func:`block_diffusion_attention`), not the causal one.
-    ``into_stream`` scales ``wo``'s draw."""
+    ``into_stream`` scales ``wo``'s draw.  ``head_norm`` false (``smallthinker``):
+    q and k pass NO norm a head and the part owns no ``q_norm`` / ``k_norm``.
+    ``product_sites`` false: the q, k, v products are NOT save sites
+    (``ops/remat.py``), the attention's output alone is — for a step whose
+    memory is its tight side, as :class:`IndexedSparseAttention`'s (``smallthinker``:
+    the trainer's estimate reads this step 3 GiB under the compiler's account,
+    so the budget would keep every product and the first compile land over the
+    line; the output is what the chooser ranks first, PERF.md section 6, PR 69)."""
 
     q_heads: int
     kv_heads: int
@@ -351,6 +361,8 @@ class GatedWindowAttention(Part):
     rotary: Optional[bool] = None
     block_length: int = 0
     into_stream: float = 1.0
+    head_norm: bool = True
+    product_sites: bool = True
 
     @property
     def counters(self):
@@ -365,10 +377,9 @@ class GatedWindowAttention(Part):
         made = {"wq": draw.normal((d, q)), "wk": draw.normal((d, kv)), "wv": draw.normal((d, kv))}
         if self.gate:
             made["wz"] = draw.normal((d, q))
-        made.update({
-            "wo": draw.normal((q, d), self.into_stream),
-            "q_norm": jnp.ones((self.head_dim,), jnp.float32), "k_norm": jnp.ones((self.head_dim,), jnp.float32),
-        })
+        made["wo"] = draw.normal((q, d), self.into_stream)
+        if self.head_norm:
+            made.update({"q_norm": jnp.ones((self.head_dim,), jnp.float32), "k_norm": jnp.ones((self.head_dim,), jnp.float32)})
         return made
 
     def apply(self, u, blk, positions, axis, cast):
@@ -376,10 +387,12 @@ class GatedWindowAttention(Part):
         with jax.named_scope("attn_proj"):
             heads = lambda t: t.reshape(b, l, -1, self.head_dim)  # noqa: E731
             # save sites (ops/remat.py): each product as the glue reads it
-            q, k, v = (heads(remat_lib.product(name, u, cast(blk["w" + name]))) for name in ("q", "k", "v"))
+            product = remat_lib.product if self.product_sites else (lambda name, a, w: a @ w)
+            q, k, v = (heads(product(name, u, cast(blk["w" + name]))) for name in ("q", "k", "v"))
             z = remat_lib.product("attn_gate", u, cast(blk["wz"])) if self.gate else None
         with jax.named_scope("attn_glue"):
-            q, k = rms_norm(q, blk["q_norm"], self.eps), rms_norm(k, blk["k_norm"], self.eps)
+            if self.head_norm:
+                q, k = rms_norm(q, blk["q_norm"], self.eps), rms_norm(k, blk["k_norm"], self.eps)
             if bool(self.window) if self.rotary is None else self.rotary:
                 q, k = rope(q, positions, self.theta), rope(k, positions, self.theta)
             group = self.q_heads // self.kv_heads

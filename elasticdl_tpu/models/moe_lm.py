@@ -16,13 +16,19 @@ sparse attention; its own loss is a second term of the total),
 ``conv_L_cache`` > 0 ``lfm2_moe``'s (LFM2: a double-gated short convolution
 in place of attention on most layers), ``block_length`` > 0 ``sdar_moe``'s
 (SDAR: Qwen3-MoE's layer TRAINED BY DIFFUSION OVER BLOCKS — the first family
-whose objective is not next-token cross-entropy and whose feed draws).
+whose objective is not next-token cross-entropy and whose feed draws),
+``sliding_window_layout`` ``smallthinker``'s (SmallThinker-21BA3B: full and
+window attention layer by layer, relu-gated experts in every layer, and a
+router that reads the rows the ATTENTION reads — the first family in which a
+value crosses from one entry of a layer to a later one).
 
 The model, with ``rmsnorm(x, g) = x * rsqrt(mean(x^2) + eps) * g``:
 
     x   = tok_emb[tokens]                                 (no position table; times sqrt(d) under ``mup_enabled``)
     for each layer, for each (norm, part) of it:  x += part(rmsnorm(x, norm))
-                 (an entry ``(norm, part, norm after)``: x += rmsnorm(part(rmsnorm(x, norm)), norm after))
+                 (an entry ``(norm, part, norm after)``: x += rmsnorm(part(rmsnorm(x, norm)), norm after);
+                  a part whose ``routes_on`` names an EARLIER entry's norm is handed what its ``route`` made of that
+                  entry's normed rows, computed ahead of that entry's own part: ``_block``)
     logits = rmsnorm(x, norm_f) Whead                     (Whead = tok_emb^T when tied; float32 logits)
     loss = CE(logits, next token) + lb_coef * LB + z_coef * Z
     LB  = E * sum_{i, e} f[i, e] * P[e],   f[i, e] = share of (layer, token) pairs whose i-th choice is e,
@@ -147,6 +153,21 @@ The metrics report that loss, the unweighed mean CE over the masked positions (`
 masked share; both copies route (2 L positions a sequence through every expert layer).  A sharded
 sequence raises.  The sampler (a block's tokens denoised over several steps against a cache of the
 finished blocks) is serving work and is not here (ROADMAP R8).
+
+With ``sliding_window_layout`` (``smallthinker``'s keys: SmallThinker-21BA3B-Instruct; what is not in its
+``config.json`` from memory of its ``modeling_smallthinker.py`` and of arXiv:2507.20984: there is no network here) a
+layer is ``(attn_norm, attention), (ffn_norm, experts)`` in EVERY layer — no dense layer, no shared expert — and the
+router sits BEFORE the attention:
+
+    u  = rmsnorm(x, attn_norm)                             eps = ``rms_norm_eps``
+    r  = u Wr (float32) ; c = top-k of r ; w = softmax(r[c])       THE ROUTER READS u, the rows the attention reads
+                                                           (= ``ops/moe.route``'s softmax over all E with the chosen renormalised)
+    x += attention_i(u)                                    (``models/attentions.GatedWindowAttention`` without its gate and its
+              norms a head): ``sliding_window_layout[i]`` 1 = the last ``sliding_window_size`` keys, 0 = every earlier key;
+              ``rope_layout[i]`` 1 = q, k take the rotary turn, 0 = NO position signal; H query heads over G key/value heads
+    v  = rmsnorm(x, ffn_norm)
+    x += sum_i w_i (relu(v Wgate[c_i]) * (v Wup[c_i])) Wdown[c_i]          relu-GATED experts: they read v, are CHOSEN by u
+    logits = rmsnorm(x, norm_f) Whead                      (untied)
 
 What the heads and experts that are not held would add is left out; the
 all-reduce of the head shares and the experts' exchange are not here.
@@ -300,7 +321,10 @@ class GatedMLP(Part):
 class RoutedExperts(Part):
     """Gated experts ``width`` wide behind a router, with a correction bias
     that chooses (``correction_bias``) and ONE gated MLP ``shared_width``
-    wide that every token passes (0: none)."""
+    wide that every token passes (0: none).  The part is two pieces,
+    :meth:`route` and the rest of :meth:`apply` ("compute what was routed"):
+    with ``routes_on`` the block calls the first on an EARLIER entry's normed
+    rows and hands the routing to the second (``_block``)."""
 
     router: Router
     width: int
@@ -308,6 +332,10 @@ class RoutedExperts(Part):
     shared_width: int = 0
     #: scales the draw of the routed experts' ``w_down``, the matrix that writes into the stream (:data:`KEYE_VL2_INTO_STREAM`)
     into_stream: float = 1.0
+    #: the gate's activation (a key of ``ops/moe.ACTIVATIONS``): a static argument of ``expert_ffn``
+    activation: str = "silu"
+    #: the norm's name of the EARLIER entry of the layer whose rows the router reads ("": the rows the experts read)
+    routes_on: str = ""
 
     counters = MOE_COUNTERS
     routes = True
@@ -324,17 +352,22 @@ class RoutedExperts(Part):
             blk["ws_down"] = draw.normal((self.shared_width, d))
         return blk
 
-    def apply(self, u, blk, positions, axis, cast):
-        b, l, dim = u.shape
-        tokens = u.reshape(b * l, dim)
+    def route(self, u, blk):
+        """The routing of the rows ``u`` [B, L, d] or [T, d] (``ops/moe.route``, under its ``moe_router`` scope)."""
         keys = dict(self.router.keys)
         if self.correction_bias:
             keys["bias"] = blk["router_bias"]
-        routing = moe.route(tokens, blk["router"], self.router.top_k, **keys)
+        return moe.route(u.reshape(-1, u.shape[-1]), blk["router"], self.router.top_k, **keys)
+
+    def apply(self, u, blk, positions, axis, cast, routing=None):
+        b, l, dim = u.shape
+        tokens = u.reshape(b * l, dim)
+        if routing is None:
+            routing = self.route(tokens, blk)  # (the one view of the rows the experts read too: the older families' text)
         y, slots, given = moe.expert_ffn(
             tokens, routing.choices, routing.weights,
             cast(blk["w_gate"]), cast(blk["w_up"]), cast(blk["w_down"]),
-            n_experts=self.router.n_experts, lo=self.router.first_held,
+            n_experts=self.router.n_experts, lo=self.router.first_held, activation=self.activation,
         )
         if self.shared_width:
             with jax.named_scope("moe_shared"):
@@ -397,12 +430,21 @@ def _block(x, blk, positions, layer: Layer, *, axis, eps, compute_dtype, unit_of
     second norm where the entry names one.  ``x`` may be
     wider than ``compute_dtype`` (a float32 residual stream): a part reads
     it cast and its output is added in ``x``'s own type.  Returns (x, each
-    part's stats — None for a part that counts nothing)."""
+    part's stats — None for a part that counts nothing).
+
+    An entry may hand a value to a LATER entry of the same layer: a part
+    whose ``routes_on`` names this entry's norm has its :meth:`route` called
+    on this entry's normed rows, ahead of this entry's own part in program
+    order, and is given the routing when its turn comes (SmallThinker: the
+    experts are CHOSEN by the rows the attention reads)."""
     cast = lambda w: w.astype(compute_dtype)  # noqa: E731
-    stats = []
-    for norm, part, *after in layer:
+    stats, handed = [], {}
+    for at, (norm, part, *after) in enumerate(layer):
         u = _rms_norm(cast(x), _gain(blk[norm], unit_offset), eps)
-        y, counted = part.apply(u, blk, positions, axis, cast)
+        for later in _parts(layer[at + 1:]):
+            if later.routes_on == norm:
+                handed[later] = {"routing": later.route(u, blk)}
+        y, counted = part.apply(u, blk, positions, axis, cast, **handed.pop(part, {}))
         for norm_after in after:
             y = _rms_norm(y, _gain(blk[norm_after], unit_offset), eps)
         x = x + y.astype(x.dtype)
@@ -1015,10 +1057,67 @@ def _lfm2_layers(
     return layers, draws, {"rms_norm_eps": float(norm_eps)}  # 8 keys a layer: an operator's 3 or 4, the experts' 4
 
 
-def _family(*, hybrid_override_pattern, attention_class, linear_attn_config, kv_lora_rank, sliding_window=0, sa_config=None, conv_L_cache=0, block_length=0) -> str:
+def _smallthinker_router(
+    *, moe_num_primary_experts, moe_num_active_primary_experts, experts_held, first_expert_held,
+    moe_primary_router_apply_softmax, norm_topk_prob, n_group, topk_group,
+):
+    """``smallthinker``'s spelling of the router's keys, mapped onto :func:`_router`'s: the top-k of the LOGITS, then a
+    softmax over the chosen (``moe_primary_router_apply_softmax``) — which is the softmax over all
+    ``moe_num_primary_experts`` with the chosen renormalised (``norm_topk_prob``): ``ops/moe.route`` as it is.  No
+    correction bias, no scale."""
+    if not moe_primary_router_apply_softmax or not norm_topk_prob:
+        raise ValueError(
+            "moe_primary_router_apply_softmax false (sigmoids of the chosen logits) or norm_topk_prob false is not supported under "
+            "sliding_window_layout: no cell runs either"
+        )
+    return _router(
+        num_experts=moe_num_primary_experts, num_experts_per_tok=moe_num_active_primary_experts, experts_held=experts_held,
+        first_expert_held=first_expert_held, scoring_func="softmax", norm_topk_prob=True, routed_scaling_factor=1.0,
+        topk_method="greedy", n_group=n_group, topk_group=topk_group,
+    )
+
+
+def _smallthinker_layers(
+    router, correction_bias,
+    *, sliding_window_layout, rope_layout, sliding_window_size, num_hidden_layers, num_attention_heads, num_key_value_heads,
+    head_dim, rope_theta, rms_norm_eps, moe_ffn_hidden_size,
+):
+    """``smallthinker``'s layers: attention (``sliding_window_layout[i]`` 1: the last ``sliding_window_size`` keys, 0:
+    every earlier key; ``rope_layout[i]`` 1: q, k take the rotary turn, 0: NO position signal; no norm a head, no gate,
+    no bias) and relu-gated experts ``moe_ffn_hidden_size`` wide in EVERY layer, whose router reads the rows the
+    ATTENTION reads (``routes_on``: ``_block`` routes ahead of the attention and hands the routing on).  ``wo`` and the
+    experts' ``w_down`` are drawn at :data:`KEYE_VL2_INTO_STREAM` of the init's scale, a STAND-IN for the same reason as
+    ``KeyeVL2``'s: at the init's own scale every token of a sequence routes alike from the third layer on (all 4096
+    tokens on the same six experts: the reference on the CPU at the published widths, PERF.md section 6, PR 69)."""
+    windows = tuple(sliding_window_layout)
+    turns = tuple(rope_layout if rope_layout is not None else windows)
+    if not len(windows) == len(turns) == num_hidden_layers or (set(windows) | set(turns)) - {0, 1}:
+        raise ValueError(
+            f"sliding_window_layout and rope_layout must give each of {num_hidden_layers} layers a 0 or a 1, got {windows!r} / {turns!r}"
+        )
+    kv_heads = num_key_value_heads or num_attention_heads
+    if num_attention_heads % kv_heads or head_dim <= 0 or head_dim % 2 or (any(windows) and sliding_window_size <= 0) or moe_ffn_hidden_size <= 0:
+        raise ValueError(
+            f"{num_attention_heads} query heads over {kv_heads} key/value heads of head_dim {head_dim} (even: rotary pairs), "
+            f"sliding_window_size {sliding_window_size}, moe_ffn_hidden_size {moe_ffn_hidden_size}"
+        )
+    attentions = {
+        (slides, turn): GatedWindowAttention(
+            num_attention_heads, kv_heads, head_dim, sliding_window_size if slides else 0, float(rope_theta), float(rms_norm_eps),
+            gate=False, rotary=bool(turn), head_norm=False, into_stream=KEYE_VL2_INTO_STREAM, product_sites=False,
+        )
+        for slides, turn in set(zip(windows, turns))
+    }
+    experts = RoutedExperts(
+        router, moe_ffn_hidden_size, correction_bias, into_stream=KEYE_VL2_INTO_STREAM, activation="relu", routes_on="attn_norm")
+    layers = tuple((("attn_norm", attentions[kind]), ("ffn_norm", experts)) for kind in zip(windows, turns))
+    return layers, 8  # keys of the stream a layer: four projections, the experts' four
+
+
+def _family(*, hybrid_override_pattern, attention_class, linear_attn_config, kv_lora_rank, sliding_window=0, sa_config=None, conv_L_cache=0, block_length=0, sliding_window_layout=None) -> str:
     """Which family's builders read the keys.  ``hybrid_override_pattern``,
     ``attention_class`` ``'eva'``, ``linear_attn_config``, ``kv_lora_rank``,
-    ``sliding_window``, ``sa_config``, ``conv_L_cache`` and ``block_length`` each name one, and one model is of one — with ONE rule for a pair:
+    ``sliding_window``, ``sa_config``, ``conv_L_cache``, ``block_length`` and ``sliding_window_layout`` each name one, and one model is of one — with ONE rule for a pair:
     ``linear_attn_config`` decides over ``kv_lora_rank`` (``kimi_linear``'s
     full-attention layers ARE latent attention: the rank is one of its own
     keys).  Any other two together are refused."""
@@ -1030,13 +1129,13 @@ def _family(*, hybrid_override_pattern, attention_class, linear_attn_config, kv_
             ("nemotron_h", hybrid_override_pattern is not None), ("evabyte", attention_class == "eva"),
             ("kimi_linear", linear_attn_config is not None), ("deepseek_v3", bool(kv_lora_rank) and linear_attn_config is None),
             ("afmoe", sliding_window > 0), ("keye_vl2", sa_config is not None), ("lfm2_moe", conv_L_cache > 0),
-            ("sdar_moe", block_length > 0),
+            ("sdar_moe", block_length > 0), ("smallthinker", sliding_window_layout is not None),
         )
         if said
     ]
     if len(named) > 1:
         raise ValueError(
-            "hybrid_override_pattern, attention_class 'eva', linear_attn_config, kv_lora_rank, sliding_window, sa_config, conv_L_cache and block_length each name a family and "
+            "hybrid_override_pattern, attention_class 'eva', linear_attn_config, kv_lora_rank, sliding_window, sa_config, conv_L_cache, block_length and sliding_window_layout each name a family and "
             f"one model is of one (linear_attn_config alone decides over kv_lora_rank): got those of {named}"
         )
     return named[0] if named else "olmoe"
@@ -1056,6 +1155,7 @@ FAMILIES = {
     "keye_vl2": _keye_vl2_layers,
     "lfm2_moe": lambda own: own(functools.partial(_lfm2_layers, *own(_lfm2_router))),
     "sdar_moe": functools.partial(_keye_vl2_layers, attention=_block_diffusion_attention, draws=0),  # 4 of the attention, 4 of the experts
+    "smallthinker": lambda own: own(functools.partial(_smallthinker_layers, *own(_smallthinker_router))),
 }
 
 
@@ -1215,6 +1315,14 @@ def model_spec(
     # sdar_moe's keys (defaults: OLMoE's block)
     block_length: int = 0,
     noise_seed: int = 0,
+    # smallthinker's keys (defaults: OLMoE's block)
+    sliding_window_layout: Optional[Sequence[int]] = None,
+    rope_layout: Optional[Sequence[int]] = None,
+    sliding_window_size: int = 0,
+    moe_num_primary_experts: int = 0,
+    moe_num_active_primary_experts: int = 0,
+    moe_ffn_hidden_size: int = 0,
+    moe_primary_router_apply_softmax: bool = True,
 ) -> ModelSpec:
     """``layer_types`` names each layer's feed-forward, ``"moe"`` or
     ``"dense"`` (both gated; dense layers ``intermediate_size`` wide, experts
@@ -1285,9 +1393,16 @@ def model_spec(
     from ``noise_seed`` (``data/codecs.block_diffusion_feed``; the mask is the id ``vocab_size - 1``), the body runs the noisy
     and the clean copy as ONE sequence of ``2 x seq_len`` rows under the rule of diffusion over blocks of ``block_length``
     (``ops/flash_attention.bd_mask``), the head reads the noisy copy and the loss is over the masked positions, weighed 1 / p.
+    ``sliding_window_layout`` (``smallthinker``: SmallThinker-21BA3B): a 0 or a 1 a layer, 1 = attention over the last
+    ``sliding_window_size`` keys, 0 = over every earlier key; ``rope_layout`` likewise, 1 = q and k take the rotary turn
+    (``rope_theta``), 0 = no position signal; ``num_attention_heads`` query heads over ``num_key_value_heads`` key/value heads
+    of ``head_dim``, no norm a head, no gate; EVERY layer's feed-forward ``moe_num_primary_experts`` relu-gated experts
+    ``moe_ffn_hidden_size`` wide, ``moe_num_active_primary_experts`` a token, whose router reads the rows the ATTENTION
+    reads (a softmax over the chosen logits: ``moe_primary_router_apply_softmax`` with ``norm_topk_prob``, both required);
+    ``experts_held`` / ``first_expert_held`` as ever; ``wo`` and ``w_down`` drawn at :data:`KEYE_VL2_INTO_STREAM` of ``init_std``.
 
     Which FAMILY the model is of follows from ``hybrid_override_pattern``,
-    ``attention_class``, ``linear_attn_config``, ``kv_lora_rank``, ``sliding_window``, ``sa_config``, ``conv_L_cache`` and ``block_length`` (:func:`_family`); the family's
+    ``attention_class``, ``linear_attn_config``, ``kv_lora_rank``, ``sliding_window``, ``sa_config``, ``conv_L_cache``, ``block_length`` and ``sliding_window_layout`` (:func:`_family`); the family's
     builders take their own keys and check their ranges, and a key that no
     builder of the chosen family reads, set to other than its default, is
     refused: it would change nothing."""
@@ -1308,7 +1423,7 @@ def model_spec(
         raise ValueError(
             f"{', '.join(foreign)}: set, but no part of the {family!r} family reads "
             f"{'it' if len(foreign) == 1 else 'them'} (the family follows from hybrid_override_pattern / attention_class / "
-            f"linear_attn_config / kv_lora_rank / sliding_window / sa_config / conv_L_cache / block_length)"
+            f"linear_attn_config / kv_lora_rank / sliding_window / sa_config / conv_L_cache / block_length / sliding_window_layout)"
         )
     return spec
 

@@ -47,6 +47,9 @@ class Part:
     routes: bool = False
     #: its router has a correction bias, which the model's own rule moves after each step
     correction_bias: bool = False
+    #: the norm's name of an EARLIER entry of its layer whose normed rows it reads too ("": none): the block calls the
+    #: part's ``route(u, params)`` there and hands :meth:`apply` the result as ``routing`` (``moe_lm._block``)
+    routes_on: str = ""
 
     def init(self, draw: Draws, d: int) -> Dict[str, Any]:
         """The part's parameters for a residual stream ``d`` wide."""

@@ -270,12 +270,23 @@ tiles whatever the lane group holds (the heads of a group unrolled in one
 step overflowed it from four heads on, step 0), and only the carried state
 grows with the heads of a group (``tests/test_chip_lowering.py`` compiles
 both cells' shapes and L = 8192 ahead of time against libtpu, no chip
-needed).  ``_MAX_L`` = 8192 is the contract of the FULL, window and rotary
-calls, tested so far and left where it was by PR 58: no cell runs them
-longer.  The masked calls have their own, ``_MAX_L_MASKED`` = 16,384
-(blocks of 1024 rows, sub-tiles of 512 / 256: a step's tiles are what they
-are at 8192), compiled ahead of time at the cell's shapes.  Longer
-sequences are what sequence parallelism is for.
+needed).  ``_MAX_L`` = 16,384 is the contract of the FULL and the window
+calls (PR 69: ``smallthinker_job`` runs both at its published 16,384
+positions, 16 blocks of 1024 rows a head, a window of 4096 = four blocks):
+a step's blocks, sub-tiles and carried state are what they are at 8192 —
+only the grid is longer (136 steps a head for the folded triangle's 136
+pairs, 80 for the window's 70 in rows of ``w + 1``) and the per-query float32 operand
+``[B * H, 2, L]`` twice as wide — so the 16 MiB hold as they did;
+``tests/benchmark/test_smallthinker_cell.py`` compiles ``[1, 16384, 28, 128]``
+full and under the window for a described v5e, ``tests/test_smallthinker.py``
+runs 16 blocks a head and a window of four through the interpreter, and
+the cell's traced runs hold both calls against the float32 masked softmax
+on their own operands (``window_output`` / ``full_output``).  The ROTARY
+calls (a second product, latent attention) stay at ``_MAX_L_ROTARY`` =
+8192, tested so far: no cell runs them longer.  The masked calls have their
+own, ``_MAX_L_MASKED`` = 16,384 (blocks of 1024 rows, sub-tiles of 512 /
+256), compiled ahead of time at the cell's shapes.  Longer sequences are
+what sequence parallelism is for.
 """
 
 from __future__ import annotations
@@ -307,7 +318,8 @@ _BLOCK = 1024
 _T_FWD = 512
 _T_BWD = 256
 _LANE = 128     # lanes of a block: 128 // D heads share them
-_MAX_L = 8192   # longest L under test (see module docstring)
+_MAX_L = 16384          # longest L of a full or window call under test (see module docstring)
+_MAX_L_ROTARY = 8192    # ... of a call with a rotary part
 
 
 def _use_interpret() -> bool:
@@ -842,7 +854,8 @@ def window_outside_contract(lq: int, window: int) -> str:
 def outside_contract(q, k, v, q_rot=None, k_rot=None, window=None) -> str:
     """Why these shapes are outside the kernels' contract, ``""`` inside it
     (callers take the XLA path on a reason instead of tripping ``_check``):
-    self-attention, L in whole 128-row tiles up to ``_MAX_L``, and a head
+    self-attention, L in whole 128-row tiles up to ``_MAX_L`` (``_MAX_L_ROTARY``
+    with a rotary part), and a head
     width that divides the 128 lanes of a block (128 // D heads share a lane
     group; where H * D is not whole lane groups the last is padded with zero
     heads).  Any other width is the XLA path's.  A rotary part (``q_rot``
@@ -867,6 +880,8 @@ def outside_contract(q, k, v, q_rot=None, k_rot=None, window=None) -> str:
             return why
     if q_rot is None:
         return ""
+    if lq > _MAX_L_ROTARY:
+        return f"L = {lq} with a rotary part is over {_MAX_L_ROTARY}"
     r = q_rot.shape[-1]
     if q_rot.shape != q.shape[:3] + (r,) or k_rot.shape != q.shape[:2] + (r,):
         return "q_rot is not [B, L, H, R] with ONE shared k_rot [B, L, R]"

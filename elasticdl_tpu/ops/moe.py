@@ -3,8 +3,9 @@
 ``route`` picks ``k`` of ``E`` experts a token (a softmax or a sigmoid over
 all ``E``, then top-k, float32 throughout); ``expert_ffn`` runs every
 (token, expert) slot whose expert it HOLDS through that expert's
-feed-forward (gated, or two matrices under relu squared) and sums a token's
-``k`` results with the router's weights.
+feed-forward (gated under silu, gated under relu, or two matrices under relu
+squared: ``ACTIVATIONS``) and sums a token's ``k`` results with the router's
+weights.
 DROPLESS: there is no capacity factor, every held slot is computed,
 whatever the routing (the sum of the held groups' sizes equals the slots
 the router sent to them; the trainer's ``moe_slots_computed`` and
@@ -72,7 +73,9 @@ scatter-add of rows anywhere.
 Device scopes (``jax.named_scope``; ``benchmark/readers/op_ms_step.py``
 reads them): ``moe_router`` here in ``route`` and in ``router_stats``,
 ``moe_dispatch`` (sort, sizes, the windows, the row gathers out and their
-transposes), ``moe_experts`` (the grouped matmuls and the gate),
+transposes), ``moe_experts`` (the grouped matmuls and the gate: silu, relu
+or, for two matrices, relu squared — ``ACTIVATIONS``, a static argument of
+``expert_ffn`` that every tier and the second tier's rerun carry),
 ``moe_combine`` (the weights, the sum into the tokens and its transpose).
 
 The EXCHANGE of expert parallelism is not here: on a mesh every device
@@ -231,7 +234,16 @@ def sort_slots(choices: jax.Array, n_experts: int):
 #: 0 on a v5e (PERF.md, PR 30): at 65,536 x 2048 x 1024, 64 uneven groups,
 #: forward + backward, 96 TFLOP/s against ``lax.ragged_dot``'s 70 and a
 #: plain matmul's 151; (512, 2048, 1024) and (1024, 1024, 1024) do not fit
-#: VMEM, the kernel's default (128, 128, 128) runs at 9.
+#: VMEM, the kernel's default (128, 128, 128) runs at 9.  At 2560-wide rows
+#: (``smallthinker_job``: 2.5 contraction tiles of the up and gate products,
+#: 2.5 column tiles of the down product; the kernel masks the ragged last
+#: tile) it stays: step 0 of PR 69 on a v5e, ONE relu-gated layer forward +
+#: backward at [16384, 2560], k = 6, 8 of 64 held, width 768 (12,459 rows
+#: computed): (512, 1024, 1024) 13.89 ms, (512, 1280, 768) 13.79, (512, 1280,
+#: 1280) 14.08, (512, 640, 1280) 14.75, (512, 512, 512) 14.81; (512, 2560,
+#: 768) does not fit VMEM.  A contraction tile that divides 2560 buys 0.1 ms
+#: a layer (0.7 %), inside a second process's noise: nothing is read off the
+#: shapes, and the 2048-wide cells compile the program they compiled.
 GMM_TILING = (512, 1024, 1024)
 
 
@@ -258,15 +270,21 @@ def _grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array, lo: int) -> ja
     )
 
 
-def _experts(x, w_gate, w_up, w_down, sizes, lo: int):
+#: What a gated expert's gate passes: ``act(x Wgate) * (x Wup)`` (``silu``: every family but one; ``relu``: SmallThinker's
+#: sparse ReGLU).  An expert of TWO matrices (``w_gate`` None) is ``relu(x Wup)^2`` whatever this says.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _experts(x, w_gate, w_up, w_down, sizes, lo: int, activation: str = "silu"):
     """The held experts' feed-forward over rows grouped by expert: gated
-    (``silu(x Wgate) * (x Wup)``), or, for experts of TWO matrices
-    (``w_gate`` None), ``relu(x Wup)^2``; then ``Wdown``."""
+    (``act(x Wgate) * (x Wup)``, ``act`` = ``ACTIVATIONS[activation]``), or,
+    for experts of TWO matrices (``w_gate`` None), ``relu(x Wup)^2``; then
+    ``Wdown``."""
     with jax.named_scope("moe_experts"):
         if w_gate is None:
             h = jnp.square(jax.nn.relu(_grouped_matmul(x, w_up, sizes, lo)))
         else:
-            h = jax.nn.silu(_grouped_matmul(x, w_gate, sizes, lo)) * _grouped_matmul(x, w_up, sizes, lo)
+            h = ACTIVATIONS[activation](_grouped_matmul(x, w_gate, sizes, lo)) * _grouped_matmul(x, w_up, sizes, lo)
         return _grouped_matmul(h, w_down, sizes, lo)
 
 
@@ -292,7 +310,10 @@ SLACK = 1.5
 #: (PERF.md, PR 68, step 0 at [49152, 2048] -> [32768, 2048]: the weighted
 #: sum's kernel 0.87 ms at 128 and 1.11 at 256, the one-piece sum 0.65 and
 #: 0.72): the one-hot is [tile, 128] and mostly zeros, so a tile twice as
-#: tall does twice the MXU's work a chunk.
+#: tall does twice the MXU's work a chunk.  At 2560-wide rows (PR 69) 128
+#: still compiles inside the 16 MiB (the whole step for a described v5e,
+#: ``tests/benchmark/test_smallthinker_cell.py``): the chunk buffers are a
+#: quarter wider and bfloat16.
 TOKEN_TILE = 128
 
 
@@ -397,7 +418,7 @@ def _weights_of_rows_bwd(res, g):
 _weights_of_rows.defvjp(_weights_of_rows_fwd, _weights_of_rows_bwd)
 
 
-def _held_window(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, first, width: int):
+def _held_window(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, first, width: int, activation: str = "silu"):
     """Rows ``[first, first + width)`` of the held experts' run of the
     sorted slots (the run starts at sorted position ``start``; ``ends`` [n]
     are where each held expert's slots end in it), through their experts
@@ -413,7 +434,7 @@ def _held_window(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, 
         sizes = jnp.concatenate([given, (width - count)[None]])
         slots = lax.dynamic_slice(jnp.pad(order, (0, width)), (start + first,), (width,))
         tok = jnp.where(lax.iota(jnp.int32, width) < count, slots // k, n_tokens)
-    y = _experts(_rows_of_tokens(u, tok, n_tokens), w_gate, w_up, w_down, sizes, 0)
+    y = _experts(_rows_of_tokens(u, tok, n_tokens), w_gate, w_up, w_down, sizes, 0, activation)
     with jax.named_scope("moe_combine"):
         w_rows = _weights_of_rows(weights, slots, inverse, start + first, count)
     return _sum_to_tokens(y, w_rows, tok, n_tokens), jnp.sum(given)
@@ -425,8 +446,8 @@ def _windows(ends, bound: int):
     return (ends[-1] + bound - 1) // bound
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(10,))
-def _overflow(acc, u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(10, 11))
+def _overflow(acc, u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound, activation="silu"):
     """``acc`` plus the held run's rows PAST ``bound``: the second tier, the
     same window of ``bound`` rows moved along the run by a loop that makes
     no trip while the run fits the first tier's buffers.  Returns (the sum,
@@ -439,24 +460,24 @@ def _overflow(acc, u, weights, w_gate, w_up, w_down, order, inverse, start, ends
     34)."""
     def body(i, carry):
         acc, given = carry
-        out, rows = _held_window(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, i * bound, bound)
+        out, rows = _held_window(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, i * bound, bound, activation)
         return acc + out, given + rows
 
     return lax.fori_loop(1, _windows(ends, bound), body, (acc, jnp.int32(0)))
 
 
-def _overflow_fwd(acc, u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound):
+def _overflow_fwd(acc, u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound, activation):
     args = (u, weights, w_gate, w_up, w_down, order, inverse, start, ends)
-    return _overflow(acc, *args, bound), args
+    return _overflow(acc, *args, bound, activation), args
 
 
-def _overflow_bwd(bound, res, g):
+def _overflow_bwd(bound, activation, res, g):
     *diff, order, inverse, start, ends = res
     g_out, _ = g
 
     def body(i, grads):
         def window(*diff):
-            return _held_window(*diff, order, inverse, start, ends, i * bound, bound)[0]
+            return _held_window(*diff, order, inverse, start, ends, i * bound, bound, activation)[0]
 
         # (trees: an expert of two matrices has None for its gate)
         return jax.tree.map(jnp.add, grads, jax.vjp(window, *diff)[1](g_out))
@@ -477,15 +498,15 @@ class Given(NamedTuple):
 
 
 # graftlint: allow[jit-shim] an inner jit, a trace cache inside the step's one compile (as megablox's gmm is), never a compile of its own
-@functools.partial(jax.jit, static_argnames=("bound",))
-def _held_tiers(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound: int):
+@functools.partial(jax.jit, static_argnames=("bound", "activation"))
+def _held_tiers(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound: int, activation: str = "silu"):
     """Both tiers of the windowed path: ([T, D] float32, :class:`Given`).
     Jitted so that a model's expert layers, alike in shape, are traced,
     differentiated and lowered ONCE (a window, its transpose and the second
     tier's loops are some 1 s of tracing a layer otherwise: ``setup_s``)."""
     args = (u, weights, w_gate, w_up, w_down, order, inverse, start, ends)
-    acc, first = _held_window(*args, 0, bound)
-    acc, second = _overflow(acc, *args, bound)
+    acc, first = _held_window(*args, 0, bound, activation)
+    acc, second = _overflow(acc, *args, bound, activation)
     return acc, Given(first, second)
 
 
@@ -498,13 +519,15 @@ def expert_ffn(
     w_down: jax.Array,
     n_experts: Optional[int] = None,
     lo: int = 0,
+    activation: str = "silu",
 ) -> Tuple[jax.Array, jax.Array, Given]:
-    """``sum_i weights[t, i] * (silu(u Wgate[e]) * (u Wup[e])) Wdown[e]``
+    """``sum_i weights[t, i] * (act(u Wgate[e]) * (u Wup[e])) Wdown[e]``
     with ``e = choices[t, i]``, over the slots whose expert is HELD, for
     every token ``t``: ``u`` [T, D], ``choices`` / ``weights`` [T, k] over
     the router's ``n_experts`` (None: as many as are held), the held
     experts ``[lo, lo + n)``'s weights [n, D, F] / [n, F, D] already in the
-    compute dtype.  ``w_gate`` None: experts of two matrices,
+    compute dtype.  ``activation`` (static: a key of ``ACTIVATIONS``) is the
+    gate's ``act``, silu or relu.  ``w_gate`` None: experts of two matrices,
     ``relu(u Wup[e])^2 Wdown[e]``, through the same tiers and buffers.
     Returns (the result [T, D] in ``u``'s dtype, the slots
     [E] the router sent each of ITS experts, the rows the grouped matmuls
@@ -514,12 +537,14 @@ def expert_ffn(
     n_experts = n_held if n_experts is None else n_experts
     if not 0 <= lo <= n_experts - n_held:
         raise ValueError(f"held experts [{lo}, {lo + n_held}) are not among the router's {n_experts}")
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"activation {activation!r} is not one of {sorted(ACTIVATIONS)}")
     order, inverse, sizes = sort_slots(choices, n_experts)
     bound = held_rows_bound(n_tokens * k, n_held, n_experts)
     if bound == n_tokens * k:
         # The buffers hold every slot: one window is the whole sorted
         # order, and the way back is its inverse permutation.
-        y = _experts(_rows_out(u, order, inverse, k), w_gate, w_up, w_down, sizes, lo)
+        y = _experts(_rows_out(u, order, inverse, k), w_gate, w_up, w_down, sizes, lo, activation)
         y = _rows_back(y, order, inverse)
         with jax.named_scope("moe_combine"):
             y = y.reshape(n_tokens, k, -1).astype(jnp.float32)
@@ -528,6 +553,6 @@ def expert_ffn(
     with jax.named_scope("moe_dispatch"):
         start = jnp.sum(sizes[:lo])
         ends = jnp.cumsum(sizes[lo:lo + n_held])
-    acc, given = _held_tiers(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound)
+    acc, given = _held_tiers(u, weights, w_gate, w_up, w_down, order, inverse, start, ends, bound, activation)
     with jax.named_scope("moe_combine"):
         return acc.astype(u.dtype), sizes, given
